@@ -12,6 +12,9 @@ from gubernator_tpu.cluster import LocalCluster
 
 
 def main(argv=None) -> int:
+    from gubernator_tpu.jaxenv import enable_compile_cache
+
+    enable_compile_cache()
     addresses = [f"127.0.0.1:{p}" for p in range(9090, 9096)]
     cluster = LocalCluster(addresses, global_sync_wait=0.05)
     cluster.start()

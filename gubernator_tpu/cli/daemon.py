@@ -7,6 +7,7 @@ GUBER_* env vars with an optional KEY=value config file injected first
 
 import argparse
 import asyncio
+import logging
 import sys
 
 from gubernator_tpu.serve.config import config_from_env, load_config_file
@@ -32,6 +33,13 @@ def main(argv=None) -> int:
         level="debug" if conf.debug else conf.log_level,
         json_format=conf.log_json,
     )
+
+    # one compile cache for every entry point: JAX_COMPILATION_CACHE_DIR
+    # when set, else <checkout>/.jax_cache (gubernator_tpu/jaxenv.py)
+    from gubernator_tpu.jaxenv import enable_compile_cache, require_tpu
+
+    log = logging.getLogger("gubernator_tpu.daemon")
+    log.info("compile cache: %s", enable_compile_cache())
 
     if conf.dist_coordinator:
         # multi-host mesh: join the jax.distributed program first; then
@@ -76,6 +84,10 @@ def main(argv=None) -> int:
         )
         if conf.dist_process_id != 0:
             from gubernator_tpu.core.engine import buckets_for_limit
+
+            # the leader checks in make_backend; a follower builds its
+            # engine here and must refuse the same silent CPU fallback
+            require_tpu("a multihost follower", conf.jax_platform)
 
             # the bucket ladder AND store geometry must match the
             # leader's exactly: warmup replays every bucket through the

@@ -63,7 +63,7 @@ DEFAULT_BUCKETS = (64, 256, 1024, 4096)
 # deployments, where the writeback's full-table HBM pass is paid once
 # per batch and only batch depth amortizes it (a 1 GiB store measured
 # 4.28M dec/s at B=16384 vs 20.6M at B=131072 —
-# BENCH_ZIPF10M_PROFILE_r5.json, docs/round5.md). Only rungs below the
+# BENCH_ZIPF10M_PROFILE_r5.json). Only rungs below the
 # configured GUBER_DEVICE_BATCH_LIMIT materialize (buckets_for_limit),
 # so default deployments compile nothing extra.
 DEEP_BUCKETS = (16384, 32768, 131072)

@@ -5,9 +5,9 @@ at B=16k on v5e — ~15x off the HBM bandwidth bound for the 16 MiB it
 actually moves. (r5 device-trace finding: current XLA lowers this
 scatter as a FULL-TABLE pass — 51us at 16 MiB, 3324us at 1 GiB, i.e.
 read+write of the whole table at ~650 GB/s regardless of update count;
-see scripts/profile_zipf10m.py and docs/round5.md, including the
-measured dead end of a sparse pallas alternative.) This kernel instead
-SWEEPS the whole store once per batch:
+see scripts/profile_zipf10m.py; a hand-pipelined sparse pallas scatter
+was a measured dead end — 565 ns/tile, scalar-core DMA-issue bound.)
+This kernel instead SWEEPS the whole store once per batch:
 
   for each tile of TILE_ROWS bucket rows (grid):  [Mosaic pipelines tiles]
     for each chunk of up to CHUNK update rows whose (sorted) bucket falls
@@ -226,19 +226,11 @@ def _apply_inline(
         [drow, jnp.broadcast_to(bkt[:, None], (B, 128))], axis=1
     )
 
-    # The session runs with x64 enabled (uint64 key hashes); tracing this
-    # kernel under x64 trips an astype recursion inside pallas (jax
-    # v0.9.x). Every input here is int32, so trace the pallas_call with
-    # x64 locally disabled — numerics are identical. (jax 0.4.x spells
-    # the context manager jax.experimental.disable_x64; 0.5+ promotes
-    # it to jax.enable_x64(False).)
-    if hasattr(jax, "enable_x64"):
-        ctx = jax.enable_x64(False)
-    else:
-        from jax.experimental import disable_x64
-
-        ctx = disable_x64()
-    with ctx:
+    # The process runs with x64 enabled (uint64 key hashes); tracing
+    # this kernel under x64 trips an astype recursion inside pallas
+    # (jax 0.9). Every input here is int32, so trace the pallas_call
+    # with x64 locally disabled — numerics are identical.
+    with jax.enable_x64(False):
         return _call(data, bounds, comb, ntiles, buckets, interpret)
 
 
@@ -268,16 +260,11 @@ def _call(data, bounds, comb, ntiles, buckets, interpret=False):
             pltpu.SemaphoreType.DMA((3,)),
         ],
     )
-    # jax 0.4.x names the params class TPUCompilerParams; 0.5+ renames
-    # it CompilerParams
-    _params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
     kwargs = (
         dict(interpret=True)
         if interpret
         else dict(
-            compiler_params=_params_cls(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)
             )
         )

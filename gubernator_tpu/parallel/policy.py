@@ -12,13 +12,6 @@ choice for GLOBAL sync — and ONE engine (parallel/sharded.py
 PartitionedEngine) consumes it, with the single-device policy as the
 degenerate case (no mesh, flat [B] batches, plain jit: byte-identical
 to the historical TpuEngine fast path).
-
-jax compat: this tree pins jax 0.4.x, where `shard_map` lives at
-`jax.experimental.shard_map.shard_map` with the replication check
-spelled `check_rep`; jax >= 0.5 promotes it to `jax.shard_map` with
-`check_vma`. `shard_map_compat` papers over both so the sharded paths
-run (and are TESTED, on simulated host devices) on either — the
-version skew that kept the mesh suite in the failure set since seed.
 """
 
 from __future__ import annotations
@@ -30,22 +23,6 @@ import numpy as np
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check=True):
-    """jax.shard_map across the 0.4/0.5 API rename (see module
-    docstring). `check` maps to check_vma (new) / check_rep (old)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check,
-    )
 
 
 @dataclass(frozen=True)

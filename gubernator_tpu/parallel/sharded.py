@@ -63,7 +63,7 @@ from gubernator_tpu.core.kernels import (
     upsert_windows_jit,
 )
 from gubernator_tpu.core.store import Store, StoreConfig, mix64, new_store
-from gubernator_tpu.parallel.policy import ShardingPolicy, shard_map_compat
+from gubernator_tpu.parallel.policy import ShardingPolicy
 
 # wall-clock reads go through the api.types MODULE attribute: the test
 # suites pin the serving clock by patching millisecond_now there (and on
@@ -988,14 +988,14 @@ class PartitionedEngine:
             else _local_decide
         )
         self._step = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 step_fn,
                 mesh=self.mesh,
                 in_specs=(Ps, Ps, Ps, P0),
                 out_specs=(Ps, P0 if span else Ps),
                 # the all_gather output IS replicated, but the static
                 # varying-axis check can't prove it — disable just there
-                check=not span,
+                check_vma=not span,
             ),
             donate_argnums=(0,),
         )
@@ -1008,7 +1008,7 @@ class PartitionedEngine:
         self._step_chain = None
         if not span:
             self._step_chain = jax.jit(
-                shard_map_compat(
+                jax.shard_map(
                     _local_decide_chain,
                     mesh=self.mesh,
                     in_specs=(Ps, Ps, Ps, Ps, P0),
@@ -1026,12 +1026,12 @@ class PartitionedEngine:
                 else _local_decide_sketch
             )
             self._step_sketch = jax.jit(
-                shard_map_compat(
+                jax.shard_map(
                     sketch_step_fn,
                     mesh=self.mesh,
                     in_specs=(Ps, Ps, Ps, Ps, P0),
                     out_specs=(Ps, Ps, P0 if span else Ps),
-                    check=not span,
+                    check_vma=not span,
                 ),
                 donate_argnums=(0, 1),
             )
@@ -1044,31 +1044,31 @@ class PartitionedEngine:
         self._sketch_min_coll = None
         if span:
             self._rows_coll = jax.jit(
-                shard_map_compat(
+                jax.shard_map(
                     functools.partial(_shard_rows, axes=self.axes),
                     mesh=self.mesh,
                     in_specs=(Ps, P0, P0),
                     out_specs=P0,
-                    check=False,
+                    check_vma=False,
                 )
             )
             if self.sketch_config is not None:
                 self._sketch_min_coll = jax.jit(
-                    shard_map_compat(
+                    jax.shard_map(
                         functools.partial(
                             _shard_sketch_min, axes=self.axes
                         ),
                         mesh=self.mesh,
                         in_specs=(Ps, P0, P0),
                         out_specs=P0,
-                        check=False,
+                        check_vma=False,
                     )
                 )
         sync_fn = functools.partial(
             _shard_sync_globals, n_shards=self.n, axes=self.axes
         )
         self._sync = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 sync_fn,
                 mesh=self.mesh,
                 in_specs=(Ps,) + (P0,) * 7,
@@ -1080,7 +1080,7 @@ class PartitionedEngine:
             _shard_upsert, n_shards=self.n, axes=self.axes
         )
         self._upsert = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 upsert_fn,
                 mesh=self.mesh,
                 in_specs=(Ps,) + (P0,) * 6,
@@ -1092,7 +1092,7 @@ class PartitionedEngine:
             _shard_upsert_full, n_shards=self.n, axes=self.axes
         )
         self._upsert_full = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 upsert_full_fn,
                 mesh=self.mesh,
                 in_specs=(Ps,) + (P0,) * 8,
@@ -1101,29 +1101,55 @@ class PartitionedEngine:
             donate_argnums=(0,),
         )
 
-    def _replicate(self, x):
-        """Stack a per-shard leaf to [n_shards, ...] laid over the
-        policy's store sharding."""
-        stacked = jnp.broadcast_to(x[None], (self.n,) + x.shape)
-        return jax.device_put(stacked, self.store_sharding)
-
-    def _fresh_store(self) -> Store:
-        base = new_store(self.config)
+    def _fresh(self, make):
+        """One tier's state from `make()` (a zero-argument builder of
+        ONE shard's pytree). Flat: on the policy's device. Mesh: the
+        [n_shards, ...] stack is built by a jitted broadcast whose
+        output already carries the store sharding, so each chip
+        allocates its own shard and nothing else — stacking first and
+        `device_put`-ing after would materialise all n shards on
+        device 0 on the way."""
         if self.flat:
+            base = make()
             if self.device is not None:
                 base = jax.device_put(base, self.device)
             return base
-        return jax.tree.map(self._replicate, base)
+        n = self.n
+
+        def stacked():
+            return jax.tree.map(
+                lambda x: jnp.broadcast_to(x[None], (n,) + x.shape),
+                make(),
+            )
+
+        return jax.jit(stacked, out_shardings=self.store_sharding)()
+
+    def _fresh_store(self) -> Store:
+        return self._fresh(functools.partial(new_store, self.config))
 
     def _fresh_sketch(self):
         from gubernator_tpu.core.sketches import new_sketch
 
-        sk = new_sketch(self.sketch_config)
-        if self.flat:
-            if self.device is not None:
-                sk = jax.device_put(sk, self.device)
-            return sk
-        return jax.tree.map(self._replicate, sk)
+        return self._fresh(
+            functools.partial(new_sketch, self.sketch_config)
+        )
+
+    def state_bytes_by_device(self) -> dict:
+        """{device id: bytes} of store + sketch resident on each device
+        — where the state lives, from the arrays' own shardings (the
+        boot log and /v1/debug/stages report it; on a mesh every device
+        must hold 1/n_shards of the total). Reads shapes and shardings
+        only, never a buffer: safe from any thread while the submit
+        thread donates the store."""
+        out: dict = {}
+        for leaf in jax.tree.leaves((self.store, self.sketch)):
+            sh = leaf.sharding
+            nbytes = leaf.dtype.itemsize * int(
+                np.prod(sh.shard_shape(leaf.shape))
+            )
+            for d in sh.addressable_devices:
+                out[d.id] = out.get(d.id, 0) + nbytes
+        return out
 
     def reset(self) -> None:
         self.store = self._fresh_store()
@@ -2252,8 +2278,9 @@ class PartitionedEngine:
 
     def warmup(self, now: Optional[int] = None) -> None:
         """Pre-compile every (batch rung, group rung) program plus the
-        GLOBAL install/sync programs (first TPU jit is ~20-40s; none of
-        it may land inside a serving RPC deadline), then reset the
+        GLOBAL install/sync programs (one decide program is one to four
+        minutes of TPU compile on a cold cache, CHANGES.md PR 21; none
+        of it may land inside a serving RPC deadline), then reset the
         state the warmup traffic dirtied. Mesh policies additionally
         walk the per-shard sub-rung ladder with batches crafted so
         every shard hits every rung. NOTE: this drives the engine's own

@@ -444,9 +444,9 @@ class ServerConfig:
     # traces held in memory, served at /v1/debug/traces).
     trace_buffer: int = 256
     # in-flight device batches the batcher keeps before stalling submits.
-    # 2 suffices co-located (PCIe fetch ~0.1ms); raise toward ~16 when
-    # the accelerator sits behind a high-latency link (fetches pipeline,
-    # so served throughput ~= depth/RTT batches/s instead of 1/RTT).
+    # 2 suits the co-located chip (PCIe fetch ~0.1ms): one batch in
+    # fetch, one in submit. Fetches pipeline, so served throughput is
+    # ~depth/RTT batches/s while the fetch RTT exceeds the batch time.
     # None = resolve GUBER_FETCH_DEPTH in the batcher (default 2).
     device_fetch_depth: Optional[int] = None
 

@@ -209,9 +209,9 @@ def main() -> int:
 
     import jax
 
-    jax.config.update(
-        "jax_compilation_cache_dir", str(ROOT / ".jax_cache")
-    )
+    from gubernator_tpu.jaxenv import enable_compile_cache
+
+    enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     from gubernator_tpu.cluster import LocalCluster

@@ -15,7 +15,7 @@ cell reporting decisions/s plus the mean unique-key count per batch.
 Reading the result: if throughput tracks store size at fixed keys, the
 floor is memory; if it tracks key count at fixed store size, it's the
 group structure. (r5 finding: it is overwhelmingly the unique-group
-count — see BENCH_ZIPF10M_PROFILE_r5.json and docs/round5.md.)
+count — see BENCH_ZIPF10M_PROFILE_r5.json.)
 
 Run on the real chip: python scripts/profile_zipf10m.py
 """
