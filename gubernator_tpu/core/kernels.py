@@ -184,6 +184,7 @@ class Sketch(NamedTuple):
     data: jax.Array  # int32 or int64 [rows, width]
 
 
+@jax.named_scope("sketch_lookup")
 def _sketch_lookup(sketch: Sketch, kh: jax.Array, wid: jax.Array):
     """Per-group (min-estimate int64[G], per-row index list int32[G])
     for window-keyed key hashes. The estimate is widened to int64
@@ -306,6 +307,7 @@ def _use_sweep_writeback(buckets: int, W: int, B: int) -> bool:
     )
 
 
+@jax.named_scope("writeback_plan")
 def _writeback_plan(
     cand: jax.Array,  # int32[B, ways, LANES] pre-write bucket contents
     bkt: jax.Array,  # int32[B] bucket per item, sorted non-decreasing
@@ -648,181 +650,182 @@ def _decide_presorted(
     end_pos_G = groups.end_pos
 
     # ---- group-level state: gathers and lookup at [G] ---------------------
-    kh_G = groups.key_hash
-    bkt = bucket_index(kh_G, buckets)  # [G] non-decreasing
-    fp = fingerprints(kh_G)
+    with jax.named_scope("probe_gather"):
+        kh_G = groups.key_hash
+        bkt = bucket_index(kh_G, buckets)  # [G] non-decreasing
+        fp = fingerprints(kh_G)
 
-    # bucket lookup: ONE sorted gather of whole bucket rows, one row per
-    # GROUP (duplicate keys share the read)
-    cand = jnp.take(
-        store.data, bkt, axis=0, indices_are_sorted=True
-    ).reshape(G, ways, LANES)
+        # bucket lookup: ONE sorted gather of whole bucket rows, one row per
+        # GROUP (duplicate keys share the read)
+        cand = jnp.take(
+            store.data, bkt, axis=0, indices_are_sorted=True
+        ).reshape(G, ways, LANES)
 
-    match = cand[:, :, L_TAG] == fp[:, None]  # [G, ways]
-    found = match.any(axis=1)
-    fway = jnp.argmax(match, axis=1).astype(jnp.int32)  # first matching way
+        match = cand[:, :, L_TAG] == fp[:, None]  # [G, ways]
+        found = match.any(axis=1)
+        fway = jnp.argmax(match, axis=1).astype(jnp.int32)  # first matching way
 
-    # eviction candidate among the ways: empty first, else earliest expiry
-    # (the rate-limit analogue of LRU-oldest, see store.py docstring)
-    evict_key = jnp.where(
-        cand[:, :, L_TAG] == 0, _I32_MIN, cand[:, :, L_EXPIRE]
-    )
-    eway = jnp.argmin(evict_key, axis=1).astype(jnp.int32)
+        # eviction candidate among the ways: empty first, else earliest expiry
+        # (the rate-limit analogue of LRU-oldest, see store.py docstring)
+        evict_key = jnp.where(
+            cand[:, :, L_TAG] == 0, _I32_MIN, cand[:, :, L_EXPIRE]
+        )
+        eway = jnp.argmin(evict_key, axis=1).astype(jnp.int32)
 
-    # found-way state selection by vector selects (ways is tiny and static)
-    sel = cand[:, 0]
-    for w in range(1, ways):
-        sel = jnp.where((fway == w)[:, None], cand[:, w], sel)
+        # found-way state selection by vector selects (ways is tiny and static)
+        sel = cand[:, 0]
+        for w in range(1, ways):
+            sel = jnp.where((fway == w)[:, None], cand[:, w], sel)
 
-    g_exp = sel[:, L_EXPIRE]
-    g_rem = sel[:, L_REMAINING]
-    g_ts = sel[:, L_TS]
-    g_limS = sel[:, L_LIMIT]
-    g_durS = sel[:, L_DURATION]
-    g_flg = sel[:, L_FLAGS]
+        g_exp = sel[:, L_EXPIRE]
+        g_rem = sel[:, L_REMAINING]
+        g_ts = sel[:, L_TS]
+        g_limS = sel[:, L_LIMIT]
+        g_durS = sel[:, L_DURATION]
+        g_flg = sel[:, L_FLAGS]
 
-    g_live = found & (g_exp >= now)  # lazy expiry (reference cache/lru.go:109)
+        g_live = found & (g_exp >= now)  # lazy expiry (reference cache/lru.go:109)
 
-    # leader's request fields define the group's semantics (group-leader
-    # rule for mixed duplicates, see module docstring)
-    lead_req = jnp.take(
-        jnp.stack([algo, h, lim_q, req.duration], axis=-1),
-        lead_clip,
-        axis=0,
-        indices_are_sorted=True,
-    )
-    g_algo = jnp.clip(lead_req[:, 0], 0, 3)
-    g_hits = lead_req[:, 1]
-    g_limQ = lead_req[:, 2]
-    g_durQ = lead_req[:, 3]
+        # leader's request fields define the group's semantics (group-leader
+        # rule for mixed duplicates, see module docstring)
+        lead_req = jnp.take(
+            jnp.stack([algo, h, lim_q, req.duration], axis=-1),
+            lead_clip,
+            axis=0,
+            indices_are_sorted=True,
+        )
+        g_algo = jnp.clip(lead_req[:, 0], 0, 3)
+        g_hits = lead_req[:, 1]
+        g_limQ = lead_req[:, 2]
+        g_durQ = lead_req[:, 3]
 
-    # stored algorithm from the entry's flag bits (core/algorithms.py:
-    # token is the all-zero encoding, so pre-r15 entries decode as 0)
-    stored_leaky = (g_flg & FLAG_ALGO_LEAKY) != 0
-    stored_sld = (g_flg & FLAG_ALGO_SLIDING) != 0
-    stored_gcra = (g_flg & FLAG_ALGO_GCRA) != 0
-    stored_algo = (
-        stored_leaky * 1 + stored_sld * 2 + stored_gcra * 3
-    ).astype(jnp.int32)
-    req_leaky = g_algo == 1
-    # Algorithm switch recreates the window. The token/leaky pair
-    # recreates as a fresh *token* bucket in both directions (reference
-    # algorithms.go:33-38,100-105, kept verbatim); sliding/GCRA
-    # requests recreate as their OWN algorithm (core/algorithms.py).
-    mismatch = g_live & (stored_algo != g_algo)
-    existing = g_live & ~mismatch
-    create_algo = jnp.where(mismatch & req_leaky, 0, g_algo)
-    eff_algo = jnp.where(existing, stored_algo, create_algo)
-    eff_leaky = eff_algo == 1
-    eff_sld = eff_algo == 2
-    eff_gcra = eff_algo == 3
+        # stored algorithm from the entry's flag bits (core/algorithms.py:
+        # token is the all-zero encoding, so pre-r15 entries decode as 0)
+        stored_leaky = (g_flg & FLAG_ALGO_LEAKY) != 0
+        stored_sld = (g_flg & FLAG_ALGO_SLIDING) != 0
+        stored_gcra = (g_flg & FLAG_ALGO_GCRA) != 0
+        stored_algo = (
+            stored_leaky * 1 + stored_sld * 2 + stored_gcra * 3
+        ).astype(jnp.int32)
+        req_leaky = g_algo == 1
+        # Algorithm switch recreates the window. The token/leaky pair
+        # recreates as a fresh *token* bucket in both directions (reference
+        # algorithms.go:33-38,100-105, kept verbatim); sliding/GCRA
+        # requests recreate as their OWN algorithm (core/algorithms.py).
+        mismatch = g_live & (stored_algo != g_algo)
+        existing = g_live & ~mismatch
+        create_algo = jnp.where(mismatch & req_leaky, 0, g_algo)
+        eff_algo = jnp.where(existing, stored_algo, create_algo)
+        eff_leaky = eff_algo == 1
+        eff_sld = eff_algo == 2
+        eff_gcra = eff_algo == 3
 
-    # leaky guard (documented divergence: reference div-by-zero,
-    # algorithms.go:107): existing leaky group with request limit <= 0
-    leaky_zero = existing & eff_leaky & (g_limQ <= 0)
+        # leaky guard (documented divergence: reference div-by-zero,
+        # algorithms.go:107): existing leaky group with request limit <= 0
+        leaky_zero = existing & eff_leaky & (g_limQ <= 0)
 
-    # effective duration: stored for existing entries, request's for groups
-    # being (re)created in this batch
-    g_durE = jnp.where(g_live, g_durS, g_durQ)
-    rate = jnp.maximum(g_durE // jnp.maximum(g_limQ, 1), 1)
-    leak = jnp.maximum(now - g_ts, 0) // rate
-    # overflow-free min(g_rem + leak, g_limS): stored remaining <= limit
-    leaky_R0 = g_rem + jnp.minimum(leak, jnp.maximum(g_limS - g_rem, 0))
+        # effective duration: stored for existing entries, request's for groups
+        # being (re)created in this batch
+        g_durE = jnp.where(g_live, g_durS, g_durQ)
+        rate = jnp.maximum(g_durE // jnp.maximum(g_limQ, 1), 1)
+        leak = jnp.maximum(now - g_ts, 0) // rate
+        # overflow-free min(g_rem + leak, g_limS): stored remaining <= limit
+        leaky_R0 = g_rem + jnp.minimum(leak, jnp.maximum(g_limS - g_rem, 0))
 
-    now64 = now.astype(jnp.int64)
+        now64 = now.astype(jnp.int64)
 
-    # sliding window (r15, core/algorithms.py conventions): rotate the
-    # stored subwindow pair to `now` — the entry's L_REMAINING lane is
-    # the CURRENT subwindow's consumed count, L_TS the PREVIOUS one's,
-    # and the window start reconstructs as expire - 2d. All in int64:
-    # the blend multiply (count * ms) overflows int32 by design. The
-    # EFFECTIVE period caps at SLIDING_MAX_DURATION_MS = 2^29-1 (half
-    # the token envelope: the ws + 2d expire anchor must stay inside
-    # int32 with now <= 2^30) — algorithms.sliding_dur is the host
-    # twin, so the byte-identity holds for any requested duration.
-    _SLD_DMAX = (1 << 29) - 1
-    d_sld = jnp.clip(g_durS.astype(jnp.int64), 1, _SLD_DMAX)
-    sld_ws0 = g_exp.astype(jnp.int64) - 2 * d_sld
-    sld_k = jnp.maximum((now64 - sld_ws0) // d_sld, 0)
-    sld_ws = sld_ws0 + sld_k * d_sld  # current subwindow start
-    sld_cur0 = jnp.where(sld_k == 0, g_rem, 0)
-    sld_prev0 = jnp.where(
-        sld_k == 0, g_ts, jnp.where(sld_k == 1, g_rem, 0)
-    )
-    sld_wrem = d_sld - (now64 - sld_ws)  # in (0, d]
-    sld_used = sld_cur0.astype(jnp.int64) + (
-        sld_prev0.astype(jnp.int64) * sld_wrem
-    ) // d_sld
-    lim_s64 = g_limS.astype(jnp.int64)
-    R0_sld = (
-        jnp.clip(lim_s64 - sld_used, 0, jnp.maximum(lim_s64, 0))
-        .astype(jnp.int32)
-    )
+        # sliding window (r15, core/algorithms.py conventions): rotate the
+        # stored subwindow pair to `now` — the entry's L_REMAINING lane is
+        # the CURRENT subwindow's consumed count, L_TS the PREVIOUS one's,
+        # and the window start reconstructs as expire - 2d. All in int64:
+        # the blend multiply (count * ms) overflows int32 by design. The
+        # EFFECTIVE period caps at SLIDING_MAX_DURATION_MS = 2^29-1 (half
+        # the token envelope: the ws + 2d expire anchor must stay inside
+        # int32 with now <= 2^30) — algorithms.sliding_dur is the host
+        # twin, so the byte-identity holds for any requested duration.
+        _SLD_DMAX = (1 << 29) - 1
+        d_sld = jnp.clip(g_durS.astype(jnp.int64), 1, _SLD_DMAX)
+        sld_ws0 = g_exp.astype(jnp.int64) - 2 * d_sld
+        sld_k = jnp.maximum((now64 - sld_ws0) // d_sld, 0)
+        sld_ws = sld_ws0 + sld_k * d_sld  # current subwindow start
+        sld_cur0 = jnp.where(sld_k == 0, g_rem, 0)
+        sld_prev0 = jnp.where(
+            sld_k == 0, g_ts, jnp.where(sld_k == 1, g_rem, 0)
+        )
+        sld_wrem = d_sld - (now64 - sld_ws)  # in (0, d]
+        sld_used = sld_cur0.astype(jnp.int64) + (
+            sld_prev0.astype(jnp.int64) * sld_wrem
+        ) // d_sld
+        lim_s64 = g_limS.astype(jnp.int64)
+        R0_sld = (
+            jnp.clip(lim_s64 - sld_used, 0, jnp.maximum(lim_s64, 0))
+            .astype(jnp.int32)
+        )
 
-    # GCRA (r15): the stored L_EXPIRE lane IS the theoretical arrival
-    # time; budget = clamp((now + tau - max(TAT, now)) // T, 0, limit)
-    # with T/tau from the STORED params for existing entries (creation
-    # uses the request's params via the generic creation machinery and
-    # the effective-params columns below). int64 throughout: tau =
-    # T*limit can exceed int32 for limit >> duration.
-    T_stored = jnp.maximum(
-        g_durS.astype(jnp.int64)
-        // jnp.maximum(g_limS.astype(jnp.int64), 1),
-        1,
-    )
-    tau_stored = jnp.minimum(
-        T_stored * jnp.maximum(lim_s64, 0), jnp.int64(_I32_MAX)
-    )
-    tat0_stored = jnp.maximum(g_exp.astype(jnp.int64), now64)
-    R0_gcra = jnp.clip(
-        (now64 + tau_stored - tat0_stored) // T_stored,
-        0,
-        jnp.maximum(lim_s64, 0),
-    ).astype(jnp.int32)
+        # GCRA (r15): the stored L_EXPIRE lane IS the theoretical arrival
+        # time; budget = clamp((now + tau - max(TAT, now)) // T, 0, limit)
+        # with T/tau from the STORED params for existing entries (creation
+        # uses the request's params via the generic creation machinery and
+        # the effective-params columns below). int64 throughout: tau =
+        # T*limit can exceed int32 for limit >> duration.
+        T_stored = jnp.maximum(
+            g_durS.astype(jnp.int64)
+            // jnp.maximum(g_limS.astype(jnp.int64), 1),
+            1,
+        )
+        tau_stored = jnp.minimum(
+            T_stored * jnp.maximum(lim_s64, 0), jnp.int64(_I32_MAX)
+        )
+        tat0_stored = jnp.maximum(g_exp.astype(jnp.int64), now64)
+        R0_gcra = jnp.clip(
+            (now64 + tau_stored - tat0_stored) // T_stored,
+            0,
+            jnp.maximum(lim_s64, 0),
+        ).astype(jnp.int32)
 
-    # group budget at batch start
-    R0_exist = jnp.where(eff_leaky, leaky_R0, g_rem)
-    R0_exist = jnp.where(eff_sld, R0_sld, R0_exist)
-    R0_exist = jnp.where(eff_gcra, R0_gcra, R0_exist)
+        # group budget at batch start
+        R0_exist = jnp.where(eff_leaky, leaky_R0, g_rem)
+        R0_exist = jnp.where(eff_sld, R0_sld, R0_exist)
+        R0_exist = jnp.where(eff_gcra, R0_gcra, R0_exist)
 
-    # creation by the group leader (reference algorithms.go:68-84,161-186)
-    over_c = g_hits > g_limQ
-    charged_ldr = ~over_c & (g_hits > 0)
-    R0_create = g_limQ - jnp.where(charged_ldr, g_hits, 0)
-    # token creation with hits > limit stores remaining = limit ("sticky
-    # over", algorithms.go:78-81); leaky stores an empty bucket (:180).
-    # Sliding/GCRA creation refusals store an untouched fresh window
-    # (their status is recomputed every call, nothing to persist).
-    R0_create = jnp.where(over_c & eff_leaky, 0, R0_create)
+        # creation by the group leader (reference algorithms.go:68-84,161-186)
+        over_c = g_hits > g_limQ
+        charged_ldr = ~over_c & (g_hits > 0)
+        R0_create = g_limQ - jnp.where(charged_ldr, g_hits, 0)
+        # token creation with hits > limit stores remaining = limit ("sticky
+        # over", algorithms.go:78-81); leaky stores an empty bucket (:180).
+        # Sliding/GCRA creation refusals store an untouched fresh window
+        # (their status is recomputed every call, nothing to persist).
+        R0_create = jnp.where(over_c & eff_leaky, 0, R0_create)
 
-    R0 = jnp.where(existing, R0_exist, R0_create)
-    # sticky-over is a token-bucket-only mutation; sliding/GCRA
-    # recompute their status from state every call
-    sticky0 = jnp.where(
-        existing,
-        (g_flg & FLAG_STICKY_OVER) != 0,
-        (eff_algo == 0) & over_c,
-    )
+        R0 = jnp.where(existing, R0_exist, R0_create)
+        # sticky-over is a token-bucket-only mutation; sliding/GCRA
+        # recompute their status from state every call
+        sticky0 = jnp.where(
+            existing,
+            (g_flg & FLAG_STICKY_OVER) != 0,
+            (eff_algo == 0) & over_c,
+        )
 
-    # effective GCRA params per group (stored for existing, request's
-    # for creations) — the response resets and the TAT writeback below
-    # share these
-    eff_lim64 = jnp.where(existing, lim_s64, g_limQ.astype(jnp.int64))
-    eff_dur64 = jnp.where(
-        existing, g_durS.astype(jnp.int64), g_durQ.astype(jnp.int64)
-    )
-    gcra_T = jnp.maximum(eff_dur64 // jnp.maximum(eff_lim64, 1), 1)
-    gcra_tau = jnp.minimum(
-        gcra_T * jnp.maximum(eff_lim64, 0), jnp.int64(_I32_MAX)
-    )
-    gcra_tat0 = jnp.where(existing, tat0_stored, now64)
-    # sliding response reset: the current subwindow's end (existing) or
-    # the creation window's end
-    sld_reset_G = jnp.where(
-        existing & eff_sld,
-        jnp.clip(sld_ws + d_sld, _I32_MIN, _I32_MAX),
-        (now + g_durQ).astype(jnp.int64),
-    ).astype(jnp.int32)
+        # effective GCRA params per group (stored for existing, request's
+        # for creations) — the response resets and the TAT writeback below
+        # share these
+        eff_lim64 = jnp.where(existing, lim_s64, g_limQ.astype(jnp.int64))
+        eff_dur64 = jnp.where(
+            existing, g_durS.astype(jnp.int64), g_durQ.astype(jnp.int64)
+        )
+        gcra_T = jnp.maximum(eff_dur64 // jnp.maximum(eff_lim64, 1), 1)
+        gcra_tau = jnp.minimum(
+            gcra_T * jnp.maximum(eff_lim64, 0), jnp.int64(_I32_MAX)
+        )
+        gcra_tat0 = jnp.where(existing, tat0_stored, now64)
+        # sliding response reset: the current subwindow's end (existing) or
+        # the creation window's end
+        sld_reset_G = jnp.where(
+            existing & eff_sld,
+            jnp.clip(sld_ws + d_sld, _I32_MIN, _I32_MAX),
+            (now + g_durQ).astype(jnp.int64),
+        ).astype(jnp.int32)
 
     # ---- writeback plan + sketch cold tier (r13) --------------------------
     # The writer/way/drop plan runs BEFORE response math so the sketch
@@ -1011,266 +1014,269 @@ def _decide_presorted(
         g_durS = jnp.where(sk_g, g_durQ, g_durS)  # request's
 
     # ---- bridge: group values needed per request, one stacked gather ------
-    bridge = jnp.take(
-        jnp.stack(
-            [
-                existing.astype(jnp.int32),
-                eff_leaky.astype(jnp.int32),
-                R0,
-                sticky0.astype(jnp.int32),
-                rate,
-                g_exp,
-                g_rem,
-                g_limS,
-                g_durS,
-                g_limQ,
-                g_durQ,
-                over_c.astype(jnp.int32),
-                leaky_zero.astype(jnp.int32),
-                # existing0, not existing: a sketch-served group is NOT
-                # a token replica — its gnp rows process as owned, the
-                # same contract as an exact-tier miss
-                (existing0 & (stored_algo == 0)).astype(jnp.int32),
-                charged_ldr.astype(jnp.int32),
-                g_hits,
-                eff_algo,
-                sld_reset_G,
-                gcra_T.astype(jnp.int32),  # T <= duration: fits int32
-                gcra_tau.astype(jnp.int32),  # clamped to I32_MAX above
-                gcra_tat0.astype(jnp.int32),  # <= I32_MAX by envelope
-            ],
-            axis=-1,
-        ),
-        groups.group_id,
-        axis=0,
-        indices_are_sorted=True,
-    )
-    existing_r = bridge[:, 0] != 0
-    eff_leaky_r = bridge[:, 1] != 0
-    R0_r = bridge[:, 2]
-    sticky0_r = bridge[:, 3] != 0
-    rate_r = bridge[:, 4]
-    g_exp_r = bridge[:, 5]
-    g_rem_r = bridge[:, 6]
-    g_limS_r = bridge[:, 7]
-    g_durS_r = bridge[:, 8]
-    g_limQ_r = bridge[:, 9]
-    g_durQ_r = bridge[:, 10]
-    over_c_r = bridge[:, 11] != 0
-    leaky_zero_r = bridge[:, 12] != 0
-    tok_replica_r = bridge[:, 13] != 0  # existing & stored token
-    charged_ldr_r = bridge[:, 14] != 0
-    g_hits_r = bridge[:, 15]
-    eff_algo_r = bridge[:, 16]
-    eff_sld_r = eff_algo_r == 2
-    eff_gcra_r = eff_algo_r == 3
-    sld_reset_r = bridge[:, 17]
-    gcra_T_r = bridge[:, 18].astype(jnp.int64)
-    gcra_tau_r = bridge[:, 19].astype(jnp.int64)
-    gcra_tat0_r = bridge[:, 20].astype(jnp.int64)
+    with jax.named_scope("segment_scan_decide"):
+        bridge = jnp.take(
+            jnp.stack(
+                [
+                    existing.astype(jnp.int32),
+                    eff_leaky.astype(jnp.int32),
+                    R0,
+                    sticky0.astype(jnp.int32),
+                    rate,
+                    g_exp,
+                    g_rem,
+                    g_limS,
+                    g_durS,
+                    g_limQ,
+                    g_durQ,
+                    over_c.astype(jnp.int32),
+                    leaky_zero.astype(jnp.int32),
+                    # existing0, not existing: a sketch-served group is NOT
+                    # a token replica — its gnp rows process as owned, the
+                    # same contract as an exact-tier miss
+                    (existing0 & (stored_algo == 0)).astype(jnp.int32),
+                    charged_ldr.astype(jnp.int32),
+                    g_hits,
+                    eff_algo,
+                    sld_reset_G,
+                    gcra_T.astype(jnp.int32),  # T <= duration: fits int32
+                    gcra_tau.astype(jnp.int32),  # clamped to I32_MAX above
+                    gcra_tat0.astype(jnp.int32),  # <= I32_MAX by envelope
+                ],
+                axis=-1,
+            ),
+            groups.group_id,
+            axis=0,
+            indices_are_sorted=True,
+        )
+        existing_r = bridge[:, 0] != 0
+        eff_leaky_r = bridge[:, 1] != 0
+        R0_r = bridge[:, 2]
+        sticky0_r = bridge[:, 3] != 0
+        rate_r = bridge[:, 4]
+        g_exp_r = bridge[:, 5]
+        g_rem_r = bridge[:, 6]
+        g_limS_r = bridge[:, 7]
+        g_durS_r = bridge[:, 8]
+        g_limQ_r = bridge[:, 9]
+        g_durQ_r = bridge[:, 10]
+        over_c_r = bridge[:, 11] != 0
+        leaky_zero_r = bridge[:, 12] != 0
+        tok_replica_r = bridge[:, 13] != 0  # existing & stored token
+        charged_ldr_r = bridge[:, 14] != 0
+        g_hits_r = bridge[:, 15]
+        eff_algo_r = bridge[:, 16]
+        eff_sld_r = eff_algo_r == 2
+        eff_gcra_r = eff_algo_r == 3
+        sld_reset_r = bridge[:, 17]
+        gcra_T_r = bridge[:, 18].astype(jnp.int64)
+        gcra_tau_r = bridge[:, 19].astype(jnp.int64)
+        gcra_tat0_r = bridge[:, 20].astype(jnp.int64)
 
-    # GLOBAL non-owner replica read: answer straight from the live entry,
-    # no mutation (reference gubernator.go:178-187). On a miss the request
-    # is processed as if owned (gubernator.go:189-194).
-    gnp_served = gnp & tok_replica_r
+        # GLOBAL non-owner replica read: answer straight from the live entry,
+        # no mutation (reference gubernator.go:178-187). On a miss the request
+        # is processed as if owned (gubernator.go:189-194).
+        gnp_served = gnp & tok_replica_r
 
-    is_creation_leader = is_leader & ~existing_r
+        is_creation_leader = is_leader & ~existing_r
 
-    # ---- cumulative-attempt prefix within groups --------------------------
-    viable = valid & ~gnp_served & ~leaky_zero_r
-    eligible = viable & (h > 0) & (h <= R0_r)
-    inc = jnp.where(eligible & ~is_creation_leader, h, 0)
-    incl1 = _seg_scan(
-        is_leader,
-        jnp.stack([inc, (viable & (h != 0)).astype(jnp.int32)], axis=-1),
-    )
-    prefix1 = jnp.where(same_prev[:, None], _shift1(incl1, 0), 0)
-    S = prefix1[:, 0]
+        # ---- cumulative-attempt prefix within groups ----
+        viable = valid & ~gnp_served & ~leaky_zero_r
+        eligible = viable & (h > 0) & (h <= R0_r)
+        inc = jnp.where(eligible & ~is_creation_leader, h, 0)
+        incl1 = _seg_scan(
+            is_leader,
+            jnp.stack([inc, (viable & (h != 0)).astype(jnp.int32)], axis=-1),
+        )
+        prefix1 = jnp.where(same_prev[:, None], _shift1(incl1, 0), 0)
+        S = prefix1[:, 0]
 
-    # admission: S + h <= R0, written subtraction-side to stay in int32
-    # (eligible already guarantees h <= R0)
-    charged = eligible & ~is_creation_leader & (S <= R0_r - h)
-    charged = charged | (is_creation_leader & charged_ldr_r)
-    # Attempt-inflated budget: used ONLY for the decr predicate below.
-    # For CHARGED positions S == the charged-only prefix (once an
-    # equal-or-smaller attempt is refused every later one is too), so
-    # decr is unaffected by the inflation; REPORTED remaining must use
-    # the charged-only prefix instead (rem_vis) or refused duplicates
-    # would see phantom consumption (sequential-greedy reports the true
-    # leftover to refused requests).
-    rem_b = jnp.maximum(R0_r - S, 0)
+        # admission: S + h <= R0, written subtraction-side to stay in int32
+        # (eligible already guarantees h <= R0)
+        charged = eligible & ~is_creation_leader & (S <= R0_r - h)
+        charged = charged | (is_creation_leader & charged_ldr_r)
+        # Attempt-inflated budget: used ONLY for the decr predicate below.
+        # For CHARGED positions S == the charged-only prefix (once an
+        # equal-or-smaller attempt is refused every later one is too), so
+        # decr is unaffected by the inflation; REPORTED remaining must use
+        # the charged-only prefix instead (rem_vis) or refused duplicates
+        # would see phantom consumption (sequential-greedy reports the true
+        # leftover to refused requests).
+        rem_b = jnp.maximum(R0_r - S, 0)
 
-    # Real (charged-only) depletion prefix: refused duplicates inflate S but
-    # consume nothing, so persistence decisions must not use S.
-    inc_chg = jnp.where(charged & ~is_creation_leader, h, 0)
-    # sticky status observed by j: a request that arrives when remaining is
-    # actually 0 flips the cached token status to OVER_LIMIT persistently
-    # (algorithms.go:41-44); leaky expiry refreshes only on a strict-
-    # decrement charge (oracle divergence-1 rule; algorithms.go:157)
-    decr = charged & ~is_creation_leader & (rem_b - h > 0)
-    incl2 = _seg_scan(
-        is_leader, jnp.stack([inc_chg, decr.astype(jnp.int32)], axis=-1)
-    )
-    prefix2 = jnp.where(same_prev[:, None], _shift1(incl2, 0), 0)
-    S_chg = prefix2[:, 0]
-    rem_vis = jnp.maximum(R0_r - S_chg, 0)  # true budget visible to j
+        # Real (charged-only) depletion prefix: refused duplicates inflate S but
+        # consume nothing, so persistence decisions must not use S.
+        inc_chg = jnp.where(charged & ~is_creation_leader, h, 0)
+        # sticky status observed by j: a request that arrives when remaining is
+        # actually 0 flips the cached token status to OVER_LIMIT persistently
+        # (algorithms.go:41-44); leaky expiry refreshes only on a strict-
+        # decrement charge (oracle divergence-1 rule; algorithms.go:157)
+        decr = charged & ~is_creation_leader & (rem_b - h > 0)
+        incl2 = _seg_scan(
+            is_leader, jnp.stack([inc_chg, decr.astype(jnp.int32)], axis=-1)
+        )
+        prefix2 = jnp.where(same_prev[:, None], _shift1(incl2, 0), 0)
+        S_chg = prefix2[:, 0]
+        rem_vis = jnp.maximum(R0_r - S_chg, 0)  # true budget visible to j
 
-    # token-only sticky flip: sliding/GCRA statuses are recomputed from
-    # state every call, like leaky (r15)
-    z = (
-        viable & (eff_algo_r == 0) & (R0_r - S_chg == 0)
-        & ~is_creation_leader
-    )
-    c3 = jnp.cumsum(z.astype(jnp.int32))
-    sticky_live = sticky0_r | (same_prev & _shift1(z, False))
+        # token-only sticky flip: sliding/GCRA statuses are recomputed from
+        # state every call, like leaky (r15)
+        z = (
+            viable & (eff_algo_r == 0) & (R0_r - S_chg == 0)
+            & ~is_creation_leader
+        )
+        c3 = jnp.cumsum(z.astype(jnp.int32))
+        sticky_live = sticky0_r | (same_prev & _shift1(z, False))
 
-    # ONE fused gather at the group end positions pulls every group
-    # total the writeback needs (narrow device gathers carry a large
-    # fixed cost; batching columns is nearly free)
-    ends = jnp.take(
-        jnp.concatenate([incl1, incl2, c3[:, None]], axis=1),
-        end_pos_G,
-        axis=0,
-        indices_are_sorted=True,
-    )  # [G, 5]
-    any_hits = ends[:, 1] > 0  # [G]
-    total_charged = ends[:, 2]  # [G]
-    any_decr = ends[:, 3] > 0  # [G]
-    z_lead = jnp.take(
-        jnp.stack([c3, z.astype(jnp.int32)], axis=-1),
-        lead_clip,
-        axis=0,
-        indices_are_sorted=True,
-    )  # [G, 2]
-    any_z = (ends[:, 4] - (z_lead[:, 0] - z_lead[:, 1])) > 0  # [G]
+        # ONE fused gather at the group end positions pulls every group
+        # total the writeback needs (narrow device gathers carry a large
+        # fixed cost; batching columns is nearly free)
+        ends = jnp.take(
+            jnp.concatenate([incl1, incl2, c3[:, None]], axis=1),
+            end_pos_G,
+            axis=0,
+            indices_are_sorted=True,
+        )  # [G, 5]
+        any_hits = ends[:, 1] > 0  # [G]
+        total_charged = ends[:, 2]  # [G]
+        any_decr = ends[:, 3] > 0  # [G]
+        z_lead = jnp.take(
+            jnp.stack([c3, z.astype(jnp.int32)], axis=-1),
+            lead_clip,
+            axis=0,
+            indices_are_sorted=True,
+        )  # [G, 2]
+        any_z = (ends[:, 4] - (z_lead[:, 0] - z_lead[:, 1])) > 0  # [G]
 
     # ---- sketch conservative update at [G] --------------------------------
-    new_sketch = sketch
-    if sketch is not None:
-        # write max(counter, estimate + charged) into each row: only
-        # the counters that DEFINE the estimate grow (Count-Less-family
-        # discipline), so cross-key collision inflation is never
-        # compounded. Non-sketch and padding groups write 0, a no-op
-        # against non-negative counters. One narrow scatter-max per row.
-        # Writes saturate at the counter dtype's max (v2 int32): a
-        # key's OWN update chain never saturates — charged <= budget
-        # <= limit - min(est, limit), so est + charged <= limit <=
-        # I32_MAX whenever charged > 0 — and a fold that saturates
-        # pins the counter at max, which only ever REFUSES (fail-
-        # closed, never an under-count of a served key).
-        upd = jnp.where(
-            sk_g, sk_est + total_charged.astype(jnp.int64), jnp.int64(0)
-        )
-        data_sk = sketch.data
-        cmax = jnp.int64(jnp.iinfo(data_sk.dtype).max)
-        upd_w = jnp.minimum(upd, cmax).astype(data_sk.dtype)
-        for r in range(len(sk_idx)):
-            data_sk = data_sk.at[r, sk_idx[r]].max(upd_w)
-        # eviction->sketch migration (computed above with the victim
-        # plan): fold recycled dead victims' consumed counts into
-        # their keys' current windows — scatter-max like the request
-        # update, so ordering between the two is immaterial. A key
-        # both folded and sketch-decided in this same batch reads its
-        # estimate from before the fold (one-batch lag, conservative
-        # thereafter).
-        v_upd_w = jnp.minimum(v_upd, cmax).astype(data_sk.dtype)
-        for r in range(len(v_idx)):
-            data_sk = data_sk.at[r, v_idx[r]].max(v_upd_w)
-        new_sketch = Sketch(data=data_sk)
+    with jax.named_scope("sketch_update"):
+        new_sketch = sketch
+        if sketch is not None:
+            # write max(counter, estimate + charged) into each row: only
+            # the counters that DEFINE the estimate grow (Count-Less-family
+            # discipline), so cross-key collision inflation is never
+            # compounded. Non-sketch and padding groups write 0, a no-op
+            # against non-negative counters. One narrow scatter-max per row.
+            # Writes saturate at the counter dtype's max (v2 int32): a
+            # key's OWN update chain never saturates — charged <= budget
+            # <= limit - min(est, limit), so est + charged <= limit <=
+            # I32_MAX whenever charged > 0 — and a fold that saturates
+            # pins the counter at max, which only ever REFUSES (fail-
+            # closed, never an under-count of a served key).
+            upd = jnp.where(
+                sk_g, sk_est + total_charged.astype(jnp.int64), jnp.int64(0)
+            )
+            data_sk = sketch.data
+            cmax = jnp.int64(jnp.iinfo(data_sk.dtype).max)
+            upd_w = jnp.minimum(upd, cmax).astype(data_sk.dtype)
+            for r in range(len(sk_idx)):
+                data_sk = data_sk.at[r, sk_idx[r]].max(upd_w)
+            # eviction->sketch migration (computed above with the victim
+            # plan): fold recycled dead victims' consumed counts into
+            # their keys' current windows — scatter-max like the request
+            # update, so ordering between the two is immaterial. A key
+            # both folded and sketch-decided in this same batch reads its
+            # estimate from before the fold (one-batch lag, conservative
+            # thereafter).
+            v_upd_w = jnp.minimum(v_upd, cmax).astype(data_sk.dtype)
+            for r in range(len(v_idx)):
+                data_sk = data_sk.at[r, v_idx[r]].max(v_upd_w)
+            new_sketch = Sketch(data=data_sk)
 
     # ---- responses --------------------------------------------------------
-    st_cached = jnp.where(sticky_live, OVER, UNDER)
+    with jax.named_scope("responses"):
+        st_cached = jnp.where(sticky_live, OVER, UNDER)
 
-    # token, existing-style position (incl. followers of a creation)
-    tok_status = jnp.where(
-        rem_vis == 0,
-        OVER,
-        jnp.where(charged | (h == 0), st_cached, OVER),
-    )
-    tok_remaining = jnp.where(
-        rem_vis == 0, 0, jnp.where(charged, rem_vis - h, rem_vis)
-    )
-    g_expire_new_r = jnp.where(existing_r, g_exp_r, now + g_durQ_r)
-    tok_reset = g_expire_new_r
+        # token, existing-style position (incl. followers of a creation)
+        tok_status = jnp.where(
+            rem_vis == 0,
+            OVER,
+            jnp.where(charged | (h == 0), st_cached, OVER),
+        )
+        tok_remaining = jnp.where(
+            rem_vis == 0, 0, jnp.where(charged, rem_vis - h, rem_vis)
+        )
+        g_expire_new_r = jnp.where(existing_r, g_exp_r, now + g_durQ_r)
+        tok_reset = g_expire_new_r
 
-    # leaky, existing-style position: status is computed fresh each call and
-    # reset_time only appears on OVER paths (algorithms.go:123-160)
-    lk_over = (rem_vis == 0) | (~charged & (h != 0))
-    lk_status = jnp.where(lk_over, OVER, UNDER)
-    lk_remaining = jnp.where(
-        rem_vis == 0, 0, jnp.where(charged, rem_vis - h, rem_vis)
-    )
-    lk_reset = jnp.where(lk_over, now + rate_r, 0)
+        # leaky, existing-style position: status is computed fresh each call and
+        # reset_time only appears on OVER paths (algorithms.go:123-160)
+        lk_over = (rem_vis == 0) | (~charged & (h != 0))
+        lk_status = jnp.where(lk_over, OVER, UNDER)
+        lk_remaining = jnp.where(
+            rem_vis == 0, 0, jnp.where(charged, rem_vis - h, rem_vis)
+        )
+        lk_reset = jnp.where(lk_over, now + rate_r, 0)
 
-    g_lim_resp = jnp.where(existing_r, g_limS_r, g_limQ_r)
-    status = jnp.where(eff_leaky_r, lk_status, tok_status)
-    remaining = jnp.where(eff_leaky_r, lk_remaining, tok_remaining)
-    reset = jnp.where(eff_leaky_r, lk_reset, tok_reset)
+        g_lim_resp = jnp.where(existing_r, g_limS_r, g_limQ_r)
+        status = jnp.where(eff_leaky_r, lk_status, tok_status)
+        remaining = jnp.where(eff_leaky_r, lk_remaining, tok_remaining)
+        reset = jnp.where(eff_leaky_r, lk_reset, tok_reset)
 
-    # sliding / GCRA, existing-style position (r15): no persisted
-    # status — OVER iff the visible budget is gone or this hit-carrying
-    # request was refused (the leaky status shape, minus its quirks)
-    sg = eff_sld_r | eff_gcra_r
-    sg_over = (rem_vis == 0) | (~charged & (h != 0))
-    sg_status = jnp.where(sg_over, OVER, UNDER)
-    sg_remaining = jnp.where(
-        rem_vis == 0, 0, jnp.where(charged, rem_vis - h, rem_vis)
-    )
-    # GCRA per-row reset: the row's own theoretical arrival time after
-    # every charge earlier in its group (S_eff adds a creation leader's
-    # charge for follower rows) plus its own n*T; a refused hit-
-    # carrying row instead reports the earliest instant the same
-    # request could succeed (TAT + n*T - tau). Matches sequential
-    # application of core/oracle.gcra by construction.
-    S_eff = S_chg + jnp.where(
-        ~existing_r & charged_ldr_r & ~is_creation_leader, g_hits_r, 0
-    )
-    tat_row = gcra_tat0_r + S_eff.astype(jnp.int64) * gcra_T_r
-    g_reset64 = (
-        tat_row
-        + h.astype(jnp.int64) * gcra_T_r
-        - jnp.where(sg_over & (h != 0), gcra_tau_r, 0)
-    )
-    gcra_reset_r = jnp.clip(g_reset64, _I32_MIN, _I32_MAX).astype(
-        jnp.int32
-    )
-    status = jnp.where(sg, sg_status, status)
-    remaining = jnp.where(sg, sg_remaining, remaining)
-    reset = jnp.where(eff_sld_r, sld_reset_r, reset)
-    reset = jnp.where(eff_gcra_r, gcra_reset_r, reset)
+        # sliding / GCRA, existing-style position (r15): no persisted
+        # status — OVER iff the visible budget is gone or this hit-carrying
+        # request was refused (the leaky status shape, minus its quirks)
+        sg = eff_sld_r | eff_gcra_r
+        sg_over = (rem_vis == 0) | (~charged & (h != 0))
+        sg_status = jnp.where(sg_over, OVER, UNDER)
+        sg_remaining = jnp.where(
+            rem_vis == 0, 0, jnp.where(charged, rem_vis - h, rem_vis)
+        )
+        # GCRA per-row reset: the row's own theoretical arrival time after
+        # every charge earlier in its group (S_eff adds a creation leader's
+        # charge for follower rows) plus its own n*T; a refused hit-
+        # carrying row instead reports the earliest instant the same
+        # request could succeed (TAT + n*T - tau). Matches sequential
+        # application of core/oracle.gcra by construction.
+        S_eff = S_chg + jnp.where(
+            ~existing_r & charged_ldr_r & ~is_creation_leader, g_hits_r, 0
+        )
+        tat_row = gcra_tat0_r + S_eff.astype(jnp.int64) * gcra_T_r
+        g_reset64 = (
+            tat_row
+            + h.astype(jnp.int64) * gcra_T_r
+            - jnp.where(sg_over & (h != 0), gcra_tau_r, 0)
+        )
+        gcra_reset_r = jnp.clip(g_reset64, _I32_MIN, _I32_MAX).astype(
+            jnp.int32
+        )
+        status = jnp.where(sg, sg_status, status)
+        remaining = jnp.where(sg, sg_remaining, remaining)
+        reset = jnp.where(eff_sld_r, sld_reset_r, reset)
+        reset = jnp.where(eff_gcra_r, gcra_reset_r, reset)
 
-    # creation leader overrides (the branchy creation responses)
-    cl_status = jnp.where(over_c_r, OVER, UNDER)
-    cl_remaining = jnp.where(
-        over_c_r, jnp.where(eff_leaky_r, 0, g_limQ_r), g_limQ_r - g_hits_r
-    )
-    cl_reset = jnp.where(eff_leaky_r, 0, now + g_durQ_r)
-    # GCRA creation: reset is the fresh TAT after the leader's own
-    # charge (now + n*T); sliding keeps the token-shaped window end
-    gcra_cl = jnp.clip(
-        gcra_tat0_r
-        + jnp.where(charged_ldr_r, g_hits_r, 0).astype(jnp.int64)
-        * gcra_T_r,
-        _I32_MIN,
-        _I32_MAX,
-    ).astype(jnp.int32)
-    cl_reset = jnp.where(eff_gcra_r, gcra_cl, cl_reset)
-    status = jnp.where(is_creation_leader, cl_status, status)
-    remaining = jnp.where(is_creation_leader, cl_remaining, remaining)
-    reset = jnp.where(is_creation_leader, cl_reset, reset)
+        # creation leader overrides (the branchy creation responses)
+        cl_status = jnp.where(over_c_r, OVER, UNDER)
+        cl_remaining = jnp.where(
+            over_c_r, jnp.where(eff_leaky_r, 0, g_limQ_r), g_limQ_r - g_hits_r
+        )
+        cl_reset = jnp.where(eff_leaky_r, 0, now + g_durQ_r)
+        # GCRA creation: reset is the fresh TAT after the leader's own
+        # charge (now + n*T); sliding keeps the token-shaped window end
+        gcra_cl = jnp.clip(
+            gcra_tat0_r
+            + jnp.where(charged_ldr_r, g_hits_r, 0).astype(jnp.int64)
+            * gcra_T_r,
+            _I32_MIN,
+            _I32_MAX,
+        ).astype(jnp.int32)
+        cl_reset = jnp.where(eff_gcra_r, gcra_cl, cl_reset)
+        status = jnp.where(is_creation_leader, cl_status, status)
+        remaining = jnp.where(is_creation_leader, cl_remaining, remaining)
+        reset = jnp.where(is_creation_leader, cl_reset, reset)
 
-    # GLOBAL replica reads return the stored status verbatim
-    status = jnp.where(
-        gnp_served, jnp.where(sticky0_r, OVER, UNDER), status
-    )
-    remaining = jnp.where(gnp_served, g_rem_r, remaining)
-    reset = jnp.where(gnp_served, g_exp_r, reset)
+        # GLOBAL replica reads return the stored status verbatim
+        status = jnp.where(
+            gnp_served, jnp.where(sticky0_r, OVER, UNDER), status
+        )
+        remaining = jnp.where(gnp_served, g_rem_r, remaining)
+        reset = jnp.where(gnp_served, g_exp_r, reset)
 
-    # leaky zero-limit guard (documented divergence)
-    status = jnp.where(leaky_zero_r, OVER, status)
-    remaining = jnp.where(leaky_zero_r, 0, remaining)
-    reset = jnp.where(leaky_zero_r, now + g_durS_r, reset)
-    resp_limit = jnp.where(leaky_zero_r, lim_q, g_lim_resp)
+        # leaky zero-limit guard (documented divergence)
+        status = jnp.where(leaky_zero_r, OVER, status)
+        remaining = jnp.where(leaky_zero_r, 0, remaining)
+        reset = jnp.where(leaky_zero_r, now + g_durS_r, reset)
+        resp_limit = jnp.where(leaky_zero_r, lim_q, g_lim_resp)
 
     # ---- quota-chain no-partial-debit (r15) -------------------------------
     # With chain coupling, a chain ANY of whose member rows reports
@@ -1337,104 +1343,105 @@ def _decide_presorted(
         ldr_chg_w = jnp.where(~existing & charged_ldr, g_hits, 0)
 
     # ---- state writeback at [G]: merged whole-bucket-row scatter ----------
-    # chg_all: every hit actually charged to the group this batch,
-    # INCLUDING a creation leader's (the historical rem_final folded
-    # the leader's charge into R0_create; chains need it explicit so a
-    # rolled-back leader restores the full budget). Without chains the
-    # arithmetic is identical to the pre-r15 R0 - total_charged.
-    chg_all = total_charged_w + ldr_chg_w
-    R0C = R0 + jnp.where(~existing & charged_ldr, g_hits, 0)
-    rem_final = R0C - chg_all
+    with jax.named_scope("writeback_apply"):
+        # chg_all: every hit actually charged to the group this batch,
+        # INCLUDING a creation leader's (the historical rem_final folded
+        # the leader's charge into R0_create; chains need it explicit so a
+        # rolled-back leader restores the full budget). Without chains the
+        # arithmetic is identical to the pre-r15 R0 - total_charged.
+        chg_all = total_charged_w + ldr_chg_w
+        R0C = R0 + jnp.where(~existing & charged_ldr, g_hits, 0)
+        rem_final = R0C - chg_all
 
-    sticky_final = sticky0 | any_z_w
+        sticky_final = sticky0 | any_z_w
 
-    w_leaky = eff_leaky
-    g_expire_new = jnp.where(existing, g_exp, now + g_durQ)
-    new_expire = jnp.where(
-        w_leaky,
-        jnp.where(
-            existing,
-            jnp.where(any_decr_w, now + g_durS, g_exp),
-            now + g_durQ,
-        ),
-        g_expire_new,
-    )
-    # sliding (r15): the rotated subwindow pair persists — expire pins
-    # the current window start (ws + 2d), L_REMAINING the current
-    # count, L_TS the previous count (store.rebase skips it there)
-    d_eff64 = jnp.where(
-        existing,
-        d_sld,
-        jnp.clip(g_durQ.astype(jnp.int64), 1, _SLD_DMAX),
-    )
-    ws_eff64 = jnp.where(existing, sld_ws, now64)
-    sld_exp_new = jnp.clip(
-        ws_eff64 + 2 * d_eff64, _I32_MIN, _I32_MAX
-    ).astype(jnp.int32)
-    new_expire = jnp.where(eff_sld, sld_exp_new, new_expire)
-    # GCRA (r15): the stored entry IS one theoretical arrival time —
-    # TAT' = max(TAT, now) + charged * T, int64 math clamped into the
-    # int32 expiry lane; TAT < now on a later batch lazy-expires the
-    # entry, which is exactly "fully drained == fresh"
-    gcra_tat_new = jnp.clip(
-        gcra_tat0 + chg_all.astype(jnp.int64) * gcra_T,
-        _I32_MIN,
-        _I32_MAX,
-    ).astype(jnp.int32)
-    new_expire = jnp.where(eff_gcra, gcra_tat_new, new_expire)
-
-    new_rem = jnp.where(
-        eff_sld,
-        jnp.where(existing, sld_cur0, 0) + chg_all,
-        rem_final,
-    )
-    new_ts = jnp.where(existing & w_leaky & ~any_hits_w, g_ts, now)
-    new_ts = jnp.where(
-        eff_sld, jnp.where(existing, sld_prev0, 0), new_ts
-    )
-    new_limit = jnp.where(existing, g_limS, g_limQ)
-    new_duration = jnp.where(existing, g_durS, g_durQ)
-    new_flags = (
-        jnp.where(w_leaky, FLAG_ALGO_LEAKY, 0)
-        | jnp.where(eff_sld, FLAG_ALGO_SLIDING, 0)
-        | jnp.where(eff_gcra, FLAG_ALGO_GCRA, 0)
-        | jnp.where(
-            (eff_algo == 0) & sticky_final, FLAG_STICKY_OVER, 0
-        )
-    ).astype(jnp.int32)
-
-    # Groups served entirely from a replica write back identical values
-    # (harmless); invalid (padding / non-owned), zero-guard, and
-    # sketch-served groups skip the write (w_mask / the plan's dropped
-    # mask, computed above before the sketch overrides).
-    new_vals = jnp.stack(
-        [
-            fp,
-            new_expire,
-            new_rem,
-            new_ts,
-            new_limit,
-            new_duration,
-            new_flags,
-            # L_KEYLOW: the key hash's low 32 bits — with the tag this
-            # makes the entry's full hash reconstructable on device
-            # (eviction->sketch migration above). Written in BOTH
-            # modes so sketch on/off store bytes stay identical.
-            lax.bitcast_convert_type(
-                (kh_G & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
-                jnp.int32,
+        w_leaky = eff_leaky
+        g_expire_new = jnp.where(existing, g_exp, now + g_durQ)
+        new_expire = jnp.where(
+            w_leaky,
+            jnp.where(
+                existing,
+                jnp.where(any_decr_w, now + g_durS, g_exp),
+                now + g_durQ,
             ),
-        ],
-        axis=-1,
-    )  # [G, LANES]
+            g_expire_new,
+        )
+        # sliding (r15): the rotated subwindow pair persists — expire pins
+        # the current window start (ws + 2d), L_REMAINING the current
+        # count, L_TS the previous count (store.rebase skips it there)
+        d_eff64 = jnp.where(
+            existing,
+            d_sld,
+            jnp.clip(g_durQ.astype(jnp.int64), 1, _SLD_DMAX),
+        )
+        ws_eff64 = jnp.where(existing, sld_ws, now64)
+        sld_exp_new = jnp.clip(
+            ws_eff64 + 2 * d_eff64, _I32_MIN, _I32_MAX
+        ).astype(jnp.int32)
+        new_expire = jnp.where(eff_sld, sld_exp_new, new_expire)
+        # GCRA (r15): the stored entry IS one theoretical arrival time —
+        # TAT' = max(TAT, now) + charged * T, int64 math clamped into the
+        # int32 expiry lane; TAT < now on a later batch lazy-expires the
+        # entry, which is exactly "fully drained == fresh"
+        gcra_tat_new = jnp.clip(
+            gcra_tat0 + chg_all.astype(jnp.int64) * gcra_T,
+            _I32_MIN,
+            _I32_MAX,
+        ).astype(jnp.int32)
+        new_expire = jnp.where(eff_gcra, gcra_tat_new, new_expire)
 
-    # Delta-add writeback, phase 2 of the plan computed above: each
-    # writing group adds (new - old) into its way's lanes; disjoint
-    # ways compose exactly and the store keeps its canonical shape
-    # (see _writeback_delta_add).
-    new_data = _writeback_apply(
-        store.data, bkt, writer_G, way_G, new_vals, cand
-    )
+        new_rem = jnp.where(
+            eff_sld,
+            jnp.where(existing, sld_cur0, 0) + chg_all,
+            rem_final,
+        )
+        new_ts = jnp.where(existing & w_leaky & ~any_hits_w, g_ts, now)
+        new_ts = jnp.where(
+            eff_sld, jnp.where(existing, sld_prev0, 0), new_ts
+        )
+        new_limit = jnp.where(existing, g_limS, g_limQ)
+        new_duration = jnp.where(existing, g_durS, g_durQ)
+        new_flags = (
+            jnp.where(w_leaky, FLAG_ALGO_LEAKY, 0)
+            | jnp.where(eff_sld, FLAG_ALGO_SLIDING, 0)
+            | jnp.where(eff_gcra, FLAG_ALGO_GCRA, 0)
+            | jnp.where(
+                (eff_algo == 0) & sticky_final, FLAG_STICKY_OVER, 0
+            )
+        ).astype(jnp.int32)
+
+        # Groups served entirely from a replica write back identical values
+        # (harmless); invalid (padding / non-owned), zero-guard, and
+        # sketch-served groups skip the write (w_mask / the plan's dropped
+        # mask, computed above before the sketch overrides).
+        new_vals = jnp.stack(
+            [
+                fp,
+                new_expire,
+                new_rem,
+                new_ts,
+                new_limit,
+                new_duration,
+                new_flags,
+                # L_KEYLOW: the key hash's low 32 bits — with the tag this
+                # makes the entry's full hash reconstructable on device
+                # (eviction->sketch migration above). Written in BOTH
+                # modes so sketch on/off store bytes stay identical.
+                lax.bitcast_convert_type(
+                    (kh_G & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
+                    jnp.int32,
+                ),
+            ],
+            axis=-1,
+        )  # [G, LANES]
+
+        # Delta-add writeback, phase 2 of the plan computed above: each
+        # writing group adds (new - old) into its way's lanes; disjoint
+        # ways compose exactly and the store keeps its canonical shape
+        # (see _writeback_delta_add).
+        new_data = _writeback_apply(
+            store.data, bkt, writer_G, way_G, new_vals, cand
+        )
 
     resp = BatchResponse(
         status=status, limit=resp_limit, remaining=remaining, reset_time=reset
@@ -1628,6 +1635,7 @@ def upsert_globals(
 PACKED_STATS = 4
 
 
+@jax.named_scope("pack")
 def pack_outputs(resp: BatchResponse, stats: BatchStats) -> jax.Array:
     """Responses + stats as ONE int32[4*B+PACKED_STATS] array: every
     device->host transfer has a fixed cost, so hosts fetch a single

@@ -34,6 +34,7 @@ carries the host-level ring's.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 from typing import Optional, Sequence, Tuple
@@ -921,6 +922,11 @@ class PartitionedEngine:
     pipe for multi-controller SPMD.
     """
 
+    #: `with self.stage_span(name):` around the two halves of
+    #: _dispatch. The serving tier installs its stage clock here
+    #: (serve/backends.py); a bare engine times nothing
+    stage_span = staticmethod(contextlib.nullcontext)
+
     def __init__(
         self,
         config: StoreConfig = StoreConfig(),
@@ -1181,37 +1187,42 @@ class PartitionedEngine:
         """Every submit path — flat or sharded, flush-prep, arrival-
         prep or merged — ends here: feed the serve-tier hot-key
         observer (numpy fields, pre-device) and pick the exact-only or
-        two-tier program for this engine's layout."""
+        two-tier program for this engine's layout. The hook and the
+        jitted call are the `observe` and `jit_call` stages: what is
+        left of the batcher's `dispatch` is pad + group-derive."""
         hook = self.observe_hook
         if hook is not None:
-            try:
-                hook(req)
-            except Exception:  # pragma: no cover - defensive
-                pass  # observability must never fail a dispatch
+            with self.stage_span("observe"):
+                try:
+                    hook(req)
+                except Exception:  # pragma: no cover - defensive
+                    pass  # observability must never fail a dispatch
         two_tier = self.sketch is not None and self.sketch_on
-        if self.flat:
-            from gubernator_tpu.core.engine import (
-                _decide_packed_jit,
-                _decide_packed_sketch_jit,
-            )
-
-            if two_tier:
-                self.store, self.sketch, packed = (
-                    _decide_packed_sketch_jit(
-                        self.store, self.sketch, req, e_now, groups
-                    )
+        with self.stage_span("jit_call"):
+            if self.flat:
+                from gubernator_tpu.core.engine import (
+                    _decide_packed_jit,
+                    _decide_packed_sketch_jit,
                 )
-                return packed
-            self.store, packed = _decide_packed_jit(
-                self.store, req, e_now, groups
-            )
-            return packed
-        if two_tier:
-            self.store, self.sketch, packed = self._step_sketch(
-                self.store, self.sketch, req, groups, e_now
-            )
-            return packed
-        self.store, packed = self._step(self.store, req, groups, e_now)
+
+                if two_tier:
+                    self.store, self.sketch, packed = (
+                        _decide_packed_sketch_jit(
+                            self.store, self.sketch, req, e_now, groups
+                        )
+                    )
+                else:
+                    self.store, packed = _decide_packed_jit(
+                        self.store, req, e_now, groups
+                    )
+            elif two_tier:
+                self.store, self.sketch, packed = self._step_sketch(
+                    self.store, self.sketch, req, groups, e_now
+                )
+            else:
+                self.store, packed = self._step(
+                    self.store, req, groups, e_now
+                )
         return packed
 
     # -- request-object API --------------------------------------------------

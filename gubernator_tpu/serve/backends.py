@@ -40,6 +40,12 @@ from gubernator_tpu.core.cache import LRUCache
 from gubernator_tpu.core.engine import TpuEngine
 from gubernator_tpu.core.oracle import get_rate_limit
 from gubernator_tpu.core.store import StoreConfig
+from gubernator_tpu.parallel.sharded import PartitionedEngine
+from gubernator_tpu.serve.stages import STAGES
+
+# every engine a backend serves from times its dispatch interior
+# (observe, jit_call) on the serving tier's stage clock
+PartitionedEngine.stage_span = staticmethod(STAGES.span)
 
 
 def chain_level_keys(r: RateLimitReq):

@@ -64,7 +64,7 @@ from gubernator_tpu.api.types import RateLimitReq, RateLimitResp
 from gubernator_tpu.serve import metrics, tracing
 from gubernator_tpu.serve.aio import collect_batch
 from gubernator_tpu.serve.faults import FAULTS, FaultError
-from gubernator_tpu.serve.stages import STAGES
+from gubernator_tpu.serve.stages import STAGES, claim_call
 
 
 class _QMeta:
@@ -75,14 +75,22 @@ class _QMeta:
     contract: only frame-flagged groups enter coverage), and the
     caller's active trace, captured at enqueue so the flusher — which
     runs outside the caller's context — can attribute batch_queue and
-    device spans to it (serve/tracing.py)."""
+    device spans to it (serve/tracing.py). `call` marks the one group
+    a gRPC call's handler enqueued first (stages.claim_call): it alone
+    records the call_queue / call_device / call_wake tiles, under
+    those names in the caller's trace too, so the tiles have their
+    call_e2e and a JSON-door, peer-loop or internal group records
+    none. `t_done` is the instant the flusher resolved the group's
+    future: call_device ends there and call_wake begins."""
 
-    __slots__ = ("t", "frame", "trace")
+    __slots__ = ("t", "frame", "call", "trace", "t_done")
 
     def __init__(self, frame: bool):
         self.t = time.monotonic()
         self.frame = frame
+        self.call = not frame and claim_call()
         self.trace = tracing.active()
+        self.t_done = 0.0
 
 
 def _prep_result(prep: "concurrent.futures.Future"):
@@ -299,14 +307,7 @@ class DeviceBatcher:
                     "device", start=t0, batch=len(resps),
                     rung=self._rung(len(resps)), inline=True,
                 )
-            try:
-                metrics.DEVICE_BATCH_SIZE.observe(len(resps))
-                metrics.DEVICE_LAUNCH_MS.observe(
-                    (time.monotonic() - t0) * 1e3
-                )
-                self._observe_cache_stats()
-            except Exception:  # pragma: no cover - defensive
-                pass
+            self._observe_batch(len(resps), time.monotonic() - t0)
             return resps
         # one queue item + ONE future per caller (an RPC's whole request
         # list): per-item futures cost ~0.1-0.3ms of event-loop work per
@@ -322,12 +323,20 @@ class DeviceBatcher:
         # stages must count ONLY groups that belong to an edge frame,
         # or the coverage ratio's numerator outgrows its denominator
         # under direct gRPC/HTTP/peer traffic), and the caller's trace
+        meta = _QMeta(frame)
         self._queue.put_nowait(
             ("decide", reqs_l, gnp_l,
              self._kick_prep("prep_reqs", reqs_l, gnp_l),
-             _QMeta(frame), fut)
+             meta, fut)
         )
-        return await fut
+        resps = await fut
+        if meta.t_done:
+            # the caller's coroutine runs again: a gRPC call's
+            # call_wake tile (future resolved -> here; event-loop and
+            # GIL wait), in the caller's context, so a traced call
+            # gets the span too
+            STAGES.add("call_wake", time.monotonic() - meta.t_done)
+        return resps
 
     def _kick_prep(self, method: str, *args):
         """Arrival-time prep kick: schedule this group's conversion +
@@ -400,10 +409,12 @@ class DeviceBatcher:
             )
         loop = asyncio.get_running_loop()
         fut = loop.create_future()
-        self._queue.put_nowait(
-            ("chain", list(reqs), _QMeta(frame), fut)
-        )
-        return await fut
+        meta = _QMeta(frame)
+        self._queue.put_nowait(("chain", list(reqs), meta, fut))
+        resps = await fut
+        if meta.t_done:
+            STAGES.add("call_wake", time.monotonic() - meta.t_done)
+        return resps
 
     async def run_serialized(self, fn, *args):
         """Run `fn(*args)` on the single submit thread, serialized with
@@ -501,7 +512,8 @@ class DeviceBatcher:
         now = time.monotonic()
         for it in traced:
             it[-2].trace.add_span(
-                "device", start=t_collect, end=now, **ann
+                "call_device" if it[-2].call else "device",
+                start=t_collect, end=now, **ann
             )
 
     async def _run(self) -> None:
@@ -568,15 +580,17 @@ class DeviceBatcher:
         chain_items = [b for b in batch if b[0] == "chain"]
         # batch_queue stage: enqueue -> collect, per frame-flagged
         # caller group (the chain lane participates since the r16
-        # audit — chained frames used to dilute coverage); traced
-        # groups get the same span regardless of frame flag
+        # audit — chained frames used to dilute coverage), call_queue
+        # per gRPC call's group; a traced group gets the span under
+        # its family's name, flagged or not
         t_collect = time.monotonic()
         for it in decide_items + chain_items:
             m = it[-2]
-            if m.frame:
-                STAGES.add("batch_queue", t_collect - m.t)
+            stage = "call_queue" if m.call else "batch_queue"
+            if m.frame or m.call:
+                STAGES.add(stage, t_collect - m.t)
             if m.trace is not None:
-                m.trace.add_span("batch_queue", start=m.t, end=t_collect)
+                m.trace.add_span(stage, start=m.t, end=t_collect)
 
         inline = self._inline
         if global_items:
@@ -629,11 +643,8 @@ class DeviceBatcher:
                 # must record PER_BATCH stages too). The chain call
                 # both submits and waits, so its whole body is the
                 # submit-thread span.
-                t0 = time.monotonic()
-                try:
+                with STAGES.span("submit_host"):
                     return self.backend.decide_chain(all_chain)
-                finally:
-                    STAGES.add("submit_host", time.monotonic() - t0)
 
             t0c = time.monotonic()
             try:
@@ -656,14 +667,11 @@ class DeviceBatcher:
                     if not fut.done():
                         fut.set_result(span)
                 # device stage per frame-flagged chain group (r16
-                # audit fix): collect -> responses resolved, the same
-                # span the decide lanes record — without it, a GEBC
-                # frame added e2e with no device span and coverage
-                # silently diluted under chained traffic
-                dev_span = time.monotonic() - t_collect
-                nf = sum(1 for it in chain_items if it[-2].frame)
-                if nf:
-                    STAGES.add("device", dev_span * nf, nf)
+                # audit fix): the same span the decide lanes record —
+                # without it, a GEBC frame added e2e with no device
+                # span and coverage silently diluted under chained
+                # traffic
+                self._stage_device(chain_items, t_collect)
                 rows = sum(
                     1 + len(getattr(r, "chain", ()) or ())
                     for r in all_chain
@@ -672,14 +680,7 @@ class DeviceBatcher:
                     chain_items, t_collect, len(all_chain),
                     extra=dict(chain=True, rows=rows),
                 )
-                try:
-                    metrics.DEVICE_BATCH_SIZE.observe(len(resps))
-                    metrics.DEVICE_LAUNCH_MS.observe(
-                        (time.monotonic() - t0c) * 1e3
-                    )
-                    self._observe_cache_stats()
-                except Exception:  # pragma: no cover - defensive
-                    pass
+                self._observe_batch(len(resps), time.monotonic() - t0c)
 
         if not decide_items:
             return
@@ -718,10 +719,7 @@ class DeviceBatcher:
                 self._fail(decide_items, e)
                 return
             self._resolve(decide_items, resps, time.monotonic() - t0)
-            span = time.monotonic() - t_collect
-            nf = sum(1 for it in decide_items if it[-2].frame)
-            if nf:
-                STAGES.add("device", span * nf, nf)
+            self._stage_device(decide_items, t_collect)
             self._trace_device(decide_items, t_collect, len(resps))
             return
 
@@ -812,29 +810,24 @@ class DeviceBatcher:
         ]
 
         def submit_call():
-            t0 = time.monotonic()
             runs = []
-            for it in decide_items:
-                p = self._prep_of(it)
-                if p is not None:
-                    runs.append(_prep_result(p))
-                elif it[0] == "decide":
-                    runs.append(
-                        self.backend.prep_reqs(
-                            it[1], [bool(g) for g in it[2]]
+            with STAGES.span("prep"):
+                for it in decide_items:
+                    p = self._prep_of(it)
+                    if p is not None:
+                        runs.append(_prep_result(p))
+                    elif it[0] == "decide":
+                        runs.append(
+                            self.backend.prep_reqs(
+                                it[1], [bool(g) for g in it[2]]
+                            )
                         )
-                    )
-                else:
-                    runs.append(self.backend.prep_group(it[1]))
-            t1 = time.monotonic()
-            merged = self.backend.merge_prepped(runs)
-            t2 = time.monotonic()
-            handle = self.backend.decide_submit_merged(merged)
-            t3 = time.monotonic()
-            STAGES.add("prep", t1 - t0)
-            STAGES.add("merge", t2 - t1)
-            STAGES.add("dispatch", t3 - t2)
-            return handle
+                    else:
+                        runs.append(self.backend.prep_group(it[1]))
+            with STAGES.span("merge"):
+                merged = self.backend.merge_prepped(runs)
+            with STAGES.span("dispatch"):
+                return self.backend.decide_submit_merged(merged)
 
         await self._submit_pipelined(
             submit_call,
@@ -867,35 +860,33 @@ class DeviceBatcher:
             # the full argsort hides inside decide_submit_arrays'
             # dispatch), so the BENCH_SUBMIT_r9 A/B compares the same
             # submit-thread interior either way
-            t0 = time.monotonic()
             parts = []
-            for it in decide_items:
-                if it[0] == "decide":
-                    parts.append(
-                        self.backend.arrays_from_reqs(
-                            it[1], [bool(g) for g in it[2]]
+            with STAGES.span("prep"):
+                for it in decide_items:
+                    if it[0] == "decide":
+                        parts.append(
+                            self.backend.arrays_from_reqs(
+                                it[1], [bool(g) for g in it[2]]
+                            )
                         )
+                    else:
+                        f = it[1]
+                        if "gnp" not in f:
+                            f = dict(f)
+                            f["gnp"] = np.zeros(
+                                f["key_hash"].shape[0], bool
+                            )
+                        parts.append(f)
+                fields = {
+                    k: (
+                        parts[0][k]
+                        if len(parts) == 1
+                        else np.concatenate([p[k] for p in parts])
                     )
-                else:
-                    f = it[1]
-                    if "gnp" not in f:
-                        f = dict(f)
-                        f["gnp"] = np.zeros(f["key_hash"].shape[0], bool)
-                    parts.append(f)
-            fields = {
-                k: (
-                    parts[0][k]
-                    if len(parts) == 1
-                    else np.concatenate([p[k] for p in parts])
-                )
-                for k in self.backend.ARRAY_FIELDS
-            }
-            t1 = time.monotonic()
-            handle = self.backend.decide_submit_arrays(fields)
-            t2 = time.monotonic()
-            STAGES.add("prep", t1 - t0)
-            STAGES.add("dispatch", t2 - t1)
-            return handle
+                    for k in self.backend.ARRAY_FIELDS
+                }
+            with STAGES.span("dispatch"):
+                return self.backend.decide_submit_arrays(fields)
 
         await self._submit_pipelined(
             submit_call,
@@ -912,14 +903,14 @@ class DeviceBatcher:
         loop = asyncio.get_running_loop()
         try:
             status, limit, remaining, reset = await loop.run_in_executor(
-                self._fetch_pool, self.backend.decide_wait_arrays, handle
+                self._fetch_pool, self._fetch,
+                self.backend.decide_wait_arrays, handle,
             )
         except Exception as e:
             self._fail(decide_items, e)
             return
         finally:
             self._inflight.release()
-            STAGES.add("fetch_wait", time.monotonic() - t1)
         k = 0
         for it, n in zip(decide_items, lens):
             span = (
@@ -936,13 +927,7 @@ class DeviceBatcher:
                 fut.set_result(self.backend.resps_from_arrays(*span))
             else:
                 fut.set_result(span)
-        # device stage: collect -> responses resolved, per
-        # frame-flagged caller group (covers submit + device execute +
-        # fetch + pipeline wait)
-        dev_span = time.monotonic() - t_collect
-        nf = sum(1 for it in decide_items if it[-2].frame)
-        if nf:
-            STAGES.add("device", dev_span * nf, nf)
+        self._stage_device(decide_items, t_collect)
         self._trace_device(
             decide_items, t_collect, k,
             extra=dict(
@@ -950,14 +935,7 @@ class DeviceBatcher:
                 fetch_ms=round((time.monotonic() - t1) * 1e3, 3),
             ),
         )
-        try:
-            metrics.DEVICE_BATCH_SIZE.observe(k)
-            metrics.DEVICE_LAUNCH_MS.observe(
-                (submit_s + (time.monotonic() - t1)) * 1e3
-            )
-            self._observe_cache_stats()
-        except Exception:  # pragma: no cover - defensive
-            pass
+        self._observe_batch(k, submit_s + (time.monotonic() - t1))
 
     async def _finish(
         self, handle, decide_items, submit_s: float, t_collect: float
@@ -966,24 +944,21 @@ class DeviceBatcher:
         loop = asyncio.get_running_loop()
         try:
             resps = await loop.run_in_executor(
-                self._fetch_pool, self.backend.decide_wait, handle
+                self._fetch_pool, self._fetch,
+                self.backend.decide_wait, handle,
             )
         except Exception as e:
             self._fail(decide_items, e)
             return
         finally:
             self._inflight.release()
-            STAGES.add("fetch_wait", time.monotonic() - t1)
         # own cost only: host submit + own fetch span — NOT the time
         # spent queued behind earlier batches, which would double-count
         # device time under steady pipelining
         self._resolve(
             decide_items, resps, submit_s + (time.monotonic() - t1)
         )
-        dev_span = time.monotonic() - t_collect
-        nf = sum(1 for it in decide_items if it[-2].frame)
-        if nf:
-            STAGES.add("device", dev_span * nf, nf)
+        self._stage_device(decide_items, t_collect)
         self._trace_device(
             decide_items, t_collect, len(resps),
             extra=dict(
@@ -1011,8 +986,45 @@ class DeviceBatcher:
             k += len(rs)
             if not fut.done():
                 fut.set_result(span)
+        self._observe_batch(len(resps), launch_s)
+
+    @staticmethod
+    def _fetch(wait, handle):
+        """decide_wait* on the fetch pool, as the fetch_wait stage."""
+        with STAGES.span("fetch_wait"):
+            return wait(handle)
+
+    @staticmethod
+    def _stage_device(items, t_collect: float) -> None:
+        """The span from the flusher's collect to this batch's futures
+        resolved (submit + device execute + fetch + any wait behind
+        earlier pipelined batches), once per caller group: `device`
+        for frame-flagged groups, `call_device` for a gRPC call's
+        group, whose call_wake tile starts at the same stamp. Call
+        right after the futures are set, before the flusher yields."""
+        now = time.monotonic()
+        span = now - t_collect
+        frames = calls = 0
+        for it in items:
+            m = it[-2]
+            if m.frame:
+                frames += 1
+            elif m.call:
+                m.t_done = now
+                calls += 1
+        if frames:
+            STAGES.add("device", span * frames, frames)
+        if calls:
+            STAGES.add("call_device", span * calls, calls)
+
+    def _observe_batch(self, n: int, launch_s: float) -> None:
+        """One device batch of n rows, launched at its padding rung:
+        useful rows over attempted slots is device_batch_size_sum /
+        device_batch_slots_total. Best-effort: metrics must never be
+        able to kill the flusher task."""
         try:
-            metrics.DEVICE_BATCH_SIZE.observe(len(resps))
+            metrics.DEVICE_BATCH_SIZE.observe(n)
+            metrics.DEVICE_BATCH_SLOTS.inc(self._rung(n))
             metrics.DEVICE_LAUNCH_MS.observe(launch_s * 1e3)
             self._observe_cache_stats()
         except Exception:  # pragma: no cover - defensive

@@ -865,42 +865,41 @@ class FrameService:
         Returns the packed n x 25-byte response records."""
         import numpy as np
 
-        t_dec = time.monotonic()
-        req_dt, resp_dt = _fast_dtypes()
-        if len(payload) != n * req_dt.itemsize:
-            raise ValueError("fast payload length mismatch")
-        if not self._fast_ok():
-            # wrong backend for pre-hashed frames: refuse loudly; the
-            # edge reconnects and re-handshakes onto the string path
-            raise ValueError(
-                "fast frame but fast path unavailable (non-array backend)"
+        with STAGES.span("bridge_decode"):
+            req_dt, resp_dt = _fast_dtypes()
+            if len(payload) != n * req_dt.itemsize:
+                raise ValueError("fast payload length mismatch")
+            if not self._fast_ok():
+                # wrong backend for pre-hashed frames: refuse loudly;
+                # the edge reconnects and re-handshakes onto the string
+                # path
+                raise ValueError(
+                    "fast frame but fast path unavailable "
+                    "(non-array backend)"
+                )
+            metrics.EDGE_FAST_ITEMS.inc(n)
+            rec = np.frombuffer(payload, dtype=req_dt)
+            fields = dict(
+                key_hash=np.ascontiguousarray(rec["key_hash"]),
+                hits=np.ascontiguousarray(rec["hits"]),
+                limit=np.ascontiguousarray(rec["limit"]),
+                duration=np.ascontiguousarray(rec["duration"]),
+                algo=np.ascontiguousarray(rec["algo"]).astype(np.int32),
             )
-        metrics.EDGE_FAST_ITEMS.inc(n)
-        rec = np.frombuffer(payload, dtype=req_dt)
-        fields = dict(
-            key_hash=np.ascontiguousarray(rec["key_hash"]),
-            hits=np.ascontiguousarray(rec["hits"]),
-            limit=np.ascontiguousarray(rec["limit"]),
-            duration=np.ascontiguousarray(rec["duration"]),
-            algo=np.ascontiguousarray(rec["algo"]).astype(np.int32),
-        )
-        # distinct-key observability: feed the HLL with the hashes so
-        # /v1/debug/stats stays meaningful under fast-path traffic
-        # (hot-key NAMES are unavailable here by design)
-        self.instance.traffic.observe_hashes(fields["key_hash"])
-        STAGES.add("bridge_decode", time.monotonic() - t_dec)
+            # distinct-key observability: feed the HLL with the hashes
+            # so /v1/debug/stats stays meaningful under fast-path
+            # traffic (hot-key NAMES are unavailable here by design)
+            self.instance.traffic.observe_hashes(fields["key_hash"])
         status, limit, remaining, reset = (
             await self._decide_arrays_shed(fields, n)
         )
-        t_enc = time.monotonic()
-        out = np.empty(n, dtype=resp_dt)
-        out["status"] = np.asarray(status, np.int64).astype(np.uint8)
-        out["limit"] = limit
-        out["remaining"] = remaining
-        out["reset_time"] = reset
-        raw = out.tobytes()
-        STAGES.add("encode", time.monotonic() - t_enc)
-        return raw
+        with STAGES.span("encode"):
+            out = np.empty(n, dtype=resp_dt)
+            out["status"] = np.asarray(status, np.int64).astype(np.uint8)
+            out["limit"] = limit
+            out["remaining"] = remaining
+            out["reset_time"] = reset
+            return out.tobytes()
 
     async def _decide_string(
         self, payload: bytes, n: int, decoder=decode_request_frame
@@ -909,9 +908,8 @@ class FrameService:
         instance (validation, routing, forwarding). Returns the
         response list, one per item, in order. `decoder` swaps in the
         GEBC chain-item decoder for chain-extended frames (r15)."""
-        t_dec = time.monotonic()
-        decoded = decoder(payload, n)
-        STAGES.add("bridge_decode", time.monotonic() - t_dec)
+        with STAGES.span("bridge_decode"):
+            decoded = decoder(payload, n)
         good = [r for r in decoded if r is not None]
         # the edge caps frames at its batch limit, but two large
         # co-batched requests can still exceed the instance's
@@ -1054,15 +1052,13 @@ class FrameService:
         status, limit, remaining, reset = (
             await self._decide_arrays_shed(fields, n)
         )
-        t_enc = time.monotonic()
-        out = np.zeros(n, dtype=_string_resp_dtype())
-        out["status"] = np.asarray(status, np.int64).astype(np.uint8)
-        out["limit"] = limit
-        out["remaining"] = remaining
-        out["reset_time"] = reset
-        raw = out.tobytes()
-        STAGES.add("encode", time.monotonic() - t_enc)
-        return raw
+        with STAGES.span("encode"):
+            out = np.zeros(n, dtype=_string_resp_dtype())
+            out["status"] = np.asarray(status, np.int64).astype(np.uint8)
+            out["limit"] = limit
+            out["remaining"] = remaining
+            out["reset_time"] = reset
+            return out.tobytes()
 
     async def _decide_string_frame(
         self, payload: bytes, n: int, magic=MAGIC_RESP, frame_id=None
@@ -1082,10 +1078,10 @@ class FrameService:
                 hdr += struct.pack("<I", frame_id)
             return hdr + await self._decide_string_folded(*fold, n)
         resps = await self._decide_string(payload, n)
-        t_enc = time.monotonic()
-        frame = encode_response_frame(resps, magic=magic, frame_id=frame_id)
-        STAGES.add("encode", time.monotonic() - t_enc)
-        return frame
+        with STAGES.span("encode"):
+            return encode_response_frame(
+                resps, magic=magic, frame_id=frame_id
+            )
 
     @staticmethod
     def _observe_transit(
@@ -1186,11 +1182,10 @@ class FrameService:
                     resps = await self._decide_string(
                         payload, n, decoder=decode_chain_request_frame
                     )
-                    t_enc = time.monotonic()
-                    frame = encode_response_frame(
-                        resps, magic=MAGIC_WRESP, frame_id=frame_id
-                    )
-                    STAGES.add("encode", time.monotonic() - t_enc)
+                    with STAGES.span("encode"):
+                        frame = encode_response_frame(
+                            resps, magic=MAGIC_WRESP, frame_id=frame_id
+                        )
                 else:
                     # GEB2 and GEBT (the trace extension changes the
                     # header, not the item payload or the response)
@@ -1581,11 +1576,10 @@ class FrameService:
                     resps = await self._decide_string(
                         payload, n, decoder=decode_chain_request_frame
                     )
-                    t_enc = time.monotonic()
-                    frame = encode_response_frame(
-                        resps, magic=MAGIC_WRESP, frame_id=frame_id
-                    )
-                    STAGES.add("encode", time.monotonic() - t_enc)
+                    with STAGES.span("encode"):
+                        frame = encode_response_frame(
+                            resps, magic=MAGIC_WRESP, frame_id=frame_id
+                        )
                 elif magic in (MAGIC_WREQ, MAGIC_WTRACE):
                     frame = await self._decide_string_frame(
                         payload, n, magic=MAGIC_WRESP, frame_id=frame_id

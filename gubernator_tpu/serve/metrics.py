@@ -60,6 +60,13 @@ DEVICE_BATCH_SIZE = Histogram(
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096),
     registry=REGISTRY,
 )
+DEVICE_BATCH_SLOTS = Counter(
+    "device_batch_slots_total",
+    "Padded rows launched: each device batch counts its padding-ladder "
+    "rung. device_batch_size_sum over this is the share of launched "
+    "slots that carried a request",
+    registry=REGISTRY,
+)
 DEVICE_LAUNCH_MS = Histogram(
     "device_launch_milliseconds",
     "Wall time of one decide kernel launch (host-observed)",

@@ -31,6 +31,12 @@ from gubernator_tpu.serve.backends import (
 )
 from gubernator_tpu.serve.config import ServerConfig
 from gubernator_tpu.serve.instance import BatchTooLargeError, Instance
+from gubernator_tpu.serve.stages import (
+    STAGES,
+    ProcessProbes,
+    mark_call,
+    unmark_call,
+)
 
 log = logging.getLogger("gubernator_tpu.server")
 
@@ -220,25 +226,58 @@ def _md_traceparent(context) -> "Optional[str]":
     return None
 
 
+async def _serve_call(instance, door, context, pb_reqs, decide, reply):
+    """One rate-limit call through a gRPC door, tiled on the stage
+    clock (serve/stages.py CALL_TILES): grpc_decode and grpc_encode
+    here, instance_route in the instance, call_queue / call_device /
+    call_wake in the batcher for the group this handler enqueues
+    first (mark_call), call_e2e around them all. Decode and encode
+    run inside the trace scope, so a sampled call's trace holds them
+    too. Bare stamps, not STAGES.span: the serving loop
+    pays for every microsecond a call (PERF.md, PR 24), and the two
+    spans are ~20 us long."""
+    t0 = time.monotonic()
+    tracer = instance.tracer
+    trace = tracer.join(
+        door, tracing.parse_traceparent(_md_traceparent(context))
+    )
+    mark = mark_call()
+    try:
+        with tracing.scope(tracer, trace) as tr:
+            reqs = [convert.req_from_pb(p) for p in pb_reqs]
+            t1 = time.monotonic()
+            STAGES.add("grpc_decode", t1 - t0)
+            if tr is not None:
+                tr.annotate(items=len(reqs))
+            resps = await decide(reqs)
+            t2 = time.monotonic()
+            out = reply([convert.resp_to_pb(r) for r in resps])
+            t3 = time.monotonic()
+            STAGES.add("grpc_encode", t3 - t2)
+    except BatchTooLargeError as e:
+        await context.abort(grpc.StatusCode.OUT_OF_RANGE, str(e))
+    finally:
+        unmark_call(mark)
+    STAGES.add("call_e2e", t3 - t0)
+    return out
+
+
+def _v1_reply(pbs):
+    return gubernator_pb2.GetRateLimitsResp(responses=pbs)
+
+
+def _peers_reply(pbs):
+    return peers_pb2.GetPeerRateLimitsResp(rate_limits=pbs)
+
+
 class V1Servicer:
     def __init__(self, instance: Instance):
         self.instance = instance
 
     async def GetRateLimits(self, request, context):
-        reqs = [convert.req_from_pb(p) for p in request.requests]
-        tracer = self.instance.tracer
-        trace = tracer.join(
-            "grpc", tracing.parse_traceparent(_md_traceparent(context))
-        )
-        try:
-            with tracing.scope(tracer, trace) as tr:
-                if tr is not None:
-                    tr.annotate(items=len(reqs))
-                resps = await self.instance.get_rate_limits(reqs)
-        except BatchTooLargeError as e:
-            await context.abort(grpc.StatusCode.OUT_OF_RANGE, str(e))
-        return gubernator_pb2.GetRateLimitsResp(
-            responses=[convert.resp_to_pb(r) for r in resps]
+        return await _serve_call(
+            self.instance, "grpc", context, request.requests,
+            self.instance.get_rate_limits, _v1_reply,
         )
 
     async def HealthCheck(self, request, context):
@@ -253,24 +292,13 @@ class PeersV1Servicer:
         self.instance = instance
 
     async def GetPeerRateLimits(self, request, context):
-        reqs = [convert.req_from_pb(p) for p in request.requests]
         # owner-serve hop of a distributed trace (r16): a forwarding
         # peer's sampled context arrives as gRPC metadata; the owner
         # records its own queue/device spans under the SAME trace id
         # in its own flight recorder
-        tracer = self.instance.tracer
-        trace = tracer.join(
-            "peers", tracing.parse_traceparent(_md_traceparent(context))
-        )
-        try:
-            with tracing.scope(tracer, trace) as tr:
-                if tr is not None:
-                    tr.annotate(items=len(reqs))
-                resps = await self.instance.get_peer_rate_limits(reqs)
-        except BatchTooLargeError as e:
-            await context.abort(grpc.StatusCode.OUT_OF_RANGE, str(e))
-        return peers_pb2.GetPeerRateLimitsResp(
-            rate_limits=[convert.resp_to_pb(r) for r in resps]
+        return await _serve_call(
+            self.instance, "peers", context, request.requests,
+            self.instance.get_peer_rate_limits, _peers_reply,
         )
 
     async def UpdatePeerGlobals(self, request, context):
@@ -1015,8 +1043,6 @@ class Server:
             )
         # stage totals export lazily at scrape time: the hot path only
         # touches the plain-float accumulator (serve/stages.py)
-        from gubernator_tpu.serve.stages import STAGES
-
         snap = STAGES.snapshot()
         for name, s in snap["stages"].items():
             metrics.STAGE_SECONDS.labels(stage=name).set(s["total_s"])
@@ -1087,8 +1113,6 @@ class Server:
         accumulators (the profiler scopes a measurement window with
         it). The reference has per-RPC Prometheus totals only; this is
         the decomposition that says which stage to attack next."""
-        from gubernator_tpu.serve.stages import STAGES
-
         shed = self.instance.shed
         if request.query.get("reset") in ("1", "true"):
             STAGES.reset()
@@ -1140,7 +1164,11 @@ class Server:
         1000) and write it under <tmpdir>/guber-profile/<?name=> (the
         process's temporary directory: /tmp unless TMPDIR says
         otherwise; ?name= is a
-        single path component, default "trace"). View with TensorBoard or
+        single path component, default "trace"). ?python=0 leaves the
+        profiler's Python tracer off (default 1): the host plane then
+        holds the stage clock's own spans (serve/stages.py
+        StageStats.span) and no Python frames, and the capture costs the
+        serving loop less. View with TensorBoard or
         Perfetto. The reference has no tracing at all
         (SURVEY.md section 5); this is the TPU-native replacement for its
         per-RPC Prometheus histograms when you need to see *inside* a
@@ -1182,6 +1210,11 @@ class Server:
                 {"error": "'ms' must be an integer"}, status=400
             )
         ms = max(0, min(ms, 60_000))  # reported below as actually captured
+        python = request.query.get("python", "1")
+        if python not in ("0", "1"):
+            return web.json_response(
+                {"error": "'python' must be 0 or 1"}, status=400
+            )
         # `name` is a single path component under a fixed base — this is
         # the only write-capable endpoint on the HTTP surface, so clients
         # must not be able to aim it at arbitrary paths
@@ -1201,7 +1234,9 @@ class Server:
         try:
             import jax
 
-            jax.profiler.start_trace(out_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = int(python)
+            jax.profiler.start_trace(out_dir, profiler_options=options)
             started = True
             await asyncio.sleep(ms / 1000.0)
         except Exception as e:
@@ -1214,16 +1249,20 @@ class Server:
             )
         finally:
             # stop even on client disconnect (CancelledError) so the
-            # endpoint is usable again without a restart
-            if started:
-                try:
-                    import jax
-
-                    jax.profiler.stop_trace()
-                except Exception:
-                    log.exception("stop_trace failed")
-            self._profiling = False
-        return web.json_response({"trace_dir": out_dir, "captured_ms": ms})
+            # endpoint is usable again without a restart. Off the
+            # serving loop: stop_trace collects and writes the capture
+            # for seconds, and calls must be answered meanwhile
+            try:
+                if started:
+                    await asyncio.to_thread(jax.profiler.stop_trace)
+            except Exception:
+                log.exception("stop_trace failed")
+            finally:
+                self._profiling = False
+        return web.json_response(
+            {"trace_dir": out_dir, "captured_ms": ms,
+             "python": int(python)}
+        )
 
     # -- discovery ----------------------------------------------------------
 
@@ -1284,6 +1323,11 @@ async def run_daemon(conf: ServerConfig) -> None:
     server = Server(conf)
     await server.start()
     log.info("Ready")
+    # the stage clock starts at Ready: warm-up ran every rung through
+    # the engine's dispatch, and its jit_call spans are compiles
+    STAGES.reset()
+    probes = ProcessProbes(STAGES)
+    probes.start()
     stop = asyncio.Event()
     graceful: list = []
     drain_task: list = []
@@ -1332,5 +1376,6 @@ async def run_daemon(conf: ServerConfig) -> None:
         except asyncio.CancelledError:
             log.warning("drain aborted (second SIGTERM)")
     log.info("shutting down")
+    probes.stop()
     await server.stop()
     watchdog.cancel()

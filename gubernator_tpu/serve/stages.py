@@ -60,19 +60,70 @@ Stages form two families:
                      hides inside dispatch
     dispatch         backend decide_submit_presorted/_arrays call:
                      pad + group-derive + device dispatch
+    jit_call         the jitted decide call alone, inside dispatch
+                     (PartitionedEngine._dispatch): argument
+                     transfer + launch, the host side of the program
+    observe          the serve-tier observe hook on the batch's numpy
+                     fields, inside dispatch; dispatch - jit_call -
+                     observe is pad + group-derive, by subtraction
     fetch_wait       decide_wait* span on the fetch pool
 
-- **per-call stages** (`PER_CALL`): recorded once per
-  Instance.get_rate_limits call from ANY front door (gRPC/HTTP/string
-  frames) — not tied to edge frames or device batches at all.
+- **per-call stages** (`PER_CALL`): the gRPC door's family. The six
+  `CALL_TILES` tile one GetRateLimits / GetPeerRateLimits call from
+  handler entry to handler return the way PER_FRAME tiles a frame;
+  `call_coverage` = sum(tile seconds) / `call_e2e` seconds, and the
+  gap is event-loop scheduling between tiles. The batcher records
+  its three tiles for the first group a gRPC handler enqueues
+  (mark_call / claim_call) and for no other: the r7 frame-coverage
+  contract is untouched, and the JSON door, peer loops and internal
+  callers add no tile seconds that lack a call_e2e. A call answered
+  whole by the shed cache, or by an inline host backend's fast
+  path, has no batcher tiles at all.
 
-    instance_route   instance-side validation/routing/assembly
-                     (excluded from the fold and fast paths, which
-                     bypass the instance)
+    grpc_decode      handler entry -> RateLimitReq list built (joining
+                     the trace, then pb -> requests)
+    instance_route   instance-side validation/routing/assembly,
+                     recorded once per Instance.get_rate_limits call
+                     from ANY door that reaches the instance (the
+                     fold and fast paths bypass it)
+    call_queue       batcher enqueue -> flusher collect, for the
+                     call's group (the twin of batch_queue)
+    call_device      flusher collect -> the group's future resolved
+                     (the twin of device)
+    call_wake        future resolved -> the awaiting coroutine runs
+                     again: event-loop and GIL wait
+    grpc_encode      RateLimitResp list -> pb in the servicer
+    call_e2e         handler entry -> return: the denominator
 
-Everything is a plain float accumulation under one lock — ~0.5us per
-record — so the clock can stay on in production. `/metrics` exports
+- **process stages** (`PER_PROCESS`): what stalls every call at once,
+  recorded by serve/server.py's probes.
+
+    loop_lag         how late a 50 ms timer on the serving loop fired:
+                     how late the loop runs what is ready
+    gc_pause         one cyclic-GC collection, gc.callbacks start ->
+                     stop
+
+Everything is a plain float accumulation into the recording thread's
+own table, no lock — ~0.5us per record — so the clock can stay on in
+production. `/metrics` exports
 the same totals as gauges (serve/metrics.py stage_seconds_total).
+Each record also bumps one of `BUCKET_EDGES_S`'s log-scale buckets
+(two an octave, 1 us .. 134 s), cumulative like the totals: a reader
+differences two snapshots' `buckets` for a window's quantiles.
+
+Thread-bound spans go through `STAGES.span(name)`: one pair of
+monotonic stamps, one `add`, and — while a profiler session records
+in a process that has imported JAX — a `jax.profiler.TraceAnnotation`
+around the body, so a /v1/debug/profile capture shows the same span
+on the profiler's clock beside the device's XLA Ops. This module
+never imports JAX itself (the JAX-free client tier imports
+serve/tracing.py, and through it this). Spans that cross an `await`
+or belong to no thread (batch_queue, device, call_queue, call_device,
+call_wake, call_e2e),
+and the per-call ones recorded from bare stamps on the serving loop
+(grpc_decode, instance_route, grpc_encode: tens of microseconds
+each, and a span object a call is not free there), stay on the stage
+clock only.
 
 The chain lane (r15) participates in BOTH families like the decide
 lanes (r16 audit fix): a frame-flagged chained group records
@@ -88,9 +139,14 @@ clock. One ContextVar read per record when tracing is idle.
 
 from __future__ import annotations
 
+import asyncio
+import contextvars
+import gc
+import math
+import sys
 import threading
 import time
-from typing import Dict, Tuple
+from typing import Dict, List
 
 from gubernator_tpu.serve import tracing
 
@@ -102,21 +158,125 @@ PER_FRAME = (
     "device",
     "encode",
 )
-PER_BATCH = ("submit_host", "prep", "merge", "dispatch", "fetch_wait")
-PER_CALL = ("instance_route",)
+PER_BATCH = (
+    "submit_host",
+    "prep",
+    "merge",
+    "dispatch",
+    "jit_call",
+    "observe",
+    "fetch_wait",
+)
+CALL_TILES = (
+    "grpc_decode",
+    "instance_route",
+    "call_queue",
+    "call_device",
+    "call_wake",
+    "grpc_encode",
+)
+PER_CALL = CALL_TILES + ("call_e2e",)
+PER_PROCESS = ("loop_lag", "gc_pause")
+
+#: upper edges of the quantile buckets: bucket 0 is [0, 1 us), bucket k
+#: is [edge k-1, edge k), and one more bucket past the last edge takes
+#: everything longer
+BUCKET_EDGES_S = tuple(1e-6 * 2.0 ** (k / 2) for k in range(55))
+_N_BUCKETS = len(BUCKET_EDGES_S) + 1
+
+
+def bucket_of(seconds: float) -> int:
+    if seconds < 1e-6:
+        return 0
+    return min(int(2 * math.log2(seconds * 1e6)) + 1, _N_BUCKETS - 1)
+
+
+#: a gRPC door's handler marks its context (mark_call); the batcher
+#: gives the call tiles to the FIRST group enqueued under the mark
+#: (claim_call), so one call records one call_queue / call_device /
+#: call_wake however many lanes its items ride, and a group from any
+#: other caller records none: the tiles never outgrow call_e2e
+_CALL: "contextvars.ContextVar" = contextvars.ContextVar(
+    "guber_stage_call", default=None
+)
+
+
+def mark_call():
+    return _CALL.set([True])
+
+
+def unmark_call(token) -> None:
+    _CALL.reset(token)
+
+
+def claim_call() -> bool:
+    mark = _CALL.get()
+    return bool(mark) and mark.pop()
+
+
+def _capturing() -> bool:
+    """A profiler session is recording in this process. JAX is picked
+    up from a process that has it, never imported here."""
+    profiler = sys.modules.get("jax.profiler")
+    return profiler is not None and profiler.TraceAnnotation.is_enabled()
+
+
+class _Span:
+    """One thread-bound span: see StageStats.span."""
+
+    __slots__ = ("_stats", "_stage", "_ann", "_t0")
+
+    def __init__(self, stats: "StageStats", stage: str):
+        self._stats = stats
+        self._stage = stage
+
+    def __enter__(self):
+        # the annotation only while a capture runs: made and dropped
+        # for nobody it is the larger half of an idle span's cost
+        self._ann = None
+        if _capturing():
+            self._ann = sys.modules["jax.profiler"].TraceAnnotation(
+                self._stage
+            )
+            self._ann.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        seconds = time.monotonic() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._stats.add(self._stage, seconds)
+        return False
 
 
 class StageStats:
     """Cumulative per-stage spans + frame end-to-end totals."""
 
     def __init__(self):
-        self._lock = threading.Lock()
-        self._stages: Dict[str, Tuple[float, int]] = {}
+        # Every thread records into a table of its own — stage ->
+        # [total seconds, count, bucket counts] — and takes no lock:
+        # the gRPC path serves ~700 calls/s on one GIL with its submit
+        # thread ~95% busy, and what a record costs there measured as
+        # milliseconds of call_p50_ms on the chip (PERF.md, PR 24).
+        # snapshot() sums the tables; it may read one between two of
+        # an add's three updates, which a cumulative debug clock can
+        # bear.
+        self._local = threading.local()
+        self._lock = threading.Lock()  # the list of tables, the frames
+        self._tables: List[Dict[str, List]] = []
         self._e2e_s = 0.0
         self._frames = 0
         self._started = time.monotonic()
 
+    def _table(self) -> Dict[str, List]:
+        table = self._local.table = {}
+        with self._lock:
+            self._tables.append(table)
+        return table
+
     def add(self, stage: str, seconds: float, n: int = 1) -> None:
+        """`n` samples that lasted `seconds` in total."""
         if seconds < 0:  # clock skew guard (edge stamp from the future)
             return
         tr = tracing.active()
@@ -124,9 +284,24 @@ class StageStats:
             # the span just ended and lasted `seconds`: the trace gets
             # the stage clock's own timing, not a parallel measurement
             tr.add_span(stage, duration_s=seconds)
-        with self._lock:
-            total, count = self._stages.get(stage, (0.0, 0))
-            self._stages[stage] = (total + seconds, count + n)
+        try:
+            table = self._local.table
+        except AttributeError:
+            table = self._table()
+        rec = table.get(stage)
+        if rec is None:
+            rec = table[stage] = [0.0, 0, [0] * _N_BUCKETS]
+        rec[0] += seconds
+        rec[1] += n
+        rec[2][bucket_of(seconds / n)] += n
+
+    def span(self, stage: str) -> _Span:
+        """`with STAGES.span("dispatch"):` records the body's wall time
+        as one sample of `stage` and, where JAX is loaded, shows the
+        same span in a profiler capture's host plane. For code that
+        stays on one thread between enter and exit (no `await`
+        inside): the profiler's annotations nest per thread."""
+        return _Span(self, stage)
 
     def add_frame(self, e2e_seconds: float) -> None:
         """One edge frame fully served (edge send stamp when the frame
@@ -140,40 +315,115 @@ class StageStats:
 
     def reset(self) -> None:
         with self._lock:
-            self._stages.clear()
+            for table in self._tables:
+                table.clear()
             self._e2e_s = 0.0
             self._frames = 0
             self._started = time.monotonic()
 
     def snapshot(self) -> dict:
+        summed: Dict[str, List] = {}
         with self._lock:
-            stages = {
-                name: {
-                    "total_s": round(total, 6),
-                    "count": count,
-                    "mean_ms": round(total / count * 1e3, 4)
-                    if count
-                    else 0.0,
-                }
-                for name, (total, count) in sorted(self._stages.items())
-            }
+            for table in self._tables:
+                for name, (total, count, buckets) in list(table.items()):
+                    rec = summed.setdefault(name, [0.0, 0, [0] * _N_BUCKETS])
+                    rec[0] += total
+                    rec[1] += count
+                    rec[2] = [a + b for a, b in zip(rec[2], buckets)]
             e2e_s, frames = self._e2e_s, self._frames
             window_s = time.monotonic() - self._started
-        attributed = sum(
-            s["total_s"] for n, s in stages.items() if n in PER_FRAME
-        )
+        stages = {
+            name: {
+                "total_s": round(total, 6),
+                "count": count,
+                "mean_ms": round(total / count * 1e3, 4) if count else 0.0,
+                "buckets": buckets,
+            }
+            for name, (total, count, buckets) in sorted(summed.items())
+        }
+
+        def total(names) -> float:
+            return sum(
+                s["total_s"] for n, s in stages.items() if n in names
+            )
+
+        attributed = total(PER_FRAME)
+        call = stages.get("call_e2e", {"total_s": 0.0, "count": 0})
         return {
             "stages": stages,
             "per_frame_stages": list(PER_FRAME),
             "per_batch_stages": list(PER_BATCH),
             "per_call_stages": list(PER_CALL),
+            "per_process_stages": list(PER_PROCESS),
+            "bucket_edges_s": list(BUCKET_EDGES_S),
             "frames": frames,
             "frame_e2e_total_s": round(e2e_s, 6),
             "attributed_total_s": round(attributed, 6),
             "coverage": round(attributed / e2e_s, 4) if e2e_s else 0.0,
+            "calls": call["count"],
+            "call_coverage": round(
+                total(CALL_TILES) / call["total_s"], 4
+            )
+            if call["total_s"]
+            else 0.0,
             "window_s": round(window_s, 3),
         }
 
 
-#: process-global clock; the bridge, batcher, and instance record here
+class ProcessProbes:
+    """The PER_PROCESS stages of one serving process: a 50 ms timer on
+    the serving loop records how late it fired as `loop_lag`, and
+    gc.callbacks times every collection as `gc_pause`. One per process
+    (serve/server.py run_daemon), started on the running loop.
+
+    The GC callback only appends to a list, which the timer drains
+    into the clock at its next tick: a collection runs on whichever
+    thread allocated last, and the clock's tables belong to threads."""
+
+    #: 20 Hz, not 100: each tick wakes the serving loop, and on the
+    #: gRPC path a loop wake-up is not free (PERF.md, PR 24). A stall
+    #: longer than a tick is always seen, a shorter one in proportion
+    TICK_S = 0.050
+
+    def __init__(self, stats: "StageStats"):
+        self._stats = stats
+        self._loop = self._timer = None
+        self._due = 0.0
+        self._gc_t0 = 0.0
+        self._gc_pauses: List[float] = []
+
+    def start(self) -> None:
+        gc.callbacks.append(self._on_gc)
+        self._loop = asyncio.get_running_loop()
+        self._due = self._loop.time() + self.TICK_S
+        self._timer = self._loop.call_at(self._due, self._tick)
+
+    def stop(self) -> None:
+        gc.callbacks.remove(self._on_gc)
+        self._timer.cancel()
+        self._drain_gc()
+
+    def _tick(self) -> None:
+        # a bare timer callback, not a task that sleeps: the serving
+        # loop pays for this twenty times a second
+        now = self._loop.time()
+        self._stats.add("loop_lag", max(0.0, now - self._due))
+        self._drain_gc()
+        self._due = now + self.TICK_S
+        self._timer = self._loop.call_at(self._due, self._tick)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc_t0 = time.monotonic()
+        else:
+            self._gc_pauses.append(time.monotonic() - self._gc_t0)
+
+    def _drain_gc(self) -> None:
+        pauses, self._gc_pauses = self._gc_pauses, []
+        for seconds in pauses:
+            self._stats.add("gc_pause", seconds)
+
+
+#: process-global clock; the doors, batcher, instance and engine record
+#: here
 STAGES = StageStats()

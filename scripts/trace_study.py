@@ -1,0 +1,256 @@
+#!/usr/bin/env python3
+"""One benchmark cell's load with the daemon's own instruments read
+while it runs: by hand, on the chip (PERF.md sections 5 and 6, PR 24).
+
+    chiprun -- python3 scripts/trace_study.py --workload <cell> --seed <n>
+
+Boots the cell's daemon and generators exactly as benchmark/run.py does
+(its harness is imported, nothing of it is edited), with
+GUBER_TRACE_SLOW_MS=50 so the flight recorder keeps the slow calls, and
+over one window of --seconds:
+
+- polls /v1/debug/stages twice a second: `loop_lag` and `gc_pause` per
+  half second, to set beside the instants the generators froze;
+- takes one /v1/debug/profile capture with the Python tracer on and one
+  with it off (each after a short throwaway capture that pays the
+  profiler's first-use cost), and reduces both with the benchmark's
+  own trace_reduce.py: return time, the slowest call due while the
+  capture ran, the loop's worst lag while it ran, idle share, idle gaps;
+- dumps /v1/debug/traces and sums the retained slow calls by tile.
+
+Prints one JSON object; the whole of it, and the Python-tracer-off
+capture's .xplane.pb, go to chiprun_out/trace_study/. The parent never
+imports JAX.
+"""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import json
+import os
+import shutil
+import sys
+import tempfile
+import threading
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [os.path.join(ROOT, "benchmark"), ROOT]
+
+from harness import bench, keyspace, workers  # noqa: E402
+from harness import daemon as daemon_mod  # noqa: E402
+
+POLL_S = 0.5
+CAPTURE_MS = 2000
+SLOW_MS = 50
+
+
+def get_json(d, path, timeout=300.0):
+    return json.loads(daemon_mod.http_get(d.http, path, timeout))
+
+
+def worst_bucket_ms(before, after, edges):
+    """Upper edge (ms) of the highest bucket that gained a sample."""
+    top = max((i for i, (a, b) in enumerate(zip(before, after)) if b > a),
+              default=None)
+    if top is None:
+        return 0.0
+    return 1e3 * edges[min(top, len(edges) - 1)]
+
+
+class Poller(threading.Thread):
+    """(seconds into the window, worst loop_lag ms, GC seconds, the
+    poll's own round trip ms) every POLL_S."""
+
+    def __init__(self, d, t0):
+        super().__init__(daemon=True)
+        self.d, self.t0, self.rows, self.stop = d, t0, [], threading.Event()
+
+    def run(self):
+        prev = None
+        while not self.stop.wait(POLL_S):
+            t = time.monotonic()
+            try:
+                s = get_json(self.d, "/v1/debug/stages", 30.0)
+            except Exception:
+                continue
+            rtt = time.monotonic() - t
+            st = s["stages"]
+            lag = st.get("loop_lag", {}).get("buckets")
+            gc_s = st.get("gc_pause", {}).get("total_s", 0.0)
+            if lag is None:
+                continue
+            if prev is not None:
+                self.rows.append((
+                    round(t - self.t0, 2),
+                    round(worst_bucket_ms(prev[0], lag, s["bucket_edges_s"]), 2),
+                    round(gc_s - prev[1], 4), round(rtt * 1e3, 1),
+                ))
+            prev = (lag, gc_s)
+
+
+def capture(d, name, python, ms=CAPTURE_MS):
+    t = time.monotonic()
+    get_json(d, f"/v1/debug/profile?ms={ms}&python={python}&name={name}")
+    return t, time.monotonic()
+
+
+def tiles_of(traces, slow_ms):
+    """The retained calls slower than slow_ms: how many, and the mean
+    milliseconds each span name holds in them."""
+    slow = [t for t in traces if t["duration_ms"] >= slow_ms]
+    total = {}
+    for t in slow:
+        for s in t["spans"]:
+            total[s["name"]] = total.get(s["name"], 0.0) + s["duration_ms"]
+    n = max(1, len(slow))
+    return {
+        "calls": len(slow),
+        "mean_duration_ms": round(sum(t["duration_ms"] for t in slow) / n, 2),
+        "mean_ms_by_span": {k: round(v / n, 3) for k, v in sorted(total.items())},
+        "start_unix_ms": [t["start_unix_ms"] for t in slow][:200],
+        "slowest": sorted(slow, key=lambda t: -t["duration_ms"])[:3],
+    }
+
+
+def main() -> int:
+    t_exec = time.monotonic()
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, default=50.0)
+    ap.add_argument("--captures", type=int, choices=(0, 1), default=1,
+                    help="0: an undisturbed window (tiles, slow calls, lag)")
+    args = ap.parse_args()
+    args.daemon_argv = ""
+    os.environ["GUBER_TRACE_SLOW_MS"] = str(SLOW_MS)
+
+    cell = bench.load("cells", args.workload)
+    config = bench.load("configs", cell["config"])
+    traffic = bench.load("traffic", cell["traffic"])
+    kind = workers.load_kind(traffic["generator"])
+    bench.build_native()
+    base = os.path.join(tempfile.gettempdir(), "guber-profile")
+    names = {"1": f"study_{args.workload}_py1", "0": f"study_{args.workload}_py0"}
+    for n in names.values():
+        shutil.rmtree(os.path.join(base, n), ignore_errors=True)
+
+    out_dir = os.path.join(ROOT, "chiprun_out", "trace_study")
+    tag = f"s{args.seed}"
+    d, device, _named, boots = bench.boot(args, config, t_exec)
+    fleet = doors = None
+    out = {"workload": args.workload, "seed": args.seed,
+           "device": {k: device[k] for k in ("platform", "kind", "count")},
+           "boot_s": boots}
+    try:
+        from harness.doors import Doors
+
+        doors = Doors(d)
+        rules = keyspace.KeyRules(traffic)
+        out["preload_wrong"] = bench.preload(
+            doors, tag, rules, config["preload_keys"])
+        spec, fleet, _ = bench.start_fleet(
+            d, kind, args.seed, args.seconds, tag, cell, config, traffic)
+        kinds = ("1", "0") if args.captures else ()
+        for python in kinds:  # the profiler's first use, paid here
+            capture(d, "study_warm", python, ms=100)
+        t0 = time.monotonic() + traffic["warmup_s"] + 0.25
+        fleet.go(t0)
+        workers.wait_until(t0)
+        unix0_ms = time.time() * 1e3
+        get_json(d, "/v1/debug/stages?reset=1")
+        get_json(d, "/v1/debug/traces?reset=1")
+        poller = Poller(d, t0)
+        poller.start()
+        caps = {}
+        at = t0 + 0.15 * args.seconds
+        for python in kinds:
+            workers.wait_until(at)
+            start, end = capture(d, names[python], python)
+            caps[python] = {"start_s": round(start - t0, 2),
+                            "return_s": round(end - start, 2)}
+            at = max(end + 5.0, t0 + 0.55 * args.seconds)
+        workers.wait_until(t0 + args.seconds)
+        poller.stop.set()
+        stages = get_json(d, "/v1/debug/stages")
+        traces = get_json(d, "/v1/debug/traces?limit=4096")
+        results = fleet.results(traffic["drain_timeout_s"])
+        summary = kind.summarize(results, spec)
+        doors.close()
+        doors = None
+        fleet.close()
+        fleet = None
+        d.stop()
+    finally:
+        if doors is not None:
+            doors.close()
+        if fleet is not None:
+            fleet.close()
+        d.stop(10.0)
+
+    for python, c in caps.items():
+        lo, hi = c["start_s"], c["start_s"] + c["return_s"]
+        if "latency_ms" in results[0]:  # the open-loop generator
+            c["call_max_ms_while_capturing"] = max(
+                (float(r["latency_ms"][(r["due_s"] >= lo) & (r["due_s"] < hi)].max())
+                 for r in results
+                 if ((r["due_s"] >= lo) & (r["due_s"] < hi)).any()),
+                default=None)
+        c["loop_lag_max_ms_while_capturing"] = max(
+            (row[1] for row in poller.rows if lo <= row[0] <= hi + POLL_S),
+            default=None)
+        reduced = bench.reduce_trace(
+            os.path.join(base, names[python]), cell["trace_match"])
+        c.update(
+            window_s=reduced["window_s"], busy_s=reduced["busy_s"],
+            idle_share_pct=100.0 * (1 - reduced["busy_s"] / reduced["window_s"]),
+            idle_gaps=reduced["idle_gaps"][:10],
+            device_ops=reduced["device_ops"][:10], step=reduced["step"])
+        if python == "0":  # kept: the operations' full HLO lines are in it
+            for f in glob.glob(os.path.join(base, names[python], "**",
+                                            "*.xplane.pb"), recursive=True):
+                os.makedirs(out_dir, exist_ok=True)
+                shutil.copy(f, os.path.join(
+                    out_dir, f"{args.workload}.py0.xplane.pb"))
+        shutil.rmtree(os.path.join(base, names[python]), ignore_errors=True)
+
+    # when each generator itself ran late, inside the window (its own
+    # `late_events` list is used up by the first sends of the warm-up)
+    froze = []
+    for r in results:
+        if "late_ms" in r and len(r["late_ms"]) == len(r["due_s"]):
+            late = r["late_ms"] > 5.0
+            froze.append([(round(float(t), 2), round(float(ms), 1)) for t, ms
+                          in zip(r["due_s"][late][:60], r["late_ms"][late][:60])])
+    st = stages["stages"]
+    out.update(
+        captures=caps,
+        call_coverage=stages.get("call_coverage"), calls=stages.get("calls"),
+        coverage=stages.get("coverage"), frames=stages.get("frames"),
+        stage_means_ms={k: v["mean_ms"] for k, v in st.items()},
+        stage_counts={k: v["count"] for k, v in st.items()},
+        stage_totals_s={k: v["total_s"] for k, v in st.items()},
+        generator={k: v for k, v in summary["generator"].items()},
+        end_to_end=summary["end_to_end"],
+        failed=summary["failed"], attempted=summary["attempted"],
+        window_unix0_ms=unix0_ms,
+        generators_late_over_5ms=froze,
+        loop_lag_rows_over_20ms=[r for r in poller.rows if r[1] >= 20.0],
+        gc_rows_over_20ms=[r for r in poller.rows if r[2] >= 0.02],
+        polls=len(poller.rows),
+        slow_calls=tiles_of(traces["traces"], SLOW_MS),
+        recorder=traces.get("counters"),
+        slow_threshold_ms=traces.get("slow_threshold_ms"),
+    )
+    path = os.path.join(out_dir, f"{args.workload}.json")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(path, "w") as f:
+        json.dump({**out, "poll_rows": poller.rows,
+                   "stages": stages}, f, default=float)
+    print(json.dumps(out, default=float))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

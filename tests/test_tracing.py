@@ -360,15 +360,16 @@ def test_three_node_trace_covers_both_sides_of_the_forward():
         owner_names = {s["name"] for s in owner["spans"]}
         assert "bridge_decode" in origin_names  # edge/bridge
         assert "peer_forward" in origin_names  # the hop
-        assert "batch_queue" in owner_names  # queue
-        assert "device" in owner_names  # device
+        # the owner served a gRPC call: the call family's names
+        assert "call_queue" in owner_names  # queue
+        assert "call_device" in owner_names  # device
         assert owner["door"] == "peers"
         fwd = next(
             s for s in origin["spans"] if s["name"] == "peer_forward"
         )
         assert fwd["annotations"]["peer"] == owner_host
         dev = next(
-            s for s in owner["spans"] if s["name"] == "device"
+            s for s in owner["spans"] if s["name"] == "call_device"
         )
         # device span annotated with batch size and ladder rung
         assert dev["annotations"]["batch"] >= 1
