@@ -42,7 +42,9 @@ from gubernator_tpu.core.kernels import (
     decide_presorted,
     decide_presorted_sketch,
     pack_outputs,
+    packed_inputs_width,
     rebase_jit,
+    unpack_inputs,
     unpack_outputs,
     upsert_globals_jit,
 )
@@ -69,32 +71,39 @@ DEFAULT_BUCKETS = (64, 256, 1024, 4096)
 DEEP_BUCKETS = (16384, 32768, 131072)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _decide_packed_jit(store, req, now, groups=None):
-    """decide_presorted + pack_outputs: one host transfer per batch."""
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(2, 3))
+def _decide_packed_jit(store, packed_in, B, G):
+    """unpack_inputs + decide_presorted + pack_outputs: one host
+    transfer per batch each way (kernels.pack_inputs lays `packed_in`
+    out; B request rows and G group slots are static)."""
+    req, groups, now = unpack_inputs(packed_in, B, G)
     store, resp, stats = decide_presorted(store, req, now, groups)
     return store, pack_outputs(resp, stats)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _decide_packed_chain_jit(store, req, now, groups, chain_id):
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(2, 3))
+def _decide_packed_chain_jit(store, packed_in, B, G):
     """Quota-chain twin of _decide_packed_jit (r15): one jitted pass
-    with chain-coupled rows (kernels.decide_presorted_chain). Chain
-    batches run exact-only — the sketch tier is never consulted
+    with chain-coupled rows (kernels.decide_presorted_chain), their
+    chain ids the one column appended to `packed_in`. Chain batches run
+    exact-only — the sketch tier is never consulted
     (core/algorithms.py eligibility)."""
     from gubernator_tpu.core.kernels import decide_presorted_chain
 
+    req, groups, now = unpack_inputs(packed_in, B, G)
+    chain_id = packed_in[packed_inputs_width(B, G) :]
     store, resp, stats = decide_presorted_chain(
         store, req, now, chain_id, groups
     )
     return store, pack_outputs(resp, stats)
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _decide_packed_sketch_jit(store, sketch, req, now, groups=None):
+@functools.partial(jax.jit, donate_argnums=(0, 1), static_argnums=(3, 4))
+def _decide_packed_sketch_jit(store, sketch, packed_in, B, G):
     """Two-tier twin of _decide_packed_jit (r13): store AND sketch
-    donate; the packed transfer layout is identical, so decide_wait
-    serves both variants unchanged."""
+    donate; the packed transfer layouts are identical both ways, so
+    _dispatch and decide_wait serve both variants unchanged."""
+    req, groups, now = unpack_inputs(packed_in, B, G)
     store, sketch, resp, stats = decide_presorted_sketch(
         store, sketch, req, now, groups
     )

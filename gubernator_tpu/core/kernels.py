@@ -91,6 +91,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from gubernator_tpu.core.store import (
@@ -1668,6 +1669,94 @@ def unpack_outputs(packed, B: int):
         packed[4 * B + 2],
         packed[4 * B + 3],
     )
+
+
+# The way in, packed like the way out: a batch's host inputs cross as
+# ONE int32 array, because every host->device transfer has a fixed cost
+# too and a decide call used to make thirteen. Layout in int32 words,
+# with B request rows and G group slots (the program is specialised on
+# both already); on a mesh the same row per shard, [n_shards, W]:
+#   B-word segments: key_hash low, key_hash high, hits, limit, duration,
+#                    algo, gnp, valid, group_id
+#   G-word segments: group key_hash low, high, leader_pos, end_pos, valid
+#   1 word:          now (engine-ms)
+#   then whatever [.., B] int32 columns the caller appends (the chain
+#   program's chain_id).
+_REQ_SEGMENTS = 9
+_GROUP_SEGMENTS = 5
+
+
+def packed_inputs_width(B: int, G: int) -> int:
+    return _REQ_SEGMENTS * B + _GROUP_SEGMENTS * G + 1
+
+
+def pack_inputs(
+    req: BatchRequest, groups: BatchGroups, e_now, *extra
+) -> np.ndarray:
+    """(req, groups, now) as ONE fresh int32[..., W] host array (numpy
+    in, numpy out; any leading shard axes are kept). 64-bit hashes go
+    as their two little-endian 32-bit words, split here so the device
+    reads two contiguous segments; bools go as 0/1 words. The buffer is
+    new every batch: it stays untouched while its transfer is in
+    flight, whatever the caller does with `req` next."""
+    B = req.key_hash.shape[-1]
+    G = groups.key_hash.shape[-1]
+    kh = req.key_hash.view(np.int32)  # (low, high) word pairs
+    gk = groups.key_hash.view(np.int32)
+    buf = np.empty(
+        req.key_hash.shape[:-1]
+        + (packed_inputs_width(B, G) + B * len(extra),),
+        np.int32,
+    )
+    o = 0
+    for n, columns in (
+        (B, (kh[..., 0::2], kh[..., 1::2], req.hits, req.limit,
+             req.duration, req.algo, req.gnp, req.valid, groups.group_id)),
+        (G, (gk[..., 0::2], gk[..., 1::2], groups.leader_pos,
+             groups.end_pos, groups.valid)),
+        (1, (e_now,)),
+        (B, extra),
+    ):
+        for c in columns:
+            buf[..., o : o + n] = c
+            o += n
+    return buf
+
+
+def _join_u64(lo: jax.Array, hi: jax.Array) -> jax.Array:
+    # shifts and ORs, not a 64-bit bitcast: the TPU has no 64-bit lanes
+    # and its compiler expands a u32[n,2]->u64[n] bitcast-convert into
+    # a loop, while this form is the (low, high) pair it keeps anyway
+    lo = lax.bitcast_convert_type(lo, jnp.uint32).astype(jnp.uint64)
+    hi = lax.bitcast_convert_type(hi, jnp.uint32).astype(jnp.uint64)
+    return (hi << jnp.uint64(32)) | lo
+
+
+def unpack_inputs(packed: jax.Array, B: int, G: int):
+    """(BatchRequest, BatchGroups, now) from a pack_inputs array, inside
+    the jitted program: static slices, bit for bit what the host held.
+    Appended columns are `packed[..., packed_inputs_width(B, G):]`."""
+    def seg(i, n=B, base=0):
+        return packed[..., base + i * n : base + (i + 1) * n]
+
+    g0 = _REQ_SEGMENTS * B
+    req = BatchRequest(
+        key_hash=_join_u64(seg(0), seg(1)),
+        hits=seg(2),
+        limit=seg(3),
+        duration=seg(4),
+        algo=seg(5),
+        gnp=seg(6) != 0,
+        valid=seg(7) != 0,
+    )
+    groups = BatchGroups(
+        key_hash=_join_u64(seg(0, G, g0), seg(1, G, g0)),
+        leader_pos=seg(2, G, g0),
+        end_pos=seg(3, G, g0),
+        valid=seg(4, G, g0) != 0,
+        group_id=seg(8),
+    )
+    return req, groups, packed[..., g0 + _GROUP_SEGMENTS * G]
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

@@ -57,8 +57,11 @@ from gubernator_tpu.core.kernels import (
     BatchRequest,
     BatchResponse,
     decide_presorted,
+    pack_inputs,
     pack_outputs,
+    packed_inputs_width,
     rebase_jit,
+    unpack_inputs,
     upsert_globals,
     upsert_globals_jit,
     upsert_windows_jit,
@@ -135,7 +138,7 @@ def _hier_psum(x: jax.Array, axes: tuple) -> jax.Array:
     return x
 
 
-def _local_decide(store: Store, req: BatchRequest, groups, now):
+def _local_decide(store: Store, packed_in, *, B, G):
     """Per-device body under shard_map: store AND batch are this device's
     shards. The host routed every request row to its owner chip
     (pad_request_sharded), so each chip runs the plain single-device
@@ -143,37 +146,41 @@ def _local_decide(store: Store, req: BatchRequest, groups, now):
     mesh analogue of the reference forwarding only owned keys to a peer
     (reference peers.go:111-207) — with its own per-shard duplicate-key
     group structure (store I/O at unique-key granularity, see
-    kernels.BatchGroups). Responses + stats pack into one int32 row per
-    shard (one host transfer total)."""
+    kernels.BatchGroups). The sub-batch arrives as this shard's row of
+    the one packed input array (kernels.pack_inputs, `now` in its own
+    slot of every row); responses + stats pack into one int32 row per
+    shard (one host transfer each way)."""
     store = jax.tree.map(lambda x: x[0], store)  # [1, r, s] -> [r, s]
-    req = jax.tree.map(lambda x: x[0], req)  # [1, B_sub] -> [B_sub]
-    groups = jax.tree.map(lambda x: x[0], groups)
+    req, groups, now = unpack_inputs(packed_in[0], B, G)
     new_store_shard, resp, stats = decide_presorted(store, req, now, groups)
     packed = pack_outputs(resp, stats)
     return jax.tree.map(lambda x: x[None], new_store_shard), packed[None]
 
 
-def _local_decide_gathered(store: Store, req: BatchRequest, groups, now,
-                           axes=("shard",)):
-    """_local_decide + one all_gather of the packed response rows: when
-    the mesh spans processes the serving host cannot fetch follower
-    shards directly, so the responses ride the compiled collective path
-    (ICI within a host, DCN between hosts) and come out replicated. On
-    the 2-D mesh the gather names both axes host-major, so the gathered
-    row order equals the flattened shard order."""
-    store, packed = _local_decide(store, req, groups, now)
-    out = packed[0]
+def _gather_rows(out, axes):
+    """One all_gather of the packed response rows: when the mesh spans
+    processes the serving host cannot fetch follower shards directly,
+    so the responses ride the compiled collective path (ICI within a
+    host, DCN between hosts) and come out replicated. On the 2-D mesh
+    the gather names both axes host-major, so the gathered row order
+    equals the flattened shard order."""
     if len(axes) == 1:
-        return store, jax.lax.all_gather(out, axes[0])
+        return jax.lax.all_gather(out, axes[0])
     # gather chips within a host over ICI first, then hosts over DCN,
     # then flatten [host, chip, ...] -> [shard, ...]
     out = jax.lax.all_gather(out, axes[-1])
     out = jax.lax.all_gather(out, axes[0])
-    return store, out.reshape((-1,) + out.shape[2:])
+    return out.reshape((-1,) + out.shape[2:])
 
 
-def _local_decide_sketch(store: Store, sketch, req: BatchRequest, groups,
-                         now):
+def _local_decide_gathered(store: Store, packed_in, *, B, G,
+                           axes=("shard",)):
+    """_local_decide with its response rows gathered (_gather_rows)."""
+    store, packed = _local_decide(store, packed_in, B=B, G=G)
+    return store, _gather_rows(packed[0], axes)
+
+
+def _local_decide_sketch(store: Store, sketch, packed_in, *, B, G):
     """Two-tier twin of _local_decide (r14): each shard carries its own
     count-min SUB-SKETCH next to its store shard. The host routes every
     key to its owner chip, so a key's sketch charges land only in its
@@ -185,8 +192,7 @@ def _local_decide_sketch(store: Store, sketch, req: BatchRequest, groups,
 
     store = jax.tree.map(lambda x: x[0], store)
     sketch = jax.tree.map(lambda x: x[0], sketch)
-    req = jax.tree.map(lambda x: x[0], req)
-    groups = jax.tree.map(lambda x: x[0], groups)
+    req, groups, now = unpack_inputs(packed_in[0], B, G)
     new_store, new_sketch, resp, stats = decide_presorted_sketch(
         store, sketch, req, now, groups
     )
@@ -198,22 +204,15 @@ def _local_decide_sketch(store: Store, sketch, req: BatchRequest, groups,
     )
 
 
-def _local_decide_sketch_gathered(store: Store, sketch, req: BatchRequest,
-                                  groups, now, axes=("shard",)):
-    """_local_decide_sketch + the _local_decide_gathered all_gather
-    (r20): the two-tier step's replicated-response form for meshes that
-    span processes — the serving leader cannot fetch follower shards'
-    packed rows, so they ride the compiled collective path and come out
-    replicated, exactly like the exact-only step."""
+def _local_decide_sketch_gathered(store: Store, sketch, packed_in, *, B, G,
+                                  axes=("shard",)):
+    """_local_decide_sketch with its response rows gathered (r20): the
+    two-tier step's replicated-response form for meshes that span
+    processes, exactly like the exact-only step."""
     store, sketch, packed = _local_decide_sketch(
-        store, sketch, req, groups, now
+        store, sketch, packed_in, B=B, G=G
     )
-    out = packed[0]
-    if len(axes) == 1:
-        return store, sketch, jax.lax.all_gather(out, axes[0])
-    out = jax.lax.all_gather(out, axes[-1])
-    out = jax.lax.all_gather(out, axes[0])
-    return store, sketch, out.reshape((-1,) + out.shape[2:])
+    return store, sketch, _gather_rows(packed[0], axes)
 
 
 def _shard_sketch_min(data, owner, idx, axes):
@@ -663,18 +662,17 @@ def build_presorted_sharded(
     return req, take_idx, groups, B_sub
 
 
-def _local_decide_chain(store: Store, req: BatchRequest, groups, chain_id,
-                        now):
+def _local_decide_chain(store: Store, packed_in, *, B, G):
     """Per-device chain decide under shard_map (r15): the host routed
     every CHAIN whole to its head-key owner shard (pad_request_chained),
     so the chain AND-reduce runs entirely shard-local — the decide path
-    keeps its no-collective property even with coupled rows."""
+    keeps its no-collective property even with coupled rows. The chain
+    ids are the one column appended to the packed input row."""
     from gubernator_tpu.core.kernels import decide_presorted_chain
 
     store = jax.tree.map(lambda x: x[0], store)
-    req = jax.tree.map(lambda x: x[0], req)
-    groups = jax.tree.map(lambda x: x[0], groups)
-    chain_id = chain_id[0]
+    req, groups, now = unpack_inputs(packed_in[0], B, G)
+    chain_id = packed_in[0, packed_inputs_width(B, G) :]
     new_store, resp, stats = decide_presorted_chain(
         store, req, now, chain_id, groups
     )
@@ -993,18 +991,7 @@ class PartitionedEngine:
             if span
             else _local_decide
         )
-        self._step = jax.jit(
-            jax.shard_map(
-                step_fn,
-                mesh=self.mesh,
-                in_specs=(Ps, Ps, Ps, P0),
-                out_specs=(Ps, P0 if span else Ps),
-                # the all_gather output IS replicated, but the static
-                # varying-axis check can't prove it — disable just there
-                check_vma=not span,
-            ),
-            donate_argnums=(0,),
-        )
+        self._step = self._mesh_decide_program(step_fn, 1)
         # quota-chain program (r15): chain-coupled rows, shard-local
         # AND-reduce (chains are routed whole to their head's owner).
         # jit is lazy, so deployments that never see a chain pay only
@@ -1013,14 +1000,8 @@ class PartitionedEngine:
         # scope limit; decide_chain_submit refuses loudly).
         self._step_chain = None
         if not span:
-            self._step_chain = jax.jit(
-                jax.shard_map(
-                    _local_decide_chain,
-                    mesh=self.mesh,
-                    in_specs=(Ps, Ps, Ps, Ps, P0),
-                    out_specs=(Ps, Ps),
-                ),
-                donate_argnums=(0,),
+            self._step_chain = self._mesh_decide_program(
+                _local_decide_chain, 1
             )
         self._step_sketch = None
         if self.sketch_config is not None:
@@ -1031,15 +1012,8 @@ class PartitionedEngine:
                 if span
                 else _local_decide_sketch
             )
-            self._step_sketch = jax.jit(
-                jax.shard_map(
-                    sketch_step_fn,
-                    mesh=self.mesh,
-                    in_specs=(Ps, Ps, Ps, Ps, P0),
-                    out_specs=(Ps, Ps, P0 if span else Ps),
-                    check_vma=not span,
-                ),
-                donate_argnums=(0, 1),
+            self._step_sketch = self._mesh_decide_program(
+                sketch_step_fn, 2
             )
         # collective host-read programs (r20): when the mesh spans
         # processes the serving host cannot index follower shards, so
@@ -1105,6 +1079,39 @@ class PartitionedEngine:
                 out_specs=Ps,
             ),
             donate_argnums=(0,),
+        )
+
+    def _mesh_decide_program(self, body, n_state: int):
+        """jit(shard_map(body)) called as (*state, packed_in, B, G):
+        `n_state` donated state pytrees and the one packed input array,
+        all split on the shard axis; B and G (the per-shard rungs
+        pack_inputs laid the rows out for) are static. A mesh that spans
+        processes gets its response rows replicated (its bodies gather
+        them) — the all_gather output IS replicated, but the static
+        varying-axis check can't prove it, so it is disabled just
+        there."""
+        Ps = self.policy.request_spec()
+        P0 = self.policy.replicated_spec()
+        span = self.policy.spans_processes
+
+        def step(*args):
+            *sharded, B, G = args
+            return jax.shard_map(
+                functools.partial(body, B=B, G=G),
+                mesh=self.mesh,
+                in_specs=(Ps,) * (n_state + 1),
+                out_specs=(Ps,) * n_state + (P0 if span else Ps,),
+                check_vma=not span,
+            )(*sharded)
+
+        # the program keeps its body's name in traces and compile logs
+        step.__name__ = step.__qualname__ = getattr(
+            body, "func", body
+        ).__name__
+        return jax.jit(
+            step,
+            donate_argnums=tuple(range(n_state)),
+            static_argnums=(n_state + 1, n_state + 2),
         )
 
     def _fresh(self, make):
@@ -1186,10 +1193,12 @@ class PartitionedEngine:
     def _dispatch(self, req, groups, e_now):
         """Every submit path — flat or sharded, flush-prep, arrival-
         prep or merged — ends here: feed the serve-tier hot-key
-        observer (numpy fields, pre-device) and pick the exact-only or
-        two-tier program for this engine's layout. The hook and the
-        jitted call are the `observe` and `jit_call` stages: what is
-        left of the batcher's `dispatch` is pad + group-derive."""
+        observer (numpy fields, pre-device), pack the batch's host
+        inputs into the ONE array that crosses to the device
+        (kernels.pack_inputs) and pick the exact-only or two-tier
+        program for this engine's layout. The hook and the jitted call
+        are the `observe` and `jit_call` stages: what is left of the
+        batcher's `dispatch` is pad + group-derive + that pack."""
         hook = self.observe_hook
         if hook is not None:
             with self.stage_span("observe"):
@@ -1198,6 +1207,8 @@ class PartitionedEngine:
                 except Exception:  # pragma: no cover - defensive
                     pass  # observability must never fail a dispatch
         two_tier = self.sketch is not None and self.sketch_on
+        B, G = req.key_hash.shape[-1], groups.key_hash.shape[-1]
+        packed_in = pack_inputs(req, groups, e_now)
         with self.stage_span("jit_call"):
             if self.flat:
                 from gubernator_tpu.core.engine import (
@@ -1208,20 +1219,20 @@ class PartitionedEngine:
                 if two_tier:
                     self.store, self.sketch, packed = (
                         _decide_packed_sketch_jit(
-                            self.store, self.sketch, req, e_now, groups
+                            self.store, self.sketch, packed_in, B, G
                         )
                     )
                 else:
                     self.store, packed = _decide_packed_jit(
-                        self.store, req, e_now, groups
+                        self.store, packed_in, B, G
                     )
             elif two_tier:
                 self.store, self.sketch, packed = self._step_sketch(
-                    self.store, self.sketch, req, groups, e_now
+                    self.store, self.sketch, packed_in, B, G
                 )
             else:
                 self.store, packed = self._step(
-                    self.store, req, groups, e_now
+                    self.store, packed_in, B, G
                 )
         return packed
 
@@ -1385,22 +1396,20 @@ class PartitionedEngine:
                 hook(req)
             except Exception:  # pragma: no cover - defensive
                 pass
+        B, G = req.key_hash.shape[-1], groups.key_hash.shape[-1]
+        packed_in = pack_inputs(req, groups, e_now, chain_local)
         if self.flat:
             from gubernator_tpu.core.engine import _decide_packed_chain_jit
 
-            B = req.key_hash.shape[0]
             self.store, packed = _decide_packed_chain_jit(
-                self.store, req, e_now, groups, chain_local
+                self.store, packed_in, B, G
             )
             order_p = np.empty(B, np.int32)
             order_p[:n] = order
             order_p[n:] = np.arange(n, B, dtype=np.int32)
             return (packed, order_p, None, n, B, self.clock.epoch)
-        B_sub = req.key_hash.shape[1]
-        self.store, packed = self._step_chain(
-            self.store, req, groups, chain_local, e_now
-        )
-        return (packed, order, take_idx, n, B_sub, self.clock.epoch)
+        self.store, packed = self._step_chain(self.store, packed_in, B, G)
+        return (packed, order, take_idx, n, B, self.clock.epoch)
 
     def decide_chain_arrays(
         self,

@@ -59,13 +59,15 @@ Stages form two families:
                      flush-time baseline path, whose full argsort
                      hides inside dispatch
     dispatch         backend decide_submit_presorted/_arrays call:
-                     pad + group-derive + device dispatch
+                     pad + group-derive + input pack + device dispatch
     jit_call         the jitted decide call alone, inside dispatch
-                     (PartitionedEngine._dispatch): argument
-                     transfer + launch, the host side of the program
+                     (PartitionedEngine._dispatch): the transfer of
+                     the batch's ONE packed input array + launch, the
+                     host side of the program
     observe          the serve-tier observe hook on the batch's numpy
                      fields, inside dispatch; dispatch - jit_call -
-                     observe is pad + group-derive, by subtraction
+                     observe is pad + group-derive + the input pack
+                     (kernels.pack_inputs), by subtraction
     fetch_wait       decide_wait* span on the fetch pool
 
 - **per-call stages** (`PER_CALL`): the gRPC door's family. The six

@@ -23,15 +23,18 @@ compile cache off: a described-device executable is written to the
 cache but can never be read back.
 """
 
+import re
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
 
 import gubernator_tpu.core  # noqa: F401  (enables x64)
 from gubernator_tpu.core import engine as engine_mod
+from gubernator_tpu.core.kernels import packed_inputs_width
 from gubernator_tpu.core.sketches import derive_sketch_config
 from gubernator_tpu.core.store import StoreConfig
 from gubernator_tpu.parallel.policy import ShardingPolicy
@@ -145,16 +148,18 @@ def test_flat_sketch_decide_1024_rung_compiles_for_v5e(topo, no_compile_cache):
         sketch=_sketch(1),
     )
     keys = np.arange(1, 1025, dtype=np.uint64) << np.uint64(32)
-    req, groups, e_now = _captured(
-        small, "_dispatch", lambda: small.decide_arrays(**_batch(keys))
+    _, _, packed_in, B, G = _captured(
+        engine_mod, "_decide_packed_sketch_jit",
+        lambda: small.decide_arrays(**_batch(keys)),
     )
-    assert req.key_hash.shape == (1024,)
+    assert (B, G) == (1024, 1024)
+    assert packed_in.shape == (packed_inputs_width(B, G),)
     real = _shapes_only_engine(lambda eng: one)(
         STORE, policy=ShardingPolicy.single(), buckets=LADDER,
         sketch=_sketch(16),
     )
     compiled = engine_mod._decide_packed_sketch_jit.lower(
-        real.store, real.sketch, *_shapes((req, e_now, groups), one)
+        real.store, real.sketch, _shapes(packed_in, one), B, G
     ).compile()
     mem = compiled.memory_analysis()
     state = 512 * MIB + 16 * MIB
@@ -163,6 +168,18 @@ def test_flat_sketch_decide_1024_rung_compiles_for_v5e(topo, no_compile_cache):
     # holds ONE copy of the state on a 16 GB chip
     assert mem.alias_size_in_bytes >= state
     assert mem.temp_size_in_bytes < 512 * MIB
+    # the batch comes in as ONE int32 parameter beside the state, and
+    # its 64-bit hashes are rebuilt from two 32-bit segments by shifts
+    # and ORs, which the TPU compiler keeps as the (low, high) pair it
+    # holds a u64 in anyway: no 64-bit bitcast-convert for it to expand
+    # into a loop over the batch
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    params = [ln for ln in entry.splitlines() if " parameter(" in ln]
+    assert len(params) == 3, params
+    assert sum("s32[%d]" % packed_in.shape[0] in ln for ln in params) == 1
+    assert not re.search(r"[us]64\[[^\n]*bitcast-convert", text)
+    assert " while(" not in text
 
 
 def test_pallas_sweep_compiles_to_a_mosaic_kernel(topo, no_compile_cache):
@@ -191,19 +208,18 @@ def test_mesh_sketch_decide_compiles_for_four_chips(topo, no_compile_cache):
     keys = np.random.default_rng(1).integers(
         1, 2**63, 1000, np.int64
     ).astype(np.uint64)
-    _, _, req, groups, e_now = _captured(
+    _, _, packed_in, B, G = _captured(
         small, "_step_sketch", lambda: small.decide_arrays(**_batch(keys))
     )
-    assert req.key_hash.shape[0] == 4  # [n_shards, B_sub]
+    # [n_shards, W]: each shard's sub-batch is one row, `now` included
+    assert packed_in.shape == (4, packed_inputs_width(B, G))
     real = _shapes_only_engine(lambda eng: eng.store_sharding)(
         SHARD_STORE, policy=ShardingPolicy.over_mesh(topo.devices),
         buckets=LADDER, sketch=_sketch(16),
     )
     sharded = NamedSharding(real.mesh, real.policy.request_spec())
-    replicated = NamedSharding(real.mesh, P())
     compiled = real._step_sketch.lower(
-        real.store, real.sketch, _shapes(req, sharded),
-        _shapes(groups, sharded), _shapes(e_now, replicated),
+        real.store, real.sketch, _shapes(packed_in, sharded), B, G
     ).compile()
     mem = compiled.memory_analysis()  # bytes on EACH device
     per_device = 128 * MIB + 16 * MIB
