@@ -1,0 +1,23 @@
+"""Reader kind `prom_sum`: the growth of some /metrics counters over the
+growth of others, inside the window.
+
+spec: {"delta": [name, ...], "per_delta": [name, ...], "scale": 1.0}
+value = sum of the first list's growth / sum of the second's * scale.
+A program that does not export one of the named counters (the parent of
+the PR that added it) reads as nothing, and so does a window in which
+the denominator did not move.
+"""
+
+
+def read(spec: dict, ctx: dict):
+    names = [*spec["delta"], *spec["per_delta"]]
+    if any(name not in ctx["prom1"] for name in names):
+        return None
+
+    def growth(which):
+        return sum(ctx["prom1"][n] - ctx["prom0"].get(n, 0.0) for n in which)
+
+    per = growth(spec["per_delta"])
+    if per <= 0:
+        return None
+    return growth(spec["delta"]) / per * spec.get("scale", 1.0)

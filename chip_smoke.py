@@ -454,15 +454,9 @@ def main(argv=None) -> int:
     if mesh:
         device_env["GUBER_SHARDS"] = "4"
         # the mesh warm-up compiles 27 decide programs (9 sub-rungs x
-        # group rungs) one at a time; with the sketch tier on that is
-        # ~40 minutes of four chips on a cold cache (PERF.md, PR 21).
-        # Cut with the daemon's own documented option, sketch tier
-        # first; the ladder cannot go below the 1000-item RPC cap and
-        # the store is never cut.
-        device_env["GUBER_SKETCH"] = "0"
-        emit(reduced={"GUBER_SKETCH": "0"},
-             why="27 mesh decide programs compile before Ready; the "
-             "exact-only program compiles about twice as fast")
+        # group rungs) side by side since PR 26: 323 s exec -> Ready on
+        # a cold cache with the default sketch tier (PERF.md, PR 26), so
+        # the daemon runs as an operator gets it, nothing cut
     exact_env = {"GUBER_BACKEND": "exact", "JAX_PLATFORMS": "cpu"}
 
     children = []

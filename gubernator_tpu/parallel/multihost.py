@@ -370,6 +370,10 @@ class MultiHostMeshEngine:
         return self.inner.n
 
     @property
+    def flat(self):
+        return self.inner.flat
+
+    @property
     def stats(self):
         return self.inner.stats
 
@@ -533,6 +537,18 @@ class MultiHostMeshEngine:
             return self.inner.update_globals(
                 key_hash, limit, remaining, reset_time, is_over, now=now
             )
+        finally:
+            self._done()
+
+    def compile_ahead(self) -> None:
+        """The warm-up's side-by-side compile (sharded.warmup_public
+        calls this first), in every process at once: compiling is local
+        to a process, and each must hold the programs before the
+        warm-up traffic that follows is replayed to it."""
+        assert self.is_leader
+        self._lockstep({"kind": "compile_ahead"})
+        try:
+            self.inner.compile_ahead()
         finally:
             self._done()
 
@@ -704,6 +720,8 @@ class MultiHostMeshEngine:
                     msg["counts"],
                     msg["now"],
                 )
+            elif kind == "compile_ahead":
+                self.inner.compile_ahead()
             elif kind == "reset":
                 self.inner.reset()
             elif kind == "upsert":

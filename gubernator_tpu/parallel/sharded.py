@@ -34,9 +34,13 @@ carries the host-level ring's.
 
 from __future__ import annotations
 
+import concurrent.futures
 import contextlib
+import ctypes
 import functools
 import logging
+import os
+import time
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -921,9 +925,14 @@ class PartitionedEngine:
     """
 
     #: `with self.stage_span(name):` around the two halves of
-    #: _dispatch. The serving tier installs its stage clock here
-    #: (serve/backends.py); a bare engine times nothing
+    #: _dispatch and the mesh's per-shard build. The serving tier
+    #: installs its stage clock here (serve/backends.py); a bare
+    #: engine times nothing
     stage_span = staticmethod(contextlib.nullcontext)
+    #: `self.shard_counts(rows, slots, max_rows)` once per mesh device
+    #: batch (`_shard_stack`); the serving tier installs its /metrics
+    #: counters here, a bare engine counts nothing
+    shard_counts = staticmethod(lambda rows, slots, max_rows: None)
 
     def __init__(
         self,
@@ -1190,13 +1199,50 @@ class PartitionedEngine:
 
     # -- the one dispatch funnel --------------------------------------------
 
+    def _shard_stack(self, build, *args, **kw):
+        """One mesh device batch's per-shard sub-batches, built by
+        `build` (pad_request_sharded / build_presorted_sharded): the
+        owner presort's contiguous slices padded to one sub-rung and
+        stacked [n_shards, B_sub] — the `shard_stack` stage — and what
+        the zipf skew made of it: rows that carry a request, slots
+        launched (shards x sub-rung), the fullest shard's rows."""
+        with self.stage_span("shard_stack"):
+            out = build(*args, **kw)
+        rows = out[0].valid.sum(axis=1)
+        self.shard_counts(
+            int(rows.sum()), out[0].valid.size, int(rows.max())
+        )
+        return out
+
+    def _decide_call(self, req, groups, e_now):
+        """(program, arguments) of one decide: the batch's host inputs
+        packed into the ONE array that crosses to the device
+        (kernels.pack_inputs) behind the state this engine's layout
+        and tiers donate — the exact-only or two-tier program, flat or
+        mesh. `_dispatch` calls it; the warm-up lowers and compiles it
+        (`compile_ahead`), so the two can never name different
+        programs."""
+        two_tier = self.sketch is not None and self.sketch_on
+        B, G = req.key_hash.shape[-1], groups.key_hash.shape[-1]
+        packed_in = pack_inputs(req, groups, e_now)
+        if self.flat:
+            from gubernator_tpu.core.engine import (
+                _decide_packed_jit,
+                _decide_packed_sketch_jit,
+            )
+
+            fn = _decide_packed_sketch_jit if two_tier else _decide_packed_jit
+        else:
+            fn = self._step_sketch if two_tier else self._step
+        state = (self.store, self.sketch) if two_tier else (self.store,)
+        return fn, (*state, packed_in, B, G)
+
     def _dispatch(self, req, groups, e_now):
         """Every submit path — flat or sharded, flush-prep, arrival-
         prep or merged — ends here: feed the serve-tier hot-key
         observer (numpy fields, pre-device), pack the batch's host
-        inputs into the ONE array that crosses to the device
-        (kernels.pack_inputs) and pick the exact-only or two-tier
-        program for this engine's layout. The hook and the jitted call
+        inputs and pick the program for this engine's layout
+        (`_decide_call`), and call it. The hook and the jitted call
         are the `observe` and `jit_call` stages: what is left of the
         batcher's `dispatch` is pad + group-derive + that pack."""
         hook = self.observe_hook
@@ -1206,34 +1252,12 @@ class PartitionedEngine:
                     hook(req)
                 except Exception:  # pragma: no cover - defensive
                     pass  # observability must never fail a dispatch
-        two_tier = self.sketch is not None and self.sketch_on
-        B, G = req.key_hash.shape[-1], groups.key_hash.shape[-1]
-        packed_in = pack_inputs(req, groups, e_now)
+        fn, args = self._decide_call(req, groups, e_now)
         with self.stage_span("jit_call"):
-            if self.flat:
-                from gubernator_tpu.core.engine import (
-                    _decide_packed_jit,
-                    _decide_packed_sketch_jit,
-                )
-
-                if two_tier:
-                    self.store, self.sketch, packed = (
-                        _decide_packed_sketch_jit(
-                            self.store, self.sketch, packed_in, B, G
-                        )
-                    )
-                else:
-                    self.store, packed = _decide_packed_jit(
-                        self.store, packed_in, B, G
-                    )
-            elif two_tier:
-                self.store, self.sketch, packed = self._step_sketch(
-                    self.store, self.sketch, packed_in, B, G
-                )
-            else:
-                self.store, packed = self._step(
-                    self.store, packed_in, B, G
-                )
+            *state, packed = fn(*args)
+        self.store = state[0]
+        if len(state) == 2:
+            self.sketch = state[1]
         return packed
 
     # -- request-object API --------------------------------------------------
@@ -1327,7 +1351,8 @@ class PartitionedEngine:
                 packed, order, None, n, req.key_hash.shape[0],
                 self.clock.epoch,
             )
-        req, order, take_idx, groups = pad_request_sharded(
+        req, order, take_idx, groups = self._shard_stack(
+            pad_request_sharded,
             self.sub_buckets,
             self.config.slots,
             self.n,
@@ -1489,7 +1514,8 @@ class PartitionedEngine:
             order_p[n:] = np.arange(n, B, dtype=np.int32)
             return dict(req=req, groups=groups, order=order_p, n=n, B=B)
         m = merge_runs(runs)
-        req, take_idx, groups, B_sub = build_presorted_sharded(
+        req, take_idx, groups, B_sub = self._shard_stack(
+            build_presorted_sharded,
             self.sub_buckets, self.config.slots, self.n, m["fields"],
             m["skey"], m["counts"],
         )
@@ -1548,7 +1574,8 @@ class PartitionedEngine:
             order_p[n:] = np.arange(n, B, dtype=np.int32)
             packed = self._dispatch(req, groups, e_now)
             return (packed, order_p, None, n, B, self.clock.epoch)
-        req, take_idx, groups, B_sub = build_presorted_sharded(
+        req, take_idx, groups, B_sub = self._shard_stack(
+            build_presorted_sharded,
             self.sub_buckets, self.config.slots, self.n, fields, skey,
             counts,
         )
@@ -1669,6 +1696,25 @@ class PartitionedEngine:
         out.append(n)
         return tuple(out)
 
+    def _rows_call(self, kh_padded: np.ndarray):
+        """(program, arguments) of the bucket-row gather behind every
+        non-mutating host read, by layout: a plain take (flat), an
+        owner-indexed gather (one-process mesh), or the owner-masked
+        psum collective with a replicated output (a mesh that spans
+        processes: follower shards are not host-addressable)."""
+        from gubernator_tpu.core.store import bucket_index
+
+        b = bucket_index(jnp.asarray(kh_padded), self.config.slots)
+        if self.flat:
+            return _rows_flat, (self.store.data, b)
+        owner = jnp.asarray(owner_of_np(kh_padded, self.n))
+        fn = (
+            self._rows_coll
+            if self.policy.spans_processes
+            else _rows_sharded
+        )
+        return fn, (self.store.data, owner, b)
+
     def _gather_entries(self, kh_padded: np.ndarray) -> np.ndarray:
         """Host np int32[B, ways, LANES]: each key's candidate bucket
         row, gathered from the key's owning shard's store — THE one
@@ -1676,20 +1722,10 @@ class PartitionedEngine:
         shares, so the addressed row can never drift between
         topologies. Non-mutating; same thread contract as
         snapshot_read."""
-        from gubernator_tpu.core.store import LANES, bucket_index
+        from gubernator_tpu.core.store import LANES
 
-        kh = jnp.asarray(kh_padded)
-        b = bucket_index(kh, self.config.slots)
-        if self.flat:
-            rows = _rows_flat(self.store.data, b)
-        elif self.policy.spans_processes:
-            # follower-process shards are not host-addressable: ride
-            # the owner-masked psum collective (replicated output)
-            owner = jnp.asarray(owner_of_np(kh_padded, self.n))
-            rows = self._rows_coll(self.store.data, owner, b)
-        else:
-            owner = jnp.asarray(owner_of_np(kh_padded, self.n))
-            rows = _rows_sharded(self.store.data, owner, b)
+        fn, args = self._rows_call(kh_padded)
+        rows = fn(*args)
         return np.asarray(rows).reshape(kh_padded.shape[0], -1, LANES)
 
     def snapshot_read(
@@ -1910,14 +1946,12 @@ class PartitionedEngine:
         """One padded replica-install call against this policy's
         layout: flat = the donated single-store upsert jit; mesh = the
         owner-masked shard_map upsert collective."""
-        if self.flat:
-            self.store = upsert_globals_jit(
-                self.store, hashes, lim, rem, reset, over, valid
-            )
-        else:
-            self.store = self._upsert(
-                self.store, hashes, lim, rem, reset, over, valid
-            )
+        self.store = self._install_program()(
+            self.store, hashes, lim, rem, reset, over, valid
+        )
+
+    def _install_program(self):
+        return upsert_globals_jit if self.flat else self._upsert
 
     def _upsert_full_padded(self, hashes, lim, rem, reset, dur, ts,
                             flags, valid):
@@ -2060,6 +2094,26 @@ class PartitionedEngine:
             now=now,
         )
 
+    def _sync_call(self, key_hash, hits, limit, duration, algo, e_now):
+        """(program, arguments, pad order) of one mesh sync
+        collective: the keys padded and sorted to their host rung."""
+        n = key_hash.shape[0]
+        req, order = pad_request_sorted(
+            extend_ladder(self.buckets, n),
+            self.config.slots,
+            key_hash,
+            hits,
+            limit,
+            duration,
+            algo,
+            np.zeros(n, bool),
+        )
+        args = (
+            self.store, req.key_hash, req.hits, req.limit, req.duration,
+            req.algo, req.valid, e_now,
+        )
+        return self._sync, args, order
+
     def _sync_padded(self, key_hash, hits, limit, duration, algo, now):
         """One padded owner-charge + psum-replicate + replica-install
         collective step; returns the padded sorted-order responses and
@@ -2107,26 +2161,10 @@ class PartitionedEngine:
                 self.stats = stats
         if n > max(self.buckets):
             _warn_ladder_overflow(max(self.buckets), n)
-        req, order = pad_request_sorted(
-            extend_ladder(self.buckets, n),
-            self.config.slots,
-            key_hash,
-            hits,
-            limit,
-            duration,
-            algo,
-            np.zeros(n, bool),
+        fn, args, order = self._sync_call(
+            key_hash, hits, limit, duration, algo, e_now
         )
-        self.store, resp = self._sync(
-            self.store,
-            req.key_hash,
-            req.hits,
-            req.limit,
-            req.duration,
-            req.algo,
-            req.valid,
-            e_now,
-        )
+        self.store, resp = fn(*args)
         return resp, order
 
     def sync_globals(
@@ -2204,6 +2242,20 @@ class PartitionedEngine:
         wend_engine = (wid + 1) * d
         return wid, np.asarray(self.clock.from_engine(wend_engine))
 
+    def _sketch_min_call(self, kh: np.ndarray, idx: np.ndarray):
+        """(program, arguments) of the count-min row-min read, by
+        layout like `_rows_call`."""
+        idx = jnp.asarray(idx)
+        if self.flat:
+            return _sketch_min_flat, (self.sketch.data, idx)
+        owner = jnp.asarray(owner_of_np(kh, self.n))
+        fn = (
+            self._sketch_min_coll
+            if self.policy.spans_processes
+            else _sketch_min_sharded
+        )
+        return fn, (self.sketch.data, owner, idx)
+
     def sketch_estimates(
         self,
         key_hash: np.ndarray,
@@ -2229,18 +2281,8 @@ class PartitionedEngine:
         )
         wid, _ = self._sketch_windows(dur, now)
         idx = sketch_indices_np(kh, wid, self.sketch_config)
-        if self.flat:
-            est = _sketch_min_flat(self.sketch.data, jnp.asarray(idx))
-        elif self.policy.spans_processes:
-            owner = jnp.asarray(owner_of_np(kh, self.n))
-            est = self._sketch_min_coll(
-                self.sketch.data, owner, jnp.asarray(idx)
-            )
-        else:
-            owner = jnp.asarray(owner_of_np(kh, self.n))
-            est = _sketch_min_sharded(
-                self.sketch.data, owner, jnp.asarray(idx)
-            )
+        fn, args = self._sketch_min_call(kh, idx)
+        est = fn(*args)
         return np.asarray(est, np.int64)[:n]
 
     def promote_from_sketch(
@@ -2290,99 +2332,181 @@ class PartitionedEngine:
         serving submit thread."""
         if self.sketch is None:
             return
-        for B in (64, 128, 256, 512, 1024):
+        for B in SKETCH_READ_RUNGS:
             kh = np.arange(1, B + 1, dtype=np.uint64) << np.uint64(32)
             durs = np.full(B, 1000, np.int64)
             self.sketch_estimates(kh, durs, now)
             self.live_mask(kh, now)
 
-    def warmup(self, now: Optional[int] = None) -> None:
-        """Pre-compile every (batch rung, group rung) program plus the
-        GLOBAL install/sync programs (one decide program is one to four
-        minutes of TPU compile on a cold cache, CHANGES.md PR 21; none
-        of it may land inside a serving RPC deadline), then reset the
-        state the warmup traffic dirtied. Mesh policies additionally
-        walk the per-shard sub-rung ladder with batches crafted so
-        every shard hits every rung. NOTE: this drives the engine's own
-        methods — the multihost lockstep wrapper must run its own
-        warmup through its broadcasting public surface
-        (parallel/multihost.py)."""
-        from gubernator_tpu.api.types import RateLimitResp
+    def compile_ahead(self) -> None:
+        """Lower and compile SIDE BY SIDE every program the warm-up
+        traffic (`warmup_public`) is about to call: one decide program
+        per (rung, group rung) — one to four minutes of TPU compile
+        each on a cold cache, one at a time 14 to 17 minutes of a boot
+        (CHANGES.md, PR 21) — the mesh's sync collective and the
+        GLOBAL install per host rung, and the promoter's host reads.
+        XLA compiles with the GIL released, so a thread a program, as
+        many at once as the host has cores, the slowest (largest group
+        rung) first. Each is lowered from the very (program, arguments)
+        the serving call makes (`_decide_call`, `_sync_call`, ...),
+        state arrays included — lowering reads their shapes and
+        shardings and donates nothing — so the traffic that follows
+        finds the same executables, in this process's jit cache or in
+        the persistent compile cache, and compiles nothing."""
+        from gubernator_tpu.core.sketches import sketch_indices_np
 
-        if now is None:
-            now = api_types.millisecond_now()
-        if self.flat:
-            from gubernator_tpu.core.engine import group_rungs
-
-            for b in self.buckets:
-                # one XLA program per (request rung, group rung) pair:
-                # craft batches whose unique-key count hits each group
-                # rung, with distinct FINGERPRINTS (value << 32)
-                for g in group_rungs(b):
-                    k = np.resize(
-                        np.arange(1, g + 1, dtype=np.uint64)
-                        << np.uint64(32),
-                        b,
-                    )
-                    ones = np.ones(b, np.int64)
-                    self.decide_arrays(
-                        k, ones, ones * 10, ones * 1000,
-                        np.zeros(b, np.int32), np.zeros(b, bool), now,
-                    )
-                # the GLOBAL replica-install path is a separate XLA
-                # program and must not pay jit time inside a broadcast
-                # RPC deadline either
-                self.update_globals(
-                    [
-                        (f"warmup:{i}", RateLimitResp(limit=1))
-                        for i in range(b)
-                    ],
-                    now=now,
+        e_now = np.int32(1)
+        jobs = []
+        for batch in warmup_batches(self):
+            if self.flat:
+                req, _, groups = pad_request_sorted(
+                    self.buckets, self.config.slots, **batch,
+                    with_groups=True,
                 )
-            self._warmup_sketch_reads(now)
-            self.reset()
-            self.stats = EngineStats()
-            return
+            else:
+                req, _, _, groups = pad_request_sharded(
+                    self.sub_buckets, self.config.slots, self.n,
+                    **batch, with_groups=True,
+                )
+            jobs.append(self._decide_call(req, groups, e_now))
+        # compile time follows the group rung (the last argument)
+        jobs.sort(key=lambda job: -job[1][-1])
+        for b in self.buckets:
+            k = np.arange(1, b + 1, dtype=np.uint64)
+            ones = np.ones(b, np.int64)
+            if not self.flat:  # flat: the sync IS a decide, above
+                jobs.append(
+                    self._sync_call(
+                        k, ones * 0, ones, ones * 1000,
+                        np.zeros(b, np.int32), e_now,
+                    )[:2]
+                )
+            z = np.zeros(b, np.int32)
+            cols = pad_to_bucket(
+                self.buckets, b, (k, np.uint64), (z, np.int32),
+                (z, np.int32), (z, np.int32), (np.zeros(b, bool), bool),
+            )
+            jobs.append((self._install_program(), (self.store, *cols)))
+        if self.sketch is not None:
+            for B in SKETCH_READ_RUNGS:
+                kh = np.arange(1, B + 1, dtype=np.uint64) << np.uint64(32)
+                idx = sketch_indices_np(
+                    kh, np.zeros(B, np.int64), self.sketch_config
+                )
+                jobs.append(self._sketch_min_call(kh, idx))
+                jobs.append(self._rows_call(kh))
+        workers = min(len(jobs), len(os.sched_getaffinity(0)))
+        t = time.monotonic()
+        with concurrent.futures.ThreadPoolExecutor(
+            workers, thread_name_prefix="guber-compile"
+        ) as pool:
+            for done in [
+                pool.submit(lambda fn, args: fn.lower(*args).compile(),
+                            fn, args)
+                for fn, args in jobs
+            ]:
+                done.result()
+        took = time.monotonic() - t
+        _trim_heap()
+        _log.info(
+            "warm-up: %d programs compiled side by side on %d threads "
+            "in %.1f s, heap trimmed in %.1f s", len(jobs), workers, took,
+            time.monotonic() - t - took,
+        )
+
+    def warmup(self, now: Optional[int] = None) -> None:
+        """Pre-compile every (batch rung, group rung) decide program
+        plus the GLOBAL install/sync programs and the promoter's reads
+        — none of it may land inside a serving RPC deadline — then
+        reset the state the warm-up traffic dirtied. One body for
+        every layout: `warmup_public`. NOTE: this drives the engine's
+        own methods — the multihost lockstep wrapper runs the same body
+        through its broadcasting public surface
+        (parallel/multihost.py)."""
         warmup_public(self, now)
 
 
-def warmup_public(engine, now: Optional[int] = None) -> None:
-    """Mesh warmup through an engine-like object's PUBLIC surface
-    (decide_arrays / update_globals / sync_globals / reset): compiles
-    every (sub-batch rung, group rung) program plus the collective
-    GLOBAL programs. Driving only the public surface is what makes it
-    lockstep-safe for the multihost wrapper — every call broadcasts,
-    so followers replay the identical compile sequence. The ONE warmup
-    body for PartitionedEngine's mesh branch and the serving
-    MeshBackend/MultiHostBackend (serve/backends.py), so the compile
-    coverage cannot drift between the library and serving tiers."""
+def _trim_heap() -> None:
+    """Hand the allocator's free memory back to the OS (glibc's
+    malloc_trim; a no-op elsewhere). A dozen threads that each loaded
+    or compiled a program leave as many malloc arenas full of freed
+    blocks, and the first burst of served traffic then paid for their
+    consolidation with the GIL held: every gRPC call of the first five
+    seconds waited up to a second, and `call_p50_ms` read +12% (chip
+    runs, PR 26: with the trim 16.7 ms, without 20.7, one compile
+    thread 17.3). The boot pays it instead."""
+    try:
+        ctypes.CDLL(None).malloc_trim(0)
+    except (OSError, AttributeError):
+        pass
+
+
+#: pow2 batch sizes the promoter's host reads are warmed at
+SKETCH_READ_RUNGS = (64, 128, 256, 512, 1024)
+
+
+def warmup_batches(engine) -> list:
+    """One crafted batch per decide program, i.e. per (rung, group
+    rung) pair, as decide_arrays' keywords less `now`. Flat: rung b with
+    g unique keys of distinct FINGERPRINTS (value << 32). Mesh: every
+    shard draws sub-rung r with g unique keys of its own (g == r is the
+    all-unique case), so every shard hits every rung."""
     from gubernator_tpu.core.engine import group_rungs
 
+    if engine.flat:
+        keys = [
+            np.resize(
+                np.arange(1, g + 1, dtype=np.uint64) << np.uint64(32), b
+            )
+            for b in engine.buckets
+            for g in group_rungs(b)
+        ]
+    else:
+        n = engine.n
+        rungs = engine.sub_buckets
+        rng = np.random.default_rng(0xB007)
+        pool = rng.integers(
+            1, 2**63, 4 * n * max(rungs), np.int64
+        ).astype(np.uint64)
+        owners = owner_of_np(pool, n)
+        per_shard = [pool[owners == s] for s in range(n)]
+        keys = [
+            np.concatenate([np.resize(p[:g], r) for p in per_shard])
+            for r in rungs
+            for g in group_rungs(r)
+        ]
+    out = []
+    for k in keys:
+        ones = np.ones(k.shape[0], np.int64)
+        out.append(dict(
+            key_hash=k, hits=ones, limit=ones * 10, duration=ones * 1000,
+            algo=np.zeros(k.shape[0], np.int32),
+            gnp=np.zeros(k.shape[0], bool),
+        ))
+    return out
+
+
+def warmup_public(engine, now: Optional[int] = None) -> None:
+    """THE warm-up, for every layout, through an engine-like object's
+    PUBLIC surface (compile_ahead / decide_arrays / update_globals /
+    sync_globals / reset): first every program compiles side by side
+    (`PartitionedEngine.compile_ahead`), then one call of each runs —
+    a decide per (rung, group rung), the GLOBAL install and the gossip
+    collective per host rung, the promoter's reads — and finds it
+    compiled; then state and counters are reset. Driving only the
+    public surface is what makes it lockstep-safe for the multihost
+    wrapper — every call broadcasts, so followers compile and replay
+    the identical sequence. The ONE warm-up body for PartitionedEngine
+    and the serving MeshBackend/MultiHostBackend (serve/backends.py),
+    so the compile coverage cannot drift between the library and
+    serving tiers."""
     if now is None:
         now = api_types.millisecond_now()
-    n = engine.n
-    rungs = engine.sub_buckets
-    rng = np.random.default_rng(0xB007)
-    pool = rng.integers(1, 2**63, 4 * n * max(rungs), np.int64).astype(
-        np.uint64
-    )
-    owners = owner_of_np(pool, n)
-    per_shard = [pool[owners == s] for s in range(n)]
-    for r in rungs:
-        # one XLA program per (sub-batch rung, group rung) pair: craft
-        # per-shard batches whose unique-key count hits each group rung
-        # (g == r is the all-unique case)
-        for g in group_rungs(r):
-            k = np.concatenate([np.resize(p[:g], r) for p in per_shard])
-            ones = np.ones(k.shape[0], np.int64)
-            engine.decide_arrays(
-                key_hash=k, hits=ones, limit=ones * 10,
-                duration=ones * 1000,
-                algo=np.zeros(k.shape[0], np.int32),
-                gnp=np.zeros(k.shape[0], bool),
-                now=now,
-            )
-    # broadcast-receive + gossip collective programs per host rung
+    engine.compile_ahead()
+    for batch in warmup_batches(engine):
+        engine.decide_arrays(now=now, **batch)
+    # broadcast-receive + gossip collective programs per host rung:
+    # neither may pay jit time inside a broadcast RPC deadline
     for b in engine.buckets:
         k = np.arange(1, b + 1, dtype=np.uint64)
         ones = np.ones(b, np.int64)

@@ -41,11 +41,21 @@ from gubernator_tpu.core.engine import TpuEngine
 from gubernator_tpu.core.oracle import get_rate_limit
 from gubernator_tpu.core.store import StoreConfig
 from gubernator_tpu.parallel.sharded import PartitionedEngine
+from gubernator_tpu.serve import metrics
 from gubernator_tpu.serve.stages import STAGES
 
+
+def _count_shards(rows: int, slots: int, max_rows: int) -> None:
+    metrics.MESH_SHARD_ROWS.inc(rows)
+    metrics.MESH_SHARD_SLOTS.inc(slots)
+    metrics.MESH_SHARD_MAX_ROWS.inc(max_rows)
+
+
 # every engine a backend serves from times its dispatch interior
-# (observe, jit_call) on the serving tier's stage clock
+# (observe, jit_call, shard_stack) on the serving tier's stage clock
+# and counts its per-shard batches into /metrics
 PartitionedEngine.stage_span = staticmethod(STAGES.span)
+PartitionedEngine.shard_counts = staticmethod(_count_shards)
 
 
 def chain_level_keys(r: RateLimitReq):

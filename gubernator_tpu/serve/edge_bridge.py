@@ -911,6 +911,7 @@ class FrameService:
         with STAGES.span("bridge_decode"):
             decoded = decoder(payload, n)
         good = [r for r in decoded if r is not None]
+        metrics.EDGE_OBJECT_ITEMS.inc(n)
         # the edge caps frames at its batch limit, but two large
         # co-batched requests can still exceed the instance's
         # MAX_BATCH_SIZE — split instead of erroring the frame

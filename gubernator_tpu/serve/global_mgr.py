@@ -30,9 +30,12 @@ from gubernator_tpu.serve.metrics import (
     GLOBAL_ASYNC_DURATIONS,
     GLOBAL_BACKLOG_DROPPED,
     GLOBAL_BROADCAST_DURATIONS,
+    GLOBAL_BROADCAST_KEYS,
     GLOBAL_FLUSH_BYTES,
+    GLOBAL_PEEK_ROWS,
     GLOBAL_TASK_RESTARTS,
 )
+from gubernator_tpu.serve.stages import STAGES
 
 log = logging.getLogger("gubernator_tpu.global")
 
@@ -372,13 +375,19 @@ class GlobalManager:
             peek = replace(r, hits=0, behavior=Behavior.BATCHING)
             peek_reqs.append(peek)
             keys.append(key)
+        t_peek = time.monotonic()
         try:
+            GLOBAL_PEEK_ROWS.inc(len(peek_reqs))
             statuses = await self.instance.decide_local(
                 peek_reqs, gnp=[False] * len(peek_reqs)
             )
             globals_batch = list(zip(keys, statuses))
+            GLOBAL_BROADCAST_KEYS.inc(len(globals_batch))
         except Exception as e:
             log.error("while peeking global statuses: %s", e)
+        # the peek crosses an await (queue + device + fetch of the
+        # batcher), so it is a bare stamp pair on the stage clock
+        STAGES.add("global_peek", time.monotonic() - t_peek)
 
         hops_mesh = 0
         sends = []

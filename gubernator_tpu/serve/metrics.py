@@ -67,6 +67,27 @@ DEVICE_BATCH_SLOTS = Counter(
     "slots that carried a request",
     registry=REGISTRY,
 )
+MESH_SHARD_ROWS = Counter(
+    "mesh_shard_rows_total",
+    "Mesh backend: rows that carried a request, summed over the shards "
+    "of every device batch. Over mesh_shard_slots_total it is the share "
+    "of launched per-shard slots that did useful work",
+    registry=REGISTRY,
+)
+MESH_SHARD_SLOTS = Counter(
+    "mesh_shard_slots_total",
+    "Mesh backend: padded rows launched, shards x the sub-rung every "
+    "shard of a device batch is padded to (the fullest shard picks it)",
+    registry=REGISTRY,
+)
+MESH_SHARD_MAX_ROWS = Counter(
+    "mesh_shard_max_rows_total",
+    "Mesh backend: the fullest shard's rows, summed over device batches. "
+    "Times the shard count over mesh_shard_rows_total it is the skew: "
+    "1 when every shard draws the same, the shard count when one shard "
+    "draws everything",
+    registry=REGISTRY,
+)
 DEVICE_LAUNCH_MS = Histogram(
     "device_launch_milliseconds",
     "Wall time of one decide kernel launch (host-observed)",
@@ -98,6 +119,15 @@ EDGE_FOLDED_ITEMS = Counter(
     "String-frame items served through the bridge's string->array fold "
     "(all-plain all-owned frames skip request/response objects and "
     "instance routing) — the slow path's share of fast-path treatment",
+    registry=REGISTRY,
+)
+EDGE_OBJECT_ITEMS = Counter(
+    "edge_object_items_total",
+    "String-frame items the bridge served through request/response "
+    "objects and Instance.get_rate_limits: frames the array fold "
+    "declined (one GLOBAL, chained, invalid or foreign-owned item sends "
+    "the whole frame here). With edge_fast_items_total and "
+    "edge_folded_items_total it splits every bridge item by path",
     registry=REGISTRY,
 )
 EDGE_STALE_RINGS = Counter(
@@ -226,6 +256,20 @@ GLOBAL_BACKLOG_DROPPED = Counter(
     "grows the hit backlog without bound); labelled by queue (hits | "
     "updates)",
     ["queue"],
+    registry=REGISTRY,
+)
+GLOBAL_BROADCAST_KEYS = Counter(
+    "global_broadcast_keys_total",
+    "Owned GLOBAL keys whose authoritative status one broadcast flush "
+    "peeked and held ready for this node's peers (sent to none where "
+    "the node has no peer)",
+    registry=REGISTRY,
+)
+GLOBAL_PEEK_ROWS = Counter(
+    "global_peek_rows_total",
+    "Zero-hit rows the owner broadcast's status peeks put through the "
+    "device batcher (one a queued key a flush): device rows no client "
+    "asked for",
     registry=REGISTRY,
 )
 REPLICATION_SNAPSHOTS_SENT = Counter(

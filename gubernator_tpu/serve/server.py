@@ -11,6 +11,7 @@ Instance.set_peers, and graceful shutdown.
 from __future__ import annotations
 
 import asyncio
+import gc
 import os
 import json
 import logging
@@ -39,6 +40,35 @@ from gubernator_tpu.serve.stages import (
 )
 
 log = logging.getLogger("gubernator_tpu.server")
+
+#: middle collections between two full ones once the daemon is Ready
+#: (CPython's default is 10; see settle_collector)
+FULL_COLLECTION_EVERY = 1000
+
+
+def settle_collector() -> None:
+    """The cyclic collector's settings for a daemon that is about to
+    say Ready.
+
+    What the boot built lives as long as the process — the traced
+    programs alone are ~10^6 Python objects — and every full collection
+    walked all of it: 106-128 ms each on the chip's host (PERF.md,
+    PR 24), longer still once the warm-up traced its programs on a
+    dozen threads (PR 26). `gc.freeze()` puts it out of the collector's
+    sight.
+
+    With the old generation emptied, CPython's brake on full
+    collections (only once the young survivors outgrow a quarter of it)
+    holds nothing back: they ran every tenth middle collection, 1.6
+    times a second under 1000-item string frames, each walking every
+    request and response in flight for ~0.1 s — a pause as long as a
+    10-per-second leaky bucket's tick, which every starved hot key
+    turned into an extra token (PERF.md, PR 26). Served requests die by
+    reference count; cycles that reach the old generation can wait a
+    hundred times longer."""
+    gc.collect()
+    gc.freeze()
+    gc.set_threshold(*gc.get_threshold()[:2], FULL_COLLECTION_EVERY)
 
 
 def make_backend(conf: ServerConfig):
@@ -1322,6 +1352,7 @@ async def run_daemon(conf: ServerConfig) -> None:
 
     server = Server(conf)
     await server.start()
+    settle_collector()
     log.info("Ready")
     # the stage clock starts at Ready: warm-up ran every rung through
     # the engine's dispatch, and its jit_call spans are compiles

@@ -68,7 +68,22 @@ Stages form two families:
                      fields, inside dispatch; dispatch - jit_call -
                      observe is pad + group-derive + the input pack
                      (kernels.pack_inputs), by subtraction
+    shard_stack      mesh backends only: the owner presort's
+                     per-shard slices padded to one sub-rung and
+                     stacked [n_shards, B_sub] with their group
+                     structure (PartitionedEngine._shard_stack) —
+                     inside merge on the arrival-prep path, inside
+                     dispatch on the flush-time path (where a native
+                     prep fuses the presort into it)
     fetch_wait       decide_wait* span on the fetch pool
+
+- **per-flush stages** (`PER_FLUSH`): the GLOBAL gossip loops, one
+  sample a flush.
+
+    global_peek      one owner broadcast's status peek
+                     (GlobalManager._update_peers): the zero-hit
+                     decide_local of every queued key, queue + device
+                     + fetch of the batcher included
 
 - **per-call stages** (`PER_CALL`): the gRPC door's family. The six
   `CALL_TILES` tile one GetRateLimits / GetPeerRateLimits call from
@@ -121,7 +136,7 @@ on the profiler's clock beside the device's XLA Ops. This module
 never imports JAX itself (the JAX-free client tier imports
 serve/tracing.py, and through it this). Spans that cross an `await`
 or belong to no thread (batch_queue, device, call_queue, call_device,
-call_wake, call_e2e),
+call_wake, call_e2e, global_peek),
 and the per-call ones recorded from bare stamps on the serving loop
 (grpc_decode, instance_route, grpc_encode: tens of microseconds
 each, and a span object a call is not free there), stay on the stage
@@ -167,8 +182,10 @@ PER_BATCH = (
     "dispatch",
     "jit_call",
     "observe",
+    "shard_stack",
     "fetch_wait",
 )
+PER_FLUSH = ("global_peek",)
 CALL_TILES = (
     "grpc_decode",
     "instance_route",
@@ -356,6 +373,7 @@ class StageStats:
             "per_frame_stages": list(PER_FRAME),
             "per_batch_stages": list(PER_BATCH),
             "per_call_stages": list(PER_CALL),
+            "per_flush_stages": list(PER_FLUSH),
             "per_process_stages": list(PER_PROCESS),
             "bucket_edges_s": list(BUCKET_EDGES_S),
             "frames": frames,

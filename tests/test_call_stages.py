@@ -415,3 +415,24 @@ def test_process_probes_record_loop_lag_and_gc_pauses():
     assert got["loop_lag"]["count"] >= 5
     assert got["gc_pause"]["count"] >= 1
     assert got["gc_pause"]["total_s"] < 5.0
+
+
+def test_the_daemon_settles_the_collector_before_ready():
+    """In a child, so this process keeps its own collector: what the
+    boot built is frozen, the young thresholds stay CPython's, and a
+    full collection waits for a thousand middle ones."""
+    code = (
+        "import gc\n"
+        "from gubernator_tpu.serve import server\n"
+        "young = gc.get_threshold()[:2]\n"
+        "keep = [[] for _ in range(1000)]\n"
+        "server.settle_collector()\n"
+        "assert gc.get_freeze_count() >= 1000\n"
+        "assert gc.get_threshold() == (*young, server.FULL_COLLECTION_EVERY)\n"
+        "assert server.FULL_COLLECTION_EVERY == 1000\n"
+    )
+    p = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert p.returncode == 0, p.stderr[-2000:]

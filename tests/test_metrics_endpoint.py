@@ -135,10 +135,14 @@ def test_metrics_endpoint_families_and_label_cardinality():
         from gubernator_tpu.serve.stages import (
             PER_BATCH,
             PER_CALL,
+            PER_FLUSH,
             PER_FRAME,
         )
 
-        known = set(PER_FRAME) | set(PER_BATCH) | set(PER_CALL)
+        known = (
+            set(PER_FRAME) | set(PER_BATCH) | set(PER_CALL)
+            | set(PER_FLUSH)
+        )
         stages = {
             s.labels["stage"]
             for s in fams["serving_stage_seconds_total"].samples

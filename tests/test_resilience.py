@@ -468,10 +468,13 @@ def test_batcher_drain_waits_for_inflight_work():
     from gubernator_tpu.serve.batcher import DeviceBatcher
 
     class _SlowBackend:
+        decided = 0
+
         def decide(self, reqs, gnp):
             import time
 
             time.sleep(0.05)
+            self.decided += len(reqs)
             return [RateLimitResp(limit=r.limit, remaining=1)
                     for r in reqs]
 
@@ -479,14 +482,21 @@ def test_batcher_drain_waits_for_inflight_work():
             pass
 
     async def run():
-        b = DeviceBatcher(_SlowBackend(), batch_wait=0.0)
+        backend = _SlowBackend()
+        b = DeviceBatcher(backend, batch_wait=0.0)
         b.start()
         futs = [asyncio.ensure_future(b.decide([_req(key=f"d{i}")],
                                                [False]))
                 for i in range(4)]
         await asyncio.sleep(0)  # let them enqueue
         await asyncio.wait_for(b.drain(), 10)
-        assert all(f.done() for f in futs)
+        # drain returned: the backend has decided everything that was
+        # in flight and every result is set. The callers' coroutines
+        # resume on the loop's next turns (on a loaded host not yet:
+        # this read False once in the driver's run of PR 26)
+        assert backend.decided == 4
+        _, pending = await asyncio.wait(futs, timeout=5)
+        assert not pending
         for f in futs:
             assert (await f)[0].remaining == 1
         await b.stop()
