@@ -938,17 +938,26 @@ class FrameService:
     def _fold_string_frame(self, payload: bytes, n: int):
         """Lean parse + eligibility screen for the string->array fold
         (r7 slow-path owner batching, bridge side). When EVERY item in
-        a string frame is plain (BATCHING/NO_BATCHING, non-empty UTF-8
-        name/key) and owned by this node under the current ring, the
-        frame needs no request/response objects and no instance
-        routing: it rides the same array path as pre-hashed frames.
-        Per-owner slow shards from the edge are all-plain all-owned by
+        a string frame is valid (non-empty UTF-8 name/key) and owned
+        by this node under the current ring, the frame needs no
+        request/response objects and no instance routing: it rides
+        the same array path as pre-hashed frames, whatever mix of
+        BATCHING / NO_BATCHING / GLOBAL it holds — on the node that
+        owns its key a GLOBAL item asks for one thing beside the
+        decide, the owner's status broadcast, which
+        _decide_string_folded queues (gubernator.go:240-242).
+        Per-owner slow shards from the edge are all-owned by
         construction, so the GUBER_EDGE_FAST=0 kill switch and mixed
         fleets get fast-path treatment minus only the client-side
-        hashing. Returns (full_keys, fields) or None; None falls back
-        to the object path, which keeps full semantics for GLOBAL
-        items, per-item validation errors, and items a stale edge
-        routed to the wrong owner (forwarded by the instance there).
+        hashing. Returns (full_keys, fields, glob, route_s) or None:
+        `glob` is [(index, name, unique_key)] of the GLOBAL items in
+        frame order, `route_s` the seconds of the ownership screen and
+        the key hashing (the Instance's share of the work, stamped
+        `instance_route` by the caller). None falls back to the object
+        path, which keeps full semantics for per-item validation
+        errors and for ANY item this node does not own: a stale edge's
+        plain item is forwarded by the instance there, a non-owner's
+        GLOBAL item answered from the replica and its hit queued.
         """
         import numpy as np
 
@@ -964,11 +973,13 @@ class FrameService:
         if n > len(payload) // 30:
             return None
         full: List[str] = []
+        glob: List[tuple] = []
         hits = np.empty(n, np.int64)
         limit = np.empty(n, np.int64)
         duration = np.empty(n, np.int64)
         algo = np.empty(n, np.int64)
         off = 0
+        route_s = 0.0
         # ownership is screened in chunks DURING the parse: a mixed-
         # ownership frame (pre-r7 edge funnelling a cluster's items
         # through one node) is near-certain to fail within its first
@@ -978,8 +989,10 @@ class FrameService:
         try:
             for i in range(n):
                 if i - checked >= 256:
+                    t0 = time.monotonic()
                     if not mask_fn(full[checked:]).all():
                         return None
+                    route_s += time.monotonic() - t0
                     checked = i
                 (nlen,) = struct.unpack_from("<H", payload, off)
                 off += 2
@@ -996,10 +1009,13 @@ class FrameService:
                     or len(raw_key) != klen
                     or not raw_name
                     or not raw_key
-                    or b == 2
                 ):
-                    return None  # truncated/invalid/GLOBAL: object path
-                full.append(raw_name.decode() + "_" + raw_key.decode())
+                    return None  # truncated/invalid: object path
+                name = raw_name.decode()
+                key = raw_key.decode()
+                full.append(name + "_" + key)
+                if b == 2:
+                    glob.append((i, name, key))
                 hits[i] = h
                 limit[i] = li
                 duration[i] = d
@@ -1008,6 +1024,7 @@ class FrameService:
             return None  # malformed frame: the object path answers it
         if off != len(payload):
             return None
+        t0 = time.monotonic()
         if full[checked:] and not mask_fn(full[checked:]).all():
             return None
         fields = dict(
@@ -1019,15 +1036,26 @@ class FrameService:
             # decode_request_frame and the JSON gateway
             algo=np.where(algo <= 3, algo, 0).astype(np.int32),
         )
-        return full, fields
+        route_s += time.monotonic() - t0
+        return full, fields, glob, route_s
 
-    async def _decide_string_folded(self, full, fields, n: int) -> bytes:
+    async def _decide_string_folded(
+        self, full, fields, glob, route_s: float, n: int
+    ) -> bytes:
         """Array-decide one folded string frame and encode the GEB3/
         GEB4 response body (25-byte decisions + empty error/owner) in
-        one numpy pass. Hot-key observability keeps full parity with
-        the object path: names AND hashes feed the sketches."""
+        one numpy pass. Before the decide it does for the frame what
+        the owner branch of Instance.get_rate_limits does item by
+        item: hot-key observability keeps full parity with the object
+        path (names AND hashes feed the sketches), the three managers
+        note the owned windows, and every GLOBAL item — shed-answered
+        or device-decided alike — queues its key's status broadcast.
+        That work and the fold's ownership screen and key hashing
+        (`route_s`) are the frame's ONE `instance_route` sample, as
+        they are on the object path."""
         import numpy as np
 
+        t_route0 = time.monotonic()
         self.instance.traffic.observe(full, fields["key_hash"])
         repl = getattr(self.instance, "repl", None)
         resc = getattr(self.instance, "rescale", None)
@@ -1036,9 +1064,9 @@ class FrameService:
             # folded frames are all-owned by construction: their
             # windows must dirty the replication queue and join the
             # rescale/checkpoint tracked sets like any other owner
-            # decide (pre-hashed fast frames carry no key strings and
-            # cannot — documented scope limit). One eligibility screen
-            # feeds all three managers.
+            # decide, GLOBAL items included (pre-hashed fast frames
+            # carry no key strings and cannot — documented scope
+            # limit). One eligibility screen feeds all three managers.
             from gubernator_tpu.serve.replication import (
                 eligible_field_indices,
             )
@@ -1050,6 +1078,14 @@ class FrameService:
                 resc.note_owned_fields(full, fields, elig=elig)
             if ckpt is not None:
                 ckpt.note_owned_fields(full, fields, elig=elig)
+        if glob:
+            metrics.EDGE_FOLDED_GLOBAL_ITEMS.inc(len(glob))
+            self.instance.global_mgr.queue_update_fields(
+                full, glob, fields
+            )
+        STAGES.add(
+            "instance_route", route_s + time.monotonic() - t_route0
+        )
         status, limit, remaining, reset = (
             await self._decide_arrays_shed(fields, n)
         )
@@ -1072,12 +1108,20 @@ class FrameService:
         if self.string_fold and n and self._arrays_ok():
             fold = self._fold_string_frame(payload, n)
         if fold is not None:
+            full, fields, glob, route_s = fold
             metrics.EDGE_FOLDED_ITEMS.inc(n)
-            STAGES.add("bridge_decode", time.monotonic() - t_dec)
+            # the lean parse alone: the fold's ownership screen and
+            # key hashing are stamped with the rest of the frame's
+            # routing work (_decide_string_folded)
+            STAGES.add(
+                "bridge_decode", time.monotonic() - t_dec - route_s
+            )
             hdr = _HDR.pack(magic, n)
             if frame_id is not None:
                 hdr += struct.pack("<I", frame_id)
-            return hdr + await self._decide_string_folded(*fold, n)
+            return hdr + await self._decide_string_folded(
+                full, fields, glob, route_s, n
+            )
         resps = await self._decide_string(payload, n)
         with STAGES.span("encode"):
             return encode_response_frame(

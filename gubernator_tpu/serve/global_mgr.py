@@ -24,7 +24,7 @@ import time
 from dataclasses import replace
 from typing import Dict, Optional
 
-from gubernator_tpu.api.types import Behavior, RateLimitReq
+from gubernator_tpu.api.types import Algorithm, Behavior, RateLimitReq
 from gubernator_tpu.serve.config import BehaviorConfig
 from gubernator_tpu.serve.metrics import (
     GLOBAL_ASYNC_DURATIONS,
@@ -174,6 +174,34 @@ class GlobalManager:
             return
         self._updates[key] = replace(r)
         self._updates_event.set()
+
+    def queue_update_fields(self, keys, glob, fields) -> None:
+        """Array entry point of queue_update (edge_bridge string->array
+        fold): one all-owned folded frame's hash keys, the
+        (index, name, unique_key) of its GLOBAL items in frame order,
+        and its dense field arrays. One request per DISTINCT key, the
+        frame's last item winning as repeated queue_update calls would
+        leave it, holding what _update_peers' zero-hit peek reads; the
+        same bound, drop counter and wake-up as queue_update."""
+        last = {keys[i]: (i, name, ukey) for i, name, ukey in glob}
+        updates = self._updates
+        for key, (i, name, ukey) in last.items():
+            if key not in updates and (
+                len(updates) >= self.conf.global_backlog
+            ):
+                self._drop("updates")
+                continue
+            updates[key] = RateLimitReq(
+                name=name,
+                unique_key=ukey,
+                hits=int(fields["hits"][i]),
+                limit=int(fields["limit"][i]),
+                duration=int(fields["duration"][i]),
+                algorithm=Algorithm(int(fields["algo"][i])),
+                behavior=Behavior.GLOBAL,
+            )
+        if updates:  # all dropped = a full backlog, which woke the loop
+            self._updates_event.set()
 
     def _drop(self, queue: str) -> None:
         self._dropped[queue] += 1

@@ -117,16 +117,23 @@ EDGE_FAST_ITEMS = Counter(
 EDGE_FOLDED_ITEMS = Counter(
     "edge_folded_items_total",
     "String-frame items served through the bridge's string->array fold "
-    "(all-plain all-owned frames skip request/response objects and "
+    "(all-valid all-owned frames skip request/response objects and "
     "instance routing) — the slow path's share of fast-path treatment",
+    registry=REGISTRY,
+)
+EDGE_FOLDED_GLOBAL_ITEMS = Counter(
+    "edge_folded_global_items_total",
+    "Items of Behavior GLOBAL among edge_folded_items_total: owned by "
+    "this node, decided on the array path with the rest of their frame, "
+    "their keys queued for the owner's status broadcast",
     registry=REGISTRY,
 )
 EDGE_OBJECT_ITEMS = Counter(
     "edge_object_items_total",
     "String-frame items the bridge served through request/response "
     "objects and Instance.get_rate_limits: frames the array fold "
-    "declined (one GLOBAL, chained, invalid or foreign-owned item sends "
-    "the whole frame here). With edge_fast_items_total and "
+    "declined (one chained, invalid or foreign-owned item sends the "
+    "whole frame here). With edge_fast_items_total and "
     "edge_folded_items_total it splits every bridge item by path",
     registry=REGISTRY,
 )

@@ -588,15 +588,19 @@ class ConsistentHashPicker:
 
         if not self._keys:
             raise RuntimeError("unable to pick a peer; pool is empty")
+        own = np.fromiter(
+            (self._by_point[p].is_owner for p in self._keys),
+            dtype=bool,
+            count=len(self._keys),
+        )
+        if own.all():
+            # every ring point is this node (a single-node ring):
+            # whatever a key hashes to, its successor is this node
+            return np.ones(len(keys), dtype=bool)
         pts = np.fromiter(
             (self._hash(k) for k in keys), dtype=np.uint64, count=len(keys)
         )
         ring = np.asarray(self._keys, dtype=np.uint64)
         idx = np.searchsorted(ring, pts, side="left")
         idx[idx == len(ring)] = 0
-        own = np.fromiter(
-            (self._by_point[p].is_owner for p in self._keys),
-            dtype=bool,
-            count=len(self._keys),
-        )
         return own[idx]

@@ -102,7 +102,10 @@ Stages form two families:
     instance_route   instance-side validation/routing/assembly,
                      recorded once per Instance.get_rate_limits call
                      from ANY door that reaches the instance (the
-                     fold and fast paths bypass it)
+                     fast path bypasses it), and once per string frame
+                     the bridge's fold serves, for the same work on
+                     arrays: ownership screen, key hashing, traffic
+                     observers, the managers' notes, GLOBAL queueing
     call_queue       batcher enqueue -> flusher collect, for the
                      call's group (the twin of batch_queue)
     call_device      flusher collect -> the group's future resolved

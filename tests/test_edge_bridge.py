@@ -15,7 +15,15 @@ import asyncio
 import struct
 from dataclasses import dataclass
 
-from gubernator_tpu.api.types import RateLimitResp, Status
+import pytest
+
+from gubernator_tpu.api.types import (
+    Algorithm,
+    Behavior,
+    RateLimitReq,
+    RateLimitResp,
+    Status,
+)
 from gubernator_tpu.serve.edge_bridge import (
     HELLO_FAST,
     HELLO_WINDOWED,
@@ -583,11 +591,15 @@ def test_fast_kill_switch_unadvertises():
 
 
 def _fold_fixture(is_owner: bool, string_fold: bool = True,
-                  fast_enabled: bool = True):
-    """Bridge over a real ConsistentHashPicker (one peer) whose batcher
-    and instance record which path served each frame."""
+                  fast_enabled: bool = True, shed=None):
+    """Bridge over a real ConsistentHashPicker (one peer) and a real
+    GlobalManager (never started: what the door queues stays in
+    `_updates`) whose batcher and instance record which path served
+    each frame."""
     import numpy as np
 
+    from gubernator_tpu.serve.config import BehaviorConfig
+    from gubernator_tpu.serve.global_mgr import GlobalManager
     from gubernator_tpu.serve.peers import ConsistentHashPicker
 
     folded_sizes = []
@@ -621,6 +633,8 @@ def _fold_fixture(is_owner: bool, string_fold: bool = True,
             ]
 
     inst = FakeInstance()
+    inst.shed = shed
+    inst.global_mgr = GlobalManager(BehaviorConfig(), inst)
     inst.picker.add(FakePeer("127.0.0.1:81", is_owner=is_owner))
     bridge = EdgeBridge(
         inst, "", fast_enabled=fast_enabled, string_fold=string_fold
@@ -682,24 +696,220 @@ def test_string_fold_serves_plain_owned_frame_via_arrays():
     assert out[1] == (0, 9, 7, 77, "", "")
 
 
-def test_string_fold_declines_global_and_unowned_frames():
-    """A GLOBAL item anywhere in the frame, or any key this node does
-    not own, must push the WHOLE frame onto the object path — the fold
-    never bypasses global-manager or forwarding semantics."""
-    bridge, folded_sizes, object_path_keys = _fold_fixture(is_owner=True)
+def _folded_global_items() -> float:
+    from gubernator_tpu.serve.metrics import REGISTRY
+
+    return REGISTRY.get_sample_value("edge_folded_global_items_total") or 0.0
+
+
+@pytest.mark.parametrize("shed_answers", [False, True],
+                         ids=["device-decided", "shed-answered"])
+def test_string_fold_serves_owned_global_items(shed_answers):
+    """Owned plain + GLOBAL items in one frame fold as ONE array group
+    with no Instance call, and every GLOBAL item queues its key's
+    status broadcast before the decide — once per distinct key with
+    the frame's last item's fields — also when the shed cache answers
+    the item and it never reaches the batcher."""
+    from gubernator_tpu.core.hashing import slot_hash_batch
+    from gubernator_tpu.serve.shedcache import ShedCache
+
+    shed = None
+    if shed_answers:
+        # a frozen refusal for g1's window: the screen answers both of
+        # its items host-side
+        shed = ShedCache(8, now_fn=lambda: 50)
+        shed.seed(int(slot_hash_batch(["api_g1"])[0]), 7, 1000, 99)
+    bridge, folded_sizes, object_path_keys = _fold_fixture(
+        is_owner=True, shed=shed
+    )
+    counted = _folded_global_items()
     out = _roundtrip_string_frame(
         bridge,
-        [_item(b"api", b"k1"), _item(b"api", b"g1", behavior=2)],
-        "fold-global",
+        [_item(b"api", b"k1"),
+         _item(b"api", b"g1", hits=1, limit=7, behavior=2),
+         _item(b"api", b"g2", hits=2, limit=9, algo=1, behavior=2),
+         _item(b"api", b"k2", behavior=1),
+         _item(b"api", b"g1", hits=3, limit=7, behavior=2)],
+        "fold-global-" + ("shed" if shed_answers else "device"),
     )
-    assert folded_sizes == []
-    assert object_path_keys == ["k1", "g1"]
-    assert out[0][:4] == (0, 5, 4, 77)
+    assert object_path_keys == []
+    assert _folded_global_items() - counted == 3
+    if shed_answers:
+        assert folded_sizes == [3]  # the residue: k1, g2, k2
+        assert out[1] == out[4] == (1, 7, 0, 99, "", "")
+    else:
+        assert folded_sizes == [5]
+        assert out[1] == (0, 7, 6, 77, "", "")
+        assert out[4] == (0, 7, 4, 77, "", "")
+    assert out[0] == out[3] == (0, 5, 4, 77, "", "")
+    assert out[2] == (0, 9, 7, 77, "", "")
+    assert bridge.instance.global_mgr._updates == {
+        "api_g1": RateLimitReq(
+            name="api", unique_key="g1", hits=3, limit=7, duration=1000,
+            behavior=Behavior.GLOBAL,
+        ),
+        "api_g2": RateLimitReq(
+            name="api", unique_key="g2", hits=2, limit=9, duration=1000,
+            algorithm=Algorithm.LEAKY_BUCKET, behavior=Behavior.GLOBAL,
+        ),
+    }
 
-    bridge, folded_sizes, object_path_keys = _fold_fixture(is_owner=False)
-    _roundtrip_string_frame(bridge, [_item(b"api", b"k1")], "fold-unowned")
+
+@pytest.mark.parametrize(
+    "is_owner,items,answered",
+    [
+        (False, [_item(b"api", b"k1"), _item(b"api", b"g1", behavior=2)],
+         True),
+        (False, [_item(b"api", b"k1")], True),
+        (True, [_item(b"api", b"k1"), _item(b"", b"g1", behavior=2)], True),
+        (True, [_item(b"api", b"k1"), _item(b"api", BAD, behavior=2)], True),
+        (True, [_item(b"api", b"k1"), _item(b"api", b"g1", behavior=2)[:-3]],
+         False),
+    ],
+    ids=["unowned-global", "unowned-plain", "empty-name", "bad-utf8",
+         "truncated"],
+)
+def test_string_fold_declines_invalid_and_unowned_frames(
+    is_owner, items, answered
+):
+    """Any key this node does not own (a non-owner's GLOBAL item needs
+    the replica answer and queue_hit, a plain one a forward), an empty
+    name, bad UTF-8 or a truncated payload pushes the WHOLE frame onto
+    the object path — the fold never bypasses forwarding or per-item
+    validation, and queues no broadcast for a frame it declined."""
+    bridge, folded_sizes, object_path_keys = _fold_fixture(is_owner=is_owner)
+    payload = b"".join(items)
+    assert bridge._fold_string_frame(payload, len(items)) is None
+    if answered:
+        out = _roundtrip_string_frame(
+            bridge, items, f"fold-decline-{is_owner}-{len(payload)}"
+        )
+        assert object_path_keys[0] == "k1"
+        assert out[0][:4] == (0, 5, 4, 77)
+    else:
+        # malformed input closes the connection on either path
+        with pytest.raises(struct.error):
+            asyncio.run(bridge._decide_string(payload, len(items)))
     assert folded_sizes == []
-    assert object_path_keys == ["k1"]
+    assert bridge.instance.global_mgr._updates == {}
+
+
+def _seeded_mixed_frame(seed: int, n: int = 1000):
+    """One frame of what the four-chip cell sends (both algorithms, the
+    three limit classes, every tenth key id Behavior GLOBAL, zipf-like
+    duplicates, peeks) as (payload, [RateLimitReq]); a key carries the
+    same hits all through the frame (the program's rule for same-key
+    items of one batch equals one-by-one service exactly then)."""
+    import random
+
+    rng = random.Random(seed)
+    classes = ((100, 60_000), (10, 1_000), (1000, 3_600_000))
+    hits_of = {}
+    items = []
+    for _ in range(n):
+        # a zipf-like head over a uniform tail of 400 key ids
+        i = (min(int(rng.paretovariate(0.9)), 400) if rng.random() < 0.6
+             else rng.randrange(400))
+        limit, duration = classes[i % 7 % 3]
+        hits = hits_of.setdefault(i, rng.choice((1, 1, 1, 2, 0)))
+        behavior = 2 if i % 10 == 3 else (1 if i % 10 == 5 else 0)
+        items.append(_item(
+            b"mixed", b"k%d" % i, hits=hits, limit=limit,
+            duration=duration, algo=i % 2, behavior=behavior,
+        ))
+    return b"".join(items), n
+
+
+def test_seeded_mixed_frame_folds_byte_identical_to_object_path(monkeypatch):
+    """The fold's answers are the object path's, byte for byte: two
+    fresh nodes on a standing clock serve the same two seeded 1000-item
+    mixed frames (the first drives keys over their limit and fills the
+    shed cache), one through the fold and one with string_fold=False,
+    and leave the same broadcasts queued."""
+    from gubernator_tpu.core.store import StoreConfig
+    from gubernator_tpu.serve.backends import TpuBackend
+    from gubernator_tpu.serve.config import ServerConfig
+    from gubernator_tpu.serve.instance import Instance
+    from gubernator_tpu.serve.metrics import REGISTRY
+    from gubernator_tpu.api.types import PeerInfo
+
+    import gubernator_tpu.api.types as types_mod
+    import gubernator_tpu.core.engine as engine_mod
+
+    def clock():
+        return 1_700_000_000_000
+
+    monkeypatch.setattr(types_mod, "millisecond_now", clock)
+    monkeypatch.setattr(engine_mod, "millisecond_now", clock)
+    addr = "127.0.0.1:9981"
+    frames = [_seeded_mixed_frame(27), _seeded_mixed_frame(2027)]
+
+    async def serve(string_fold: bool):
+        conf = ServerConfig(
+            grpc_address=addr, advertise_address=addr, shed_cache=True
+        )
+        # the broadcast loop stays asleep: what was queued is read back
+        conf.behaviors.global_sync_wait = 3600.0
+        inst = Instance(
+            conf,
+            TpuBackend(
+                StoreConfig(rows=16, slots=1 << 10), buckets=(64, 1024)
+            ),
+        )
+        inst.start()
+        await inst.set_peers([PeerInfo(address=addr, is_owner=True)])
+        inst.shed.now_fn = clock
+        calls = []
+        served = inst.get_rate_limits
+
+        async def counting(reqs, stage_frame=False):
+            calls.append(len(reqs))
+            return await served(reqs, stage_frame=stage_frame)
+
+        inst.get_rate_limits = counting
+        bridge = EdgeBridge(inst, "", string_fold=string_fold)
+        try:
+            out = [
+                await bridge._decide_string_frame(payload, n)
+                for payload, n in frames
+            ]
+            return out, calls, dict(inst.global_mgr._updates), inst.shed.hits
+        finally:
+            await inst.stop()
+
+    def grown():
+        return {
+            c: REGISTRY.get_sample_value(c) or 0.0
+            for c in ("edge_folded_items_total", "edge_object_items_total",
+                      "edge_folded_global_items_total")
+        }
+
+    before = grown()
+    folded, fold_calls, fold_updates, fold_shed = asyncio.run(serve(True))
+    mid = grown()
+    plain, obj_calls, obj_updates, obj_shed = asyncio.run(serve(False))
+    after = grown()
+    assert fold_calls == [] and obj_calls == [1000, 1000]
+    assert folded == plain
+    assert fold_updates == obj_updates and len(fold_updates) > 20
+    assert fold_shed == obj_shed > 0  # the shed cache answered items
+    statuses = [folded[1][8 + 29 * j] for j in range(1000)]
+    assert 0 < sum(statuses) < 1000  # keys over their limit, and under
+    n_global = sum(
+        1 for payload, n in frames for r in decode_request_frame(payload, n)
+        if r.behavior == Behavior.GLOBAL
+    )
+    assert n_global > 100
+    assert {c: mid[c] - before[c] for c in mid} == {
+        "edge_folded_items_total": 2000.0,
+        "edge_object_items_total": 0.0,
+        "edge_folded_global_items_total": float(n_global),
+    }
+    assert {c: after[c] - mid[c] for c in mid} == {
+        "edge_folded_items_total": 0.0,
+        "edge_object_items_total": 2000.0,
+        "edge_folded_global_items_total": 0.0,
+    }
 
 
 def test_string_fold_kill_switch():
@@ -728,3 +938,9 @@ def test_picker_self_owned_mask_matches_get():
     assert mask.any() and not mask.all()  # 500 keys spread over 3 peers
     for k, owned in zip(keys, mask):
         assert picker.get(k).is_owner == bool(owned)
+    # a ring whose every point is this node owns every key unhashed;
+    # one whose only point is another node owns none
+    for is_owner in (True, False):
+        alone = ConsistentHashPicker()
+        alone.add(FakePeer("10.0.0.1:81", is_owner=is_owner))
+        assert alone.self_owned_mask(keys).tolist() == [is_owner] * 500

@@ -7,13 +7,14 @@ reference of one-node GLOBAL semantics (benchmark/reference_global.py).
 The stream has what the cell sends: both algorithms, the three limit
 classes of benchmark/traffic/geb-frames-global.json, in-batch
 duplicates, keys driven over their limit, peeks, 10% of the key ids
-Behavior GLOBAL, and frames of three kinds — mixed (plain + GLOBAL:
-the object path), plain only (pre-hashed fast frames) and GLOBAL only.
+Behavior GLOBAL, and frames of three kinds — mixed (plain + GLOBAL) and
+GLOBAL only (string frames: since PR 27 the string->array fold serves
+them, the node owning every key) and plain only (pre-hashed fast frames).
 The clock stands still (the r10 fake-clock pattern), so every answer is
 exact whatever the windows' lengths. After the stream every key's window
 is read back and equals the reference's: no broadcast peek moved a
-counter. The counters PR 26 added are held to hand-counted values on
-one crafted frame.
+counter. The counters PRs 26 and 27 added are held to hand-counted
+values on one crafted frame.
 """
 
 import asyncio
@@ -53,6 +54,7 @@ COUNTERS = (
     "mesh_shard_rows_total", "mesh_shard_slots_total",
     "mesh_shard_max_rows_total", "edge_object_items_total",
     "edge_fast_items_total", "edge_folded_items_total",
+    "edge_folded_global_items_total",
     "global_peek_rows_total", "global_broadcast_keys_total",
 )
 
@@ -202,7 +204,10 @@ def test_seeded_stream_equals_oracle_and_reference(node):
         if any(r.behavior == Behavior.GLOBAL for r in fr)
     )
     assert n_global > 400
-    assert grew["edge_object_items_total"] == n_mixed
+    # no frame holds a key the node does not own: none rides the object path
+    assert grew["edge_object_items_total"] == 0
+    assert grew["edge_folded_items_total"] == n_mixed
+    assert grew["edge_folded_global_items_total"] == n_global
     assert grew["edge_fast_items_total"] == sum(map(len, frames)) - n_mixed
     assert grew["global_peek_rows_total"] > 0
     assert grew["global_broadcast_keys_total"] == grew["global_peek_rows_total"]
@@ -227,9 +232,9 @@ def test_seeded_stream_equals_oracle_and_reference(node):
 
 
 def test_counters_on_one_crafted_frame(node):
-    """Seven fresh keys in one frame, one of them GLOBAL: the frame rides
-    the object path as ONE device batch, and the owner's broadcast then
-    peeks the one GLOBAL key in a batch of its own."""
+    """Seven fresh keys in one frame, one of them GLOBAL: the frame folds
+    into the array path as ONE device batch, and the owner's broadcast
+    then peeks the one GLOBAL key in a batch of its own."""
     cluster, addr = node
     time.sleep(10 * SYNC_WAIT)  # earlier broadcasts have flushed
     frame = [req(i, 1, name="crafted") for i in (100, 101, 102, 103, 104,
@@ -246,9 +251,10 @@ def test_counters_on_one_crafted_frame(node):
     assert [a[0] for a in answers] == [0] * 7
     time.sleep(20 * SYNC_WAIT)
     assert grown(before) == {
-        "edge_object_items_total": 7.0,
+        "edge_object_items_total": 0.0,
         "edge_fast_items_total": 0.0,
-        "edge_folded_items_total": 0.0,
+        "edge_folded_items_total": 7.0,
+        "edge_folded_global_items_total": 1.0,
         "global_peek_rows_total": 1.0,
         "global_broadcast_keys_total": 1.0,
         # two device batches: the frame's seven rows, then the one peek

@@ -168,6 +168,50 @@ def test_broadcast_dedup_last_wins_and_skips_owner():
     assert all(r.behavior == Behavior.BATCHING for r in peek_batch)
 
 
+@pytest.mark.parametrize("entry", ["queue_update", "queue_update_fields"])
+def test_update_backlog_bound_drops_and_counts(entry):
+    """Both entry points of the owner's broadcast queue hold the
+    GUBER_GLOBAL_BACKLOG bound alike: past it a NEW key is dropped and
+    counted, a key already queued is refreshed for free (last wins)."""
+    import numpy as np
+
+    from gubernator_tpu.serve.metrics import REGISTRY
+
+    def dropped():
+        return REGISTRY.get_sample_value(
+            "global_backlog_dropped_total", {"queue": "updates"}
+        ) or 0.0
+
+    reqs = [_req("a1", hits=1), _req("a2", hits=2), _req("a3", hits=3),
+            _req("a4", hits=4), _req("a1", hits=5)]
+
+    async def main():
+        gm = GlobalManager(_conf(global_backlog=2), FakeInstance({}))
+        if entry == "queue_update":
+            for r in reqs:
+                gm.queue_update(r)
+        else:
+            gm.queue_update_fields(
+                [r.hash_key() for r in reqs],
+                [(i, r.name, r.unique_key) for i, r in enumerate(reqs)],
+                dict(
+                    hits=np.array([r.hits for r in reqs]),
+                    limit=np.array([r.limit for r in reqs]),
+                    duration=np.array([r.duration for r in reqs]),
+                    algo=np.array([int(r.algorithm) for r in reqs]),
+                ),
+            )
+        return gm
+
+    before = dropped()
+    gm = run(main())
+    assert dropped() - before == 2  # a3 and a4
+    assert gm._dropped["updates"] == 2
+    assert gm._updates == {"gm_a1": reqs[4], "gm_a2": reqs[1]}
+    assert gm._updates_event.is_set()
+    assert gm.backlog_sizes() == {"hits": 0, "updates": 2}
+
+
 def test_failing_peer_does_not_block_others_or_kill_loops():
     peers = {
         "a": FakePeer("A", fail=True),
