@@ -1,5 +1,5 @@
 .PHONY: all native proto test bench readme readme-check profile-stages \
-	profile-submit profile-shed profile-trace chaos chaos-rolling \
+	profile-shed profile-trace chaos chaos-rolling \
 	chaos-restore \
 	perf-gate clean
 
@@ -36,18 +36,6 @@ OUT ?= BENCH_STAGES.json
 profile-stages: native
 	python scripts/profile_serving_stages.py --seconds $(SECONDS) \
 	  --json $(OUT)
-
-# arrival-time host-prep A/B (r9): the BENCH_STAGES_r7 workload with
-# GUBER_PREP_AT_ARRIVAL flipped between interleaved rounds; reports the
-# per-batch submit interior (prep+merge+dispatch) and decisions/s for
-# both modes (medians). SUBMIT_SECONDS/ROUNDS/SUBMIT_OUT overridable:
-# make profile-submit SUBMIT_SECONDS=12 ROUNDS=7 SUBMIT_OUT=x.json
-SUBMIT_SECONDS ?= 3
-ROUNDS ?= 14
-SUBMIT_OUT ?= BENCH_SUBMIT.json
-profile-submit: native
-	python scripts/profile_submit.py --seconds $(SUBMIT_SECONDS) \
-	  --rounds $(ROUNDS) --json $(SUBMIT_OUT)
 
 # over-limit shed cache A/B (r10): the bench_serving shed workload
 # through the compiled edge door with instance.shed flipped between

@@ -423,9 +423,6 @@ def run_zipf10m(args) -> int:
             "GUBER_PREP_THREADS": os.environ.get(
                 "GUBER_PREP_THREADS", "<default>"
             ),
-            "GUBER_PREP_AT_ARRIVAL": os.environ.get(
-                "GUBER_PREP_AT_ARRIVAL", "1"
-            ),
         },
         notes=(
             "depth rows share one fixed store footprint; throughput "
@@ -1216,9 +1213,6 @@ def run_shed(args) -> int:
             "GUBER_SHED_CACHE_KEYS": env.get(
                 "GUBER_SHED_CACHE_KEYS", "<default>"
             ),
-            "GUBER_PREP_AT_ARRIVAL": env.get(
-                "GUBER_PREP_AT_ARRIVAL", "1"
-            ),
         },
         rows=rows,
     )
@@ -1699,22 +1693,11 @@ def main(argv=None) -> int:
         help="in-flight device batches per node (GUBER_FETCH_DEPTH); "
         "2 suits a co-located chip (PCIe fetch)",
     )
-    parser.add_argument(
-        "--prep-at-arrival",
-        choices=["0", "1"],
-        default=None,
-        help="override GUBER_PREP_AT_ARRIVAL for every node this "
-        "harness boots (r9 host-prep pipeline A/B; default: env / on)",
-    )
     args = parser.parse_args(argv)
     if args.fetch_depth is not None:
         import os
 
         os.environ["GUBER_FETCH_DEPTH"] = str(args.fetch_depth)
-    if args.prep_at_arrival is not None:
-        import os
-
-        os.environ["GUBER_PREP_AT_ARRIVAL"] = args.prep_at_arrival
     if args.scenario == "flash-crowd":
         return run_flash_crowd(args)
     if args.scenario == "mixed-tenant-zipf":

@@ -13,10 +13,10 @@ implement it:
   process serves, followers run the lockstep step loop.
 
 All backends are driven from the single serving event loop / batcher task.
-Concurrency contract with the pipelined batcher: decide_submit calls are
-strictly serialized (one submit thread), but up to fetch_depth
-decide_wait calls run CONCURRENTLY on fetch worker threads and may
-overlap later decide_submit/update_globals calls — safe because a wait
+Concurrency contract with the pipelined batcher: decide_submit_merged
+calls are strictly serialized (one submit thread), but up to fetch_depth
+decide_wait_arrays calls run CONCURRENTLY on fetch worker threads and may
+overlap later decide_submit_merged/update_globals calls — safe because a wait
 touches only its own handle and the engine's stats counters (which land
 under EngineStats' lock), never the store or clock. Keep that split when
 adding backend state; no other locking exists anywhere (the reference
@@ -288,10 +288,10 @@ class _ArrayOps:
 
     The serving hot path (edge GEB6 frames, serve/edge_bridge.py) carries
     pre-hashed dense arrays end-to-end; these helpers are the object<->
-    array seam so the batcher can flatten MIXED batches (array groups
+    array seam so the batcher can merge MIXED batches (array groups
     from the edge + request-object groups from gRPC/JSON callers) into
-    ONE device submit. Requires self.engine with decide_submit/decide_wait
-    taking (key_hash, hits, limit, duration, algo, gnp, now)."""
+    ONE device submit. Requires self.engine with prep_run /
+    merge_prepped / decide_submit_merged / decide_wait."""
 
     #: field order used everywhere a fields-dict is flattened
     ARRAY_FIELDS = ("key_hash", "hits", "limit", "duration", "algo", "gnp")
@@ -315,8 +315,7 @@ class _ArrayOps:
         """Arrival-time per-group prep (serve/batcher.py): presort +
         clip one caller group on a prep-pool thread, so it sits in the
         batcher queue as a sorted run the flush-time merge combine
-        stitches without re-sorting. `gnp` defaults to all-False like
-        decide_submit_arrays' flush path."""
+        stitches without re-sorting. `gnp` defaults to all-False."""
         if "gnp" not in fields:
             import numpy as np
 
@@ -337,30 +336,16 @@ class _ArrayOps:
         return self.engine.merge_prepped(runs)
 
     def decide_submit_merged(self, merged, now: Optional[int] = None):
-        """Dispatch one merge_prepped batch. Same handle contract as
-        decide_submit_arrays; fetch with decide_wait_arrays."""
+        """Dispatch one merge_prepped batch; fetch with
+        decide_wait_arrays."""
         from gubernator_tpu.api.types import millisecond_now
 
         if now is None:
             now = millisecond_now()
         return self.engine.decide_submit_merged(merged, now)
 
-    def decide_submit_arrays(self, fields: dict, now: Optional[int] = None):
-        from gubernator_tpu.api.types import millisecond_now
-
-        if fields["key_hash"].shape[0] == 0:
-            return None
-        if now is None:
-            now = millisecond_now()
-        return self.engine.decide_submit(now=now, **fields)
-
     def decide_wait_arrays(self, handle):
         """(status, limit, remaining, reset_time) int arrays."""
-        if handle is None:
-            import numpy as np
-
-            z = np.empty(0, np.int64)
-            return z, z, z, z
         return self.engine.decide_wait(handle)
 
     @staticmethod
@@ -529,17 +514,6 @@ class TpuBackend(_ArrayOps):
     def decide(self, reqs, gnp, now=None):
         return self.engine.get_rate_limits(reqs, now=now, gnp=list(gnp))
 
-    def decide_submit(self, reqs, gnp, now=None):
-        """Presort + dispatch without waiting (see engine.decide_submit);
-        the batcher pipelines the next batch's host work against this
-        batch's device time through this split."""
-        return self.engine.get_rate_limits_submit(
-            reqs, now=now, gnp=list(gnp)
-        )
-
-    def decide_wait(self, handle):
-        return self.engine.get_rate_limits_wait(handle)
-
     def update_globals(self, updates, now=None):
         self.engine.update_globals(list(updates), now=now)
 
@@ -581,23 +555,11 @@ class MeshBackend(_ArrayOps):
                 store, devices=devices, buckets=buckets, sketch=sketch
             )
         self.engine = engine
-        if not hasattr(engine, "decide_submit"):
-            # an engine without the submit/wait split (none in-tree since
-            # the multihost wrapper gained it in r4): None attributes make
-            # the batcher fall back to blocking decide and keep the edge
-            # bridge's array fast path off
-            self.decide_submit = None
-            self.decide_wait = None
-            self.decide_submit_arrays = None
-            self.decide_wait_arrays = None
-        if not hasattr(engine, "prep_run"):
-            # likewise for the arrival-time prep surface (r9): without
-            # engine-side prep_run/merge_prepped the batcher keeps the
-            # flush-time concat+argsort path
-            self.prep_group = None
-            self.prep_reqs = None
-            self.merge_prepped = None
-            self.decide_submit_merged = None
+        # every engine carries the launch surface the batcher drives; one
+        # that does not is refused here, not at its first flush
+        for name in ("prep_run", "merge_prepped", "decide_submit_merged",
+                     "decide_wait"):
+            getattr(engine, name)
         if not hasattr(engine, "snapshot_read"):
             # bucket replication needs the engine's non-mutating row
             # read (r11); the sharded engines don't expose it yet —
@@ -629,28 +591,6 @@ class MeshBackend(_ArrayOps):
                 now=now, **self.arrays_from_reqs(reqs, gnp)
             )
         )
-
-    def decide_submit(self, reqs, gnp, now=None):
-        """Shard + dispatch without waiting (MeshEngine.decide_submit):
-        gives the mesh backend the same host/device pipelining the
-        single-chip backend has — the batcher preps batch N+1 while the
-        whole mesh computes batch N. The multihost lockstep wrapper has
-        the split too (followers dispatch-and-move-on, fetches stay
-        leader-local), so this pipelines across hosts as well."""
-        from gubernator_tpu.api.types import millisecond_now
-
-        if len(reqs) == 0:
-            return None
-        if now is None:
-            now = millisecond_now()
-        return self.engine.decide_submit(
-            now=now, **self.arrays_from_reqs(reqs, gnp)
-        )
-
-    def decide_wait(self, handle):
-        if handle is None:
-            return []
-        return self.resps_from_arrays(*self.engine.decide_wait(handle))
 
     def update_globals(self, updates, now=None):
         np = self._np

@@ -11,19 +11,28 @@ The backend call itself runs in a worker thread (it blocks on the device);
 the event loop keeps accepting requests for the *next* batch meanwhile,
 giving natural double-buffering: batch N on device while batch N+1 fills.
 
-Backends exposing decide_submit/decide_wait (the device backends) get one
-more level of pipelining: the flusher submits batch N+1 (host presort +
+A decide batch has exactly two routes, chosen by what the backend IS:
+
+* a HOST backend (ExactBackend, the tests' fakes) is decided by one
+  blocking `backend.decide` per batch — on the loop when the backend is
+  marked `inline_decide` (microseconds of dict work), else on a worker
+  thread;
+* a DEVICE backend (one that offers `decide_submit_merged`; the rest of
+  the surface is checked at construction) is launched by
+  `_flush_merged`, below.
+
+The device route is pipelined: the flusher submits batch N+1 (merge +
 async dispatch) while batch N's device fetch is still in flight, so
-sustained throughput tracks max(host work, device time) per batch instead
-of their sum. Up to `fetch_depth` batches may be in flight (default 2):
-submits stay strictly serialized on one thread, but fetches run on a
-fetch_depth-wide pool and may complete out of order — each batch's
-futures resolve independently, and the engines' stats land through a
-lock (core/engine.py EngineStats). Depth 2 is the default: the device is
-co-located (fetch is ~0.1ms over PCIe), so one batch in fetch and one in
-submit keep it fed; a device behind a slower link would need
-depth ~ fetch RTT / batch time to run at device rate rather than at
-1/RTT. The native prep's reusable buffer ring is sized to
+sustained throughput tracks max(host work, device time) per batch
+instead of their sum. Up to `fetch_depth` batches may be in flight
+(default 2): submits stay strictly serialized on one thread, but
+fetches run on a fetch_depth-wide pool and may complete out of order —
+each batch's futures resolve independently, and the engines' stats land
+through a lock (core/engine.py EngineStats). Depth 2 is the default:
+the device is co-located (fetch is ~0.1ms over PCIe), so one batch in
+fetch and one in submit keep it fed; a device behind a slower link
+would need depth ~ fetch RTT / batch time to run at device rate rather
+than at 1/RTT. The native prep's reusable buffer ring is sized to
 depth+1 generations at construction (hashlib_native.set_prep_generations)
 so no in-flight batch's host arrays are ever overwritten by a later
 submit.
@@ -35,19 +44,17 @@ per-batch fixed device costs (the big-store full-table writeback pass).
 Idle flush semantics are unchanged: the hold predicate is False whenever
 a slot is free.
 
-Arrival-time prep (r9, GUBER_PREP_AT_ARRIVAL): on array-capable device
-backends, each caller group's host prep — request->array conversion +
-batch hashing (object groups), device-dtype clipping, and the
-ownership/bucket PRE-SORT — is kicked onto a small prep pool the moment
-the group is enqueued, overlapping the queue wait it was going to pay
-anyway (batch_queue measured 16.7ms mean at the r7 profile while
-submit_host burned 32.8ms serialized). By flush time the batch is a set
-of sorted runs; the submit thread k-way MERGES them (serve/prep.py,
-O(n log k)) and dispatches — the only serialized work left. The
-submit-thread interior is stage-attributed as prep/merge/dispatch
-(serve/stages.py); flush-time prep remains as the fallback for
-un-prepped groups and as the whole path when the knob is off
-(the BENCH_SUBMIT_r9.json A/B baseline).
+Arrival-time prep (r9): each caller group's host prep —
+request->array conversion + batch hashing (object groups), device-dtype
+clipping, and the ownership/bucket PRE-SORT — is kicked onto a small
+prep pool the moment the group is enqueued, overlapping the queue wait
+it was going to pay anyway (batch_queue measured 16.7ms mean at the r7
+profile while submit_host burned 32.8ms serialized). By flush time the
+batch is a set of sorted runs; the submit thread k-way MERGES them
+(serve/prep.py, O(n log k)) and dispatches — the only serialized work
+left. prep/merge/dispatch (serve/stages.py) are the whole submit-thread
+interior; a group that reaches the flush without a prep future (stop()
+raced its enqueue) is prepped there, inside the `prep` span.
 """
 
 from __future__ import annotations
@@ -93,6 +100,21 @@ class _QMeta:
         self.t_done = 0.0
 
 
+#: what a device backend owes the batcher besides decide_submit_merged
+DEVICE_SURFACE = (
+    "prep_reqs", "prep_group", "merge_prepped",
+    "decide_wait_arrays", "resps_from_arrays",
+)
+
+
+def is_device_backend(backend) -> bool:
+    """THE statement of which route a backend's batches take: one that
+    offers decide_submit_merged is launched on the device route
+    (DeviceBatcher._flush_merged, the edge bridge's array frames);
+    anything else is a host backend, decided by its blocking decide."""
+    return getattr(backend, "decide_submit_merged", None) is not None
+
+
 def _prep_result(prep: "concurrent.futures.Future"):
     """Resolve an arrival-prep future on the submit thread. A pool
     shutdown (stop() racing a flush) surfaces as CancelledError, which
@@ -124,10 +146,9 @@ class DeviceBatcher:
         backend,
         batch_wait: float = 0.0005,
         batch_limit: int = 1000,
-        fetch_depth: Optional[int] = None,
+        fetch_depth: int = 2,
         deep_batch: bool = False,
-        prep_at_arrival: Optional[bool] = None,
-        prep_threads: Optional[int] = None,
+        prep_threads: int = 0,
     ):
         self.backend = backend
         self.batch_wait = batch_wait
@@ -141,12 +162,10 @@ class DeviceBatcher:
         # semantics are byte-identical to deep_batch=False because the
         # hold predicate is False whenever a pipeline slot is free.
         self.deep_batch = bool(deep_batch)
-        if fetch_depth is None:
-            fetch_depth = int(os.environ.get("GUBER_FETCH_DEPTH", "2"))
         self.fetch_depth = max(1, int(fetch_depth))
         self._queue: "asyncio.Queue" = asyncio.Queue()
         self._task: Optional[asyncio.Task] = None
-        # in-flight fetches of submitted batches (pipelined backends
+        # in-flight fetches of submitted batches (device backends
         # only); each task resolves its own batch's futures. The
         # semaphore admits a submit only while fewer than fetch_depth
         # batches are outstanding.
@@ -195,23 +214,22 @@ class DeviceBatcher:
         # collect_batch — possibly parked in a batch_wait straggler
         # window — but not yet flushed), and _flushing (mid-flush).
         self._inline = bool(getattr(backend, "inline_decide", False))
-        # arrival-time prep (r9): needs the backend's prep surface
-        # (engine-side presort + merge-combined dispatch). The flag is a
-        # plain attribute read per enqueue/flush so the submit profiler
-        # can A/B it at runtime (scripts/profile_submit.py).
-        self._prep_ok = (
-            callable(getattr(backend, "prep_group", None))
-            and getattr(backend, "merge_prepped", None) is not None
-            and getattr(backend, "decide_submit_merged", None) is not None
-            and getattr(backend, "decide_submit_arrays", None) is not None
-        )
-        if prep_at_arrival is None:
-            prep_at_arrival = os.environ.get(
-                "GUBER_PREP_AT_ARRIVAL", "1"
-            ).lower() not in ("0", "false", "no", "off")
-        self.prep_at_arrival = bool(prep_at_arrival)
-        if prep_threads is None:
-            prep_threads = int(os.environ.get("GUBER_PREP_THREADS", "0"))
+        # a DEVICE backend is one that offers decide_submit_merged; it
+        # then owes the rest of the launch surface, checked here so a
+        # half-built backend fails at construction instead of at its
+        # first flush
+        self._device = is_device_backend(backend)
+        if self._device:
+            missing = [
+                m for m in DEVICE_SURFACE
+                if not callable(getattr(backend, m, None))
+            ]
+            if missing:
+                raise TypeError(
+                    f"{type(backend).__name__} offers decide_submit_merged"
+                    f" but not {', '.join(missing)}: a device backend "
+                    f"carries the whole launch surface"
+                )
         if prep_threads <= 0:
             # auto: leave a core for the serving loop — a prep pool as
             # wide as the box measurably thrashes small hosts (2-core
@@ -220,14 +238,14 @@ class DeviceBatcher:
             # narrow pools keep up easily)
             prep_threads = max(1, min(4, (os.cpu_count() or 2) - 1))
         self.prep_threads = prep_threads
-        # workers spawn on first submit, so an idle/disabled prep path
-        # costs no threads
+        # workers spawn on first submit, so an idle prep pool costs no
+        # threads; host backends get none
         self._prep_pool = (
             concurrent.futures.ThreadPoolExecutor(
                 max_workers=self.prep_threads,
                 thread_name_prefix="guber-prep",
             )
-            if self._prep_ok
+            if self._device
             else None
         )
         self._flushing = False
@@ -342,11 +360,10 @@ class DeviceBatcher:
         """Arrival-time prep kick: schedule this group's conversion +
         presort on the prep pool NOW, so it overlaps the group's own
         queue wait. Returns the prep future to ride in the queue tuple,
-        or None when arrival prep is off/unsupported (the flush-time
-        fallback preps it on the submit thread instead). `method` is
-        resolved lazily — backends without the prep surface must not
-        pay (or fail) an attribute lookup per enqueue."""
-        if not (self._prep_ok and self.prep_at_arrival):
+        or None on a host backend and when stop() already shut the pool
+        down (a group that still reaches a flush is prepped there, on
+        the submit thread)."""
+        if not self._device:
             return None
         try:
             return self._prep_pool.submit(
@@ -361,8 +378,8 @@ class DeviceBatcher:
         optional, default all-False; the edge routes GLOBAL items via the
         request-object path). Resolves to (status, limit, remaining,
         reset_time) arrays for exactly these rows, co-batched and
-        pipelined with every other caller. Only valid on backends
-        exposing decide_submit_arrays (the device backends).
+        pipelined with every other caller. Only valid on device
+        backends (is_device_backend).
         `frame=False` keeps a group out of the per-frame stage clock —
         a chunked frame flags only its first chunk, so one frame
         contributes one batch_queue/device span, not one per chunk.
@@ -684,67 +701,74 @@ class DeviceBatcher:
 
         if not decide_items:
             return
-        if self._prep_ok and self.prep_at_arrival:
-            # merge-combine path (r9): every group is (or can be) a
-            # pre-sorted run; the submit thread merges runs instead of
-            # re-sorting the flattened batch. Object-only batches ride
-            # it too — their conversion/hashing happened at arrival.
+        if self._device:
             await self._flush_merged(decide_items, t_collect)
             return
-        if any(b[0] == "decide_arrays" for b in decide_items):
-            # mixed/array batch: flatten everything to dense arrays and
-            # take the array submit path (bridge gates array groups to
-            # array-capable backends, so decide_submit_arrays exists)
-            await self._flush_arrays(decide_items, t_collect)
-            return
+        # host backend: one blocking decide per batch (a cancel mid-call
+        # is handled by _run; the worker thread finishes on its own and
+        # to_thread discards its result). Host backends marked
+        # inline_decide run right here on the loop — their decide is
+        # microseconds of dict work and the to_thread handoff would
+        # dominate the request latency.
         reqs = [r for it in decide_items for r in it[1]]
         gnp = [g for it in decide_items for g in it[2]]
         t0 = time.monotonic()
-        submit = getattr(self.backend, "decide_submit", None)
-        if submit is None:
-            # non-pipelined backend: one blocking decide per batch (a
-            # cancel mid-call is handled by _run; the worker thread
-            # finishes on its own and to_thread discards its result).
-            # Host backends marked inline_decide run right here on the
-            # loop — their decide is microseconds of dict work and the
-            # to_thread handoff would dominate the request latency.
-            try:
-                if inline:
-                    resps = self.backend.decide(reqs, gnp)
-                else:
-                    resps = await asyncio.to_thread(
-                        self.backend.decide, reqs, gnp
-                    )
-            except Exception as e:
-                self._fail(decide_items, e)
-                return
-            self._resolve(decide_items, resps, time.monotonic() - t0)
-            self._stage_device(decide_items, t_collect)
-            self._trace_device(decide_items, t_collect, len(resps))
+        try:
+            if inline:
+                resps = self.backend.decide(reqs, gnp)
+            else:
+                resps = await asyncio.to_thread(
+                    self.backend.decide, reqs, gnp
+                )
+        except Exception as e:
+            self._fail(decide_items, e)
             return
+        self._resolve(decide_items, resps, time.monotonic() - t0)
+        self._stage_device(decide_items, t_collect)
+        self._trace_device(decide_items, t_collect, len(resps))
 
-        # pipelined path: submit now (host presort + async dispatch);
-        # fetch in a background task so the flusher can collect and
-        # submit the NEXT batch while the device computes this one.
-        await self._submit_pipelined(
-            lambda: submit(reqs, gnp),
-            decide_items,
-            lambda handle, submit_s: self._finish(
-                handle, decide_items, submit_s, t_collect
-            ),
-        )
+    async def _flush_merged(self, decide_items, t_collect) -> None:
+        """The device route, and the only launch path: resolve every
+        group's pre-sorted run (its arrival prep's result; a group that
+        carries no prep future is prepped here), k-way merge the runs
+        into one sorted batch, and dispatch — no concat + full argsort
+        anywhere. The submit-thread interior is stage-attributed as
+        prep (waiting out unfinished arrival preps), merge, and
+        dispatch. All of it runs inside submit_call — off the event
+        loop, and inside the failure guard, so a conversion error fails
+        THIS batch's callers, never the flusher task. The fetch runs in
+        a background task (_finish_arrays), so the flusher can collect
+        and submit the NEXT batch while the device computes this one."""
+        # group lengths are exception-free to read and needed for the
+        # response slicing regardless of submit outcome
+        lens = [
+            it[1]["key_hash"].shape[0]
+            if it[0] == "decide_arrays"
+            else len(it[1])
+            for it in decide_items
+        ]
 
-    async def _submit_pipelined(
-        self, submit_call, decide_items, finish_factory
-    ) -> None:
-        """The pipelined paths' shared submit discipline: semaphore
-        admission (bounds outstanding batches at fetch_depth), shielded
-        executor submit, release/fail on every exit, and ownership
-        transfer of the live batch to the fetch task. A cancel while
-        waiting for a slot reaches _run's handler with nothing
-        submitted. `submit_call` runs on the single submit thread, so
-        per-batch host work (flatten/convert/presort) belongs inside it
-        — off the event loop AND inside the failure guard."""
+        def submit_call():
+            runs = []
+            with STAGES.span("prep"):
+                for it in decide_items:
+                    # queue tuples carry their arrival-prep future at
+                    # slot 3 (object group) or 2 (array group)
+                    p = it[3] if it[0] == "decide" else it[2]
+                    if p is not None:
+                        runs.append(_prep_result(p))
+                    elif it[0] == "decide":
+                        runs.append(self.backend.prep_reqs(it[1], it[2]))
+                    else:
+                        runs.append(self.backend.prep_group(it[1]))
+            with STAGES.span("merge"):
+                merged = self.backend.merge_prepped(runs)
+            with STAGES.span("dispatch"):
+                return self.backend.decide_submit_merged(merged)
+
+        # admission bounds outstanding batches at fetch_depth; a cancel
+        # while waiting for a slot reaches _run's handler with nothing
+        # submitted
         await self._inflight.acquire()
         # t0 AFTER admission: under a saturated pipeline the acquire
         # blocks for up to a batch period, which is queue wait, not
@@ -776,7 +800,11 @@ class DeviceBatcher:
             return
         submit_s = time.monotonic() - t0
         STAGES.add("submit_host", submit_s)
-        task = asyncio.ensure_future(finish_factory(handle, submit_s))
+        task = asyncio.ensure_future(
+            self._finish_arrays(
+                handle, decide_items, lens, submit_s, t_collect
+            )
+        )
         # hold the reference until done (stop() drains the set); discard
         # on completion so an idle batcher doesn't pin the last batches'
         # requests/responses until the next flush
@@ -786,115 +814,6 @@ class DeviceBatcher:
         # later cancel must not fail its futures from _run. _live_batch
         # is the same list object _run handed to _flush.
         self._live_batch.clear()
-
-    def _prep_of(self, it):
-        """The arrival-prep future riding a decide queue tuple (None =
-        un-prepped; flush preps it on the submit thread)."""
-        return it[3] if it[0] == "decide" else it[2]
-
-    async def _flush_merged(self, decide_items, t_collect) -> None:
-        """Merge-combine flush (r9): resolve every group's pre-sorted
-        run (arrival prep result, or flush-time prep for stragglers),
-        k-way merge the runs into one sorted batch, and dispatch — no
-        concat + full argsort anywhere. The submit-thread interior is
-        stage-attributed as prep (fallback prep + waiting out unfinished
-        arrival preps), merge, and dispatch; with arrival prep keeping
-        up, prep ~ 0 and merge+dispatch are all that remains serialized.
-        Runs inside submit_call so a conversion error fails THIS batch's
-        callers, never the flusher task."""
-        lens = [
-            it[1]["key_hash"].shape[0]
-            if it[0] == "decide_arrays"
-            else len(it[1])
-            for it in decide_items
-        ]
-
-        def submit_call():
-            runs = []
-            with STAGES.span("prep"):
-                for it in decide_items:
-                    p = self._prep_of(it)
-                    if p is not None:
-                        runs.append(_prep_result(p))
-                    elif it[0] == "decide":
-                        runs.append(
-                            self.backend.prep_reqs(
-                                it[1], [bool(g) for g in it[2]]
-                            )
-                        )
-                    else:
-                        runs.append(self.backend.prep_group(it[1]))
-            with STAGES.span("merge"):
-                merged = self.backend.merge_prepped(runs)
-            with STAGES.span("dispatch"):
-                return self.backend.decide_submit_merged(merged)
-
-        await self._submit_pipelined(
-            submit_call,
-            decide_items,
-            lambda handle, submit_s: self._finish_arrays(
-                handle, decide_items, lens, submit_s, t_collect
-            ),
-        )
-
-    async def _flush_arrays(self, decide_items, t_collect) -> None:
-        """Array-path sibling of the pipelined branch in _flush: convert
-        request-object groups, concatenate all groups into one dense
-        field set, submit once, and let _finish_arrays slice responses
-        back per group. The flatten runs inside submit_call — on the
-        submit thread, where a conversion error (e.g. an out-of-int64
-        value from a JSON caller) fails THIS batch instead of killing
-        the flusher task."""
-        # group lengths are exception-free to read and needed for the
-        # response slicing regardless of submit outcome
-        lens = [
-            it[1]["key_hash"].shape[0]
-            if it[0] == "decide_arrays"
-            else len(it[1])
-            for it in decide_items
-        ]
-
-        def submit_call():
-            # flush-time prep baseline: record the same prep/dispatch
-            # sub-stages the merged path does (merge has no analogue —
-            # the full argsort hides inside decide_submit_arrays'
-            # dispatch), so the BENCH_SUBMIT_r9 A/B compares the same
-            # submit-thread interior either way
-            parts = []
-            with STAGES.span("prep"):
-                for it in decide_items:
-                    if it[0] == "decide":
-                        parts.append(
-                            self.backend.arrays_from_reqs(
-                                it[1], [bool(g) for g in it[2]]
-                            )
-                        )
-                    else:
-                        f = it[1]
-                        if "gnp" not in f:
-                            f = dict(f)
-                            f["gnp"] = np.zeros(
-                                f["key_hash"].shape[0], bool
-                            )
-                        parts.append(f)
-                fields = {
-                    k: (
-                        parts[0][k]
-                        if len(parts) == 1
-                        else np.concatenate([p[k] for p in parts])
-                    )
-                    for k in self.backend.ARRAY_FIELDS
-                }
-            with STAGES.span("dispatch"):
-                return self.backend.decide_submit_arrays(fields)
-
-        await self._submit_pipelined(
-            submit_call,
-            decide_items,
-            lambda handle, submit_s: self._finish_arrays(
-                handle, decide_items, lens, submit_s, t_collect
-            ),
-        )
 
     async def _finish_arrays(
         self, handle, decide_items, lens, submit_s, t_collect
@@ -936,36 +855,6 @@ class DeviceBatcher:
             ),
         )
         self._observe_batch(k, submit_s + (time.monotonic() - t1))
-
-    async def _finish(
-        self, handle, decide_items, submit_s: float, t_collect: float
-    ):
-        t1 = time.monotonic()
-        loop = asyncio.get_running_loop()
-        try:
-            resps = await loop.run_in_executor(
-                self._fetch_pool, self._fetch,
-                self.backend.decide_wait, handle,
-            )
-        except Exception as e:
-            self._fail(decide_items, e)
-            return
-        finally:
-            self._inflight.release()
-        # own cost only: host submit + own fetch span — NOT the time
-        # spent queued behind earlier batches, which would double-count
-        # device time under steady pipelining
-        self._resolve(
-            decide_items, resps, submit_s + (time.monotonic() - t1)
-        )
-        self._stage_device(decide_items, t_collect)
-        self._trace_device(
-            decide_items, t_collect, len(resps),
-            extra=dict(
-                submit_ms=round(submit_s * 1e3, 3),
-                fetch_ms=round((time.monotonic() - t1) * 1e3, 3),
-            ),
-        )
 
     def _fail(self, items, exc: BaseException) -> None:
         # both queue item shapes carry their future last

@@ -266,25 +266,13 @@ class ServerConfig:
     # are untouched, so latency under light load does not change; under
     # saturation per-request latency grows toward one deep-batch period.
     device_deep_batch: bool = False
-    # Arrival-time host prep (r9, GUBER_PREP_AT_ARRIVAL): convert +
-    # hash + ownership/bucket-presort each caller group on a small prep
-    # pool WHEN IT IS ENQUEUED, so groups sit in the device queue as
-    # sorted runs and the submit thread only k-way MERGES them before
-    # dispatch (O(n log k), serve/prep.py) — instead of paying
-    # flatten + concat + full argsort serialized at flush. Only takes
-    # effect on array-capable device backends; decisions are
-    # byte-identical either way (tests/test_prep_pipeline.py).
-    # GUBER_PREP_AT_ARRIVAL=0 restores flush-time prep, the pre-r9
-    # behavior and the A/B baseline of BENCH_SUBMIT_r9.json.
-    # None defers to DeviceBatcher, the single owner of the env read
-    # (same contract as device_fetch_depth / GUBER_FETCH_DEPTH below);
-    # library embedders may pin True/False here instead.
-    prep_at_arrival: Optional[bool] = None
-    # Python prep-pool width. 0 = defer to DeviceBatcher, which owns
-    # the GUBER_PREP_THREADS env read (auto default: min(4, cores-1) —
-    # leave a core for the serving loop). The same env var also sizes
-    # the NATIVE prep pool inside libguberhash (guberhash.cc, default
-    # = cores); one knob governs both tiers of host prep parallelism.
+    # GUBER_PREP_THREADS: width of the batcher's arrival-prep pool
+    # (serve/batcher.py: each caller group is converted, hashed and
+    # presorted when it is ENQUEUED, so the submit thread only k-way
+    # merges sorted runs). 0 = auto: min(4, cores-1), leaving a core
+    # for the serving loop. The same env var also sizes the NATIVE prep
+    # pool inside libguberhash (guberhash.cc, default = cores); one
+    # knob governs both tiers of host prep parallelism.
     prep_threads: int = 0
     # Over-limit shed cache (r10, serve/shedcache.py): a bounded host
     # LRU of frozen token-bucket over-limit verdicts consulted BEFORE a
@@ -446,9 +434,9 @@ class ServerConfig:
     # in-flight device batches the batcher keeps before stalling submits.
     # 2 suits the co-located chip (PCIe fetch ~0.1ms): one batch in
     # fetch, one in submit. Fetches pipeline, so served throughput is
-    # ~depth/RTT batches/s while the fetch RTT exceeds the batch time.
-    # None = resolve GUBER_FETCH_DEPTH in the batcher (default 2).
-    device_fetch_depth: Optional[int] = None
+    # ~depth/RTT batches/s while the fetch RTT exceeds the batch time
+    # (GUBER_FETCH_DEPTH).
+    device_fetch_depth: int = 2
 
     # static peers: list of gRPC addresses; advertise address must appear
     peers: List[str] = field(default_factory=list)
@@ -924,14 +912,8 @@ def config_from_env(env: Optional[dict] = None) -> ServerConfig:
             for p in _get(env, "GUBER_CHECKPOINT_EXPORT_PEERS").split(",")
             if p.strip()
         ],
-        # prep_at_arrival / prep_threads deliberately NOT resolved
-        # here: their None/0 defaults defer to DeviceBatcher, the
-        # single owner of the GUBER_PREP_AT_ARRIVAL /
-        # GUBER_PREP_THREADS env reads (batcher.py __init__) — the
-        # same contract as device_fetch_depth below
-        # device_fetch_depth deliberately NOT resolved here: the field's
-        # None default defers to DeviceBatcher, the single owner of the
-        # GUBER_FETCH_DEPTH env read (batcher.py __init__)
+        prep_threads=_get_int(env, "GUBER_PREP_THREADS", 0),
+        device_fetch_depth=_get_int(env, "GUBER_FETCH_DEPTH", 2),
         peers=peers,
         etcd_endpoints=etcd,
         etcd_prefix=_get(env, "GUBER_ETCD_PREFIX", "/gubernator-tpu/peers/"),

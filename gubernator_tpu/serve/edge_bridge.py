@@ -153,6 +153,7 @@ from gubernator_tpu.api.types import (
     RateLimitResp,
 )
 from gubernator_tpu.serve import metrics, tracing
+from gubernator_tpu.serve.batcher import is_device_backend
 from gubernator_tpu.serve.config import MAX_BATCH_SIZE
 from gubernator_tpu.serve.faults import FAULTS
 from gubernator_tpu.serve.stages import STAGES
@@ -669,11 +670,7 @@ class FrameService:
         backend. Deliberately independent of the GUBER_EDGE_FAST kill
         switch: that switch governs the pre-hashed WIRE protocol, not
         this node's ability to decide arrays."""
-        backend = getattr(self.instance, "backend", None)
-        return (
-            getattr(backend, "decide_submit_arrays", None) is not None
-            and getattr(backend, "decide_submit", None) is not None
-        )
+        return is_device_backend(getattr(self.instance, "backend", None))
 
     def _fast_ok(self) -> bool:
         """Pre-hashed frames need a backend that takes arrays. Ring

@@ -85,10 +85,9 @@ class Instance:
             backend,
             batch_wait=conf.device_batch_wait,
             batch_limit=conf.device_batch_limit,
-            fetch_depth=getattr(conf, "device_fetch_depth", None),
-            deep_batch=getattr(conf, "device_deep_batch", False),
-            prep_at_arrival=getattr(conf, "prep_at_arrival", None),
-            prep_threads=getattr(conf, "prep_threads", None) or None,
+            fetch_depth=conf.device_fetch_depth,
+            deep_batch=conf.device_deep_batch,
+            prep_threads=conf.prep_threads,
         )
         self.global_mgr = GlobalManager(conf.behaviors, self)
         # distributed tracing (r16, serve/tracing.py): per-instance so

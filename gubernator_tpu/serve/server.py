@@ -520,12 +520,9 @@ class Server:
 
             log.info(
                 "native prep: %d thread(s) (GUBER_PREP_THREADS), "
-                "writeback=%s (GUBER_WRITEBACK), arrival prep %s "
-                "(GUBER_PREP_AT_ARRIVAL)",
+                "writeback=%s (GUBER_WRITEBACK)",
                 _hn.prep_threads(),
                 os.environ.get("GUBER_WRITEBACK", "auto"),
-                "on" if self.instance.batcher.prep_at_arrival
-                and self.instance.batcher._prep_ok else "off",
             )
         else:
             log.info(

@@ -48,18 +48,17 @@ Stages form two families:
   exceed their sum). None of these enter per-frame coverage — the
   r7 contract (frame-flagged groups only) is untouched.
 
-    submit_host      decide_submit* call on the submit thread
-                     (admission -> handle, incl. executor queueing)
-    prep             submit-thread group prep: flush-time fallback
-                     conversion/presort of un-prepped groups, plus
-                     waiting out arrival preps that hadn't finished
-                     (~0 when GUBER_PREP_AT_ARRIVAL keeps up)
+    submit_host      the submit thread's call (batcher._flush_merged:
+                     admission -> handle, incl. executor queueing)
+    prep             waiting out arrival preps that hadn't finished
+                     (~0 when the prep pool keeps up), plus the
+                     conversion/presort of a group that carries no
+                     prep future
     merge            k-way merge of the groups' pre-sorted runs into
-                     one sorted batch (serve/prep.py); absent on the
-                     flush-time baseline path, whose full argsort
-                     hides inside dispatch
-    dispatch         backend decide_submit_presorted/_arrays call:
-                     pad + group-derive + input pack + device dispatch
+                     one sorted, padded batch with its duplicate-key
+                     groups derived (serve/prep.py, merge_prepped)
+    dispatch         backend decide_submit_merged call: epoch
+                     bookkeeping + input pack + device dispatch
     jit_call         the jitted decide call alone, inside dispatch
                      (PartitionedEngine._dispatch): the transfer of
                      the batch's ONE packed input array + launch, the

@@ -411,38 +411,6 @@ def table_resilience_knobs() -> str:
     return "\n".join(lines)
 
 
-def table_host_prep() -> str:
-    """Arrival-time host-prep A/B (r9), from BENCH_SUBMIT_r9.json: the
-    submit-thread interior (prep+merge+dispatch per device batch) and
-    end-to-end decisions/s with GUBER_PREP_AT_ARRIVAL off vs on,
-    interleaved rounds on one box."""
-    doc = json.loads((ROOT / "BENCH_SUBMIT_r9.json").read_text())
-    ms = doc["median_submit_ms_per_batch"]
-    dec = doc["median_decisions_per_sec"]
-    lines = [
-        "| GUBER_PREP_AT_ARRIVAL | submit interior (prep+merge+"
-        "dispatch) / batch | decisions/s |",
-        "|---|---|---|",
-        f"| 0 (flush-time prep, pre-r9) | {ms['off']:.2f} ms "
-        f"| {dec['off']:,.0f} |",
-        f"| 1 (arrival prep + merge combine) | {ms['on']:.2f} ms "
-        f"| {dec['on']:,.0f} |",
-        "",
-        f"(medians of {doc['rounds_per_mode']} interleaved rounds per "
-        f"mode, {doc['workers']} workers x {doc['batch_items']}-item "
-        f"frames through the compiled edge gRPC door — the "
-        f"BENCH_STAGES_r7 workload; submit interior drop "
-        f"**{doc['submit_drop']:.0%}** "
-        f"(paired per-round median "
-        f"{doc.get('paired_submit_drop', doc['submit_drop']):.0%}, "
-        f"decisions/s parity "
-        f"{doc.get('paired_decisions_ratio', 1.0):.2f}x). Methodology, "
-        f"scope, and the CPU-container acceptance note are in the "
-        f"artifact.)",
-    ]
-    return "\n".join(lines)
-
-
 def table_shed() -> str:
     """Over-limit shed cache A/B (r10), from BENCH_SHED_r10.json: the
     bridge-tier screen's decisions/s OFF vs ON per over-limit traffic
@@ -812,7 +780,6 @@ TABLES = {
     "served-throughput-table": table_served_throughput,
     "edge-cluster-table": table_edge_cluster,
     "resilience-knobs-table": table_resilience_knobs,
-    "host-prep-table": table_host_prep,
     "shed-table": table_shed,
     "frontdoor-table": table_frontdoor,
     "sketch-table": table_sketch,

@@ -12,8 +12,6 @@ names which BENCH_* artifact motivates each workload):
 
   shed_r10    shed-share-0.9 workload, shed cache OFF vs ON
               (BENCH_SHED_r10.json's screen, bridge tier)
-  submit_r9   saturation workload, GUBER_PREP_AT_ARRIVAL OFF vs ON
-              (BENCH_SUBMIT_r9.json's host-prep pipeline)
   stages_r7   saturation workload, credit window 1 (round-trip, the
               pre-r7 shape) vs the full advertised window
               (BENCH_STAGES_r7/BENCH_SERVING_DEVICE_r7's pipelining)
@@ -88,7 +86,6 @@ SHARDS = 4
 
 GATED = (
     "shed_r10",
-    "submit_r9",
     "stages_r7",
     "sketch_r13",
     "sketch2_r21",
@@ -355,7 +352,6 @@ def main() -> int:
     instance = cluster.servers[0].instance
     shed_obj = instance.shed
     assert shed_obj is not None, "gate expects the shipped defaults"
-    batcher = instance.batcher
 
     inject_spec = (
         f"edge_frame:delay={args.inject_frame_ms}ms"
@@ -369,12 +365,6 @@ def main() -> int:
     def flip_shed(on: bool):
         async def f():
             instance.shed = shed_obj if on else None
-
-        cluster.run(f())
-
-    def flip_prep(on: bool):
-        async def f():
-            batcher.prep_at_arrival = on
 
         cluster.run(f())
 
@@ -436,21 +426,6 @@ def main() -> int:
         m, rows = paired("shed_r10", shed_off, drive,
                          args.seconds, args.rounds)
         measured["shed_r10"], detail["shed_r10"] = m, rows
-
-        # -- submit_r9: arrival prep OFF vs ON, saturation shape -----
-        print("workload submit_r9 (prep OFF vs ON)...", file=sys.stderr)
-        drive0 = bridge_drive(0.0)
-
-        def prep_off(s):
-            flip_prep(False)
-            try:
-                return drive0(s)
-            finally:
-                flip_prep(True)
-
-        m, rows = paired("submit_r9", prep_off, drive0,
-                         args.seconds, args.rounds)
-        measured["submit_r9"], detail["submit_r9"] = m, rows
 
         # -- stages_r7: window 1 (round-trip) vs full window ---------
         # smaller frames than the saturation shape: at 1000-item
@@ -1069,12 +1044,6 @@ def main() -> int:
                             f"{args.share} shed workload",
                     "committed": round(measured["shed_r10"], 4),
                 },
-                "submit_r9": {
-                    "artifact": "BENCH_SUBMIT_r9.json",
-                    "pair": "GUBER_PREP_AT_ARRIVAL off vs on, "
-                            "saturation workload",
-                    "committed": round(measured["submit_r9"], 4),
-                },
                 "stages_r7": {
                     "artifact": "BENCH_STAGES_r7.json",
                     "pair": "credit window 1 (round-trip) vs full "
@@ -1230,9 +1199,6 @@ def main() -> int:
             "env_knobs": {
                 "GUBER_GEB_PORT": str(GEB_PORT),
                 "GUBER_SHED_CACHE": "1",
-                "GUBER_PREP_AT_ARRIVAL": os.environ.get(
-                    "GUBER_PREP_AT_ARRIVAL", "1"
-                ),
                 "GUBER_DEVICE_BATCH_LIMIT": str(
                     args.device_batch_limit
                 ),

@@ -13,7 +13,7 @@ per-item protobuf work (~110k dec/s regardless of serving-side
 changes — the r7 any-protocol ceiling), which would mask the device
 work the shed removes.
 
-Methodology is the r9 profile-submit recipe: load generated OUT of
+Methodology is the r9 paired-rounds recipe: load generated OUT of
 process (in-process client threads thrash the serving GIL), the shed
 flipped at runtime between INTERLEAVED short OFF/ON rounds with
 alternating within-round order (ambient throttling on a shared box
@@ -381,7 +381,7 @@ def main() -> int:
                     "shape). INTERLEAVED short OFF/ON rounds flip "
                     "instance.shed in-process (alternating order); "
                     "paired per-round ratios are the drift-robust "
-                    "headline, per the r9 profile-submit methodology."
+                    "headline, per the r9 paired-rounds methodology."
                 ),
                 "host_cpus": os.cpu_count(),
                 "seconds_per_round": args.seconds,
@@ -394,9 +394,6 @@ def main() -> int:
                     "GUBER_SHED_CACHE_KEYS": str(shed_obj.capacity),
                     "GUBER_DEVICE_BATCH_LIMIT": str(
                         args.device_batch_limit
-                    ),
-                    "GUBER_PREP_AT_ARRIVAL": os.environ.get(
-                        "GUBER_PREP_AT_ARRIVAL", "1"
                     ),
                 },
                 "series": {str(k): v for k, v in series.items()},

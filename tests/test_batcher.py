@@ -1,10 +1,11 @@
 """DeviceBatcher unit tests: the pipelined submit/wait flusher.
 
-The serving claim under test: with a backend exposing
-decide_submit/decide_wait, the flusher submits batch N+1 while batch N's
-fetch is still in flight (throughput tracks max(host, device) per batch,
-not the sum), submits stay strictly serialized, a failed fetch fails only
-its own batch, and backends without the split still work unchanged.
+The serving claim under test: with a device backend (one offering
+decide_submit_merged and the surface that goes with it), the flusher
+submits batch N+1 while batch N's fetch is still in flight (throughput
+tracks max(host, device) per batch, not the sum), submits stay strictly
+serialized, a failed fetch fails only its own batch, and host backends
+(a blocking decide only) still work unchanged.
 """
 
 import asyncio
@@ -23,7 +24,10 @@ def _req(i: int) -> RateLimitReq:
 
 
 class PipelinedFake:
-    """Records submit/wait interleaving; waits block until released."""
+    """A device backend as the batcher sees one — the launch surface of
+    serve/backends.py _ArrayOps over plain lists: a group's "sorted
+    run" is its request list, a merged batch their concatenation.
+    Records submit/wait interleaving; waits block until released."""
 
     def __init__(self):
         self.submits = []
@@ -33,7 +37,16 @@ class PipelinedFake:
         self.concurrent_submits = 0
         self.fail_wait_for = set()
 
-    def decide_submit(self, reqs, gnp, now=None):
+    def prep_reqs(self, reqs, gnp):
+        return list(reqs)
+
+    def prep_group(self, fields):
+        raise AssertionError("these tests enqueue no array groups")
+
+    def merge_prepped(self, runs):
+        return [r for run in runs for r in run]
+
+    def decide_submit_merged(self, reqs, now=None):
         with self.lock:
             self.concurrent_submits += 1
             assert self.concurrent_submits == 1, "submits must serialize"
@@ -46,7 +59,7 @@ class PipelinedFake:
             with self.lock:
                 self.concurrent_submits -= 1
 
-    def decide_wait(self, handle):
+    def decide_wait_arrays(self, handle):
         idx, reqs = handle
         assert self.releases[idx].wait(timeout=30), (
             f"fetch {idx} never released"
@@ -54,7 +67,30 @@ class PipelinedFake:
         self.waits.append(idx)
         if idx in self.fail_wait_for:
             raise RuntimeError(f"fetch {idx} failed")
-        return [RateLimitResp(limit=r.limit, remaining=7) for r in reqs]
+        n = len(reqs)
+        return [0] * n, [r.limit for r in reqs], [7] * n, [0] * n
+
+    @staticmethod
+    def resps_from_arrays(status, limit, remaining, reset):
+        return [
+            RateLimitResp(limit=lim, remaining=rem)
+            for lim, rem in zip(limit, remaining)
+        ]
+
+
+def test_half_built_device_backend_refused_at_construction():
+    """Offering decide_submit_merged makes a backend a device backend;
+    one that then lacks part of the launch surface must fail loudly in
+    DeviceBatcher.__init__ — not silently take some slower route, and
+    not at its first flush."""
+
+    class NoMerge(PipelinedFake):
+        merge_prepped = None
+
+    with pytest.raises(TypeError, match="merge_prepped"):
+        DeviceBatcher(NoMerge(), batch_wait=0)
+    assert DeviceBatcher(PipelinedFake(), batch_wait=0)._device
+    assert not DeviceBatcher(BlockingFake(), batch_wait=0)._device
 
 
 @pytest.fixture()
@@ -353,7 +389,7 @@ def test_deep_batch_respects_batch_limit(loop_run):
 
 
 class BlockingFake:
-    """A backend with only the blocking decide() — the fallback path."""
+    """A host backend: only the blocking decide()."""
 
     def __init__(self):
         self.calls = 0
@@ -363,7 +399,7 @@ class BlockingFake:
         return [RateLimitResp(limit=r.limit, remaining=3) for r in reqs]
 
 
-def test_non_pipelined_backend_fallback(loop_run):
+def test_host_backend_blocking_decide(loop_run):
     async def scenario():
         be = BlockingFake()
         b = DeviceBatcher(be, batch_wait=0, batch_limit=8)

@@ -32,8 +32,7 @@ EDGE_BIN = edge_binary()
 
 
 class _ArrBackend:
-    decide_submit_arrays = object()
-    decide_submit = object()
+    decide_submit_merged = object()  # a device backend
 
 
 class _Traffic:

@@ -36,10 +36,18 @@ def test_start_serves_and_stop_terminates():
         c.stop()
     assert c._thread is None or not c._thread.is_alive()
     assert c.servers == []
-    # the ports are released (a new bind succeeds)
+    # the listeners are gone: a server could bind the ports again.
+    # Probed the way a server binds, with SO_REUSEADDR: a connection
+    # the stopping server closed before its client did (the channels
+    # above close on gRPC's own threads, so on a loaded box stop() can
+    # win that race) lingers on the port in FIN-WAIT/TIME-WAIT, and a
+    # plain bind takes that remnant for a holder (EADDRINUSE, the one
+    # failure of the six-worker runs at PRs 26-27). A listener still
+    # bound refuses this bind too, so the check loses nothing.
     for a in addrs:
         host, _, port = a.rpartition(":")
         with socket.socket() as s:
+            s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
             s.bind((host, int(port)))
 
 

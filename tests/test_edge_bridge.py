@@ -165,8 +165,7 @@ def test_response_roundtrip():
 
 
 class _FakeBackendArrays:
-    decide_submit_arrays = object()
-    decide_submit = object()
+    decide_submit_merged = object()  # a device backend
 
 
 class _FakeTraffic:

@@ -56,8 +56,7 @@ class FakePicker:
 class Inst:
     def __init__(self, self_host, hosts):
         class FakeBackend:
-            decide_submit_arrays = object()
-            decide_submit = object()
+            decide_submit_merged = object()  # a device backend
 
         self.backend = FakeBackend()
         self.picker = FakePicker([(h, h == self_host) for h in hosts])

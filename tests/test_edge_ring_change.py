@@ -42,8 +42,7 @@ NODE_B = "10.99.0.2:81"  # joins later, bridge on 127.0.0.1 TCP
 
 
 class FakeBackend:
-    decide_submit_arrays = object()
-    decide_submit = object()
+    decide_submit_merged = object()  # a device backend
 
 
 class FakePicker:

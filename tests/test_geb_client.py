@@ -25,8 +25,7 @@ class FakePeer:
 
 
 class _FakeBackendArrays:
-    decide_submit_arrays = object()
-    decide_submit = object()
+    decide_submit_merged = object()  # a device backend
 
 
 class _FakeTraffic:

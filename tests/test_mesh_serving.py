@@ -3,8 +3,8 @@ engine behind a real gRPC server (instance + batcher + warmup), not just
 the engine-level suite in test_sharded.py.
 
 Covers the production wiring GUBER_BACKEND=mesh uses: warmup compiles
-the sub-batch rung ladder through the public decide path, the pipelined
-decide_submit/decide_wait split engages via the batcher, GLOBAL owned
+the sub-batch rung ladder through the public decide path, the batcher
+launches through the pipelined merged route, GLOBAL owned
 keys broadcast-and-install across the mesh shards, and the oracle
 semantics hold over the wire.
 """

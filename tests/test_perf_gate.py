@@ -73,7 +73,7 @@ def test_baseline_manifest_guards_the_committed_artifacts():
     # the interior wins (incl. the r13 sketch pair) + the two
     # public-door ratios are guarded
     for name in (
-        "shed_r10", "submit_r9", "stages_r7", "sketch_r13",
+        "shed_r10", "stages_r7", "sketch_r13",
         "shard_r14",
         "frontdoor_geb_over_grpc", "frontdoor_http_over_grpc",
     ):

@@ -4,11 +4,12 @@ The serving contract under test: a device batch built by MERGING the
 caller groups' pre-sorted runs (arrival-time prep, serve/prep.py +
 engine decide_submit_presorted) is byte-identical — padded request
 fields, duplicate-key group structure, and response permutation — to
-the flush-time concat + full-argsort path it replaces, across mixed
-request-object/array groups, duplicate keys, GNP flags, saturating
-values, empty groups, and carry overflow; and that arrival-time vs
-flush-time prep produce identical decisions, responses slice back to
-the right callers, and stop() mid-prep strands no futures.
+the engines' concat + full-argsort reference (decide_submit), across
+mixed request-object/array groups, duplicate keys, GNP flags, saturating
+values, empty groups, and carry overflow; and that a group prepped at
+arrival and one prepped at the flush (no prep future) produce identical
+decisions, responses slice back to the right callers, and stop()
+mid-prep strands no futures.
 """
 
 import asyncio
@@ -31,9 +32,10 @@ from gubernator_tpu.parallel.sharded import (
     prep_run_sharded,
     sub_batch_ladder,
 )
-from gubernator_tpu.serve.backends import TpuBackend
+from gubernator_tpu.serve.backends import MeshBackend, TpuBackend
 from gubernator_tpu.serve.batcher import DeviceBatcher
 from gubernator_tpu.serve.prep import merge_runs, merge_sorted_runs
+from gubernator_tpu.serve.stages import STAGES
 
 BUCKETS = (64, 256, 1024)
 SLOTS = 1 << 10
@@ -205,9 +207,10 @@ def test_merged_fields_byte_identical_sharded():
 
 def test_engine_presorted_matches_concat_argsort_end_to_end():
     """Twin engines, same batches, same clock: one decides via the
-    flush-time array path (decide_submit_arrays), the other via
-    arrival-prep + merge (decide_submit_presorted). Every response
-    array — and therefore every store mutation — must be identical."""
+    engine's concat + argsort reference (decide_submit), the other via
+    the served path, prep + merge (decide_submit_merged). Every
+    response array — and therefore every store mutation — must be
+    identical."""
     be_a = TpuBackend(StoreConfig(rows=4, slots=SLOTS), buckets=BUCKETS)
     be_b = TpuBackend(StoreConfig(rows=4, slots=SLOTS), buckets=BUCKETS)
     rng = np.random.default_rng(0xD0)
@@ -220,8 +223,8 @@ def test_engine_presorted_matches_concat_argsort_end_to_end():
             for _ in range(k)
         ]
         cat = _concat(groups)
-        ra = be_a.decide_wait_arrays(
-            be_a.decide_submit_arrays(dict(cat), now=now)
+        ra = be_a.engine.decide_wait(
+            be_a.engine.decide_submit(now=now, **cat)
         )
         merged = be_b.merge_prepped(
             [be_b.prep_group(dict(g)) for g in groups]
@@ -247,6 +250,18 @@ def _mk_reqs(tag, n, limit=1000):
     ]
 
 
+def _array_group40():
+    """One 40-row array group (no gnp) whose limit column, 5000..5039,
+    echoes back, so a slicing error shows per row."""
+    return dict(
+        key_hash=np.arange(1, 41, dtype=np.uint64) << np.uint64(32),
+        hits=np.ones(40, np.int64),
+        limit=np.arange(5000, 5040, dtype=np.int64),
+        duration=np.full(40, 60_000, np.int64),
+        algo=np.zeros(40, np.int32),
+    )
+
+
 def _run(coro):
     return asyncio.run(coro)
 
@@ -262,21 +277,11 @@ def test_batcher_merged_slicing_mixed_groups():
         be = TpuBackend(
             StoreConfig(rows=4, slots=SLOTS), buckets=BUCKETS
         )
-        b = DeviceBatcher(
-            be, batch_wait=0, batch_limit=256, prep_at_arrival=True
-        )
-        assert b._prep_ok
+        b = DeviceBatcher(be, batch_wait=0, batch_limit=256)
+        assert b._device
         # enqueue BEFORE starting the flusher: one deterministic batch
         # composition (plus the carry group that overflows it)
-        fields = dict(
-            key_hash=(
-                np.arange(1, 41, dtype=np.uint64) << np.uint64(32)
-            ),
-            hits=np.ones(40, np.int64),
-            limit=np.arange(5000, 5040, dtype=np.int64),
-            duration=np.full(40, 60_000, np.int64),
-            algo=np.zeros(40, np.int32),
-        )
+        fields = _array_group40()
         tasks = [
             asyncio.ensure_future(b.decide(_mk_reqs("a", 30), [False] * 30)),
             asyncio.ensure_future(b.decide_arrays(dict(fields))),
@@ -304,19 +309,78 @@ def test_batcher_merged_slicing_mixed_groups():
     _run(scenario())
 
 
+@pytest.mark.parametrize("shape", ["objects", "arrays", "mixed"])
+def test_every_batch_launches_through_the_merged_route(shape, monkeypatch):
+    """One launch path: whatever a batch is made of, a device backend's
+    batch is prepped, merged and dispatched — each span once on the
+    stage clock, which is what the benchmark's submit_host /
+    dispatch metrics read. The engine's argsort submit is the
+    reference, never the served path, and the backend offers the
+    batcher no second way to launch."""
+    be = TpuBackend(StoreConfig(rows=4, slots=SLOTS), buckets=BUCKETS)
+    assert not hasattr(be, "decide_submit")
+
+    def reference_only(*a, **k):
+        raise AssertionError("engine.decide_submit on the served path")
+
+    monkeypatch.setattr(be.engine, "decide_submit", reference_only)
+    fields = _array_group40()
+
+    async def scenario():
+        b = DeviceBatcher(be, batch_wait=0, batch_limit=256)
+        tasks = []
+        if shape != "arrays":
+            tasks.append(b.decide(_mk_reqs("o", 30), [False] * 30))
+        if shape != "objects":
+            tasks.append(b.decide_arrays(fields))
+        tasks = [asyncio.ensure_future(t) for t in tasks]
+        await asyncio.sleep(0)  # everything enqueued: ONE batch
+        b.start()
+        out = await asyncio.gather(*tasks)
+        await b.stop()
+        return out
+
+    def counts():
+        st = STAGES.snapshot()["stages"]
+        return [
+            st.get(name, {"count": 0})["count"]
+            for name in ("prep", "merge", "dispatch", "submit_host")
+        ]
+
+    before = counts()
+    out = _run(scenario())
+    assert [a - b for a, b in zip(counts(), before)] == [1, 1, 1, 1]
+    if shape != "arrays":
+        assert [r.limit for r in out[0]] == [1000 + i for i in range(30)]
+    if shape != "objects":
+        assert list(out[-1][1]) == list(range(5000, 5040))
+
+
+def test_mesh_backend_refuses_engine_without_launch_surface():
+    """Every in-tree engine carries prep_run / merge_prepped /
+    decide_submit_merged; one that does not is an AttributeError when
+    the backend is built, not a silently slower route."""
+
+    class NoPrep:
+        def decide_arrays(self, **kw):
+            raise NotImplementedError
+
+    with pytest.raises(AttributeError, match="prep_run"):
+        MeshBackend(engine=NoPrep())
+
+
 def test_arrival_vs_flush_prep_identical_decisions():
-    """Same traffic, same pinned clock, twin backends: arrival-time
-    prep ON vs the flush-time fallback (prep futures suppressed) must
-    produce identical responses — prepping earlier changes WHERE the
-    work runs, never the result."""
+    """Same traffic, same pinned clock, twin backends: groups prepped
+    at arrival vs groups that reach the flush with no prep future (what
+    a stop() racing the enqueue leaves behind; the submit thread preps
+    them) must produce identical responses — prepping earlier changes
+    WHERE the work runs, never the result."""
 
     async def run_once(suppress_kick):
         be = TpuBackend(
             StoreConfig(rows=4, slots=SLOTS), buckets=BUCKETS
         )
-        b = DeviceBatcher(
-            be, batch_wait=0, batch_limit=1024, prep_at_arrival=True
-        )
+        b = DeviceBatcher(be, batch_wait=0, batch_limit=1024)
         if suppress_kick:
             b._kick_prep = lambda *a, **k: None
         tasks = [
@@ -355,8 +419,7 @@ def test_stop_mid_prep_strands_no_futures():
             StoreConfig(rows=4, slots=SLOTS), buckets=BUCKETS
         )
         b = DeviceBatcher(
-            be, batch_wait=0.05, batch_limit=1024,
-            prep_at_arrival=True, prep_threads=1,
+            be, batch_wait=0.05, batch_limit=1024, prep_threads=1,
         )
         real_prep = be.prep_group
         started = threading.Event()
@@ -436,16 +499,13 @@ def test_decide_arrays_empty_group_dtype_contract():
 def test_merged_path_conversion_error_fails_batch_not_flusher():
     """A group whose arrival prep raises (out-of-int64 value) fails
     that batch's callers with per-item errors — and the flusher stays
-    alive to serve the next batch (parity with the flush-time path's
-    failure envelope)."""
+    alive to serve the next batch."""
 
     async def scenario():
         be = TpuBackend(
             StoreConfig(rows=4, slots=SLOTS), buckets=BUCKETS
         )
-        b = DeviceBatcher(
-            be, batch_wait=0, batch_limit=1024, prep_at_arrival=True
-        )
+        b = DeviceBatcher(be, batch_wait=0, batch_limit=1024)
         b.start()
         bad = [
             RateLimitReq(

@@ -109,8 +109,7 @@ def _expected_counts(hosts, reqs):
 
 
 class FakeBackend:
-    decide_submit_arrays = object()
-    decide_submit = object()
+    decide_submit_merged = object()  # a device backend
 
 
 class FakePicker:
