@@ -203,6 +203,20 @@ SHED_ENTRIES = Gauge(
     "GUBER_SHED_CACHE_KEYS)",
     registry=REGISTRY,
 )
+SHED_INDEX_USES = Gauge(
+    "shed_index_uses_total",
+    "Consults of the shed cache's sorted fingerprint index by the "
+    "array paths (screen_fields / observe_fields: two a GEB frame)",
+    registry=REGISTRY,
+)
+SHED_INDEX_REBUILDS = Gauge(
+    "shed_index_rebuilds_total",
+    "Re-sorts of that index: only a change of the cached KEY SET "
+    "leads to one, once the overlay of new fingerprints is full; a "
+    "change of a cached value never does. rebuilds / uses near 1 "
+    "means every frame pays a sort",
+    registry=REGISTRY,
+)
 FAULTS_INJECTED = Counter(
     "faults_injected_total",
     "Injected faults fired (serve/faults.py, GUBER_FAULT_SPEC) — a "

@@ -143,7 +143,7 @@ def make_backend(conf: ServerConfig):
         # STRICT hard-fails only when a HOST-side part was explicitly
         # sized (the operator oversubscribed on purpose): the device
         # tiers always fit by the carve-out, but the DEFAULT shed
-        # cache (~12.5 MiB) overflows any tiny budget on its own, and
+        # cache (~20 MiB) overflows any tiny budget on its own, and
         # failing a pre-r13 strict config whose knobs never changed
         # would be a regression — those boots warn instead
         fields = type(conf).__dataclass_fields__
@@ -1064,6 +1064,8 @@ class Server:
             metrics.SHED_HITS.set(shed.hits)
             metrics.SHED_LOOKUPS.set(shed.lookups)
             metrics.SHED_ENTRIES.set(len(shed))
+            metrics.SHED_INDEX_USES.set(shed.index_uses)
+            metrics.SHED_INDEX_REBUILDS.set(shed.index_rebuilds)
         if self.instance.repl is not None:
             metrics.REPLICATION_STANDBY_ENTRIES.set(
                 self.instance.repl.standby_len

@@ -56,6 +56,21 @@ until its reset_time — the fail-closed direction for a rate limiter,
 and the same over-admission-adjacent envelope the store's eviction
 counters already flag.
 
+Structure: two tiers consult it in two shapes. The instance tier
+probes one request at a time (lookup_resp / observe_resps): a dict of
+fingerprint -> (limit, duration, reset_time, slot) in LRU order
+answers those, O(1) each. The bridge tier screens whole thousand-item
+frames (screen_fields / observe_fields): every verdict also owns a
+slot of four numpy columns, found through a sorted index of the
+fingerprints plus a small overlay of those bound since the last sort.
+The cached VALUES change all the time — a one-second window gets a
+new reset_time every second, verdicts are confirmed, contradicted,
+purged — and each such change is a scalar write to a slot; only a
+change of the cached KEY SET reaches the index, one argsort per
+OVERLAY_MAX new fingerprints (class docstring; counted by
+index_uses / index_rebuilds). Nothing on the frame path iterates over
+the entries in Python.
+
 Thread model: event-loop confined like the rest of the serving tier
 (the bridge and instance both consult from the loop); the only
 cross-thread reader is the /metrics scrape, which reads plain ints.
@@ -63,9 +78,10 @@ cross-thread reader is the /metrics scrape, which reads plain ints.
 
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from gubernator_tpu.api.types import (
     Algorithm,
@@ -97,13 +113,24 @@ assert SHEDDABLE_ALGOS == {ALGO_TOKEN}, (
 #: Zipf workload can keep over limit at once, not the whole key space
 DEFAULT_KEYS = 1 << 16
 
-#: rough per-entry host footprint (OrderedDict node + uint64 key + the
-#: 3-int tuple) used by the boot-time lint, measured on CPython 3.10
-ENTRY_BYTES = 200
+#: rough per-entry host footprint used by the boot-time lint, measured
+#: on CPython 3.10 at the default bound: ~260 B of OrderedDict node,
+#: uint64 key and 4-int tuple, 32 B of slot columns, 16 B of sorted
+#: index, the columns' doubling slack
+ENTRY_BYTES = 320
 
 #: per-call bound on observe_fields' population walk (uncached frozen
 #: verdicts); correctness rows (cached fingerprints) are never capped
 OBSERVE_INSERT_CAP = 512
+
+#: fingerprints bound to a slot since the last sort wait in a small
+#: overlay (searched beside the sorted index); one more than this and
+#: the next consult folds them in, by one argsort of the fingerprint
+#: column
+OVERLAY_MAX = 256
+
+#: slot columns start this long and double up to the capacity
+_FIRST_SLOTS = 1024
 
 
 def footprint_mib(keys: int) -> float:
@@ -139,7 +166,22 @@ class ShedCache:
     Keys are the uint64 slot-hash fingerprints the device store is
     addressed by (core/hashing.slot_hash_batch) — shared between the
     instance tier (which hashes key strings once per batch anyway) and
-    the bridge tier (whose fast frames arrive pre-hashed)."""
+    the bridge tier (whose fast frames arrive pre-hashed).
+
+    Every live verdict owns one SLOT of four numpy columns
+    (fingerprint, limit, duration, reset_time): the table the
+    vectorized screen gathers from. `_entries` maps fingerprint ->
+    (limit, duration, reset_time, slot) in LRU order for the point
+    operations, which never read a numpy scalar. A change of VALUE
+    (new reset_time, confirm, drop) is a write in place; a dropped
+    slot reads reset_time 0 — a live verdict's is a unix-ms instant in
+    the future — which `now < reset_time` already refuses, until a
+    fresh verdict is written to it. Only a change of the KEY SET
+    touches the lookup index, and lazily: a fingerprint bound to a
+    slot waits in an unsorted overlay of at most OVERLAY_MAX, folded
+    into the sorted index by one argsort of the fingerprint column. A
+    stale index row (its slot dropped or recycled since the sort) is
+    harmless: a match is confirmed against the slot's own fingerprint."""
 
     def __init__(
         self,
@@ -153,21 +195,27 @@ class ShedCache:
         # backend never wholesale-resets (exact backend)
         self.generation_fn = generation_fn
         self._gen = generation_fn() if generation_fn is not None else 0
-        # fingerprint -> (limit, duration, reset_time_unix_ms)
-        self._entries: "OrderedDict[int, Tuple[int, int, int]]" = (
+        # fingerprint -> (limit, duration, reset_time_unix_ms, slot),
+        # least recently inserted-or-looked-up first
+        self._entries: "OrderedDict[int, Tuple[int, int, int, int]]" = (
             OrderedDict()
         )
-        # vectorized-screen snapshot (sorted key/limit/duration/reset
-        # arrays), rebuilt lazily after any mutation: the bridge
-        # screens thousand-item frames, and per-item dict probes from
-        # a Python loop measured ~1.4 ms/frame on a throttled 2-core
-        # box — a searchsorted against a sorted snapshot is ~30 us.
-        # Under steady over-limit load the entry set barely changes,
-        # so rebuilds (O(entries)) are rare.
-        self._snap = None
+        # the slots: a column each for fingerprint, limit, duration
+        # and reset_time (0 = no live verdict here)
+        n = min(self.capacity, _FIRST_SLOTS)
+        self._fp = np.zeros(n, np.uint64)
+        self._lim = np.zeros(n, np.int64)
+        self._dur = np.zeros(n, np.int64)
+        self._reset = np.zeros(n, np.int64)
+        self._new_fp = np.zeros(OVERLAY_MAX, np.uint64)
+        self._new_slot = np.zeros(OVERLAY_MAX, np.int64)
+        self._top = 0
+        self.purge_all()
         # monotonic counters (ints: GIL-atomic, scrape reads them raw)
         self.hits = 0
         self.lookups = 0
+        self.index_uses = 0  # screen_fields / observe_fields consults
+        self.index_rebuilds = 0  # re-sorts of the index
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -179,6 +227,15 @@ class ShedCache:
         # otherwise make `if shed:` silently skip population
         return True
 
+    def __contains__(self, h: int) -> bool:
+        return h in self._entries
+
+    def get(self, h: int) -> Optional[Tuple[int, int, int]]:
+        """(limit, duration, reset_time) cached for a fingerprint, or
+        None: a read with no side effect (no counter, no recency)."""
+        e = self._entries.get(h)
+        return None if e is None else e[:3]
+
     def refresh_generation(self) -> None:
         """Clear everything when the engine wiped its store (EpochClock
         reset_required -> engine.reset()): every cached verdict pointed
@@ -188,28 +245,38 @@ class ShedCache:
         g = self.generation_fn()
         if g != self._gen:
             self._gen = g
-            self._entries.clear()
-            self._snap = None
+            self.purge_all()
 
     def purge(self, fingerprints) -> None:
         """Drop entries for these uint64 fingerprints (GLOBAL installs:
         the owner's broadcast replaced the replica, so the cached
         verdict is no longer provably current)."""
         for h in fingerprints:
-            if self._entries.pop(int(h), None) is not None:
-                self._snap = None
+            self._drop(int(h))
 
     def purge_all(self) -> None:
         self._entries.clear()
-        self._snap = None
+        self._reset[: self._top] = 0
+        self._top = 0  # slots [0, _top) have been handed out
+        self._free: List[int] = []  # dropped slots below _top
+        # the sorted index: fingerprints of the slots live at the last
+        # sort, and their slots
+        self._ix_fp = np.zeros(0, np.uint64)
+        self._ix_slot = np.zeros(0, np.int64)
+        # the overlay: how many of _new_fp / _new_slot were bound since
+        # the sort (past OVERLAY_MAX: the next consult re-sorts), and
+        # those as sorted arrays once a consult needed them
+        self._new = 0
+        self._overlay = None
 
     def reset_counters(self) -> None:
-        """Zero the hit/lookup counters (entries stay live) — the
-        profiler scopes measurement windows with
-        /v1/debug/stages?reset=1, and per-window hit rates need the
-        same scoping."""
+        """Zero the counters (entries stay live) — the profiler scopes
+        measurement windows with /v1/debug/stages?reset=1, and
+        per-window hit and rebuild rates need the same scoping."""
         self.hits = 0
         self.lookups = 0
+        self.index_uses = 0
+        self.index_rebuilds = 0
 
     def stats(self) -> dict:
         lk = self.lookups
@@ -219,8 +286,107 @@ class ShedCache:
             hits=self.hits,
             lookups=lk,
             hit_rate=round(self.hits / lk, 4) if lk else 0.0,
+            index_uses=self.index_uses,
+            index_rebuilds=self.index_rebuilds,
             generation=self._gen,
         )
+
+    # -- slots and their index -----------------------------------------------
+
+    def _store(
+        self, h: int, limit: int, duration: int, reset_time: int
+    ) -> None:
+        """Write one frozen verdict: in place when the fingerprint is
+        cached, else into a slot of its own — the LRU entry's at the
+        bound, a dropped one, or the next never used."""
+        entries = self._entries
+        e = entries.get(h)
+        if e is not None:
+            slot = e[3]
+            if e[2] != reset_time or e[0] != limit or e[1] != duration:
+                entries[h] = (limit, duration, reset_time, slot)
+                self._lim[slot] = limit
+                self._dur[slot] = duration
+                self._reset[slot] = reset_time
+            entries.move_to_end(h)
+            return
+        if len(entries) >= self.capacity:
+            slot = entries.popitem(last=False)[1][3]
+        elif self._free:
+            slot = self._free.pop()
+        else:
+            slot = self._top
+            if slot == self._fp.shape[0]:
+                self._grow()
+            self._top = slot + 1
+        entries[h] = (limit, duration, reset_time, slot)
+        self._fp[slot] = h
+        self._lim[slot] = limit
+        self._dur[slot] = duration
+        self._reset[slot] = reset_time
+        k = self._new
+        if k < OVERLAY_MAX:
+            self._new_fp[k] = h
+            self._new_slot[k] = slot
+        self._new = k + 1
+        self._overlay = None
+
+    def _drop(self, h: int) -> None:
+        """Forget a fingerprint if it is cached; its slot can never
+        shed again until _store writes a fresh verdict to it."""
+        e = self._entries.pop(h, None)
+        if e is not None:
+            self._reset[e[3]] = 0
+            self._free.append(e[3])
+
+    def _grow(self) -> None:
+        n = self._fp.shape[0]
+        more = min(self.capacity, 2 * n) - n
+        self._fp, self._lim, self._dur, self._reset = (
+            np.concatenate([col, np.zeros(more, col.dtype)])
+            for col in (self._fp, self._lim, self._dur, self._reset)
+        )
+
+    def _resort(self) -> None:
+        """Rebuild the sorted index from the live slots' fingerprint
+        column and empty the overlay."""
+        self.index_rebuilds += 1
+        live = np.flatnonzero(self._reset[: self._top])
+        fp = self._fp[live]
+        order = np.argsort(fp)
+        self._ix_fp = fp[order]
+        self._ix_slot = live[order]
+        self._new = 0
+        self._overlay = None
+
+    def _find(self, kh):
+        """(slot int64[n], found bool[n]) for a frame's fingerprints:
+        one searchsorted against the sorted index, one against the
+        overlay where it has anything (its latest binding of a
+        fingerprint wins), and the slot's own fingerprint as the proof.
+        `found` rows may be dropped slots (reset_time 0). Callers have
+        checked that the cache is not empty."""
+        self.index_uses += 1
+        k = self._new
+        if k > OVERLAY_MAX or not self._ix_fp.shape[0]:
+            self._resort()
+            k = 0
+        ix_fp = self._ix_fp
+        pos = np.searchsorted(ix_fp, kh)
+        np.minimum(pos, ix_fp.shape[0] - 1, out=pos)
+        slot = self._ix_slot[pos]
+        if k:
+            if self._overlay is None:
+                fp = self._new_fp[:k]
+                order = np.argsort(fp, kind="stable")
+                self._overlay = fp[order], self._new_slot[:k][order]
+            ov_fp, ov_slot = self._overlay
+            # side="right" - 1: the LAST of equal fingerprints, the
+            # binding in force (-1 wraps to a row the compare refuses)
+            j = np.searchsorted(ov_fp, kh, side="right") - 1
+            hit = np.flatnonzero(ov_fp[j] == kh)
+            slot[hit] = ov_slot[j[hit]]
+        return slot, self._fp[slot] == kh
 
     # -- consult -------------------------------------------------------------
 
@@ -241,8 +407,7 @@ class ShedCache:
             now = self.now_fn()
         if now >= e[2]:
             # expired: the first post-reset hit must reach the device
-            del self._entries[h]
-            self._snap = None
+            self._drop(h)
             return None
         if e[0] != limit or e[1] != duration:
             return None
@@ -263,29 +428,6 @@ class ShedCache:
             return None
         return over_limit_resp(req.limit, reset)
 
-    def _snapshot(self):
-        """(keys_sorted u64, limit i64, duration i64, reset i64) of
-        the live entries, rebuilt lazily after mutations — the
-        vectorized screen's lookup table."""
-        import numpy as np
-
-        snap = self._snap
-        if snap is None:
-            m = len(self._entries)
-            keys = np.fromiter(self._entries.keys(), np.uint64, m)
-            vals = np.fromiter(
-                (v for e in self._entries.values() for v in e),
-                np.int64, 3 * m,
-            ).reshape(m, 3)
-            order = np.argsort(keys)
-            snap = self._snap = (
-                keys[order],
-                vals[order, 0],
-                vals[order, 1],
-                vals[order, 2],
-            )
-        return snap
-
     def screen_fields(self, fields: Dict, now: Optional[int] = None):
         """Bridge-tier consult over one frame's dense arrays
         (key_hash/hits/limit/duration/algo[/gnp]). Returns None when
@@ -293,27 +435,23 @@ class ShedCache:
         remaining, reset) int64[n] with the shed rows filled; residue
         rows are zero and overwritten by the device results).
 
-        Fully vectorized — one searchsorted against the sorted entry
-        snapshot plus elementwise gates — so a thousand-item frame
-        screens in tens of microseconds of event-loop time (the
-        per-item dict-probe loop this replaced measured ~1.4 ms/frame
-        on a throttled 2-core host, which ate the shed's own win).
+        Fully vectorized — _find's searchsorted, gathers from the slot
+        columns and elementwise gates — so a thousand-item frame
+        screens in ~0.1 ms of event-loop time whatever the cache
+        holds and however often its values change (the per-item
+        dict-probe loop this replaced measured ~1.4 ms/frame on a
+        throttled 2-core host, which ate the shed's own win).
         Two deliberate approximations vs lookup(): screen hits do not
         refresh LRU recency (entries refresh on insert; with the
         bound sized to the over-limit head that's ample), and expired
         entries are skipped, not deleted (lookup()/observe/insert
         pressure prunes them)."""
-        import numpy as np
-
         if not self._entries:
             return None
         if now is None:
             now = self.now_fn()
         kh = np.asarray(fields["key_hash"], np.uint64)
-        keys_s, lim_s, dur_s, reset_s = self._snapshot()
-        idx = np.searchsorted(keys_s, kh)
-        idx[idx == keys_s.shape[0]] = 0
-        found = keys_s[idx] == kh
+        slot, found = self._find(kh)
         eligible = (
             (np.asarray(fields["algo"]) == int(Algorithm.TOKEN_BUCKET))
             & (np.asarray(fields["hits"]) > 0)
@@ -325,12 +463,13 @@ class ShedCache:
             # local-processing path — leave them to the device
             eligible &= ~np.asarray(gnp, bool)
         limit = np.asarray(fields["limit"], np.int64)
+        reset = self._reset[slot]
         mask = (
             found
             & eligible
-            & (lim_s[idx] == limit)
-            & (dur_s[idx] == np.asarray(fields["duration"], np.int64))
-            & (now < reset_s[idx])
+            & (self._lim[slot] == limit)
+            & (self._dur[slot] == np.asarray(fields["duration"], np.int64))
+            & (now < reset)
         )
         shed = int(mask.sum())
         self.lookups += int(eligible.sum())
@@ -342,7 +481,7 @@ class ShedCache:
         ).astype(np.int64)
         limit_out = np.where(mask, limit, 0)
         remaining = np.zeros(kh.shape[0], np.int64)
-        reset_out = np.where(mask, reset_s[idx], 0)
+        reset_out = np.where(mask, reset, 0)
         return mask, (status, limit_out, remaining, reset_out)
 
     # -- populate / invalidate ----------------------------------------------
@@ -365,13 +504,7 @@ class ShedCache:
             now = self.now_fn()
         if now >= reset_time:
             return
-        entries = self._entries
-        if entries.get(h) != (limit, duration, reset_time):
-            self._snap = None
-        entries[int(h)] = (int(limit), int(duration), int(reset_time))
-        entries.move_to_end(int(h))
-        if len(entries) > self.capacity:
-            entries.popitem(last=False)
+        self._store(int(h), int(limit), int(duration), int(reset_time))
 
     def _observe_one(
         self,
@@ -390,8 +523,7 @@ class ShedCache:
             # a leaky request recreates a stored token window
             # (algorithm switch, kernels.py mismatch path): whatever we
             # cached for this fingerprint no longer exists
-            if self._entries.pop(h, None) is not None:
-                self._snap = None
+            self._drop(h)
             return
         frozen = (
             r_status == int(Status.OVER_LIMIT) and r_remaining == 0
@@ -400,13 +532,7 @@ class ShedCache:
             # the frozen fixed point: stored remaining is 0 and sticky,
             # and every same-param hit until r_reset echoes this exact
             # response (module docstring)
-            entries = self._entries
-            if entries.get(h) != (req_limit, req_duration, r_reset):
-                self._snap = None
-            entries[h] = (req_limit, req_duration, r_reset)
-            entries.move_to_end(h)
-            if len(entries) > self.capacity:
-                entries.popitem(last=False)
+            self._store(h, req_limit, req_duration, r_reset)
             return
         e = self._entries.get(h)
         if e is None:
@@ -421,8 +547,7 @@ class ShedCache:
         # a response that contradicts the cached window (under limit,
         # different stored params, different reset) proves it is gone —
         # reset, evicted, or rewritten
-        del self._entries[h]
-        self._snap = None
+        self._drop(h)
 
     def observe_resps(
         self,
@@ -453,24 +578,20 @@ class ShedCache:
         for exactly these `fields` rows. The walk is bounded: every
         row touching a CACHED fingerprint is visited (confirm / drop /
         leaky pop — the correctness rows, pre-filtered with one
-        vectorized snapshot membership test), while frozen-verdict
+        vectorized _find membership test), while frozen-verdict
         rows for UNCACHED fingerprints — pure population — are capped
         at OBSERVE_INSERT_CAP per call, so an over-limit-heavy frame
         whose key cardinality exceeds the cache bound cannot drag a
         ~1 ms/frame Python walk into steady state (the cost the
         vectorized screen exists to avoid)."""
-        import numpy as np
-
         status, limit_r, remaining, reset = results
         sa = np.asarray(status)
         ra = np.asarray(remaining)
         frozen = (sa == int(Status.OVER_LIMIT)) & (ra == 0)
         kh = np.asarray(fields["key_hash"], np.uint64)
         if self._entries:
-            keys_s = self._snapshot()[0]
-            pos = np.searchsorted(keys_s, kh)
-            pos[pos == keys_s.shape[0]] = 0
-            cached = keys_s[pos] == kh
+            slot, found = self._find(kh)
+            cached = found & (self._reset[slot] != 0)
         else:
             cached = np.zeros(kh.shape[0], bool)
         must = np.flatnonzero(cached)

@@ -25,6 +25,7 @@ the device would echo verbatim. The suites here pin:
 
 import asyncio
 import struct
+from collections import OrderedDict
 
 import numpy as np
 import pytest
@@ -132,12 +133,12 @@ def test_r15_algorithms_never_shed():
     c._observe_one(42, 1, 10, 1000, int(Algorithm.GCRA),
                    int(Status.OVER_LIMIT), 10, 0, clock.t + 500,
                    clock.t)
-    assert 42 not in c._entries
+    assert 42 not in c
     # ...and never populates one of its own
     for algo in (2, 3):
         c._observe_one(7, 1, 10, 1000, algo, int(Status.OVER_LIMIT),
                        10, 0, clock.t + 500, clock.t)
-        assert 7 not in c._entries
+        assert 7 not in c
 
 
 def test_lru_bound_and_observe_drop():
@@ -147,15 +148,15 @@ def test_lru_bound_and_observe_drop():
         c._observe_one(h, 1, 5, 1000, 0, int(Status.OVER_LIMIT), 5, 0,
                        clock.t + 9999, clock.t)
     assert len(c) == 4  # bounded; oldest evicted
-    assert 0 not in c._entries and 5 in c._entries
+    assert 0 not in c and 5 in c
     # an under-limit response for a cached fingerprint drops it
     c._observe_one(5, 1, 5, 1000, 0, int(Status.UNDER_LIMIT), 5, 3,
                    clock.t + 9999, clock.t)
-    assert 5 not in c._entries
+    assert 5 not in c
     # a leaky request for a cached fingerprint drops it (algo switch)
     c._observe_one(4, 1, 5, 1000, 1, int(Status.UNDER_LIMIT), 5, 4, 0,
                    clock.t)
-    assert 4 not in c._entries
+    assert 4 not in c
 
 
 def test_observe_confirmation_vs_contradiction():
@@ -173,11 +174,11 @@ def test_observe_confirmation_vs_contradiction():
     # window (limit 10, same reset): keep
     c._observe_one(9, 1, 20, 1000, 0, int(Status.OVER_LIMIT), 10, 0,
                    reset, clock.t)
-    assert c._entries[9] == (10, 1000, reset)
+    assert c.get(9) == (10, 1000, reset)
     # a different reset means the window was recreated: drop
     c._observe_one(9, 1, 20, 1000, 0, int(Status.OVER_LIMIT), 10, 0,
                    reset + 5, clock.t)
-    assert 9 not in c._entries
+    assert 9 not in c
 
 
 def test_generation_clears():
@@ -190,6 +191,323 @@ def test_generation_clears():
     gen[0] += 1  # engine store wiped
     c.refresh_generation()
     assert len(c) == 0
+
+
+# -- the slot table against a plain-dict model of the rules -----------------
+
+
+class _DictModel:
+    """The cache's rules, one dict probe a row (the structure this
+    module had before its lookup table lived in slots): the reference
+    the vectorized table must agree with after every operation."""
+
+    def __init__(self, capacity, insert_cap):
+        self.capacity = capacity
+        self.insert_cap = insert_cap
+        self.entries = OrderedDict()
+
+    def store(self, h, limit, duration, reset):
+        self.entries[h] = (limit, duration, reset)
+        self.entries.move_to_end(h)
+        if len(self.entries) > self.capacity:
+            self.entries.popitem(last=False)
+
+    def seed(self, h, limit, duration, reset, now):
+        if now < reset:
+            self.store(h, limit, duration, reset)
+
+    def lookup(self, h, limit, duration, now):
+        e = self.entries.get(h)
+        if e is None:
+            return None
+        if now >= e[2]:
+            del self.entries[h]
+            return None
+        if e[0] != limit or e[1] != duration:
+            return None
+        self.entries.move_to_end(h)
+        return e[2]
+
+    def sheds(self, h, hits, limit, duration, algo, gnp, now):
+        """screen_fields' rule for one row: no recency refresh, an
+        expired entry skipped and not deleted."""
+        e = self.entries.get(h)
+        if e is None or algo != 0 or hits <= 0 or gnp:
+            return None
+        if e[0] != limit or e[1] != duration or now >= e[2]:
+            return None
+        return e[2]
+
+    def observe_one(self, h, limit, duration, algo, status, r_limit,
+                    remaining, r_reset, now):
+        if algo != 0:
+            self.entries.pop(h, None)
+            return
+        frozen = status == int(Status.OVER_LIMIT) and remaining == 0
+        if frozen and r_limit == limit and now < r_reset:
+            self.store(h, limit, duration, r_reset)
+            return
+        e = self.entries.get(h)
+        if e is None:
+            return
+        if frozen and r_limit == e[0] and r_reset == e[2]:
+            return
+        del self.entries[h]
+
+    def observe_rows(self, rows, now):
+        cached = [r[0] in self.entries for r in rows]
+        must = [r for r, c in zip(rows, cached) if c]
+        ins = [
+            r for r, c in zip(rows, cached)
+            if not c and r[4] == int(Status.OVER_LIMIT) and r[6] == 0
+        ][: self.insert_cap]
+        for r in must + ins:
+            self.observe_one(*r, now)
+
+
+@pytest.mark.parametrize(
+    "seed,capacity,overlay,insert_cap",
+    [(1, 40, 6, 5), (2, 40, 256, 512), (3, 4096, 16, 512)],
+)
+def test_slot_table_equals_dict_model(
+    monkeypatch, seed, capacity, overlay, insert_cap
+):
+    """A few thousand seeded seed / observe_fields / observe_resps /
+    lookup / purge / refresh_generation operations under a moving
+    clock: after EVERY one, screen_fields over every key ever used
+    sheds exactly the rows the model's per-row rule sheds, with the
+    model's reset_time — so a dropped, expired, purged, evicted or
+    recycled fingerprint never sheds — and len() stays inside the
+    capacity. The small overlay and capacity make folds, slot
+    recycling and LRU eviction happen every few operations."""
+    from gubernator_tpu.serve import shedcache
+
+    monkeypatch.setattr(shedcache, "OVERLAY_MAX", overlay)
+    monkeypatch.setattr(shedcache, "OBSERVE_INSERT_CAP", insert_cap)
+    rng = np.random.default_rng(seed)
+    clock = FakeClock()
+    gen = [0]
+    c = ShedCache(capacity, now_fn=clock, generation_fn=lambda: gen[0])
+    model = _DictModel(capacity, insert_cap)
+    pool = rng.integers(
+        0, 1 << 64, size=min(3 * capacity, 300), dtype=np.uint64
+    )
+    pool[0] = 0  # a fingerprint of 0 is a key like any other
+    pool[1] = (1 << 64) - 1
+    params = [(10, 1000), (100, 60_000), (1000, 3_600_000)]
+
+    def param_of(h):
+        return params[int(h) % 3]
+
+    def random_rows(n):
+        rows = []
+        for h in rng.choice(pool, n).tolist():
+            limit, duration = param_of(h)
+            if rng.random() < 0.1:
+                limit += 1  # a request under other params
+            algo = int(rng.choice([0, 0, 0, 0, 1, 2, 3]))
+            over = rng.random() < 0.6
+            r_limit = param_of(h)[0] if rng.random() < 0.9 else limit
+            r_reset = clock.t + int(rng.choice([-5, 1, 300, 800, 5000]))
+            if rng.random() < 0.3 and h in model.entries:
+                r_reset = model.entries[h][2]  # echo the cached window
+            rows.append((
+                h, limit, duration, algo,
+                int(Status.OVER_LIMIT if over else Status.UNDER_LIMIT),
+                r_limit, 0 if over else int(rng.integers(0, 5)), r_reset,
+            ))
+        return rows
+
+    def fields_of(rows, hits=None):
+        n = len(rows)
+        return dict(
+            key_hash=np.array([r[0] for r in rows], np.uint64),
+            hits=np.ones(n, np.int64) if hits is None else hits,
+            limit=np.array([r[1] for r in rows], np.int64),
+            duration=np.array([r[2] for r in rows], np.int64),
+            algo=np.array([r[3] for r in rows], np.int32),
+        )
+
+    def check_screen():
+        assert len(c) == len(model.entries) <= capacity
+        n = pool.shape[0]
+        limit = np.array([param_of(h)[0] for h in pool.tolist()], np.int64)
+        duration = np.array(
+            [param_of(h)[1] for h in pool.tolist()], np.int64
+        )
+        odd = rng.random(n) < 0.05
+        limit[odd] += 1
+        fields = dict(
+            key_hash=pool,
+            hits=(rng.random(n) < 0.9).astype(np.int64),
+            limit=limit,
+            duration=duration,
+            algo=rng.choice([0, 0, 0, 0, 0, 1, 3], n).astype(np.int32),
+            gnp=rng.random(n) < 0.05,
+        )
+        want = [
+            model.sheds(
+                int(pool[i]), int(fields["hits"][i]), int(limit[i]),
+                int(duration[i]), int(fields["algo"][i]),
+                bool(fields["gnp"][i]), clock.t,
+            )
+            for i in range(n)
+        ]
+        got = c.screen_fields(fields)
+        if got is None:
+            assert not any(w is not None for w in want)
+            return
+        mask, (status, limit_out, remaining, reset) = got
+        assert mask.tolist() == [w is not None for w in want]
+        assert reset.tolist() == [w or 0 for w in want]
+        assert status.tolist() == [
+            int(Status.OVER_LIMIT) if w is not None else 0 for w in want
+        ]
+        assert (limit_out == np.where(mask, limit, 0)).all()
+        assert not remaining.any()
+
+    for step in range(2500):
+        op = rng.random()
+        if op < 0.35:
+            rows = random_rows(int(rng.integers(1, 60)))
+            c.observe_fields(
+                fields_of(rows),
+                tuple(
+                    np.array([r[k] for r in rows], np.int64)
+                    for k in (4, 5, 6, 7)
+                ),
+            )
+            model.observe_rows(rows, clock.t)
+        elif op < 0.5:
+            rows = random_rows(int(rng.integers(1, 8)))
+            reqs = [
+                RateLimitReq(name="n", unique_key="k", hits=1,
+                             limit=r[1], duration=r[2],
+                             algorithm=Algorithm(r[3]))
+                for r in rows
+            ]
+            resps = [
+                RateLimitResp(status=Status(r[4]), limit=r[5],
+                              remaining=r[6], reset_time=r[7])
+                for r in rows
+            ]
+            c.observe_resps([r[0] for r in rows], reqs, resps)
+            for r in rows:
+                model.observe_one(*r, clock.t)
+        elif op < 0.65:
+            for h in rng.choice(pool, 4).tolist():
+                limit, duration = param_of(h)
+                assert c.lookup(h, limit, duration) == model.lookup(
+                    h, limit, duration, clock.t
+                )
+        elif op < 0.8:
+            for h in rng.choice(pool, 3).tolist():
+                limit, duration = param_of(h)
+                reset = clock.t + int(rng.choice([-1, 0, 400, 9000]))
+                c.seed(h, limit, duration, reset)
+                model.seed(h, limit, duration, reset, clock.t)
+        elif op < 0.9:
+            gone = rng.choice(pool, int(rng.integers(1, 10)))
+            c.purge(gone)
+            for h in gone.tolist():
+                model.entries.pop(h, None)
+        elif op < 0.98:
+            clock.t += int(rng.choice([1, 50, 400, 1000]))
+        else:
+            wiped = rng.random() < 0.5  # the engine reset its store
+            gen[0] += wiped
+            c.refresh_generation()
+            if wiped:
+                model.entries.clear()
+        check_screen()
+        assert [h for h in model.entries] == list(c._entries)
+    assert c.index_rebuilds < c.index_uses
+
+
+def test_value_changes_never_resort_the_index():
+    """Counts, not times: with 4,000 verdicts cached, 1,000 changes of
+    VALUE — a new reset_time in place, a confirmation, a drop by a
+    contradicting response, by expiry on lookup, by purge — leave the
+    sorted index and the overlay as they were, consults in between
+    included, and every consult still reads the changed values. Only
+    a fingerprint that holds no slot goes to the overlay, and the
+    overlay folds once when it is past OVERLAY_MAX."""
+    from gubernator_tpu.serve.shedcache import OVERLAY_MAX
+
+    clock = FakeClock()
+    c = ShedCache(1 << 16, now_fn=clock)
+    rng = np.random.default_rng(7)
+    fps = np.unique(rng.integers(0, 1 << 64, size=4000, dtype=np.uint64))
+    m = fps.shape[0]
+    over = int(Status.OVER_LIMIT)
+    for h in fps.tolist():
+        c._observe_one(h, 1, 100, 60_000, 0, over, 100, 0,
+                       clock.t + 60_000, clock.t)
+    fields = dict(
+        key_hash=fps,
+        hits=np.ones(m, np.int64),
+        limit=np.full(m, 100, np.int64),
+        duration=np.full(m, 60_000, np.int64),
+        algo=np.zeros(m, np.int32),
+    )
+    assert c.screen_fields(fields)[0].all()  # folds what was inserted
+    rebuilds, uses = c.index_rebuilds, c.index_uses
+    assert c._new == 0
+
+    want_reset = {h: clock.t + 60_000 for h in fps.tolist()}
+    for i in range(1000):
+        h = int(fps[(i * 37) % m])
+        kind = i % 5
+        if kind == 0 and h in want_reset:  # the window moved: in place
+            want_reset[h] = clock.t + 70_000 + i
+            c._observe_one(h, 1, 100, 60_000, 0, over, 100, 0,
+                           want_reset[h], clock.t)
+        elif kind == 1 and h in want_reset:  # confirmed
+            c._observe_one(h, 1, 100, 60_000, 0, over, 100, 0,
+                           want_reset[h], clock.t)
+        elif kind == 2:  # contradicted: under limit again
+            c._observe_one(h, 1, 100, 60_000, 0,
+                           int(Status.UNDER_LIMIT), 100, 7,
+                           clock.t + 60_000, clock.t)
+            want_reset.pop(h, None)
+        elif kind == 3:  # expired on lookup
+            c.lookup(h, 100, 60_000, now=clock.t + 10**9)
+            want_reset.pop(h, None)
+        elif kind == 4:
+            c.purge(np.array([h], np.uint64))
+            want_reset.pop(h, None)
+        if i % 10 == 9:
+            got = c.screen_fields(fields)
+            assert got[0].tolist() == [h in want_reset for h in fps.tolist()]
+            assert got[1][3].tolist() == [
+                want_reset.get(h, 0) for h in fps.tolist()
+            ]
+            c.observe_fields(
+                {k: v[:50] for k, v in fields.items()},
+                tuple(np.zeros(50, np.int64) for _ in range(4)),
+            )  # 50 under-limit rows: cached ones drop, in place
+            for h in fps[:50].tolist():
+                want_reset.pop(h, None)
+    assert len(c) == len(want_reset) < m
+    assert c.index_uses == uses + 200
+    assert c.index_rebuilds == rebuilds and c._new == 0
+
+    # a fingerprint with no slot waits in the overlay (and sheds from
+    # there); one past OVERLAY_MAX folds it, once
+    fresh = np.setdiff1d(
+        rng.integers(0, 1 << 64, size=OVERLAY_MAX + 8, dtype=np.uint64), fps
+    )[: OVERLAY_MAX + 1]
+    for h in fresh[:OVERLAY_MAX].tolist():
+        c._observe_one(h, 1, 100, 60_000, 0, over, 100, 0,
+                       clock.t + 60_000, clock.t)
+    probe = dict(fields, key_hash=np.resize(fresh, m))
+    assert c.screen_fields(probe)[0][:OVERLAY_MAX].all()
+    assert c.index_rebuilds == rebuilds and c._new == OVERLAY_MAX
+    c._observe_one(int(fresh[OVERLAY_MAX]), 1, 100, 60_000, 0, over, 100,
+                   0, clock.t + 60_000, clock.t)
+    assert c.screen_fields(probe)[0].all()
+    assert c.screen_fields(probe)[0].all()
+    assert c.index_rebuilds == rebuilds + 1 and c._new == 0
 
 
 # -- instance harness -------------------------------------------------------

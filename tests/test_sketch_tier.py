@@ -1039,10 +1039,10 @@ def test_shed_seed_gates():
                      duration=1000)
     assert c.lookup_resp(1, r).reset_time == clock.t + 500
     c.seed(2, 5, 1000, clock.t - 1)  # expired: ignored
-    assert 2 not in c._entries
+    assert 2 not in c
     c.seed(3, 5, 1000, clock.t + 500)
     c.seed(4, 5, 1000, clock.t + 500)  # capacity 2: LRU evicts
-    assert len(c) == 2 and 1 not in c._entries
+    assert len(c) == 2 and 1 not in c
 
 
 def test_committed_artifact_headline():
