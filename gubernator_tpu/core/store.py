@@ -320,6 +320,37 @@ def new_store(config: StoreConfig = StoreConfig()) -> Store:
     )
 
 
+# rows of the table `rebase` shifts at a time (16 MiB at 16 ways)
+REBASE_BLOCK_ROWS = 1 << 15
+
+
+def _rebase_block(data: jax.Array, delta: jax.Array) -> jax.Array:
+    """`rebase` of one block of bucket rows int32[..., rows, W]."""
+    lane = jnp.arange(data.shape[-1]) % LANES
+    is_expire = lane == L_EXPIRE
+    is_ts = lane == L_TS
+    # broadcast each entry's flags across its 8 lanes so the L_TS
+    # decision can read them elementwise (entries are LANES-aligned;
+    # shape-generic over any leading axes — sharded stores carry one)
+    lead = data.shape[:-1]
+    W = data.shape[-1]
+    flags = data.reshape(*lead, W // LANES, LANES)[
+        ..., L_FLAGS : L_FLAGS + 1
+    ]
+    flags = jnp.broadcast_to(flags, (*lead, W // LANES, LANES)).reshape(
+        *lead, W
+    )
+    ts_is_count = (flags & FLAG_ALGO_SLIDING) != 0
+    is_time = is_expire | (is_ts & ~ts_is_count)
+    shifted = jnp.clip(
+        data.astype(jnp.int64) - jnp.where(is_time, delta, 0),
+        TIME_FLOOR,
+        COUNTER_MAX,
+    ).astype(jnp.int32)
+    return jnp.where(is_time, shifted, data)
+
+
+@jax.named_scope("rebase")
 def rebase(store: Store, delta: jax.Array) -> Store:
     """Shift all stored times by -delta (the host moved the epoch forward
     by `delta` ms). One elementwise pass over the store; runs every ~12
@@ -332,29 +363,31 @@ def rebase(store: Store, delta: jax.Array) -> Store:
     subwindow's consumed total, core/algorithms.py) — shifting it there
     would corrupt the blend. Each entry's own L_FLAGS lane decides; the
     per-entry broadcast is one extra elementwise select in a pass that
-    runs twice a month."""
-    lane = jnp.arange(store.data.shape[-1]) % LANES
-    is_expire = lane == L_EXPIRE
-    is_ts = lane == L_TS
-    # broadcast each entry's flags across its 8 lanes so the L_TS
-    # decision can read them elementwise (entries are LANES-aligned;
-    # shape-generic over any leading axes — sharded stores carry one)
-    lead = store.data.shape[:-1]
-    W = store.data.shape[-1]
-    flags = store.data.reshape(*lead, W // LANES, LANES)[
-        ..., L_FLAGS : L_FLAGS + 1
-    ]
-    flags = jnp.broadcast_to(flags, (*lead, W // LANES, LANES)).reshape(
-        *lead, W
+    runs twice a month.
+
+    The pass walks the table REBASE_BLOCK_ROWS bucket rows at a time and
+    writes each block back where it was, so with the store donated
+    (`kernels.rebase_jit`) the program holds one table and a block:
+    reading the flags reshapes what it reads, XLA copies what is
+    reshaped, and as one expression over the whole table that copy was
+    a second table — at 16 ways x 2^24 rows the TPU compiler refused the
+    program (17.25 GB wanted of 15.75; PR 30)."""
+    data = store.data
+    buckets, W = data.shape[-2:]
+    rows = min(buckets, REBASE_BLOCK_ROWS)
+    assert buckets % rows == 0, (buckets, rows)
+    lead = data.shape[:-2]
+
+    def shift_block(i, data):
+        at = (0,) * len(lead) + (i * rows, 0)
+        block = jax.lax.dynamic_slice(data, at, lead + (rows, W))
+        return jax.lax.dynamic_update_slice(
+            data, _rebase_block(block, delta), at
+        )
+
+    return Store(
+        data=jax.lax.fori_loop(0, buckets // rows, shift_block, data)
     )
-    ts_is_count = (flags & FLAG_ALGO_SLIDING) != 0
-    is_time = is_expire | (is_ts & ~ts_is_count)
-    shifted = jnp.clip(
-        store.data.astype(jnp.int64) - jnp.where(is_time, delta, 0),
-        TIME_FLOOR,
-        COUNTER_MAX,
-    ).astype(jnp.int32)
-    return Store(data=jnp.where(is_time, shifted, store.data))
 
 
 def mix64(x: jax.Array) -> jax.Array:

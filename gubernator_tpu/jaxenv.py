@@ -74,7 +74,8 @@ def device_summary() -> dict:
 
 def describe_devices(state_bytes=None) -> dict:
     """`device_summary()` plus, per device, the allocator's bytes in
-    use (None where the backend keeps no such statistic — the CPU) and
+    use now, at their peak since boot and at most (None where the
+    backend keeps no such statistic — the CPU) and
     `state_bytes`, the engine's own account of the store + sketch bytes
     resident there ({device id: bytes})."""
     import jax
@@ -87,6 +88,7 @@ def describe_devices(state_bytes=None) -> dict:
                 "id": d.id,
                 "bytes_in_use": stats.get("bytes_in_use"),
                 "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+                "bytes_limit": stats.get("bytes_limit"),
                 "state_bytes": (state_bytes or {}).get(d.id, 0),
             }
         )

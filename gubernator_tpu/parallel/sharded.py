@@ -1174,6 +1174,15 @@ class PartitionedEngine:
         return out
 
     def reset(self) -> None:
+        """Empty state, the old state's device memory handed back
+        BEFORE the new is allocated. Built first and swapped after, two
+        tables were alive at once: that instant was the process's peak
+        (1,128 MB against 554 MB of state at 2^20 rows), and a store
+        that fills more than half a chip could not finish its warm-up,
+        which ends here (PR 30). Submit-thread contract, like every
+        call that replaces the store."""
+        for leaf in jax.tree.leaves((self.store, self.sketch)):
+            leaf.delete()
         self.store = self._fresh_store()
         if self.sketch_config is not None:
             self.sketch = self._fresh_sketch()

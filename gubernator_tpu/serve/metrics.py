@@ -450,6 +450,31 @@ DRAIN_DURATION = Gauge(
     "GUBER_DRAIN_TIMEOUT_MS)",
     registry=REGISTRY,
 )
+# -- device memory (PR 30): what the chip holds against what the state
+# needs, set lazily at /metrics scrape from the device's own allocator
+# statistics; on a mesh the fullest device's. 0 where the backend keeps
+# no such statistic (the CPU).
+DEVICE_MEMORY_PEAK = Gauge(
+    "device_memory_peak_bytes",
+    "Most bytes the device allocator has had in use at once since the "
+    "process started (memory_stats peak_bytes_in_use; the fullest "
+    "device's): against store_state_bytes it says whether a second "
+    "table was ever alive",
+    registry=REGISTRY,
+)
+DEVICE_MEMORY_LIMIT = Gauge(
+    "device_memory_limit_bytes",
+    "Bytes the device allocator may hand out (memory_stats "
+    "bytes_limit): HBM less what the runtime reserves",
+    registry=REGISTRY,
+)
+STORE_STATE_BYTES = Gauge(
+    "store_state_bytes",
+    "Bytes of rate-limit state resident on a device: exact table + "
+    "sketch, from the arrays' own shapes and shardings (the fullest "
+    "device's; on a mesh 1/n_shards of the whole)",
+    registry=REGISTRY,
+)
 # -- queue-visibility gauges (r16): occupancy the stage clock cannot
 # express (it times spans, not standing depth). All set lazily at
 # /metrics scrape like shed_entries — the hot paths keep plain

@@ -446,18 +446,22 @@ class Server:
             # a missing or unloadable .so must not abort startup: the
             # numpy twins serve, and this report is what says so
             has_prep = False
-        device = None
-        engine = getattr(self.backend, "engine", None)
-        if engine is not None:
-            from gubernator_tpu.jaxenv import describe_devices
-
-            by_dev = getattr(engine, "state_bytes_by_device", None)
-            device = describe_devices(by_dev() if by_dev else None)
         return {
-            "device": device,
+            "device": self._describe_device(),
             "host_prep": "native" if has_prep else "numpy",
             "hasher": "native" if using_native_hash() else "python",
         }
+
+    def _describe_device(self):
+        """The devices as JAX reports them, with the engine's state
+        bytes on each; None on the exact backend."""
+        engine = getattr(self.backend, "engine", None)
+        if engine is None:
+            return None
+        from gubernator_tpu.jaxenv import describe_devices
+
+        by_dev = getattr(engine, "state_bytes_by_device", None)
+        return describe_devices(by_dev() if by_dev else None)
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -507,10 +511,11 @@ class Server:
                 "serving from %s (%s) x %d; per device after warm-up: %s",
                 d["platform"], d["kind"], d["count"],
                 ", ".join(
-                    "#%d state %.0f MiB, in use %s" % (
+                    "#%d state %.0f MiB, in use %s, peak %s" % (
                         x["id"], x["state_bytes"] / (1 << 20),
-                        "n/a" if x["bytes_in_use"] is None
-                        else "%.0f MiB" % (x["bytes_in_use"] / (1 << 20)),
+                        *("n/a" if x[k] is None
+                          else "%.0f MiB" % (x[k] / (1 << 20))
+                          for k in ("bytes_in_use", "peak_bytes_in_use")),
                     )
                     for x in d["devices"]
                 ),
@@ -1057,6 +1062,18 @@ class Server:
                 metrics.PEER_BREAKER_STATE.labels(peer=peer.host).set(
                     peer.breaker.state_code
                 )
+        # device memory against state bytes, from the allocator's own
+        # statistics (None on the CPU, which keeps none: the gauges
+        # stay 0); on a mesh the fullest device's
+        device = self._describe_device()
+        if device is not None:
+            per = device["devices"]
+            metrics.DEVICE_MEMORY_PEAK.set(
+                max(x["peak_bytes_in_use"] or 0 for x in per))
+            metrics.DEVICE_MEMORY_LIMIT.set(
+                max(x["bytes_limit"] or 0 for x in per))
+            metrics.STORE_STATE_BYTES.set(
+                max(x["state_bytes"] for x in per))
         # shed-cache totals export lazily at scrape time too: the hot
         # path only bumps plain ints (serve/shedcache.py)
         shed = self.instance.shed

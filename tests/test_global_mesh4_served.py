@@ -107,6 +107,32 @@ def grown(before: dict) -> dict:
     return {c: v - before[c] for c, v in counters().items()}
 
 
+def flushed_bytes() -> dict:
+    """`global_flush_bytes_total` by path so far. The registry is the
+    process's: under `--dist loadfile` another file's flushes (which
+    file shares this worker varies with the worker count) stand in it
+    already, so a test compares growth, never the level (PR 30: this
+    is what failed here in the driver's six-worker run)."""
+    return {path: REGISTRY.get_sample_value(
+        "global_flush_bytes_total", {"path": path}) or 0.0
+        for path in ("mesh", "rpc")}
+
+
+def settled(before: dict, done, timeout: float = 30.0) -> dict:
+    """`grown(before)` once `done(it)` holds, or as it stands at the
+    deadline (the caller's assertions then say what is missing). The
+    broadcast loop moves `global_peek_rows_total` BEFORE it awaits the
+    peek and `global_broadcast_keys_total` after it, so a fixed sleep
+    reads the pair apart whenever a flush is in flight — under six
+    xdist workers a flush outlasts 10 sync waits (PR 30)."""
+    deadline = time.monotonic() + timeout
+    while True:
+        grew = grown(before)
+        if done(grew) or time.monotonic() > deadline:
+            return grew
+        time.sleep(SYNC_WAIT)
+
+
 class FakeClock:
     def __call__(self):
         return T0
@@ -170,6 +196,7 @@ def test_seeded_stream_equals_oracle_and_reference(node):
     frames = stream(26)
     assert sum(map(len, frames)) >= 4000
     before = counters()
+    flushed_before = flushed_bytes()
     peeks_before = STAGES.snapshot()["stages"].get(
         "global_peek", {"count": 0})["count"]
     got = through_the_door(addr, frames)
@@ -196,8 +223,10 @@ def test_seeded_stream_equals_oracle_and_reference(node):
     assert over > 200  # keys were driven over their limit
 
     # the real broadcast loop ran beside the stream and peeked
-    time.sleep(10 * SYNC_WAIT)
-    grew = grown(before)
+    grew = settled(before, lambda g: (
+        g["global_peek_rows_total"] > 0
+        and g["global_broadcast_keys_total"] == g["global_peek_rows_total"]
+    ))
     n_global = sum(r.behavior == Behavior.GLOBAL for fr in frames for r in fr)
     n_mixed = sum(
         len(fr) for fr in frames
@@ -215,9 +244,7 @@ def test_seeded_stream_equals_oracle_and_reference(node):
     # ...and found no peer: nothing was flushed, and the in-mesh psum
     # (queue_hit -> apply_global_hits) is a non-owner's path, which a
     # node that owns every key never takes
-    for path in ("mesh", "rpc"):
-        assert not REGISTRY.get_sample_value(
-            "global_flush_bytes_total", {"path": path})
+    assert flushed_bytes() == flushed_before
 
     # every key's window read back: no peek moved a counter
     ids = sorted({int(r.unique_key[1:]) for fr in frames for r in fr})
@@ -236,7 +263,13 @@ def test_counters_on_one_crafted_frame(node):
     into the array path as ONE device batch, and the owner's broadcast
     then peeks the one GLOBAL key in a batch of its own."""
     cluster, addr = node
-    time.sleep(10 * SYNC_WAIT)  # earlier broadcasts have flushed
+    # earlier broadcasts have flushed: nothing queued, none in flight
+    mgr = cluster.servers[0].instance.global_mgr
+    settled(counters(), lambda g: (
+        not mgr.backlog_sizes()["updates"]
+        and REGISTRY.get_sample_value("global_broadcast_keys_total")
+        == REGISTRY.get_sample_value("global_peek_rows_total")
+    ))
     frame = [req(i, 1, name="crafted") for i in (100, 101, 102, 103, 104,
                                                  105, 106)]
     assert [r.behavior == Behavior.GLOBAL for r in frame].count(True) == 1
@@ -249,8 +282,9 @@ def test_counters_on_one_crafted_frame(node):
         "shard_stack", {"count": 0})["count"]
     answers = through_the_door(addr, [frame])[0]
     assert [a[0] for a in answers] == [0] * 7
-    time.sleep(20 * SYNC_WAIT)
-    assert grown(before) == {
+    assert settled(
+        before, lambda g: g["global_broadcast_keys_total"] >= 1.0
+    ) == {
         "edge_object_items_total": 0.0,
         "edge_fast_items_total": 0.0,
         "edge_folded_items_total": 7.0,

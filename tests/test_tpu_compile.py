@@ -11,7 +11,8 @@ chip_smoke.py runs them: no chip time, every later PR guarded.
 Cost (PR 21, this sandbox; XLA's TPU compiler uses one core): the flat
 sketch-tier decide 153 s, the Mosaic sweep 1.6 s, the 4-device mesh
 decide 81 s. A whole decide program is minutes of XLA time, so this
-file stays at three programs; it runs in one xdist worker.
+file stays at three of them; it runs in one xdist worker. PR 30 adds
+the small programs that take the 8 GiB table (about 6 s together).
 
 Rules this file keeps (on-chip-measurement guide, section 2): the
 topology is described inside a module-scoped fixture that skips when it
@@ -111,6 +112,28 @@ def _shapes_only_engine(state_sharding_for):
     return ShapesOnly
 
 
+def _table_programs(rows, S, b=64):
+    """name -> lowered, for the small programs that take the whole
+    exact table int32[rows, 128] and give it back (the GLOBAL /
+    promoter install, the full-lane install, the epoch rebase), plus
+    the host reads' row gather under "rows_flat"; `S(shape, dtype)`
+    makes the argument shapes."""
+    from gubernator_tpu.core import kernels as K
+    from gubernator_tpu.core.store import Store
+    from gubernator_tpu.parallel.sharded import _rows_flat
+
+    store = Store(data=S((rows, 128), jnp.int32))
+    i32, u64, flag = S((b,), jnp.int32), S((b,), jnp.uint64), S((b,), jnp.bool_)
+    return {
+        "install": K.upsert_globals_jit.lower(
+            store, u64, i32, i32, i32, flag, flag),
+        "install_full": K.upsert_windows_jit.lower(
+            store, u64, i32, i32, i32, i32, i32, i32, flag),
+        "rebase": K.rebase_jit.lower(store, S((), jnp.int32)),
+        "rows_flat": _rows_flat.lower(store.data, i32),
+    }
+
+
 def _captured(engine, attr, call):
     """The positional arguments `engine.<attr>` receives when `call()`
     runs — the engine's own padding, presort and group structure for a
@@ -180,6 +203,34 @@ def test_flat_sketch_decide_1024_rung_compiles_for_v5e(topo, no_compile_cache):
     assert sum("s32[%d]" % packed_in.shape[0] in ln for ln in params) == 1
     assert not re.search(r"[us]64\[[^\n]*bitcast-convert", text)
     assert " while(" not in text
+
+
+def test_store_programs_hold_one_table_at_2_24_rows(topo, no_compile_cache):
+    """`GUBER_STORE_TARGET_KEYS=100000000`: 16 ways x 2^24 rows = 8 GiB
+    of a 16 GB chip (PR 30). Every program that takes the table beside
+    the decide — the GLOBAL / promoter install and the full-lane
+    install (the decide's own gather, plan and scatter-add writeback at
+    rung 64), the epoch rebase, the host reads' row gather — compiles
+    for the chip at the real row count in a second or so each, its
+    output the donated table's buffer and its temporaries far under a
+    table. Before PR 30 `rebase` did not compile here: 17.25 GB wanted
+    of 15.75 (a reshape of the table is a copy of the table). The
+    decide itself at this geometry compiles in ~160 s (the same
+    program text as the 2^20-row one above, temporaries 2.6 MB: my
+    compile, PR 30) and is left to the chip runs of
+    `exact100m.geb-frames`."""
+    one = SingleDeviceSharding(topo.devices[0])
+    b = 64
+    programs = _table_programs(
+        1 << 24, lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=one), b)
+    gather = programs.pop("rows_flat").compile().memory_analysis()
+    assert gather.output_size_in_bytes == b * 128 * 4
+    assert gather.temp_size_in_bytes < MIB
+    for name, lowered in programs.items():
+        mem = lowered.compile().memory_analysis()
+        assert mem.alias_size_in_bytes == 8 << 30, (name, mem)
+        assert mem.temp_size_in_bytes < 64 * MIB, (name, mem)
 
 
 def test_pallas_sweep_compiles_to_a_mosaic_kernel(topo, no_compile_cache):
