@@ -26,7 +26,9 @@ Data-movement design (the performance core):
   the sort (bucket gather, group-leader gathers, writeback destinations)
   is monotonically non-decreasing, and all requests touching one bucket
   are contiguous — which gives the writeback its per-bucket conflict
-  accounting and XLA its sorted gather/scatter fast path.
+  accounting and XLA its sorted gather fast path (the scatter takes
+  the sorted hint only where the batch is deep beside the store:
+  writeback_form).
 - Per-group hit sums use a *segmented saturating* associative scan:
   segment flags reset at group leaders, and the add saturates at int32
   max so refused oversized hits can never wrap (saturation only engages
@@ -274,38 +276,69 @@ def _segment_ends(is_leader: jax.Array, ar: jax.Array) -> jax.Array:
     )
 
 
-def _use_sweep_writeback(buckets: int, W: int, B: int) -> bool:
-    """Trace-time selection of the pallas store-sweep writeback
-    (core/pallas_sweep.py). GUBER_WRITEBACK: "auto" (default) picks the
-    sweep in its measured winning regime — dense updates, B >= 4x the
-    bucket count, where it beats the XLA scatter by a robust 1.14-1.34x
-    on v5e (scripts/bench_sweep_regime.py). Below that the two trade
-    within noise (sweep +7% at density 0.5, -23% at density 1.0 on the
-    flagship 32k-bucket store), so auto conservatively keeps the
-    scatter there. "sweep"/"scatter" force one path; unknown values
-    fall back to auto."""
+# writeback_form's crossover: the hinted scatter wins from B = rows / 32
+# up (a ~1.5 ns a table row sweep against ~70 ns a batch row of touches
+# puts the tie near rows / 44; measured either side of it at 2^15 to
+# 2^20 rows, scripts/bench_sweep_regime.py --hint on v5e, PR 31)
+SORTED_HINT_ROWS_PER_ITEM = 32
+
+
+def writeback_form(buckets: int, W: int, B: int) -> str:
+    """Trace-time choice of the writeback's form, from the shapes
+    _writeback_apply sees (its table is [buckets, W], its batch B rows;
+    under shard_map both are one shard's) and nothing else. One
+    arithmetic — delta rows added at sorted, possibly duplicate bucket
+    indices into disjoint ways — in three forms whose cost models
+    differ (ms a call on v5e, PERF.md section 6, PR 31):
+
+    - "sweep": the pallas store sweep (core/pallas_sweep.py), dense
+      updates, B >= 4x the bucket count, where it beat the hinted XLA
+      scatter by 1.14-1.34x (r3). Never on a non-TPU backend: it is a
+      Mosaic kernel.
+    - "scatter_sorted": the XLA scatter-add told its indices are
+      sorted. The TPU then sweeps the WHOLE operand in place: cost
+      follows the table's bytes (0.05 ms at 2^15 rows, 1.6 at 2^20,
+      25-26 at 2^24), hardly B. Taken where the batch is deep beside
+      the store, B >= buckets / 32.
+    - "scatter": the same scatter-add without the hint. The TPU then
+      touches the B rows: cost follows B (0.007 ms at B = 64, 0.08 at
+      1024, 1.2 at 16384) whatever the table's size. Everywhere else:
+      every rung of the default ladder into any store of more than
+      2^15 rows, and all but a full 1024-group batch into that one.
+
+    GUBER_WRITEBACK: "auto" (default) as above; "scatter" never takes
+    the sweep, "sweep" takes it wherever its shape constraints allow;
+    whether the scatter is told is the shapes' business under all
+    three. Unknown values fall back to auto."""
     import os
 
     mode = os.environ.get("GUBER_WRITEBACK", "auto")
+    scatter = (
+        "scatter_sorted"
+        if SORTED_HINT_ROWS_PER_ITEM * B >= buckets
+        else "scatter"
+    )
     if mode == "scatter":
-        return False
+        return scatter
     if mode != "sweep" and jax.default_backend() != "tpu":
         # the sweep is a Mosaic TPU kernel: auto must never pick it on
         # a CPU/GPU backend, where its non-interpret lowering cannot
         # compile (the CPU mesh-serving stacks hit exactly this before
         # r14 gated it). GUBER_WRITEBACK=sweep still forces the path
         # (interpret-mode tests and TPU-bound benches).
-        return False
+        return scatter
     if mode != "sweep" and B < 4 * buckets:
-        return False
+        return scatter
     from gubernator_tpu.core.pallas_sweep import CHUNK, TILE_ROWS
 
-    return (
+    if (
         W == 128
         and buckets % TILE_ROWS == 0
         and B >= CHUNK
         and B % 8 == 0
-    )
+    ):
+        return "sweep"
+    return scatter
 
 
 @jax.named_scope("writeback_plan")
@@ -395,7 +428,8 @@ def _writeback_apply(
 ) -> jax.Array:
     """Phase 2: apply the planned updates as ONE scatter-ADD of delta
     rows (the arithmetic and measured rationale live on
-    _writeback_delta_add)."""
+    _writeback_delta_add), in the form writeback_form picks from this
+    call's traced shapes: the only place a decide writes the table."""
     B = bkt.shape[0]
     buckets, W = data.shape
     ways = W // LANES
@@ -412,11 +446,14 @@ def _writeback_apply(
         dmask[:, :, None], delta8[:, None, :], 0
     ).reshape(B, W)
 
-    if _use_sweep_writeback(buckets, W, B):
+    form = writeback_form(buckets, W, B)
+    if form == "sweep":
         from gubernator_tpu.core.pallas_sweep import _apply_inline
 
         return _apply_inline(data, bkt, drow)
-    return data.at[bkt].add(drow, indices_are_sorted=True)
+    return data.at[bkt].add(
+        drow, indices_are_sorted=form == "scatter_sorted"
+    )
 
 
 def _writeback_delta_add(
@@ -449,7 +486,10 @@ def _writeback_delta_add(
     already-sorted bucket stream and duplicate indices are legal by the
     arithmetic: updates to one bucket touch DISJOINT ways, so the adds
     compose exactly (old + (new - old) = new; int32 wrap-around in the
-    subtraction self-corrects on the add). Measured on v5e this replaces
+    subtraction self-corrects on the add) and in any order — which is
+    why the three forms of the apply (pallas sweep, scatter-add told
+    its indices are sorted, scatter-add not told: writeback_form) leave
+    the table equal word for word. Measured on v5e this replaces
     ~500us of [B,128] segmented select-scans with ~30us of [B,16]
     cumsums + one add-scatter at B=16384.
 
@@ -578,9 +618,12 @@ def _decide_presorted(
     Caller contract (engine.pad_request_sorted / the decide() wrapper):
     - rows are ordered so that (bucket(key_hash), fingerprint(key_hash))
       is non-decreasing over the WHOLE batch, including invalid rows —
-      this is what lets every gather/scatter run with
-      indices_are_sorted=True. Hosts pad by repeating the last real
-      row's key with valid=False, which preserves monotonicity.
+      this is what lets every gather run with indices_are_sorted=True,
+      and the writeback scatter where that hint pays (a batch deep
+      beside the store; elsewhere the hint is withheld because the
+      TPU's sorted scatter sweeps its whole operand: writeback_form).
+      Hosts pad by repeating the last real row's key with valid=False,
+      which preserves monotonicity.
     - invalid rows may appear anywhere (the mesh path masks non-owned
       rows in place, serve/parallel sharding), with one constraint: a
       group containing any valid row must have a VALID leader (first

@@ -50,8 +50,10 @@ The dense regime the r2 note hypothesized is REAL and now measured:
 
 Small-store / big-batch deployments (B >= ~4x bucket count) get
 1.14-1.34x from the sweep, so GUBER_WRITEBACK=auto (the default,
-kernels._use_sweep_writeback) selects it exactly there; =sweep/=scatter
-force a path.
+kernels.writeback_form) selects it exactly there; =sweep/=scatter
+force a path. "scatter" in this table is the scatter-add told its
+indices are sorted, the form writeback_form still gives every one of
+these shapes when it does not give the sweep.
 
 Because the update stream is bucket-sorted, rows DMA'd beyond the tile's
 [lo, hi) range map outside [0, TILE_ROWS) and one-hot to zero — the

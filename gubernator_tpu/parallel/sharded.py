@@ -1173,6 +1173,23 @@ class PartitionedEngine:
                 out[d.id] = out.get(d.id, 0) + nbytes
         return out
 
+    def writeback_forms(self) -> dict:
+        """{decide rung: the writeback forms its programs were traced
+        with}, for the boot log: kernels.writeback_form at this
+        engine's table shape (one SHARD's on a mesh, which is what the
+        kernel sees under shard_map) and each of the rung's group
+        rungs, the row count the writeback runs at. Shapes only, like
+        state_bytes_by_device."""
+        from gubernator_tpu.core.engine import group_rungs
+        from gubernator_tpu.core.kernels import writeback_form
+
+        data = self.store.data
+        *_, rows, W = data.sharding.shard_shape(data.shape)
+        return {
+            b: sorted({writeback_form(rows, W, g) for g in group_rungs(b)})
+            for b in (self.buckets if self.flat else self.sub_buckets)
+        }
+
     def reset(self) -> None:
         """Empty state, the old state's device memory handed back
         BEFORE the new is allocated. Built first and swapped after, two

@@ -523,11 +523,19 @@ class Server:
         if dev["host_prep"] == "native":
             from gubernator_tpu.native import hashlib_native as _hn
 
+            engine = getattr(self.backend, "engine", None)
+            forms = (
+                engine.writeback_forms()
+                if hasattr(engine, "writeback_forms") else {}
+            )
             log.info(
                 "native prep: %d thread(s) (GUBER_PREP_THREADS), "
-                "writeback=%s (GUBER_WRITEBACK)",
+                "writeback=%s (GUBER_WRITEBACK), form by rung: %s",
                 _hn.prep_threads(),
                 os.environ.get("GUBER_WRITEBACK", "auto"),
+                " ".join(
+                    "%d:%s" % (b, "/".join(f)) for b, f in forms.items()
+                ) or "n/a",
             )
         else:
             log.info(

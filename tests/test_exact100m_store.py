@@ -238,17 +238,13 @@ def _plain_writeback(table, bkt, write_item, found, fway, eway, new_vals):
     return out.reshape(table.shape), dropped, evicted
 
 
-@pytest.mark.parametrize("seed", range(6))
-@pytest.mark.parametrize("ways", [16, 4])
-def test_writeback_equals_a_plain_row_write_on_random_plans(seed, ways):
-    """found / miss / evict / dropped creates, several writers in one
-    bucket, every way, buckets at both ends of the table: the table
-    after the one scatter-add equals the plain write, word for word."""
-    rng = np.random.default_rng(1000 * ways + seed)
-    buckets, B = 32, 96
+def _random_plan(rng, buckets, B, ways, empty_share):
+    """(table, the positional arguments of _writeback_delta_add after
+    it): found / miss writers and non-writers (duplicates, padding:
+    they carry a real bucket and add a zero row), several to a bucket,
+    buckets at both ends of the table; `empty_share` of the ways start
+    empty, none at 0.0, so misses there evict and drop."""
     table = rng.integers(1, 1 << 20, (buckets, ways, LANES)).astype(np.int32)
-    # every third seed leaves no way empty: its misses evict and drop
-    empty_share = (0.0, 0.15, 0.6)[seed % 3]
     table[rng.random((buckets, ways)) < empty_share] = 0
     table = table.reshape(buckets, ways * LANES)
     bkt = np.sort(rng.choice(
@@ -272,17 +268,76 @@ def test_writeback_equals_a_plain_row_write_on_random_plans(seed, ways):
     is_b_leader = np.r_[True, bkt[1:] != bkt[:-1]]
     b_end = np.asarray(K._segment_ends(
         jnp.asarray(is_b_leader), jnp.arange(B, dtype=jnp.int32)))
+    return table, (bkt, write_item, found, fway, eway, new_vals, cand,
+                   is_b_leader, b_end)
+
+
+@pytest.mark.parametrize("seed", range(6))
+@pytest.mark.parametrize("ways", [16, 4])
+def test_writeback_equals_a_plain_row_write_on_random_plans(seed, ways):
+    """found / miss / evict / dropped creates, several writers in one
+    bucket, every way, buckets at both ends of the table: the table
+    after the one scatter-add equals the plain write, word for word."""
+    rng = np.random.default_rng(1000 * ways + seed)
+    # every third seed leaves no way empty: its misses evict and drop
+    empty_share = (0.0, 0.15, 0.6)[seed % 3]
+    table, plan = _random_plan(rng, 32, 96, ways, empty_share)
+    bkt, write_item, found, fway, eway, new_vals = plan[:6]
 
     want, want_dropped, want_evicted = _plain_writeback(
         table, bkt, write_item, found, fway, eway, new_vals)
     got, dropped, evicted = jax.jit(K._writeback_delta_add)(
-        *(jnp.asarray(x) for x in (
-            table, bkt, write_item, found, fway, eway, new_vals, cand,
-            is_b_leader, b_end)))
+        *(jnp.asarray(x) for x in (table, *plan)))
     assert np.array_equal(np.asarray(got), want)
     assert (int(dropped), int(evicted)) == (want_dropped, want_evicted)
     if empty_share == 0.0:
         assert want_dropped > 0 and want_evicted > 0
+
+
+@pytest.mark.parametrize("seed", range(6))
+@pytest.mark.parametrize("buckets,B", [
+    (32, 96),    # deep beside the store: the shapes alone pick the hint
+    (4096, 64),  # a served shape: the shapes alone withhold it
+])
+def test_the_hinted_and_the_unhinted_scatter_are_one_function(
+    monkeypatch, seed, buckets, B
+):
+    """The same seeded plan — duplicate buckets, non-writers that carry
+    a real bucket, full buckets on every third seed — through the
+    scatter-add told its indices are sorted and through the one not
+    told (kernels.writeback_form's two XLA forms, each forced in turn
+    whatever the shapes would pick): the tables are equal word for
+    word, `dropped` and `evicted` equal, and both equal the plain
+    row-by-row write."""
+    rng = np.random.default_rng(7000 + 10 * buckets + seed)
+    empty_share = (0.0, 0.15, 0.6)[seed % 3]
+    table, plan = _random_plan(rng, buckets, B, 16, empty_share)
+    natural = K.writeback_form(buckets, 128, B)
+    assert natural == ("scatter_sorted" if 32 * B >= buckets else "scatter")
+    out = {}
+    for form in ("scatter_sorted", "scatter"):
+        monkeypatch.setattr(K, "writeback_form", lambda *a, form=form: form)
+
+        def apply(*a):  # a function of its own: jit caches by function
+            return K._writeback_delta_add(*a)
+
+        args = tuple(jnp.asarray(x) for x in (table, *plan))
+        sorted_flags = {
+            e.params["indices_are_sorted"]
+            for e in jax.make_jaxpr(apply)(*args).jaxpr.eqns
+            if e.primitive.name == "scatter-add"
+        }
+        assert sorted_flags == {form == "scatter_sorted"}
+        got, dropped, evicted = jax.jit(apply)(*args)
+        out[form] = (np.asarray(got), int(dropped), int(evicted))
+    hinted, plain = out["scatter_sorted"], out["scatter"]
+    assert np.array_equal(hinted[0], plain[0])
+    assert hinted[1:] == plain[1:]
+    bkt, write_item, found, fway, eway, new_vals = plan[:6]
+    want = _plain_writeback(
+        table, bkt, write_item, found, fway, eway, new_vals)
+    assert np.array_equal(plain[0], want[0]) and plain[1:] == want[1:]
+    assert not np.array_equal(plain[0], table)
 
 
 # -- one table --------------------------------------------------------------

@@ -457,23 +457,114 @@ def test_writeback_auto_selection(monkeypatch):
     values force a path."""
     import jax as jax_mod
 
-    from gubernator_tpu.core.kernels import _use_sweep_writeback
+    from gubernator_tpu.core.kernels import writeback_form
+
+    def sweeps(buckets, W, B):
+        return writeback_form(buckets, W, B) == "sweep"
 
     monkeypatch.delenv("GUBER_WRITEBACK", raising=False)
     # auto never picks the Mosaic TPU kernel on a non-TPU backend
-    assert not _use_sweep_writeback(2048, 128, 16384)
+    assert not sweeps(2048, 128, 16384)
     # ... the regime assertions below model a TPU host
     monkeypatch.setattr(jax_mod, "default_backend", lambda: "tpu")
     # flagship store (32k buckets, 32k batch): density 1 -> scatter
-    assert not _use_sweep_writeback(1 << 15, 128, 1 << 15)
+    assert not sweeps(1 << 15, 128, 1 << 15)
     # dense small-store regime: density >= 4 -> sweep
-    assert _use_sweep_writeback(2048, 128, 16384)
-    assert _use_sweep_writeback(4096, 128, 32768)
+    assert sweeps(2048, 128, 16384)
+    assert sweeps(4096, 128, 32768)
     # shape constraints still gate the sweep even in its regime
-    assert not _use_sweep_writeback(2048, 64, 16384)  # W != 128
-    assert not _use_sweep_writeback(100, 128, 16384)  # buckets % 128
+    assert not sweeps(2048, 64, 16384)  # W != 128
+    assert not sweeps(100, 128, 16384)  # buckets % 128
 
     monkeypatch.setenv("GUBER_WRITEBACK", "scatter")
-    assert not _use_sweep_writeback(2048, 128, 16384)
+    assert not sweeps(2048, 128, 16384)
     monkeypatch.setenv("GUBER_WRITEBACK", "sweep")
-    assert _use_sweep_writeback(1 << 15, 128, 16384)
+    assert sweeps(1 << 15, 128, 16384)
+
+
+# every (rows of the table a decide sees, rows of its writeback) a cell
+# traces: the default ladder into the default, the 10M-key and the
+# 100M-key store, and the mesh's sub-rungs into one shard's 2^18 rows.
+# A group rung is never above its rung, so the rungs bound the
+# writeback's rows. All the unhinted scatter but one: a full
+# 1024-group batch into the default store's 2^15 rows sits AT rows / 32,
+# where the hinted pass (61 us) still beats 1024 row touches (76 us).
+_SERVED_SHAPES = [
+    (1 << r, B) for r in (15, 20, 24) for B in (64, 256, 1024)
+] + [(1 << 18, B) for B in (64, 96, 128, 192, 256, 384, 512, 768, 1024)]
+
+
+_SERVED_FORMS = {
+    shape: "scatter_sorted" if shape == (1 << 15, 1024) else "scatter"
+    for shape in _SERVED_SHAPES
+}
+
+
+@pytest.mark.parametrize("rows,B,auto,scatter_mode", [
+    *((*shape, form, form) for shape, form in _SERVED_FORMS.items()),
+    # the 1024 rung's lower group rungs into the default store
+    (1 << 15, 384, "scatter", "scatter"),
+    (1 << 15, 768, "scatter", "scatter"),
+    # deep batches (GUBER_DEVICE_DEEP_BATCH, cli/bench_serving.py; no
+    # cell): the hint from B = rows / 32 up, the sweep from 4 x rows up
+    (1 << 15, 16384, "scatter_sorted", "scatter_sorted"),
+    (1 << 15, 131072, "sweep", "scatter_sorted"),
+    (1 << 18, 16384, "scatter_sorted", "scatter_sorted"),
+    (1 << 20, 16384, "scatter", "scatter"),
+    (1 << 24, 16384, "scatter", "scatter"),
+])
+def test_writeback_form_at_every_traced_shape(
+    monkeypatch, rows, B, auto, scatter_mode
+):
+    """The form is a function of the traced shapes alone: the same
+    answer for a flat table and for one shard of a mesh's, whatever the
+    deployment is called."""
+    import jax as jax_mod
+
+    from gubernator_tpu.core.kernels import writeback_form
+
+    monkeypatch.setattr(jax_mod, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("GUBER_WRITEBACK", raising=False)
+    assert writeback_form(rows, 128, B) == auto
+    # =scatter keeps the sweep out and leaves the hint to the shapes
+    monkeypatch.setenv("GUBER_WRITEBACK", "scatter")
+    assert writeback_form(rows, 128, B) == scatter_mode
+    # off the TPU auto is =scatter
+    monkeypatch.delenv("GUBER_WRITEBACK")
+    monkeypatch.setattr(jax_mod, "default_backend", lambda: "cpu")
+    assert writeback_form(rows, 128, B) == scatter_mode
+
+
+@pytest.mark.parametrize("rows,B,hinted", [
+    (1 << 24, 1024, False),  # exact100m's top rung
+    (1 << 15, 64, False),  # the default daemon's bottom rung
+    (1 << 15, 1024, True),  # its top rung, every key its own group
+    (1 << 15, 16384, True),  # a deep batch into the default store
+])
+def test_writeback_apply_lowers_the_form_it_chose(rows, B, hinted):
+    """_writeback_apply traced at a served and at a deep shape (shapes
+    only: no table is made): ONE scatter-add, and its
+    indices_are_sorted is what writeback_form said."""
+    import jax
+    import jax.numpy as jnp
+
+    from gubernator_tpu.core import kernels as K
+    from gubernator_tpu.core.store import LANES
+
+    closed = jax.make_jaxpr(
+        lambda d, b, r: K._writeback_apply(
+            d, b, jnp.ones(b.shape, bool), jnp.zeros(b.shape, jnp.int32),
+            r[:, :LANES], r.reshape(b.shape[0], 16, LANES))
+    )(
+        jax.ShapeDtypeStruct((rows, 128), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.ShapeDtypeStruct((B, 128), jnp.int32),
+    )
+    scatters = [
+        e for e in closed.jaxpr.eqns if e.primitive.name == "scatter-add"
+    ]
+    assert len(scatters) == 1
+    assert scatters[0].params["indices_are_sorted"] is hinted
+    assert hinted == (K.writeback_form(rows, 128, B) == "scatter_sorted")
+    # duplicate buckets are the rule, under either form
+    assert scatters[0].params["unique_indices"] is False

@@ -116,6 +116,26 @@ def test_engine_classes_are_one_implementation():
         ), f"{name} forked on MeshEngine"
 
 
+@pytest.mark.parametrize("slots,want", [
+    # 2^12 rows: rung 64's group rung and rung 256's low ones sit under
+    # rows / 32, 128 rows and up reach it
+    (1 << 12, {64: ["scatter"], 256: ["scatter", "scatter_sorted"]}),
+    (1 << 14, {64: ["scatter"], 256: ["scatter"]}),
+])
+def test_writeback_forms_reads_one_shards_shape(slots, want):
+    """The boot log's `form by rung`: kernels.writeback_form at the
+    table shape the kernel sees — the whole table flat, ONE shard's
+    rows on a mesh (`slots` sizes a shard) — over each rung's group
+    rungs; the mesh lists its sub-rung ladder."""
+    config = StoreConfig(rows=16, slots=slots)
+    flat = TpuEngine(config, buckets=(64, 256))
+    assert flat.writeback_forms() == want
+    mesh = MeshEngine(config, buckets=(64, 256))
+    forms = mesh.writeback_forms()
+    assert tuple(forms) == mesh.sub_buckets
+    assert {b: forms[b] for b in want} == want
+
+
 def test_single_vs_one_shard_mesh_identical_under_pressure():
     """A 1-device mesh policy IS the degenerate case: same table
     geometry, same kernel — decisions stay byte-identical even under

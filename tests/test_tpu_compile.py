@@ -47,10 +47,10 @@ from gubernator_tpu.parallel.sharded import (
 
 NOW = 1_700_000_000_000
 MIB = 1 << 20
-# what `GUBER_STORE_TARGET_KEYS=10000000` derives (chip_smoke.py): 16
-# ways x 2^20 bucket rows = 512 MiB, plus the default 16 MiB v2 sketch
-STORE = StoreConfig(rows=16, slots=1 << 20)
-SHARD_STORE = StoreConfig(rows=16, slots=1 << 18)  # a quarter each
+# `GUBER_STORE_TARGET_KEYS=10000000` derives 16 ways x 2^20 bucket rows
+# = 512 MiB (chip_smoke.py), plus the default 16 MiB v2 sketch; on the
+# four-chip mesh a quarter of it each
+SHARD_STORE = StoreConfig(rows=16, slots=1 << 18)
 LADDER = (64, 256, 1024)  # buckets_for_limit(1000), the daemon's default
 
 
@@ -162,9 +162,22 @@ def _batch(keys):
     )
 
 
-def test_flat_sketch_decide_1024_rung_compiles_for_v5e(topo, no_compile_cache):
-    """The program the default daemon serves: decide_presorted_sketch at
-    the 1024 rung, 512 MiB store + 16 MiB sketch, both donated."""
+@pytest.mark.parametrize("slots,temp_limit", [
+    (1 << 20, 512 * MIB),  # zipf10m
+    # the table that fills a chip (exact100m, PR 30): a second table,
+    # or a pass that is not in place, cannot hide under this limit. The
+    # temporaries read 2.6 MB at both row counts with the scatter told
+    # its indices are sorted (PR 30) and without (PR 31).
+    (1 << 24, 3 * MIB),
+])
+def test_flat_sketch_decide_1024_rung_compiles_for_v5e(
+    topo, no_compile_cache, slots, temp_limit
+):
+    """The program a one-chip daemon serves: decide_presorted_sketch at
+    the 1024 rung, store + 16 MiB sketch, both donated — at 2^20 rows
+    (512 MiB, zipf10m) and at 2^24 (8 GiB, exact100m), where the
+    writeback's unhinted scatter-add (kernels.writeback_form) must
+    still write into the donated table."""
     one = SingleDeviceSharding(topo.devices[0])
     small = TpuEngine(
         StoreConfig(rows=16, slots=1 << 10), buckets=LADDER,
@@ -178,19 +191,19 @@ def test_flat_sketch_decide_1024_rung_compiles_for_v5e(topo, no_compile_cache):
     assert (B, G) == (1024, 1024)
     assert packed_in.shape == (packed_inputs_width(B, G),)
     real = _shapes_only_engine(lambda eng: one)(
-        STORE, policy=ShardingPolicy.single(), buckets=LADDER,
-        sketch=_sketch(16),
+        StoreConfig(rows=16, slots=slots), policy=ShardingPolicy.single(),
+        buckets=LADDER, sketch=_sketch(16),
     )
     compiled = engine_mod._decide_packed_sketch_jit.lower(
         real.store, real.sketch, _shapes(packed_in, one), B, G
     ).compile()
     mem = compiled.memory_analysis()
-    state = 512 * MIB + 16 * MIB
+    state = slots * 512 + 16 * MIB
     assert state <= mem.argument_size_in_bytes < state + MIB
     # store and sketch are donated: the outputs alias them, so the step
     # holds ONE copy of the state on a 16 GB chip
     assert mem.alias_size_in_bytes >= state
-    assert mem.temp_size_in_bytes < 512 * MIB
+    assert mem.temp_size_in_bytes < temp_limit
     # the batch comes in as ONE int32 parameter beside the state, and
     # its 64-bit hashes are rebuilt from two 32-bit segments by shifts
     # and ORs, which the TPU compiler keeps as the (low, high) pair it
