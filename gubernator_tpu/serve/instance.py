@@ -14,7 +14,11 @@ execution model:
   forward to their owner peer (micro-batched per peer unless NO_BATCHING).
   Responses reassemble in request order (gubernator.go:75-169).
 - GetPeerRateLimits serves owner-side batches for other peers
-  (gubernator.go:210-227).
+  (gubernator.go:210-227): whatever arrives is applied, no ownership
+  check. On the stage clock a peer call's tiles are grpc_decode,
+  peer_serve (this module: the call less its waits for the batcher),
+  call_queue, call_device, call_wake, grpc_encode — no instance_route,
+  which is get_rate_limits' alone.
 - UpdatePeerGlobals installs owner-broadcast GLOBAL replicas
   (gubernator.go:199-207).
 - set_peers rebuilds the picker on membership change, reusing existing
@@ -118,6 +122,13 @@ class Instance:
             )
         else:
             self.shed = None
+        # the owner side of the ring (get_peer_rate_limits): forwarded
+        # batches served, their items, and the items the shed screen
+        # answered without a device trip. Plain ints, exported at
+        # scrape (peer_serve_*_total) like the shed cache's
+        self.peer_serve_batches = 0
+        self.peer_serve_items = 0
+        self.peer_serve_shed_hits = 0
         # bucket replication (r11, serve/replication.py): owned windows
         # snapshot to each key's ring successor so a killed owner's
         # quota state survives takeover. OFF by default
@@ -756,6 +767,31 @@ class Instance:
                 f"'PeerRequest.rate_limits' list too large; max size is "
                 f"'{MAX_BATCH_SIZE}'"
             )
+        # the owner side's own tile of a peer call (serve/stages.py
+        # peer_serve): the call less what it waited for the batcher,
+        # which the batcher's call tiles cover. Bare stamps, one
+        # sample a call: the span crosses the awaits
+        t0 = time.monotonic()
+        waited = [0.0]
+        self.peer_serve_batches += 1
+        self.peer_serve_items += len(reqs)
+        try:
+            return await self._peer_serve(reqs, waited)
+        finally:
+            STAGES.add("peer_serve", time.monotonic() - t0 - waited[0])
+
+    @staticmethod
+    async def _batcher_wait(decide, waited: List[float]):
+        """Await one batcher decide and add its seconds to `waited`."""
+        t = time.monotonic()
+        try:
+            return await decide
+        finally:
+            waited[0] += time.monotonic() - t
+
+    async def _peer_serve(
+        self, reqs: Sequence[RateLimitReq], waited: List[float]
+    ) -> List[RateLimitResp]:
         try:
             if FAULTS.enabled:
                 # owner-side injection point: a chaos spec can make THIS
@@ -783,8 +819,11 @@ class Instance:
                     else:
                         ok_idx.append(i)
                 if ok_idx:
-                    cresps = await self.batcher.decide_chain(
-                        [reqs[i] for i in ok_idx]
+                    cresps = await self._batcher_wait(
+                        self.batcher.decide_chain(
+                            [reqs[i] for i in ok_idx]
+                        ),
+                        waited,
                     )
                     for i, resp in zip(ok_idx, cresps):
                         out_c[i] = resp
@@ -793,7 +832,7 @@ class Instance:
                 ]
                 if plain:
                     presps = await self._peer_serve_plain(
-                        [r for _, r in plain]
+                        [r for _, r in plain], waited
                     )
                     for (i, _), resp in zip(plain, presps):
                         out_c[i] = resp
@@ -801,12 +840,12 @@ class Instance:
                     o if o is not None else RateLimitResp()
                     for o in out_c
                 ]
-            return await self._peer_serve_plain(reqs)
+            return await self._peer_serve_plain(reqs, waited)
         except Exception as e:
             return [RateLimitResp(error=str(e)) for _ in reqs]
 
     async def _peer_serve_plain(
-        self, reqs: Sequence[RateLimitReq]
+        self, reqs: Sequence[RateLimitReq], waited: List[float]
     ) -> List[RateLimitResp]:
         """The owner-side decide for PLAIN (non-chained) forwarded
         batches: shed screen + device decide (the pre-r15
@@ -814,7 +853,9 @@ class Instance:
         try:
             shed = self.shed
             if shed is None:
-                return await self.decide_local(reqs, [False] * len(reqs))
+                return await self._batcher_wait(
+                    self.decide_local(reqs, [False] * len(reqs)), waited
+                )
             # owner-side shed screen: forwarded items for a frozen
             # over-limit key are answered without a device trip; the
             # residue decides normally and its responses populate the
@@ -834,9 +875,13 @@ class Instance:
                 else:
                     residue.append((i, r))
                     res_fps.append(int(hashes[i]))
+            self.peer_serve_shed_hits += len(reqs) - len(residue)
             if residue:
-                resps = await self.decide_local(
-                    [r for _, r in residue], [False] * len(residue)
+                resps = await self._batcher_wait(
+                    self.decide_local(
+                        [r for _, r in residue], [False] * len(residue)
+                    ),
+                    waited,
                 )
                 shed.observe_resps(
                     res_fps, [r for _, r in residue], resps

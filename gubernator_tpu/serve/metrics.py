@@ -217,6 +217,28 @@ SHED_INDEX_REBUILDS = Gauge(
     "means every frame pays a sort",
     registry=REGISTRY,
 )
+PEER_SERVE_BATCHES = Gauge(
+    "peer_serve_batches_total",
+    "GetPeerRateLimits batches this node served as the owner "
+    "(Instance.get_peer_rate_limits; exported lazily at scrape like "
+    "the shed cache's totals). Pair with the peer_serve stage for the "
+    "owner side's own seconds a batch",
+    registry=REGISTRY,
+)
+PEER_SERVE_ITEMS = Gauge(
+    "peer_serve_items_total",
+    "Rate-limit items in those batches: what the node's peers "
+    "forwarded to it",
+    registry=REGISTRY,
+)
+PEER_SERVE_SHED_HITS = Gauge(
+    "peer_serve_shed_hits_total",
+    "Forwarded items the owner-side shed screen answered over-limit "
+    "from the host cache, without a device trip; / "
+    "peer_serve_items_total = the share of a ring member's peer "
+    "traffic that never reaches the batcher",
+    registry=REGISTRY,
+)
 FAULTS_INJECTED = Counter(
     "faults_injected_total",
     "Injected faults fired (serve/faults.py, GUBER_FAULT_SPEC) — a "

@@ -259,7 +259,8 @@ def _md_traceparent(context) -> "Optional[str]":
 async def _serve_call(instance, door, context, pb_reqs, decide, reply):
     """One rate-limit call through a gRPC door, tiled on the stage
     clock (serve/stages.py CALL_TILES): grpc_decode and grpc_encode
-    here, instance_route in the instance, call_queue / call_device /
+    here, instance_route (GetRateLimits) or peer_serve
+    (GetPeerRateLimits) in the instance, call_queue / call_device /
     call_wake in the batcher for the group this handler enqueues
     first (mark_call), call_e2e around them all. Decode and encode
     run inside the trace scope, so a sampled call's trace holds them
@@ -1091,6 +1092,9 @@ class Server:
             metrics.SHED_ENTRIES.set(len(shed))
             metrics.SHED_INDEX_USES.set(shed.index_uses)
             metrics.SHED_INDEX_REBUILDS.set(shed.index_rebuilds)
+        metrics.PEER_SERVE_BATCHES.set(self.instance.peer_serve_batches)
+        metrics.PEER_SERVE_ITEMS.set(self.instance.peer_serve_items)
+        metrics.PEER_SERVE_SHED_HITS.set(self.instance.peer_serve_shed_hits)
         if self.instance.repl is not None:
             metrics.REPLICATION_STANDBY_ENTRIES.set(
                 self.instance.repl.standby_len

@@ -84,9 +84,11 @@ Stages form two families:
                      decide_local of every queued key, queue + device
                      + fetch of the batcher included
 
-- **per-call stages** (`PER_CALL`): the gRPC door's family. The six
-  `CALL_TILES` tile one GetRateLimits / GetPeerRateLimits call from
-  handler entry to handler return the way PER_FRAME tiles a frame;
+- **per-call stages** (`PER_CALL`): the gRPC door's family. Six of the
+  seven `CALL_TILES` tile one call from handler entry to handler
+  return the way PER_FRAME tiles a frame: a GetRateLimits call records
+  `instance_route` and no `peer_serve`, a GetPeerRateLimits call
+  `peer_serve` and no `instance_route`, both the other five.
   `call_coverage` = sum(tile seconds) / `call_e2e` seconds, and the
   gap is event-loop scheduling between tiles. The batcher records
   its three tiles for the first group a gRPC handler enqueues
@@ -105,6 +107,12 @@ Stages form two families:
                      the bridge's fold serves, for the same work on
                      arrays: ownership screen, key hashing, traffic
                      observers, the managers' notes, GLOBAL queueing
+    peer_serve       the owner side of a forwarded batch, once per
+                     Instance.get_peer_rate_limits call: everything
+                     it does on the loop that is not waiting for the
+                     batcher — key hashing, the shed screen item by
+                     item, the residue's lists, the cache's
+                     population from the answers, the stitch
     call_queue       batcher enqueue -> flusher collect, for the
                      call's group (the twin of batch_queue)
     call_device      flusher collect -> the group's future resolved
@@ -141,8 +149,8 @@ or belong to no thread (batch_queue, device, call_queue, call_device,
 call_wake, call_e2e, global_peek),
 and the per-call ones recorded from bare stamps on the serving loop
 (grpc_decode, instance_route, grpc_encode: tens of microseconds
-each, and a span object a call is not free there), stay on the stage
-clock only.
+each, and a span object a call is not free there; peer_serve, which
+crosses the batcher's await), stay on the stage clock only.
 
 The chain lane (r15) participates in BOTH families like the decide
 lanes (r16 audit fix): a frame-flagged chained group records
@@ -190,7 +198,8 @@ PER_BATCH = (
 PER_FLUSH = ("global_peek",)
 CALL_TILES = (
     "grpc_decode",
-    "instance_route",
+    "instance_route",  # a GetRateLimits call's; a peer call has none
+    "peer_serve",  # a GetPeerRateLimits call's; a client call has none
     "call_queue",
     "call_device",
     "call_wake",

@@ -1,7 +1,8 @@
 """The gRPC door's call tiles, the span helper, the stage clock's
 quantile buckets and the process probes (serve/stages.py, PR 24).
 
-- a tiny daemon served over real gRPC records all six CALL_TILES with
+- a tiny daemon served over real gRPC records a GetRateLimits call's
+  six CALL_TILES (all but peer_serve, the peer door's) with
   call_coverage >= 0.8 and NO batch_queue/device for those calls; a GEB
   frame through the same daemon records no call_* stage (one test, a
   case a door), and both count their batches' padded slots;
@@ -49,6 +50,9 @@ from gubernator_tpu.serve.stages import (
 )
 
 CALLS = 40
+#: a GetRateLimits call's tiles: peer_serve is a GetPeerRateLimits
+#: call's, in instance_route's place (tests/test_ring_owner6.py)
+V1_TILES = tuple(t for t in CALL_TILES if t != "peer_serve")
 
 
 @pytest.fixture()
@@ -145,8 +149,9 @@ def test_each_door_records_its_own_family(node, door):
     call_family = set(snap["per_call_stages"]) - {"instance_route"}
     if door.startswith("grpc"):
         # one set of tiles a call, however many lanes its items ride
-        for tile in CALL_TILES + ("call_e2e",):
+        for tile in V1_TILES + ("call_e2e",):
             assert seen[tile]["count"] == CALLS, (tile, seen.get(tile))
+        assert "peer_serve" not in seen
         assert snap["calls"] == CALLS
         assert 0.8 <= snap["call_coverage"] <= 1.0, snap["call_coverage"]
         # the r7 contract: per-frame stages are frames' alone
@@ -181,7 +186,7 @@ def test_sampled_grpc_call_trace_holds_the_call_spans(node):
         client.get_rate_limits(_reqs("t", 0), timeout=30)
     (trace,) = recorder.snapshot()["traces"]
     names = [s["name"] for s in trace["spans"]]
-    for name in CALL_TILES:
+    for name in V1_TILES:
         assert names.count(name) == 1, (name, names)
     # one name a span: the stage clock's, not the frame family's
     assert not {"batch_queue", "device"} & set(names)
