@@ -1,0 +1,166 @@
+"""A forwarded batch as columns: the PeersV1 door's array form.
+
+`PeersV1/GetPeerRateLimits` carries up to 1000 items a call, and an
+owner that makes a `RateLimitReq`, a `RateLimitResp` and two protobuf
+messages for each of them spends its one GIL on objects (PERF.md,
+PR 32: ~20 ms a batch). These two classes are what the door and the
+instance pass instead: the request's serialised bytes parsed into numpy
+columns by one native call (`PeerBatch.from_wire`), and the answer
+columns serialised by another (`PeerAnswers.to_wire`). Nothing here
+imports JAX or the serving tier.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Iterator, List, Optional, Tuple
+
+import numpy as np
+
+from gubernator_tpu.api.types import Behavior, Status
+from gubernator_tpu.core.hashing import native_lib
+
+
+class PeerBatch:
+    """The items of one serialised GetPeerRateLimitsReq, as columns.
+
+    `fields` is what DeviceBatcher.decide_arrays and the shed cache's
+    screen take (key_hash / hits / limit / duration / algo); `behavior`
+    rides beside it; the key strings stay in `wire` and are decoded
+    for GLOBAL items alone (global_items)."""
+
+    __slots__ = ("wire", "fields", "behavior", "_spans")
+
+    def __init__(self, wire: bytes, cols: Dict[str, np.ndarray]):
+        self.wire = wire
+        self.fields = {
+            k: cols[k]
+            for k in ("key_hash", "hits", "limit", "duration", "algo")
+        }
+        self.behavior = cols["behavior"]
+        self._spans = cols
+
+    def __len__(self) -> int:
+        return self.behavior.shape[0]
+
+    @classmethod
+    def from_wire(
+        cls, wire: bytes, max_items: int
+    ) -> Optional["PeerBatch"]:
+        """The batch, or None where the native parser is not built or
+        declines the message (native/guberhash.cc
+        guber_parse_peer_batch: a chain, an unknown field or wire type,
+        an enum value without a name, invalid UTF-8, a truncated
+        message, more than `max_items` items) and for an empty
+        message: the caller parses those with the protobuf runtime and
+        serves objects."""
+        lib = native_lib()
+        if lib is None:
+            return None
+        n, cols = lib.parse_peer_batch(wire, max_items)
+        if n <= 0:
+            return None
+        return cls(wire, cols)
+
+    def global_items(self) -> Tuple[Dict[int, str], List[tuple]]:
+        """({index: hash key}, [(index, name, unique_key)]) of the
+        Behavior GLOBAL items in batch order: the `keys` and `glob`
+        GlobalManager.queue_update_fields takes. Strings are built for
+        these items only."""
+        idx = np.flatnonzero(self.behavior == int(Behavior.GLOBAL))
+        keys: Dict[int, str] = {}
+        glob: List[tuple] = []
+        if idx.shape[0]:
+            wire, s = self.wire, self._spans
+            for i, no, nl, ko, kl in zip(
+                idx.tolist(),
+                s["name_off"][idx].tolist(), s["name_len"][idx].tolist(),
+                s["key_off"][idx].tolist(), s["key_len"][idx].tolist(),
+            ):
+                name = wire[no : no + nl].decode()
+                ukey = wire[ko : ko + kl].decode()
+                keys[i] = name + "_" + ukey
+                glob.append((i, name, ukey))
+        return keys, glob
+
+
+def _column(name: str, cast=int) -> property:
+    """One field of an _AnswerRow: read from, and written to, row
+    `_i` of the PeerAnswers column `name`."""
+
+    def get(row: "_AnswerRow"):
+        return cast(int(getattr(row._cols, name)[row._i]))
+
+    def put(row: "_AnswerRow", v) -> None:
+        getattr(row._cols, name)[row._i] = int(v)
+
+    return property(get, put)
+
+
+class _AnswerRow:
+    """One row of a PeerAnswers as a RateLimitResp would read — and a
+    `status` (limit, ...) written here lands in the column."""
+
+    __slots__ = ("_cols", "_i")
+
+    def __init__(self, cols: "PeerAnswers", i: int):
+        self._cols = cols
+        self._i = i
+
+    status = _column("status", Status)
+    limit = _column("limit")
+    remaining = _column("remaining")
+    reset_time = _column("reset_time")
+
+    @property
+    def error(self) -> str:
+        return self._cols.error
+
+    @property
+    def metadata(self) -> Dict[str, str]:
+        return {}
+
+
+class PeerAnswers:
+    """The answers to one PeerBatch, as four int64 columns in batch
+    order. `error` is set where the whole batch failed (every item then
+    answers with it, as the object path's per-item error replies do).
+    Iterating yields rows that read like RateLimitResp and write
+    through to the columns."""
+
+    __slots__ = ("status", "limit", "remaining", "reset_time", "error")
+
+    def __init__(self, status, limit, remaining, reset_time, error=""):
+        # columns of its own, writable and of the wire's width: a
+        # device batch may answer in narrower, read-only ones
+        self.status, self.limit, self.remaining, self.reset_time = (
+            np.array(c, np.int64)
+            for c in (status, limit, remaining, reset_time)
+        )
+        self.error = error
+
+    @classmethod
+    def failed(cls, n: int, error: str) -> "PeerAnswers":
+        return cls(*np.zeros((4, n), np.int64), error=error)
+
+    def __len__(self) -> int:
+        return self.status.shape[0]
+
+    def __getitem__(self, i: int) -> _AnswerRow:
+        return _AnswerRow(self, range(len(self))[i])
+
+    def __iter__(self) -> Iterator[_AnswerRow]:
+        return (_AnswerRow(self, i) for i in range(len(self)))
+
+    def to_wire(self) -> bytes:
+        """The serialised GetPeerRateLimitsResp: one native call, or
+        the protobuf runtime where every item carries the error."""
+        if self.error:
+            from gubernator_tpu.api.proto.gen import gubernator_pb2, peers_pb2
+
+            return peers_pb2.GetPeerRateLimitsResp(
+                rate_limits=[gubernator_pb2.RateLimitResp(error=self.error)]
+                * len(self)
+            ).SerializeToString()
+        return native_lib().encode_peer_answers(
+            self.status, self.limit, self.remaining, self.reset_time
+        )

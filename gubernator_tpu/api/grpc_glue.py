@@ -5,6 +5,12 @@ so the servicer registration and client stubs for the two services
 (V1, PeersV1 — reference proto/gubernator.proto:27-45, proto/peers.proto:28-34)
 are written out by hand against the generated message classes. Works with
 both sync and asyncio grpc channels/servers.
+
+One method is registered pass-through: PeersV1/GetPeerRateLimits hands
+its servicer the request's serialised bytes and takes bytes or a message
+back (add_peers_servicer), so the owner side of the ring can serve a
+forwarded batch as arrays without a protobuf message per item. Every
+other method, and every client stub, goes through the generated classes.
 """
 
 from __future__ import annotations
@@ -37,14 +43,25 @@ def add_v1_servicer(server: grpc.Server, servicer) -> None:
     )
 
 
+def _bytes_or_message(reply) -> bytes:
+    return reply if isinstance(reply, bytes) else reply.SerializeToString()
+
+
 def add_peers_servicer(server: grpc.Server, servicer) -> None:
-    """servicer must expose GetPeerRateLimits(req, ctx),
-    UpdatePeerGlobals(req, ctx) and ReplicateBuckets(req, ctx)."""
+    """servicer must expose GetPeerRateLimits(wire, ctx),
+    UpdatePeerGlobals(req, ctx) and ReplicateBuckets(req, ctx).
+
+    GetPeerRateLimits is registered pass-through: the handler is given
+    the request's serialised bytes and returns bytes or a
+    GetPeerRateLimitsResp, so a servicer that can serve a forwarded
+    batch as arrays (serve/server.py: the wire fold) never has the
+    runtime build a message per item; one that cannot parses the bytes
+    itself (GetPeerRateLimitsReq.FromString)."""
     handlers = {
         "GetPeerRateLimits": grpc.unary_unary_rpc_method_handler(
             servicer.GetPeerRateLimits,
-            request_deserializer=peers_pb2.GetPeerRateLimitsReq.FromString,
-            response_serializer=peers_pb2.GetPeerRateLimitsResp.SerializeToString,
+            request_deserializer=None,
+            response_serializer=_bytes_or_message,
         ),
         "UpdatePeerGlobals": grpc.unary_unary_rpc_method_handler(
             servicer.UpdatePeerGlobals,

@@ -39,32 +39,37 @@ def _slot_hash_batch_py(keys: Iterable[str]) -> np.ndarray:
     return np.array([_slot_hash_py(k) for k in keys], dtype=np.uint64)
 
 
-# The native batch hasher (XXH64, gubernator_tpu/native) is loaded lazily.
+# The native library (XXH64, gubernator_tpu/native) is loaded lazily.
 # Native and fallback produce different hash values; that is fine — slot
 # hashes are local to one process's store — but one process must use ONE
 # implementation consistently, which the lazy singleton guarantees.
-_native_batch = None
+_native = None
 _native_checked = False
 
 
-def _load_native():
-    global _native_batch, _native_checked
-    if _native_checked:
-        return
-    _native_checked = True
-    try:
-        from gubernator_tpu.native import hashlib_native
+def native_lib():
+    """The loaded gubernator_tpu.native.hashlib_native module, or None
+    where libguberhash.so is not built: the ONE handle through which
+    this process hashes slot keys natively. Whoever hashes keys inside
+    a native call of its own (the PeersV1 door's wire fold) takes the
+    library from here, so its hashes are slot_hash_batch's."""
+    global _native, _native_checked
+    if not _native_checked:
+        _native_checked = True
+        try:
+            from gubernator_tpu.native import hashlib_native
 
-        _native_batch = hashlib_native.hash_batch
-    except Exception:
-        _native_batch = None
+            _native = hashlib_native
+        except Exception:
+            _native = None
+    return _native
 
 
 def slot_hash_batch(keys: List[str]) -> np.ndarray:
     """uint64[len(keys)] of slot hashes; uses the native extension if built."""
-    _load_native()
-    if _native_batch is not None:
-        return _native_batch(keys)
+    lib = native_lib()
+    if lib is not None:
+        return lib.hash_batch(keys)
     return _slot_hash_batch_py(keys)
 
 
@@ -77,8 +82,7 @@ def using_native_hash() -> bool:
     implementation. The bridge hello advertises this bit (HELLO_XXH64,
     serve/edge_bridge.py) so a fast client can verify agreement instead
     of silently splitting buckets between two hash functions."""
-    _load_native()
-    return _native_batch is not None
+    return native_lib() is not None
 
 
 def slot_hash(key: str) -> int:

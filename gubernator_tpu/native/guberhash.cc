@@ -1339,3 +1339,212 @@ int64_t guber_prep_run(const uint64_t* key_hash, const int64_t* hits,
 }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------------
+// The PeersV1 door's wire fold (serve/server.py GetPeerRateLimits): one
+// forwarded batch, serialised GetPeerRateLimitsReq bytes -> request
+// columns, and answer columns -> serialised GetPeerRateLimitsResp bytes,
+// each in ONE GIL-free call, so a 1000-item batch makes no Python object
+// per item. The parser accepts exactly the messages whose meaning it
+// shares with the protobuf runtime and DECLINES everything else (a
+// negative code); the caller then parses the same bytes with the runtime
+// and serves them through the object path, so declining is always safe.
+// ---------------------------------------------------------------------------
+
+namespace {
+
+// decline codes of guber_parse_peer_batch (hashlib_native.PEER_DECLINE)
+constexpr int64_t PEER_CHAIN = -1;      // a quota chain (field 8)
+constexpr int64_t PEER_UNKNOWN = -2;    // unknown field, or a known one
+                                        // under another wire type
+constexpr int64_t PEER_ENUM = -3;       // algorithm / behavior not named
+constexpr int64_t PEER_UTF8 = -4;       // name or key not valid UTF-8
+constexpr int64_t PEER_TRUNCATED = -5;  // a length or varint runs past
+                                        // its message
+constexpr int64_t PEER_TOO_MANY = -6;   // more items than max_items
+
+// One varint of at most 10 bytes; false where it runs past `end` or
+// carries more than 64 bits.
+inline bool read_varint(const uint8_t*& p, const uint8_t* end,
+                        uint64_t& out) {
+  uint64_t v = 0;
+  for (int shift = 0; shift < 64; shift += 7) {
+    if (p >= end) return false;
+    const uint8_t b = *p++;
+    if (shift == 63 && b > 1) return false;
+    v |= static_cast<uint64_t>(b & 0x7F) << shift;
+    if (b < 0x80) {
+      out = v;
+      return true;
+    }
+  }
+  return false;
+}
+
+// Strict UTF-8 (RFC 3629: no overlong forms, no surrogates, nothing past
+// U+10FFFF) — what the protobuf runtime demands of a proto3 string and
+// what Python's str needs to decode it.
+inline bool valid_utf8(const uint8_t* p, size_t len) {
+  const uint8_t* const end = p + len;
+  while (p < end) {
+    const uint8_t c = *p;
+    if (c < 0x80) {
+      ++p;
+    } else if (c >= 0xC2 && c <= 0xDF) {
+      if (end - p < 2 || (p[1] & 0xC0) != 0x80) return false;
+      p += 2;
+    } else if (c >= 0xE0 && c <= 0xEF) {
+      if (end - p < 3 || (p[1] & 0xC0) != 0x80 || (p[2] & 0xC0) != 0x80)
+        return false;
+      if (c == 0xE0 && p[1] < 0xA0) return false;  // overlong
+      if (c == 0xED && p[1] > 0x9F) return false;  // surrogate
+      p += 3;
+    } else if (c >= 0xF0 && c <= 0xF4) {
+      if (end - p < 4 || (p[1] & 0xC0) != 0x80 || (p[2] & 0xC0) != 0x80 ||
+          (p[3] & 0xC0) != 0x80)
+        return false;
+      if (c == 0xF0 && p[1] < 0x90) return false;  // overlong
+      if (c == 0xF4 && p[1] > 0x8F) return false;  // past U+10FFFF
+      p += 4;
+    } else {
+      return false;
+    }
+  }
+  return true;
+}
+
+inline uint8_t* put_varint(uint8_t* p, uint64_t v) {
+  while (v >= 0x80) {
+    *p++ = static_cast<uint8_t>(v) | 0x80;
+    v >>= 7;
+  }
+  *p++ = static_cast<uint8_t>(v);
+  return p;
+}
+
+}  // namespace
+
+extern "C" {
+
+// Parse a serialised GetPeerRateLimitsReq (repeated RateLimitReq
+// requests = 1) into columns. Returns the number of items, or a decline
+// code < 0 (the columns are then garbage). Per item: key_hash =
+// xxh64(name + "_" + unique_key, seed) hashed off the wire bytes — the
+// value core/hashing.slot_hash_batch gives the same key — hits / limit /
+// duration as int64, algorithm and behavior as their enum numbers, and
+// where name and unique_key lie in `buf` (for the few items whose key
+// strings the caller needs). An absent field reads its proto3 default;
+// of a field sent twice the last wins, as in the runtime. A tag is
+// accepted only in its one-byte canonical form.
+int64_t guber_parse_peer_batch(const uint8_t* buf, int64_t len,
+                               int64_t max_items, uint64_t seed,
+                               uint64_t* key_hash, int64_t* hits,
+                               int64_t* limit, int64_t* duration,
+                               int32_t* algo, uint8_t* behavior,
+                               int32_t* name_off, int32_t* name_len,
+                               int32_t* key_off, int32_t* key_len) {
+  if (len > INT32_MAX) return PEER_TRUNCATED;
+  const uint8_t* p = buf;
+  const uint8_t* const end = buf + len;
+  std::vector<uint8_t> scratch;
+  int64_t n = 0;
+  while (p < end) {
+    if (*p++ != 0x0A) return PEER_UNKNOWN;  // requests = 1, LEN
+    uint64_t mlen;
+    if (!read_varint(p, end, mlen) ||
+        mlen > static_cast<uint64_t>(end - p))
+      return PEER_TRUNCATED;
+    if (n >= max_items) return PEER_TOO_MANY;
+    const uint8_t* q = p;
+    const uint8_t* const qend = p + mlen;
+    p = qend;
+    const uint8_t* name = q;
+    const uint8_t* key = q;
+    uint64_t nlen = 0, klen = 0;
+    uint64_t f_hits = 0, f_limit = 0, f_dur = 0, f_algo = 0, f_beh = 0;
+    while (q < qend) {
+      const uint8_t tag = *q++;
+      if (tag == 0x0A || tag == 0x12) {  // name = 1 / unique_key = 2
+        uint64_t slen;
+        if (!read_varint(q, qend, slen) ||
+            slen > static_cast<uint64_t>(qend - q))
+          return PEER_TRUNCATED;
+        if (!valid_utf8(q, slen)) return PEER_UTF8;
+        if (tag == 0x0A) {
+          name = q;
+          nlen = slen;
+        } else {
+          key = q;
+          klen = slen;
+        }
+        q += slen;
+      } else if (tag == 0x18 || tag == 0x20 || tag == 0x28 ||
+                 tag == 0x30 || tag == 0x38) {
+        uint64_t v;
+        if (!read_varint(q, qend, v)) return PEER_TRUNCATED;
+        switch (tag) {
+          case 0x18: f_hits = v; break;   // hits = 3
+          case 0x20: f_limit = v; break;  // limit = 4
+          case 0x28: f_dur = v; break;    // duration = 5
+          case 0x30: f_algo = v; break;   // algorithm = 6
+          default: f_beh = v; break;      // behavior = 7
+        }
+      } else if (tag == 0x42) {  // chain = 8
+        return PEER_CHAIN;
+      } else {
+        return PEER_UNKNOWN;
+      }
+    }
+    // the enums' named values: Algorithm 0..3, Behavior 0..2
+    if (f_algo > 3 || f_beh > 2) return PEER_ENUM;
+    scratch.resize(nlen + 1 + klen);
+    if (nlen) std::memcpy(scratch.data(), name, nlen);
+    scratch[nlen] = '_';
+    if (klen) std::memcpy(scratch.data() + nlen + 1, key, klen);
+    key_hash[n] = xxh64(scratch.data(), scratch.size(), seed);
+    hits[n] = static_cast<int64_t>(f_hits);
+    limit[n] = static_cast<int64_t>(f_limit);
+    duration[n] = static_cast<int64_t>(f_dur);
+    algo[n] = static_cast<int32_t>(f_algo);
+    behavior[n] = static_cast<uint8_t>(f_beh);
+    name_off[n] = static_cast<int32_t>(name - buf);
+    name_len[n] = static_cast<int32_t>(nlen);
+    key_off[n] = static_cast<int32_t>(key - buf);
+    key_len[n] = static_cast<int32_t>(klen);
+    ++n;
+  }
+  return n;
+}
+
+// An item of guber_encode_peer_answers is at most this long: the
+// item's tag and one length byte, then four fields of a tag and a
+// 10-byte varint each.
+int64_t guber_peer_answer_max_bytes() { return 2 + 4 * 11; }
+
+// Serialise answer columns as a GetPeerRateLimitsResp (repeated
+// RateLimitResp rate_limits = 1: status = 1, limit = 2, remaining = 3,
+// reset_time = 4; no error, no metadata — what an owner's reply to its
+// peer holds). proto3: a zero field is left out. `out` holds
+// n * guber_peer_answer_max_bytes(); returns the bytes written.
+int64_t guber_encode_peer_answers(const int64_t* status,
+                                  const int64_t* limit,
+                                  const int64_t* remaining,
+                                  const int64_t* reset_time, int64_t n,
+                                  uint8_t* out) {
+  uint8_t* p = out;
+  for (int64_t i = 0; i < n; ++i) {
+    *p++ = 0x0A;
+    uint8_t* const len_at = p++;
+    const int64_t vals[4] = {status[i], limit[i], remaining[i],
+                             reset_time[i]};
+    for (int f = 0; f < 4; ++f) {
+      if (vals[f] == 0) continue;
+      *p++ = static_cast<uint8_t>((f + 1) << 3);
+      p = put_varint(p, static_cast<uint64_t>(vals[f]));
+    }
+    *len_at = static_cast<uint8_t>(p - len_at - 1);  // <= 44
+  }
+  return p - out;
+}
+
+}  // extern "C"

@@ -718,3 +718,78 @@ def unflatten_resp(packed, order, counts, n: int, b_sub: int) -> np.ndarray:
         _ptr(out, ctypes.c_int32),
     )
     return out
+
+
+# -- the PeersV1 door's wire fold (guberhash.cc, last section) ---------------
+
+#: why guber_parse_peer_batch declined a batch, by its return code
+PEER_DECLINE = {
+    -1: "chain",
+    -2: "unknown_field",
+    -3: "bad_enum",
+    -4: "bad_utf8",
+    -5: "truncated",
+    -6: "too_many_items",
+    -7: "stale_library",  # a libguberhash.so built before the fold
+}
+
+try:
+    _vp = ctypes.c_void_p
+    _lib.guber_parse_peer_batch.restype = ctypes.c_int64
+    _lib.guber_parse_peer_batch.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_uint64,
+    ] + [_vp] * 10
+    _lib.guber_encode_peer_answers.restype = ctypes.c_int64
+    _lib.guber_encode_peer_answers.argtypes = [_vp] * 4 + [
+        ctypes.c_int64, _vp,
+    ]
+    _lib.guber_peer_answer_max_bytes.restype = ctypes.c_int64
+    _PEER_ANSWER_MAX = int(_lib.guber_peer_answer_max_bytes())
+    _HAS_PEER_WIRE = True
+except AttributeError:  # parse_peer_batch declines everything
+    _HAS_PEER_WIRE = False
+
+_PEER_COLUMNS = (
+    ("key_hash", np.uint64), ("hits", np.int64), ("limit", np.int64),
+    ("duration", np.int64), ("algo", np.int32), ("behavior", np.uint8),
+    ("name_off", np.int32), ("name_len", np.int32),
+    ("key_off", np.int32), ("key_len", np.int32),
+)
+
+
+def parse_peer_batch(wire: bytes, max_items: int):
+    """(n, columns) of a serialised GetPeerRateLimitsReq: n >= 0 items
+    and a dict of n-long numpy columns (key_hash as slot_hash_batch
+    hashes name + "_" + unique_key, hits, limit, duration, algo,
+    behavior, and the offsets of name and unique_key in `wire`), or
+    (code < 0, None) where the native parser declines (PEER_DECLINE).
+    One call with the GIL released, no object per item."""
+    if not _HAS_PEER_WIRE:
+        return -7, None
+    # an item is at least its tag and a length byte
+    cap = min(max_items, len(wire) // 2) + 1
+    cols = {name: np.empty(cap, dt) for name, dt in _PEER_COLUMNS}
+    n = _lib.guber_parse_peer_batch(
+        wire, len(wire), max_items, _SEED,
+        *[a.ctypes.data for a in cols.values()],
+    )
+    if n < 0:
+        return n, None
+    return n, {name: a[:n] for name, a in cols.items()}
+
+
+def encode_peer_answers(status, limit, remaining, reset_time) -> bytes:
+    """The serialised GetPeerRateLimitsResp of four answer columns
+    (zero fields left out, no error, no metadata), in one call."""
+    cols = [
+        np.ascontiguousarray(c, np.int64)
+        for c in (status, limit, remaining, reset_time)
+    ]
+    n = cols[0].shape[0]
+    if any(c.shape != (n,) for c in cols):
+        raise ValueError("answer columns differ in length")
+    out = np.empty(n * _PEER_ANSWER_MAX, np.uint8)
+    m = _lib.guber_encode_peer_answers(
+        *[c.ctypes.data for c in cols], n, out.ctypes.data,
+    )
+    return out[:m].tobytes()

@@ -382,7 +382,10 @@ class DeviceBatcher:
         backends (is_device_backend).
         `frame=False` keeps a group out of the per-frame stage clock —
         a chunked frame flags only its first chunk, so one frame
-        contributes one batch_queue/device span, not one per chunk.
+        contributes one batch_queue/device span, not one per chunk —
+        and, enqueued first by a gRPC call's handler, makes it that
+        call's group (stages.claim_call): call_queue / call_device /
+        call_wake are its tiles then.
 
         Empty-group contract (pinned by tests/test_prep_pipeline.py):
         a zero-row `key_hash` resolves immediately to four EMPTY
@@ -395,12 +398,18 @@ class DeviceBatcher:
             raise RuntimeError("DeviceBatcher is stopped")
         loop = asyncio.get_running_loop()
         fut = loop.create_future()
+        meta = _QMeta(frame)
         self._queue.put_nowait(
             ("decide_arrays", fields,
              self._kick_prep("prep_group", fields),
-             _QMeta(frame), fut)
+             meta, fut)
         )
-        return await fut
+        res = await fut
+        if meta.t_done:
+            # a gRPC call's array group (the PeersV1 door's folded
+            # batch): its call_wake tile, as in decide()
+            STAGES.add("call_wake", time.monotonic() - meta.t_done)
+        return res
 
     async def decide_chain(
         self, reqs: Sequence[RateLimitReq], frame: bool = False

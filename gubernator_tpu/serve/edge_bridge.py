@@ -156,6 +156,7 @@ from gubernator_tpu.serve import metrics, tracing
 from gubernator_tpu.serve.batcher import is_device_backend
 from gubernator_tpu.serve.config import MAX_BATCH_SIZE
 from gubernator_tpu.serve.faults import FAULTS
+from gubernator_tpu.serve.shedcache import screened_decide
 from gubernator_tpu.serve.stages import STAGES
 
 log = logging.getLogger("gubernator_tpu.edge")
@@ -302,6 +303,10 @@ _FAST_RESP_DTYPE = None
 # shards (edge.cc fill_string_decisions), so empty owner here keeps
 # parity with a locally-served item on the object path.
 _STRING_RESP_DTYPE = None
+
+
+def _stamp_shed(seconds: float) -> None:
+    STAGES.add("shed", seconds)
 
 
 def _string_resp_dtype():
@@ -812,8 +817,9 @@ class FrameService:
 
     async def _decide_arrays_shed(self, fields: dict, n: int):
         """Over-limit shed screen in front of the batcher (r10,
-        serve/shedcache.py): items whose frozen token-bucket refusal
-        is cached host-side are answered HERE and never enqueue; only
+        serve/shedcache.py screened_decide, which the PeersV1 door's
+        fold runs too): items whose frozen token-bucket refusal
+        is cached host-side are answered THERE and never enqueue; only
         the residue rides the device, and its responses stitch back in
         frame order (and repopulate the cache). Screen + stitch time
         is the frame's `shed` stage — a fully-shed frame has no
@@ -821,41 +827,10 @@ class FrameService:
         that part of its e2e, so the r7 frame-coverage contract keeps
         no hole. Shared by the pre-hashed fast path and the string
         fold."""
-        shed = getattr(self.instance, "shed", None)
-        if shed is None:
-            return await self._decide_arrays_chunked(fields, n)
-        t0 = time.monotonic()
-        shed.refresh_generation()
-        screened = shed.screen_fields(fields)
-        if screened is None:
-            STAGES.add("shed", time.monotonic() - t0)
-            res = await self._decide_arrays_chunked(fields, n)
-            # population is shed work too: without the stage add, a
-            # cold-cache frame's observe walk would sit between the
-            # device and encode spans as a coverage hole
-            t1 = time.monotonic()
-            shed.observe_fields(fields, res)
-            STAGES.add("shed", time.monotonic() - t1)
-            return res
-        mask, (status, limit, remaining, reset) = screened
-        keep = ~mask
-        n_res = int(keep.sum())
-        if n_res == 0:
-            STAGES.add("shed", time.monotonic() - t0)
-            return status, limit, remaining, reset
-        residue = {k: v[keep] for k, v in fields.items()}
-        STAGES.add("shed", time.monotonic() - t0)
-        rs, rl, rr, rt = await self._decide_arrays_chunked(
-            residue, n_res
+        return await screened_decide(
+            getattr(self.instance, "shed", None), fields, n,
+            self._decide_arrays_chunked, _stamp_shed,
         )
-        t1 = time.monotonic()
-        shed.observe_fields(residue, (rs, rl, rr, rt))
-        status[keep] = rs
-        limit[keep] = rl
-        remaining[keep] = rr
-        reset[keep] = rt
-        STAGES.add("shed", time.monotonic() - t1)
-        return status, limit, remaining, reset
 
     async def _decide_fast(self, payload: bytes, n: int):
         """Decode one pre-hashed payload and run it through the batcher.

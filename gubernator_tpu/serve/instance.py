@@ -15,7 +15,11 @@ execution model:
   Responses reassemble in request order (gubernator.go:75-169).
 - GetPeerRateLimits serves owner-side batches for other peers
   (gubernator.go:210-227): whatever arrives is applied, no ownership
-  check. On the stage clock a peer call's tiles are grpc_decode,
+  check. A batch arrives as columns parsed straight off the wire
+  (fold_peer_batch: no request or response object per item, the GEB
+  door's array decide) or, where the fold declines it — a chain in it,
+  replication or rescale on, a host backend, odd wire — as request
+  objects. On the stage clock a peer call's tiles are grpc_decode,
   peer_serve (this module: the call less its waits for the batcher),
   call_queue, call_device, call_wake, grpc_encode — no instance_route,
   which is get_rate_limits' alone.
@@ -32,6 +36,7 @@ import logging
 import time
 from typing import List, Optional, Sequence, Tuple
 
+from gubernator_tpu.api.columns import PeerAnswers, PeerBatch
 from gubernator_tpu.api.types import (
     Behavior,
     HealthCheckResp,
@@ -42,12 +47,13 @@ from gubernator_tpu.api.types import (
 from gubernator_tpu.core.hashing import slot_hash_batch
 from gubernator_tpu.core.sketches import TrafficStats
 from gubernator_tpu.serve import metrics, tracing
-from gubernator_tpu.serve.batcher import DeviceBatcher
+from gubernator_tpu.serve.batcher import DeviceBatcher, is_device_backend
 from gubernator_tpu.serve.breaker import OPEN as BREAKER_OPEN
 from gubernator_tpu.serve.config import MAX_BATCH_SIZE, ServerConfig
 from gubernator_tpu.serve.faults import FAULTS
 from gubernator_tpu.serve.global_mgr import GlobalManager
 from gubernator_tpu.serve.peers import ConsistentHashPicker, PeerClient
+from gubernator_tpu.serve.shedcache import screened_decide
 from gubernator_tpu.serve.stages import STAGES
 
 log = logging.getLogger("gubernator_tpu.instance")
@@ -58,6 +64,11 @@ UNHEALTHY = "unhealthy"
 
 class BatchTooLargeError(ValueError):
     pass
+
+
+def _no_stamp(seconds: float) -> None:
+    """screened_decide's stamp for a folded peer batch: its screen and
+    stitch are inside the call's one `peer_serve` sample already."""
 
 
 def chain_error(r: RateLimitReq, conf: ServerConfig) -> str:
@@ -123,12 +134,15 @@ class Instance:
         else:
             self.shed = None
         # the owner side of the ring (get_peer_rate_limits): forwarded
-        # batches served, their items, and the items the shed screen
-        # answered without a device trip. Plain ints, exported at
-        # scrape (peer_serve_*_total) like the shed cache's
+        # batches served, their items, the items the shed screen
+        # answered without a device trip, and the items served as
+        # columns (the fold; the rest went through request objects).
+        # Plain ints, exported at scrape (peer_serve_*_total) like the
+        # shed cache's
         self.peer_serve_batches = 0
         self.peer_serve_items = 0
         self.peer_serve_shed_hits = 0
+        self.peer_serve_folded_items = 0
         # bucket replication (r11, serve/replication.py): owned windows
         # snapshot to each key's ring successor so a killed owner's
         # quota state survives takeover. OFF by default
@@ -759,9 +773,33 @@ class Instance:
 
     # -- peer-facing API ----------------------------------------------------
 
+    def fold_peer_batch(self, wire: bytes) -> Optional[PeerBatch]:
+        """A serialised GetPeerRateLimitsReq as the columns
+        get_peer_rate_limits serves with no object per item, or None:
+        the PeersV1 door then parses `wire` with the protobuf runtime
+        and passes request objects, as before the fold. Declined from
+        what this instance can see in itself — replication or rescale
+        on (their hooks look ownership up item by item:
+        _peer_serve_replication), a backend the batcher cannot hand
+        arrays — and by the parser for whatever in the message the
+        object path would treat differently (api/columns.py
+        PeerBatch.from_wire). No switch and no size threshold: a batch
+        of one item folds like one of a thousand."""
+        if (
+            self.repl is not None
+            or self.rescale is not None
+            or not is_device_backend(self.backend)
+        ):
+            return None
+        return PeerBatch.from_wire(wire, MAX_BATCH_SIZE)
+
     async def get_peer_rate_limits(
-        self, reqs: Sequence[RateLimitReq]
-    ) -> List[RateLimitResp]:
+        self, reqs: "Sequence[RateLimitReq] | PeerBatch"
+    ) -> "List[RateLimitResp] | PeerAnswers":
+        """Serve one forwarded batch as its owner: request objects in,
+        response objects out, or (fold_peer_batch) a PeerBatch in and
+        its PeerAnswers out — columns whose rows iterate like
+        responses."""
         if len(reqs) > MAX_BATCH_SIZE:
             raise BatchTooLargeError(
                 f"'PeerRequest.rate_limits' list too large; max size is "
@@ -776,9 +814,48 @@ class Instance:
         self.peer_serve_batches += 1
         self.peer_serve_items += len(reqs)
         try:
+            if isinstance(reqs, PeerBatch):
+                self.peer_serve_folded_items += len(reqs)
+                return await self._peer_serve_folded(reqs, waited)
             return await self._peer_serve(reqs, waited)
         finally:
             STAGES.add("peer_serve", time.monotonic() - t0 - waited[0])
+
+    async def _peer_serve_folded(
+        self, batch: PeerBatch, waited: List[float]
+    ) -> PeerAnswers:
+        """_peer_serve_plain on columns: the shed screen over the whole
+        batch, the residue through batcher.decide_arrays as this call's
+        group, the cache's population and the stitch in place — the
+        GEB door's array decide (shedcache.screened_decide), with the
+        screen's and the stitch's seconds left to `peer_serve`. Every
+        GLOBAL item, shed-answered or decided, queues its key's status
+        broadcast first, as decide_local and the screen loop do item by
+        item."""
+        n = len(batch)
+        try:
+            if FAULTS.enabled:
+                await FAULTS.inject("peer_serve")
+            fields = batch.fields
+            keys, glob = batch.global_items()
+            if glob:
+                self.global_mgr.queue_update_fields(keys, glob, fields)
+            decided = 0
+
+            def decide(rows: dict, n_rows: int):
+                nonlocal decided
+                decided = n_rows
+                return self._batcher_wait(
+                    self.batcher.decide_arrays(rows, frame=False), waited
+                )
+
+            answers = await screened_decide(
+                self.shed, fields, n, decide, _no_stamp
+            )
+            self.peer_serve_shed_hits += n - decided
+            return PeerAnswers(*answers)
+        except Exception as e:
+            return PeerAnswers.failed(n, str(e))
 
     @staticmethod
     async def _batcher_wait(decide, waited: List[float]):

@@ -239,6 +239,14 @@ PEER_SERVE_SHED_HITS = Gauge(
     "traffic that never reaches the batcher",
     registry=REGISTRY,
 )
+PEER_SERVE_FOLDED_ITEMS = Gauge(
+    "peer_serve_folded_items_total",
+    "Forwarded items the PeersV1 door served as arrays (the wire fold: "
+    "no request or response object per item); / peer_serve_items_total "
+    "= the fold's share of engagement, below 1 where chains, "
+    "replication, rescale or odd wire sent batches down the object path",
+    registry=REGISTRY,
+)
 FAULTS_INJECTED = Counter(
     "faults_injected_total",
     "Injected faults fired (serve/faults.py, GUBER_FAULT_SPEC) — a "

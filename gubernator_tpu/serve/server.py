@@ -20,6 +20,7 @@ from typing import Optional
 
 import grpc
 from aiohttp import web
+from google.protobuf.message import DecodeError
 
 from gubernator_tpu.api import convert
 from gubernator_tpu.api.grpc_glue import add_peers_servicer, add_v1_servicer
@@ -257,16 +258,20 @@ def _md_traceparent(context) -> "Optional[str]":
 
 
 async def _serve_call(instance, door, context, pb_reqs, decide, reply):
-    """One rate-limit call through a gRPC door, tiled on the stage
-    clock (serve/stages.py CALL_TILES): grpc_decode and grpc_encode
+    """One rate-limit call through a gRPC door as request OBJECTS,
+    tiled on the stage clock (serve/stages.py CALL_TILES): every
+    V1/GetRateLimits call — its items route, validate, forward and
+    carry metadata one by one — and the PeersV1/GetPeerRateLimits
+    batches the wire fold declines (_serve_folded serves the rest as
+    arrays, with the same tiles). grpc_decode and grpc_encode
     here, instance_route (GetRateLimits) or peer_serve
     (GetPeerRateLimits) in the instance, call_queue / call_device /
     call_wake in the batcher for the group this handler enqueues
     first (mark_call), call_e2e around them all. Decode and encode
     run inside the trace scope, so a sampled call's trace holds them
     too. Bare stamps, not STAGES.span: the serving loop
-    pays for every microsecond a call (PERF.md, PR 24), and the two
-    spans are ~20 us long."""
+    pays for every microsecond a call (PERF.md, PR 24), and on a
+    two-item call the two spans are ~20 us long."""
     t0 = time.monotonic()
     tracer = instance.tracer
     trace = tracer.join(
@@ -318,15 +323,67 @@ class V1Servicer:
         )
 
 
+async def _serve_folded(instance, context, wire: bytes):
+    """One GetPeerRateLimits call served as arrays, or None where the
+    fold declines the batch (Instance.fold_peer_batch) and the caller
+    serves it through _serve_call: wire bytes -> columns (one native
+    parse: `grpc_decode`, which here covers the bytes -> fields work
+    the runtime's FromString does outside that span on the object
+    path) -> Instance.get_peer_rate_limits on the columns
+    (`peer_serve`, and the batcher's three call tiles for the residue's
+    array group) -> wire bytes (one native encode: `grpc_encode`).
+    No request, response or protobuf item object is made. The same
+    tiles, trace scope and call mark as _serve_call; a declined batch
+    has recorded nothing."""
+    t0 = time.monotonic()
+    batch = instance.fold_peer_batch(wire)
+    if batch is None:
+        return None
+    tracer = instance.tracer
+    trace = tracer.join(
+        "peers", tracing.parse_traceparent(_md_traceparent(context))
+    )
+    mark = mark_call()
+    try:
+        with tracing.scope(tracer, trace) as tr:
+            t1 = time.monotonic()
+            STAGES.add("grpc_decode", t1 - t0)
+            if tr is not None:
+                tr.annotate(items=len(batch))
+            answers = await instance.get_peer_rate_limits(batch)
+            t2 = time.monotonic()
+            out = answers.to_wire()
+            t3 = time.monotonic()
+            STAGES.add("grpc_encode", t3 - t2)
+    finally:
+        unmark_call(mark)
+    STAGES.add("call_e2e", t3 - t0)
+    return out
+
+
 class PeersV1Servicer:
     def __init__(self, instance: Instance):
         self.instance = instance
 
-    async def GetPeerRateLimits(self, request, context):
+    async def GetPeerRateLimits(self, wire: bytes, context):
         # owner-serve hop of a distributed trace (r16): a forwarding
         # peer's sampled context arrives as gRPC metadata; the owner
         # records its own queue/device spans under the SAME trace id
-        # in its own flight recorder
+        # in its own flight recorder.
+        # `wire` is the serialised GetPeerRateLimitsReq
+        # (api/grpc_glue.py registers the method pass-through): the
+        # fold serves it as arrays where it can, and what it declines
+        # is parsed here and served through request objects
+        out = await _serve_folded(self.instance, context, wire)
+        if out is not None:
+            return out
+        try:
+            request = peers_pb2.GetPeerRateLimitsReq.FromString(wire)
+        except DecodeError:
+            # what grpc answers when its own deserializer raises
+            await context.abort(
+                grpc.StatusCode.INTERNAL, "Exception deserializing request!"
+            )
         return await _serve_call(
             self.instance, "peers", context, request.requests,
             self.instance.get_peer_rate_limits, _peers_reply,
@@ -1095,6 +1152,9 @@ class Server:
         metrics.PEER_SERVE_BATCHES.set(self.instance.peer_serve_batches)
         metrics.PEER_SERVE_ITEMS.set(self.instance.peer_serve_items)
         metrics.PEER_SERVE_SHED_HITS.set(self.instance.peer_serve_shed_hits)
+        metrics.PEER_SERVE_FOLDED_ITEMS.set(
+            self.instance.peer_serve_folded_items
+        )
         if self.instance.repl is not None:
             metrics.REPLICATION_STANDBY_ENTRIES.set(
                 self.instance.repl.standby_len

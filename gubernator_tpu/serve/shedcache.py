@@ -78,6 +78,7 @@ cross-thread reader is the /metrics scrape, which reads plain ints.
 
 from __future__ import annotations
 
+import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -620,3 +621,51 @@ class ShedCache:
                 int(sa[i]), int(limit_a[i]), int(ra[i]),
                 int(reset_a[i]), now,
             )
+
+
+async def screened_decide(
+    shed: Optional[ShedCache], fields: Dict, n: int, decide, stamp
+):
+    """One array group through the screen, the batcher and the cache's
+    population — the array doors' shared decide (the GEB door's frames,
+    serve/edge_bridge.py _decide_arrays_shed; the PeersV1 door's folded
+    batches, serve/instance.py): rows whose frozen refusal is cached
+    are answered here and never enqueue, `await decide(rows, count)`
+    resolves the residue to its (status, limit, remaining, reset_time)
+    arrays, which populate the cache and stitch back in the group's
+    order. `stamp(seconds)` is handed the screen's and the stitch's
+    wall time, one call for each side of the await (a GEB frame's two
+    `shed` samples); with no cache (`shed` None) the group goes to
+    `decide` whole. Returns the four arrays for all n rows."""
+    if shed is None:
+        return await decide(fields, n)
+    t0 = time.monotonic()
+    shed.refresh_generation()
+    screened = shed.screen_fields(fields)
+    if screened is None:
+        stamp(time.monotonic() - t0)
+        res = await decide(fields, n)
+        # population is shed work too: without the stamp, a
+        # cold-cache frame's observe walk would sit between the
+        # device and encode spans as a coverage hole
+        t1 = time.monotonic()
+        shed.observe_fields(fields, res)
+        stamp(time.monotonic() - t1)
+        return res
+    mask, (status, limit, remaining, reset) = screened
+    keep = ~mask
+    n_res = int(keep.sum())
+    if n_res == 0:
+        stamp(time.monotonic() - t0)
+        return status, limit, remaining, reset
+    residue = {k: v[keep] for k, v in fields.items()}
+    stamp(time.monotonic() - t0)
+    rs, rl, rr, rt = await decide(residue, n_res)
+    t1 = time.monotonic()
+    shed.observe_fields(residue, (rs, rl, rr, rt))
+    status[keep] = rs
+    limit[keep] = rl
+    remaining[keep] = rr
+    reset[keep] = rt
+    stamp(time.monotonic() - t1)
+    return status, limit, remaining, reset

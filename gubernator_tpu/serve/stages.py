@@ -98,8 +98,13 @@ Stages form two families:
   whole by the shed cache, or by an inline host backend's fast
   path, has no batcher tiles at all.
 
-    grpc_decode      handler entry -> RateLimitReq list built (joining
-                     the trace, then pb -> requests)
+    grpc_decode      handler entry -> the call's items in the form the
+                     instance serves: joining the trace, then pb ->
+                     RateLimitReq list (the runtime's bytes -> pb ran
+                     before the handler), or on a GetPeerRateLimits
+                     batch the wire fold serves (serve/server.py
+                     _serve_folded) the one native parse, wire bytes ->
+                     columns, all of it inside the span
     instance_route   instance-side validation/routing/assembly,
                      recorded once per Instance.get_rate_limits call
                      from ANY door that reaches the instance (the
@@ -110,16 +115,24 @@ Stages form two families:
     peer_serve       the owner side of a forwarded batch, once per
                      Instance.get_peer_rate_limits call: everything
                      it does on the loop that is not waiting for the
-                     batcher — key hashing, the shed screen item by
-                     item, the residue's lists, the cache's
-                     population from the answers, the stitch
+                     batcher. On a folded batch (columns): GLOBAL
+                     items' broadcast queueing, the shed screen over
+                     the whole batch, the residue's row selection, the
+                     cache's population from the answers, the stitch
+                     in place (shedcache.screened_decide; the keys
+                     were hashed by the parse, inside grpc_decode). On
+                     the object path: key hashing, the shed screen
+                     item by item, the residue's lists, the
+                     population, the stitch
     call_queue       batcher enqueue -> flusher collect, for the
                      call's group (the twin of batch_queue)
     call_device      flusher collect -> the group's future resolved
                      (the twin of device)
     call_wake        future resolved -> the awaiting coroutine runs
                      again: event-loop and GIL wait
-    grpc_encode      RateLimitResp list -> pb in the servicer
+    grpc_encode      RateLimitResp list -> pb in the servicer, or a
+                     folded batch's answer columns -> wire bytes (one
+                     native call)
     call_e2e         handler entry -> return: the denominator
 
 - **process stages** (`PER_PROCESS`): what stalls every call at once,

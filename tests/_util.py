@@ -145,3 +145,39 @@ def spawn_daemon_edge(
     edge.kill()
     daemon.kill()
     pytest.fail("edge never started listening")
+
+
+def native_lib_for_tests(tmp_dir):
+    """gubernator_tpu.native.hashlib_native over a libguberhash.so
+    that has the PeersV1 wire fold: the checkout's own where it is
+    built and current, else one compiled from guberhash.cc into
+    `tmp_dir` and loaded from there under a private module name — a
+    test never drops a .so into the checkout other tests run from
+    (tests/test_chip_smoke.py does the same with a copy)."""
+    import importlib.util
+    import shutil
+    import subprocess
+
+    native = (
+        pathlib.Path(__file__).resolve().parent.parent
+        / "gubernator_tpu" / "native"
+    )
+    try:
+        from gubernator_tpu.native import hashlib_native
+
+        if getattr(hashlib_native, "_HAS_PEER_WIRE", False):
+            return hashlib_native
+    except ImportError:
+        pass
+    tmp_dir = pathlib.Path(tmp_dir)
+    for name in ("guberhash.cc", "Makefile", "hashlib_native.py"):
+        shutil.copy(native / name, tmp_dir)
+    subprocess.run(
+        ["make", "-C", str(tmp_dir)], check=True, capture_output=True
+    )
+    spec = importlib.util.spec_from_file_location(
+        "_hashlib_native_for_tests", tmp_dir / "hashlib_native.py"
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
