@@ -664,21 +664,22 @@ class DeviceBatcher:
             ]
 
             def chain_call():
-                # submit_host on the submit thread, like the decide
-                # lanes (the r16 frame-coverage audit: the chain lane
-                # must record PER_BATCH stages too). The chain call
-                # both submits and waits, so its whole body is the
-                # submit-thread span.
-                with STAGES.span("submit_host"):
-                    return self.backend.decide_chain(all_chain)
+                # the chain lane records the batch tiles like the
+                # decide lanes (the r16 frame-coverage audit: it must
+                # record PER_BATCH stages too). The chain call both
+                # submits and waits, so its whole body is submit_call
+                # and its batch has no fetch tile.
+                with STAGES.span("submit_call") as sp:
+                    return self.backend.decide_chain(all_chain), sp
 
             t0c = time.monotonic()
+            sp = None  # an inline (host) backend's batch has no tiles
             try:
                 if inline:
                     resps = self.backend.decide_chain(all_chain)
                 else:
                     loop = asyncio.get_running_loop()
-                    resps = await loop.run_in_executor(
+                    resps, sp = await loop.run_in_executor(
                         self._submit_pool, chain_call
                     )
             except Exception as e:
@@ -686,6 +687,8 @@ class DeviceBatcher:
                     if not fut.done():
                         fut.set_exception(e)
             else:
+                if sp is not None:
+                    self._stage_submit(t_collect, t0c, sp)
                 k = 0
                 for _, reqs_c, _m, fut in chain_items:
                     span = resps[k : k + len(reqs_c)]
@@ -697,7 +700,9 @@ class DeviceBatcher:
                 # without it, a GEBC frame added e2e with no device
                 # span and coverage silently diluted under chained
                 # traffic
-                self._stage_device(chain_items, t_collect)
+                resolved = self._stage_device(chain_items, t_collect)
+                if sp is not None:
+                    STAGES.add("batch_e2e", resolved - t_collect)
                 rows = sum(
                     1 + len(getattr(r, "chain", ()) or ())
                     for r in all_chain
@@ -758,22 +763,27 @@ class DeviceBatcher:
         ]
 
         def submit_call():
-            runs = []
-            with STAGES.span("prep"):
-                for it in decide_items:
-                    # queue tuples carry their arrival-prep future at
-                    # slot 3 (object group) or 2 (array group)
-                    p = it[3] if it[0] == "decide" else it[2]
-                    if p is not None:
-                        runs.append(_prep_result(p))
-                    elif it[0] == "decide":
-                        runs.append(self.backend.prep_reqs(it[1], it[2]))
-                    else:
-                        runs.append(self.backend.prep_group(it[1]))
-            with STAGES.span("merge"):
-                merged = self.backend.merge_prepped(runs)
-            with STAGES.span("dispatch"):
-                return self.backend.decide_submit_merged(merged)
+            # the span's own two stamps go back with the handle: the
+            # loop tiles the hand-off's legs around them (_stage_submit)
+            with STAGES.span("submit_call") as sp:
+                runs = []
+                with STAGES.span("prep"):
+                    for it in decide_items:
+                        # queue tuples carry their arrival-prep future
+                        # at slot 3 (object group) or 2 (array group)
+                        p = it[3] if it[0] == "decide" else it[2]
+                        if p is not None:
+                            runs.append(_prep_result(p))
+                        elif it[0] == "decide":
+                            runs.append(
+                                self.backend.prep_reqs(it[1], it[2])
+                            )
+                        else:
+                            runs.append(self.backend.prep_group(it[1]))
+                with STAGES.span("merge"):
+                    merged = self.backend.merge_prepped(runs)
+                with STAGES.span("dispatch"):
+                    return self.backend.decide_submit_merged(merged), sp
 
         # admission bounds outstanding batches at fetch_depth; a cancel
         # while waiting for a slot reaches _run's handler with nothing
@@ -791,7 +801,7 @@ class DeviceBatcher:
             loop.run_in_executor(self._submit_pool, submit_call)
         )
         try:
-            handle = await asyncio.shield(submit_fut)
+            handle, sp = await asyncio.shield(submit_fut)
         except asyncio.CancelledError:
             # consume the shielded submit's outcome so an exception is
             # not logged as unretrieved at GC; a returned handle is
@@ -807,11 +817,11 @@ class DeviceBatcher:
             self._inflight.release()
             self._fail(decide_items, e)
             return
-        submit_s = time.monotonic() - t0
-        STAGES.add("submit_host", submit_s)
+        t_submitted = self._stage_submit(t_collect, t0, sp)
         task = asyncio.ensure_future(
             self._finish_arrays(
-                handle, decide_items, lens, submit_s, t_collect
+                handle, decide_items, lens, t_submitted - t0, t_collect,
+                t_submitted,
             )
         )
         # hold the reference until done (stop() drains the set); discard
@@ -825,20 +835,27 @@ class DeviceBatcher:
         self._live_batch.clear()
 
     async def _finish_arrays(
-        self, handle, decide_items, lens, submit_s, t_collect
+        self, handle, decide_items, lens, submit_s, t_collect, t_submitted
     ):
         t1 = time.monotonic()
         loop = asyncio.get_running_loop()
         try:
-            status, limit, remaining, reset = await loop.run_in_executor(
-                self._fetch_pool, self._fetch,
-                self.backend.decide_wait_arrays, handle,
+            (status, limit, remaining, reset), sp = (
+                await loop.run_in_executor(
+                    self._fetch_pool, self._fetch,
+                    self.backend.decide_wait_arrays, handle,
+                )
             )
         except Exception as e:
             self._fail(decide_items, e)
             return
         finally:
             self._inflight.release()
+        # the fetch's two legs, around fetch_wait's own stamps: the hop
+        # to guber-fetch (from the end of submit_host, the ensure_future
+        # of this task included) and the answer's way back to the loop
+        STAGES.add("fetch_wake", sp.t0 - t_submitted)
+        STAGES.add("fetch_return", time.monotonic() - sp.t1)
         k = 0
         for it, n in zip(decide_items, lens):
             span = (
@@ -855,7 +872,8 @@ class DeviceBatcher:
                 fut.set_result(self.backend.resps_from_arrays(*span))
             else:
                 fut.set_result(span)
-        self._stage_device(decide_items, t_collect)
+        resolved = self._stage_device(decide_items, t_collect)
+        STAGES.add("batch_e2e", resolved - t_collect)
         self._trace_device(
             decide_items, t_collect, k,
             extra=dict(
@@ -888,18 +906,35 @@ class DeviceBatcher:
 
     @staticmethod
     def _fetch(wait, handle):
-        """decide_wait* on the fetch pool, as the fetch_wait stage."""
-        with STAGES.span("fetch_wait"):
-            return wait(handle)
+        """decide_wait* on the fetch pool, as the fetch_wait stage; the
+        span goes back with the answer, for its two stamps."""
+        with STAGES.span("fetch_wait") as sp:
+            return wait(handle), sp
 
     @staticmethod
-    def _stage_device(items, t_collect: float) -> None:
+    def _stage_submit(t_collect: float, t0: float, sp) -> float:
+        """One device batch's tiles up to the loop running again after
+        its submit: admit_wait (collect -> t0, the pipeline slot
+        taken), then the hand-off's two legs around the submit
+        thread's own span `sp` — submit_wake (t0 -> its first line)
+        and submit_return (its last line -> now) — and submit_host,
+        their sum with submit_call by construction. Returns now."""
+        now = time.monotonic()
+        STAGES.add("admit_wait", t0 - t_collect)
+        STAGES.add("submit_wake", sp.t0 - t0)
+        STAGES.add("submit_return", now - sp.t1)
+        STAGES.add("submit_host", now - t0)
+        return now
+
+    @staticmethod
+    def _stage_device(items, t_collect: float) -> float:
         """The span from the flusher's collect to this batch's futures
         resolved (submit + device execute + fetch + any wait behind
         earlier pipelined batches), once per caller group: `device`
         for frame-flagged groups, `call_device` for a gRPC call's
         group, whose call_wake tile starts at the same stamp. Call
-        right after the futures are set, before the flusher yields."""
+        right after the futures are set, before the flusher yields.
+        Returns that stamp: a device batch's batch_e2e ends there."""
         now = time.monotonic()
         span = now - t_collect
         frames = calls = 0
@@ -914,6 +949,7 @@ class DeviceBatcher:
             STAGES.add("device", span * frames, frames)
         if calls:
             STAGES.add("call_device", span * calls, calls)
+        return now
 
     def _observe_batch(self, n: int, launch_s: float) -> None:
         """One device batch of n rows, launched at its padding rung:

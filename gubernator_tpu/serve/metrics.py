@@ -184,6 +184,24 @@ STAGE_SAMPLES = Gauge(
     ["stage"],
     registry=REGISTRY,
 )
+THREAD_CPU_SECONDS = Gauge(
+    "thread_cpu_seconds_total",
+    "Seconds the serving threads were ON A CORE, summed by role, from "
+    "the kernel's per-thread CPU clocks read at scrape and nowhere "
+    "else (serve/stages.py ThreadClocks): loop | submit | fetch | prep "
+    "| other (the process's CPU clock less the four: PJRT, gRPC core, "
+    "native pools). What a thread RAN, beside the stage spans, which "
+    "say what it was TAKEN. Absent where the host gives no thread "
+    "clock",
+    ["thread"],
+    registry=REGISTRY,
+)
+THREAD_WALL_SECONDS = Gauge(
+    "thread_wall_seconds_total",
+    "The monotonic clock at the instant thread_cpu_seconds_total was "
+    "read: difference both over two scrapes for a share of one core",
+    registry=REGISTRY,
+)
 SHED_HITS = Gauge(
     "shed_hits_total",
     "Requests answered from the host over-limit shed cache instead of "

@@ -34,8 +34,10 @@ from gubernator_tpu.serve.backends import (
 from gubernator_tpu.serve.config import ServerConfig
 from gubernator_tpu.serve.instance import BatchTooLargeError, Instance
 from gubernator_tpu.serve.stages import (
+    BATCH_TILES,
     STAGES,
     ProcessProbes,
+    ThreadClocks,
     mark_call,
     unmark_call,
 )
@@ -488,6 +490,8 @@ class Server:
         self.grpc_server: Optional[grpc.aio.Server] = None
         self._http_runner: Optional[web.AppRunner] = None
         self._pool = None
+        # read by the scrape and snapshot handlers only
+        self.thread_clocks = ThreadClocks()
 
     def device_report(self) -> dict:
         """What this daemon serves from, for the boot log and
@@ -1165,6 +1169,13 @@ class Server:
         for name, s in snap["stages"].items():
             metrics.STAGE_SECONDS.labels(stage=name).set(s["total_s"])
             metrics.STAGE_SAMPLES.labels(stage=name).set(s["count"])
+        # what the serving threads ran, beside what the spans say they
+        # were taken: the threads' CPU clocks, read here and in
+        # /v1/debug/stages only (this handler runs on the serving loop)
+        threads = self.thread_clocks.snapshot()
+        metrics.THREAD_WALL_SECONDS.set(threads["wall_s"])
+        for role, cpu_s in threads["cpu_s"].items():
+            metrics.THREAD_CPU_SECONDS.labels(thread=role).set(cpu_s)
         # queue-visibility gauges (r16): standing occupancy the stage
         # clock can't express, set lazily at scrape like shed_entries
         qs = self.instance.batcher.queue_stats()
@@ -1242,6 +1253,10 @@ class Server:
         # the time went, this says how much work never became a stage
         if shed is not None:
             body["shed_cache"] = shed.stats()
+        # the serving threads' CPU clocks at this instant, cumulative
+        # since the process began (a reset does not touch them: a
+        # reader differences two snapshots' `cpu_s` and `wall_s`)
+        body["threads"] = self.thread_clocks.snapshot()
         # what served those stages: devices as JAX reports them, with
         # per-device bytes, and the host-prep / hasher implementations
         body.update(self.device_report())
@@ -1441,6 +1456,16 @@ async def run_daemon(conf: ServerConfig) -> None:
     server = Server(conf)
     await server.start()
     settle_collector()
+    clocks = server.thread_clocks
+    step_s = clocks.measure_granularity()
+    log.info(
+        "tracing: stage clock on (%d batch tiles from collect to "
+        "resolve, /v1/debug/stages batch_coverage); thread CPU clock %s"
+        "%s, read at scrape only (thread_cpu_seconds_total)",
+        len(BATCH_TILES), clocks.source,
+        f", observed granularity {step_s * 1e3:.4g} ms"
+        if step_s is not None else "",
+    )
     log.info("Ready")
     # the stage clock starts at Ready: warm-up ran every rung through
     # the engine's dispatch, and its jit_call spans are compiles

@@ -5,11 +5,12 @@ device trace sees inside a batch, Prometheus sees per-RPC totals, but
 nothing said WHERE a served decision's wall time went between the edge
 socket and the response write. This module is that decomposition: a
 process-global accumulator of per-stage monotonic spans, recorded at
-six fixed points of the serving path and exposed as
-`/v1/debug/stages` (serve/server.py) plus the
-`scripts/profile_serving_stages.py` artifact.
+fixed points of the serving path (the tuples below name every one) and
+exposed as `/v1/debug/stages` and, lazily at scrape, `/metrics`
+(serve/server.py); `scripts/trace_study.py` reads one window of it on
+the chip.
 
-Stages form two families:
+Stages form five families:
 
 - **per-frame stages** (`PER_FRAME`): spans that tile one edge frame's
   end-to-end wall time, so their totals are directly comparable to the
@@ -38,18 +39,71 @@ Stages form two families:
                      behind earlier pipelined batches)
     encode           responses resolved -> response frame written
 
-- **per-batch stages** (`PER_BATCH`): the batcher's submit/wait split,
-  recorded once per device batch. They do NOT tile frame e2e (one
-  batch serves many frames) but attribute the `device` span's
-  interior: host submit (presort + dispatch) vs device fetch wait.
-  The r9 host-prep pipeline splits submit_host's interior further:
-  prep + merge + dispatch tile the submit_call body (submit_host
-  additionally includes the submit-executor queue wait, so it can
-  exceed their sum). None of these enter per-frame coverage — the
-  r7 contract (frame-flagged groups only) is untouched.
+- **per-batch stages** (`PER_BATCH`): recorded once per device batch,
+  never per call, frame or item. Seven of them, `BATCH_TILES`, tile
+  one batch's life from the flusher's collect to its futures resolved
+  the way PER_FRAME tiles a frame — each stamp is taken once and ends
+  one tile and begins the next, across the three threads a batch
+  visits (the serving loop, `guber-submit`, `guber-fetch`; the stamps
+  travel in the closure and in the fetch's return value):
 
-    submit_host      the submit thread's call (batcher._flush_merged:
-                     admission -> handle, incl. executor queueing)
+    batch_e2e = admit_wait + submit_wake + submit_call + submit_return
+              + fetch_wake + fetch_wait + fetch_return + resolve
+
+  `batch_coverage` = sum(tile seconds) / `batch_e2e` seconds; the gap
+  is the resolve (slicing the answers back per caller group and
+  setting the futures, on the loop). They do NOT tile frame e2e (one
+  batch serves many frames): they are the interior of `device` and of
+  `call_device`. `submit_host` is kept as the sum it always was:
+  submit_host = submit_wake + submit_call + submit_return, by
+  construction (the same three stamps), so what used to be read as
+  "submit_host less prep, merge and dispatch" is now two measured
+  legs.
+
+    admit_wait       flusher collect -> a pipeline slot taken
+                     (batcher._flush_merged: `await _inflight.acquire()`
+                     returned): the wait for one of fetch_depth slots,
+                     plus whatever the flush did before it (a GLOBAL
+                     install, a chain call of the same flush window)
+    submit_wake      slot taken -> submit_call's first line on
+                     `guber-submit`: the OUTBOUND leg of the hand-off —
+                     the executor's queue (a run_serialized read or an
+                     earlier batch still on the thread), the thread's
+                     wake-up, and taking the GIL from a loop that keeps
+                     running frames
+    submit_call      submit_call's first line -> its last, on the
+                     submit thread's own clock (a `span`: one bar on
+                     the profiler's clock during a capture) = prep +
+                     merge + dispatch. What the thread was TAKEN, GIL
+                     waits inside its numpy and native calls included;
+                     what it RAN is thread_cpu_seconds_total{submit}
+    submit_return    submit_call's last line -> _flush_merged runs
+                     again after its await: the RETURN leg —
+                     call_soon_threadsafe, the loop's wake-up, the
+                     ready callbacks queued ahead of it, the GIL
+    submit_host      slot taken -> _flush_merged runs again: the three
+                     above, summed by construction
+    fetch_wake       end of submit_host -> _fetch's first line on
+                     `guber-fetch`: the ensure_future hop to
+                     _finish_arrays, the executor's queue, the thread's
+                     wake-up, the GIL
+    fetch_wait       decide_wait* span on the fetch pool
+    fetch_return     fetch_wait's end -> _finish_arrays runs again on
+                     the loop: the answer's way back; it is inside
+                     every call's call_device and every frame's device
+    batch_e2e        flusher collect -> the batch's futures set: the
+                     denominator, one sample a device batch
+
+  The chain lane (r15) submits AND waits inside one call on the submit
+  thread, so its batch records admit_wait, the three submit legs,
+  submit_host and batch_e2e and no fetch tile (its coverage is whole
+  without them). `run_serialized` reads (bucket replication) and a
+  host backend's blocking decide are no device batches and record
+  none of this; a run_serialized read that holds the submit thread
+  shows as the next batch's submit_wake.
+
+  The submit thread's interior, inside submit_call:
+
     prep             waiting out arrival preps that hadn't finished
                      (~0 when the prep pool keeps up), plus the
                      conversion/presort of a group that carries no
@@ -74,7 +128,6 @@ Stages form two families:
                      inside merge on the arrival-prep path, inside
                      dispatch on the flush-time path (where a native
                      prep fuses the presort into it)
-    fetch_wait       decide_wait* span on the fetch pool
 
 - **per-flush stages** (`PER_FLUSH`): the GLOBAL gossip loops, one
   sample a flush.
@@ -143,6 +196,14 @@ Stages form two families:
     gc_pause         one cyclic-GC collection, gc.callbacks start ->
                      stop
 
+Every span above is WALL time: a thread's work plus its waits for the
+one GIL. What the serving threads actually RAN is read from the
+kernel's per-thread CPU clocks, at scrape and nowhere else
+(`thread_clocks`, below: /metrics thread_cpu_seconds_total{thread} and
+`threads` in /v1/debug/stages) — no span reads a CPU clock, because
+the chip host's (a gVisor sandbox) advance in 10 ms ticks and each read
+is a syscall under the GIL.
+
 Everything is a plain float accumulation into the recording thread's
 own table, no lock — ~0.5us per record — so the clock can stay on in
 production. `/metrics` exports
@@ -159,7 +220,9 @@ on the profiler's clock beside the device's XLA Ops. This module
 never imports JAX itself (the JAX-free client tier imports
 serve/tracing.py, and through it this). Spans that cross an `await`
 or belong to no thread (batch_queue, device, call_queue, call_device,
-call_wake, call_e2e, global_peek),
+call_wake, call_e2e, global_peek, and the batch tiles between
+threads: admit_wait, submit_wake, submit_return, submit_host,
+fetch_wake, fetch_return, batch_e2e),
 and the per-call ones recorded from bare stamps on the serving loop
 (grpc_decode, instance_route, grpc_encode: tens of microseconds
 each, and a span object a call is not free there; peer_serve, which
@@ -168,7 +231,7 @@ crosses the batcher's await), stay on the stage clock only.
 The chain lane (r15) participates in BOTH families like the decide
 lanes (r16 audit fix): a frame-flagged chained group records
 batch_queue and device spans, and the serialized chain call records
-submit_host on the submit thread — before this, chained traffic added
+submit_host and its three legs — before this, chained traffic added
 frame e2e with no per-frame stages and silently diluted coverage.
 
 Tracing tie-in (r16, serve/tracing.py): when the caller's context
@@ -183,10 +246,11 @@ import asyncio
 import contextvars
 import gc
 import math
+import os
 import sys
 import threading
 import time
-from typing import Dict, List
+from typing import Callable, Dict, List, Optional
 
 from gubernator_tpu.serve import tracing
 
@@ -198,7 +262,19 @@ PER_FRAME = (
     "device",
     "encode",
 )
-PER_BATCH = (
+#: what tiles one device batch from the flusher's collect to its
+#: futures resolved; submit_host = the three submit_* of them
+BATCH_TILES = (
+    "admit_wait",
+    "submit_wake",
+    "submit_call",
+    "submit_return",
+    "fetch_wake",
+    "fetch_wait",
+    "fetch_return",
+)
+PER_BATCH = BATCH_TILES + (
+    "batch_e2e",
     "submit_host",
     "prep",
     "merge",
@@ -206,7 +282,6 @@ PER_BATCH = (
     "jit_call",
     "observe",
     "shard_stack",
-    "fetch_wait",
 )
 PER_FLUSH = ("global_peek",)
 CALL_TILES = (
@@ -265,9 +340,11 @@ def _capturing() -> bool:
 
 
 class _Span:
-    """One thread-bound span: see StageStats.span."""
+    """One thread-bound span: see StageStats.span. `t0` and `t1` are
+    its two monotonic stamps, for a caller that tiles the time around
+    the span from the same instants (the batcher's hand-off legs)."""
 
-    __slots__ = ("_stats", "_stage", "_ann", "_t0")
+    __slots__ = ("_stats", "_stage", "_ann", "t0", "t1")
 
     def __init__(self, stats: "StageStats", stage: str):
         self._stats = stats
@@ -282,14 +359,14 @@ class _Span:
                 self._stage
             )
             self._ann.__enter__()
-        self._t0 = time.monotonic()
+        self.t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        seconds = time.monotonic() - self._t0
+        self.t1 = time.monotonic()
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        self._stats.add(self._stage, seconds)
+        self._stats.add(self._stage, self.t1 - self.t0)
         return False
 
 
@@ -392,10 +469,12 @@ class StageStats:
 
         attributed = total(PER_FRAME)
         call = stages.get("call_e2e", {"total_s": 0.0, "count": 0})
+        batch = stages.get("batch_e2e", {"total_s": 0.0, "count": 0})
         return {
             "stages": stages,
             "per_frame_stages": list(PER_FRAME),
             "per_batch_stages": list(PER_BATCH),
+            "per_batch_tiles": list(BATCH_TILES),
             "per_call_stages": list(PER_CALL),
             "per_flush_stages": list(PER_FLUSH),
             "per_process_stages": list(PER_PROCESS),
@@ -409,6 +488,12 @@ class StageStats:
                 total(CALL_TILES) / call["total_s"], 4
             )
             if call["total_s"]
+            else 0.0,
+            "batches": batch["count"],
+            "batch_coverage": round(
+                total(BATCH_TILES) / batch["total_s"], 4
+            )
+            if batch["total_s"]
             else 0.0,
             "window_s": round(window_s, 3),
         }
@@ -466,6 +551,111 @@ class ProcessProbes:
         pauses, self._gc_pauses = self._gc_pauses, []
         for seconds in pauses:
             self._stats.add("gc_pause", seconds)
+
+
+#: the serving threads by role. `loop` is the thread that reads (the
+#: scrape and snapshot handlers run on the serving loop), the next three
+#: are serve/batcher.py's pools by the names it gives them, and `other`
+#: is the rest of the process by subtraction: the PJRT client's, gRPC
+#: core's and the native pools' threads
+THREAD_ROLES = ("loop", "submit", "fetch", "prep", "other")
+_POOL_ROLES = (
+    ("guber-submit", "submit"),
+    ("guber-fetch", "fetch"),
+    ("guber-prep", "prep"),
+)
+
+
+def _pthread_cpu_s(thread: threading.Thread) -> Optional[float]:
+    """The thread's CPU clock (user + system seconds it was on a
+    core), or None where the host has none to give."""
+    try:
+        return time.clock_gettime(time.pthread_getcpuclockid(thread.ident))
+    except (AttributeError, OSError, OverflowError, TypeError):
+        return None
+
+
+def _proc_stat_cpu_s(thread: threading.Thread) -> Optional[float]:
+    """The same from /proc/self/task/<tid>/stat: utime + stime, the
+    14th and 15th fields, in clock ticks."""
+    try:
+        with open(f"/proc/self/task/{thread.native_id}/stat", "rb") as f:
+            fields = f.read().rsplit(b")", 1)[1].split()
+        return (int(fields[11]) + int(fields[12])) / os.sysconf("SC_CLK_TCK")
+    except (AttributeError, OSError, ValueError, IndexError):
+        return None
+
+
+class ThreadClocks:
+    """Every serving thread's on-CPU seconds, summed by role: what the
+    threads RAN, beside the stage clock's wall spans, which say what
+    they were TAKEN. Read by the /metrics and /v1/debug/stages
+    handlers and by nothing else — no hot path reads a CPU clock — so
+    a reader differences two scrapes, each of which carries the
+    monotonic clock of the same instant (`wall_s`).
+
+    The source is chosen once, by trying it on the constructing
+    thread: `pthread` (clock_gettime on pthread_getcpuclockid), else
+    `proc_stat`, else `none` — and then `cpu_s` is empty: a series
+    that cannot be read is absent, not 0."""
+
+    SOURCES = (("pthread", _pthread_cpu_s), ("proc_stat", _proc_stat_cpu_s))
+
+    def __init__(self, sources=SOURCES):
+        self.source = "none"
+        self._read: Optional[Callable] = None
+        #: the smallest step the clock was seen to take (measure_granularity)
+        self.granularity_s: Optional[float] = None
+        me = threading.current_thread()
+        for name, read in sources:
+            if read(me) is not None:
+                self.source, self._read = name, read
+                break
+
+    def snapshot(self) -> dict:
+        """Call on the serving loop: the calling thread is `loop`."""
+        out = {
+            "thread_clock": self.source,
+            "granularity_s": self.granularity_s,
+            "wall_s": time.monotonic(),
+            "cpu_s": {},
+        }
+        if self._read is None:
+            return out
+        cpu = dict.fromkeys(THREAD_ROLES, 0.0)
+        me = threading.current_thread()
+        for t in threading.enumerate():
+            role = "loop" if t is me else next(
+                (r for prefix, r in _POOL_ROLES if t.name.startswith(prefix)),
+                None,
+            )
+            if role is not None:
+                # a thread that exited since enumerate() reads as None
+                cpu[role] += self._read(t) or 0.0
+        # the process's clock and the threads' tick separately
+        cpu["other"] = max(0.0, time.process_time() - sum(cpu.values()))
+        out["cpu_s"] = cpu
+        return out
+
+    def measure_granularity(self) -> Optional[float]:
+        """Spin on the calling thread until its clock has stepped three
+        times (or 0.1 s is spent) and keep the smallest step: ~1 us on
+        a kernel that accounts by the nanosecond, the tick (10 ms under
+        gVisor) on one that samples. Once, at boot."""
+        if self._read is None:
+            return None
+        me = threading.current_thread()
+        deadline = time.monotonic() + 0.1
+        last, seen = self._read(me), []
+        while len(seen) < 3 and time.monotonic() < deadline:
+            now = self._read(me)
+            if now is None:
+                break
+            if now > last:
+                seen.append(now - last)
+                last = now
+        self.granularity_s = min(seen) if seen else None
+        return self.granularity_s
 
 
 #: process-global clock; the doors, batcher, instance and engine record

@@ -16,7 +16,13 @@ over one window of --seconds:
   profiler's first-use cost), and reduces both with the benchmark's
   own trace_reduce.py: return time, the slowest call due while the
   capture ran, the loop's worst lag while it ran, idle share, idle gaps;
-- dumps /v1/debug/traces and sums the retained slow calls by tile.
+- dumps /v1/debug/traces and sums the retained slow calls by tile;
+- reads a device batch's tiles (`batch_coverage`, the hand-off's legs
+  beside the residue they used to be, microseconds a batch) and every
+  serving thread's CPU share of the window (`threads`: the CPU clocks
+  of the snapshots at its two ends), PR 36;
+- names the THREAD whose line holds each of the top idle gaps of the
+  Python-tracer capture (scripts/gap_threads.py).
 
 Prints one JSON object; the whole of it, and the Python-tracer-off
 capture's .xplane.pb, go to chiprun_out/trace_study/. The parent never
@@ -30,6 +36,7 @@ import glob
 import json
 import os
 import shutil
+import subprocess
 import sys
 import tempfile
 import threading
@@ -96,6 +103,61 @@ def capture(d, name, python, ms=CAPTURE_MS):
     return t, time.monotonic()
 
 
+def batch_tiles(st):
+    """A device batch's life in microseconds a batch: each tile, the
+    interior of submit_call, and the hand-off both ways it can be
+    read — the two measured legs, and the residue submit_host less
+    prep, merge and dispatch that stood for them before PR 36."""
+    def per(name, count):
+        n = st.get(count, {}).get("count", 0)
+        return st.get(name, {}).get("total_s", 0.0) / n * 1e6 if n else None
+
+    out = {name: per(name, "batch_e2e") for name in (
+        "batch_e2e", "admit_wait", "fetch_wake", "fetch_wait", "fetch_return")}
+    out.update({name: per(name, "submit_host") for name in (
+        "submit_host", "submit_wake", "submit_call", "submit_return",
+        "prep", "merge", "dispatch", "jit_call", "observe")})
+    if out["submit_host"] is not None and out["submit_wake"] is not None:
+        out["legs"] = out["submit_wake"] + out["submit_return"]
+        out["residue"] = (out["submit_host"] - out["prep"] - out["merge"]
+                          - out["dispatch"])
+    return out
+
+
+def thread_shares(t0, t1, batches):
+    """Each role's CPU seconds over the wall seconds between two
+    `threads` snapshots, in percent of one core, and the submit
+    thread's CPU microseconds a device batch."""
+    if not t0 or not t1 or not t1.get("cpu_s") or not t0.get("cpu_s"):
+        return {"thread_clock": (t1 or {}).get("thread_clock", "absent")}
+    wall = t1["wall_s"] - t0["wall_s"]
+    ran = {k: t1["cpu_s"][k] - t0["cpu_s"].get(k, 0.0) for k in t1["cpu_s"]}
+    return {
+        "thread_clock": t1["thread_clock"],
+        "granularity_s": t1.get("granularity_s"),
+        "wall_s": wall,
+        "cpu_pct": {k: 100.0 * v / wall for k, v in ran.items()},
+        "serving_threads_cpu_pct": 100.0 * sum(
+            v for k, v in ran.items() if k != "other") / wall,
+        "submit_cpu_us_per_batch": (
+            ran["submit"] / batches * 1e6 if batches else None),
+    }
+
+
+def gap_threads(profile_dir):
+    """scripts/gap_threads.py over one capture, in a CPU child."""
+    files = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "scripts", "gap_threads.py"),
+         *files],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=600)
+    if p.returncode != 0:
+        return {"error": p.stderr[-1000:]}
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
 def tiles_of(traces, slow_ms):
     """The retained calls slower than slow_ms: how many, and the mean
     milliseconds each span name holds in them."""
@@ -159,7 +221,7 @@ def main() -> int:
         fleet.go(t0)
         workers.wait_until(t0)
         unix0_ms = time.time() * 1e3
-        get_json(d, "/v1/debug/stages?reset=1")
+        threads0 = get_json(d, "/v1/debug/stages?reset=1").get("threads")
         get_json(d, "/v1/debug/traces?reset=1")
         poller = Poller(d, t0)
         poller.start()
@@ -207,6 +269,9 @@ def main() -> int:
             idle_share_pct=100.0 * (1 - reduced["busy_s"] / reduced["window_s"]),
             idle_gaps=reduced["idle_gaps"][:10],
             device_ops=reduced["device_ops"][:10], step=reduced["step"])
+        if python == "1":  # the Python tracer names what a thread ran
+            c["idle_gap_threads"] = gap_threads(
+                os.path.join(base, names[python]))
         if python == "0":  # kept: the operations' full HLO lines are in it
             for f in glob.glob(os.path.join(base, names[python], "**",
                                             "*.xplane.pb"), recursive=True):
@@ -228,6 +293,11 @@ def main() -> int:
         captures=caps,
         call_coverage=stages.get("call_coverage"), calls=stages.get("calls"),
         coverage=stages.get("coverage"), frames=stages.get("frames"),
+        batch_coverage=stages.get("batch_coverage"),
+        batches=stages.get("batches"),
+        batch_tiles_us=batch_tiles(st),
+        threads=thread_shares(threads0, stages.get("threads"),
+                              stages.get("batches")),
         stage_means_ms={k: v["mean_ms"] for k, v in st.items()},
         stage_counts={k: v["count"] for k, v in st.items()},
         stage_totals_s={k: v["total_s"] for k, v in st.items()},

@@ -88,6 +88,7 @@ def test_metrics_endpoint_families_and_label_cardinality():
                 "distinct_keys_estimate",
                 "serving_stage_seconds_total",
                 "serving_stage_samples_total",
+                "thread_wall_seconds_total",
                 "batcher_queue_depth",
                 "batcher_queue_oldest_age_seconds",
                 "prep_pool_backlog",
@@ -149,6 +150,22 @@ def test_metrics_endpoint_families_and_label_cardinality():
         }
         assert stages <= known, stages
         assert "instance_route" in stages  # traffic populated it
+
+        # bounded `thread` label set (PR 36): the five roles, fixed —
+        # never a thread's name or id (absent only where the host has
+        # no thread CPU clock, and then the whole family is)
+        from gubernator_tpu.serve.stages import THREAD_ROLES, ThreadClocks
+
+        if ThreadClocks().source != "none":
+            threads = {
+                s.labels["thread"]
+                for s in fams["thread_cpu_seconds_total"].samples
+            }
+            assert threads == set(THREAD_ROLES) and len(threads) == 5
+            wall = next(
+                s.value for s in fams["thread_wall_seconds_total"].samples
+            )
+            assert wall > 0
 
         # trace counters moved (trace_sample=1.0 on every node)
         started = next(
