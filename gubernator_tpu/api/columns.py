@@ -20,6 +20,11 @@ from gubernator_tpu.api.types import Behavior, Status
 from gubernator_tpu.core.hashing import native_lib
 
 
+#: the columns DeviceBatcher.decide_arrays and the shed cache's screen
+#: take, of those a native parser fills
+DECIDE_FIELDS = ("key_hash", "hits", "limit", "duration", "algo")
+
+
 class PeerBatch:
     """The items of one serialised GetPeerRateLimitsReq, as columns.
 
@@ -32,10 +37,7 @@ class PeerBatch:
 
     def __init__(self, wire: bytes, cols: Dict[str, np.ndarray]):
         self.wire = wire
-        self.fields = {
-            k: cols[k]
-            for k in ("key_hash", "hits", "limit", "duration", "algo")
-        }
+        self.fields = {k: cols[k] for k in DECIDE_FIELDS}
         self.behavior = cols["behavior"]
         self._spans = cols
 
@@ -66,21 +68,26 @@ class PeerBatch:
         Behavior GLOBAL items in batch order: the `keys` and `glob`
         GlobalManager.queue_update_fields takes. Strings are built for
         these items only."""
-        idx = np.flatnonzero(self.behavior == int(Behavior.GLOBAL))
-        keys: Dict[int, str] = {}
-        glob: List[tuple] = []
-        if idx.shape[0]:
-            wire, s = self.wire, self._spans
-            for i, no, nl, ko, kl in zip(
-                idx.tolist(),
-                s["name_off"][idx].tolist(), s["name_len"][idx].tolist(),
-                s["key_off"][idx].tolist(), s["key_len"][idx].tolist(),
-            ):
-                name = wire[no : no + nl].decode()
-                ukey = wire[ko : ko + kl].decode()
-                keys[i] = name + "_" + ukey
-                glob.append((i, name, ukey))
-        return keys, glob
+        glob = global_rows(self.wire, self._spans)
+        return {i: name + "_" + ukey for i, name, ukey in glob}, glob
+
+
+def global_rows(wire: bytes, cols: Dict[str, np.ndarray]) -> List[tuple]:
+    """[(index, name, unique_key)] of the Behavior GLOBAL items among
+    natively parsed columns, in item order: name and unique_key are
+    decoded from their spans in `wire` (name_off / name_len / key_off /
+    key_len, validated UTF-8 by the parser) for these rows alone."""
+    idx = np.flatnonzero(cols["behavior"] == int(Behavior.GLOBAL))
+    if not idx.shape[0]:
+        return []
+    return [
+        (i, wire[no : no + nl].decode(), wire[ko : ko + kl].decode())
+        for i, no, nl, ko, kl in zip(
+            idx.tolist(),
+            cols["name_off"][idx].tolist(), cols["name_len"][idx].tolist(),
+            cols["key_off"][idx].tolist(), cols["key_len"][idx].tolist(),
+        )
+    ]
 
 
 def _column(name: str, cast=int) -> property:

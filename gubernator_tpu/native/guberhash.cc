@@ -1353,7 +1353,9 @@ int64_t guber_prep_run(const uint64_t* key_hash, const int64_t* hits,
 
 namespace {
 
-// decline codes of guber_parse_peer_batch (hashlib_native.PEER_DECLINE)
+// decline codes of guber_parse_peer_batch (hashlib_native.PEER_DECLINE);
+// guber_parse_string_frame shares -4 .. -6 and adds its own
+// (hashlib_native.STRING_DECLINE)
 constexpr int64_t PEER_CHAIN = -1;      // a quota chain (field 8)
 constexpr int64_t PEER_UNKNOWN = -2;    // unknown field, or a known one
                                         // under another wire type
@@ -1362,6 +1364,12 @@ constexpr int64_t PEER_UTF8 = -4;       // name or key not valid UTF-8
 constexpr int64_t PEER_TRUNCATED = -5;  // a length or varint runs past
                                         // its message
 constexpr int64_t PEER_TOO_MANY = -6;   // more items than max_items
+// -7 is hashlib_native's own: a library built before the symbol
+constexpr int64_t FRAME_EMPTY = -8;     // an empty name or unique_key
+constexpr int64_t FRAME_TRAILING = -9;  // bytes left after the last item
+constexpr int64_t FRAME_NUL = -10;      // a NUL byte in a name or key:
+                                        // the joined keys could not be
+                                        // split again
 
 // One varint of at most 10 bytes; false where it runs past `end` or
 // carries more than 64 bits.
@@ -1513,6 +1521,75 @@ int64_t guber_parse_peer_batch(const uint8_t* buf, int64_t len,
     key_len[n] = static_cast<int32_t>(klen);
     ++n;
   }
+  return n;
+}
+
+// Parse the payload of one GEB string frame (serve/edge_bridge.py: the
+// header's item count `n`, then per item <H name_len, name, <H key_len,
+// unique_key, <qqqBB hits, limit, duration, algorithm, behavior, all
+// little-endian) into columns: the native twin of the lean parse in
+// EdgeBridge._fold_string_frame, which stays as its fallback and its
+// oracle. Returns n, or a decline code < 0 (the columns are then
+// garbage) exactly where that loop returns None: n over len / 30 (an
+// item is at least 30 bytes), a truncated item, an empty name or key,
+// invalid UTF-8, bytes left over - and for a NUL byte in a name or key,
+// which the loop serves. key_hash is xxh64(name + "_" + unique_key,
+// seed), core/hashing.slot_hash_batch's value; an algorithm byte over 3
+// reads 0, as the loop clamps it. `keys_out` (len bytes: an item's hash
+// key and its separator are shorter than the item) receives the n hash
+// keys joined by NUL, *keys_len their bytes: one decode and one split
+// make the frame's key strings, no Python step per item.
+int64_t guber_parse_string_frame(const uint8_t* buf, int64_t len,
+                                 int64_t n, uint64_t seed,
+                                 uint64_t* key_hash, int64_t* hits,
+                                 int64_t* limit, int64_t* duration,
+                                 int32_t* algo, uint8_t* behavior,
+                                 int32_t* name_off, int32_t* name_len,
+                                 int32_t* key_off, int32_t* key_len,
+                                 uint8_t* keys_out, int64_t* keys_len) {
+  constexpr int64_t FIX = 26;  // <qqqBB
+  if (len > INT32_MAX) return PEER_TRUNCATED;
+  if (n < 0 || n > len / 30) return PEER_TOO_MANY;
+  const uint8_t* p = buf;
+  const uint8_t* const end = buf + len;
+  uint8_t* w = keys_out;
+  for (int64_t i = 0; i < n; ++i) {
+    const uint8_t* span[2];
+    int64_t slen[2];
+    for (int f = 0; f < 2; ++f) {  // name, then unique_key
+      if (end - p < 2) return PEER_TRUNCATED;
+      slen[f] = p[0] | (static_cast<int64_t>(p[1]) << 8);
+      p += 2;
+      if (end - p < slen[f]) return PEER_TRUNCATED;
+      if (slen[f] == 0) return FRAME_EMPTY;
+      if (std::memchr(p, 0, slen[f]) != nullptr) return FRAME_NUL;
+      if (!valid_utf8(p, slen[f])) return PEER_UTF8;
+      span[f] = p;
+      p += slen[f];
+    }
+    if (end - p < FIX) return PEER_TRUNCATED;
+    if (i) *w++ = 0;
+    uint8_t* const k0 = w;
+    std::memcpy(w, span[0], slen[0]);
+    w += slen[0];
+    *w++ = '_';
+    std::memcpy(w, span[1], slen[1]);
+    w += slen[1];
+    key_hash[i] = xxh64(k0, static_cast<size_t>(w - k0), seed);
+    // a little-endian host, as the numpy views of the fast frames assume
+    std::memcpy(&hits[i], p, 8);
+    std::memcpy(&limit[i], p + 8, 8);
+    std::memcpy(&duration[i], p + 16, 8);
+    algo[i] = p[24] <= 3 ? p[24] : 0;
+    behavior[i] = p[25];
+    p += FIX;
+    name_off[i] = static_cast<int32_t>(span[0] - buf);
+    name_len[i] = static_cast<int32_t>(slen[0]);
+    key_off[i] = static_cast<int32_t>(span[1] - buf);
+    key_len[i] = static_cast<int32_t>(slen[1]);
+  }
+  if (p != end) return FRAME_TRAILING;
+  *keys_len = w - keys_out;
   return n;
 }
 

@@ -778,6 +778,58 @@ def parse_peer_batch(wire: bytes, max_items: int):
     return n, {name: a[:n] for name, a in cols.items()}
 
 
+# -- the GEB door's string-frame parse (guberhash.cc, same section) ----------
+
+#: why guber_parse_string_frame declined a frame, by its return code:
+#: PEER_DECLINE's names where the reason is shared
+STRING_DECLINE = {
+    -4: PEER_DECLINE[-4],
+    -5: PEER_DECLINE[-5],
+    -6: PEER_DECLINE[-6],
+    -7: PEER_DECLINE[-7],
+    -8: "empty_name_or_key",
+    -9: "trailing_bytes",
+    -10: "nul_byte",
+}
+
+try:  # absent in a stale prebuilt .so: the door keeps its Python loop
+    _lib.guber_parse_string_frame.restype = ctypes.c_int64
+    _lib.guber_parse_string_frame.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_uint64,
+    ] + [_vp] * 12
+    _HAS_STRING_FRAME = True
+except AttributeError:
+    _HAS_STRING_FRAME = False
+
+
+def parse_string_frame(payload: bytes, n: int):
+    """(n, columns, keys) of one GEB string frame's payload (`n` the
+    header's item count): the _PEER_COLUMNS of parse_peer_batch —
+    key_hash as slot_hash_batch hashes name + "_" + unique_key, an
+    algorithm byte over 3 read as 0, the offsets of name and
+    unique_key in `payload` — and the n hash keys as one `bytes`,
+    joined by NUL and valid UTF-8. (code < 0, None, None) where the
+    native parser declines (STRING_DECLINE). One call with the GIL
+    released, no object per item."""
+    if not _HAS_STRING_FRAME:
+        return -7, None, None
+    size = len(payload)
+    # the wire count is untrusted: an item is at least 30 bytes
+    if not 0 <= n <= size // 30:
+        return -6, None, None
+    cols = {name: np.empty(n, dt) for name, dt in _PEER_COLUMNS}
+    keys = np.empty(size, np.uint8)
+    keys_len = ctypes.c_int64(0)
+    got = _lib.guber_parse_string_frame(
+        payload, size, n, _SEED,
+        *[a.ctypes.data for a in cols.values()],
+        keys.ctypes.data, ctypes.addressof(keys_len),
+    )
+    if got < 0:
+        return got, None, None
+    return got, cols, keys[: keys_len.value].tobytes()
+
+
 def encode_peer_answers(status, limit, remaining, reset_time) -> bytes:
     """The serialised GetPeerRateLimitsResp of four answer columns
     (zero fields left out, no error, no metadata), in one call."""

@@ -146,12 +146,14 @@ import time
 import zlib
 from typing import List, Optional
 
+from gubernator_tpu.api.columns import DECIDE_FIELDS, global_rows
 from gubernator_tpu.api.types import (
     Algorithm,
     Behavior,
     RateLimitReq,
     RateLimitResp,
 )
+from gubernator_tpu.core.hashing import native_lib
 from gubernator_tpu.serve import metrics, tracing
 from gubernator_tpu.serve.batcher import is_device_backend
 from gubernator_tpu.serve.config import MAX_BATCH_SIZE
@@ -303,6 +305,28 @@ _FAST_RESP_DTYPE = None
 # shards (edge.cc fill_string_decisions), so empty owner here keeps
 # parity with a locally-served item on the object path.
 _STRING_RESP_DTYPE = None
+
+
+def _parse_string_native(payload: bytes, n: int):
+    """(hash keys, columns) of one string frame's payload by ONE native
+    call with the GIL released (native/guberhash.cc
+    guber_parse_string_frame): the keys `name + "_" + unique_key` as a
+    list of str from one decode and one split of the parser's
+    NUL-joined buffer, the columns hashlib_native.parse_string_frame's.
+    None where libguberhash.so is not built, or the parser declines
+    (counted by reason): EdgeBridge._fold_string_frame then runs its
+    per-item loop over the same bytes."""
+    lib = native_lib()
+    if lib is None:
+        return None
+    got, cols, keys = lib.parse_string_frame(payload, n)
+    if got < 0:
+        metrics.EDGE_STRING_NATIVE_DECLINED.labels(
+            reason=lib.STRING_DECLINE[got]
+        ).inc()
+        return None
+    metrics.EDGE_STRING_NATIVE_FRAMES.inc()
+    return (keys.decode().split("\x00") if n else []), cols
 
 
 def _stamp_shed(seconds: float) -> None:
@@ -909,7 +933,10 @@ class FrameService:
 
     def _fold_string_frame(self, payload: bytes, n: int):
         """Lean parse + eligibility screen for the string->array fold
-        (r7 slow-path owner batching, bridge side). When EVERY item in
+        (r7 slow-path owner batching, bridge side): the parse is one
+        native call (_parse_string_native) and, where the library is
+        not built or declines the payload, the per-item loop below —
+        same columns, same hashes, same declines. When EVERY item in
         a string frame is valid (non-empty UTF-8 name/key) and owned
         by this node under the current ring, the frame needs no
         request/response objects and no instance routing: it rides
@@ -924,8 +951,9 @@ class FrameService:
         hashing. Returns (full_keys, fields, glob, route_s) or None:
         `glob` is [(index, name, unique_key)] of the GLOBAL items in
         frame order, `route_s` the seconds of the ownership screen and
-        the key hashing (the Instance's share of the work, stamped
-        `instance_route` by the caller). None falls back to the object
+        (in the loop; the native parse hashes off the wire) the key
+        hashing — the Instance's share of the work, stamped
+        `instance_route` by the caller. None falls back to the object
         path, which keeps full semantics for per-item validation
         errors and for ANY item this node does not own: a stale edge's
         plain item is forwarded by the instance there, a non-owner's
@@ -939,6 +967,19 @@ class FrameService:
         mask_fn = getattr(picker, "self_owned_mask", None)
         if mask_fn is None or not getattr(picker, "size", lambda: 0)():
             return None
+        parsed = _parse_string_native(payload, n)
+        if parsed is not None:
+            full, cols = parsed
+            t0 = time.monotonic()
+            if not mask_fn(full).all():
+                return None
+            route_s = time.monotonic() - t0
+            fields = {k: cols[k] for k in DECIDE_FIELDS}
+            return full, fields, global_rows(payload, cols), route_s
+        # the library is not built, or it declined the payload: the
+        # per-item loop below is the same parse (and the oracle the
+        # native one is tested against)
+
         # the wire count is untrusted: bound it by the payload's
         # minimum bytes/item (2+2 length prefixes + 26 fixed) before
         # sizing arrays from it, like _decide_fast's exact-length check

@@ -128,6 +128,25 @@ EDGE_FOLDED_GLOBAL_ITEMS = Counter(
     "their keys queued for the owner's status broadcast",
     registry=REGISTRY,
 )
+EDGE_STRING_NATIVE_FRAMES = Counter(
+    "edge_string_native_frames_total",
+    "String frames whose payload ONE native call parsed into columns, "
+    "key hashes and hash keys (libguberhash.so guber_parse_string_frame, "
+    "GIL released) for the string->array fold; a string frame counted "
+    "neither here nor as declined was parsed by the per-item Python "
+    "loop (library not built)",
+    registry=REGISTRY,
+)
+EDGE_STRING_NATIVE_DECLINED = Counter(
+    "edge_string_native_declined_total",
+    "String frames the native parser declined, by reason "
+    "(hashlib_native.STRING_DECLINE: too_many_items, truncated, "
+    "empty_name_or_key, bad_utf8, trailing_bytes, nul_byte, "
+    "stale_library); the Python loop then parses the same bytes and "
+    "what it declines too is served by the object path",
+    ["reason"],
+    registry=REGISTRY,
+)
 EDGE_OBJECT_ITEMS = Counter(
     "edge_object_items_total",
     "String-frame items the bridge served through request/response "

@@ -22,7 +22,10 @@ over one window of --seconds:
   serving thread's CPU share of the window (`threads`: the CPU clocks
   of the snapshots at its two ends), PR 36;
 - names the THREAD whose line holds each of the top idle gaps of the
-  Python-tracer capture (scripts/gap_threads.py).
+  Python-tracer capture (scripts/gap_threads.py);
+- reads the GEB door's `edge_*` counters by growth over the window
+  (`door_counters`: which path served the items, and how many string
+  frames the native parser took or declined), PR 37.
 
 Prints one JSON object; the whole of it, and the Python-tracer-off
 capture's .xplane.pb, go to chiprun_out/trace_study/. The parent never
@@ -158,6 +161,15 @@ def gap_threads(profile_dir):
     return json.loads(p.stdout.strip().splitlines()[-1])
 
 
+def door_counters(prom0, prom1):
+    """Growth of every `edge_*_total` series of /metrics between two
+    scrapes, series that stood still left out."""
+    return {
+        k: v - prom0.get(k, 0.0) for k, v in sorted(prom1.items())
+        if k.startswith("edge_") and "_total" in k and v != prom0.get(k, 0.0)
+    }
+
+
 def tiles_of(traces, slow_ms):
     """The retained calls slower than slow_ms: how many, and the mean
     milliseconds each span name holds in them."""
@@ -223,6 +235,7 @@ def main() -> int:
         unix0_ms = time.time() * 1e3
         threads0 = get_json(d, "/v1/debug/stages?reset=1").get("threads")
         get_json(d, "/v1/debug/traces?reset=1")
+        prom0 = d.prom()
         poller = Poller(d, t0)
         poller.start()
         caps = {}
@@ -236,6 +249,7 @@ def main() -> int:
         workers.wait_until(t0 + args.seconds)
         poller.stop.set()
         stages = get_json(d, "/v1/debug/stages")
+        prom1 = d.prom()
         traces = get_json(d, "/v1/debug/traces?limit=4096")
         results = fleet.results(traffic["drain_timeout_s"])
         summary = kind.summarize(results, spec)
@@ -295,6 +309,7 @@ def main() -> int:
         coverage=stages.get("coverage"), frames=stages.get("frames"),
         batch_coverage=stages.get("batch_coverage"),
         batches=stages.get("batches"),
+        door_counters=door_counters(prom0, prom1),
         batch_tiles_us=batch_tiles(st),
         threads=thread_shares(threads0, stages.get("threads"),
                               stages.get("batches")),

@@ -149,11 +149,12 @@ def spawn_daemon_edge(
 
 def native_lib_for_tests(tmp_dir):
     """gubernator_tpu.native.hashlib_native over a libguberhash.so
-    that has the PeersV1 wire fold: the checkout's own where it is
-    built and current, else one compiled from guberhash.cc into
-    `tmp_dir` and loaded from there under a private module name — a
-    test never drops a .so into the checkout other tests run from
-    (tests/test_chip_smoke.py does the same with a copy)."""
+    that has the PeersV1 wire fold and the GEB string-frame parse: the
+    checkout's own where it is built and current, else one compiled
+    from guberhash.cc into `tmp_dir` and loaded from there under a
+    private module name — a test never drops a .so into the checkout
+    other tests run from (tests/test_chip_smoke.py does the same with
+    a copy)."""
     import importlib.util
     import shutil
     import subprocess
@@ -165,7 +166,9 @@ def native_lib_for_tests(tmp_dir):
     try:
         from gubernator_tpu.native import hashlib_native
 
-        if getattr(hashlib_native, "_HAS_PEER_WIRE", False):
+        if getattr(hashlib_native, "_HAS_PEER_WIRE", False) and getattr(
+            hashlib_native, "_HAS_STRING_FRAME", False
+        ):
             return hashlib_native
     except ImportError:
         pass
