@@ -12,6 +12,7 @@ base_seed, canary_every, canaries_per_worker, key_classes, algorithms.
 from __future__ import annotations
 
 import asyncio
+import math
 import time
 
 import numpy as np
@@ -31,6 +32,11 @@ def build(spec: dict) -> np.ndarray:
         spec["config"]["key_population"], (frames, t["items_per_frame"]), base
     )
     return keyspace.seeded_order(draws, spec["seed"], w)
+
+
+def by_second(done: np.ndarray, t0: float, seconds: float) -> np.ndarray:
+    """Frames answered in each second of the window."""
+    return np.bincount((done - t0).astype(int), minlength=math.ceil(seconds))
 
 
 def run_worker(spec: dict, conn) -> None:
@@ -99,6 +105,7 @@ async def _run(spec: dict, conn) -> None:
     inside = (done >= t0) & (done < t_end)
     conn.send(("done", {
         "frames_in_window": int(inside.sum()),
+        "frames_by_second": by_second(done[inside], t0, spec["seconds"]),
         "frame_ms": (done - sent)[inside] * 1e3,
         "frames_sent": next_frame, "failed": failed_frames,
         "wrapped": max(0, next_frame - len(ids)),
@@ -124,6 +131,11 @@ def summarize(results, spec: dict) -> dict:
             "frame_p50_ms": stats.percentile(frame_ms, 50) if len(frame_ms) else None,
             "frame_p99_ms": stats.percentile(frame_ms, 99) if len(frame_ms) else None,
             "frames_wrapped": sum(r["wrapped"] for r in results),
+            # the window second by second: a run that reads low by a hole
+            # (a stalled second) shows it here, one that ran at a lower
+            # level all through does not (PERF.md section 6, PR 38)
+            "frames_by_second": sum(
+                r["frames_by_second"] for r in results).tolist(),
             "client": results[0]["client"],
         },
     }

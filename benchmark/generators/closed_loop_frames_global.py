@@ -137,6 +137,7 @@ async def _run(spec: dict, conn) -> None:
     inside = (done >= t0) & (done < t_end)
     conn.send(("done", {
         "frames_in_window": int(inside.sum()),
+        "frames_by_second": plain.by_second(done[inside], t0, spec["seconds"]),
         "frame_ms": (done - sent)[inside] * 1e3,
         "frames_sent": next_frame, "failed": failed_frames,
         "wrapped": max(0, next_frame - len(ids)),

@@ -1,7 +1,8 @@
-"""The run: a JAX-free parent that boots the daemon (the only process
-that touches the chip), preloads and checks it, starts the generator
-processes, measures one window, checks again, stops everything and
-prints the result line.
+"""The run: a JAX-free parent that boots the configuration's daemons
+(the only processes that touch a chip; one unless the configuration
+says `nodes`: harness/daemon.py), preloads and checks them through node
+0, starts the generator processes, measures one window, checks again,
+stops everything and prints the result line.
 
 Everything that belongs to one cell, configuration, traffic mix,
 generator kind, per-layer metric or reader kind is a file found by its
@@ -27,7 +28,7 @@ import numpy as np
 import check
 from harness import daemon as daemon_mod
 from harness import keyspace, workers
-from harness.daemon import ROOT, BenchFailure, Daemon
+from harness.daemon import ROOT, BenchFailure, Daemon, Ring
 
 BENCH = os.path.join(ROOT, "benchmark")
 OUT_DIR = os.path.join(ROOT, "chiprun_out", "benchmark")
@@ -183,7 +184,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--daemon-argv", default="",
-                    help="tests only: run this JSON argv in the daemon's place")
+                    help="tests only: run this JSON argv in the daemon's place; "
+                         "an object {node: argv} replaces those nodes alone")
     args = ap.parse_args(argv)
     try:
         return run(args, t_exec)
@@ -199,66 +201,118 @@ def cache_entries(cache_dir: str) -> int:
     )
 
 
-def boot(args, config: dict, t_exec: float):
-    """The daemon up and on the device the cell asks for: (daemon,
-    device report, the platform named in the environment or '', the
-    seconds each boot took).
+def daemon_argvs(text: str, n: int) -> dict:
+    """--daemon-argv as {node: argv}: a JSON list is every node's."""
+    if not text:
+        return {}
+    argv = json.loads(text)
+    if isinstance(argv, dict):
+        return {int(i): a for i, a in argv.items()}
+    return dict.fromkeys(range(n), argv)
 
-    The daemon that is measured has found its programs in the compile
-    cache. A boot that had to compile some (it added entries to the
-    cache: the first run in a checkout) has done the compiling, and is
-    stopped and booted once more: a daemon that compiled its ladder
-    itself served the window that followed with one stall of ~16 s
-    (PERF.md section 6), which no later run of the cell sees."""
+
+def boot_nodes(ring: Ring, which, deadline: float, named: str, cache_dir: str,
+               boots: list, first: bool) -> list:
+    """Nodes `which` started together and waited for, each held to the
+    device its configuration says; the seconds until the last was Ready
+    go to `boots`. Returns the nodes that compiled a program."""
+    n, cached = len(ring.nodes), cache_entries(cache_dir)
+    started = [ring.start(i) for i in which]
+    t = time.monotonic()
+    for d in started:
+        d.wait_ready(deadline, n)
+    boots.append(time.monotonic() - t)
+    built = []
+    for d in started:
+        where = f" (node {d.index})" if n > 1 else ""
+        report = d.stages()
+        device = ring.devices[d.index] = report["device"]
+        chips = ring.specs[d.index]["chips"]
+        if device is None:
+            raise BenchFailure(f"the daemon{where} reports no device")
+        if device["platform"] != "tpu" and not named:
+            raise BenchFailure(
+                f"the daemon{where} serves from '{device['platform']}', not a TPU"
+            )
+        # one daemon may see more chips than it uses (cell 6); a ring's
+        # node sees its own and no other's
+        if device["count"] < chips or (n > 1 and device["count"] != chips):
+            raise BenchFailure(
+                f"{device['count']} devices{where}, the cell needs {chips}"
+            )
+        compiles = d.compiles()
+        if compiles.pop("built"):
+            built.append(d.index)
+        emit(phase="boot", seconds=boots[-1], cold=compiles["cache_hits"] == 0,
+             cache_dir=cache_dir,
+             cache_entries_added=cache_entries(cache_dir) - cached,
+             boots_again=first and d.index in built,
+             device={k: device[k] for k in ("platform", "kind", "count")},
+             host_prep=report["host_prep"], hasher=report["hasher"], **compiles,
+             **({"node": d.index} if n > 1 else {}))
+    return built
+
+
+def set_aside(ring: Ring, which) -> float:
+    """The nodes that compiled stopped, their logs kept; the deadline of
+    the boot that follows, which finds every program in the cache."""
+    for i in which:
+        ring.nodes[i].stop()
+        os.replace(ring.nodes[i].log_path, ring.nodes[i].log_path + ".compiled")
+    return time.monotonic() + REBOOT_TIMEOUT
+
+
+def boot(args, config: dict, t_exec: float):
+    """The configuration's daemons up, each on the device it asks for:
+    (the ring, node 0's device report, the platform named in the
+    environment or '', the seconds each boot took).
+
+    The daemons that are measured have found their programs in the
+    compile cache. A boot that had to compile some (its own log says so:
+    the first run in a checkout) has done the compiling, and is stopped
+    and booted once more: a daemon that compiled its ladder itself
+    served the window that followed with one stall of ~16 s (PERF.md
+    section 6), which no later run of the cell sees.
+
+    Node 0 boots first, alone, under that rule (its second boot on
+    addresses drawn anew, as one daemon's always was: no peer has been
+    given the list yet); the others then boot together, and those of
+    them that compiled once more, on the addresses their peers know."""
     named = next((os.environ[k] for k in PLATFORM_ENVS if os.environ.get(k)), "")
     cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
                  or os.path.join(ROOT, ".jax_cache"))
-    argv = json.loads(args.daemon_argv) if args.daemon_argv else None
+    argvs = daemon_argvs(args.daemon_argv, len(daemon_mod.node_specs(config)))
+    how = (named, cache_dir)
     deadline, boots = t_exec + BOOT_TIMEOUT, []
-    while True:
-        cached = cache_entries(cache_dir)
-        d = Daemon(args.workload, config["env"], OUT_DIR, argv)
-        try:
-            t = time.monotonic()
-            d.wait_ready(deadline)
-            boots.append(time.monotonic() - t)
-            report = d.stages()
-            device = report["device"]
-            if device is None:
-                raise BenchFailure("the daemon reports no device")
-            if device["platform"] != "tpu" and not named:
-                raise BenchFailure(
-                    f"the daemon serves from '{device['platform']}', not a TPU"
-                )
-            if device["count"] < config["chips"]:
-                raise BenchFailure(
-                    f"{device['count']} devices, the cell needs {config['chips']}"
-                )
-            compiles = d.compiles()
-            added = cache_entries(cache_dir) - cached
-            again = added > 0 and len(boots) == 1
-            emit(phase="boot", seconds=boots[-1], cold=compiles["cache_hits"] == 0,
-                 cache_dir=cache_dir, cache_entries_added=added, boots_again=again,
-                 device={k: device[k] for k in ("platform", "kind", "count")},
-                 host_prep=report["host_prep"], hasher=report["hasher"], **compiles)
-        except BaseException:
-            d.stop(10.0)
-            raise
-        if not again:
-            return d, device, named, boots
-        d.stop()
-        os.replace(d.log_path, d.log_path + ".compiled")
-        deadline = time.monotonic() + REBOOT_TIMEOUT
+    ring = Ring(args.workload, config, OUT_DIR, argvs)
+    try:
+        if boot_nodes(ring, [0], deadline, *how, boots, True):
+            deadline = set_aside(ring, [0])
+            ring.stop()  # lets the ports held for the other nodes go
+            ring = Ring(args.workload, config, OUT_DIR, argvs)
+            boot_nodes(ring, [0], deadline, *how, boots, False)
+        others = list(range(1, len(ring.nodes)))
+        if others:
+            deadline = max(deadline, t_exec + BOOT_TIMEOUT)
+            built = boot_nodes(ring, others, deadline, *how, boots, True)
+            if built:
+                deadline = set_aside(ring, built)
+                boot_nodes(ring, built, deadline, *how, boots, False)
+    except BaseException:
+        ring.stop(10.0)
+        raise
+    return ring, ring.devices[0], named, boots
 
 
-def start_fleet(d: Daemon, kind, seed: int, seconds: float, tag: str,
+def start_fleet(ring: Ring, kind, seed: int, seconds: float, tag: str,
                 cell: dict, config: dict, traffic: dict, read_share: float = 1.0):
     """The generator processes of one window, built and connected:
-    (the spec each was given, the fleet, what each said when ready)."""
+    (the spec each was given, the fleet, what each said when ready).
+    `grpc` and `geb` are node 0's; `nodes` has every node's doors."""
     spec = {
         "seed": seed, "seconds": seconds, "tag": tag, "cell": cell,
-        "config": config, "traffic": traffic, "grpc": d.grpc, "geb": d.geb,
-        "door": kind.DOOR, "read_share": read_share,
+        "config": config, "traffic": traffic, "grpc": ring.grpc, "geb": ring.geb,
+        "door": kind.DOOR, "read_share": read_share, "nodes": ring.addrs,
     }
     fleet = workers.Fleet(
         traffic["generator"],
@@ -271,31 +325,41 @@ def start_fleet(d: Daemon, kind, seed: int, seconds: float, tag: str,
         raise
 
 
-def snapshot(d: Daemon) -> dict:
-    return {"stages": d.stages(), "prom": d.prom()}
+def snapshot(ring: Ring) -> dict:
+    """Every node's stages and counters, one request each; node 0's at
+    the top as well."""
+    nodes = [{"stages": d.stages(), "prom": d.prom()} for d in ring.nodes]
+    return dict(nodes[0], nodes=nodes)
 
 
-def window(args, d: Daemon, fleet, cell: dict, traffic: dict, t_exec: float):
+def programs_built(ring: Ring) -> int:
+    return sum(d.compiles()["programs"] for d in ring.nodes)
+
+
+def window(args, ring: Ring, fleet, cell: dict, traffic: dict, t_exec: float):
     """Warm-up, then the measured window. Returns what was read at its
     ends; the generators' own results are collected after it."""
     t0 = time.monotonic() + traffic["warmup_s"] + 0.25
     fleet.go(t0)
     workers.wait_until(t0)
-    w = {"setup_s": t0 - t_exec, "snap0": snapshot(d),
-         "compiles0": d.compiles()["programs"]}
+    w = {"setup_s": t0 - t_exec, "snap0": snapshot(ring),
+         "compiles0": programs_built(ring)}
     prof, thread = {}, None
     if args.trace:
         workers.wait_until(t0 + READ_SHARE * args.seconds)
-        w["snap1"] = snapshot(d)
+        w["snap1"] = snapshot(ring)
         ms = int(min(cell["trace_ms"], args.seconds * 1000 * 0.3))
         prof["t_start"] = time.monotonic()
         thread = threading.Thread(
-            target=capture_profile, args=(d, profile_name(args), ms, prof)
+            target=capture_profile,
+            args=(ring.nodes[cell.get("trace_node", 0)], profile_name(args),
+                  ms, prof),
         )
         thread.start()
     workers.wait_until(t0 + args.seconds)
+    ring.check_alive()  # before any door is asked through a dead node
     if not args.trace:
-        w["snap1"] = snapshot(d)
+        w["snap1"] = snapshot(ring)
     w["seconds"] = time.monotonic() - t0
     w["results"] = fleet.results(traffic["drain_timeout_s"])
     if thread is not None:
@@ -303,9 +367,24 @@ def window(args, d: Daemon, fleet, cell: dict, traffic: dict, t_exec: float):
         if "error" in prof or thread.is_alive():
             raise BenchFailure(f"profile capture failed: {prof.get('error')}")
         w["capture_s"] = prof["t_end"] - prof["t_start"]
-    w["prom_end"] = d.prom()
-    w["compiled"] = d.compiles()["programs"] - w["compiles0"]
+    w["prom_end"] = [d.prom() for d in ring.nodes]
+    w["compiled"] = programs_built(ring) - w["compiles0"]
     return w
+
+
+def layer_ctx(snap0: dict, snap1: dict, reduced: dict, generator: dict,
+              device_kind: str, config: dict, cell: dict) -> dict:
+    """What a reader is handed: node 0's two snapshots at the top, and
+    for a ring every node's under `nodes` (readers/nodes.py chooses)."""
+    def ends(a, b):
+        return {"stages0": a["stages"], "stages1": b["stages"],
+                "prom0": a["prom"], "prom1": b["prom"]}
+
+    ctx = {**ends(snap0, snap1), "trace": reduced, "generator": generator,
+           "device_kind": device_kind, "config": config, "cell": cell}
+    if len(snap0["nodes"]) > 1:
+        ctx["nodes"] = [ends(a, b) for a, b in zip(snap0["nodes"], snap1["nodes"])]
+    return ctx
 
 
 def profile_name(args) -> str:
@@ -323,14 +402,15 @@ def run(args, t_exec: float) -> int:
     shutil.rmtree(profile_dir, ignore_errors=True)
 
     tag = f"s{args.seed}"
-    d, device, named, boots = boot(args, config, t_exec)
+    ring, device, named, boots = boot(args, config, t_exec)
+    n_nodes = len(ring.nodes)
     phases["boot"] = sum(boots)
     rehearsal = device["platform"] != "tpu"
     fleet = doors = None
     try:
         from harness.doors import Doors
 
-        doors = Doors(d)
+        doors = Doors(ring)
         rules = keyspace.KeyRules(traffic)
         t = t_preload = time.monotonic()
         wrong = preload(doors, tag, rules, config["preload_keys"])
@@ -346,14 +426,14 @@ def run(args, t_exec: float) -> int:
 
         t = time.monotonic()
         spec, fleet, ready = start_fleet(
-            d, kind, args.seed, args.seconds, tag, cell, config, traffic,
+            ring, kind, args.seed, args.seconds, tag, cell, config, traffic,
             READ_SHARE if args.trace else 1.0,
         )
         phases["generators"] = time.monotonic() - t
         phases["warmup"] = traffic["warmup_s"]
         emit(phase="generators_ready", seconds=phases["generators"], workers=ready)
 
-        w = window(args, d, fleet, cell, traffic, t_exec)
+        w = window(args, ring, fleet, cell, traffic, t_exec)
         results = w.pop("results")
         summary = kind.summarize(results, spec)
         first_sent = min(r["first_sent"] for r in results)
@@ -362,27 +442,29 @@ def run(args, t_exec: float) -> int:
                         1e3 * (first_sent - t_preload), int(time.time() * 1000))
         post["tallies"]["span_ms"] = span_ms
         post["tallies"]["since_preload_ms"] = span_ms + 1e3 * (first_sent - t_preload)
-        after = d.stages()["device"]
+        after = [d.stages()["device"] for d in ring.nodes]
         doors.close()
         doors = None
         fleet.close()
         fleet = None
-        rc = d.stop()
+        rc = ring.stop()
     finally:
         if doors is not None:
             doors.close()
         if fleet is not None:
             fleet.close()
-        d.stop(10.0)
+        ring.stop(10.0)
 
     # evictions and dropped creates are held to the whole run, not only
-    # the window: the preload must not have cost a live key either
+    # the window: the preload must not have cost a live key either; on
+    # every node
     counters = {
-        name: w["prom_end"].get(name, 0.0)
+        name: sum(prom.get(name, 0.0) for prom in w["prom_end"])
         for name in ("store_evictions_total", "store_dropped_creates_total")
     }
     emit(phase="window", seconds=w["seconds"], setup_s=w["setup_s"],
-         phases=phases, generator=summary["generator"], daemon_exit=rc)
+         phases=phases, generator=summary["generator"], daemon_exit=rc,
+         **({"node_exits": ring.exits} if n_nodes > 1 else {}))
     emit(phase="post_window_check", **post, counters_whole_run=counters,
          counters_limit=0, programs_compiled_in_window=w["compiled"])
     if w["compiled"]:
@@ -409,21 +491,21 @@ def run(args, t_exec: float) -> int:
               + ", ".join(k for k, ok in held.items() if not ok)
               + f"; {json.dumps(post)[:1500]}", file=sys.stderr)
 
-    peaks = [x.get("peak_bytes_in_use") for x in after["devices"]]
+    peaks = [x.get("peak_bytes_in_use") for a in after for x in a["devices"]]
     dev = {
         "platform": device["platform"], "kind": device["kind"],
-        "count": device["count"],
+        "count": sum(x["count"] for x in ring.devices),
         "memory_peak_bytes": max((p for p in peaks if p), default=None),
     }
+    if n_nodes > 1:
+        dev["nodes"] = n_nodes
     line = {"correct": correct, "attempted": summary["attempted"],
             "failed": summary["failed"], "metrics": {}, "device": dev}
     if args.trace:
         reduced = reduce_trace(profile_dir, cell["trace_match"])
         shutil.rmtree(profile_dir, ignore_errors=True)
-        ctx = {"stages0": w["snap0"]["stages"], "stages1": w["snap1"]["stages"],
-               "prom0": w["snap0"]["prom"], "prom1": w["snap1"]["prom"],
-               "trace": reduced, "generator": summary["generator"],
-               "device_kind": device["kind"], "config": config, "cell": cell}
+        ctx = layer_ctx(w["snap0"], w["snap1"], reduced, summary["generator"],
+                        device["kind"], config, cell)
         dev["busy_s"] = reduced["busy_s"]
         dev["window_s"] = reduced["window_s"]
         line["breakdown"] = {"device_ops": reduced["device_ops"][:10],

@@ -4,7 +4,8 @@ HBM bytes are the bound (a decide step does a few integer operations
 per byte it moves). What the algorithm NEEDS is the bucket row of every
 item read and written back, the sketch cells of the items the exact
 tier refused, and the request and response arrays -- not the size of
-the table. A step that rewrites the whole table (ROADMAP S3) shows as a
+the table. A step that passes over the whole table (ROADMAP S1: the
+writeback's sorted scatter until PR 31; no step does now) shows as a
 small share of this roofline: that is what the share is there to expose.
 
 Distinct bucket rows touched <= items; the items are counted, so the

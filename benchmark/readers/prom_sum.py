@@ -5,17 +5,22 @@ spec: {"delta": [name, ...], "per_delta": [name, ...], "scale": 1.0}
 value = sum of the first list's growth / sum of the second's * scale.
 A program that does not export one of the named counters (the parent of
 the PR that added it) reads as nothing, and so does a window in which
-the denominator did not move.
+the denominator did not move. With "node": "all" the growths are summed
+over the ring's nodes (readers/nodes.py).
 """
+
+from readers import nodes
 
 
 def read(spec: dict, ctx: dict):
     names = [*spec["delta"], *spec["per_delta"]]
-    if any(name not in ctx["prom1"] for name in names):
+    chosen = nodes.chosen(spec, ctx)
+    if any(name not in n["prom1"] for name in names for n in chosen):
         return None
 
     def growth(which):
-        return sum(ctx["prom1"][n] - ctx["prom0"].get(n, 0.0) for n in which)
+        return sum(n["prom1"][name] - n["prom0"].get(name, 0.0)
+                   for name in which for n in chosen)
 
     per = growth(spec["per_delta"])
     if per <= 0:

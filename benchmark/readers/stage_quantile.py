@@ -7,16 +7,27 @@ spec: {"stage": name, "q": 0.5, "scale": 1e3}
 value = the q-quantile in seconds * scale, linear inside the bucket it
 falls in; the open last bucket reads as its lower edge. Nothing
 recorded in the window, or a program that prints no buckets -> None.
+With "node": "all" the ring's nodes' samples are pooled (readers/nodes.py).
 """
+
+from readers import nodes
+
+
+def _window_counts(n: dict, stage: str):
+    after = n["stages1"]["stages"].get(stage, {}).get("buckets")
+    if not after:
+        return None
+    before = n["stages0"]["stages"].get(stage, {}).get("buckets")
+    return [b - a for a, b in zip(before or [0] * len(after), after)]
 
 
 def read(spec: dict, ctx: dict):
+    chosen = nodes.chosen(spec, ctx)
+    per_node = [c for c in (_window_counts(n, spec["stage"]) for n in chosen) if c]
     edges = ctx["stages1"].get("bucket_edges_s")
-    after = ctx["stages1"]["stages"].get(spec["stage"], {}).get("buckets")
-    if not edges or not after:
+    if not edges or not per_node:  # a node that never saw the stage pools nothing
         return None
-    before = ctx["stages0"]["stages"].get(spec["stage"], {}).get("buckets")
-    counts = [b - a for a, b in zip(before or [0] * len(after), after)]
+    counts = [sum(c) for c in zip(*per_node)]
     total = sum(counts)
     if total <= 0:
         return None

@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
-"""The one sweep that finds an open-loop cell's knee: one daemon, rising
-steps of a few seconds each, every step on fresh keys. Run once, on the
-chip, when a cell is defined; the rate it finds is then FIXED in the
-cell's file (the benchmark never searches for a rate).
+"""The one sweep that finds an open-loop cell's knee: the cell's daemons
+(one, or the configuration's ring: load, checks and readings at node 0),
+rising steps of a few seconds each, every step on fresh keys. Run once,
+on the chip, when a cell is defined; the rate it finds is then FIXED in
+the cell's file (the benchmark never searches for a rate).
 
     python3 benchmark/sweep.py --workload <cell> --rates 1000,2000,... [--seconds 10]
 
@@ -27,7 +28,7 @@ def delta(before: dict, after: dict, name: str) -> float:
 
 def main() -> int:
     from harness import bench, keyspace, workers
-    from harness.daemon import Daemon
+    from harness.daemon import Ring
     from harness.doors import Doors
 
     ap = argparse.ArgumentParser()
@@ -46,17 +47,21 @@ def main() -> int:
         traffic["call_timeout_s"] = args.call_timeout
     kind = workers.load_kind(traffic["generator"])
     bench.build_native()
-    d = Daemon(args.workload + ".sweep", config["env"], bench.OUT_DIR)
+    ring = Ring(args.workload + ".sweep", config, bench.OUT_DIR)
     fleet = doors = None
     try:
-        d.wait_ready(time.monotonic() + bench.BOOT_TIMEOUT)
-        doors = Doors(d)
+        for i in range(len(ring.specs)):
+            ring.start(i)
+        for d in ring.nodes:
+            d.wait_ready(time.monotonic() + bench.BOOT_TIMEOUT, len(ring.nodes))
+        d = ring.nodes[0]
+        doors = Doors(ring)
         rules = keyspace.KeyRules(traffic)
         for step, rate in enumerate(float(r) for r in args.rates.split(",")):
             tag = f"sweep{step}"
             bench.preload(doors, tag, rules, config["preload_keys"])
             spec, fleet, _ = bench.start_fleet(
-                d, kind, args.seed + step, args.seconds, tag,
+                ring, kind, args.seed + step, args.seconds, tag,
                 dict(cell, rate=rate), config, traffic)
             t0 = time.monotonic() + traffic["warmup_s"] + 0.25
             fleet.go(t0)
@@ -84,7 +89,7 @@ def main() -> int:
             fleet.close()
         if doors is not None:
             doors.close()
-        d.stop()
+        ring.stop()
     return 0
 
 

@@ -102,7 +102,7 @@ def test_every_peer_metric_names_the_cell_and_a_reader():
         for key in set(old) - {"cells", "what", "layer", "moves"}:
             assert spec[key] == old[key], (name, key)
     e2e = next(m for m in bench["end_to_end"] if m["name"] == "decisions_per_s")
-    assert e2e["workloads"][-1] == CELL and e2e["bound"] == 0.05
+    assert e2e["workloads"][-1] == CELL and e2e["bound"] == 0.1  # 0.05 until PR 38
 
 
 def test_the_new_readings_by_hand_and_on_a_program_without_them():
