@@ -120,29 +120,75 @@ def canary_verdict(key, limit, algo, replies, peek, now_ms: int):
 def tally_faults(ids, offered, admitted, in_doubt, limit, duration, algo,
                  span_ms: float, lead_ms: float = 0.0):
     """Arrays over the keys the run touched (hits = 1 everywhere).
-    Returns the count of keys outside their bounds and the first few.
+    Returns the count of keys outside their bounds, the first few, the
+    token keys held exactly, and what is reported beside them and judges
+    nothing (`leaky_over_steady`, `closest`: below).
 
     `span_ms` runs from the generators' first send to their last reply,
     `lead_ms` from the start of the preload (which creates the keys, so
     a window may already be open at the first send) to that first send.
-    upper: a token window admits `limit`, and the windows that can be
+
+    upper, token: a window admits `limit`, and the windows that can be
     open in the span are one that the preload left open plus those that
     open in it, and never more than fit between the preload's start and
-    the last reply; a leaky bucket holds at most `limit` at the first
-    send and gains one hit per `duration // limit` ms.
+    the last reply (`windows`).
+
+    upper, leaky: limit x creations that fit + ticks that fit + 1, the
+    most reference.py's `_leaky` can admit, line by line:
+    (i) a request admits only tokens the bucket holds, and it holds what
+        a creation granted and what leaked since. A leak is
+        `(now - stamp) // rate` at a request that carries hits, which
+        then moves `stamp` to `now`: floors over disjoint gaps between
+        such requests, so all the leaks of a span sum to at most
+        `span // rate` (a key hammered faster than its tick leaks
+        nothing: every gap floors to 0). The gap that ends at the first
+        send began at the preload and is lost to `min(., limit)`: the
+        preload left the bucket full.
+    (ii) a creation grants `limit`. A bucket is created when the request
+        finds `expire < now`, and `expire` is `duration` after the
+        creation or a later admit, so creations lie more than `duration`
+        apart: 1 + span // duration fit in the span. The bucket the
+        preload left is no extra grant when limit >= 2: its first admit
+        takes the `hits < remaining` branch, which moves `expire` to
+        now + duration, so from there it stands for the span's first
+        creation. With limit == 1 that admit is `hits == remaining`,
+        `expire` stays, and the bucket may expire and be created again
+        at once: `windows`, as for a token key.
+    (iii) the two ADD, because the admit through `hits == remaining`
+        (the bucket's last token) leaves `expire` where it was. The
+        schedule that shows it, 10 a second (rate 100 ms): t = 0 created,
+        ten hits admitted, the ninth left expire = 1000; t = 100, 200,
+        ... 1000: one token leaked, admitted as the last, expire still
+        1000; t = 1001: expired, created FULL, ten admitted. 20 a
+        second where a leaky bucket would give 10, all through the run.
+    No schedule does better (benchmark/tests/test_reference.py: 1,200
+    seeded schedules never pass it, the adaptive one above reaches 99%).
+    The form before PR 39, `limit + span // rate + 1` (a bucket that
+    never expires), is what the reference itself passes on a key it sees
+    about once a tick: a door node that thins the stream, a pause in the
+    served path. What this costs the check's sight: PERF.md section 2.
+
     lower: one window's worth, less hits whose answer was lost.
-    A token key whose window outlasts preload and span has upper == lower."""
+    A token key whose window outlasts preload and span has upper == lower.
+
+    Reported, not judged: `leaky_over_steady`, the leaky keys above the
+    old form (0 while every leaky key is hammered; a later change that
+    moves it shows here), and `closest`, for each algorithm and key
+    class the key that came nearest the bound it was judged by (keys
+    whose offer is the bound, and token keys held exactly, say nothing
+    and are left out), a leaky key with the old form beside it."""
     span = int(span_ms) + 1
     whole = span + int(lead_ms) + 1
     windows = np.minimum(2 + span // duration, 1 + whole // duration)
-    upper = np.where(
-        algo == TOKEN, limit * windows,
-        limit + span // np.maximum(duration // np.maximum(limit, 1), 1) + 1,
-    )
-    upper = np.minimum(offered, upper)
+    ticks = span // np.maximum(duration // np.maximum(limit, 1), 1) + 1
+    creations = np.where(limit > 1, 1 + span // duration, windows)
+    steady = limit + ticks
+    bound = np.where(algo == TOKEN, limit * windows, limit * creations + ticks)
+    upper = np.minimum(offered, bound)
     lower = np.minimum(offered, np.maximum(limit - in_doubt, 0))
     bad = (admitted > upper) | (admitted < lower)
-    exact = int(np.sum((algo == TOKEN) & (windows == 1) & (offered > limit)))
+    held = (algo == TOKEN) & (windows == 1)
+    exact = int(np.sum(held & (offered > limit)))
     faults = [
         {"id": int(ids[i]), "offered": int(offered[i]),
          "admitted": int(admitted[i]), "lower": int(lower[i]),
@@ -150,7 +196,23 @@ def tally_faults(ids, offered, admitted, in_doubt, limit, duration, algo,
          "duration_ms": int(duration[i])}
         for i in np.flatnonzero(bad)[:5]
     ]
-    return int(bad.sum()), faults, exact
+    closest = []
+    judged = (offered > bound) & ~held
+    for a, li, d in sorted(set(zip(algo[judged].tolist(), limit[judged].tolist(),
+                                   duration[judged].tolist()))):
+        of = np.flatnonzero(judged & (algo == a) & (limit == li) & (duration == d))
+        i = of[np.argmin(bound[of] - admitted[of])]
+        closest.append({
+            "algo": a, "limit": li, "duration_ms": d, "keys": int(len(of)),
+            "id": int(ids[i]), "offered": int(offered[i]),
+            "admitted": int(admitted[i]), "upper": int(bound[i]),
+            **({} if a == TOKEN else {"steady": int(steady[i])}),
+        })
+    seen = {
+        "leaky_over_steady": int(np.sum((algo != TOKEN) & (admitted > steady))),
+        "closest": closest,
+    }
+    return int(bad.sum()), faults, exact, seen
 
 
 def malformed(status, limit, remaining, want_limit) -> int:

@@ -121,12 +121,13 @@ async def _run(spec: dict, conn) -> None:
             plain_frames += n_global == 0
             if canary >= 0:
                 tally.canary_answered(canary, resps.pop())
-            tally.answered(
-                row, [r.status for r in resps], [r.limit for r in resps],
-                [r.remaining for r in resps],
-            )
+            fields = ([r.status for r in resps], [r.limit for r in resps],
+                      [r.remaining for r in resps])
             if any(r.error for r in resps):
-                tally.malformed += 1
+                tally.answered_with_errors(
+                    row, *fields, [bool(r.error) for r in resps])
+            else:
+                tally.answered(row, *fields)
 
     first = time.monotonic()
     await asyncio.gather(*[lane() for _ in range(t["inflight"])])

@@ -69,6 +69,14 @@ def answers(raw: bytes):
             [r.remaining for r in rs], any(r.error for r in rs))
 
 
+def item_errors(raw: bytes):
+    """Which items of one serialised GetPeerRateLimitsResp are errors."""
+    from gubernator_tpu.api.proto.gen import peers_pb2
+
+    return [bool(r.error) for r in
+            peers_pb2.GetPeerRateLimitsResp.FromString(raw).rate_limits]
+
+
 async def peer_door_check(call, seed: int, algos, timeout: float) -> dict:
     """check.py's seeded sequence through the peer door, item by item
     against the plain reference."""
@@ -159,8 +167,13 @@ async def _run(spec: dict, conn) -> None:
         if canary >= 0:
             tally.canary_replies[canary].append(
                 (status.pop(), limit.pop(), remaining.pop()))
-        tally.answered(row, status, limit, remaining)
-        tally.malformed += error
+        if error:
+            errors = item_errors(raw)
+            if canary >= 0:  # an error in the canary's place is malformed too
+                tally.malformed += errors.pop()
+            tally.answered_with_errors(row, status, limit, remaining, errors)
+        else:
+            tally.answered(row, status, limit, remaining)
     last = time.monotonic()
     cpu_share = (time.process_time() - cpu0) / (last - first)
     await channel.close()

@@ -159,7 +159,7 @@ def verdicts(results, spec, doors, span_ms: float, lead_ms: float,
                 canary_faults.append(fault)
     ids, offered, admitted, in_doubt = workers.merge_tallies(results)
     limit, duration, algo = rules.of(ids)
-    n_bad, faults, exact = check.tally_faults(
+    n_bad, faults, exact, seen = check.tally_faults(
         ids, offered, admitted, in_doubt, limit, duration, algo, span_ms,
         lead_ms
     )
@@ -169,8 +169,9 @@ def verdicts(results, spec, doors, span_ms: float, lead_ms: float,
                      "first": canary_faults[:3]},
         "tallies": {"keys": int(len(ids)), "hits_offered": int(offered.sum()),
                     "hits_admitted": int(admitted.sum()),
+                    "hits_in_doubt": int(in_doubt.sum()),
                     "keys_held_exactly": exact, "outside_bounds": n_bad,
-                    "limit": 0, "first": faults},
+                    "limit": 0, "first": faults, **seen},
         "malformed": {"replies": sum(r["tally"]["malformed"] for r in results),
                       "limit": 0},
     }
@@ -486,6 +487,20 @@ def run(args, t_exec: float) -> int:
         "no failed call": summary["failed"] == 0,
     }
     correct = all(held.values())
+    # every number compared, beside its limit: the run's last lines on
+    # standard error and the last key of its result line
+    compared = {
+        "preload_wrong": wrong, "pre_window_differ": pre["differ"],
+        "canaries_differ": post["canaries"]["differ"],
+        "tallies_outside_bounds": post["tallies"]["outside_bounds"],
+        "malformed_replies": post["malformed"]["replies"],
+        "evictions": counters["store_evictions_total"],
+        "dropped_creates": counters["store_dropped_creates_total"],
+        "failed": summary["failed"],
+    }
+    checks = {name: {"value": v, "limit": 0} for name, v in compared.items()}
+    checks["pre_window_over_limit_answers"] = {
+        "value": pre["over_limit_answers"], "at_least": 1}
     if not correct:
         print("benchmark: not correct: "
               + ", ".join(k for k, ok in held.items() if not ok)
@@ -526,5 +541,8 @@ def run(args, t_exec: float) -> int:
         # a named non-TPU platform: the run proves the harness and the
         # checks, and reports no timing under a device metric's name
         line["rehearsal"] = named
+    line["checks"] = checks
+    for name, c in checks.items():
+        print(f"benchmark: compared {name}: {json.dumps(c)}", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 3 if rehearsal else 0

@@ -104,8 +104,8 @@ def wait_until(t: float) -> None:
 class Tally:
     """Per key, over everything one worker sent (warm-up included, so a
     key's whole history is counted): hits offered in answered calls,
-    hits admitted, hits whose answer was lost; and replies that were
-    not well-formed. Canary keys are kept apart, reply by reply."""
+    hits admitted, hits whose answer was lost or an error; and replies
+    that were not well-formed. Canary keys are kept apart, reply by reply."""
 
     def __init__(self, spec: dict, ids: np.ndarray):
         self.rules = keyspace.KeyRules(spec["traffic"])
@@ -136,6 +136,20 @@ class Tally:
         )
         np.add.at(self.offered, idx, 1)
         np.add.at(self.admitted, idx[status == 0], 1)
+
+    def answered_with_errors(self, ids, status, limit, remaining, error) -> None:
+        """One answered frame in which some items came back as errors
+        (`error`: truth per item). An error item is no answer: its status
+        field reads 0 and is not an admitted hit, and the hit may or may
+        not have reached its owner. So each counts as in doubt and as one
+        malformed reply (the run is not correct, and says by how many
+        items); the rest of the frame is tallied as answered."""
+        bad = np.asarray(error, bool).ravel()
+        ids = np.asarray(ids).ravel()
+        self.malformed += int(bad.sum())
+        self.lost(ids[bad])
+        self.answered(ids[~bad], *(np.asarray(x).ravel()[~bad]
+                                   for x in (status, limit, remaining)))
 
     def lost(self, ids) -> None:
         np.add.at(self.in_doubt, np.searchsorted(self.pool, np.ravel(ids)), 1)

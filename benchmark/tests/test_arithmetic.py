@@ -111,15 +111,17 @@ def test_zipf_recipe_is_the_programs():
 
 
 def test_tally_bounds():
-    ids = np.arange(6)
-    limit = np.array([1000, 1000, 100, 100, 10, 1000])
-    duration = np.array([3_600_000, 3_600_000, 60_000, 60_000, 1000, 3_600_000])
-    algo = np.array([TOKEN, TOKEN, TOKEN, TOKEN, TOKEN, LEAKY])
-    offered = np.array([5000, 5000, 500, 50, 900, 5000])
-    zero = np.zeros(6, np.int64)
+    ids = np.arange(7)
+    limit = np.array([1000, 1000, 100, 100, 10, 1000, 10])
+    duration = np.array([3_600_000, 3_600_000, 60_000, 60_000, 1000, 3_600_000, 1000])
+    algo = np.array([TOKEN, TOKEN, TOKEN, TOKEN, TOKEN, LEAKY, LEAKY])
+    offered = np.array([5000, 5000, 500, 50, 900, 5000, 5000])
+    zero = np.zeros(7, np.int64)
     span = 40_000.0  # ms: 1 h keys see one window, 60 s keys one, 1 s keys 41
-    ok = np.array([1000, 1000, 100, 50, 410, 1012])
-    n, _, exact = check.tally_faults(ids, offered, ok, zero, limit, duration, algo, span)
+    # the 1 h leaky key is created once and 40 s / 3.6 s a hit = 11 leak, + 1;
+    # the 1 s one is created 41 times and 40 s / 100 ms a hit = 400 leak, + 1
+    ok = np.array([1000, 1000, 100, 50, 410, 1012, 10 * 41 + 400 + 1])
+    n, _, exact, _ = check.tally_faults(ids, offered, ok, zero, limit, duration, algo, span)
     assert n == 0 and exact == 3  # the two 1 h token keys and the 60 s key
     one_over = ok.copy(); one_over[0] += 1  # under-admission by a single hit
     assert check.tally_faults(ids, offered, one_over, zero, limit, duration, algo, span)[0] == 1
@@ -127,8 +129,48 @@ def test_tally_bounds():
     assert check.tally_faults(ids, offered, one_short, zero, limit, duration, algo, span)[0] == 1
     lost = zero.copy(); lost[1] = 1  # ... unless its answer was lost
     assert check.tally_faults(ids, offered, one_short, lost, limit, duration, algo, span)[0] == 0
-    leaky_over = ok.copy(); leaky_over[5] = 1013  # 40 s / 3.6 s a hit = 11, + 1
-    assert check.tally_faults(ids, offered, leaky_over, zero, limit, duration, algo, span)[0] == 1
+    for key in (5, 6):  # a leaky key AT its bound is sound, one over is a fault
+        leaky_over = ok.copy(); leaky_over[key] += 1
+        n, first, _, _ = check.tally_faults(
+            ids, offered, leaky_over, zero, limit, duration, algo, span)
+        assert n == 1 and first[0]["id"] == key and first[0]["upper"] == ok[key]
+
+
+def test_tally_reports_the_old_leaky_form_and_the_closest_keys():
+    """Reported, not judged: keys above the form before PR 39
+    (limit + span // rate + 1) and, by algorithm and class, the key
+    nearest the bound that judged it."""
+    limit = np.array([10, 10, 10, 10, 1000, 100, 10, 10, 100])
+    duration = np.array([1000] * 4 + [3_600_000, 60_000, 1000, 1000, 60_000])
+    algo = np.array([LEAKY] * 6 + [TOKEN] * 3)
+    ids = np.arange(11, 20)
+    offered = np.array([5000, 5000, 5000, 300, 5000, 90, 5000, 5000, 5000])
+    #          old form 10 + 400 + 1 = 411; bound 10 x 41 + 401 = 811
+    admitted = np.array([411, 412, 600, 300, 1000, 90, 380, 409, 100])
+    n, first, exact, seen = check.tally_faults(
+        ids, offered, admitted, np.zeros(9, np.int64), limit, duration, algo,
+        40_000.0)
+    assert (n, first, exact) == (0, [], 1)
+    assert seen["leaky_over_steady"] == 2  # keys 12 and 13; key 14's offer is under it
+    assert seen["closest"] == [
+        # token, 10 a second: 41 windows of 10; key 18 came nearest
+        {"algo": TOKEN, "limit": 10, "duration_ms": 1000, "keys": 2, "id": 18,
+         "offered": 5000, "admitted": 409, "upper": 410},
+        # (the 60 s token key is held exactly: it says nothing here)
+        # leaky, 10 a second: three keys driven over the bound, 13 nearest
+        {"algo": LEAKY, "limit": 10, "duration_ms": 1000, "keys": 3, "id": 13,
+         "offered": 5000, "admitted": 600, "upper": 811, "steady": 411},
+        # (the 60 s leaky key was offered 90 of 100: its offer is its bound)
+        {"algo": LEAKY, "limit": 1000, "duration_ms": 3_600_000, "keys": 1,
+         "id": 15, "offered": 5000, "admitted": 1000, "upper": 1012,
+         "steady": 1012},
+    ]
+    # a key over the old form and under the new is sound; over the new, a fault
+    admitted[2] = 812
+    n, first, _, seen = check.tally_faults(
+        ids, offered, admitted, np.zeros(9, np.int64), limit, duration, algo,
+        40_000.0)
+    assert n == 1 and first[0]["id"] == 13 and seen["leaky_over_steady"] == 2
 
 
 @pytest.mark.parametrize("lead_ms,admitted,faults", [
@@ -137,7 +179,7 @@ def test_tally_bounds():
 ])
 def test_tally_bounds_count_the_window_the_preload_opened(lead_ms, admitted, faults):
     one = lambda v: np.array([v])
-    n, _, exact = check.tally_faults(
+    n, _, exact, _ = check.tally_faults(
         one(1), one(500), one(admitted), one(0), one(100), one(60_000),
         one(TOKEN), 40_000.0, lead_ms)
     assert n == faults and exact == (lead_ms < 20_000)

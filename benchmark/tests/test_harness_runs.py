@@ -72,7 +72,11 @@ def checkout(tmp_path_factory):
 
 
 def run(root, cell, *extra, trace=0, seed=2**31 + 7):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # a TMPDIR of the checkout's own: the daemon's profile capture lands
+    # under <TMPDIR>/guber-profile/bench_<cell>, and two test files that
+    # run one cell name side by side (`pytest -n 4`) met there
+    os.makedirs(root / "tmp", exist_ok=True)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(root / "tmp"))
     env.pop("XLA_FLAGS", None)
     p = subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload", cell, "--seed",

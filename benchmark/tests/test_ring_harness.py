@@ -365,7 +365,11 @@ def checkout(tmp_path_factory):
 
 
 def run(root, cell, *extra, trace=0, seed=2**31 + 38):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # a TMPDIR of the checkout's own: the daemon's profile capture lands
+    # under <TMPDIR>/guber-profile/bench_<cell>, and two test files that
+    # run one cell name side by side (`pytest -n 4`) met there
+    os.makedirs(root / "tmp", exist_ok=True)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(root / "tmp"))
     env.pop("XLA_FLAGS", None)
     p = subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload", cell, "--seed",
@@ -428,7 +432,8 @@ def test_a_node_lost_under_load_is_a_failure_that_names_it(checkout):
 
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
-               "breakdown", "rehearsal"}  # the parent's, traced, on the CPU
+               "breakdown", "rehearsal",  # the parent's, traced, on the CPU
+               "checks"}  # PR 39: every number compared beside its limit, last
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes", "busy_s",
                "window_s"}
 PHASE_KEYS = {  # the parent's lines, in order
@@ -454,12 +459,25 @@ def test_one_daemon_prints_the_parents_lines_key_for_key(checkout):
     last = lines.pop()
     assert set(last) == RESULT_KEYS and set(last["device"]) == DEVICE_KEYS
     assert last["correct"] is True and last["device"]["count"] == 1
+    # every number compared beside its limit: the line's LAST key, and
+    # the last lines of standard error
+    assert list(last)[-1] == "checks" and len(last["checks"]) == 9
+    assert all(c["value"] == 0 for c in last["checks"].values() if "limit" in c)
+    assert last["checks"]["pre_window_over_limit_answers"]["value"] >= 1
+    assert [x.split(":")[1].strip() for x in p.stderr.splitlines()[-9:]
+            ] == [f"compared {name}" for name in last["checks"]]
     assert [x["phase"] for x in lines if x["phase"] != "boot"] == list(PHASE_KEYS)[1:]
     for x in lines:
         assert set(x) == PHASE_KEYS[x["phase"]], x["phase"]
     (window,) = phase(lines, "window")
     assert set(window["phases"]) == {"build", "boot", "preload", "check",
                                      "generators", "warmup"}
+    # reported beside the tallies, judging nothing (PR 39)
+    (post,) = phase(lines, "post_window_check")
+    assert post["tallies"]["leaky_over_steady"] == 0
+    assert post["tallies"]["hits_in_doubt"] == 0
+    assert {c["algo"] for c in post["tallies"]["closest"]} == {0, 1}
+    assert all(c["admitted"] <= c["upper"] for c in post["tallies"]["closest"])
     # a one-daemon cell reads node 0 and has no other: "node": 0 and
     # "all" read nothing here only because nobody forwards to one daemon
     assert not [n for n in phase(lines, "trace")[0]["layer_metrics_read"]
