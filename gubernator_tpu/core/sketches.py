@@ -84,6 +84,7 @@ tests read estimates host-side for windows the device charged.
 
 from __future__ import annotations
 
+import ctypes
 import threading
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
@@ -466,29 +467,181 @@ class SpaceSaving:
             self.total = 0
 
 
+#: at or under this many items (HyperLogLog.add_hashes' own threshold)
+#: a batch's hashes go into the native fold as plain ints in a ctypes
+#: array: a 2-item gRPC call pays for no numpy array's address
+_SMALL = 16
+_SMALL_HASHES = [ctypes.c_uint64 * n for n in range(_SMALL + 1)]
+
+
+class NativeHotKeys:
+    """SpaceSaving's summary of str keys behind libguberhash.so: slots
+    of (key hash, count, err, the key's bytes) that one native call per
+    batch folds with the GIL released (guberhash.cc guber_traffic_fold;
+    TrafficStats drives it). After the same batches it tracks the same
+    keys with the same count and err as SpaceSaving — the class above
+    is its oracle (tests/test_traffic_native.py) and what TrafficStats
+    holds where the library is not built. Strings are built here, at
+    scrape time, for the keys asked for. `lock` is TrafficStats' one
+    lock: held across every call on the handle."""
+
+    _handle = None
+
+    def __init__(self, lib, capacity: int, lock):
+        if capacity < 1:
+            raise ValueError(f"capacity {capacity}: at least one slot")
+        self.capacity = capacity
+        self._lib = lib
+        self._lock = lock
+        self._handle = lib.hotkeys_new(capacity)
+        # held here: at interpreter exit the module's names are gone
+        # before the last summary is
+        self._free = lib.hotkeys_free
+
+    def __del__(self):
+        if self._handle is not None:
+            self._free(self._handle)
+
+    def total_and_top(self, n: int = 20):
+        """(items observed, [(key, count, err)] hot-first) of ONE
+        moment: both read under the lock in one export."""
+        with self._lock:
+            total, counts, errs, offsets, keys = self._lib.hotkeys_export(
+                self._handle
+            )
+        order = np.argsort(-counts, kind="stable")[: max(n, 0)]
+        return total, [
+            (
+                keys[offsets[i] : offsets[i + 1]].decode(),
+                int(counts[i]),
+                int(errs[i]),
+            )
+            for i in order.tolist()
+        ]
+
+    @property
+    def total(self) -> int:
+        return self.total_and_top(0)[0]
+
+    def top(self, n: int = 20) -> List[Tuple[str, int, int]]:
+        return self.total_and_top(n)[1]
+
+    def reset(self) -> None:
+        with self._lock:
+            self._lib.hotkeys_reset(self._handle)
+
+
+def _fold_lib():
+    """The native library where it has the observers' fold, else None
+    (not built, or built before the symbol)."""
+    from gubernator_tpu.core.hashing import native_lib
+
+    lib = native_lib()
+    return lib if getattr(lib, "_HAS_TRAFFIC_FOLD", False) else None
+
+
 class TrafficStats:
-    """Per-instance traffic observability: distinct keys + hot keys."""
+    """Per-instance traffic observability: distinct keys + hot keys.
 
-    def __init__(self, hll_p: int = 14, top_capacity: int = 256):
+    One batch is one fold of both sketches. Where libguberhash.so has
+    the symbol it is ONE native call on columns with the GIL released
+    (`implementation` "native": `hot` a NativeHotKeys, the HLL's
+    registers still this object's numpy array); anywhere else, and with
+    `native=False`, the Python classes above fold it ("python") — same
+    registers, same tracked keys, counts and errs either way.
+    `native_folds` / `python_folds` count the batches each folded
+    (plain ints, exported at scrape: traffic_*_folds_total)."""
+
+    def __init__(
+        self, hll_p: int = 14, top_capacity: int = 256, native: bool = True
+    ):
         self.hll = HyperLogLog(hll_p)
-        self.hot = SpaceSaving(top_capacity)
+        self._lib = _fold_lib() if native else None
+        if self._lib is None:
+            self.hot = SpaceSaving(top_capacity)
+        else:
+            # one lock for the registers and the summary: held across
+            # the call, which gives the GIL up
+            self.hot = NativeHotKeys(
+                self._lib, top_capacity, self.hll._lock
+            )
+            self._reg = self.hll._reg.ctypes.data
+        self.native_folds = 0
+        self.python_folds = 0
 
-    def observe(self, keys: List[str], hashes: np.ndarray) -> None:
-        self.hll.add_hashes(hashes)
-        self.hot.observe(keys)
+    @property
+    def implementation(self) -> str:
+        return "python" if self._lib is None else "native"
+
+    def observe(
+        self,
+        keys: List[str],
+        hashes: np.ndarray,
+        packed: Optional[bytes] = None,
+    ) -> None:
+        """Fold one batch: `hashes[i]` the slot hash of `keys[i]`.
+        `packed` is the same keys as UTF-8 joined by NUL, where the
+        caller holds them so (the GEB door's native parse): the native
+        fold then reads no `keys` at all."""
+        if not keys:
+            return
+        lib = self._lib
+        if lib is None:
+            self.python_folds += 1
+            self.hll.add_hashes(hashes)
+            self.hot.observe(keys)
+            return
+        self.native_folds += 1
+        offsets = None
+        if packed is None:
+            if len(keys) != hashes.shape[0]:
+                raise ValueError(
+                    f"{len(keys)} keys for {hashes.shape[0]} hashes"
+                )
+            # one join and one encode, no step per key; a key that
+            # holds a NUL itself (the object path serves those) cannot
+            # be split again, so such a batch is cut by offsets
+            packed = "\x00".join(keys).encode()
+            if packed.count(b"\x00") != len(keys) - 1:
+                packed, offs = lib._pack(keys)
+                offsets = offs.ctypes.data
+        self._fold(self.hot._handle, hashes, packed, offsets)
+
+    def _fold(self, handle, hashes: np.ndarray, packed, offsets) -> None:
+        n = hashes.shape[0]
+        if n <= _SMALL:
+            at = _SMALL_HASHES[n](*hashes.tolist())
+        else:
+            hashes = np.ascontiguousarray(hashes, np.uint64)
+            at = hashes.ctypes.data
+        with self.hll._lock:
+            self._lib.traffic_fold(
+                handle, at, n, packed, offsets, self._reg, self.hll.p
+            )
 
     def observe_hashes(self, hashes: np.ndarray) -> None:
         """Hash-only observation (edge fast path: key strings never
         reach Python). Distinct-key estimation stays exact; hot-key
         NAMES are unavailable for this traffic by design."""
-        self.hll.add_hashes(hashes)
+        if not hashes.size:
+            return
+        if self._lib is None:
+            self.python_folds += 1
+            self.hll.add_hashes(hashes)
+            return
+        self.native_folds += 1
+        self._fold(None, hashes, None, None)
 
     def snapshot(self, top_n: int = 20) -> dict:
+        if self._lib is None:
+            total, top = self.hot.total, self.hot.top(top_n)
+        else:
+            total, top = self.hot.total_and_top(top_n)
         return {
             "distinct_keys_estimate": self.hll.estimate(),
-            "observed_total": self.hot.total,
+            "observed_total": total,
             "hot_keys": [
                 {"key": k, "count": c, "max_overestimate": e}
-                for k, c, e in self.hot.top(top_n)
+                for k, c, e in top
             ],
         }

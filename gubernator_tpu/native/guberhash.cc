@@ -17,6 +17,7 @@
 #include <algorithm>
 #include <cstdint>
 #include <cstring>
+#include <string>
 #include <vector>
 
 namespace {
@@ -1622,6 +1623,267 @@ int64_t guber_encode_peer_answers(const int64_t* status,
     *len_at = static_cast<uint8_t>(p - len_at - 1);  // <= 44
   }
   return p - out;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// The traffic observers' per-batch fold (core/sketches.py TrafficStats):
+// the hot-key summary (Space-Saving) and the HyperLogLog registers take a
+// whole batch - the key hashes the door already holds and the keys' bytes
+// - in ONE GIL-free call: no Python dictionary pass and no heap cascade
+// over 1000 names a frame on the serving loop. core/sketches.py
+// SpaceSaving.observe + observe_weighted and HyperLogLog.add_hashes stay
+// as the fallback where this symbol is absent, and as the oracle: after
+// the same batches the summary here tracks the same keys with the same
+// count and err, and the registers are byte-identical
+// (tests/test_traffic_native.py).
+// ---------------------------------------------------------------------------
+
+namespace {
+
+struct HotSlot {
+  uint64_t hash;
+  int64_t count;
+  int64_t err;
+  int32_t next;  // the next slot of its bucket, -1 at the end
+  std::string key;
+};
+
+// One key of a batch after pre-aggregation.
+struct BatchKey {
+  uint64_t hash;
+  const uint8_t* key;
+  size_t len;
+  int64_t weight;
+};
+
+struct HotKeys {
+  explicit HotKeys(int64_t capacity) : cap(capacity) {
+    size_t b = 16;
+    while (b < static_cast<size_t>(2 * capacity)) b <<= 1;
+    heads.assign(b, -1);
+    slots.reserve(capacity);
+  }
+
+  int64_t cap;
+  int64_t total = 0;
+  std::vector<HotSlot> slots;  // at most cap, never shrinks but by reset
+  std::vector<int32_t> heads;  // bucket -> first slot, by the key's hash
+  // scratch of one fold, kept for its allocations
+  std::vector<BatchKey> agg;
+  std::vector<int32_t> seen;
+  std::vector<int32_t> fresh;
+  std::vector<int32_t> heap;
+
+  size_t bucket(uint64_t h) const { return h & (heads.size() - 1); }
+
+  int32_t find(uint64_t h, const uint8_t* key, size_t len) const {
+    for (int32_t s = heads[bucket(h)]; s >= 0; s = slots[s].next) {
+      const HotSlot& t = slots[s];
+      if (t.hash == h && t.key.size() == len &&
+          std::memcmp(t.key.data(), key, len) == 0)
+        return s;
+    }
+    return -1;
+  }
+
+  void link(int32_t s) {
+    int32_t& head = heads[bucket(slots[s].hash)];
+    slots[s].next = head;
+    head = s;
+  }
+
+  void unlink(int32_t s) {
+    int32_t* at = &heads[bucket(slots[s].hash)];
+    while (*at != s) at = &slots[*at].next;
+    *at = slots[s].next;
+  }
+
+  void put(int32_t s, const BatchKey& k, int64_t floor) {
+    HotSlot& t = slots[s];
+    t.hash = k.hash;
+    t.count = floor + k.weight;
+    t.err = floor;
+    t.key.assign(reinterpret_cast<const char*>(k.key), k.len);
+    link(s);
+  }
+
+  // (count, key) as Python orders the summary's heap of tuples: a str
+  // compares by code point, which is the byte order of its UTF-8
+  bool below(int32_t a, int32_t b) const {
+    const HotSlot& x = slots[a];
+    const HotSlot& y = slots[b];
+    if (x.count != y.count) return x.count < y.count;
+    const size_t common = std::min(x.key.size(), y.key.size());
+    const int c = std::memcmp(x.key.data(), y.key.data(), common);
+    return c != 0 ? c < 0 : x.key.size() < y.key.size();
+  }
+
+  // heap[i] down to its place in the min-heap under it
+  void sink(size_t i) {
+    const size_t n = heap.size();
+    const int32_t v = heap[i];
+    for (size_t c; (c = 2 * i + 1) < n; i = c) {
+      if (c + 1 < n && below(heap[c + 1], heap[c])) ++c;
+      if (!below(heap[c], v)) break;
+      heap[i] = heap[c];
+    }
+    heap[i] = v;
+  }
+};
+
+// rho of one hash into its register: HyperLogLog.add_hashes, an item
+inline void hll_add(uint8_t* reg, int p, uint64_t h) {
+  const uint64_t rem = h << p;
+  const uint8_t rho = static_cast<uint8_t>(
+      rem ? __builtin_clzll(rem) + 1 : 64 - p + 1);
+  uint8_t& r = reg[h >> (64 - p)];
+  if (rho > r) r = rho;
+}
+
+}  // namespace
+
+extern "C" {
+
+void* guber_hotkeys_new(int64_t capacity) {
+  return capacity >= 1 ? new HotKeys(capacity) : nullptr;
+}
+
+void guber_hotkeys_free(void* hot) { delete static_cast<HotKeys*>(hot); }
+
+void guber_hotkeys_reset(void* hot) {
+  HotKeys* s = static_cast<HotKeys*>(hot);
+  s->slots.clear();
+  std::fill(s->heads.begin(), s->heads.end(), -1);
+  s->total = 0;
+}
+
+// Fold one batch of n items into the hot-key summary `hot` and the HLL
+// registers `reg` (uint8[1 << p]); either may be null and is then left
+// out (a pre-hashed frame has no names: registers only). hashes[i] is
+// the slot hash OF key i, whose bytes are keys[offsets[i]:offsets[i+1]]
+// or, where `offsets` is null, the i-th of n NUL-joined keys
+// (guber_parse_string_frame's keys_out as it comes). The summary does
+// what SpaceSaving.observe + observe_weighted do, in their order: the
+// batch pre-aggregated in order of first occurrence, tracked keys add
+// their weight, free slots fill, then each new key replaces the CURRENT
+// minimum by (count, key) and inherits its count as err. Returns 0, or
+// -1 (nothing folded) where the joined keys are not n.
+int64_t guber_traffic_fold(void* hot, const uint64_t* hashes, int64_t n,
+                           const uint8_t* keys, int64_t keys_len,
+                           const int64_t* offsets, uint8_t* reg,
+                           int64_t p) {
+  HotKeys* s = static_cast<HotKeys*>(hot);
+  if (s != nullptr && keys != nullptr && n > 0) {
+    // pre-aggregate: open addressing over the batch, first come first
+    size_t cells = 16;
+    while (cells < static_cast<size_t>(2 * n)) cells <<= 1;
+    s->seen.assign(cells, -1);
+    s->agg.clear();
+    int64_t at = 0;  // the next joined key starts here
+    for (int64_t i = 0; i < n; ++i) {
+      const uint8_t* key;
+      size_t len;
+      if (offsets != nullptr) {
+        key = keys + offsets[i];
+        len = static_cast<size_t>(offsets[i + 1] - offsets[i]);
+      } else {
+        if (at > keys_len) return -1;  // fewer keys than items
+        const void* nul = std::memchr(keys + at, 0, keys_len - at);
+        const int64_t stop =
+            nul ? static_cast<const uint8_t*>(nul) - keys : keys_len;
+        key = keys + at;
+        len = static_cast<size_t>(stop - at);
+        at = stop + 1;
+      }
+      const uint64_t h = hashes[i];
+      size_t c = h & (cells - 1);
+      for (;; c = (c + 1) & (cells - 1)) {
+        const int32_t a = s->seen[c];
+        if (a < 0) {
+          s->seen[c] = static_cast<int32_t>(s->agg.size());
+          s->agg.push_back({h, key, len, 1});
+          break;
+        }
+        BatchKey& k = s->agg[a];
+        if (k.hash == h && k.len == len &&
+            std::memcmp(k.key, key, len) == 0) {
+          ++k.weight;
+          break;
+        }
+      }
+    }
+    if (offsets == nullptr && at != keys_len + 1) return -1;  // more keys
+    s->total += n;
+    s->fresh.clear();
+    for (size_t a = 0; a < s->agg.size(); ++a) {
+      const BatchKey& k = s->agg[a];
+      const int32_t slot = s->find(k.hash, k.key, k.len);
+      if (slot >= 0)
+        s->slots[slot].count += k.weight;
+      else
+        s->fresh.push_back(static_cast<int32_t>(a));
+    }
+    size_t f = 0;
+    for (; f < s->fresh.size() &&
+           static_cast<int64_t>(s->slots.size()) < s->cap; ++f) {
+      s->slots.emplace_back();
+      s->put(static_cast<int32_t>(s->slots.size() - 1),
+             s->agg[s->fresh[f]], 0);
+    }
+    if (f < s->fresh.size()) {
+      // the cascade: a min-heap of the slots by (count, key), whose
+      // root is the victim; its slot takes the new key and sinks
+      s->heap.resize(s->slots.size());
+      for (size_t i = 0; i < s->heap.size(); ++i)
+        s->heap[i] = static_cast<int32_t>(i);
+      for (size_t i = s->heap.size() / 2; i-- > 0;) s->sink(i);
+      for (; f < s->fresh.size(); ++f) {
+        const int32_t victim = s->heap[0];
+        s->unlink(victim);
+        s->put(victim, s->agg[s->fresh[f]], s->slots[victim].count);
+        s->sink(0);
+      }
+    }
+  }
+  if (reg != nullptr)
+    for (int64_t i = 0; i < n; ++i)
+      hll_add(reg, static_cast<int>(p), hashes[i]);
+  return 0;
+}
+
+// The summary's size: tracked keys, and through the pointers the items
+// observed so far and the bytes of the tracked keys together.
+int64_t guber_hotkeys_size(const void* hot, int64_t* total,
+                           int64_t* key_bytes) {
+  const HotKeys* s = static_cast<const HotKeys*>(hot);
+  int64_t bytes = 0;
+  for (const HotSlot& t : s->slots) bytes += t.key.size();
+  *total = s->total;
+  *key_bytes = bytes;
+  return static_cast<int64_t>(s->slots.size());
+}
+
+// Every tracked key, in no order: count and err by slot, the keys' bytes
+// end to end in `keys` with offsets[slot] .. offsets[slot + 1] (the
+// arrays sized from guber_hotkeys_size under the caller's lock).
+// Returns the number of slots written.
+int64_t guber_hotkeys_export(const void* hot, int64_t* counts,
+                             int64_t* errs, int64_t* offsets,
+                             uint8_t* keys) {
+  const HotKeys* s = static_cast<const HotKeys*>(hot);
+  int64_t at = 0;
+  int64_t i = 0;
+  for (const HotSlot& t : s->slots) {
+    counts[i] = t.count;
+    errs[i] = t.err;
+    offsets[i++] = at;
+    std::memcpy(keys + at, t.key.data(), t.key.size());
+    at += t.key.size();
+  }
+  offsets[i] = at;
+  return i;
 }
 
 }  // extern "C"

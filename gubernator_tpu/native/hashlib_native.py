@@ -845,3 +845,73 @@ def encode_peer_answers(status, limit, remaining, reset_time) -> bytes:
         *[c.ctypes.data for c in cols], n, out.ctypes.data,
     )
     return out[:m].tobytes()
+
+
+# -- the traffic observers' per-batch fold (guberhash.cc, last section) ------
+
+try:  # absent in a stale prebuilt .so: TrafficStats keeps its Python classes
+    _lib.guber_hotkeys_new.restype = _vp
+    _lib.guber_hotkeys_new.argtypes = [ctypes.c_int64]
+    _lib.guber_hotkeys_free.restype = None
+    _lib.guber_hotkeys_free.argtypes = [_vp]
+    _lib.guber_hotkeys_reset.restype = None
+    _lib.guber_hotkeys_reset.argtypes = [_vp]
+    _lib.guber_traffic_fold.restype = ctypes.c_int64
+    _lib.guber_traffic_fold.argtypes = [
+        _vp, _vp, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int64, _vp,
+        _vp, ctypes.c_int64,
+    ]
+    _lib.guber_hotkeys_size.restype = ctypes.c_int64
+    _lib.guber_hotkeys_size.argtypes = [_vp] * 3
+    _lib.guber_hotkeys_export.restype = ctypes.c_int64
+    _lib.guber_hotkeys_export.argtypes = [_vp] * 5
+    hotkeys_free = _lib.guber_hotkeys_free
+    _HAS_TRAFFIC_FOLD = True
+except AttributeError:
+    _HAS_TRAFFIC_FOLD = False
+
+
+def hotkeys_new(capacity: int) -> int:
+    """A handle to an empty native hot-key summary of `capacity` slots
+    (hotkeys_free gives it back). The library keeps no lock: the holder
+    serialises every call on one handle."""
+    return _lib.guber_hotkeys_new(capacity)
+
+
+def hotkeys_reset(handle: int) -> None:
+    _lib.guber_hotkeys_reset(handle)
+
+
+def traffic_fold(handle, hashes, n: int, keys, offsets, reg, p) -> None:
+    """Fold one batch of n items into the summary `handle` and the HLL
+    registers at address `reg` (uint8[1 << p]) in one call with the GIL
+    released; either may be None and is left out. `hashes` is n
+    contiguous uint64 (an array's address or a ctypes array),
+    hashes[i] the slot hash of key i; `keys` their UTF-8 bytes end to
+    end, cut by `offsets` (n + 1 int64, likewise) or, with offsets
+    None, joined by NUL as parse_string_frame returns them."""
+    if _lib.guber_traffic_fold(
+        handle, hashes, n, keys, 0 if keys is None else len(keys),
+        offsets, reg, p,
+    ):
+        raise ValueError(f"the joined keys are not {n}")
+
+
+def hotkeys_export(handle):
+    """(total, counts, errs, offsets, keys) of a summary: the items it
+    has observed, and by slot, in no order, each tracked key's count and
+    err and its bytes keys[offsets[i]:offsets[i + 1]]."""
+    total = ctypes.c_int64(0)
+    size = ctypes.c_int64(0)
+    n = _lib.guber_hotkeys_size(
+        handle, ctypes.addressof(total), ctypes.addressof(size)
+    )
+    counts = np.empty(n, np.int64)
+    errs = np.empty(n, np.int64)
+    offsets = np.empty(n + 1, np.int64)
+    keys = np.empty(size.value, np.uint8)
+    _lib.guber_hotkeys_export(
+        handle, counts.ctypes.data, errs.ctypes.data, offsets.ctypes.data,
+        keys.ctypes.data,
+    )
+    return total.value, counts, errs, offsets, keys.tobytes()

@@ -308,11 +308,13 @@ _STRING_RESP_DTYPE = None
 
 
 def _parse_string_native(payload: bytes, n: int):
-    """(hash keys, columns) of one string frame's payload by ONE native
-    call with the GIL released (native/guberhash.cc
+    """(hash keys, columns, packed keys) of one string frame's payload
+    by ONE native call with the GIL released (native/guberhash.cc
     guber_parse_string_frame): the keys `name + "_" + unique_key` as a
     list of str from one decode and one split of the parser's
-    NUL-joined buffer, the columns hashlib_native.parse_string_frame's.
+    NUL-joined buffer, the columns hashlib_native.parse_string_frame's,
+    and that buffer as it came (the traffic observers fold it without
+    a str: core/sketches.py TrafficStats.observe).
     None where libguberhash.so is not built, or the parser declines
     (counted by reason): EdgeBridge._fold_string_frame then runs its
     per-item loop over the same bytes."""
@@ -326,7 +328,7 @@ def _parse_string_native(payload: bytes, n: int):
         ).inc()
         return None
     metrics.EDGE_STRING_NATIVE_FRAMES.inc()
-    return (keys.decode().split("\x00") if n else []), cols
+    return (keys.decode().split("\x00") if n else []), cols, keys
 
 
 def _stamp_shed(seconds: float) -> None:
@@ -948,12 +950,14 @@ class FrameService:
         Per-owner slow shards from the edge are all-owned by
         construction, so the GUBER_EDGE_FAST=0 kill switch and mixed
         fleets get fast-path treatment minus only the client-side
-        hashing. Returns (full_keys, fields, glob, route_s) or None:
-        `glob` is [(index, name, unique_key)] of the GLOBAL items in
-        frame order, `route_s` the seconds of the ownership screen and
-        (in the loop; the native parse hashes off the wire) the key
+        hashing. Returns (full_keys, fields, glob, route_s, packed) or
+        None: `glob` is [(index, name, unique_key)] of the GLOBAL items
+        in frame order, `route_s` the seconds of the ownership screen
+        and (in the loop; the native parse hashes off the wire) the key
         hashing — the Instance's share of the work, stamped
-        `instance_route` by the caller. None falls back to the object
+        `instance_route` by the caller — and `packed` the native
+        parse's NUL-joined key bytes (None from the loop), for the
+        traffic observers. None falls back to the object
         path, which keeps full semantics for per-item validation
         errors and for ANY item this node does not own: a stale edge's
         plain item is forwarded by the instance there, a non-owner's
@@ -969,13 +973,15 @@ class FrameService:
             return None
         parsed = _parse_string_native(payload, n)
         if parsed is not None:
-            full, cols = parsed
+            full, cols, packed = parsed
             t0 = time.monotonic()
             if not mask_fn(full).all():
                 return None
             route_s = time.monotonic() - t0
             fields = {k: cols[k] for k in DECIDE_FIELDS}
-            return full, fields, global_rows(payload, cols), route_s
+            return (
+                full, fields, global_rows(payload, cols), route_s, packed
+            )
         # the library is not built, or it declined the payload: the
         # per-item loop below is the same parse (and the oracle the
         # native one is tested against)
@@ -1050,17 +1056,19 @@ class FrameService:
             algo=np.where(algo <= 3, algo, 0).astype(np.int32),
         )
         route_s += time.monotonic() - t0
-        return full, fields, glob, route_s
+        return full, fields, glob, route_s, None
 
     async def _decide_string_folded(
-        self, full, fields, glob, route_s: float, n: int
+        self, full, fields, glob, route_s: float, n: int, packed=None
     ) -> bytes:
         """Array-decide one folded string frame and encode the GEB3/
         GEB4 response body (25-byte decisions + empty error/owner) in
         one numpy pass. Before the decide it does for the frame what
         the owner branch of Instance.get_rate_limits does item by
         item: hot-key observability keeps full parity with the object
-        path (names AND hashes feed the sketches), the three managers
+        path (names AND hashes feed the sketches: one native fold of
+        the hashes and `packed`, the parse's key bytes, with the GIL
+        released — core/sketches.py TrafficStats), the three managers
         note the owned windows, and every GLOBAL item — shed-answered
         or device-decided alike — queues its key's status broadcast.
         That work and the fold's ownership screen and key hashing
@@ -1069,7 +1077,7 @@ class FrameService:
         import numpy as np
 
         t_route0 = time.monotonic()
-        self.instance.traffic.observe(full, fields["key_hash"])
+        self.instance.traffic.observe(full, fields["key_hash"], packed)
         repl = getattr(self.instance, "repl", None)
         resc = getattr(self.instance, "rescale", None)
         ckpt = getattr(self.instance, "checkpoint", None)
@@ -1121,7 +1129,7 @@ class FrameService:
         if self.string_fold and n and self._arrays_ok():
             fold = self._fold_string_frame(payload, n)
         if fold is not None:
-            full, fields, glob, route_s = fold
+            full, fields, glob, route_s, packed = fold
             metrics.EDGE_FOLDED_ITEMS.inc(n)
             # the lean parse alone: the fold's ownership screen and
             # key hashing are stamped with the rest of the frame's
@@ -1133,7 +1141,7 @@ class FrameService:
             if frame_id is not None:
                 hdr += struct.pack("<I", frame_id)
             return hdr + await self._decide_string_folded(
-                full, fields, glob, route_s, n
+                full, fields, glob, route_s, n, packed
             )
         resps = await self._decide_string(payload, n)
         with STAGES.span("encode"):

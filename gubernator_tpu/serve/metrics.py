@@ -284,6 +284,22 @@ PEER_SERVE_FOLDED_ITEMS = Gauge(
     "replication, rescale or odd wire sent batches down the object path",
     registry=REGISTRY,
 )
+TRAFFIC_NATIVE_FOLDS = Gauge(
+    "traffic_native_folds_total",
+    "Batches the traffic observers (distinct-key HLL + hot-key "
+    "summary, /v1/debug/stats) folded in one native call with the GIL "
+    "released (libguberhash.so guber_traffic_fold); exported lazily "
+    "at scrape. / (this + traffic_python_folds_total) = the native "
+    "fold's share of engagement",
+    registry=REGISTRY,
+)
+TRAFFIC_PYTHON_FOLDS = Gauge(
+    "traffic_python_folds_total",
+    "Batches the same observers folded in Python on the serving loop "
+    "(core/sketches.py SpaceSaving + HyperLogLog): every one where "
+    "libguberhash.so is not built or predates the fold, none otherwise",
+    registry=REGISTRY,
+)
 FAULTS_INJECTED = Counter(
     "faults_injected_total",
     "Injected faults fired (serve/faults.py, GUBER_FAULT_SPEC) — a "

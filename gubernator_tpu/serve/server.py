@@ -611,6 +611,15 @@ class Server:
             else "pure-Python blake2b fallback (make -C "
             "gubernator_tpu/native builds the native one)",
         )
+        log.info(
+            "traffic observers: %s",
+            "one native fold a batch, GIL released (libguberhash.so "
+            "guber_traffic_fold; traffic_native_folds_total)"
+            if self.instance.traffic.implementation == "native"
+            else "Python SpaceSaving + HyperLogLog on the serving loop "
+            "(libguberhash.so not built, or built before the fold: make "
+            "-C gubernator_tpu/native; traffic_python_folds_total)",
+        )
 
         shed = self.instance.shed
         if shed is not None:
@@ -1159,6 +1168,9 @@ class Server:
         metrics.PEER_SERVE_FOLDED_ITEMS.set(
             self.instance.peer_serve_folded_items
         )
+        traffic = self.instance.traffic
+        metrics.TRAFFIC_NATIVE_FOLDS.set(traffic.native_folds)
+        metrics.TRAFFIC_PYTHON_FOLDS.set(traffic.python_folds)
         if self.instance.repl is not None:
             metrics.REPLICATION_STANDBY_ENTRIES.set(
                 self.instance.repl.standby_len

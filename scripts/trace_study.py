@@ -25,7 +25,9 @@ over one window of --seconds:
   Python-tracer capture (scripts/gap_threads.py);
 - reads the GEB door's `edge_*` counters by growth over the window
   (`door_counters`: which path served the items, and how many string
-  frames the native parser took or declined), PR 37.
+  frames the native parser took or declined), PR 37; and beside them
+  the traffic observers' `traffic_*_folds_total` (which implementation
+  folded the batches), PR 40.
 
 Prints one JSON object; the whole of it, and the Python-tracer-off
 capture's .xplane.pb, go to chiprun_out/trace_study/. The parent never
@@ -162,11 +164,12 @@ def gap_threads(profile_dir):
 
 
 def door_counters(prom0, prom1):
-    """Growth of every `edge_*_total` series of /metrics between two
-    scrapes, series that stood still left out."""
+    """Growth of every `edge_*_total` and `traffic_*_total` series of
+    /metrics between two scrapes, series that stood still left out."""
     return {
         k: v - prom0.get(k, 0.0) for k, v in sorted(prom1.items())
-        if k.startswith("edge_") and "_total" in k and v != prom0.get(k, 0.0)
+        if k.startswith(("edge_", "traffic_")) and "_total" in k
+        and v != prom0.get(k, 0.0)
     }
 
 

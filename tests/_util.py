@@ -1,8 +1,10 @@
 """Shared test helpers."""
 
+import itertools
 import json
 import os
 import pathlib
+import random
 import socket
 import time
 import urllib.error
@@ -65,16 +67,52 @@ def edge_binary() -> "pathlib.Path":
     return root / "gubernator_tpu" / "native" / "edge" / "guber-edge"
 
 
+_PORT_LANES = 16
+_port_lane = None  # this process's ports, in the order it hands them out
+
+
 def free_ports(n):
-    """n distinct ephemeral localhost ports (bind-then-release)."""
-    socks = [socket.socket() for _ in range(n)]
-    try:
-        for s in socks:
-            s.bind(("127.0.0.1", 0))
-        return [s.getsockname()[1] for s in socks]
-    finally:
-        for s in socks:
-            s.close()
+    """n distinct localhost ports that nobody holds and the kernel
+    hands to nobody: drawn below the range it serves bind(0) and
+    connect() from, each proved free by binding it
+    (benchmark/harness/daemon.py free_sockets does the same). A daemon
+    binds its doors seconds after its ports were chosen; a port drawn
+    with bind(0) and let go was meanwhile drawn by a neighbour's
+    bind(0) under the driver's six workers (test_ring_route.py x 6 and
+    test_shm_lane.py x 1 failed so in one run, all passing alone). The
+    16,384 ports below the range are cut into lanes, one an xdist
+    worker (by process id outside xdist), and a process walks its lane
+    in a shuffled order without handing a port out twice before the
+    lane is spent — so two tests, or two workers, cannot hold the same
+    port between the draw and the bind either."""
+    global _port_lane
+    if _port_lane is None:
+        try:
+            with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
+                low = int(f.read().split()[0])
+        except (OSError, ValueError, IndexError):
+            low = 32768
+        first = max(1024, low - 16384)
+        width = (low - first) // _PORT_LANES
+        worker = os.environ.get("PYTEST_XDIST_WORKER", "")
+        lane = (
+            int(worker[2:]) if worker[2:].isdigit() else os.getpid()
+        ) % _PORT_LANES
+        ports = list(range(first + lane * width, first + (lane + 1) * width))
+        random.shuffle(ports)
+        # no room below the range: the kernel's own draw, as ever
+        _port_lane = itertools.cycle(ports if width >= 64 else [0])
+    got = []
+    for port in itertools.islice(_port_lane, 4096):
+        with socket.socket() as s:
+            try:
+                s.bind(("0.0.0.0", port))
+            except OSError:
+                continue
+            got.append(s.getsockname()[1])
+        if len(got) == n:
+            return got
+    raise RuntimeError(f"no {n} free ports in this worker's lane")
 
 
 def spawn_daemon_edge(
@@ -147,18 +185,25 @@ def spawn_daemon_edge(
     pytest.fail("edge never started listening")
 
 
+_native_for_tests = None
+
+
 def native_lib_for_tests(tmp_dir):
     """gubernator_tpu.native.hashlib_native over a libguberhash.so
-    that has the PeersV1 wire fold and the GEB string-frame parse: the
-    checkout's own where it is built and current, else one compiled
-    from guberhash.cc into `tmp_dir` and loaded from there under a
-    private module name — a test never drops a .so into the checkout
-    other tests run from (tests/test_chip_smoke.py does the same with
-    a copy)."""
+    that has the PeersV1 wire fold, the GEB string-frame parse and the
+    traffic observers' fold: the checkout's own where it is built and
+    current, else one compiled from guberhash.cc into `tmp_dir` (once
+    a process: the files that ask share it) and loaded from there
+    under a private module name — a test never drops a .so into the
+    checkout other tests run from (tests/test_chip_smoke.py does the
+    same with a copy)."""
+    global _native_for_tests
     import importlib.util
     import shutil
     import subprocess
 
+    if _native_for_tests is not None:
+        return _native_for_tests
     native = (
         pathlib.Path(__file__).resolve().parent.parent
         / "gubernator_tpu" / "native"
@@ -166,9 +211,13 @@ def native_lib_for_tests(tmp_dir):
     try:
         from gubernator_tpu.native import hashlib_native
 
-        if getattr(hashlib_native, "_HAS_PEER_WIRE", False) and getattr(
-            hashlib_native, "_HAS_STRING_FRAME", False
+        if all(
+            getattr(hashlib_native, has, False)
+            for has in (
+                "_HAS_PEER_WIRE", "_HAS_STRING_FRAME", "_HAS_TRAFFIC_FOLD"
+            )
         ):
+            _native_for_tests = hashlib_native
             return hashlib_native
     except ImportError:
         pass
@@ -183,4 +232,5 @@ def native_lib_for_tests(tmp_dir):
     )
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    _native_for_tests = mod
     return mod

@@ -32,7 +32,7 @@ class _FakeTraffic:
     def observe_hashes(self, h):
         pass
 
-    def observe(self, keys, hashes):
+    def observe(self, keys, hashes, packed=None):
         pass
 
 

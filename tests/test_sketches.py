@@ -52,13 +52,45 @@ def test_hll_real_key_hashes():
     assert abs(h.estimate() - distinct) <= 0.05 * distinct
 
 
-def test_space_saving_finds_heavy_hitters():
+@pytest.fixture(scope="module")
+def native(tmp_path_factory):
+    """libguberhash.so with the observers' fold (built out of tree
+    where the checkout has none), lent to core.hashing for this file:
+    TrafficStats takes it from there."""
+    from _util import native_lib_for_tests
+    from gubernator_tpu.core import hashing
+
+    lib = native_lib_for_tests(tmp_path_factory.mktemp("native"))
+    mp = pytest.MonkeyPatch()
+    mp.setattr(hashing, "_native", lib)
+    mp.setattr(hashing, "_native_checked", True)
+    yield lib
+    mp.undo()
+
+
+# The three summary tests run against a TrafficStats on either
+# implementation (PR 40): "python" holds SpaceSaving itself, "native"
+# the summary behind libguberhash.so that must keep its guarantees.
+IMPLEMENTATIONS = ["python", "native"]
+
+
+def _traffic(native, implementation, **kw):
+    ts = TrafficStats(native=implementation == "native", **kw)
+    assert ts.implementation == implementation
+    assert isinstance(ts.hot, SpaceSaving) == (implementation == "python")
+    return ts
+
+
+@pytest.mark.parametrize("implementation", IMPLEMENTATIONS)
+def test_space_saving_finds_heavy_hitters(native, implementation):
     rng = np.random.default_rng(3)
     # zipf stream over 10k keys: the top keys dominate
     stream = [f"key_{z}" for z in rng.zipf(1.3, 50_000) % 10_000]
-    ss = SpaceSaving(capacity=128)
+    ts = _traffic(native, implementation, top_capacity=128)
+    ss = ts.hot
     for i in range(0, len(stream), 500):
-        ss.observe(stream[i : i + 500])
+        keys = stream[i : i + 500]
+        ts.observe(keys, slot_hash_batch(keys))
 
     true_counts = {}
     for k in stream:
@@ -74,15 +106,18 @@ def test_space_saving_finds_heavy_hitters():
             assert c - e <= true_counts[k] <= c, (k, c, e, true_counts[k])
 
 
-def test_space_saving_capacity_bound():
-    ss = SpaceSaving(capacity=16)
-    ss.observe([f"k{i}" for i in range(1000)])
-    assert len(ss.top(100)) <= 16
-    assert ss.total == 1000
+@pytest.mark.parametrize("implementation", IMPLEMENTATIONS)
+def test_space_saving_capacity_bound(native, implementation):
+    ts = _traffic(native, implementation, top_capacity=16)
+    keys = [f"k{i}" for i in range(1000)]
+    ts.observe(keys, slot_hash_batch(keys))
+    assert len(ts.hot.top(100)) <= 16
+    assert ts.hot.total == 1000
 
 
-def test_traffic_stats_snapshot():
-    ts = TrafficStats()
+@pytest.mark.parametrize("implementation", IMPLEMENTATIONS)
+def test_traffic_stats_snapshot(native, implementation):
+    ts = _traffic(native, implementation)
     keys = ["a_1", "a_1", "b_2"]
     ts.observe(keys, slot_hash_batch(keys))
     snap = ts.snapshot()

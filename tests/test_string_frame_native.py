@@ -184,7 +184,8 @@ def test_native_parse_equals_python_loop(native, python_parse, seed, n):
     python_parse()
     by_loop = bridge._fold_string_frame(payload, n)
     _same_fold(by_native, by_loop)
-    full, fields, glob, _ = by_native
+    full, fields, glob, _, packed = by_native
+    assert packed == keys
     assert full == [
         (name + b"_" + key).decode() for name, key, *_ in rows
     ]
