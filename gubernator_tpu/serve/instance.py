@@ -52,7 +52,11 @@ from gubernator_tpu.serve.breaker import OPEN as BREAKER_OPEN
 from gubernator_tpu.serve.config import MAX_BATCH_SIZE, ServerConfig
 from gubernator_tpu.serve.faults import FAULTS
 from gubernator_tpu.serve.global_mgr import GlobalManager
-from gubernator_tpu.serve.peers import ConsistentHashPicker, PeerClient
+from gubernator_tpu.serve.peers import (
+    ConsistentHashPicker,
+    ForwardCounts,
+    PeerClient,
+)
 from gubernator_tpu.serve.shedcache import screened_decide
 from gubernator_tpu.serve.stages import STAGES
 
@@ -143,6 +147,12 @@ class Instance:
         self.peer_serve_items = 0
         self.peer_serve_shed_hits = 0
         self.peer_serve_folded_items = 0
+        # the forwarder side (serve/peers.py): RPCs sent to the peers
+        # that own what this node was asked, their items, and the items
+        # that came back as errors by reason; shared by every
+        # PeerClient this instance builds, exported at scrape
+        # (peer_forward_*_total)
+        self.peer_forward = ForwardCounts()
         # bucket replication (r11, serve/replication.py): owned windows
         # snapshot to each key's ring successor so a killed owner's
         # quota state survives takeover. OFF by default
@@ -569,7 +579,14 @@ class Instance:
                         )
                     )
         if tasks:
+            t_wait = time.monotonic()
             await asyncio.gather(*tasks)
+            if stage_frame and forwards:
+                # the forward lane's excess over the local lane: what
+                # tiles a frame that waited on a peer (stages.py
+                # forward_wait); a node that owns every key has no
+                # forwards and records none
+                STAGES.add("forward_wait", time.monotonic() - t_wait)
         for i in seeded_idx:
             resp = out[i]
             if resp is not None and not resp.error:
@@ -1115,7 +1132,10 @@ class Instance:
             if existing is not None:
                 peer = existing
             else:
-                peer = PeerClient(self.conf.behaviors, info.address)
+                peer = PeerClient(
+                    self.conf.behaviors, info.address,
+                    counts=self.peer_forward,
+                )
             peer.is_owner = info.is_owner
             peer.mesh_local = getattr(info, "mesh_local", False)
             try:

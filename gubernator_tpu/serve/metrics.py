@@ -284,6 +284,33 @@ PEER_SERVE_FOLDED_ITEMS = Gauge(
     "replication, rescale or odd wire sent batches down the object path",
     registry=REGISTRY,
 )
+PEER_FORWARD_BATCHES = Gauge(
+    "peer_forward_batches_total",
+    "GetPeerRateLimits RPCs this node sent to the peers that own what "
+    "it was asked (serve/peers.py PeerClient; plain ints exported "
+    "lazily at scrape like peer_serve_*). Pair with the forward_rpc "
+    "stage for a forward's seconds; 0 on a node that owns every key",
+    registry=REGISTRY,
+)
+PEER_FORWARD_ITEMS = Gauge(
+    "peer_forward_items_total",
+    "Rate-limit items in those RPCs; / peer_forward_batches_total = "
+    "the forwarded batch's size, and over a ring the sum equals the "
+    "owners' peer_serve_items_total",
+    registry=REGISTRY,
+)
+PEER_FORWARD_FAILED_ITEMS = Gauge(
+    "peer_forward_failed_items_total",
+    "Forwarded items that came back to their caller as an error, by "
+    "reason: deadline (no answer within GUBER_PEER_TIMEOUT_MS / "
+    "GUBER_BATCH_TIMEOUT_MS; the owner may have applied the hits, so "
+    "the batch is not sent again), breaker_open (never sent), "
+    "transport (refused, reset, an application error), closed (the "
+    "client was replaced under its caller). Each failed RPC also logs "
+    "one WARNING with the peer, the items and the seconds waited",
+    ["reason"],
+    registry=REGISTRY,
+)
 TRAFFIC_NATIVE_FOLDS = Gauge(
     "traffic_native_folds_total",
     "Batches the traffic observers (distinct-key HLL + hot-key "

@@ -20,6 +20,7 @@ import asyncio
 import bisect
 import logging
 import random
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import grpc
@@ -38,8 +39,50 @@ from gubernator_tpu.serve.breaker import (
 )
 from gubernator_tpu.serve.config import BehaviorConfig
 from gubernator_tpu.serve.faults import FAULTS, FaultError
+from gubernator_tpu.serve.stages import STAGES
 
 log = logging.getLogger("gubernator_tpu.peers")
+
+#: why a forwarded item came back as an error: the label values of
+#: peer_forward_failed_items_total. `deadline` is a peer that did not
+#: answer in time (the hits may have been applied: never re-sent),
+#: `breaker_open` a forward that was never sent, `closed` a client
+#: replaced under its caller (set_peers), `transport` everything else
+#: (refused, reset, an application error, a reply of the wrong length)
+FORWARD_FAIL_REASONS = ("deadline", "breaker_open", "transport", "closed")
+
+
+class ForwardCounts:
+    """The forwarder side of the ring in plain ints, one object an
+    Instance, shared by its PeerClients and exported at scrape
+    (peer_forward_*_total) like the owner side's peer_serve_*."""
+
+    __slots__ = ("batches", "items", "failed")
+
+    def __init__(self):
+        self.batches = 0  # GetPeerRateLimits RPCs sent
+        self.items = 0  # the items in them
+        self.failed = dict.fromkeys(FORWARD_FAIL_REASONS, 0)  # items
+
+
+class PeerDeadlineError(asyncio.TimeoutError):
+    """A peer RPC past its deadline, with words: asyncio's own
+    TimeoutError prints as '' and the item's error text said nothing."""
+
+
+def fail_reason(exc: BaseException) -> str:
+    if isinstance(exc, asyncio.TimeoutError):
+        return "deadline"
+    if isinstance(exc, BreakerOpenError):
+        return "breaker_open"
+    if isinstance(exc, grpc.RpcError):
+        code = getattr(exc, "code", None)
+        try:
+            if callable(code) and code() == grpc.StatusCode.DEADLINE_EXCEEDED:
+                return "deadline"
+        except Exception:
+            pass
+    return "transport"
 
 
 def is_retryable(exc: BaseException, all_peek: bool = False) -> bool:
@@ -78,9 +121,11 @@ class PeerClient:
         host: str,
         is_owner: bool = False,
         mesh_local: bool = False,
+        counts: Optional[ForwardCounts] = None,
     ):
         self.conf = conf
         self.host = host
+        self.counts = counts if counts is not None else ForwardCounts()
         self.is_owner = is_owner  # true when this peer is this server
         # true when this peer's replica state rides THIS node's mesh
         # (PeerInfo.mesh_local): broadcast installs for it short-circuit
@@ -221,6 +266,7 @@ class PeerClient:
         — same wire behavior as per-item enqueueing, a fraction of the
         event-loop cost."""
         if self._closed:
+            self.counts.failed["closed"] += len(reqs)
             raise RuntimeError(
                 f"peer client for '{self.host}' is closed"
             )
@@ -230,9 +276,12 @@ class PeerClient:
         # the caller's trace context rides the queue entry (r16): the
         # flusher task that sends the batched RPC runs outside the
         # caller's context, so the traceparent must be captured HERE —
-        # one branch, None for unsampled/untraced callers
+        # one branch, None for unsampled/untraced callers. The enqueue
+        # stamp rides it too: forward_queue ends where the flusher
+        # starts to build the group's RPC
         self._queue.put_nowait(
-            (list(reqs), fut, tracing.propagation_header())
+            (list(reqs), fut, tracing.propagation_header(),
+             time.monotonic())
         )
         return await fut
 
@@ -241,6 +290,11 @@ class PeerClient:
         reqs: Sequence[RateLimitReq],
         traceparent: Optional[str] = None,
     ) -> List[RateLimitResp]:
+        """One GetPeerRateLimits RPC with the forwarder's stages
+        (forward_encode, forward_rpc, forward_decode: serve/stages.py
+        PER_FORWARD), its counters, and for a failure one WARNING line
+        and an error that says which deadline passed."""
+        t_enc = time.monotonic()
         pb_req = peers_pb2.GetPeerRateLimitsReq(
             requests=[convert.req_to_pb(r) for r in reqs]
         )
@@ -259,7 +313,7 @@ class PeerClient:
             else {}
         )
 
-        async def call() -> List[RateLimitResp]:
+        async def call():
             pb_resp = await self.stub.GetPeerRateLimits(
                 pb_req, timeout=timeout or None, **kw
             )
@@ -267,15 +321,52 @@ class PeerClient:
                 raise RuntimeError(
                     "peer responded with mismatched rate limit list size"
                 )
-            return [convert.resp_from_pb(p) for p in pb_resp.rate_limits]
+            return pb_resp
 
-        # a batch of pure peeks (hits all 0) is idempotent end to end;
-        # anything carrying hits only retries transport-level failures
-        # (is_retryable) so a slow peer is never double-counted
-        return await self._call_resilient(
-            call, idempotent=all(r.hits == 0 for r in reqs),
-            timeout=timeout,
-        )
+        counts = self.counts
+        counts.batches += 1
+        counts.items += len(reqs)
+        # bare stamps: forward_rpc crosses an await
+        t_sent = time.monotonic()
+        STAGES.add("forward_encode", t_sent - t_enc)
+        try:
+            # a batch of pure peeks (hits all 0) is idempotent end to
+            # end; anything carrying hits only retries transport-level
+            # failures (is_retryable) so a slow peer is never
+            # double-counted
+            pb_resp = await self._call_resilient(
+                call, idempotent=all(r.hits == 0 for r in reqs),
+                timeout=timeout,
+            )
+        except Exception as e:
+            waited = time.monotonic() - t_sent
+            STAGES.add("forward_rpc", waited)
+            reason = fail_reason(e)
+            counts.failed[reason] += len(reqs)
+            if reason == "deadline":
+                knob = (
+                    "GUBER_PEER_TIMEOUT_MS"
+                    if self.conf.peer_timeout > 0
+                    else "GUBER_BATCH_TIMEOUT_MS"
+                )
+                e = PeerDeadlineError(
+                    f"no answer from peer '{self.host}' for {len(reqs)} "
+                    f"item(s) after {waited:.3f} s: past the deadline "
+                    f"{knob} = {timeout * 1e3:g} ms "
+                    f"(a batch that carries hits is not sent again)"
+                )
+            log.warning(
+                "forward to peer '%s' failed (%s): %d item(s), waited "
+                "%.3f s - %s",
+                self.host, reason, len(reqs), waited,
+                e if str(e) else type(e).__name__,
+            )
+            raise e
+        t_got = time.monotonic()
+        STAGES.add("forward_rpc", t_got - t_sent)
+        resps = [convert.resp_from_pb(p) for p in pb_resp.rate_limits]
+        STAGES.add("forward_decode", time.monotonic() - t_got)
+        return resps
 
     async def update_peer_globals(self, updates) -> None:
         """updates: sequence of (key, RateLimitResp). Installing a
@@ -432,26 +523,32 @@ class PeerClient:
                 exc = RuntimeError(
                     f"peer client for '{self.host}' closed mid-batch"
                 )
-                for _, fut, _tp in batch:
+
+                def fail(group) -> None:
+                    reqs, fut = group[0], group[1]
                     if not fut.done():
+                        self.counts.failed["closed"] += len(reqs)
                         fut.set_exception(exc)
-                for _, fut, _tp in self._carry:
-                    if not fut.done():
-                        fut.set_exception(exc)
+
+                for group in batch:
+                    fail(group)
+                for group in self._carry:
+                    fail(group)
                 self._carry.clear()
                 while True:
                     try:
-                        _, fut, _tp = self._queue.get_nowait()
+                        fail(self._queue.get_nowait())
                     except asyncio.QueueEmpty:
                         break
-                    if not fut.done():
-                        fut.set_exception(exc)
                 raise
 
     async def _send_batch(self, batch) -> None:
         # groups flatten into one peer RPC; responses slice back per
         # group (reference peers.go:143-172, group-granular here)
-        reqs = [r for g, _, _tp in batch for r in g]
+        t_collected = time.monotonic()
+        for group in batch:
+            STAGES.add("forward_queue", t_collected - group[3])
+        reqs = [r for g, *_ in batch for r in g]
         # one traceparent per RPC: micro-batching can coalesce groups
         # from different traced callers, so the FIRST traced group's
         # context represents the wire hop (documented scope limit —
@@ -460,14 +557,14 @@ class PeerClient:
         try:
             resps = await self.get_peer_rate_limits(reqs, traceparent=tp)
         except Exception as e:  # entire batch failed (peers.go:186-192)
-            for _, fut, _tp in batch:
+            for _, fut, *_ in batch:
                 if not fut.done():
                     fut.set_exception(
                         RuntimeError(f"while fetching from peer - '{e}'")
                     )
             return
         k = 0
-        for g, fut, _tp in batch:
+        for g, fut, *_ in batch:
             span = resps[k : k + len(g)]
             k += len(g)
             if not fut.done():

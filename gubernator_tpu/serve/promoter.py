@@ -190,6 +190,27 @@ class DeviceTopK:
         self._counts: Dict[int, int] = {}
         self._dirty = False
         self._lock = threading.Lock()
+        self._warm()
+
+    def _warm(self) -> None:
+        """Both device programs of the table run once HERE, where the
+        table is built (the instance's construction, before Ready), on
+        no-op inputs: an all-padding batch (weight 0: nothing matches,
+        nothing inserts) and a decay of the all-zero table. Their
+        first use is what traces, lowers and builds them — or loads
+        them from the compile cache — and it used to be the first
+        batch that carried HITS after boot, on the submit thread with
+        the GIL held for the tracing: 0.32 s on the v5e host with a
+        warm cache (trace 43 ms, lowering 83 ms, cache load 191 ms),
+        seconds with a cold one. In a ring that is an owner's first
+        forwarded batch with hits, and the forwarder's deadline is
+        0.5 s (ROADMAP R-A9; PERF.md section 6, PR 41). The calls are
+        the serving calls themselves, so the jit cache and the
+        persistent cache hold the same entries as before."""
+        self.observe_arrays(
+            np.zeros(0, np.uint64), np.zeros(0, np.int64), {}
+        )
+        self.decay()  # ends in a host read: both programs have run
 
     def observe_arrays(self, kh, weights, payloads: Dict) -> None:
         """Fold a pre-aggregated batch (distinct uint64 keys + int64

@@ -1168,6 +1168,11 @@ class Server:
         metrics.PEER_SERVE_FOLDED_ITEMS.set(
             self.instance.peer_serve_folded_items
         )
+        fwd = self.instance.peer_forward
+        metrics.PEER_FORWARD_BATCHES.set(fwd.batches)
+        metrics.PEER_FORWARD_ITEMS.set(fwd.items)
+        for reason, items in fwd.failed.items():
+            metrics.PEER_FORWARD_FAILED_ITEMS.labels(reason=reason).set(items)
         traffic = self.instance.traffic
         metrics.TRAFFIC_NATIVE_FOLDS.set(traffic.native_folds)
         metrics.TRAFFIC_PYTHON_FOLDS.set(traffic.python_folds)

@@ -10,7 +10,7 @@ exposed as `/v1/debug/stages` and, lazily at scrape, `/metrics`
 (serve/server.py); `scripts/trace_study.py` reads one window of it on
 the chip.
 
-Stages form five families:
+Stages form six families:
 
 - **per-frame stages** (`PER_FRAME`): spans that tile one edge frame's
   end-to-end wall time, so their totals are directly comparable to the
@@ -38,6 +38,15 @@ Stages form five families:
                      covers submit + device execute + fetch + any wait
                      behind earlier pipelined batches)
     encode           responses resolved -> response frame written
+    forward_wait     a string frame on the object path with items
+                     another node owns (Instance.get_rate_limits): its
+                     local lane done (or, with no local item, its
+                     routing done) -> every forwarded group answered.
+                     The forward lane's excess over the local lane, so
+                     such a frame tiles as bridge_decode +
+                     max(local lane, forward lane) + encode; its
+                     interior is PER_FORWARD's. A node that owns every
+                     key records none
 
 - **per-batch stages** (`PER_BATCH`): recorded once per device batch,
   never per call, frame or item. Seven of them, `BATCH_TILES`, tile
@@ -137,6 +146,32 @@ Stages form five families:
                      decide_local of every queued key, queue + device
                      + fetch of the batcher included
 
+- **per-forward stages** (`PER_FORWARD`): the forwarder side of the
+  ring (serve/peers.py PeerClient), recorded by a node that sends
+  items to the peer that owns them and by no other: a daemon that
+  owns every key records none. The four tile one forward from the
+  instance's enqueue to the answers back with their groups:
+
+    forward_queue    a group's enqueue (get_peer_rate_limits_grouped)
+                     -> the flusher has collected its batch and starts
+                     to build the RPC: BatchWait, the RPC in flight
+                     ahead of it (one a peer), the loop. One sample a
+                     GROUP (one frame's items for one owner)
+    forward_encode   convert.req_to_pb x items and the message's
+                     build. One sample an RPC, like the next two
+    forward_rpc      RPC sent -> reply or failure in hand, deadline,
+                     breaker and retries included (bare stamps: it
+                     crosses an await): the wire, the owner's whole
+                     GetPeerRateLimits call and this node's loop
+                     getting back to it
+    forward_decode   resp_from_pb x items (the slice back to the
+                     groups is a few list slices, uncounted)
+
+  Beside them the plain counts peer_forward_batches_total,
+  peer_forward_items_total and peer_forward_failed_items_total{reason}
+  (/metrics, scrape-lazy): items / batches is the forwarded batch's
+  size, failed items are error items some client was handed.
+
 - **per-call stages** (`PER_CALL`): the gRPC door's family. Six of the
   seven `CALL_TILES` tile one call from handler entry to handler
   return the way PER_FRAME tiles a frame: a GetRateLimits call records
@@ -220,7 +255,8 @@ on the profiler's clock beside the device's XLA Ops. This module
 never imports JAX itself (the JAX-free client tier imports
 serve/tracing.py, and through it this). Spans that cross an `await`
 or belong to no thread (batch_queue, device, call_queue, call_device,
-call_wake, call_e2e, global_peek, and the batch tiles between
+call_wake, call_e2e, global_peek, forward_wait, forward_queue,
+forward_rpc, and the batch tiles between
 threads: admit_wait, submit_wake, submit_return, submit_host,
 fetch_wake, fetch_return, batch_e2e),
 and the per-call ones recorded from bare stamps on the serving loop
@@ -261,6 +297,14 @@ PER_FRAME = (
     "batch_queue",
     "device",
     "encode",
+    "forward_wait",
+)
+#: what tiles one forward, enqueue to answers: serve/peers.py
+PER_FORWARD = (
+    "forward_queue",
+    "forward_encode",
+    "forward_rpc",
+    "forward_decode",
 )
 #: what tiles one device batch from the flusher's collect to its
 #: futures resolved; submit_host = the three submit_* of them
@@ -477,6 +521,7 @@ class StageStats:
             "per_batch_tiles": list(BATCH_TILES),
             "per_call_stages": list(PER_CALL),
             "per_flush_stages": list(PER_FLUSH),
+            "per_forward_stages": list(PER_FORWARD),
             "per_process_stages": list(PER_PROCESS),
             "bucket_edges_s": list(BUCKET_EDGES_S),
             "frames": frames,

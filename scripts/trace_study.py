@@ -27,7 +27,11 @@ over one window of --seconds:
   (`door_counters`: which path served the items, and how many string
   frames the native parser took or declined), PR 37; and beside them
   the traffic observers' `traffic_*_folds_total` (which implementation
-  folded the batches), PR 40.
+  folded the batches), PR 40;
+- on a ring's door node (the harness's node 0), the forwarder's stages
+  and `peer_forward_*` counters (`forwarder`), PR 41: run a ring cell
+  with `--captures 0`, a capture on the door node stalls its forwards
+  past their deadline.
 
 Prints one JSON object; the whole of it, and the Python-tracer-off
 capture's .xplane.pb, go to chiprun_out/trace_study/. The parent never
@@ -163,13 +167,44 @@ def gap_threads(profile_dir):
     return json.loads(p.stdout.strip().splitlines()[-1])
 
 
-def door_counters(prom0, prom1):
-    """Growth of every `edge_*_total` and `traffic_*_total` series of
-    /metrics between two scrapes, series that stood still left out."""
+def grown(prom0, prom1, prefixes):
+    """Growth of every `*_total` series of /metrics that starts with one
+    of `prefixes` between two scrapes, series that stood still left out."""
     return {
         k: v - prom0.get(k, 0.0) for k, v in sorted(prom1.items())
-        if k.startswith(("edge_", "traffic_")) and "_total" in k
+        if k.startswith(prefixes) and "_total" in k
         and v != prom0.get(k, 0.0)
+    }
+
+
+def door_counters(prom0, prom1):
+    """The GEB door's `edge_*_total` and the traffic observers'
+    `traffic_*_total` over the window."""
+    return grown(prom0, prom1, ("edge_", "traffic_"))
+
+
+def forwarder(st, prom0, prom1):
+    """The forwarder side of a ring's door node (PR 41), None where
+    nothing was forwarded: the four stages that tile a forward
+    (`forward_queue` a group; `forward_encode`, `forward_rpc`,
+    `forward_decode` an RPC) and a frame's `forward_wait` in
+    microseconds a sample, and the growth of the `peer_forward_*`
+    counters over the window with the items a batch."""
+    def us(name):
+        n = st.get(name, {}).get("count", 0)
+        return st[name]["total_s"] / n * 1e6 if n else None
+
+    grew = grown(prom0, prom1, ("peer_forward_",))
+    if not grew and "forward_rpc" not in st:
+        return None
+    batches = grew.get("peer_forward_batches_total", 0.0)
+    return {
+        "stage_us": {name: us(name) for name in (
+            "forward_queue", "forward_encode", "forward_rpc", "forward_decode",
+            "forward_wait")},
+        "counters": grew,
+        "items_per_batch": (grew.get("peer_forward_items_total", 0.0) / batches
+                            if batches else None),
     }
 
 
@@ -313,6 +348,7 @@ def main() -> int:
         batch_coverage=stages.get("batch_coverage"),
         batches=stages.get("batches"),
         door_counters=door_counters(prom0, prom1),
+        forwarder=forwarder(st, prom0, prom1),
         batch_tiles_us=batch_tiles(st),
         threads=thread_shares(threads0, stages.get("threads"),
                               stages.get("batches")),

@@ -103,7 +103,15 @@ def test_over_the_limit(cluster):
 
 
 def test_token_bucket_window_reset(cluster):
-    # reference functional_test.go:97-146 (25ms window for CI stability)
+    # reference functional_test.go:97-146. A 500 ms window: the two
+    # hits must land inside ONE window of the wall clock, and at the
+    # reference's 5 ms (or 25 ms here until PR 41) a client thread
+    # preempted between them under six xdist workers saw the second
+    # hit open a new window (remaining 1 again: the driver's one
+    # failure at PR 40) — the treatment test_leaky_bucket_drain got.
+    # What is asserted is the same: 1 -> 0 inside the window, then a
+    # sleep past its end and a fresh window (1 again, reset_time set).
+    window = 500 * MILLISECOND
     with V1Client(cluster.get_peer()) as client:
         def hit():
             return client.get_rate_limits(
@@ -112,7 +120,7 @@ def test_token_bucket_window_reset(cluster):
                         name="test_token_bucket",
                         unique_key="account:1234",
                         algorithm=Algorithm.TOKEN_BUCKET,
-                        duration=25 * MILLISECOND,
+                        duration=window,
                         limit=2,
                         hits=1,
                     )
@@ -122,12 +130,14 @@ def test_token_bucket_window_reset(cluster):
 
         rl = hit()
         assert (rl.remaining, rl.status) == (1, Status.UNDER_LIMIT)
+        first_reset = rl.reset_time
         rl = hit()
         assert (rl.remaining, rl.status) == (0, Status.UNDER_LIMIT)
-        time.sleep(0.03)
+        assert rl.reset_time == first_reset  # the same window
+        time.sleep(window / 1000 + 0.1)  # past its end
         rl = hit()
         assert (rl.remaining, rl.status) == (1, Status.UNDER_LIMIT)
-        assert rl.reset_time != 0
+        assert rl.reset_time != 0 and rl.reset_time > first_reset
 
 
 def test_leaky_bucket_drain(cluster):

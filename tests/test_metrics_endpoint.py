@@ -137,12 +137,13 @@ def test_metrics_endpoint_families_and_label_cardinality():
             PER_BATCH,
             PER_CALL,
             PER_FLUSH,
+            PER_FORWARD,
             PER_FRAME,
         )
 
         known = (
             set(PER_FRAME) | set(PER_BATCH) | set(PER_CALL)
-            | set(PER_FLUSH)
+            | set(PER_FLUSH) | set(PER_FORWARD)
         )
         stages = {
             s.labels["stage"]
