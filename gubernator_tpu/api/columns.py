@@ -3,11 +3,15 @@
 `PeersV1/GetPeerRateLimits` carries up to 1000 items a call, and an
 owner that makes a `RateLimitReq`, a `RateLimitResp` and two protobuf
 messages for each of them spends its one GIL on objects (PERF.md,
-PR 32: ~20 ms a batch). These two classes are what the door and the
-instance pass instead: the request's serialised bytes parsed into numpy
-columns by one native call (`PeerBatch.from_wire`), and the answer
-columns serialised by another (`PeerAnswers.to_wire`). Nothing here
-imports JAX or the serving tier.
+PR 32: ~20 ms a batch). `PeerBatch` and `PeerAnswers` are what the
+door and the instance pass instead: the request's serialised bytes
+parsed into numpy columns by one native call (`PeerBatch.from_wire`),
+and the answer columns serialised by another (`PeerAnswers.to_wire`).
+`ForwardGroup` and `ForwardAnswers` are the same thing seen from the
+forwarder (serve/peers.py PeerClient.forward_columns): the rows of a
+GEB string frame that one peer owns, serialised from the frame's own
+columns and key bytes, and the peer's reply parsed back to columns.
+Nothing here imports JAX or the serving tier.
 """
 
 from __future__ import annotations
@@ -16,7 +20,13 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
-from gubernator_tpu.api.types import Behavior, Status
+from gubernator_tpu.api.types import (
+    Algorithm,
+    Behavior,
+    RateLimitReq,
+    RateLimitResp,
+    Status,
+)
 from gubernator_tpu.core.hashing import native_lib
 
 
@@ -171,3 +181,126 @@ class PeerAnswers:
         return native_lib().encode_peer_answers(
             self.status, self.limit, self.remaining, self.reset_time
         )
+
+
+def split_ready() -> bool:
+    """True where libguberhash.so is built and holds the four calls the
+    GEB door's split by owner and the forwarder's column RPC are made
+    of (ring_owners, encode_peer_batch, parse_peer_answers,
+    encode_string_answers): without them a frame of mixed ownership is
+    served through request objects, as before the split."""
+    lib = native_lib()
+    return lib is not None and getattr(lib, "_HAS_SPLIT", False)
+
+
+class ForwardGroup:
+    """The rows of one natively parsed GEB string frame that one peer
+    owns: the forwarder's queue entry where the door holds columns and
+    no request objects. `cols` are hashlib_native.parse_string_frame's
+    over `payload`, shared by the frame's groups; `rows` (int32, frame
+    order) names this group's items."""
+
+    __slots__ = ("payload", "cols", "rows")
+
+    def __init__(self, payload: bytes, cols: Dict[str, np.ndarray], rows):
+        self.payload = payload
+        self.cols = cols
+        self.rows = np.ascontiguousarray(rows, np.int32)
+
+    def __len__(self) -> int:
+        return self.rows.shape[0]
+
+    def all_peeks(self) -> bool:
+        """Every row's hits is 0: the batch may be sent again."""
+        return not self.cols["hits"][self.rows].any()
+
+    def fields(self) -> Dict[str, np.ndarray]:
+        """The rows' decide columns, for the shed cache's population."""
+        return {k: self.cols[k][self.rows] for k in DECIDE_FIELDS}
+
+    def to_wire(self) -> bytes:
+        """The rows as a serialised GetPeerRateLimitsReq, the bytes
+        convert.req_to_pb's messages serialise to: one native call."""
+        return native_lib().encode_peer_batch(
+            self.payload, self.cols, self.rows
+        )
+
+    def requests(self) -> List[RateLimitReq]:
+        """The rows as request objects, equal to what
+        edge_bridge.decode_request_frame makes of the same items: built
+        only where a forward FAILED, for the failure code that exists
+        (Instance.forward_failed)."""
+        c, wire = self.cols, self.payload
+        rows = self.rows
+        return [
+            RateLimitReq(
+                name=wire[no : no + nl].decode(),
+                unique_key=wire[ko : ko + kl].decode(),
+                hits=h, limit=li, duration=d,
+                algorithm=Algorithm(a),
+                behavior=Behavior(b) if b <= 2 else Behavior.BATCHING,
+            )
+            for no, nl, ko, kl, h, li, d, a, b in zip(
+                *(c[k][rows].tolist() for k in (
+                    "name_off", "name_len", "key_off", "key_len", "hits",
+                    "limit", "duration", "algo", "behavior",
+                ))
+            )
+        ]
+
+
+class ForwardAnswers:
+    """A peer's answers to one ForwardGroup, in the group's order: four
+    int64 columns, `errors` {row: text} for the items the owner
+    answered with an error, and `opaque` — the rows whose answer says
+    nothing of a stored window (an error, a degraded answer) and must
+    not reach the shed cache (ShedCache.observe_resps skips the same)."""
+
+    __slots__ = (
+        "status", "limit", "remaining", "reset_time", "errors", "opaque"
+    )
+
+    def __init__(self, status, limit, remaining, reset_time,
+                 errors=None, opaque=()):
+        self.status = status
+        self.limit = limit
+        self.remaining = remaining
+        self.reset_time = reset_time
+        self.errors: Dict[int, str] = errors or {}
+        self.opaque = list(opaque)
+
+    def __len__(self) -> int:
+        return self.status.shape[0]
+
+    @classmethod
+    def from_resps(cls, resps) -> "ForwardAnswers":
+        """From response objects or protobuf items (a reply the native
+        parser declined: one that carries an error or metadata)."""
+        n = len(resps)
+        cols = np.zeros((4, n), np.int64)
+        errors, opaque = {}, []
+        for i, r in enumerate(resps):
+            cols[0, i] = int(r.status)
+            cols[1, i] = r.limit
+            cols[2, i] = r.remaining
+            cols[3, i] = r.reset_time
+            if r.error:
+                errors[i] = r.error
+                opaque.append(i)
+            elif r.metadata.get("degraded"):
+                opaque.append(i)
+        return cls(*cols, errors=errors, opaque=opaque)
+
+    def resps(self) -> List[RateLimitResp]:
+        """As response objects, for a caller that passed requests to
+        the flusher (no error, no metadata: a reply with either is
+        handed on as the runtime parsed it)."""
+        return [
+            RateLimitResp(
+                status=Status(s), limit=li, remaining=r, reset_time=t
+            )
+            for s, li, r, t in zip(
+                self.status.tolist(), self.limit.tolist(),
+                self.remaining.tolist(), self.reset_time.tolist(),
+            )
+        ]

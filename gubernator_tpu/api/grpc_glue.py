@@ -9,8 +9,12 @@ both sync and asyncio grpc channels/servers.
 One method is registered pass-through: PeersV1/GetPeerRateLimits hands
 its servicer the request's serialised bytes and takes bytes or a message
 back (add_peers_servicer), so the owner side of the ring can serve a
-forwarded batch as arrays without a protobuf message per item. Every
-other method, and every client stub, goes through the generated classes.
+forwarded batch as arrays without a protobuf message per item; and the
+client stub offers the same method a second time as bytes in, bytes out
+(PeersV1Stub.GetPeerRateLimitsWire), for a forwarder that serialises a
+batch from columns (serve/peers.py PeerClient.forward_columns). Every
+other method, and every other client stub, goes through the generated
+classes.
 """
 
 from __future__ import annotations
@@ -103,6 +107,13 @@ class PeersV1Stub:
             f"/{PEERS_SERVICE}/GetPeerRateLimits",
             request_serializer=peers_pb2.GetPeerRateLimitsReq.SerializeToString,
             response_deserializer=peers_pb2.GetPeerRateLimitsResp.FromString,
+        )
+        # the same method pass-through: a serialised
+        # GetPeerRateLimitsReq in, the reply's bytes out
+        self.GetPeerRateLimitsWire = channel.unary_unary(
+            f"/{PEERS_SERVICE}/GetPeerRateLimits",
+            request_serializer=None,
+            response_deserializer=None,
         )
         self.UpdatePeerGlobals = channel.unary_unary(
             f"/{PEERS_SERVICE}/UpdatePeerGlobals",

@@ -1625,6 +1625,206 @@ int64_t guber_encode_peer_answers(const int64_t* status,
   return p - out;
 }
 
+// ---------------------------------------------------------------------------
+// The GEB door's split of a string frame by owner (serve/edge_bridge.py
+// _plan_split) and the forwarder's column RPC (serve/peers.py
+// PeerClient.forward_columns): the four calls below are the converses of
+// the three above. Which ring member owns each key of a frame; the rows
+// a peer owns as the bytes of a GetPeerRateLimitsReq; a peer's reply as
+// answer columns; and a frame's answer columns, with an owner tag and an
+// error text a row, as the response frame's items.
+// ---------------------------------------------------------------------------
+
+// owner[i] = the ring position whose point is the successor of key i's
+// crc32 (IEEE) on `ring` (m ascending points, a position past the last
+// wraps to 0): serve/peers.py ConsistentHashPicker.get, key for key.
+// `keys` are the n hash keys joined by NUL, as guber_parse_string_frame
+// leaves them. Returns 0, or -1 where the buffer does not hold n keys.
+int64_t guber_ring_owners(const uint8_t* keys, int64_t keys_len, int64_t n,
+                          const uint32_t* ring, int64_t m, int32_t* owner) {
+  if (!crc_init_done) crc_init();
+  if (n <= 0) return (n == 0 && keys_len == 0) ? 0 : -1;
+  if (m <= 0) return -1;
+  const uint8_t* p = keys;
+  const uint8_t* const end = keys + keys_len;
+  for (int64_t i = 0; i < n; ++i) {
+    uint32_t c = 0xFFFFFFFFu;
+    while (p < end && *p != 0) {
+      c = crc_table[(c ^ *p++) & 0xFF] ^ (c >> 8);
+    }
+    c ^= 0xFFFFFFFFu;
+    const uint32_t* at = std::lower_bound(ring, ring + m, c);
+    owner[i] = at == ring + m ? 0 : static_cast<int32_t>(at - ring);
+    if (i + 1 < n) {
+      if (p >= end) return -1;  // fewer keys than n
+      ++p;                      // the separator
+    }
+  }
+  return p == end ? 0 : -1;
+}
+
+// Serialise `n_rows` items of a parsed string frame (the columns of
+// guber_parse_string_frame over the payload `buf`; rows[j] names the
+// item, or with rows null the items 0 .. n_rows - 1) as a
+// GetPeerRateLimitsReq: repeated RateLimitReq requests = 1 with name =
+// 1, unique_key = 2, hits = 3, limit = 4, duration = 5, algorithm = 6,
+// behavior = 7 in field order, a zero or empty field left out — byte
+// for byte what the protobuf runtime writes for api/convert.py
+// req_to_pb's message, and what guber_parse_peer_batch takes without a
+// decline. A behavior byte over 2 reads 0, as decode_request_frame
+// clamps it. Returns the bytes written, or -1 where `cap` is too small.
+int64_t guber_encode_peer_batch(const uint8_t* buf, const int32_t* name_off,
+                                const int32_t* name_len,
+                                const int32_t* key_off,
+                                const int32_t* key_len, const int64_t* hits,
+                                const int64_t* limit,
+                                const int64_t* duration, const int32_t* algo,
+                                const uint8_t* behavior, const int32_t* rows,
+                                int64_t n_rows, uint8_t* out, int64_t cap) {
+  uint8_t* p = out;
+  const uint8_t* const end = out + cap;
+  for (int64_t j = 0; j < n_rows; ++j) {
+    const int64_t i = rows ? rows[j] : j;
+    const int64_t nl = name_len[i], kl = key_len[i];
+    // tag + length (<= 5) of the item; two strings of tag + length
+    // (<= 3: a u16 length) + bytes; five fields of tag + varint
+    if (end - p < 6 + 4 + nl + 4 + kl + 5 * 11) return -1;
+    const uint64_t vals[5] = {
+        static_cast<uint64_t>(hits[i]), static_cast<uint64_t>(limit[i]),
+        static_cast<uint64_t>(duration[i]), static_cast<uint64_t>(algo[i]),
+        behavior[i] <= 2 ? behavior[i] : 0u};
+    uint8_t body[5 * 11];
+    uint8_t* b = body;
+    for (int f = 0; f < 5; ++f) {
+      if (vals[f] == 0) continue;
+      *b++ = static_cast<uint8_t>((f + 3) << 3);
+      b = put_varint(b, vals[f]);
+    }
+    uint8_t lenbuf[2][3];
+    const size_t nlv = nl ? put_varint(lenbuf[0], nl) - lenbuf[0] : 0;
+    const size_t klv = kl ? put_varint(lenbuf[1], kl) - lenbuf[1] : 0;
+    const uint64_t item = (nl ? 1 + nlv + nl : 0) + (kl ? 1 + klv + kl : 0) +
+                          static_cast<uint64_t>(b - body);
+    *p++ = 0x0A;
+    p = put_varint(p, item);
+    if (nl) {
+      *p++ = 0x0A;
+      std::memcpy(p, lenbuf[0], nlv);
+      p += nlv;
+      std::memcpy(p, buf + name_off[i], nl);
+      p += nl;
+    }
+    if (kl) {
+      *p++ = 0x12;
+      std::memcpy(p, lenbuf[1], klv);
+      p += klv;
+      std::memcpy(p, buf + key_off[i], kl);
+      p += kl;
+    }
+    std::memcpy(p, body, b - body);
+    p += b - body;
+  }
+  return p - out;
+}
+
+// what guber_parse_peer_answers declines beside the shared codes: an
+// item that carries an error text or metadata (the caller parses those
+// replies with the protobuf runtime: hashlib_native.ANSWER_DECLINE)
+constexpr int64_t ANSWER_TEXT = -11;
+
+// Parse a serialised GetPeerRateLimitsResp (repeated RateLimitResp
+// rate_limits = 1: status = 1, limit = 2, remaining = 3, reset_time = 4)
+// into four int64 columns: the converse of guber_encode_peer_answers,
+// and what the protobuf runtime reads from the same bytes wherever this
+// does not decline. Returns the number of items, or a decline code < 0
+// (the columns are then garbage): an item with an error (5) or metadata
+// (6), an unknown field or wire type, a status without a name, a length
+// or varint past its message, more than max_items items. An absent
+// field reads 0; of a field sent twice the last wins.
+int64_t guber_parse_peer_answers(const uint8_t* buf, int64_t len,
+                                 int64_t max_items, int64_t* status,
+                                 int64_t* limit, int64_t* remaining,
+                                 int64_t* reset_time) {
+  const uint8_t* p = buf;
+  const uint8_t* const end = buf + len;
+  int64_t n = 0;
+  while (p < end) {
+    if (*p++ != 0x0A) return PEER_UNKNOWN;  // rate_limits = 1, LEN
+    uint64_t mlen;
+    if (!read_varint(p, end, mlen) ||
+        mlen > static_cast<uint64_t>(end - p))
+      return PEER_TRUNCATED;
+    if (n >= max_items) return PEER_TOO_MANY;
+    const uint8_t* q = p;
+    const uint8_t* const qend = p + mlen;
+    p = qend;
+    uint64_t vals[4] = {0, 0, 0, 0};
+    while (q < qend) {
+      const uint8_t tag = *q++;
+      if (tag == 0x08 || tag == 0x10 || tag == 0x18 || tag == 0x20) {
+        if (!read_varint(q, qend, vals[(tag >> 3) - 1]))
+          return PEER_TRUNCATED;
+      } else if (tag == 0x2A || tag == 0x32) {
+        return ANSWER_TEXT;
+      } else {
+        return PEER_UNKNOWN;
+      }
+    }
+    if (vals[0] > 1) return PEER_ENUM;  // Status 0..1
+    status[n] = static_cast<int64_t>(vals[0]);
+    limit[n] = static_cast<int64_t>(vals[1]);
+    remaining[n] = static_cast<int64_t>(vals[2]);
+    reset_time[n] = static_cast<int64_t>(vals[3]);
+    ++n;
+  }
+  return n;
+}
+
+// The items of a GEB string response frame (serve/edge_bridge.py: per
+// item <B status, <qqq limit, remaining, reset_time, <H error_len,
+// error, <H owner_len, owner) from four answer columns and, a row, an
+// error text and an owner tag: err[i] and owner[i] index the `m` byte
+// strings strs[str_off[k] : str_off[k + 1]], -1 for none. Byte for
+// byte encode_response_frame's items for the same answers. Returns the
+// bytes written, or -1 where `cap` is too small, an index is out of
+// range or a string is longer than a u16 says.
+int64_t guber_encode_string_answers(const int64_t* status,
+                                    const int64_t* limit,
+                                    const int64_t* remaining,
+                                    const int64_t* reset_time,
+                                    const int32_t* err, const int32_t* owner,
+                                    const uint8_t* strs,
+                                    const int64_t* str_off, int64_t m,
+                                    int64_t n, uint8_t* out, int64_t cap) {
+  uint8_t* p = out;
+  const uint8_t* const end = out + cap;
+  for (int64_t i = 0; i < n; ++i) {
+    if (end - p < 25) return -1;
+    *p++ = static_cast<uint8_t>(status[i]);
+    std::memcpy(p, &limit[i], 8);
+    std::memcpy(p + 8, &remaining[i], 8);
+    std::memcpy(p + 16, &reset_time[i], 8);
+    p += 24;
+    const int32_t idx[2] = {err[i], owner[i]};
+    for (int f = 0; f < 2; ++f) {
+      int64_t slen = 0;
+      const uint8_t* s = nullptr;
+      if (idx[f] >= 0) {
+        if (idx[f] >= m) return -1;
+        s = strs + str_off[idx[f]];
+        slen = str_off[idx[f] + 1] - str_off[idx[f]];
+        if (slen < 0 || slen > 0xFFFF) return -1;
+      }
+      if (end - p < 2 + slen) return -1;
+      *p++ = static_cast<uint8_t>(slen & 0xFF);
+      *p++ = static_cast<uint8_t>(slen >> 8);
+      if (slen) std::memcpy(p, s, slen);
+      p += slen;
+    }
+  }
+  return p - out;
+}
+
 }  // extern "C"
 
 // ---------------------------------------------------------------------------

@@ -847,6 +847,139 @@ def encode_peer_answers(status, limit, remaining, reset_time) -> bytes:
     return out[:m].tobytes()
 
 
+# -- the GEB door's split by owner and the forwarder's column RPC ------------
+# (guberhash.cc, same section: the converses of the three calls above)
+
+#: why guber_parse_peer_answers declined a reply, by its return code:
+#: PEER_DECLINE's names where the reason is shared
+ANSWER_DECLINE = {
+    -2: PEER_DECLINE[-2],
+    -3: PEER_DECLINE[-3],
+    -5: PEER_DECLINE[-5],
+    -6: PEER_DECLINE[-6],
+    -7: PEER_DECLINE[-7],
+    -11: "error_or_metadata",
+}
+
+try:  # absent in a stale prebuilt .so: the door declines to split
+    _lib.guber_ring_owners.restype = ctypes.c_int64
+    _lib.guber_ring_owners.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, _vp,
+        ctypes.c_int64, _vp,
+    ]
+    _lib.guber_encode_peer_batch.restype = ctypes.c_int64
+    _lib.guber_encode_peer_batch.argtypes = [ctypes.c_char_p] + [_vp] * 10 + [
+        ctypes.c_int64, _vp, ctypes.c_int64,
+    ]
+    _lib.guber_parse_peer_answers.restype = ctypes.c_int64
+    _lib.guber_parse_peer_answers.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+    ] + [_vp] * 4
+    _lib.guber_encode_string_answers.restype = ctypes.c_int64
+    _lib.guber_encode_string_answers.argtypes = [_vp] * 6 + [
+        ctypes.c_char_p, _vp, ctypes.c_int64, ctypes.c_int64, _vp,
+        ctypes.c_int64,
+    ]
+    _HAS_SPLIT = True
+except AttributeError:
+    _HAS_SPLIT = False
+
+#: the columns of parse_string_frame that guber_encode_peer_batch reads
+_FORWARD_COLUMNS = (
+    "name_off", "name_len", "key_off", "key_len", "hits", "limit",
+    "duration", "algo", "behavior",
+)
+
+
+def ring_owners(keys: bytes, n: int, ring: np.ndarray) -> np.ndarray:
+    """int32[n]: for each of the n hash keys in `keys` (joined by NUL,
+    as parse_string_frame returns them) the position on `ring` (uint32,
+    ascending crc32 points) of the key's successor, wrapping: the index
+    ConsistentHashPicker.get finds. One call with the GIL released."""
+    out = np.empty(n, np.int32)
+    if _lib.guber_ring_owners(
+        keys, len(keys), n, ring.ctypes.data, ring.shape[0], out.ctypes.data
+    ):
+        raise ValueError(f"the joined keys are not {n}")
+    return out
+
+
+def encode_peer_batch(payload: bytes, cols: dict, rows=None) -> bytes:
+    """The serialised GetPeerRateLimitsReq of the items `rows` (int32
+    indices, or every item) of one parsed string frame: `cols` as
+    parse_string_frame returned them for `payload`. The bytes the
+    protobuf runtime writes for the same requests (convert.req_to_pb);
+    one call, no object per item."""
+    if rows is None:
+        n = cols["hits"].shape[0]
+        lens = cols["name_len"], cols["key_len"]
+        rows_at = None
+    else:
+        rows = np.ascontiguousarray(rows, np.int32)
+        n = rows.shape[0]
+        lens = cols["name_len"][rows], cols["key_len"][rows]
+        rows_at = rows.ctypes.data
+    cap = int(lens[0].sum()) + int(lens[1].sum()) + n * 69
+    out = np.empty(cap, np.uint8)
+    m = _lib.guber_encode_peer_batch(
+        payload, *[cols[c].ctypes.data for c in _FORWARD_COLUMNS],
+        rows_at, n, out.ctypes.data, cap,
+    )
+    if m < 0:
+        raise ValueError("peer batch does not fit its buffer")
+    return out[:m].tobytes()
+
+
+def parse_peer_answers(wire: bytes, max_items: int):
+    """(n, (status, limit, remaining, reset_time)) of a serialised
+    GetPeerRateLimitsResp as four int64 columns, or (code < 0, None)
+    where the native parser declines (ANSWER_DECLINE: an item with an
+    error or metadata among them). One call, no object per item."""
+    if not _HAS_SPLIT:
+        return -7, None
+    cap = min(max_items, len(wire) // 2) + 1
+    cols = [np.empty(cap, np.int64) for _ in range(4)]
+    n = _lib.guber_parse_peer_answers(
+        wire, len(wire), max_items, *[c.ctypes.data for c in cols],
+    )
+    if n < 0:
+        return n, None
+    return n, tuple(c[:n] for c in cols)
+
+
+def encode_string_answers(
+    status, limit, remaining, reset_time, err, owner, strings
+) -> bytes:
+    """The items of a GEB string response frame from four answer
+    columns and, a row, an error text and an owner tag: `err` and
+    `owner` (int32) index `strings` (a list of bytes), -1 for none.
+    Byte for byte edge_bridge.encode_response_frame's items."""
+    cols = [
+        np.ascontiguousarray(c, np.int64)
+        for c in (status, limit, remaining, reset_time)
+    ]
+    n = cols[0].shape[0]
+    err = np.ascontiguousarray(err, np.int32)
+    owner = np.ascontiguousarray(owner, np.int32)
+    if any(c.shape != (n,) for c in (*cols, err, owner)):
+        raise ValueError("answer columns differ in length")
+    off = np.zeros(len(strings) + 1, np.int64)
+    np.cumsum(
+        np.fromiter(map(len, strings), np.int64, len(strings)), out=off[1:]
+    )
+    longest = max(map(len, strings), default=0)
+    cap = n * (29 + 2 * longest)
+    out = np.empty(cap, np.uint8)
+    m = _lib.guber_encode_string_answers(
+        *[c.ctypes.data for c in cols], err.ctypes.data, owner.ctypes.data,
+        b"".join(strings), off.ctypes.data, len(strings), n,
+        out.ctypes.data, cap,
+    )
+    if m < 0:
+        raise ValueError("answer strings do not fit the frame's items")
+    return out[:m].tobytes()
+
+
 # -- the traffic observers' per-batch fold (guberhash.cc, last section) ------
 
 try:  # absent in a stale prebuilt .so: TrafficStats keeps its Python classes

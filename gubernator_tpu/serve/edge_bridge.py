@@ -126,6 +126,23 @@ back in frame order. Applies to the pre-hashed framings (GEB6/GEB7)
 and the string fold alike; object-path string items get the same
 treatment inside Instance.get_rate_limits.
 
+The split by owner (PR 43): on a node that shares its ring, a string
+frame mostly holds keys of OTHER nodes (a client that does not route
+by owner sends nothing else). Such a frame is served as columns from
+the wire to the wire too: one owner index a row (the key's crc32 ring
+position, computed beside the parse), ONE shed screen over all rows,
+the owned residue to the batcher, each other owner's residue to that
+peer's forwarder as one column group (serve/peers.py
+PeerClient.forward_columns: same queue, batch limit, deadline, breaker
+and retry rule as a group of request objects), and one encode of the
+answer columns with an owner-tag column. What it does not carry — a
+GLOBAL or NO_BATCHING item another node owns, a chain, an invalid
+item, an open rescale transition, a backend or a library without the
+means — sends the whole frame through Instance.get_rate_limits as
+before, counted by reason (edge_split_declined_total); a forward that
+FAILS is rebuilt as request objects then and answered by the code
+that says what a failed forward means (Instance.forward_failed).
+
 Non-windowed frames (GEB1/GEB6) keep their one-in-flight round-trip
 semantics for version-skewed edges; a bridge serves both framings on
 the same connection. Malformed input closes the connection.
@@ -146,7 +163,11 @@ import time
 import zlib
 from typing import List, Optional
 
-from gubernator_tpu.api.columns import DECIDE_FIELDS, global_rows
+from gubernator_tpu.api.columns import (
+    DECIDE_FIELDS,
+    ForwardGroup,
+    global_rows,
+)
 from gubernator_tpu.api.types import (
     Algorithm,
     Behavior,
@@ -316,7 +337,7 @@ def _parse_string_native(payload: bytes, n: int):
     and that buffer as it came (the traffic observers fold it without
     a str: core/sketches.py TrafficStats.observe).
     None where libguberhash.so is not built, or the parser declines
-    (counted by reason): EdgeBridge._fold_string_frame then runs its
+    (counted by reason): EdgeBridge._fold_by_loop then runs its
     per-item loop over the same bytes."""
     lib = native_lib()
     if lib is None:
@@ -576,6 +597,68 @@ class _ConnWindow:
     def cancel_all(self) -> None:
         for t in list(self.tasks):
             t.cancel()
+
+
+class _Split:
+    """One string frame of mixed ownership on its way through the
+    split (FrameService._plan_split): the frame's parsed columns, the
+    four answer columns every lane writes its rows of, and beside them
+    an owner-tag and an error column that index `peers`' hosts and
+    `strings` — what ONE native encode turns into the response frame's
+    items."""
+
+    __slots__ = (
+        "payload", "cols", "fields", "full", "peers", "answers", "tag",
+        "err", "strings", "mine", "groups", "lanes",
+    )
+
+    def __init__(self, payload, cols, fields, full, peers, answers):
+        import numpy as np
+
+        self.payload = payload
+        self.cols = cols
+        self.fields = fields
+        self.full = full  # the hash keys, for an error's text
+        self.peers = peers  # the ring: tag g names peers[g].host
+        self.answers = answers  # status, limit, remaining, reset_time
+        self.tag = None  # int32[n]: the owner tag's string, -1 none
+        self.err = np.full(len(full), -1, np.int32)  # the error's
+        self.strings: List[bytes] = []  # past the ring's hosts
+        self.mine = None  # the owned residue's rows
+        self.groups: list = []  # (peer, its residue's rows)
+        self.lanes = (0, 0, 0)  # rows decided here, forwarded, shed
+
+    def _string(self, text: str) -> int:
+        self.strings.append(text.encode()[:0xFFFF])
+        return len(self.peers) + len(self.strings) - 1
+
+    def said(self, i: int, text: str) -> None:
+        """Row i carries the error text its owner sent, beside the
+        answer and under the tag it has."""
+        self.err[i] = self._string(text)
+
+    def fail(self, i: int, text: str) -> None:
+        """Row i answers with an error item of this node's making:
+        zeros, the text, no owner tag."""
+        for col in self.answers:
+            col[i] = 0
+        self.err[i] = self._string(text)
+        self.tag[i] = -1
+
+    def put(self, i: int, resp: RateLimitResp) -> None:
+        """Row i answers as `resp` does: what a failed forward's ladder
+        returned (Instance.forward_failed) — an answer under the tag of
+        whoever gave it, or an error item."""
+        if resp.error:
+            self.fail(i, resp.error)
+        else:
+            for col, v in zip(self.answers, (
+                int(resp.status), resp.limit, resp.remaining,
+                resp.reset_time,
+            )):
+                col[i] = v
+        host = resp.metadata.get("owner", "")
+        self.tag[i] = self._string(host) if host else -1
 
 
 class FrameService:
@@ -933,7 +1016,7 @@ class FrameService:
             for r in decoded
         ]
 
-    def _fold_string_frame(self, payload: bytes, n: int):
+    def _screen_string_frame(self, payload: bytes, n: int):
         """Lean parse + eligibility screen for the string->array fold
         (r7 slow-path owner batching, bridge side): the parse is one
         native call (_parse_string_native) and, where the library is
@@ -950,38 +1033,71 @@ class FrameService:
         Per-owner slow shards from the edge are all-owned by
         construction, so the GUBER_EDGE_FAST=0 kill switch and mixed
         fleets get fast-path treatment minus only the client-side
-        hashing. Returns (full_keys, fields, glob, route_s, packed) or
-        None: `glob` is [(index, name, unique_key)] of the GLOBAL items
-        in frame order, `route_s` the seconds of the ownership screen
-        and (in the loop; the native parse hashes off the wire) the key
+        hashing.
+
+        Ownership is ONE column over the parsed rows: each key's
+        position on the ring (ConsistentHashPicker.owner_column: crc32
+        and the search a key in one native call over the parser's
+        NUL-joined buffer). Returns (fold, mixed, reason), one of them
+        set. `fold` — every row owned, or a one-node ring, which asks
+        no key — is (full_keys, fields, glob, route_s, packed): `glob`
+        is [(index, name, unique_key)] of the GLOBAL items in frame
+        order, `route_s` the seconds of the ownership screen and (in
+        the loop; the native parse hashes off the wire) the key
         hashing — the Instance's share of the work, stamped
         `instance_route` by the caller — and `packed` the native
         parse's NUL-joined key bytes (None from the loop), for the
-        traffic observers. None falls back to the object
-        path, which keeps full semantics for per-item validation
-        errors and for ANY item this node does not own: a stale edge's
-        plain item is forwarded by the instance there, a non-owner's
-        GLOBAL item answered from the replica and its hit queued.
+        traffic observers. `mixed` — some rows are another node's — is
+        (full_keys, cols, packed, owner, route_s) for _plan_split,
+        `owner` the column. `reason` says why the frame is neither (a
+        label of edge_split_declined_total; '' where this node shares
+        its ring with nobody): the object path answers it, which keeps
+        full semantics for per-item validation errors and for whatever
+        the split does not carry.
         """
-        import numpy as np
-
-        from gubernator_tpu.core.hashing import slot_hash_batch
-
         picker = getattr(self.instance, "picker", None)
         mask_fn = getattr(picker, "self_owned_mask", None)
         if mask_fn is None or not getattr(picker, "size", lambda: 0)():
-            return None
+            return None, None, ""
+        own = picker.ring()[2]
+        # this node shares its ring: some point on it is another node's
+        shared = not own.all()
         parsed = _parse_string_native(payload, n)
         if parsed is not None:
             full, cols, packed = parsed
             t0 = time.monotonic()
-            if not mask_fn(full).all():
-                return None
+            owner = None
+            if shared:
+                owner = picker.owner_column(full, packed)
+                if own[owner].all():
+                    owner = None
             route_s = time.monotonic() - t0
+            if owner is not None:
+                return None, (full, cols, packed, owner, route_s), ""
             fields = {k: cols[k] for k in DECIDE_FIELDS}
             return (
                 full, fields, global_rows(payload, cols), route_s, packed
-            )
+            ), None, ""
+        # a frame the native parse did not take is never split: by the
+        # loop below it folds where every row is owned, else it is the
+        # object path's
+        fold = self._fold_by_loop(payload, n, mask_fn)
+        if fold is not None or not shared:
+            return fold, None, ""
+        lib = native_lib()
+        return None, None, (
+            "invalid_item"
+            if lib is not None and getattr(lib, "_HAS_STRING_FRAME", False)
+            else "no_native"
+        )
+
+    def _fold_by_loop(self, payload: bytes, n: int, mask_fn):
+        """_screen_string_frame's parse and ownership screen item by
+        item: the fold's 5-tuple, or None."""
+        import numpy as np
+
+        from gubernator_tpu.core.hashing import slot_hash_batch
+
         # the library is not built, or it declined the payload: the
         # per-item loop below is the same parse (and the oracle the
         # native one is tested against)
@@ -1058,6 +1174,48 @@ class FrameService:
         route_s += time.monotonic() - t0
         return full, fields, glob, route_s, None
 
+    def _note_frame(self, full, fields, glob, packed, mine=None) -> None:
+        """What the owner branch of Instance.get_rate_limits does item
+        by item, for one frame served as arrays: the traffic observers
+        see every key (names AND hashes feed the sketches: one native
+        fold of the hashes and `packed`, the parse's key bytes, with
+        the GIL released — core/sketches.py TrafficStats); the three
+        managers note the OWNED windows — every row of a folded frame,
+        the rows `mine` of a split one — which must dirty the
+        replication queue and join the rescale/checkpoint tracked sets
+        like any other owner decide, GLOBAL items included (pre-hashed
+        fast frames carry no key strings and cannot — documented scope
+        limit), one eligibility screen feeding all three; and every
+        GLOBAL item, shed-answered or device-decided alike, queues its
+        key's status broadcast (`glob` rows are owned: a foreign one
+        keeps its frame off the array routes)."""
+        inst = self.instance
+        inst.traffic.observe(full, fields["key_hash"], packed)
+        repl = getattr(inst, "repl", None)
+        resc = getattr(inst, "rescale", None)
+        ckpt = getattr(inst, "checkpoint", None)
+        if repl is not None or resc is not None or ckpt is not None:
+            from gubernator_tpu.serve.replication import (
+                eligible_field_indices,
+            )
+
+            # the managers take the owned rows alone; `glob` below
+            # indexes the FRAME, so the frame's columns keep their names
+            own_full, own_fields = full, fields
+            if mine is not None:
+                own_full = [full[i] for i in mine.tolist()]
+                own_fields = {k: v[mine] for k, v in fields.items()}
+            elig = eligible_field_indices(own_fields)
+            if repl is not None:
+                repl.queue_dirty_fields(own_full, own_fields, elig=elig)
+            if resc is not None:
+                resc.note_owned_fields(own_full, own_fields, elig=elig)
+            if ckpt is not None:
+                ckpt.note_owned_fields(own_full, own_fields, elig=elig)
+        if glob:
+            metrics.EDGE_FOLDED_GLOBAL_ITEMS.inc(len(glob))
+            inst.global_mgr.queue_update_fields(full, glob, fields)
+
     async def _decide_string_folded(
         self, full, fields, glob, route_s: float, n: int, packed=None
     ) -> bytes:
@@ -1065,45 +1223,14 @@ class FrameService:
         GEB4 response body (25-byte decisions + empty error/owner) in
         one numpy pass. Before the decide it does for the frame what
         the owner branch of Instance.get_rate_limits does item by
-        item: hot-key observability keeps full parity with the object
-        path (names AND hashes feed the sketches: one native fold of
-        the hashes and `packed`, the parse's key bytes, with the GIL
-        released — core/sketches.py TrafficStats), the three managers
-        note the owned windows, and every GLOBAL item — shed-answered
-        or device-decided alike — queues its key's status broadcast.
+        item (_note_frame).
         That work and the fold's ownership screen and key hashing
         (`route_s`) are the frame's ONE `instance_route` sample, as
         they are on the object path."""
         import numpy as np
 
         t_route0 = time.monotonic()
-        self.instance.traffic.observe(full, fields["key_hash"], packed)
-        repl = getattr(self.instance, "repl", None)
-        resc = getattr(self.instance, "rescale", None)
-        ckpt = getattr(self.instance, "checkpoint", None)
-        if repl is not None or resc is not None or ckpt is not None:
-            # folded frames are all-owned by construction: their
-            # windows must dirty the replication queue and join the
-            # rescale/checkpoint tracked sets like any other owner
-            # decide, GLOBAL items included (pre-hashed fast frames
-            # carry no key strings and cannot — documented scope
-            # limit). One eligibility screen feeds all three managers.
-            from gubernator_tpu.serve.replication import (
-                eligible_field_indices,
-            )
-
-            elig = eligible_field_indices(fields)
-            if repl is not None:
-                repl.queue_dirty_fields(full, fields, elig=elig)
-            if resc is not None:
-                resc.note_owned_fields(full, fields, elig=elig)
-            if ckpt is not None:
-                ckpt.note_owned_fields(full, fields, elig=elig)
-        if glob:
-            metrics.EDGE_FOLDED_GLOBAL_ITEMS.inc(len(glob))
-            self.instance.global_mgr.queue_update_fields(
-                full, glob, fields
-            )
+        self._note_frame(full, fields, glob, packed)
         STAGES.add(
             "instance_route", route_s + time.monotonic() - t_route0
         )
@@ -1118,31 +1245,257 @@ class FrameService:
             out["reset_time"] = reset
             return out.tobytes()
 
+    def _split_declined(self, reason: str) -> int:
+        """One string frame of a shared ring goes to the object path,
+        by its reason (edge_split_declined_total); returns how many
+        have for that reason."""
+        counts = self.instance.edge_split
+        counts.declined[reason] += 1
+        return counts.declined[reason]
+
+    def _shares_ring(self) -> bool:
+        picker = getattr(self.instance, "picker", None)
+        return getattr(picker, "size", lambda: 0)() > 1
+
+    def _plan_split(self, payload: bytes, n: int, mixed):
+        """The split of one string frame of mixed ownership, up to the
+        point where nothing has been sent anywhere: a _Split, or the
+        reason (a str) the frame is the object path's whole — an item
+        the split does not carry (a GLOBAL or NO_BATCHING one another
+        node owns: the replica path, the unary RPC), a state it does
+        not know (an open rescale transition reroutes items one by
+        one), a frame past the per-RPC cap, an instance that takes
+        no columns. Every check comes before the
+        first side effect.
+
+        Then, as the folded path does for a frame and the owner branch
+        of Instance.get_rate_limits item by item: the traffic observers
+        see every key, the three managers the OWNED rows, every GLOBAL
+        item (owned, by the rule above) queues its status broadcast —
+        with the ownership column's seconds the frame's ONE
+        `instance_route` sample — and ONE shed screen runs over all
+        rows: a cached refusal answers owned and foreign rows alike
+        (lookup_resp's place on the object path, and why fewer rows
+        cross than are foreign-owned), a foreign one under its owner's
+        tag. The residue is cut into the owned rows and one group a
+        foreign owner, in frame order; screen and cut are the frame's
+        first `shed` sample."""
+        import numpy as np
+
+        full, cols, packed, owner, route_s = mixed
+        inst = self.instance
+        why = inst.split_unavailable()
+        if why:
+            return why
+        if n > MAX_BATCH_SIZE:
+            return "too_many_items"
+        t_route0 = time.monotonic()
+        _, peers, own = inst.picker.ring()
+        owned = own[owner]
+        behavior = cols["behavior"]
+        foreign_behavior = behavior[~owned]
+        if (foreign_behavior == int(Behavior.GLOBAL)).any():
+            return "foreign_global"
+        if (foreign_behavior == int(Behavior.NO_BATCHING)).any():
+            return "foreign_no_batching"
+        resc = getattr(inst, "rescale", None)
+        if resc is not None and resc._transition is not None:
+            return "rescale_transition"
+
+        fields = {k: cols[k] for k in DECIDE_FIELDS}
+        self._note_frame(
+            full, fields, global_rows(payload, cols), packed,
+            mine=np.flatnonzero(owned),
+        )
+        t_shed0 = time.monotonic()
+        STAGES.add("instance_route", route_s + t_shed0 - t_route0)
+
+        shed = getattr(inst, "shed", None)
+        screened = None
+        if shed is not None:
+            shed.refresh_generation()
+            screened = shed.screen_fields(fields)
+        if screened is None:
+            todo = np.ones(n, bool)
+            answers = tuple(np.zeros(n, np.int64) for _ in range(4))
+        else:
+            mask, answers = screened
+            todo = ~mask
+        plan = _Split(payload, cols, fields, full, peers, answers)
+        # the owner tag a forwarded answer carries, shed or not
+        # (Instance.get_rate_limits: metadata["owner"] = peer.host)
+        plan.tag = np.where(owned, -1, owner).astype(np.int32)
+        plan.mine = np.flatnonzero(todo & owned)
+        for g in np.flatnonzero(~own).tolist():
+            rows = np.flatnonzero(todo & (owner == g))
+            if rows.shape[0]:
+                plan.groups.append((peers[g], rows))
+        sent = sum(rows.shape[0] for _, rows in plan.groups)
+        plan.lanes = (plan.mine.shape[0], sent, n - plan.mine.shape[0] - sent)
+        _stamp_shed(time.monotonic() - t_shed0)
+        return plan
+
+    async def _serve_split(self, plan: "_Split") -> bytes:
+        """Serve a planned split to the response frame's items: the
+        foreign groups to their owners' forwarders at once (ONE queue
+        entry and one future a group, so their RPCs overlap the local
+        device batch), the owned residue through the batcher as the
+        frame's one `batch_queue` / `device` sample, then the wait for
+        the last group (`forward_wait`, as get_rate_limits stamps it)
+        and ONE encode over the answer columns, the owner-tag column
+        and the error texts. From here on a failure is an error item
+        for the rows it struck, in the object path's words: nothing
+        raises out of a lane."""
+        inst = self.instance
+        tasks = [
+            asyncio.ensure_future(self._forward_rows(plan, peer, rows))
+            for peer, rows in plan.groups
+        ]
+        mine = plan.mine
+        if mine.shape[0]:
+            try:
+                residue = {k: v[mine] for k, v in plan.fields.items()}
+                res = await self._decide_arrays_chunked(
+                    residue, mine.shape[0]
+                )
+                t0 = time.monotonic()
+                shed = getattr(inst, "shed", None)
+                if shed is not None:
+                    shed.observe_fields(residue, res)
+                for col, got in zip(plan.answers, res):
+                    col[mine] = got
+                _stamp_shed(time.monotonic() - t0)
+            except Exception as e:
+                for i in mine.tolist():
+                    plan.fail(
+                        i,
+                        f"while applying rate limit for "
+                        f"'{plan.full[i]}' - '{e}'",
+                    )
+        if tasks:
+            t_wait = time.monotonic()
+            await asyncio.gather(*tasks)
+            # the forward lane's excess over the local lane: what tiles
+            # a frame that waited on a peer (stages.py forward_wait)
+            STAGES.add("forward_wait", time.monotonic() - t_wait)
+        with STAGES.span("encode"):
+            strings = [p.host.encode() for p in plan.peers] + plan.strings
+            return native_lib().encode_string_answers(
+                *plan.answers, plan.err, plan.tag, strings
+            )
+
+    async def _forward_rows(self, plan: "_Split", peer, rows) -> None:
+        """One foreign group of a split frame: through the owner's
+        forwarder as columns (PeerClient.forward_columns), its answers
+        written into the frame's columns and into the shed cache, as
+        forward_group does with response objects. A forward that FAILS
+        is rebuilt as request objects then and handed to the code that
+        says what a failed forward means (Instance.forward_failed:
+        takeover, degraded, the per-item error text); whatever comes
+        back — answers under another node's tag, error items — lands in
+        the same columns. Never raises."""
+        import numpy as np
+
+        inst = self.instance
+        group = ForwardGroup(plan.payload, plan.cols, rows)
+        tr = tracing.active()
+        t_fwd = time.monotonic() if tr is not None else 0.0
+        try:
+            got = await peer.forward_columns(group)
+            if tr is not None:
+                tr.add_span(
+                    "peer_forward", start=t_fwd,
+                    peer=peer.host, items=len(group),
+                )
+            for col, ans in zip(
+                plan.answers,
+                (got.status, got.limit, got.remaining, got.reset_time),
+            ):
+                col[rows] = ans
+            for j, text in got.errors.items():
+                plan.said(int(rows[j]), text)
+            shed = getattr(inst, "shed", None)
+            if shed is not None:
+                fields = group.fields()
+                res = (got.status, got.limit, got.remaining, got.reset_time)
+                if got.opaque:
+                    keep = np.ones(len(group), bool)
+                    keep[got.opaque] = False
+                    fields = {k: v[keep] for k, v in fields.items()}
+                    res = tuple(c[keep] for c in res)
+                shed.observe_fields(fields, res)
+        except Exception as e:
+            idx = rows.tolist()
+            try:
+                resps = await inst.forward_failed(
+                    list(zip(idx, group.requests())), peer, e
+                )
+            except Exception as e2:  # the failure code itself failed
+                resps = [
+                    RateLimitResp(
+                        error=(
+                            f"while fetching rate limit '{plan.full[i]}' "
+                            f"from peer - '{e2}'"
+                        )
+                    )
+                    for i in idx
+                ]
+            for i, resp in zip(idx, resps):
+                plan.put(i, resp)
+
     async def _decide_string_frame(
         self, payload: bytes, n: int, magic=MAGIC_RESP, frame_id=None
     ) -> bytes:
         """Serve one string frame to a complete encoded response frame.
-        Tries the array fold first; anything it declines rides the
-        object path through the full instance."""
+        Tries the array routes first — the fold where this node owns
+        every key, the split by owner where it does not; anything they
+        decline rides the object path through the full instance."""
         t_dec = time.monotonic()
-        fold = None
-        if self.string_fold and n and self._arrays_ok():
-            fold = self._fold_string_frame(payload, n)
-        if fold is not None:
-            full, fields, glob, route_s, packed = fold
+        fold = mixed = plan = None
+        reason = ""
+        if n:
+            if self.string_fold and self._arrays_ok():
+                fold, mixed, reason = self._screen_string_frame(payload, n)
+            elif self._shares_ring():
+                reason = "no_arrays"
+        t_screened = time.monotonic()
+        if mixed is not None:
+            try:
+                plan = self._plan_split(payload, n, mixed)
+            except Exception:
+                # nothing has been sent anywhere: the object path
+                # answers the frame, and the fault is counted and said
+                plan = None
+                if self._split_declined("error") <= 5:
+                    log.exception(
+                        "the split of a string frame by owner failed "
+                        "before it sent a row; the object path serves it"
+                    )
+            if isinstance(plan, str):
+                plan, reason = None, plan
+        if reason:
+            self._split_declined(reason)
+        if fold is not None or plan is not None:
             metrics.EDGE_FOLDED_ITEMS.inc(n)
-            # the lean parse alone: the fold's ownership screen and
-            # key hashing are stamped with the rest of the frame's
-            # routing work (_decide_string_folded)
-            STAGES.add(
-                "bridge_decode", time.monotonic() - t_dec - route_s
-            )
+            # the lean parse alone: the ownership screen and the key
+            # hashing are stamped with the rest of the frame's routing
+            # work (_decide_string_folded, _plan_split)
+            route_s = fold[3] if fold is not None else mixed[-1]
+            STAGES.add("bridge_decode", t_screened - t_dec - route_s)
             hdr = _HDR.pack(magic, n)
             if frame_id is not None:
                 hdr += struct.pack("<I", frame_id)
+        if fold is not None:
+            full, fields, glob, route_s, packed = fold
             return hdr + await self._decide_string_folded(
                 full, fields, glob, route_s, n, packed
             )
+        if plan is not None:
+            counts = self.instance.edge_split
+            counts.frames += 1
+            for lane, rows in zip(("owned", "forwarded", "shed"), plan.lanes):
+                counts.items[lane] += rows
+            return hdr + await self._serve_split(plan)
         resps = await self._decide_string(payload, n)
         with STAGES.span("encode"):
             return encode_response_frame(
@@ -1245,6 +1598,8 @@ class FrameService:
                     # object path — chains need the instance's
                     # routing/validation and are never foldable
                     # (coupled multi-key decides)
+                    if self._shares_ring():
+                        self._split_declined("chain")
                     resps = await self._decide_string(
                         payload, n, decoder=decode_chain_request_frame
                     )
@@ -1639,6 +1994,8 @@ class FrameService:
                         frame = _HDR.pack(MAGIC_FAST_RESP, n) + raw
                 elif magic == MAGIC_WCHAIN:
                     # chain-extended items (r15): object path only
+                    if self._shares_ring():
+                        self._split_declined("chain")
                     resps = await self._decide_string(
                         payload, n, decoder=decode_chain_request_frame
                     )

@@ -36,7 +36,7 @@ import logging
 import time
 from typing import List, Optional, Sequence, Tuple
 
-from gubernator_tpu.api.columns import PeerAnswers, PeerBatch
+from gubernator_tpu.api.columns import PeerAnswers, PeerBatch, split_ready
 from gubernator_tpu.api.types import (
     Behavior,
     HealthCheckResp,
@@ -56,6 +56,7 @@ from gubernator_tpu.serve.peers import (
     ConsistentHashPicker,
     ForwardCounts,
     PeerClient,
+    SplitCounts,
 )
 from gubernator_tpu.serve.shedcache import screened_decide
 from gubernator_tpu.serve.stages import STAGES
@@ -153,6 +154,11 @@ class Instance:
         # PeerClient this instance builds, exported at scrape
         # (peer_forward_*_total)
         self.peer_forward = ForwardCounts()
+        # the GEB door's split of mixed-ownership string frames
+        # (serve/edge_bridge.py): frames served as columns, their items
+        # by lane, frames declined to the object path by reason;
+        # exported at scrape (edge_split_*_total)
+        self.edge_split = SplitCounts()
         # bucket replication (r11, serve/replication.py): owned windows
         # snapshot to each key's ring successor so a killed owner's
         # quota state survives takeover. OFF by default
@@ -415,7 +421,6 @@ class Instance:
         STAGES.add("instance_route", time.monotonic() - t_route0)
 
         async def forward(i, r, peer):
-            key = r.hash_key()
             tr = tracing.active()
             t_fwd = time.monotonic() if tr is not None else 0.0
             try:
@@ -429,19 +434,7 @@ class Instance:
                 if shed is not None and not r.chain:
                     shed.observe_resps([fps[i]], [r], [resp])
             except Exception as e:
-                taken = await self._takeover_fallback([(i, r)], peer, e)
-                if taken is not None:
-                    out[i] = taken[0]
-                    return
-                degraded = await self._degraded_fallback([(i, r)], peer, e)
-                if degraded is not None:
-                    out[i] = degraded[0]
-                    return
-                resp = RateLimitResp(
-                    error=(
-                        f"while fetching rate limit '{key}' from peer - '{e}'"
-                    )
-                )
+                (resp,) = await self.forward_failed([(i, r)], peer, e)
             out[i] = resp
 
         async def forward_group(peer, items):
@@ -482,23 +475,9 @@ class Instance:
                             [resp for _, _, resp in plain],
                         )
             except Exception as e:
-                taken = await self._takeover_fallback(items, peer, e)
-                if taken is not None:
-                    for (i, _), resp in zip(items, taken):
-                        out[i] = resp
-                    return
-                degraded = await self._degraded_fallback(items, peer, e)
-                if degraded is not None:
-                    for (i, _), resp in zip(items, degraded):
-                        out[i] = resp
-                    return
-                for i, r in items:
-                    out[i] = RateLimitResp(
-                        error=(
-                            f"while fetching rate limit "
-                            f"'{r.hash_key()}' from peer - '{e}'"
-                        )
-                    )
+                failed = await self.forward_failed(items, peer, e)
+                for (i, _), resp in zip(items, failed):
+                    out[i] = resp
 
         # group BATCHING forwards per owner; NO_BATCHING keeps its
         # direct-unary contract (reference peers.go:73-90)
@@ -643,6 +622,32 @@ class Instance:
         if seeds:
             await self._install_seeds(seeds)
         return await self.decide_local(reqs, [False] * len(reqs))
+
+    async def forward_failed(
+        self, items, peer, exc
+    ) -> List[RateLimitResp]:
+        """What a forward that FAILED means, for every caller that
+        forwards (get_rate_limits' forward and forward_group, the GEB
+        door's split by owner): `items` [(index, req)] were bound for
+        `peer` and `exc` came back. The ladder: successor takeover,
+        then degraded local answers, then one error item an item that
+        names the key and the cause — never a pass, never a dropped
+        row."""
+        taken = await self._takeover_fallback(items, peer, exc)
+        if taken is not None:
+            return taken
+        degraded = await self._degraded_fallback(items, peer, exc)
+        if degraded is not None:
+            return degraded
+        return [
+            RateLimitResp(
+                error=(
+                    f"while fetching rate limit '{r.hash_key()}' "
+                    f"from peer - '{exc}'"
+                )
+            )
+            for _, r in items
+        ]
 
     async def _takeover_fallback(self, items, peer, exc):
         """Successor takeover (GUBER_REPLICATION=1): a forward that
@@ -1191,6 +1196,27 @@ class Instance:
             if peer is not None:
                 await peer.close()
         log.info("peers updated: %s", [p.address for p in peers])
+        if picker.size() > 1:
+            why = self.split_unavailable()
+            log.info(
+                "ring of %d: a string frame that holds other nodes' keys "
+                "is %s", picker.size(),
+                "split by owner as columns (owned rows to the batcher, "
+                "the others' to their owners' forwarders, one encode)"
+                if not why else
+                f"served through request objects, every item of it ({why})",
+            )
+
+    def split_unavailable(self) -> str:
+        """Why this node's GEB door cannot split a string frame of
+        mixed ownership by owner as columns, '' where it can: what the
+        door reads from its instance before it looks at a frame (a
+        label of edge_split_declined_total)."""
+        if not is_device_backend(self.backend):
+            return "no_arrays"
+        if not split_ready():
+            return "no_native"
+        return ""
 
     def get_peer(self, key: str) -> PeerClient:
         return self.picker.get(key)

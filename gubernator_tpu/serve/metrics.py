@@ -117,8 +117,9 @@ EDGE_FAST_ITEMS = Counter(
 EDGE_FOLDED_ITEMS = Counter(
     "edge_folded_items_total",
     "String-frame items served through the bridge's string->array fold "
-    "(all-valid all-owned frames skip request/response objects and "
-    "instance routing) — the slow path's share of fast-path treatment",
+    "(all-valid frames skip request/response objects and instance "
+    "routing: every key owned, or — edge_split_frames_total — split "
+    "by owner) — the slow path's share of fast-path treatment",
     registry=REGISTRY,
 )
 EDGE_FOLDED_GLOBAL_ITEMS = Counter(
@@ -308,6 +309,37 @@ PEER_FORWARD_FAILED_ITEMS = Gauge(
     "transport (refused, reset, an application error), closed (the "
     "client was replaced under its caller). Each failed RPC also logs "
     "one WARNING with the peer, the items and the seconds waited",
+    ["reason"],
+    registry=REGISTRY,
+)
+EDGE_SPLIT_FRAMES = Gauge(
+    "edge_split_frames_total",
+    "String frames of mixed ownership the GEB door served split by "
+    "owner as columns (serve/edge_bridge.py _plan_split: one owner "
+    "index a row, the owned rows to the batcher, each other node's to "
+    "its forwarder as a column group, one encode); plain ints exported "
+    "lazily at scrape. 0 on a node that shares its ring with nobody",
+    registry=REGISTRY,
+)
+EDGE_SPLIT_ITEMS = Gauge(
+    "edge_split_items_total",
+    "The items of those frames by lane: owned (decided here), "
+    "forwarded (sent to the peer that owns them: they are "
+    "peer_forward_items_total's too), shed (answered over-limit from "
+    "the host cache, owned and foreign alike). The three sum to the "
+    "split frames' items, which are counted in "
+    "edge_folded_items_total as well",
+    ["lane"],
+    registry=REGISTRY,
+)
+EDGE_SPLIT_DECLINED = Gauge(
+    "edge_split_declined_total",
+    "String frames that a node on a shared ring served through "
+    "request objects, every item of them, by reason "
+    "(serve/peers.py SPLIT_DECLINE_REASONS): invalid_item, chain, "
+    "foreign_global, foreign_no_batching, rescale_transition, "
+    "too_many_items, no_arrays, no_native, error. Their items are "
+    "edge_object_items_total's",
     ["reason"],
     registry=REGISTRY,
 )

@@ -26,10 +26,15 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import grpc
 
 from gubernator_tpu.api import convert
+from gubernator_tpu.api.columns import (
+    ForwardAnswers,
+    ForwardGroup,
+    split_ready,
+)
 from gubernator_tpu.api.grpc_glue import PeersV1Stub
 from gubernator_tpu.api.proto.gen import peers_pb2
 from gubernator_tpu.api.types import Behavior, RateLimitReq, RateLimitResp
-from gubernator_tpu.core.hashing import ring_hash
+from gubernator_tpu.core.hashing import native_lib, ring_hash
 from gubernator_tpu.serve import metrics, tracing
 from gubernator_tpu.serve.aio import collect_batch
 from gubernator_tpu.serve.breaker import (
@@ -63,6 +68,37 @@ class ForwardCounts:
         self.batches = 0  # GetPeerRateLimits RPCs sent
         self.items = 0  # the items in them
         self.failed = dict.fromkeys(FORWARD_FAIL_REASONS, 0)  # items
+
+
+#: why the GEB door served a string frame on a shared ring through
+#: request objects and not as columns: the label values of
+#: edge_split_declined_total (serve/edge_bridge.py _plan_split)
+SPLIT_DECLINE_REASONS = (
+    "invalid_item",  # the native parse declined the payload
+    "chain",  # a GEBC chain frame
+    "foreign_global",  # a GLOBAL item another node owns (replica path)
+    "foreign_no_batching",  # a NO_BATCHING one (its own unary RPC)
+    "rescale_transition",  # an open double-serve window reroutes items
+    "too_many_items",  # a frame past the per-RPC cap
+    "no_arrays",  # a backend that takes no arrays, or the fold off
+    "no_native",  # libguberhash.so not built, or without the symbols
+    "error",  # the split raised before a row was sent anywhere
+)
+
+
+class SplitCounts:
+    """The GEB door's split by owner in plain ints, one object an
+    Instance, shared by its doors and exported at scrape
+    (edge_split_*_total)."""
+
+    __slots__ = ("frames", "items", "declined")
+
+    def __init__(self):
+        self.frames = 0  # string frames served split by owner
+        # their items by lane: decided here, sent to the peer that owns
+        # them, answered from the shed cache (owned and foreign alike)
+        self.items = dict.fromkeys(("owned", "forwarded", "shed"), 0)
+        self.declined = dict.fromkeys(SPLIT_DECLINE_REASONS, 0)  # frames
 
 
 class PeerDeadlineError(asyncio.TimeoutError):
@@ -112,6 +148,44 @@ def is_retryable(exc: BaseException, all_peek: bool = False) -> bool:
     return False
 
 
+class _WireReply:
+    """One GetPeerRateLimits reply taken as BYTES (a flusher batch's
+    RPC): four answer columns by one native parse, or, where
+    the parser declines (an item with an error or metadata, odd wire, a
+    library without the symbol), the protobuf runtime's items. Raises
+    what the message path raises: a reply that is not the message, or
+    one of another length than the batch."""
+
+    __slots__ = ("cols", "items")
+
+    def __init__(self, wire: bytes, n: int):
+        lib = native_lib()
+        got, self.cols = (
+            lib.parse_peer_answers(wire, n) if lib is not None
+            else (-7, None)
+        )
+        self.items = None
+        if got < 0:
+            self.items = peers_pb2.GetPeerRateLimitsResp.FromString(
+                wire
+            ).rate_limits
+            got = len(self.items)
+        if got != n:
+            raise RuntimeError(
+                "peer responded with mismatched rate limit list size"
+            )
+
+    def answers(self, a: int, b: int) -> ForwardAnswers:
+        if self.items is not None:
+            return ForwardAnswers.from_resps(self.items[a:b])
+        return ForwardAnswers(*(c[a:b] for c in self.cols))
+
+    def resps(self, a: int, b: int) -> List[RateLimitResp]:
+        if self.items is not None:
+            return [convert.resp_from_pb(p) for p in self.items[a:b]]
+        return self.answers(a, b).resps()
+
+
 class PeerClient:
     """Connection to one peer (possibly this server itself)."""
 
@@ -134,9 +208,11 @@ class PeerClient:
         self.channel: Optional[grpc.aio.Channel] = None
         self.stub: Optional[PeersV1Stub] = None
         # queue items are GROUPS: (reqs list, future resolving to the
-        # matching resps list). One future per group (r7 owner
-        # batching): a request batch forwarding hundreds of items to
-        # one owner costs one enqueue + one future, not one per item.
+        # matching resps list) or (ForwardGroup, future resolving to
+        # its ForwardAnswers: forward_columns). One future per group
+        # (r7 owner batching): a request batch forwarding hundreds of
+        # items to one owner costs one enqueue + one future, not one
+        # per item.
         self._queue: "asyncio.Queue[Tuple[List[RateLimitReq], asyncio.Future]]" = (  # noqa: E501
             asyncio.Queue()
         )
@@ -265,13 +341,31 @@ class PeerClient:
         still coalesces with other callers' groups up to batch_limit
         — same wire behavior as per-item enqueueing, a fraction of the
         event-loop cost."""
+        if not reqs:
+            self._refuse_closed(0)
+            return []
+        return await self._enqueue(list(reqs))
+
+    async def forward_columns(self, group: ForwardGroup) -> ForwardAnswers:
+        """get_peer_rate_limits_grouped for a door that holds columns
+        and no request objects (the GEB door's split by owner): the
+        same queue, flusher, batch limit, deadline, breaker and retry
+        rule; the RPC's bytes are serialised from the group's columns
+        and key bytes and its reply parsed back to columns, one native
+        call each (_forward_wire). Raises what the grouped call raises;
+        the caller then rebuilds request objects (ForwardGroup.requests)
+        for the failure code that exists."""
+        return await self._enqueue(group)
+
+    def _refuse_closed(self, n: int) -> None:
         if self._closed:
-            self.counts.failed["closed"] += len(reqs)
+            self.counts.failed["closed"] += n
             raise RuntimeError(
                 f"peer client for '{self.host}' is closed"
             )
-        if not reqs:
-            return []
+
+    async def _enqueue(self, group):
+        self._refuse_closed(len(group))
         fut: asyncio.Future = asyncio.get_running_loop().create_future()
         # the caller's trace context rides the queue entry (r16): the
         # flusher task that sends the batched RPC runs outside the
@@ -280,8 +374,7 @@ class PeerClient:
         # stamp rides it too: forward_queue ends where the flusher
         # starts to build the group's RPC
         self._queue.put_nowait(
-            (list(reqs), fut, tracing.propagation_header(),
-             time.monotonic())
+            (group, fut, tracing.propagation_header(), time.monotonic())
         )
         return await fut
 
@@ -290,14 +383,92 @@ class PeerClient:
         reqs: Sequence[RateLimitReq],
         traceparent: Optional[str] = None,
     ) -> List[RateLimitResp]:
-        """One GetPeerRateLimits RPC with the forwarder's stages
-        (forward_encode, forward_rpc, forward_decode: serve/stages.py
-        PER_FORWARD), its counters, and for a failure one WARNING line
-        and an error that says which deadline passed."""
+        """One GetPeerRateLimits RPC as messages, for a caller that
+        goes round the flusher (a NO_BATCHING forward, GLOBAL gossip),
+        with the forwarder's stages (forward_encode, forward_rpc,
+        forward_decode: serve/stages.py PER_FORWARD), its counters, and
+        for a failure one WARNING line and an error that says which
+        deadline passed."""
         t_enc = time.monotonic()
         pb_req = peers_pb2.GetPeerRateLimitsReq(
             requests=[convert.req_to_pb(r) for r in reqs]
         )
+
+        async def send(timeout, kw):
+            pb_resp = await self.stub.GetPeerRateLimits(
+                pb_req, timeout=timeout, **kw
+            )
+            if len(pb_resp.rate_limits) != len(reqs):
+                raise RuntimeError(
+                    "peer responded with mismatched rate limit list size"
+                )
+            return pb_resp, 0.0
+
+        # a batch of pure peeks (hits all 0) is idempotent end to end;
+        # anything carrying hits only retries transport-level failures
+        # (is_retryable) so a slow peer is never double-counted
+        pb_resp, t_got = await self._forward_rpc(
+            send, len(reqs), all(r.hits == 0 for r in reqs), t_enc,
+            traceparent,
+        )
+        resps = [convert.resp_from_pb(p) for p in pb_resp.rate_limits]
+        STAGES.add("forward_decode", time.monotonic() - t_got)
+        return resps
+
+    async def _forward_wire(self, batch, traceparent) -> _WireReply:
+        """get_peer_rate_limits for a flusher batch: the request is the
+        groups' serialised bytes end to end (a repeated field's
+        serialisations concatenate) — a ForwardGroup by one native
+        call, a group of request objects by the runtime — sent through
+        the stub's pass-through method, and the reply's bytes are
+        parsed to columns inside the same deadline, breaker and retry
+        envelope that checks the message path's reply. The same three
+        stages and counters; the parse's seconds are forward_decode's,
+        not forward_rpc's."""
+        t_enc = time.monotonic()
+        parts, idempotent = [], True
+        for group, *_ in batch:
+            if isinstance(group, ForwardGroup):
+                parts.append(group.to_wire())
+                idempotent = idempotent and group.all_peeks()
+            else:
+                parts.append(
+                    peers_pb2.GetPeerRateLimitsReq(
+                        requests=[convert.req_to_pb(r) for r in group]
+                    ).SerializeToString()
+                )
+                idempotent = idempotent and all(
+                    r.hits == 0 for r in group
+                )
+        wire = b"".join(parts)
+        n = sum(len(group) for group, *_ in batch)
+
+        async def send(timeout, kw):
+            raw = await self.stub.GetPeerRateLimitsWire(
+                wire, timeout=timeout, **kw
+            )
+            t = time.monotonic()
+            reply = _WireReply(raw, n)
+            return reply, time.monotonic() - t
+
+        reply, t_got = await self._forward_rpc(
+            send, n, idempotent, t_enc, traceparent
+        )
+        STAGES.add("forward_decode", time.monotonic() - t_got)
+        return reply
+
+    async def _forward_rpc(
+        self, send, n: int, idempotent: bool, t_enc: float,
+        traceparent: Optional[str],
+    ):
+        """The part of a forward that does not depend on what its bytes
+        were made from: `send(timeout, metadata kwargs)` -> (reply,
+        the seconds it spent decoding the reply once it had it) inside
+        the deadline, breaker and retry envelope, the RPC's counters,
+        forward_encode (since `t_enc`) and forward_rpc (less those
+        seconds, which are forward_decode's), and for a failure one
+        WARNING line and an error that says which deadline passed.
+        Returns (the reply, the stamp forward_decode starts from)."""
         timeout = self.conf.effective_peer_timeout()
         if traceparent is None:
             # direct callers (NO_BATCHING forwards, GLOBAL gossip) run
@@ -312,37 +483,22 @@ class PeerClient:
             if traceparent
             else {}
         )
-
-        async def call():
-            pb_resp = await self.stub.GetPeerRateLimits(
-                pb_req, timeout=timeout or None, **kw
-            )
-            if len(pb_resp.rate_limits) != len(reqs):
-                raise RuntimeError(
-                    "peer responded with mismatched rate limit list size"
-                )
-            return pb_resp
-
         counts = self.counts
         counts.batches += 1
-        counts.items += len(reqs)
+        counts.items += n
         # bare stamps: forward_rpc crosses an await
         t_sent = time.monotonic()
         STAGES.add("forward_encode", t_sent - t_enc)
         try:
-            # a batch of pure peeks (hits all 0) is idempotent end to
-            # end; anything carrying hits only retries transport-level
-            # failures (is_retryable) so a slow peer is never
-            # double-counted
-            pb_resp = await self._call_resilient(
-                call, idempotent=all(r.hits == 0 for r in reqs),
+            reply, decoded_s = await self._call_resilient(
+                lambda: send(timeout or None, kw), idempotent=idempotent,
                 timeout=timeout,
             )
         except Exception as e:
             waited = time.monotonic() - t_sent
             STAGES.add("forward_rpc", waited)
             reason = fail_reason(e)
-            counts.failed[reason] += len(reqs)
+            counts.failed[reason] += n
             if reason == "deadline":
                 knob = (
                     "GUBER_PEER_TIMEOUT_MS"
@@ -350,7 +506,7 @@ class PeerClient:
                     else "GUBER_BATCH_TIMEOUT_MS"
                 )
                 e = PeerDeadlineError(
-                    f"no answer from peer '{self.host}' for {len(reqs)} "
+                    f"no answer from peer '{self.host}' for {n} "
                     f"item(s) after {waited:.3f} s: past the deadline "
                     f"{knob} = {timeout * 1e3:g} ms "
                     f"(a batch that carries hits is not sent again)"
@@ -358,15 +514,13 @@ class PeerClient:
             log.warning(
                 "forward to peer '%s' failed (%s): %d item(s), waited "
                 "%.3f s - %s",
-                self.host, reason, len(reqs), waited,
+                self.host, reason, n, waited,
                 e if str(e) else type(e).__name__,
             )
             raise e
-        t_got = time.monotonic()
+        t_got = time.monotonic() - decoded_s
         STAGES.add("forward_rpc", t_got - t_sent)
-        resps = [convert.resp_from_pb(p) for p in pb_resp.rate_limits]
-        STAGES.add("forward_decode", time.monotonic() - t_got)
-        return resps
+        return reply, t_got
 
     async def update_peer_globals(self, updates) -> None:
         """updates: sequence of (key, RateLimitResp). Installing a
@@ -548,14 +702,17 @@ class PeerClient:
         t_collected = time.monotonic()
         for group in batch:
             STAGES.add("forward_queue", t_collected - group[3])
-        reqs = [r for g, *_ in batch for r in g]
         # one traceparent per RPC: micro-batching can coalesce groups
         # from different traced callers, so the FIRST traced group's
         # context represents the wire hop (documented scope limit —
         # head sampling makes same-flush collisions rare)
         tp = next((g[2] for g in batch if g[2]), None)
+        # every flusher batch goes out as bytes, whoever queued its
+        # groups (_forward_wire); get_peer_rate_limits, the message
+        # path, is the direct callers' (NO_BATCHING forwards, GLOBAL
+        # gossip)
         try:
-            resps = await self.get_peer_rate_limits(reqs, traceparent=tp)
+            reply = await self._forward_wire(batch, tp)
         except Exception as e:  # entire batch failed (peers.go:186-192)
             for _, fut, *_ in batch:
                 if not fut.done():
@@ -565,10 +722,13 @@ class PeerClient:
             return
         k = 0
         for g, fut, *_ in batch:
-            span = resps[k : k + len(g)]
-            k += len(g)
-            if not fut.done():
-                fut.set_result(span)
+            a, k = k, k + len(g)
+            if fut.done():
+                continue
+            if isinstance(g, ForwardGroup):
+                fut.set_result(reply.answers(a, k))
+            else:
+                fut.set_result(reply.resps(a, k))
 
 
 class ConsistentHashPicker:
@@ -579,6 +739,9 @@ class ConsistentHashPicker:
         self._keys: List[int] = []
         self._by_point: Dict[int, PeerClient] = {}
         self._by_host: Dict[str, PeerClient] = {}
+        # (ring points uint32, their peers) for owner_column: rebuilt
+        # after an add, and a picker is never added to once it serves
+        self._ring_cache = None
 
     def new(self) -> "ConsistentHashPicker":
         return ConsistentHashPicker(self._hash)
@@ -603,6 +766,7 @@ class ConsistentHashPicker:
             bisect.insort(self._keys, point)
         self._by_point[point] = peer
         self._by_host[peer.host] = peer
+        self._ring_cache = None
 
     def size(self) -> int:
         return len(self._keys)
@@ -674,30 +838,61 @@ class ConsistentHashPicker:
                 entry[1].append(key)
         return out
 
-    def self_owned_mask(self, keys: Sequence[str]):
-        """bool[len(keys)]: the key's ring successor is this server
-        itself (is_owner). Vectorized ownership screen for the edge
-        bridge's string->array fold (r7): one hash call per key plus a
-        single searchsorted against the ring, instead of a get() with
-        its dict lookups per key. Placement parity with get():
-        bisect_left == searchsorted side='left', wraparound to 0."""
+    def ring(self):
+        """(points uint64[m] ascending, [the peer at each point],
+        is_owner bool[m]): the ring as owner_column indexes it."""
+        import numpy as np
+
+        cached = self._ring_cache
+        if cached is None:
+            cached = self._ring_cache = (
+                np.asarray(self._keys, dtype=np.uint64),
+                [self._by_point[p] for p in self._keys],
+            )
+        points, peers = cached
+        own = np.fromiter(
+            (p.is_owner for p in peers), dtype=bool, count=len(peers)
+        )
+        return points, peers, own
+
+    def owner_column(self, keys: Sequence[str], packed=None):
+        """int32[len(keys)]: each key's position on ring() — the peer
+        get() returns, key for key (bisect_left == lower bound,
+        wraparound to 0). With `packed` (the keys' UTF-8 bytes joined
+        by NUL, as the native string-frame parse leaves them) and the
+        ring's own hash, one native call with the GIL released: crc32
+        and the search a key (hashlib_native.ring_owners); otherwise
+        one hash call per key and a single searchsorted."""
         import numpy as np
 
         if not self._keys:
             raise RuntimeError("unable to pick a peer; pool is empty")
-        own = np.fromiter(
-            (self._by_point[p].is_owner for p in self._keys),
-            dtype=bool,
-            count=len(self._keys),
+        points = self.ring()[0]
+        if packed is not None and self._hash is ring_hash and split_ready():
+            # crc32 points: they fit the native call's uint32
+            return native_lib().ring_owners(
+                packed, len(keys), points.astype(np.uint32)
+            )
+        pts = np.fromiter(
+            (self._hash(k) for k in keys), dtype=np.uint64, count=len(keys)
         )
+        idx = np.searchsorted(points, pts, side="left")
+        idx[idx == len(points)] = 0
+        return idx.astype(np.int32)
+
+    def self_owned_mask(self, keys: Sequence[str], packed=None):
+        """bool[len(keys)]: the key's ring successor is this server
+        itself (is_owner). Vectorized ownership screen for the edge
+        bridge's string->array fold (r7): owner_column's positions
+        looked up in the ring's is_owner column, instead of a get()
+        with its dict lookups per key."""
+        import numpy as np
+
+        if not self._keys:
+            raise RuntimeError("unable to pick a peer; pool is empty")
+        own = self.ring()[2]
         if own.all():
             # every ring point is this node (a single-node ring):
             # whatever a key hashes to, its successor is this node
             return np.ones(len(keys), dtype=bool)
-        pts = np.fromiter(
-            (self._hash(k) for k in keys), dtype=np.uint64, count=len(keys)
-        )
-        ring = np.asarray(self._keys, dtype=np.uint64)
-        idx = np.searchsorted(ring, pts, side="left")
-        idx[idx == len(ring)] = 0
-        return own[idx]
+        return own[self.owner_column(keys, packed)]

@@ -1173,6 +1173,12 @@ class Server:
         metrics.PEER_FORWARD_ITEMS.set(fwd.items)
         for reason, items in fwd.failed.items():
             metrics.PEER_FORWARD_FAILED_ITEMS.labels(reason=reason).set(items)
+        split = self.instance.edge_split
+        metrics.EDGE_SPLIT_FRAMES.set(split.frames)
+        for lane, items in split.items.items():
+            metrics.EDGE_SPLIT_ITEMS.labels(lane=lane).set(items)
+        for reason, frames in split.declined.items():
+            metrics.EDGE_SPLIT_DECLINED.labels(reason=reason).set(frames)
         traffic = self.instance.traffic
         metrics.TRAFFIC_NATIVE_FOLDS.set(traffic.native_folds)
         metrics.TRAFFIC_PYTHON_FOLDS.set(traffic.python_folds)
@@ -1315,10 +1321,19 @@ class Server:
         process's temporary directory: /tmp unless TMPDIR says
         otherwise; ?name= is a
         single path component, default "trace"). ?python=0 leaves the
-        profiler's Python tracer off (default 1): the host plane then
+        profiler's Python tracer off: the host plane then
         holds the stage clock's own spans (serve/stages.py
         StageStats.span) and no Python frames, and the capture costs the
-        serving loop less. View with TensorBoard or
+        serving loop less. The default is 1 on a node that owns every
+        key and 0 on a member of a shared ring: STOPPING a
+        Python-tracer capture holds the interpreter lock while it
+        gathers every thread's frames — longer, on a busy owner, than
+        the 0.5 s its peers give a forwarded batch
+        (GUBER_BATCH_TIMEOUT_MS), so the capture would fail their
+        forwards (PR 43: 868 items of one RPC, in the ring cell's
+        traced run); ?python=1 asks for it all the same, and the reply
+        says which it was and why (`python`, `python_from`). View with
+        TensorBoard or
         Perfetto. The reference has no tracing at all
         (SURVEY.md section 5); this is the TPU-native replacement for its
         per-RPC Prometheus histograms when you need to see *inside* a
@@ -1360,7 +1375,16 @@ class Server:
                 {"error": "'ms' must be an integer"}, status=400
             )
         ms = max(0, min(ms, 60_000))  # reported below as actually captured
-        python = request.query.get("python", "1")
+        python = request.query.get("python")
+        # said in the reply: the default follows the ring (docstring)
+        python_from = "query"
+        if python is None:
+            shared = self.instance.picker.size() > 1
+            python = "0" if shared else "1"
+            python_from = (
+                "default: a member of a shared ring" if shared
+                else "default: a node that owns every key"
+            )
         if python not in ("0", "1"):
             return web.json_response(
                 {"error": "'python' must be 0 or 1"}, status=400
@@ -1411,7 +1435,7 @@ class Server:
                 self._profiling = False
         return web.json_response(
             {"trace_dir": out_dir, "captured_ms": ms,
-             "python": int(python)}
+             "python": int(python), "python_from": python_from}
         )
 
     # -- discovery ----------------------------------------------------------

@@ -152,20 +152,26 @@ Stages form six families:
   owns every key records none. The four tile one forward from the
   instance's enqueue to the answers back with their groups:
 
-    forward_queue    a group's enqueue (get_peer_rate_limits_grouped)
+    forward_queue    a group's enqueue (get_peer_rate_limits_grouped,
+                     or forward_columns for the GEB door's split)
                      -> the flusher has collected its batch and starts
                      to build the RPC: BatchWait, the RPC in flight
                      ahead of it (one a peer), the loop. One sample a
                      GROUP (one frame's items for one owner)
     forward_encode   convert.req_to_pb x items and the message's
-                     build. One sample an RPC, like the next two
+                     build; a column group's bytes by one native call
+                     (PeerClient._forward_wire). One sample an RPC,
+                     like the next two
     forward_rpc      RPC sent -> reply or failure in hand, deadline,
                      breaker and retries included (bare stamps: it
                      crosses an await): the wire, the owner's whole
                      GetPeerRateLimits call and this node's loop
                      getting back to it
     forward_decode   resp_from_pb x items (the slice back to the
-                     groups is a few list slices, uncounted)
+                     groups is a few list slices, uncounted); for an
+                     RPC that carried a column group, the reply's one
+                     native parse (its seconds taken out of
+                     forward_rpc) and the slices
 
   Beside them the plain counts peer_forward_batches_total,
   peer_forward_items_total and peer_forward_failed_items_total{reason}

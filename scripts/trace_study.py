@@ -25,9 +25,12 @@ over one window of --seconds:
   Python-tracer capture (scripts/gap_threads.py);
 - reads the GEB door's `edge_*` counters by growth over the window
   (`door_counters`: which path served the items, and how many string
-  frames the native parser took or declined), PR 37; and beside them
-  the traffic observers' `traffic_*_folds_total` (which implementation
-  folded the batches), PR 40;
+  frames the native parser took or declined), PR 37; beside them the
+  traffic observers' `traffic_*_folds_total` (which implementation
+  folded the batches), PR 40; and on a ring's door node the split by
+  owner's `edge_split_frames_total`, `edge_split_items_total{lane}`
+  and `edge_split_declined_total{reason}` (how often it engaged, where
+  its items went, what it declined and why), PR 43;
 - on a ring's door node (the harness's node 0), the forwarder's stages
   and `peer_forward_*` counters (`forwarder`), PR 41: run a ring cell
   with `--captures 0`, a capture on the door node stalls its forwards
@@ -178,7 +181,8 @@ def grown(prom0, prom1, prefixes):
 
 
 def door_counters(prom0, prom1):
-    """The GEB door's `edge_*_total` and the traffic observers'
+    """The GEB door's `edge_*_total` (the split by owner's
+    `edge_split_*_total` among them) and the traffic observers'
     `traffic_*_total` over the window."""
     return grown(prom0, prom1, ("edge_", "traffic_"))
 

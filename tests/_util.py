@@ -190,8 +190,8 @@ _native_for_tests = None
 
 def native_lib_for_tests(tmp_dir):
     """gubernator_tpu.native.hashlib_native over a libguberhash.so
-    that has the PeersV1 wire fold, the GEB string-frame parse and the
-    traffic observers' fold: the checkout's own where it is built and
+    that has the PeersV1 wire fold, the GEB string-frame parse, the
+    traffic observers' fold and the GEB door's split by owner: the checkout's own where it is built and
     current, else one compiled from guberhash.cc into `tmp_dir` (once
     a process: the files that ask share it) and loaded from there
     under a private module name — a test never drops a .so into the
@@ -214,7 +214,8 @@ def native_lib_for_tests(tmp_dir):
         if all(
             getattr(hashlib_native, has, False)
             for has in (
-                "_HAS_PEER_WIRE", "_HAS_STRING_FRAME", "_HAS_TRAFFIC_FOLD"
+                "_HAS_PEER_WIRE", "_HAS_STRING_FRAME", "_HAS_TRAFFIC_FOLD",
+                "_HAS_SPLIT",
             )
         ):
             _native_for_tests = hashlib_native
@@ -234,3 +235,18 @@ def native_lib_for_tests(tmp_dir):
     spec.loader.exec_module(mod)
     _native_for_tests = mod
     return mod
+
+
+class WireDoor:
+    """PeersV1Stub's pass-through door (GetPeerRateLimitsWire: bytes
+    in, bytes out — what a PeerClient's flusher sends) for a stub fake
+    that answers messages through GetPeerRateLimits."""
+
+    async def GetPeerRateLimitsWire(self, wire, timeout=None, **kw):
+        from gubernator_tpu.api.proto.gen import peers_pb2
+
+        reply = await self.GetPeerRateLimits(
+            peers_pb2.GetPeerRateLimitsReq.FromString(wire),
+            timeout=timeout, **kw
+        )
+        return reply.SerializeToString()

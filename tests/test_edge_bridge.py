@@ -599,7 +599,7 @@ def _fold_fixture(is_owner: bool, string_fold: bool = True,
 
     from gubernator_tpu.serve.config import BehaviorConfig
     from gubernator_tpu.serve.global_mgr import GlobalManager
-    from gubernator_tpu.serve.peers import ConsistentHashPicker
+    from gubernator_tpu.serve.peers import ConsistentHashPicker, SplitCounts
 
     folded_sizes = []
     object_path_keys = []
@@ -620,6 +620,10 @@ def _fold_fixture(is_owner: bool, string_fold: bool = True,
         traffic = _FakeTraffic()
         batcher = FakeBatcher()
         picker = ConsistentHashPicker()
+        edge_split = SplitCounts()
+
+        def split_unavailable(self):
+            return "no_arrays"  # no forwarder behind this fake
 
         async def get_rate_limits(self, reqs, stage_frame=False):
             object_path_keys.extend(r.unique_key for r in reqs)
@@ -778,7 +782,7 @@ def test_string_fold_declines_invalid_and_unowned_frames(
     validation, and queues no broadcast for a frame it declined."""
     bridge, folded_sizes, object_path_keys = _fold_fixture(is_owner=is_owner)
     payload = b"".join(items)
-    assert bridge._fold_string_frame(payload, len(items)) is None
+    assert bridge._screen_string_frame(payload, len(items))[0] is None
     if answered:
         out = _roundtrip_string_frame(
             bridge, items, f"fold-decline-{is_owner}-{len(payload)}"

@@ -9,6 +9,7 @@ import asyncio
 
 import pytest
 
+from _util import WireDoor
 from gubernator_tpu.api import convert
 from gubernator_tpu.api.proto.gen import peers_pb2
 from gubernator_tpu.api.types import Behavior, RateLimitReq, RateLimitResp
@@ -23,7 +24,7 @@ def _req(i: int) -> RateLimitReq:
     )
 
 
-class FakeStub:
+class FakeStub(WireDoor):
     """Records each GetPeerRateLimits batch; echoes per-request answers."""
 
     def __init__(self):

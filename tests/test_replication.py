@@ -368,8 +368,11 @@ async def _two_peer_instance(conf):
         PeerInfo(address=conf.advertise_address, is_owner=True),
         PeerInfo(address=dead, is_owner=False),
     ])
-    keys = [f"dk{i}" for i in range(256)
-            if inst.get_peer(_req(f"dk{i}").hash_key()).host == dead]
+    # the dead peer's arc follows the port drawn: over 256 keys one
+    # draw in ~250 left it none (the driver's run of PR 43 lost a test
+    # to it), over 4096 one in ~4000
+    keys = [f"dk{i}" for i in range(4096)
+            if inst.get_peer(_req(f"dk{i}").hash_key()).host == dead][:8]
     assert keys, "no key landed on the dead peer"
     return inst, dead, keys
 

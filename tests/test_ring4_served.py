@@ -40,7 +40,7 @@ import time
 import grpc
 import pytest
 
-from _util import free_ports
+from _util import WireDoor, free_ports
 from gubernator_tpu.api.types import Algorithm, RateLimitReq
 from gubernator_tpu.cluster import LocalCluster
 from gubernator_tpu.core import oracle
@@ -145,6 +145,25 @@ def through_the_doors(doors, frames, together: int = 1):
     return asyncio.run(run())
 
 
+def ring_ports(per_node: int):
+    """`per_node` x NODES free ports, the first NODES (the gRPC ones,
+    whose addresses are the ring's points) drawn again until every node
+    holds at least a tenth of the crc32 circle: free ports cut random
+    arcs, and a sliver of an arc leaves a frame — or 60 keys — without
+    one of the four owners (the harness chooses its ports for the same
+    reason: benchmark/harness/daemon.py even_points)."""
+    for _ in range(200):
+        ports = free_ports(per_node * NODES)
+        addresses = [f"127.0.0.1:{p}" for p in ports[:NODES]]
+        share = [0] * NODES
+        for i in range(2000):
+            owner = reference_ring.owner_of(f"{NAME}_probe{i}", addresses)
+            share[addresses.index(owner)] += 1
+        if min(share) >= 200:
+            return ports
+    raise RuntimeError("no even ring in 200 draws of free ports")
+
+
 @pytest.fixture(scope="module")
 def ring():
     """Four daemons' worth of serving stack in a ring, each backend
@@ -158,7 +177,7 @@ def ring():
     for mod in (types_mod, engine_mod, oracle):
         mp.setattr(mod, "millisecond_now", clock)
     conf = config_from_env(deployment_env())
-    ports = free_ports(2 * NODES)
+    ports = ring_ports(2)
     cluster = LocalCluster(
         [f"127.0.0.1:{p}" for p in ports[:NODES]],
         backend_factory=lambda: make_backend(conf),
@@ -443,7 +462,7 @@ def test_a_failed_forward_is_counted_under_its_reason(exc, reason):
 
 
 def test_a_client_closed_under_its_callers_counts_them_as_closed():
-    class _Never:
+    class _Never(WireDoor):
         async def GetPeerRateLimits(self, pb_req, timeout=None):
             await asyncio.Event().wait()
 

@@ -1,7 +1,7 @@
 """The GEB door's native string-frame parse (PR 37): one call of
 `libguberhash.so guber_parse_string_frame` — wire bytes -> columns, key
 hashes and the NUL-joined hash keys, GIL released — against the
-per-item Python loop of `EdgeBridge._fold_string_frame`, which stays as
+per-item Python loop of `EdgeBridge._fold_by_loop`, which stays as
 its fallback and is the oracle here.
 
 - (a) wire: random frames (names and keys of 1-200 bytes, multi-byte
@@ -35,7 +35,7 @@ from gubernator_tpu.core import hashing
 from gubernator_tpu.serve import edge_bridge
 from gubernator_tpu.serve.edge_bridge import EdgeBridge, decode_request_frame
 from gubernator_tpu.serve.metrics import REGISTRY
-from gubernator_tpu.serve.peers import ConsistentHashPicker
+from gubernator_tpu.serve.peers import ConsistentHashPicker, SplitCounts
 from test_edge_bridge import (
     BAD,
     FakePeer,
@@ -102,6 +102,10 @@ def _bridge(owners=(True,)):
         batcher = FakeBatcher()
         picker = ConsistentHashPicker()
         shed = None
+        edge_split = SplitCounts()
+
+        def split_unavailable(self):
+            return "no_arrays"  # no forwarder behind this fake
 
         async def get_rate_limits(self, reqs, stage_frame=False):
             paths.append(("objects", len(reqs)))
@@ -180,9 +184,9 @@ def test_native_parse_equals_python_loop(native, python_parse, seed, n):
     bridge, _ = _bridge()
     got, cols, keys = native.parse_string_frame(payload, n)
     assert got == n
-    by_native = bridge._fold_string_frame(payload, n)
+    by_native = bridge._screen_string_frame(payload, n)[0]
     python_parse()
-    by_loop = bridge._fold_string_frame(payload, n)
+    by_loop = bridge._screen_string_frame(payload, n)[0]
     _same_fold(by_native, by_loop)
     full, fields, glob, _, packed = by_native
     assert packed == keys
@@ -260,9 +264,9 @@ def test_native_parse_declines_where_the_loop_does(
     got, cols, keys = native.parse_string_frame(payload, n)
     assert got < 0 and cols is None and keys is None
     assert native.STRING_DECLINE[got] in reasons
-    assert bridge._fold_string_frame(payload, n) is None
+    assert bridge._screen_string_frame(payload, n)[0] is None
     python_parse()
-    assert bridge._fold_string_frame(payload, n) is None
+    assert bridge._screen_string_frame(payload, n)[0] is None
 
 
 @pytest.mark.parametrize("where", ["name", "key", "key-end"])
@@ -279,10 +283,10 @@ def test_nul_byte_declines_natively_and_the_loop_serves(
     assert native.STRING_DECLINE[got] == "nul_byte"
     bridge, _ = _bridge()
     declined = _declined("nul_byte")
-    by_native = bridge._fold_string_frame(payload, 3)
+    by_native = bridge._screen_string_frame(payload, 3)[0]
     assert _declined("nul_byte") - declined == 1
     python_parse()
-    by_loop = bridge._fold_string_frame(payload, 3)
+    by_loop = bridge._screen_string_frame(payload, 3)[0]
     _same_fold(by_native, by_loop)
     assert "\x00" in by_native[0][1] and by_native[2][0][0] == 1
 
@@ -291,11 +295,11 @@ def test_stale_library_declines_every_frame(native, monkeypatch):
     """A libguberhash.so built before the symbol keeps the loop."""
     payload = _item(b"api", b"k1")
     bridge, _ = _bridge()
-    want = bridge._fold_string_frame(payload, 1)
+    want = bridge._screen_string_frame(payload, 1)[0]
     monkeypatch.setattr(native, "_HAS_STRING_FRAME", False)
     assert native.parse_string_frame(payload, 1) == (-7, None, None)
     declined = _declined("stale_library")
-    _same_fold(bridge._fold_string_frame(payload, 1), want)
+    _same_fold(bridge._screen_string_frame(payload, 1)[0], want)
     assert _declined("stale_library") - declined == 1
 
 
