@@ -714,6 +714,151 @@ inline int32_t clip_i64(int64_t v, int64_t lo, int64_t hi) {
   return (int32_t)(v < lo ? lo : (v > hi ? hi : v));
 }
 
+// One mesh device batch as the engine stacks it: n_shards rows of B
+// request cells (six fields, valid, group_id) and G group cells, rows
+// written compactly with stride B / G. Both producers fill it —
+// guber_prep_sharded (flush-time presort) and guber_merge_runs_sharded
+// (merge of arrival-time runs) — so the padding conventions below have
+// one native home, as engine.build_groups is their one Python home.
+struct ShardStack {
+  int64_t B, G;
+  uint64_t* kh;
+  int32_t* hits;
+  int32_t* limit;
+  int32_t* dur;
+  int32_t* algo;
+  uint8_t* gnp;
+  uint8_t* valid;
+  int32_t* gid;
+  uint64_t* gkh;
+  int32_t* glead;
+  int32_t* gend;
+  uint8_t* gvalid;
+};
+
+// Shard s once its first cnt (> 0) request rows stand in the six field
+// columns: the padding tail repeats the last real row with valid=0, and
+// the group cells come from the gc shard-local leader positions `ls`
+// with build_groups' conventions — the final real group owns the
+// request padding tail, padded request rows point at it, padded group
+// slots carry leader=B / end=B-1 / valid=0 / the key of row B-1.
+inline void finish_shard(const ShardStack& o, int64_t s, int64_t cnt,
+                         const int32_t* ls, int64_t gc) {
+  const int64_t B = o.B, G = o.G;
+  uint64_t* kh_o = o.kh + s * B;
+  int32_t* hi_o = o.hits + s * B;
+  int32_t* li_o = o.limit + s * B;
+  int32_t* du_o = o.dur + s * B;
+  int32_t* al_o = o.algo + s * B;
+  uint8_t* gn_o = o.gnp + s * B;
+  uint8_t* va_o = o.valid + s * B;
+  int32_t* gi_o = o.gid + s * B;
+  uint64_t* gk_o = o.gkh + s * G;
+  int32_t* gl_o = o.glead + s * G;
+  int32_t* ge_o = o.gend + s * G;
+  uint8_t* gv_o = o.gvalid + s * G;
+  std::fill(kh_o + cnt, kh_o + B, kh_o[cnt - 1]);
+  std::fill(hi_o + cnt, hi_o + B, hi_o[cnt - 1]);
+  std::fill(li_o + cnt, li_o + B, li_o[cnt - 1]);
+  std::fill(du_o + cnt, du_o + B, du_o[cnt - 1]);
+  std::fill(al_o + cnt, al_o + B, al_o[cnt - 1]);
+  std::fill(gn_o + cnt, gn_o + B, gn_o[cnt - 1]);
+  std::memset(va_o, 1, cnt);
+  std::memset(va_o + cnt, 0, B - cnt);
+  for (int64_t g = 0; g < gc; ++g) {
+    const int64_t lead = ls[g];
+    const int64_t next = (g + 1 < gc) ? ls[g + 1] : cnt;
+    gl_o[g] = (int32_t)lead;
+    ge_o[g] = (int32_t)((g + 1 < gc) ? next - 1 : B - 1);
+    gk_o[g] = kh_o[lead];
+    gv_o[g] = 1;
+    for (int64_t j = lead; j < next; ++j) gi_o[j] = (int32_t)g;
+  }
+  std::fill(gi_o + cnt, gi_o + B, (int32_t)(gc - 1));
+  std::fill(gl_o + gc, gl_o + G, (int32_t)B);
+  std::fill(ge_o + gc, ge_o + G, (int32_t)(B - 1));
+  std::memset(gv_o + gc, 0, G - gc);
+  std::fill(gk_o + gc, gk_o + G, kh_o[B - 1]);
+}
+
+// The empty shards, once every other shard stands (their fill row is
+// another shard's): numpy twin semantics — every request cell
+// replicates the sorted row clip(starts[s], 0, n-1), the next shard's
+// first row or the batch's last, found in the stack through take_idx
+// (an empty batch: zeros); valid=0, group ids 0, no real group, group
+// keys that same row's.
+inline void fill_empty_shards(const ShardStack& o, const int64_t* counts,
+                              const int64_t* starts, int64_t n_shards,
+                              int64_t n, const int64_t* take_idx) {
+  const int64_t B = o.B, G = o.G;
+  for (int64_t s = 0; s < n_shards; ++s) {
+    if (counts[s] != 0) continue;
+    const int64_t src =
+        n > 0 ? take_idx[starts[s] < n ? starts[s] : n - 1] : -1;
+    const uint64_t kf = src >= 0 ? o.kh[src] : 0;
+    std::fill_n(o.kh + s * B, B, kf);
+    std::fill_n(o.hits + s * B, B, src >= 0 ? o.hits[src] : 0);
+    std::fill_n(o.limit + s * B, B, src >= 0 ? o.limit[src] : 0);
+    std::fill_n(o.dur + s * B, B, src >= 0 ? o.dur[src] : 0);
+    std::fill_n(o.algo + s * B, B, src >= 0 ? o.algo[src] : 0);
+    std::fill_n(o.gnp + s * B, B, src >= 0 ? o.gnp[src] : (uint8_t)0);
+    std::fill_n(o.valid + s * B, B, (uint8_t)0);
+    std::fill_n(o.gid + s * B, B, 0);
+    std::fill_n(o.gkh + s * G, G, kf);
+    std::fill_n(o.glead + s * G, G, (int32_t)B);
+    std::fill_n(o.gend + s * G, G, (int32_t)(B - 1));
+    std::fill_n(o.gvalid + s * G, G, (uint8_t)0);
+  }
+}
+
+// Stable k-way merge of k pre-sorted key runs (ns[r] keys each, n in
+// all): row(i, r, j, key) once per merged position i, in order, for
+// run r's j-th key. A binary min-heap of run heads ordered by (key,
+// run index): the run tie-break keeps equal keys in caller order (runs
+// are caller-ordered), matching a stable sort of the concatenation.
+// Shared by the flat and the sharded merge entry points below.
+template <class Row>
+inline void merge_sorted_runs(const uint64_t* const* skeys,
+                              const int64_t* ns, int64_t k, int64_t n,
+                              Row&& row) {
+  struct Head {
+    uint64_t key;
+    int64_t run;
+  };
+  std::vector<Head> heap;
+  heap.reserve((size_t)k);
+  std::vector<int64_t> pos((size_t)k, 0);
+  auto lt = [](const Head& a, const Head& b) {
+    return a.key < b.key || (a.key == b.key && a.run < b.run);
+  };
+  auto sift_down = [&](size_t i) {
+    const size_t sz = heap.size();
+    for (;;) {
+      size_t s = i, l = 2 * i + 1, r2 = 2 * i + 2;
+      if (l < sz && lt(heap[l], heap[s])) s = l;
+      if (r2 < sz && lt(heap[r2], heap[s])) s = r2;
+      if (s == i) return;
+      std::swap(heap[i], heap[s]);
+      i = s;
+    }
+  };
+  for (int64_t r = 0; r < k; ++r)
+    if (ns[r] > 0) heap.push_back({skeys[r][0], r});
+  for (size_t i = heap.size(); i-- > 0;) sift_down(i);
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t r = heap[0].run;
+    const int64_t j = pos[(size_t)r]++;
+    row(i, r, j, heap[0].key);
+    if (j + 1 < ns[r]) {
+      heap[0].key = skeys[r][j + 1];
+    } else {
+      heap[0] = heap.back();
+      heap.pop_back();
+    }
+    if (!heap.empty()) sift_down(0);
+  }
+}
+
 }  // namespace
 
 extern "C" {
@@ -1004,6 +1149,9 @@ int64_t guber_prep_sharded(
   // loops make the padding tail a vectorized constant fill and the real
   // rows a single gather+store stream), then groups from the sort
   // pass's leader scratch with build_groups' padding conventions.
+  const ShardStack stack{B,        G,        kh_out,    hits_out, limit_out,
+                         dur_out,  algo_out, gnp_out,   valid_out, gid_out,
+                         gkh_out,  glead_out, gend_out, gvalid_out};
   std::atomic<int64_t> next_shard2{0};
   pool.run([&](int, int) {
     for (;;) {
@@ -1020,98 +1168,26 @@ int64_t guber_prep_sharded(
       int32_t* du_o = dur_out + s * B;
       int32_t* al_o = algo_out + s * B;
       uint8_t* gn_o = gnp_out + s * B;
-      uint8_t* va_o = valid_out + s * B;
-      int32_t* gi_o = gid_out + s * B;
-      uint64_t* gk_o = gkh_out + s * G;
-      int32_t* gl_o = glead_out + s * G;
-      int32_t* ge_o = gend_out + s * G;
-      uint8_t* gv_o = gvalid_out + s * G;
-
       for (int64_t j = 0; j < cnt; ++j) kh_o[j] = key_hash[ord[j]];
-      std::fill(kh_o + cnt, kh_o + B, kh_o[cnt - 1]);
       for (int64_t j = 0; j < cnt; ++j)
         hi_o[j] = clip_i64(hits[ord[j]], lo, hi);
-      std::fill(hi_o + cnt, hi_o + B, hi_o[cnt - 1]);
       for (int64_t j = 0; j < cnt; ++j)
         li_o[j] = clip_i64(limit[ord[j]], lo, hi);
-      std::fill(li_o + cnt, li_o + B, li_o[cnt - 1]);
       for (int64_t j = 0; j < cnt; ++j)
         du_o[j] = clip_i64(duration[ord[j]], dlo, dhi);
-      std::fill(du_o + cnt, du_o + B, du_o[cnt - 1]);
       for (int64_t j = 0; j < cnt; ++j) al_o[j] = algo[ord[j]];
-      std::fill(al_o + cnt, al_o + B, al_o[cnt - 1]);
       for (int64_t j = 0; j < cnt; ++j) gn_o[j] = gnp[ord[j]];
-      std::fill(gn_o + cnt, gn_o + B, gn_o[cnt - 1]);
-      std::memset(va_o, 1, cnt);
-      std::memset(va_o + cnt, 0, B - cnt);
       int64_t* tk = take_idx_out + st;
       const int64_t base = s * B;
       for (int64_t j = 0; j < cnt; ++j) tk[j] = base + j;
-
-      // groups: leaders from the sort pass; run fills are sequential
-      const int32_t* ls = lead_scratch + st;
-      const int64_t gc = gcounts[s];
-      for (int64_t g = 0; g < gc; ++g) {
-        const int64_t lead = ls[g];
-        const int64_t next = (g + 1 < gc) ? ls[g + 1] : cnt;
-        gl_o[g] = (int32_t)lead;
-        ge_o[g] = (int32_t)((g + 1 < gc) ? next - 1 : B - 1);
-        gk_o[g] = kh_o[lead];
-        gv_o[g] = 1;
-        for (int64_t j = lead; j < next; ++j) gi_o[j] = (int32_t)g;
-      }
-      std::fill(gi_o + cnt, gi_o + B, (int32_t)(gc - 1));
-      // padded group slots: leader=B, end=B-1, invalid, key of row B-1
-      std::fill(gl_o + gc, gl_o + G, (int32_t)B);
-      std::fill(ge_o + gc, ge_o + G, (int32_t)(B - 1));
-      std::memset(gv_o + gc, 0, G - gc);
-      std::fill(gk_o + gc, gk_o + G, kh_o[B - 1]);
+      // padding tail + groups: leaders from the sort pass
+      finish_shard(stack, s, cnt, lead_scratch + st, gcounts[s]);
     }
   });
 
   if (dbg) t4 = now_us();
-  // serial fixup for empty shards: numpy twin semantics — padded cells
-  // replicate order[clip(starts[s], 0, n-1)] (the next shard's first
-  // sorted row), group ids 0, group keys kh_padded[B-1].
-  for (int64_t s = 0; s < n_shards; ++s) {
-    if (counts_out[s] != 0) continue;
-    const int64_t src = starts[s] < n ? starts[s] : (n > 0 ? n - 1 : 0);
-    const int32_t row = n > 0 ? order_out[src] : 0;
-    const uint64_t kf = n > 0 ? key_hash[row] : 0;
-    const int32_t hf = n > 0 ? clip_i64(hits[row], lo, hi) : 0;
-    const int32_t lf = n > 0 ? clip_i64(limit[row], lo, hi) : 0;
-    const int32_t df = n > 0 ? clip_i64(duration[row], dlo, dhi) : 0;
-    const int32_t af = n > 0 ? algo[row] : 0;
-    const uint8_t gf = n > 0 ? gnp[row] : 0;
-    uint64_t* kh_o = kh_out + s * B;
-    int32_t* hi_o = hits_out + s * B;
-    int32_t* li_o = limit_out + s * B;
-    int32_t* du_o = dur_out + s * B;
-    int32_t* al_o = algo_out + s * B;
-    uint8_t* gn_o = gnp_out + s * B;
-    uint8_t* va_o = valid_out + s * B;
-    int32_t* gi_o = gid_out + s * B;
-    for (int64_t j = 0; j < B; ++j) {
-      kh_o[j] = kf;
-      hi_o[j] = hf;
-      li_o[j] = lf;
-      du_o[j] = df;
-      al_o[j] = af;
-      gn_o[j] = gf;
-      va_o[j] = 0;
-      gi_o[j] = 0;
-    }
-    uint64_t* gk_o = gkh_out + s * G;
-    int32_t* gl_o = glead_out + s * G;
-    int32_t* ge_o = gend_out + s * G;
-    uint8_t* gv_o = gvalid_out + s * G;
-    for (int64_t q = 0; q < G; ++q) {
-      gk_o[q] = kf;
-      gl_o[q] = (int32_t)B;
-      ge_o[q] = (int32_t)(B - 1);
-      gv_o[q] = 0;
-    }
-  }
+  fill_empty_shards(stack, counts_out, starts.data(), n_shards, n,
+                    take_idx_out);
   if (dbg) {
     const int64_t t5 = now_us();
     fprintf(stderr,
@@ -1155,21 +1231,20 @@ void guber_unflatten_resp(const int32_t* packed, const int32_t* order,
 // Inputs are k parallel pointer tables (one entry per run) of the
 // sorted skey / device-dtype fields / within-run caller order, plus
 // per-run lengths ns[k] and flattened-batch base offsets bases[k].
-// Outputs: merged skey[n] (group derivation + mesh slicing),
-// order_out[B] (global caller index; tail = identity, the engine's
-// padding convention), the six padded field arrays [B] (tail repeats
-// the last merged row, valid=0 — pad_request_sorted's convention), and
-// the duplicate-key group stream (group_id[n], leader_pos[n], g_real).
-// Pass B == n to skip padding (the mesh path lays out per-shard
-// sub-batches from the flat merged stream instead).
+// Outputs: merged skey[n] (group derivation), order_out[B] (global
+// caller index; tail = identity, the engine's padding convention), the
+// six padded field arrays [B] (tail repeats the last merged row,
+// valid=0 — pad_request_sorted's convention), and the duplicate-key
+// group stream (group_id[n], leader_pos[n], g_real). Pass B == n to
+// skip padding (the numpy twins' flat merge, serve/prep.py).
 // When n_rungs > 0, the group stream is additionally PADDED to the
 // smallest rung G >= max(g_real, 1) of g_rungs (engine.group_rungs'
 // ladder, engine.build_groups' conventions): gkh/glead/gend/gvalid
 // sized G (caller allocates g_rungs[n_rungs-1]), group_id_out sized B
 // with the padding tail pointing at the last real group, and the
 // picked G returned through g_pick_out — so the whole merge + pad +
-// group build is one GIL-free call. n_rungs == 0 skips the padding
-// (the mesh path lays out per-shard groups itself).
+// group build is one GIL-free call. n_rungs == 0 skips the padding.
+// The mesh's stacked layout is guber_merge_runs_sharded's, below.
 int64_t guber_merge_runs(
     const uint64_t* const* skeys, const uint64_t* const* khs,
     const int32_t* const* hits, const int32_t* const* limits,
@@ -1185,62 +1260,25 @@ int64_t guber_merge_runs(
   int64_t n = 0;
   for (int64_t r = 0; r < k; ++r) n += ns[r];
   if (n > B) return -1;
-  // binary min-heap of run heads ordered by (key, run index): the run
-  // tie-break is what keeps equal keys in caller order (runs are
-  // caller-ordered), matching a stable sort of the concatenation
-  struct Head {
-    uint64_t key;
-    int64_t run;
-  };
-  std::vector<Head> heap;
-  heap.reserve((size_t)k);
-  std::vector<int64_t> pos((size_t)k, 0);
-  auto lt = [](const Head& a, const Head& b) {
-    return a.key < b.key || (a.key == b.key && a.run < b.run);
-  };
-  auto sift_down = [&](size_t i) {
-    const size_t sz = heap.size();
-    for (;;) {
-      size_t s = i, l = 2 * i + 1, r2 = 2 * i + 2;
-      if (l < sz && lt(heap[l], heap[s])) s = l;
-      if (r2 < sz && lt(heap[r2], heap[s])) s = r2;
-      if (s == i) return;
-      std::swap(heap[i], heap[s]);
-      i = s;
-    }
-  };
-  for (int64_t r = 0; r < k; ++r)
-    if (ns[r] > 0) heap.push_back({skeys[r][0], r});
-  for (size_t i = heap.size(); i-- > 0;) sift_down(i);
-
   int64_t g = -1;
   uint64_t prev_key = 0;
-  for (int64_t i = 0; i < n; ++i) {
-    const int64_t r = heap[0].run;
-    const int64_t j = pos[(size_t)r]++;
-    const uint64_t key = heap[0].key;
-    skey_out[i] = key;
-    order_out[i] = (int32_t)(orders[r][j] + bases[r]);
-    kh_out[i] = khs[r][j];
-    hits_out[i] = hits[r][j];
-    limit_out[i] = limits[r][j];
-    dur_out[i] = durs[r][j];
-    algo_out[i] = algos[r][j];
-    gnp_out[i] = gnps[r][j];
-    valid_out[i] = 1;
-    if (i == 0 || key != prev_key) {
-      leader_pos_out[++g] = (int32_t)i;
-      prev_key = key;
-    }
-    group_id_out[i] = (int32_t)g;
-    if (j + 1 < ns[r]) {
-      heap[0].key = skeys[r][j + 1];
-    } else {
-      heap[0] = heap.back();
-      heap.pop_back();
-    }
-    if (!heap.empty()) sift_down(0);
-  }
+  merge_sorted_runs(
+      skeys, ns, k, n, [&](int64_t i, int64_t r, int64_t j, uint64_t key) {
+        skey_out[i] = key;
+        order_out[i] = (int32_t)(orders[r][j] + bases[r]);
+        kh_out[i] = khs[r][j];
+        hits_out[i] = hits[r][j];
+        limit_out[i] = limits[r][j];
+        dur_out[i] = durs[r][j];
+        algo_out[i] = algos[r][j];
+        gnp_out[i] = gnps[r][j];
+        valid_out[i] = 1;
+        if (i == 0 || key != prev_key) {
+          leader_pos_out[++g] = (int32_t)i;
+          prev_key = key;
+        }
+        group_id_out[i] = (int32_t)g;
+      });
   const int64_t g_real = g + 1;
   *g_real_out = g_real;
   // padding tail: repeat the last merged row with valid=0; order maps
@@ -1289,6 +1327,107 @@ int64_t guber_merge_runs(
     const int32_t gid_pad = (int32_t)(g_real > 0 ? g_real - 1 : 0);
     for (int64_t i = n; i < B; ++i) group_id_out[i] = gid_pad;
   }
+  return 0;
+}
+
+// The same merge laid out for the mesh: ONE GIL-free call gives what
+// serve/prep.py merge_runs + sharded.py build_presorted_sharded +
+// stack_shard_groups build in numpy (the twin, and the oracle of
+// tests/test_prep_pipeline.py: byte-identical in every output). The
+// composite sort key carries the owner shard above bit owner_shift
+// (32 + the store's bucket bits: guber_prep_run), so the merged stream
+// is the shards' contiguous slices in shard order and every row's cell
+// is known as it is merged: shard s's rows land at [s, 0..count) of
+// the stacked [n_shards, B_sub] columns, B_sub the smallest of
+// sub_rungs that holds the fullest shard. Groups break at shard
+// boundaries (the owner bits differ), their leaders are shard-local,
+// and G_sub is the smallest of group_rungs(B_sub) that holds the
+// fullest shard's groups; padding as finish_shard / fill_empty_shards
+// say. Output buffers are caller-allocated for n_shards rows of a rung
+// >= B_sub and written compactly (stride B_sub / G_sub, picked_out =
+// {B_sub, G_sub}); order_out[n] and take_idx_out[n] (merged position
+// -> flat cell s * B_sub + j) are per merged row; counts_out[n_shards]
+// the rows a shard. Returns 0; 1 = DECLINED, nothing written: the
+// fullest shard exceeds the ladder's top, whose extension (and the
+// one-time warning) is the Python caller's; < 0 on runs that break the
+// contract (an owner >= n_shards, keys out of order).
+int64_t guber_merge_runs_sharded(
+    const uint64_t* const* skeys, const uint64_t* const* khs,
+    const int32_t* const* hits, const int32_t* const* limits,
+    const int32_t* const* durs, const int32_t* const* algos,
+    const uint8_t* const* gnps, const int32_t* const* orders,
+    const int64_t* ns, const int64_t* bases, int64_t k, int64_t n_shards,
+    int64_t owner_shift, const int64_t* sub_rungs, int64_t n_sub_rungs,
+    int32_t* order_out, int64_t* take_idx_out, int64_t* counts_out,
+    int64_t* picked_out, uint64_t* kh_out, int32_t* hits_out,
+    int32_t* limit_out, int32_t* dur_out, int32_t* algo_out,
+    uint8_t* gnp_out, uint8_t* valid_out, int32_t* gid_out,
+    uint64_t* gkh_out, int32_t* glead_out, int32_t* gend_out,
+    uint8_t* gvalid_out) {
+  int64_t n = 0;
+  std::fill_n(counts_out, n_shards, 0);
+  for (int64_t r = 0; r < k; ++r) {
+    n += ns[r];
+    for (int64_t j = 0; j < ns[r]; ++j) {
+      const uint64_t owner = skeys[r][j] >> owner_shift;
+      if (owner >= (uint64_t)n_shards) return -1;
+      ++counts_out[owner];
+    }
+  }
+  std::vector<int64_t> starts((size_t)n_shards + 1, 0);
+  std::vector<int64_t> gcounts((size_t)n_shards, 0);
+  int64_t maxc = 1;
+  for (int64_t s = 0; s < n_shards; ++s) {
+    starts[s + 1] = starts[s] + counts_out[s];
+    if (counts_out[s] > maxc) maxc = counts_out[s];
+  }
+  const int64_t B = pick_rung(sub_rungs, n_sub_rungs, maxc);
+  if (B < 0) return 1;
+  // shard-local leader positions, shard s's from lead[starts[s]]: at
+  // most one a row
+  std::vector<int32_t> lead((size_t)n);
+  uint64_t prev_key = 0;
+  bool sorted = true;
+  merge_sorted_runs(
+      skeys, ns, k, n, [&](int64_t i, int64_t r, int64_t j, uint64_t key) {
+        const int64_t s = (int64_t)(key >> owner_shift);
+        const int64_t local = i - starts[s];
+        if ((uint64_t)local >= (uint64_t)counts_out[s]) {
+          sorted = false;  // an owner out of shard order: write nothing
+          return;
+        }
+        const int64_t dst = s * B + local;
+        order_out[i] = (int32_t)(orders[r][j] + bases[r]);
+        take_idx_out[i] = dst;
+        kh_out[dst] = khs[r][j];
+        hits_out[dst] = hits[r][j];
+        limit_out[dst] = limits[r][j];
+        dur_out[dst] = durs[r][j];
+        algo_out[dst] = algos[r][j];
+        gnp_out[dst] = gnps[r][j];
+        if (local == 0 || key != prev_key) {
+          lead[(size_t)(starts[s] + gcounts[s]++)] = (int32_t)local;
+          prev_key = key;
+        }
+      });
+  if (!sorted) return -2;
+  int64_t maxg = 1;
+  for (int64_t s = 0; s < n_shards; ++s)
+    if (gcounts[s] > maxg) maxg = gcounts[s];
+  int64_t gr[4];
+  const int64_t G = pick_rung(gr, group_rungs_c(B, gr), maxg);
+  if (G < 0) return -3;  // unreachable: the top rung is B >= maxc >= maxg
+  picked_out[0] = B;
+  picked_out[1] = G;
+  const ShardStack stack{B,        G,        kh_out,    hits_out, limit_out,
+                         dur_out,  algo_out, gnp_out,   valid_out, gid_out,
+                         gkh_out,  glead_out, gend_out, gvalid_out};
+  for (int64_t s = 0; s < n_shards; ++s)
+    if (counts_out[s])
+      finish_shard(stack, s, counts_out[s], lead.data() + starts[s],
+                   gcounts[s]);
+  fill_empty_shards(stack, counts_out, starts.data(), n_shards, n,
+                    take_idx_out);
   return 0;
 }
 

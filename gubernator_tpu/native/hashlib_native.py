@@ -491,22 +491,34 @@ def prep_sharded(
     if rc != 0:
         raise RuntimeError(f"guber_prep_sharded failed: rc={rc}")
     B, G = int(picked[0]), int(picked[1])
+    fields, groups = _stack_views(
+        n_shards, B, G, kh_o, hi_o, li_o, du_o, al_o, gn_o, va_o, gi_o,
+        gk_o, gl_o, ge_o, gv_o,
+    )
+    return order, counts, take_idx, fields, groups, B, G
 
-    def view2(a, w):
+
+def _stack_views(n_shards, B, G, kh, hits, limit, dur, algo, gnp, valid,
+                 gid, gkh, glead, gend, gvalid):
+    """(fields, groups) of a mesh device batch over buffers a native call
+    filled compactly: n_shards rows of stride B (request cells) or G
+    (group cells) from each buffer's start — guber_prep_sharded's and
+    guber_merge_runs_sharded's output convention."""
+
+    def rows(a, w):
         return a[: n_shards * w].reshape(n_shards, w)
 
     fields = dict(
-        key_hash=view2(kh_o, B), hits=view2(hi_o, B),
-        limit=view2(li_o, B), duration=view2(du_o, B),
-        algo=view2(al_o, B), gnp=view2(gn_o, B).view(bool),
-        valid=view2(va_o, B).view(bool),
+        key_hash=rows(kh, B), hits=rows(hits, B), limit=rows(limit, B),
+        duration=rows(dur, B), algo=rows(algo, B),
+        gnp=rows(gnp, B).view(bool), valid=rows(valid, B).view(bool),
     )
     groups = dict(
-        key_hash=view2(gk_o, G), leader_pos=view2(gl_o, G),
-        end_pos=view2(ge_o, G), valid=view2(gv_o, G).view(bool),
-        group_id=view2(gi_o, B),
+        key_hash=rows(gkh, G), leader_pos=rows(glead, G),
+        end_pos=rows(gend, G), valid=rows(gvalid, G).view(bool),
+        group_id=rows(gid, B),
     )
-    return order, counts, take_idx, fields, groups, B, G
+    return fields, groups
 
 
 try:
@@ -522,6 +534,19 @@ try:
     _HAS_MERGE = True
 except AttributeError:
     _HAS_MERGE = False
+
+try:
+    _lib.guber_merge_runs_sharded.restype = ctypes.c_int64
+    _lib.guber_merge_runs_sharded.argtypes = (
+        [_vpp] * 8
+        + [_i64p, _i64p]
+        + [ctypes.c_int64] * 3
+        + [_i64p, ctypes.c_int64]
+        + [ctypes.c_void_p] * 16
+    )
+    _HAS_MERGE_SHARDED = True
+except (AttributeError, NameError):  # a library built before PR 44
+    _HAS_MERGE_SHARDED = False
 
 try:
     _lib.guber_prep_run.restype = ctypes.c_int64
@@ -606,13 +631,34 @@ def run_addrs(run: dict) -> tuple:
     )
 
 
+def _run_tables(runs):
+    """(tabs, ns, bases) of guber_merge_runs' input: the eight pointer
+    tables from the per-run address tuples prep stamped at ARRIVAL
+    (run_addrs above) — `.ctypes.data` per array here would cost ~8k
+    ctypes-interface constructions of pure submit-thread Python, which
+    is exactly the wall this path exists to remove — the run lengths,
+    and each run's base in the flattened batch. The run dicts keep the
+    arrays alive for the duration of the call."""
+    k = len(runs)
+    addrs = [r.get("_addrs") or run_addrs(r) for r in runs]
+    tabs = [
+        (ctypes.c_void_p * k)(*[a[col] for a in addrs])
+        for col in range(8)
+    ]
+    ns = np.asarray([r["n"] for r in runs], np.int64)
+    bases = np.zeros(k, np.int64)
+    np.cumsum(ns[:-1], out=bases[1:])
+    return tabs, ns, bases
+
+
 def merge_runs_native(runs, B: int, g_rungs=None) -> dict:
     """Fused k-way merge of pre-sorted per-group runs (guber_merge_runs):
     one GIL-free pass produces the merged sort-key stream, the global
     caller-order permutation, all six device-dtype field arrays padded
     to B rows (tail repeats the last merged row, valid=False — the
-    engine's padding convention; pass B == n for a flat merge), and the
-    duplicate-key group stream. `runs` are engine prep_run dicts in
+    engine's padding convention; pass B == n for a flat merge: the
+    numpy twins' — the mesh's stacked layout is
+    merge_runs_sharded_native's), and the duplicate-key group stream. `runs` are engine prep_run dicts in
     caller order; ties across runs resolve in run order, so the merged
     permutation equals np.argsort(concat, kind='stable') — the
     merge-combine equivalence contract (tests/test_prep_pipeline.py).
@@ -632,20 +678,7 @@ def merge_runs_native(runs, B: int, g_rungs=None) -> dict:
     k = len(runs)
     n = int(sum(r["n"] for r in runs))
     assert B >= n, (B, n)
-
-    # pointer tables from the per-run address tuples prep stamped at
-    # ARRIVAL (run_addrs below) — `.ctypes.data` per array here would
-    # cost ~8k ctypes-interface constructions of pure submit-thread
-    # Python, which is exactly the wall this path exists to remove.
-    # The run dicts keep the arrays alive for the duration of the call.
-    addrs = [r.get("_addrs") or run_addrs(r) for r in runs]
-    tabs = [
-        (ctypes.c_void_p * k)(*[a[col] for a in addrs])
-        for col in range(8)
-    ]
-    ns = np.asarray([r["n"] for r in runs], np.int64)
-    bases = np.zeros(k, np.int64)
-    np.cumsum(ns[:-1], out=bases[1:])
+    tabs, ns, bases = _run_tables(runs)
 
     if g_rungs is not None:
         rungs = np.ascontiguousarray(g_rungs, np.int64)
@@ -699,6 +732,78 @@ def merge_runs_native(runs, B: int, g_rungs=None) -> dict:
     else:
         out.update(group_id=gid[:n], leader_pos=lead[:n])
     return out
+
+
+def merge_runs_sharded_native(runs, n_shards: int, store_buckets: int,
+                              sub_rungs):
+    """The same merge laid out for the mesh (guber_merge_runs_sharded):
+    one GIL-free call merges the runs and writes the stacked
+    [n_shards, B_sub] request columns (repeat-pad / clamp, valid =
+    j < count), the per-shard group structure with LOCAL indices at one
+    G_sub (engine.build_groups' conventions), the merged `order[n]`,
+    `take_idx[n]` and the rows a shard — byte for byte what
+    serve/prep.py merge_runs + parallel/sharded.py
+    build_presorted_sharded + stack_shard_groups give in numpy
+    (tests/test_prep_pipeline.py). `sub_rungs` is the engine's sub-rung
+    ladder, ascending; B_sub is its smallest rung that holds the
+    fullest shard.
+
+    Returns dict(n, order, take_idx, counts, B_sub, G_sub, fields
+    {key_hash/hits/limit/duration/algo/gnp/valid: [n_shards, B_sub]},
+    groups {key_hash/leader_pos/end_pos/valid: [n_shards, G_sub],
+    group_id: [n_shards, B_sub]}), or None where the fullest shard
+    exceeds the ladder's top: extending the ladder, and the warning
+    that goes with it, stay the numpy twin's."""
+    if not _HAS_MERGE_SHARDED:
+        raise AttributeError(
+            "libguberhash.so predates guber_merge_runs_sharded; rebuild "
+            "with make -C gubernator_tpu/native"
+        )
+    k = len(runs)
+    n = int(sum(r["n"] for r in runs))
+    tabs, ns, bases = _run_tables(runs)
+    rungs = np.ascontiguousarray(sub_rungs, np.int64)
+    # no shard holds more than n rows: buffers for the rung that n
+    # itself would take (the ladder's top where n is past it)
+    cap = int(rungs[min(np.searchsorted(rungs, n), rungs.shape[0] - 1)])
+    cells = n_shards * cap
+    order = np.empty(n, np.int32)
+    take_idx = np.empty(n, np.int64)
+    counts = np.empty(n_shards, np.int64)
+    picked = np.empty(2, np.int64)
+    u64 = np.empty((2, cells), np.uint64)  # key_hash, group key_hash
+    i32 = np.empty((7, cells), np.int32)
+    u8 = np.empty((3, cells), np.uint8)
+    kh, gkh = u64
+    hits, limit, dur, algo, gid, glead, gend = i32
+    gnp, valid, gvalid = u8
+    bucket_bits = max(int(store_buckets).bit_length() - 1, 1)
+    rc = _lib.guber_merge_runs_sharded(
+        *tabs,
+        _ptr(ns, ctypes.c_int64), _ptr(bases, ctypes.c_int64), k,
+        n_shards, 32 + bucket_bits,
+        _ptr(rungs, ctypes.c_int64), rungs.shape[0],
+        order.ctypes.data, take_idx.ctypes.data, counts.ctypes.data,
+        picked.ctypes.data,
+        *(
+            a.ctypes.data
+            for a in (kh, hits, limit, dur, algo, gnp, valid, gid,
+                      gkh, glead, gend, gvalid)
+        ),
+    )
+    if rc == 1:
+        return None
+    if rc != 0:
+        raise RuntimeError(f"guber_merge_runs_sharded failed: rc={rc}")
+    B, G = int(picked[0]), int(picked[1])
+    fields, groups = _stack_views(
+        n_shards, B, G, kh, hits, limit, dur, algo, gnp, valid, gid,
+        gkh, glead, gend, gvalid,
+    )
+    return dict(
+        n=n, order=order, take_idx=take_idx, counts=counts, B_sub=B,
+        G_sub=G, fields=fields, groups=groups,
+    )
 
 
 def unflatten_resp(packed, order, counts, n: int, b_sub: int) -> np.ndarray:

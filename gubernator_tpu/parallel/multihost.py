@@ -373,6 +373,18 @@ class MultiHostMeshEngine:
     def flat(self):
         return self.inner.flat
 
+    #: a lockstep fleet's merged batch crosses as the FLAT sorted form
+    #: (merge_prepped below), so every process lays it out in numpy
+    stack_implementation = "numpy"
+
+    @property
+    def native_stacks(self):
+        return self.inner.native_stacks
+
+    @property
+    def numpy_stacks(self):
+        return self.inner.numpy_stacks
+
     @property
     def stats(self):
         return self.inner.stats

@@ -612,6 +612,11 @@ def build_presorted_sharded(
     batch — the merge-combine twin of pad_request_sharded
     (with_groups=True), minus the argsort it no longer needs.
     Byte-identical outputs are pinned by tests/test_prep_pipeline.py.
+    The served mesh path takes the native twin where the library has
+    it (guber_merge_runs_sharded: the merge and this layout in one
+    GIL-free call, PartitionedEngine.merge_prepped); this form is the
+    fallback, the chain lane's and the lockstep follower's layout, and
+    the oracle the native one is held to, byte for byte.
     """
     from gubernator_tpu.core.engine import choose_bucket
 
@@ -960,6 +965,11 @@ class PartitionedEngine:
         # traffic regardless of door or topology. Must never raise into
         # the dispatch path.
         self.observe_hook = None
+        # merged mesh batches by who laid them out per shard: the
+        # native merge in its one call, or numpy on the submit thread
+        # (/metrics mesh_native_stacks_total, mesh_numpy_stacks_total)
+        self.native_stacks = 0
+        self.numpy_stacks = 0
         # sketch cold tier (r13; sharded over the mesh axis since r14):
         # `sketch_on` is the runtime A/B flag (scripts/perf_gate.py
         # flips it between paired rounds; both variants compile lazily)
@@ -1239,6 +1249,28 @@ class PartitionedEngine:
             int(rows.sum()), out[0].valid.size, int(rows.max())
         )
         return out
+
+    @property
+    def stack_implementation(self) -> str:
+        """Who lays a merged mesh batch out per shard: "native"
+        (guber_merge_runs_sharded in the library that loaded) or
+        "numpy" (`_stack_presorted`)."""
+        if _hn is not None and getattr(_hn, "_HAS_MERGE_SHARDED", False):
+            return "native"
+        return "numpy"
+
+    def _stack_presorted(self, fields, skey, counts):
+        """(req, take_idx, groups, B_sub) of an already-merged sorted
+        batch, laid out in numpy: the twin of the native sharded merge
+        (`merge_prepped`) — what serves without the library, for a
+        batch past the sub-rung ladder's top, and for the lockstep
+        follower's `decide_submit_presorted`."""
+        self.numpy_stacks += 1
+        return self._shard_stack(
+            build_presorted_sharded,
+            self.sub_buckets, self.config.slots, self.n, fields, skey,
+            counts,
+        )
 
     def _decide_call(self, req, groups, e_now):
         """(program, arguments) of one decide: the batch's host inputs
@@ -1539,15 +1571,33 @@ class PartitionedEngine:
             order_p[:n] = m["order"]
             order_p[n:] = np.arange(n, B, dtype=np.int32)
             return dict(req=req, groups=groups, order=order_p, n=n, B=B)
+        n = int(sum(r["n"] for r in runs))
+        m = None
+        if self.stack_implementation == "native" and n:
+            # merge + the stacked layout in ONE native call, GIL
+            # released; None = the fullest shard is past the ladder's
+            # top, which the numpy twin below extends (and warns of)
+            with self.stage_span("shard_stack"):
+                m = _hn.merge_runs_sharded_native(
+                    runs, self.n, self.config.slots, self.sub_buckets
+                )
+        if m is not None:
+            self.native_stacks += 1
+            self.shard_counts(
+                n, self.n * m["B_sub"], int(m["counts"].max())
+            )
+            return dict(
+                req=BatchRequest(**m["fields"]),
+                groups=BatchGroups(**m["groups"]), order=m["order"],
+                take_idx=m["take_idx"], n=n, B_sub=m["B_sub"],
+            )
         m = merge_runs(runs)
-        req, take_idx, groups, B_sub = self._shard_stack(
-            build_presorted_sharded,
-            self.sub_buckets, self.config.slots, self.n, m["fields"],
-            m["skey"], m["counts"],
+        req, take_idx, groups, B_sub = self._stack_presorted(
+            m["fields"], m["skey"], m["counts"]
         )
         return dict(
             req=req, groups=groups, order=m["order"],
-            take_idx=take_idx, n=m["order"].shape[0], B_sub=B_sub,
+            take_idx=take_idx, n=n, B_sub=B_sub,
         )
 
     def decide_submit_merged(self, merged: dict, now: int):
@@ -1600,10 +1650,8 @@ class PartitionedEngine:
             order_p[n:] = np.arange(n, B, dtype=np.int32)
             packed = self._dispatch(req, groups, e_now)
             return (packed, order_p, None, n, B, self.clock.epoch)
-        req, take_idx, groups, B_sub = self._shard_stack(
-            build_presorted_sharded,
-            self.sub_buckets, self.config.slots, self.n, fields, skey,
-            counts,
+        req, take_idx, groups, B_sub = self._stack_presorted(
+            fields, skey, counts
         )
         if order is None:
             order = np.arange(n, dtype=np.int32)

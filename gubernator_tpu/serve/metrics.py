@@ -88,6 +88,25 @@ MESH_SHARD_MAX_ROWS = Counter(
     "draws everything",
     registry=REGISTRY,
 )
+MESH_NATIVE_STACKS = Gauge(
+    "mesh_native_stacks_total",
+    "Mesh backend: merged device batches whose per-shard layout "
+    "(stacked [shards, sub-rung] columns, group structure, take_idx) "
+    "the native merge wrote in its one call with the GIL released "
+    "(libguberhash.so guber_merge_runs_sharded); exported lazily at "
+    "scrape. / (this + mesh_numpy_stacks_total) = its share of "
+    "engagement",
+    registry=REGISTRY,
+)
+MESH_NUMPY_STACKS = Gauge(
+    "mesh_numpy_stacks_total",
+    "Mesh backend: merged device batches laid out per shard in numpy "
+    "on the submit thread (parallel/sharded.py "
+    "build_presorted_sharded): every one where libguberhash.so is not "
+    "built or predates the sharded merge, a batch past the sub-rung "
+    "ladder's top, a lockstep follower's; none otherwise",
+    registry=REGISTRY,
+)
 DEVICE_LAUNCH_MS = Histogram(
     "device_launch_milliseconds",
     "Wall time of one decide kernel launch (host-observed)",

@@ -19,10 +19,19 @@ fields byte-identical to the flush-time concat+argsort path
 
 Pure numpy (plus the optional native lib) on purpose: importing this
 module never pulls jax. merge_runs dispatches to the fused native
-merge (guber_merge_runs, one GIL-free pass) when the library is built;
-the engines (core/engine.py, parallel/sharded.py) consume the merged
-output through their `merge_prepped` / `decide_submit_presorted`
-entry points.
+merge (guber_merge_runs, one GIL-free pass) when the library is built.
+
+Who calls it since PR 44: the engines' `merge_prepped` take ONE native
+call for the whole merge where the library has it — flat
+(guber_merge_runs with group rungs: merge + pad + groups) and mesh
+(guber_merge_runs_sharded: merge + the stacked [n_shards, B_sub]
+layout + per-shard groups + take_idx) — and never come here. This
+module's flat merge is what is left for the rest: the engines without
+the library, a mesh batch past its sub-rung ladder, the multi-host
+leader (whose flat merged form is the lockstep wire format), and the
+oracle of tests/test_prep_pipeline.py; its output goes to
+`build_presorted_request` / `build_presorted_sharded` through
+`merge_prepped` / `decide_submit_presorted`.
 """
 
 from __future__ import annotations

@@ -514,6 +514,12 @@ class Server:
             "hasher": "native" if using_native_hash() else "python",
         }
 
+    def _mesh_engine(self):
+        """The backend's engine where it lays device batches out per
+        shard (a mesh), else None."""
+        engine = getattr(self.backend, "engine", None)
+        return None if getattr(engine, "flat", True) else engine
+
     def _describe_device(self):
         """The devices as JAX reports them, with the engine's state
         bytes on each; None on the exact backend."""
@@ -620,6 +626,21 @@ class Server:
             "(libguberhash.so not built, or built before the fold: make "
             "-C gubernator_tpu/native; traffic_python_folds_total)",
         )
+
+        engine = self._mesh_engine()
+        if engine is not None:
+            log.info(
+                "mesh batches: %s",
+                "merged and laid out per shard in one native call, GIL "
+                "released (libguberhash.so guber_merge_runs_sharded; "
+                "mesh_native_stacks_total)"
+                if engine.stack_implementation == "native"
+                else "laid out per shard in numpy on the submit thread "
+                "(a multi-host mesh, whose merged batch crosses flat; "
+                "else libguberhash.so is not built, or was built before "
+                "the sharded merge: make -C gubernator_tpu/native; "
+                "mesh_numpy_stacks_total)",
+            )
 
         shed = self.instance.shed
         if shed is not None:
@@ -1182,6 +1203,10 @@ class Server:
         traffic = self.instance.traffic
         metrics.TRAFFIC_NATIVE_FOLDS.set(traffic.native_folds)
         metrics.TRAFFIC_PYTHON_FOLDS.set(traffic.python_folds)
+        engine = self._mesh_engine()
+        if engine is not None:
+            metrics.MESH_NATIVE_STACKS.set(engine.native_stacks)
+            metrics.MESH_NUMPY_STACKS.set(engine.numpy_stacks)
         if self.instance.repl is not None:
             metrics.REPLICATION_STANDBY_ENTRIES.set(
                 self.instance.repl.standby_len

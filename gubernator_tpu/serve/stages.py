@@ -133,10 +133,14 @@ Stages form six families:
     shard_stack      mesh backends only: the owner presort's
                      per-shard slices padded to one sub-rung and
                      stacked [n_shards, B_sub] with their group
-                     structure (PartitionedEngine._shard_stack) —
-                     inside merge on the arrival-prep path, inside
-                     dispatch on the flush-time path (where a native
-                     prep fuses the presort into it)
+                     structure — inside merge on the arrival-prep
+                     path, where it IS the merge: one native call
+                     merges the runs and writes the stack
+                     (PartitionedEngine.merge_prepped; the numpy
+                     twin, _stack_presorted, where the library lacks
+                     it); inside dispatch on the flush-time path
+                     (_shard_stack, where a native prep fuses the
+                     presort into it)
 
 - **per-flush stages** (`PER_FLUSH`): the GLOBAL gossip loops, one
   sample a flush.

@@ -27,7 +27,8 @@ over one window of --seconds:
   (`door_counters`: which path served the items, and how many string
   frames the native parser took or declined), PR 37; beside them the
   traffic observers' `traffic_*_folds_total` (which implementation
-  folded the batches), PR 40; and on a ring's door node the split by
+  folded the batches), PR 40; on a mesh `mesh_*_stacks_total` (who laid
+  the merged batches out per shard), PR 44; and on a ring's door node the split by
   owner's `edge_split_frames_total`, `edge_split_items_total{lane}`
   and `edge_split_declined_total{reason}` (how often it engaged, where
   its items went, what it declined and why), PR 43;
@@ -182,9 +183,12 @@ def grown(prom0, prom1, prefixes):
 
 def door_counters(prom0, prom1):
     """The GEB door's `edge_*_total` (the split by owner's
-    `edge_split_*_total` among them) and the traffic observers'
-    `traffic_*_total` over the window."""
-    return grown(prom0, prom1, ("edge_", "traffic_"))
+    `edge_split_*_total` among them), the traffic observers'
+    `traffic_*_total` and the mesh's `mesh_*_total` (PR 44: who laid
+    the merged batches out per shard, `mesh_native_stacks_total` /
+    `mesh_numpy_stacks_total`, beside the shard rows and slots) over
+    the window."""
+    return grown(prom0, prom1, ("edge_", "traffic_", "mesh_"))
 
 
 def forwarder(st, prom0, prom1):

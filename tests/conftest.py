@@ -8,12 +8,48 @@ forced through jax.config). They are also what child processes of a
 test inherit (daemons, lockstep followers), and JAX_PLATFORMS=cpu is the
 platform named BY NAME that lets a device backend boot without a TPU
 (gubernator_tpu/jaxenv.py require_tpu).
+
+The session also builds `gubernator_tpu/native/libguberhash.so` before
+a test is collected (PR 44). It is git-ignored, so a fresh checkout has
+none, and a run on one counted 943 where the same tree with the library
+counts 967: `tests/test_prep_native.py` skips whole, and the served
+tests that hold the native folds to their counters fail — worse when
+the library APPEARS while the run is under way (another process's
+`make`), because a worker whose first import failed imports it again
+later and then serves with half its modules on the twins
+(`tests/test_geb_differential.py`, `tests/test_global_mesh4_served.py`
+and `tests/test_ring4_served.py` in the driver's take-up run of PR 44's
+parent). `make` is a no-op where the library is newer than its source.
 """
 
 import os
+import pathlib
+import subprocess
 
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
     + " --xla_force_host_platform_device_count=8"
 ).strip()
 os.environ["JAX_PLATFORMS"] = "cpu"
+
+
+def _build_native_library() -> None:
+    """`make -C gubernator_tpu/native`, once, in the process that
+    starts the session (an xdist worker finds it built). A box with no
+    compiler keeps what it had: the tests that need the library skip
+    or build a private copy as before (tests/_util.py)."""
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        return
+    native = (
+        pathlib.Path(__file__).resolve().parent.parent
+        / "gubernator_tpu" / "native"
+    )
+    try:
+        subprocess.run(
+            ["make", "-C", str(native)], capture_output=True, timeout=600
+        )
+    except (OSError, subprocess.TimeoutExpired):
+        pass
+
+
+_build_native_library()

@@ -298,3 +298,56 @@ def test_counters_on_one_crafted_frame(node):
     }
     stacks = STAGES.snapshot()["stages"]["shard_stack"]["count"]
     assert stacks - stack_before == 2
+
+
+def test_merged_batches_count_who_stacked_them(node, monkeypatch):
+    """PR 44: every merged device batch of the mesh is laid out per
+    shard by the native merge in its one call (`mesh_native_stacks_total`
+    = batches, `mesh_numpy_stacks_total` stands still); with the symbol
+    hidden numpy lays them out and the counters trade places — the
+    answers are the same frame for frame, and equal the reference."""
+    from gubernator_tpu.native import hashlib_native
+
+    if not hashlib_native._HAS_MERGE_SHARDED:
+        pytest.skip("libguberhash.so predates guber_merge_runs_sharded")
+    cluster, addr = node
+    server = cluster.servers[0]
+    engine = server.instance.backend.engine
+    names = ("mesh_native_stacks_total", "mesh_numpy_stacks_total")
+
+    def read():
+        server._refresh_store_metrics()
+        return [REGISTRY.get_sample_value(c) for c in names] + [
+            STAGES.snapshot()["stages"].get(
+                "shard_stack", {"count": 0})["count"]
+        ]
+
+    def serve(tag):
+        # plain keys only: no broadcast peek adds a batch of its own
+        ids = [i for i in range(N_IDS) if not is_global(i)]
+        frames = [
+            [req(i, 1 + (i + f) % 2, name=tag) for i in ids[f:f + 20]] * 2
+            for f in range(12)
+        ]
+        ref = reference_global.OwnerNode(peers=0)
+        want = [
+            [ref.decide(r.unique_key, r.hits, r.limit, r.duration,
+                        int(r.algorithm), int(r.behavior), T0)[:3] + ("",)
+             for r in frame]
+            for frame in frames
+        ]
+        before = read()
+        got = through_the_door(addr, frames)
+        assert got == want
+        return got, [a - b for a, b in zip(read(), before)]
+
+    assert engine.stack_implementation == "native"
+    with_library, (native, numpy_, stacks) = serve("stacked-native")
+    assert stacks >= 12 and (native, numpy_) == (stacks, 0)
+
+    monkeypatch.setattr(hashlib_native, "_HAS_MERGE_SHARDED", False)
+    assert engine.stack_implementation == "numpy"
+    hidden, (native, numpy_, stacks) = serve("stacked-numpy")
+    assert stacks >= 12 and (native, numpy_) == (0, stacks)
+    assert hidden == with_library
+

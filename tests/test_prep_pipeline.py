@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from gubernator_tpu.api.types import Algorithm, RateLimitReq
+from gubernator_tpu.core.engine import _hn as _native  # None: not built
 from gubernator_tpu.core.engine import (
     build_presorted_request,
     pad_request_sorted,
@@ -203,6 +204,313 @@ def test_merged_fields_byte_identical_sharded():
                 )
             _assert_same(m["order"], order_ref, "order")
             _assert_same(take, take_ref, "take_idx")
+
+
+# -- the native sharded merge against its numpy twin (PR 44) ----------------
+
+SUB = sub_batch_ladder(BUCKETS)
+
+
+def _keys_owned_by(rng, n_shards, per_shard):
+    """{shard: uint64 key hashes it owns}, `per_shard` distinct each."""
+    from gubernator_tpu.parallel.sharded import owner_of_np
+
+    kh = rng.integers(1, 2**63, 64 * n_shards * per_shard, np.int64).astype(
+        np.uint64
+    )
+    kh = np.unique(kh)
+    rng.shuffle(kh)
+    owner = owner_of_np(kh, n_shards)
+    owned = {s: kh[owner == s][:per_shard] for s in range(n_shards)}
+    assert all(v.shape[0] == per_shard for v in owned.values())
+    return owned
+
+
+def _group_of(rng, key_hash):
+    """A caller group over the given key hashes, other fields random."""
+    n = key_hash.shape[0]
+    g = _rand_group(rng, n)
+    g["key_hash"] = np.asarray(key_hash, np.uint64)
+    return g
+
+
+def _numpy_twin(runs, n_shards, sub=SUB):
+    """(merged, req, take_idx, groups, B_sub): the flat merge, then the
+    layout per shard in numpy (stack_shard_groups inside it)."""
+    m = merge_runs(runs)
+    return (m, *build_presorted_sharded(
+        sub, SLOTS, n_shards, m["fields"], m["skey"], m["counts"]
+    ))
+
+
+def _assert_native_stack_is_the_twin(groups, n_shards, sub=SUB):
+    """guber_merge_runs_sharded == the numpy twin, byte for byte in
+    every output; returns the native call's result."""
+    from gubernator_tpu.native import hashlib_native as hn
+
+    runs = [prep_run_sharded(g, SLOTS, n_shards) for g in groups]
+    m, req, take, grp, B_sub = _numpy_twin(runs, n_shards, sub)
+    got = hn.merge_runs_sharded_native(runs, n_shards, SLOTS, sub)
+    assert got["B_sub"] == B_sub
+    assert got["G_sub"] == grp.key_hash.shape[1]
+    assert got["n"] == m["order"].shape[0]
+    _assert_same(got["order"], m["order"], "order")
+    _assert_same(got["take_idx"], take, "take_idx")
+    _assert_same(got["counts"], np.asarray(m["counts"], np.int64), "counts")
+    assert set(got["fields"]) == set(req._fields)
+    for f in req._fields:
+        _assert_same(got["fields"][f], getattr(req, f), f"req.{f}")
+        assert got["fields"][f].flags.c_contiguous, f
+    assert set(got["groups"]) == set(grp._fields)
+    for f in grp._fields:
+        _assert_same(got["groups"][f], getattr(grp, f), f"groups.{f}")
+        assert got["groups"][f].flags.c_contiguous, f
+    return got
+
+
+needs_sharded_merge = pytest.mark.skipif(
+    not getattr(_native, "_HAS_MERGE_SHARDED", False),
+    reason="libguberhash.so not built, or built before "
+    "guber_merge_runs_sharded (make -C gubernator_tpu/native)",
+)
+
+
+@needs_sharded_merge
+@pytest.mark.parametrize("n_shards", [2, 4, 8])
+@pytest.mark.parametrize("n_runs", [1, 2, 7, 64])
+def test_native_sharded_merge_is_the_numpy_twin(n_runs, n_shards):
+    """Random duplicate-heavy runs with saturating values and GNP flags,
+    for 1, 2, 7 and 64 runs over 2, 4 and 8 shards."""
+    rng = np.random.default_rng(0x5AD + 97 * n_runs + n_shards)
+    for trial in range(6):
+        pool = rng.integers(1, 2**63, 40 + 8 * trial, np.int64).astype(
+            np.uint64
+        )
+        top = max(2, 1000 // n_runs)
+        groups = [
+            _rand_group(rng, int(rng.integers(1, top)), pool)
+            for _ in range(n_runs)
+        ]
+        _assert_native_stack_is_the_twin(groups, n_shards)
+
+
+def _case_empty_shard(rng, n_shards):
+    owned = _keys_owned_by(rng, n_shards, 5)
+    # shard 1 (a middle one where there is one) and the last draw nothing
+    full = [s for s in range(n_shards) if s not in (1, n_shards - 1)]
+    keys = np.concatenate([owned[s] for s in full] or [owned[0]])
+    return [_group_of(rng, rng.choice(keys, 40)) for _ in range(3)]
+
+
+def _case_all_but_one_shard_empty(rng, n_shards):
+    owned = _keys_owned_by(rng, n_shards, 5)
+    return [_group_of(rng, rng.choice(owned[n_shards - 1], 30))]
+
+
+def _case_one_row_shard(rng, n_shards):
+    owned = _keys_owned_by(rng, n_shards, 6)
+    keys = np.concatenate(
+        [owned[0][:1]] + [owned[s] for s in range(1, n_shards)]
+    )
+    lone = np.array([owned[0][0]], np.uint64)  # shard 0's one row
+    rest = rng.choice(keys[1:], 50)
+    return [_group_of(rng, rest[:25]), _group_of(rng, lone),
+            _group_of(rng, rest[25:])]
+
+
+def _case_hot_key_fills_a_shard(rng, n_shards):
+    """The cell's LEAKY hot key: one key repeated until its shard alone
+    fills a whole sub-rung, the other shards a few rows each."""
+    owned = _keys_owned_by(rng, n_shards, 4)
+    hot = owned[n_shards // 2][0]
+    other = np.concatenate(
+        [owned[s] for s in range(n_shards) if s != n_shards // 2]
+    )
+    rung = SUB[3]
+    return [
+        _group_of(rng, np.concatenate(
+            [np.full(rung // 2, hot, np.uint64), rng.choice(other, 9)]
+        )),
+        _group_of(rng, np.full(rung - rung // 2, hot, np.uint64)),
+    ]
+
+
+def _fullest_shard_draws(rng, n_shards, rows):
+    """Runs whose fullest shard draws exactly `rows` (distinct keys and
+    repeats mixed), the others fewer."""
+    owned = _keys_owned_by(rng, n_shards, 12)
+    fullest = rng.choice(owned[0], rows)
+    others = np.concatenate([
+        rng.choice(owned[s], rows // 3) for s in range(1, n_shards)
+    ])
+    keys = np.concatenate([fullest, others])
+    rng.shuffle(keys)
+    cut = keys.shape[0] // 2
+    return [_group_of(rng, keys[:cut]), _group_of(rng, keys[cut:])]
+
+
+def _case_exactly_on_a_sub_rung(rng, n_shards):
+    return _fullest_shard_draws(rng, n_shards, SUB[2])
+
+
+def _case_one_past_a_sub_rung(rng, n_shards):
+    return _fullest_shard_draws(rng, n_shards, SUB[2] + 1)
+
+
+def _case_exactly_on_the_top_rung(rng, n_shards):
+    return _fullest_shard_draws(rng, n_shards, SUB[-1])
+
+
+def _case_duplicates_straddle_runs(rng, n_shards):
+    """The same few keys in every run, each row's other fields its own:
+    equal sort keys must resolve in run order, then in caller order."""
+    owned = _keys_owned_by(rng, n_shards, 2)
+    keys = np.concatenate([owned[s] for s in range(n_shards)])
+    return [_group_of(rng, rng.choice(keys, 17)) for _ in range(5)]
+
+
+def _case_single_rows(rng, n_shards):
+    owned = _keys_owned_by(rng, n_shards, 1)
+    return [_group_of(rng, owned[s]) for s in reversed(range(n_shards))]
+
+
+def _case_empty_runs_between(rng, n_shards):
+    pool = rng.integers(1, 2**63, 16, np.int64).astype(np.uint64)
+    return [
+        _rand_group(rng, n, pool) for n in (0, 31, 0, 0, 12, 0)
+    ]
+
+
+EDGE_CASES = [
+    _case_empty_shard, _case_all_but_one_shard_empty, _case_one_row_shard,
+    _case_hot_key_fills_a_shard, _case_exactly_on_a_sub_rung,
+    _case_one_past_a_sub_rung, _case_exactly_on_the_top_rung,
+    _case_duplicates_straddle_runs, _case_single_rows,
+    _case_empty_runs_between,
+]
+
+
+@needs_sharded_merge
+@pytest.mark.parametrize("n_shards", [2, 4, 8])
+@pytest.mark.parametrize(
+    "case", EDGE_CASES, ids=lambda c: c.__name__[len("_case_"):]
+)
+def test_native_sharded_merge_edge_layouts(case, n_shards):
+    rng = np.random.default_rng(0xED6E + n_shards)
+    groups = case(rng, n_shards)
+    got = _assert_native_stack_is_the_twin(groups, n_shards)
+    counts = got["counts"]
+    if case is _case_empty_shard:
+        assert counts[1] == 0 or n_shards == 2
+        assert counts[n_shards - 1] == 0
+        assert not got["fields"]["valid"][n_shards - 1].any()
+    elif case is _case_all_but_one_shard_empty:
+        assert (counts[:-1] == 0).all() and counts[-1] == 30
+    elif case is _case_one_row_shard:
+        assert counts[0] == 1
+    elif case is _case_hot_key_fills_a_shard:
+        assert counts.max() == got["B_sub"] == SUB[3]
+        assert got["fields"]["valid"][n_shards // 2].all()
+    elif case is _case_exactly_on_a_sub_rung:
+        assert counts.max() == got["B_sub"] == SUB[2]
+    elif case is _case_one_past_a_sub_rung:
+        assert counts.max() == SUB[2] + 1 and got["B_sub"] == SUB[3]
+    elif case is _case_exactly_on_the_top_rung:
+        assert counts.max() == got["B_sub"] == SUB[-1]
+
+
+@needs_sharded_merge
+@pytest.mark.parametrize("hidden", [False, True], ids=["native", "hidden"])
+def test_mesh_merge_prepped_follows_the_library(hidden, monkeypatch):
+    """PartitionedEngine.merge_prepped on a mesh: with the symbol the
+    native call lays the batch out, with it hidden the numpy twin does
+    — the same dict either way — and the engine's two counters say
+    which; `shard_counts` is told the same rows, slots and fullest
+    shard by both."""
+    import jax
+
+    from gubernator_tpu.native import hashlib_native as hn
+    from gubernator_tpu.parallel.sharded import MeshEngine
+
+    if hidden:
+        monkeypatch.setattr(hn, "_HAS_MERGE_SHARDED", False)
+    eng = MeshEngine(
+        StoreConfig(rows=4, slots=SLOTS), devices=jax.devices()[:4],
+        buckets=BUCKETS,
+    )
+    assert eng.stack_implementation == ("numpy" if hidden else "native")
+    told = []
+    eng.shard_counts = lambda *a: told.append(a)
+    rng = np.random.default_rng(0x44)
+    batches = 5
+    for _ in range(batches):
+        pool = rng.integers(1, 2**63, 24, np.int64).astype(np.uint64)
+        groups = [
+            _rand_group(rng, int(rng.integers(1, 90)), pool)
+            for _ in range(int(rng.integers(1, 5)))
+        ]
+        runs = [eng.prep_run(g) for g in groups]
+        merged = eng.merge_prepped(runs)
+        m, req, take, grp, B_sub = _numpy_twin(runs, 4, eng.sub_buckets)
+        assert merged["B_sub"] == B_sub
+        assert merged["n"] == m["order"].shape[0]
+        _assert_same(merged["order"], m["order"], "order")
+        _assert_same(merged["take_idx"], take, "take_idx")
+        for f in req._fields:
+            _assert_same(getattr(merged["req"], f), getattr(req, f), f)
+        for f in grp._fields:
+            _assert_same(getattr(merged["groups"], f), getattr(grp, f), f)
+        assert told[-1] == (
+            merged["n"], 4 * B_sub, int(np.max(m["counts"]))
+        )
+    assert (eng.native_stacks, eng.numpy_stacks) == (
+        (0, batches) if hidden else (batches, 0)
+    )
+    assert len(told) == batches
+
+
+@needs_sharded_merge
+def test_batch_past_the_ladder_declines_to_the_twin_and_warns_once(
+    monkeypatch, caplog
+):
+    """A fullest shard past the sub-rung ladder's top: the native call
+    declines (None, nothing written), `merge_prepped` serves the batch
+    from the numpy twin, which extends the ladder as it did and warns —
+    once, however many such batches follow."""
+    import logging
+
+    import jax
+
+    import gubernator_tpu.parallel.sharded as sharded_mod
+    from gubernator_tpu.core.engine import extend_ladder
+    from gubernator_tpu.native import hashlib_native as hn
+    from gubernator_tpu.parallel.sharded import MeshEngine
+
+    monkeypatch.setattr(sharded_mod, "_warned_ladder_overflow", False)
+    eng = MeshEngine(
+        StoreConfig(rows=4, slots=SLOTS), devices=jax.devices()[:4],
+        buckets=BUCKETS,
+    )
+    rng = np.random.default_rng(0x70F)
+    top = max(eng.sub_buckets)
+    groups = _fullest_shard_draws(rng, 4, top + 1)
+    runs = [eng.prep_run(g) for g in groups]
+    assert hn.merge_runs_sharded_native(
+        runs, 4, SLOTS, eng.sub_buckets
+    ) is None
+    with caplog.at_level(logging.WARNING, logger="gubernator.sharded"):
+        first = eng.merge_prepped(runs)
+        second = eng.merge_prepped(runs)
+    warned = [r for r in caplog.records if "exceeds the configured ladder"
+              in r.getMessage()]
+    assert len(warned) == 1
+    want_rung = extend_ladder(eng.sub_buckets, top + 1)[-1]
+    assert first["B_sub"] == second["B_sub"] == want_rung > top
+    assert first["req"].key_hash.shape == (4, want_rung)
+    assert (eng.native_stacks, eng.numpy_stacks) == (0, 2)
+    # a batch that fits goes back to the native call
+    eng.merge_prepped([eng.prep_run(_rand_group(rng, 50))])
+    assert (eng.native_stacks, eng.numpy_stacks) == (1, 2)
 
 
 def test_engine_presorted_matches_concat_argsort_end_to_end():
