@@ -184,13 +184,12 @@ class PeerAnswers:
 
 
 def split_ready() -> bool:
-    """True where libguberhash.so is built and holds the four calls the
-    GEB door's split by owner and the forwarder's column RPC are made
-    of (ring_owners, encode_peer_batch, parse_peer_answers,
-    encode_string_answers): without them a frame of mixed ownership is
+    """True where libguberhash.so loaded, and with it the four calls
+    the GEB door's split by owner and the forwarder's column RPC are
+    made of (ring_owners, encode_peer_batch, parse_peer_answers,
+    encode_string_answers): without it a frame of mixed ownership is
     served through request objects, as before the split."""
-    lib = native_lib()
-    return lib is not None and getattr(lib, "_HAS_SPLIT", False)
+    return native_lib() is not None
 
 
 class ForwardGroup:

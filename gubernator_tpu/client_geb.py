@@ -183,7 +183,10 @@ def _load_hasher() -> None:
         return
     _hash_checked = True
     try:
-        # ctypes + numpy only — no JAX (gubernator_tpu.native)
+        # ctypes + numpy only — no JAX. The one import of the native
+        # module outside core/hashing.native_lib(), which this client
+        # cannot reach (`gubernator_tpu.core` imports JAX); whole or
+        # absent here too: any failure is the blake2b fallback
         from gubernator_tpu.native import hashlib_native
 
         _hash_batch = hashlib_native.hash_batch

@@ -35,7 +35,7 @@ from gubernator_tpu.api.types import (
     millisecond_now,
     resps_from_columns,
 )
-from gubernator_tpu.core.hashing import slot_hash_batch
+from gubernator_tpu.core.hashing import native_lib, slot_hash_batch
 from gubernator_tpu.core.kernels import (
     BatchGroups,
     BatchRequest,
@@ -132,20 +132,12 @@ def _np_presort(key_hash: np.ndarray, store_buckets: int) -> np.ndarray:
     ).astype(np.int32)
 
 
-try:  # native LSD radix presort (~3.6x numpy at 16k keys); same order
-    from gubernator_tpu.native import hashlib_native as _hn
-
-    if not _hn._HAS_PRESORT:  # stale prebuilt .so without the symbol
-        raise AttributeError("guber_presort missing")
-    _presort = _hn.presort
-except (ImportError, AttributeError, OSError):  # pragma: no cover
-    # not built / stale / load failure — the numpy path works
-    _presort = _np_presort
-    _hn = None
-
-# native one-pass gather+clip+pad marshalling (guberhash.cc); the numpy
-# fallback below costs ~40ns/element across the six request fields
-_marshal = _hn if (_hn is not None and _hn._HAS_MARSHAL) else None
+# libguberhash.so, whole or absent (core/hashing.native_lib): the native
+# LSD radix presort (~3.6x numpy at 16k keys, same order) and one-pass
+# gather+clip+pad marshalling (the numpy form costs ~40ns/element across
+# the six request fields), else the numpy twins in this file
+_hn = native_lib()
+_presort = _hn.presort if _hn is not None else _np_presort
 
 
 def _np_presort_grouped(key_hash: np.ndarray, store_buckets: int):
@@ -163,9 +155,7 @@ def _np_presort_grouped(key_hash: np.ndarray, store_buckets: int):
 
 
 _presort_grouped = (
-    _hn.presort_grouped
-    if (_hn is not None and _hn._HAS_PRESORT_GROUPED)
-    else _np_presort_grouped
+    _hn.presort_grouped if _hn is not None else _np_presort_grouped
 )
 
 
@@ -391,7 +381,6 @@ def pad_request_sorted(
 
     if (
         _hn is not None
-        and getattr(_hn, "_HAS_PREP", False)
         and n
         and with_groups
         and _hn.prep_threads() > 1
@@ -438,20 +427,20 @@ def pad_request_sorted(
 
     valid = np.zeros(B, bool)
     valid[:n] = True
-    if _marshal is not None and n:
+    if _hn is not None and n:
         req = BatchRequest(
-            key_hash=_marshal.gather_pad_u64(key_hash, order_n, B),
-            hits=_marshal.gather_pad_i64_clip(
+            key_hash=_hn.gather_pad_u64(key_hash, order_n, B),
+            hits=_hn.gather_pad_i64_clip(
                 hits, order_n, B, -_I32_SAT, _I32_SAT
             ),
-            limit=_marshal.gather_pad_i64_clip(
+            limit=_hn.gather_pad_i64_clip(
                 limit, order_n, B, -_I32_SAT, _I32_SAT
             ),
-            duration=_marshal.gather_pad_i64_clip(
+            duration=_hn.gather_pad_i64_clip(
                 duration, order_n, B, TIME_FLOOR, MAX_DURATION_MS
             ),
-            algo=_marshal.gather_pad_i32(algo, order_n, B),
-            gnp=_marshal.gather_pad_u8(
+            algo=_hn.gather_pad_i32(algo, order_n, B),
+            gnp=_hn.gather_pad_u8(
                 np.asarray(gnp, bool).view(np.uint8), order_n, B
             ).view(bool),
             valid=valid,
@@ -536,23 +525,23 @@ def _gather_clip_sorted(fields: dict, order: np.ndarray, n: int) -> dict:
     order. Native gather_pad helpers when built (one GIL-free C call
     per field — arrival preps run while the serving loop is hot, so op
     count is wall time); numpy fallback is elementwise-identical."""
-    if _marshal is not None and n:
+    if _hn is not None and n:
         return dict(
-            key_hash=_marshal.gather_pad_u64(
+            key_hash=_hn.gather_pad_u64(
                 fields["key_hash"], order, n
             ),
-            hits=_marshal.gather_pad_i64_clip(
+            hits=_hn.gather_pad_i64_clip(
                 fields["hits"], order, n, -_I32_SAT, _I32_SAT
             ),
-            limit=_marshal.gather_pad_i64_clip(
+            limit=_hn.gather_pad_i64_clip(
                 fields["limit"], order, n, -_I32_SAT, _I32_SAT
             ),
-            duration=_marshal.gather_pad_i64_clip(
+            duration=_hn.gather_pad_i64_clip(
                 fields["duration"], order, n, TIME_FLOOR,
                 MAX_DURATION_MS,
             ),
-            algo=_marshal.gather_pad_i32(fields["algo"], order, n),
-            gnp=_marshal.gather_pad_u8(
+            algo=_hn.gather_pad_i32(fields["algo"], order, n),
+            gnp=_hn.gather_pad_u8(
                 np.asarray(fields["gnp"], bool).view(np.uint8), order, n
             ).view(bool),
         )
@@ -576,7 +565,7 @@ def prep_run_single(fields: dict, store_buckets: int) -> dict:
     the merge is engine-agnostic). One fused native call when built
     (guber_prep_run — prep threads stay off the interpreter); the
     numpy fallback below is bit-identical."""
-    if _hn is not None and getattr(_hn, "_HAS_PREP_RUN", False):
+    if _hn is not None:
         return _hn.prep_run(
             fields, store_buckets, 1, -_I32_SAT, _I32_SAT, TIME_FLOOR,
             MAX_DURATION_MS,

@@ -18,10 +18,14 @@ Two independent hash roles, mirroring the reference's split:
 from __future__ import annotations
 
 import hashlib
+import logging
 import zlib
 from typing import Iterable, List
 
 import numpy as np
+
+log = logging.getLogger(__name__)
+
 
 def ring_hash(key: str) -> int:
     """crc32 point on the ring, matching reference hash.go:40-42."""
@@ -39,7 +43,7 @@ def _slot_hash_batch_py(keys: Iterable[str]) -> np.ndarray:
     return np.array([_slot_hash_py(k) for k in keys], dtype=np.uint64)
 
 
-# The native library (XXH64, gubernator_tpu/native) is loaded lazily.
+# The native library (gubernator_tpu/native) is loaded lazily, once.
 # Native and fallback produce different hash values; that is fine — slot
 # hashes are local to one process's store — but one process must use ONE
 # implementation consistently, which the lazy singleton guarantees.
@@ -48,11 +52,14 @@ _native_checked = False
 
 
 def native_lib():
-    """The loaded gubernator_tpu.native.hashlib_native module, or None
-    where libguberhash.so is not built: the ONE handle through which
-    this process hashes slot keys natively. Whoever hashes keys inside
-    a native call of its own (the PeersV1 door's wire fold) takes the
-    library from here, so its hashes are slot_hash_batch's."""
+    """The loaded gubernator_tpu.native.hashlib_native module, or None:
+    the ONE place this package asks whether libguberhash.so is there,
+    and the one answer a process holds. The library is whole — built
+    from this tree's guberhash.cc, every symbol bound — or absent: not
+    built, unloadable, or built from another source (the import names
+    the missing symbol). Absent, the numpy / Python twins serve and the
+    doors' native folds decline to the object path; the reason is
+    logged here, once."""
     global _native, _native_checked
     if not _native_checked:
         _native_checked = True
@@ -60,8 +67,12 @@ def native_lib():
             from gubernator_tpu.native import hashlib_native
 
             _native = hashlib_native
-        except Exception:
+        except Exception as e:
             _native = None
+            log.warning(
+                "libguberhash.so is absent, the numpy and Python forms "
+                "serve: %s", e,
+            )
     return _native
 
 

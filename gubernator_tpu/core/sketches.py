@@ -532,19 +532,18 @@ class NativeHotKeys:
 
 
 def _fold_lib():
-    """The native library where it has the observers' fold, else None
-    (not built, or built before the symbol)."""
+    """The native library, whose guber_traffic_fold is the observers'
+    fold, or None where it is absent."""
     from gubernator_tpu.core.hashing import native_lib
 
-    lib = native_lib()
-    return lib if getattr(lib, "_HAS_TRAFFIC_FOLD", False) else None
+    return native_lib()
 
 
 class TrafficStats:
     """Per-instance traffic observability: distinct keys + hot keys.
 
-    One batch is one fold of both sketches. Where libguberhash.so has
-    the symbol it is ONE native call on columns with the GIL released
+    One batch is one fold of both sketches. Where libguberhash.so
+    loaded it is ONE native call on columns with the GIL released
     (`implementation` "native": `hot` a NativeHotKeys, the HLL's
     registers still this object's numpy array); anywhere else, and with
     `native=False`, the Python classes above fold it ("python") — same

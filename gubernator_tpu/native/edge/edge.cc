@@ -1479,7 +1479,7 @@ class Router {
         if (nd.bridge.empty()) {
           eligible = false;
         } else {
-          // a departed endpoint (nullptr: this ring snapshot predates
+          // a departed endpoint (nullptr: this ring snapshot is older than
           // an eviction) or a peer that hasn't advertised the fast
           // path (mixed fleet, or its lane hasn't completed the first
           // hello yet) gets its items over the slow path instead of a

@@ -1504,7 +1504,7 @@ constexpr int64_t PEER_UTF8 = -4;       // name or key not valid UTF-8
 constexpr int64_t PEER_TRUNCATED = -5;  // a length or varint runs past
                                         // its message
 constexpr int64_t PEER_TOO_MANY = -6;   // more items than max_items
-// -7 is hashlib_native's own: a library built before the symbol
+// -7 is unused
 constexpr int64_t FRAME_EMPTY = -8;     // an empty name or unique_key
 constexpr int64_t FRAME_TRAILING = -9;  // bytes left after the last item
 constexpr int64_t FRAME_NUL = -10;      // a NUL byte in a name or key:

@@ -1,4 +1,8 @@
-"""ctypes bindings for libguberhash.so (see guberhash.cc)."""
+"""ctypes bindings for libguberhash.so (see guberhash.cc).
+
+Importing this module either binds every symbol below or raises
+ImportError: the package reaches it through core/hashing.native_lib()
+alone, which turns that error into "absent" once, for everybody."""
 
 from __future__ import annotations
 
@@ -10,59 +14,140 @@ from typing import List
 import numpy as np
 
 _SO = pathlib.Path(__file__).resolve().parent / "libguberhash.so"
+_REBUILD = "make -C gubernator_tpu/native"
 if not _SO.exists():
-    raise ImportError(f"native hash library not built: {_SO}")
+    raise ImportError(f"native library not built: {_SO} ({_REBUILD})")
 
 _lib = ctypes.CDLL(str(_SO))
-_lib.guber_hash_batch.argtypes = [
-    ctypes.c_char_p,
-    ctypes.POINTER(ctypes.c_int64),
-    ctypes.c_int64,
-    ctypes.c_uint64,
-    ctypes.POINTER(ctypes.c_uint64),
-]
-_lib.guber_crc32_batch.argtypes = [
-    ctypes.c_char_p,
-    ctypes.POINTER(ctypes.c_int64),
-    ctypes.c_int64,
-    ctypes.POINTER(ctypes.c_uint32),
-]
-try:  # symbol absent in a stale prebuilt .so — the hash/crc fast paths
-    # above must keep working regardless; presort() raises if missing
-    _lib.guber_presort.argtypes = [
-        ctypes.POINTER(ctypes.c_uint64),
-        ctypes.c_int64,
-        ctypes.c_uint64,
-        ctypes.POINTER(ctypes.c_int32),
-    ]
-    _HAS_PRESORT = True
-except AttributeError:
-    _HAS_PRESORT = False
 
+_i32p = ctypes.POINTER(ctypes.c_int32)
+_i64p = ctypes.POINTER(ctypes.c_int64)
+_u64p = ctypes.POINTER(ctypes.c_uint64)
+_u8p = ctypes.POINTER(ctypes.c_uint8)
+_vpp = ctypes.POINTER(ctypes.c_void_p)
+_vp = ctypes.c_void_p
+_i64 = ctypes.c_int64
+
+# Every symbol this module calls, declared here and nowhere else. The
+# library is whole or absent: one built from another guberhash.cc than
+# this tree's lacks a name, ctypes raises AttributeError on it, and the
+# import fails — to every caller (core/hashing.native_lib) the same as
+# a library that was never built.
 try:
-    _i32p = ctypes.POINTER(ctypes.c_int32)
+    _lib.guber_hash_batch.argtypes = [
+        ctypes.c_char_p, _i64p, _i64, ctypes.c_uint64, _u64p,
+    ]
+    _lib.guber_crc32_batch.argtypes = [
+        ctypes.c_char_p, _i64p, _i64, ctypes.POINTER(ctypes.c_uint32),
+    ]
+    _lib.guber_presort.argtypes = [_u64p, _i64, ctypes.c_uint64, _i32p]
+    _lib.guber_presort_grouped.argtypes = [
+        _u64p, _i64, ctypes.c_uint64, _i32p, _i32p, _i32p, _i64p,
+    ]
+    _lib.guber_presort_sharded.argtypes = [
+        _u64p, _i64, ctypes.c_uint64, ctypes.c_uint64, _i32p, _i64p,
+    ]
+    _lib.guber_presort_sharded_grouped.argtypes = [
+        _u64p, _i64, ctypes.c_uint64, ctypes.c_uint64, _i32p, _i64p,
+        _i32p, _i32p, _i64p,
+    ]
+    # one-pass gather + clip + pad marshalling
     _lib.guber_gather_pad_i64_clip.argtypes = [
-        ctypes.POINTER(ctypes.c_int64), _i32p, ctypes.c_int64,
-        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, _i32p,
+        _i64p, _i32p, _i64, _i64, _i64, _i64, _i32p,
     ]
-    _lib.guber_gather_pad_i32.argtypes = [
-        _i32p, _i32p, ctypes.c_int64, ctypes.c_int64, _i32p,
-    ]
-    _lib.guber_gather_pad_u64.argtypes = [
-        ctypes.POINTER(ctypes.c_uint64), _i32p, ctypes.c_int64,
-        ctypes.c_int64, ctypes.POINTER(ctypes.c_uint64),
-    ]
-    _lib.guber_gather_pad_u8.argtypes = [
-        ctypes.POINTER(ctypes.c_uint8), _i32p, ctypes.c_int64,
-        ctypes.c_int64, ctypes.POINTER(ctypes.c_uint8),
-    ]
+    _lib.guber_gather_pad_i32.argtypes = [_i32p, _i32p, _i64, _i64, _i32p]
+    _lib.guber_gather_pad_u64.argtypes = [_u64p, _i32p, _i64, _i64, _u64p]
+    _lib.guber_gather_pad_u8.argtypes = [_u8p, _i32p, _i64, _i64, _u8p]
     _lib.guber_unpermute_i32.argtypes = [
-        _i32p, _i32p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-        _i32p,
+        _i32p, _i32p, _i64, _i64, _i64, _i32p,
     ]
-    _HAS_MARSHAL = True
-except AttributeError:
-    _HAS_MARSHAL = False
+    # fused batch prep, arrival-time runs and their merges
+    _lib.guber_prep_sharded.restype = _i64
+    _lib.guber_prep_sharded.argtypes = [
+        _u64p, _i64p, _i64p, _i64p, _i32p, _u8p,          # inputs
+        _i64, ctypes.c_uint64, _i64,                      # n, buckets, ns
+        _i64p, _i64, _i64,                   # rungs, n_rungs, g_override
+        _i64, _i64, _i64, _i64,                           # clips
+        _i32p, _i64p, _i64p,                      # order, counts, picked
+        _u64p, _i32p, _i32p, _i32p, _i32p, _u8p, _u8p,    # fields
+        _u64p, _i32p, _i32p, _u8p, _i32p,                 # groups
+        _i64p,                                            # take_idx
+    ]
+    _lib.guber_prep_threads.restype = _i64
+    _lib.guber_unflatten_resp.argtypes = [
+        _i32p, _i32p, _i64p, _i64, _i64, _i64, _i64, _i32p,
+    ]
+    _lib.guber_prep_run.restype = _i64
+    _lib.guber_prep_run.argtypes = [
+        _u64p, _i64p, _i64p, _i64p, _i32p, _u8p,
+        _i64, ctypes.c_uint64, _i64, _i64, _i64, _i64, _i64,
+        _i32p, _i64p, _u64p, _u64p, _i32p, _i32p, _i32p, _i32p, _u8p,
+    ]
+    _lib.guber_merge_runs.restype = _i64
+    _lib.guber_merge_runs.argtypes = [
+        _vpp, _vpp, _vpp, _vpp, _vpp, _vpp, _vpp, _vpp,
+        _i64p, _i64p, _i64, _i64,
+        _i64p, _i64,
+        _u64p, _i32p, _u64p, _i32p, _i32p, _i32p, _i32p, _u8p, _u8p,
+        _i32p, _i32p, _u64p, _i32p, _u8p, _i64p, _i64p,
+    ]
+    _lib.guber_merge_runs_sharded.restype = _i64
+    _lib.guber_merge_runs_sharded.argtypes = (
+        [_vpp] * 8 + [_i64p, _i64p] + [_i64] * 3 + [_i64p, _i64]
+        + [_vp] * 16
+    )
+    # the PeersV1 door's wire fold, the GEB door's string-frame parse,
+    # its split by owner and the forwarder's column RPC
+    _lib.guber_parse_peer_batch.restype = _i64
+    _lib.guber_parse_peer_batch.argtypes = [
+        ctypes.c_char_p, _i64, _i64, ctypes.c_uint64,
+    ] + [_vp] * 10
+    _lib.guber_encode_peer_answers.restype = _i64
+    _lib.guber_encode_peer_answers.argtypes = [_vp] * 4 + [_i64, _vp]
+    _lib.guber_peer_answer_max_bytes.restype = _i64
+    _lib.guber_parse_string_frame.restype = _i64
+    _lib.guber_parse_string_frame.argtypes = [
+        ctypes.c_char_p, _i64, _i64, ctypes.c_uint64,
+    ] + [_vp] * 12
+    _lib.guber_ring_owners.restype = _i64
+    _lib.guber_ring_owners.argtypes = [
+        ctypes.c_char_p, _i64, _i64, _vp, _i64, _vp,
+    ]
+    _lib.guber_encode_peer_batch.restype = _i64
+    _lib.guber_encode_peer_batch.argtypes = (
+        [ctypes.c_char_p] + [_vp] * 10 + [_i64, _vp, _i64]
+    )
+    _lib.guber_parse_peer_answers.restype = _i64
+    _lib.guber_parse_peer_answers.argtypes = [
+        ctypes.c_char_p, _i64, _i64,
+    ] + [_vp] * 4
+    _lib.guber_encode_string_answers.restype = _i64
+    _lib.guber_encode_string_answers.argtypes = [_vp] * 6 + [
+        ctypes.c_char_p, _vp, _i64, _i64, _vp, _i64,
+    ]
+    # the traffic observers' per-batch fold
+    _lib.guber_hotkeys_new.restype = _vp
+    _lib.guber_hotkeys_new.argtypes = [_i64]
+    _lib.guber_hotkeys_free.restype = None
+    _lib.guber_hotkeys_free.argtypes = [_vp]
+    _lib.guber_hotkeys_reset.restype = None
+    _lib.guber_hotkeys_reset.argtypes = [_vp]
+    _lib.guber_traffic_fold.restype = _i64
+    _lib.guber_traffic_fold.argtypes = [
+        _vp, _vp, _i64, ctypes.c_char_p, _i64, _vp, _vp, _i64,
+    ]
+    _lib.guber_hotkeys_size.restype = _i64
+    _lib.guber_hotkeys_size.argtypes = [_vp] * 3
+    _lib.guber_hotkeys_export.restype = _i64
+    _lib.guber_hotkeys_export.argtypes = [_vp] * 5
+except AttributeError as e:
+    raise ImportError(
+        f"native library not built from this tree's guberhash.cc: {e} "
+        f"({_REBUILD})"
+    ) from None
+
+_PEER_ANSWER_MAX = int(_lib.guber_peer_answer_max_bytes())
+hotkeys_free = _lib.guber_hotkeys_free
 
 
 def _ptr(a, ctype):
@@ -124,30 +209,10 @@ def unpermute_i32(sorted_stack: np.ndarray, order: np.ndarray,
     return out
 
 
-try:
-    _lib.guber_presort_grouped.argtypes = [
-        ctypes.POINTER(ctypes.c_uint64),
-        ctypes.c_int64,
-        ctypes.c_uint64,
-        ctypes.POINTER(ctypes.c_int32),
-        ctypes.POINTER(ctypes.c_int32),
-        ctypes.POINTER(ctypes.c_int32),
-        ctypes.POINTER(ctypes.c_int64),
-    ]
-    _HAS_PRESORT_GROUPED = True
-except AttributeError:
-    _HAS_PRESORT_GROUPED = False
-
-
 def presort_grouped(key_hash: np.ndarray, buckets: int):
     """(order int32[n], group_id int32[n], leader_pos int32[n], G) —
     the presort permutation plus the duplicate-key group structure of
     the sorted stream (only leader_pos[:G] is meaningful)."""
-    if not _HAS_PRESORT_GROUPED:
-        raise AttributeError(
-            "libguberhash.so predates guber_presort_grouped; rebuild with "
-            "make -C gubernator_tpu/native"
-        )
     kh = np.ascontiguousarray(key_hash, np.uint64)
     n = kh.shape[0]
     order = np.empty(n, np.int32)
@@ -161,19 +226,6 @@ def presort_grouped(key_hash: np.ndarray, buckets: int):
     )
     return order, group_id, leader_pos, G.value
 
-
-try:
-    _lib.guber_presort_sharded.argtypes = [
-        ctypes.POINTER(ctypes.c_uint64),
-        ctypes.c_int64,
-        ctypes.c_uint64,
-        ctypes.c_uint64,
-        ctypes.POINTER(ctypes.c_int32),
-        ctypes.POINTER(ctypes.c_int64),
-    ]
-    _HAS_PRESORT_SHARDED = True
-except AttributeError:
-    _HAS_PRESORT_SHARDED = False
 
 # Fixed seed: slot hashes are instance-local but stable across restarts for
 # debuggability.
@@ -224,11 +276,6 @@ def presort(key_hash: np.ndarray, buckets: int) -> np.ndarray:
     the order decide_presorted requires. Bit-identical to
     np.argsort(store.group_sort_key_np(kh, buckets), kind="stable") and
     ~15x faster (LSD radix in C)."""
-    if not _HAS_PRESORT:
-        raise AttributeError(
-            "libguberhash.so predates guber_presort; rebuild with "
-            "make -C gubernator_tpu/native"
-        )
     kh = np.ascontiguousarray(key_hash, np.uint64)
     out = np.empty(kh.shape[0], np.int32)
     _lib.guber_presort(
@@ -240,23 +287,6 @@ def presort(key_hash: np.ndarray, buckets: int) -> np.ndarray:
     return out
 
 
-try:
-    _lib.guber_presort_sharded_grouped.argtypes = [
-        ctypes.POINTER(ctypes.c_uint64),
-        ctypes.c_int64,
-        ctypes.c_uint64,
-        ctypes.c_uint64,
-        ctypes.POINTER(ctypes.c_int32),
-        ctypes.POINTER(ctypes.c_int64),
-        ctypes.POINTER(ctypes.c_int32),
-        ctypes.POINTER(ctypes.c_int32),
-        ctypes.POINTER(ctypes.c_int64),
-    ]
-    _HAS_PRESORT_SHARDED_GROUPED = True
-except AttributeError:
-    _HAS_PRESORT_SHARDED_GROUPED = False
-
-
 def presort_sharded_grouped(key_hash: np.ndarray, buckets: int,
                             n_shards: int):
     """(order, counts, group_id, leader_pos, group_counts) — the sharded
@@ -264,11 +294,6 @@ def presort_sharded_grouped(key_hash: np.ndarray, buckets: int,
     the GLOBAL group index of sorted row i; leader_pos[:sum(group_counts)]
     holds each global group's first sorted row; group_counts[s] counts
     shard s's groups."""
-    if not _HAS_PRESORT_SHARDED_GROUPED:
-        raise AttributeError(
-            "libguberhash.so predates guber_presort_sharded_grouped; "
-            "rebuild with make -C gubernator_tpu/native"
-        )
     kh = np.ascontiguousarray(key_hash, np.uint64)
     n = kh.shape[0]
     order = np.empty(n, np.int32)
@@ -291,11 +316,6 @@ def presort_sharded(key_hash: np.ndarray, buckets: int, n_shards: int):
     (owner_shard, bucket, fingerprint) plus per-shard row counts. The
     contiguous per-shard runs of the permutation are the mesh engine's
     per-chip sub-batches (parallel/sharded.py pad_request_sharded)."""
-    if not _HAS_PRESORT_SHARDED:
-        raise AttributeError(
-            "libguberhash.so predates guber_presort_sharded; rebuild with "
-            "make -C gubernator_tpu/native"
-        )
     kh = np.ascontiguousarray(key_hash, np.uint64)
     order = np.empty(kh.shape[0], np.int32)
     counts = np.empty(n_shards, np.int64)
@@ -310,36 +330,9 @@ def presort_sharded(key_hash: np.ndarray, buckets: int, n_shards: int):
     return order, counts
 
 
-try:
-    _u64p = ctypes.POINTER(ctypes.c_uint64)
-    _i64p = ctypes.POINTER(ctypes.c_int64)
-    _u8p = ctypes.POINTER(ctypes.c_uint8)
-    _lib.guber_prep_sharded.restype = ctypes.c_int64
-    _lib.guber_prep_sharded.argtypes = [
-        _u64p, _i64p, _i64p, _i64p, _i32p, _u8p,          # inputs
-        ctypes.c_int64, ctypes.c_uint64, ctypes.c_int64,  # n, buckets, ns
-        _i64p, ctypes.c_int64, ctypes.c_int64,            # rungs, n_rungs, g_override
-        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,  # clips
-        _i32p, _i64p, _i64p,                              # order, counts, picked
-        _u64p, _i32p, _i32p, _i32p, _i32p, _u8p, _u8p,    # fields
-        _u64p, _i32p, _i32p, _u8p, _i32p,                 # groups
-        _i64p,                                            # take_idx
-    ]
-    _lib.guber_prep_threads.restype = ctypes.c_int64
-    _lib.guber_unflatten_resp.argtypes = [
-        _i32p, _i32p, _i64p, ctypes.c_int64, ctypes.c_int64,
-        ctypes.c_int64, ctypes.c_int64, _i32p,
-    ]
-    _HAS_PREP = True
-except AttributeError:
-    _HAS_PREP = False
-
-
 def prep_threads() -> int:
     """Effective prep thread-pool width (GUBER_PREP_THREADS env,
     default hardware_concurrency; resolved once per process)."""
-    if not _HAS_PREP:
-        return 1
     return int(_lib.guber_prep_threads())
 
 
@@ -433,11 +426,6 @@ def prep_sharded(
     thread (default 2; see set_prep_generations). Callers keeping
     results past that — e.g. decide handles under a deep fetch
     pipeline — must copy."""
-    if not _HAS_PREP:
-        raise AttributeError(
-            "libguberhash.so predates guber_prep_sharded; rebuild with "
-            "make -C gubernator_tpu/native"
-        )
     kh = np.ascontiguousarray(key_hash, np.uint64)
     hits = np.ascontiguousarray(hits, np.int64)
     limit = np.ascontiguousarray(limit, np.int64)
@@ -521,46 +509,6 @@ def _stack_views(n_shards, B, G, kh, hits, limit, dur, algo, gnp, valid,
     return fields, groups
 
 
-try:
-    _lib.guber_merge_runs.restype = ctypes.c_int64
-    _vpp = ctypes.POINTER(ctypes.c_void_p)
-    _lib.guber_merge_runs.argtypes = [
-        _vpp, _vpp, _vpp, _vpp, _vpp, _vpp, _vpp, _vpp,
-        _i64p, _i64p, ctypes.c_int64, ctypes.c_int64,
-        _i64p, ctypes.c_int64,
-        _u64p, _i32p, _u64p, _i32p, _i32p, _i32p, _i32p, _u8p, _u8p,
-        _i32p, _i32p, _u64p, _i32p, _u8p, _i64p, _i64p,
-    ]
-    _HAS_MERGE = True
-except AttributeError:
-    _HAS_MERGE = False
-
-try:
-    _lib.guber_merge_runs_sharded.restype = ctypes.c_int64
-    _lib.guber_merge_runs_sharded.argtypes = (
-        [_vpp] * 8
-        + [_i64p, _i64p]
-        + [ctypes.c_int64] * 3
-        + [_i64p, ctypes.c_int64]
-        + [ctypes.c_void_p] * 16
-    )
-    _HAS_MERGE_SHARDED = True
-except (AttributeError, NameError):  # a library built before PR 44
-    _HAS_MERGE_SHARDED = False
-
-try:
-    _lib.guber_prep_run.restype = ctypes.c_int64
-    _lib.guber_prep_run.argtypes = [
-        _u64p, _i64p, _i64p, _i64p, _i32p, _u8p,
-        ctypes.c_int64, ctypes.c_uint64, ctypes.c_int64,
-        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-        _i32p, _i64p, _u64p, _u64p, _i32p, _i32p, _i32p, _i32p, _u8p,
-    ]
-    _HAS_PREP_RUN = True
-except AttributeError:
-    _HAS_PREP_RUN = False
-
-
 def prep_run(fields: dict, buckets: int, n_shards: int,
              lo: int, hi: int, dlo: int, dhi: int) -> dict:
     """Fused arrival-time per-group prep (guber_prep_run): sharded
@@ -568,11 +516,6 @@ def prep_run(fields: dict, buckets: int, n_shards: int,
     composite sort-key stream, in ONE GIL-free call — the producer
     side of merge_runs_native. Output layout matches the engines'
     numpy prep_run fallbacks bit-for-bit."""
-    if not _HAS_PREP_RUN:
-        raise AttributeError(
-            "libguberhash.so predates guber_prep_run; rebuild with "
-            "make -C gubernator_tpu/native"
-        )
     kh = np.ascontiguousarray(fields["key_hash"], np.uint64)
     hits = np.ascontiguousarray(fields["hits"], np.int64)
     limit = np.ascontiguousarray(fields["limit"], np.int64)
@@ -670,11 +613,6 @@ def merge_runs_native(runs, B: int, g_rungs=None) -> dict:
     conventions — and the dict gains G, group_key_hash/group_end/
     group_valid [:G], padded leader_pos [:G], and a B-sized group_id.
     """
-    if not _HAS_MERGE:
-        raise AttributeError(
-            "libguberhash.so predates guber_merge_runs; rebuild with "
-            "make -C gubernator_tpu/native"
-        )
     k = len(runs)
     n = int(sum(r["n"] for r in runs))
     assert B >= n, (B, n)
@@ -754,11 +692,6 @@ def merge_runs_sharded_native(runs, n_shards: int, store_buckets: int,
     group_id: [n_shards, B_sub]}), or None where the fullest shard
     exceeds the ladder's top: extending the ladder, and the warning
     that goes with it, stay the numpy twin's."""
-    if not _HAS_MERGE_SHARDED:
-        raise AttributeError(
-            "libguberhash.so predates guber_merge_runs_sharded; rebuild "
-            "with make -C gubernator_tpu/native"
-        )
     k = len(runs)
     n = int(sum(r["n"] for r in runs))
     tabs, ns, bases = _run_tables(runs)
@@ -835,24 +768,7 @@ PEER_DECLINE = {
     -4: "bad_utf8",
     -5: "truncated",
     -6: "too_many_items",
-    -7: "stale_library",  # a libguberhash.so built before the fold
 }
-
-try:
-    _vp = ctypes.c_void_p
-    _lib.guber_parse_peer_batch.restype = ctypes.c_int64
-    _lib.guber_parse_peer_batch.argtypes = [
-        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_uint64,
-    ] + [_vp] * 10
-    _lib.guber_encode_peer_answers.restype = ctypes.c_int64
-    _lib.guber_encode_peer_answers.argtypes = [_vp] * 4 + [
-        ctypes.c_int64, _vp,
-    ]
-    _lib.guber_peer_answer_max_bytes.restype = ctypes.c_int64
-    _PEER_ANSWER_MAX = int(_lib.guber_peer_answer_max_bytes())
-    _HAS_PEER_WIRE = True
-except AttributeError:  # parse_peer_batch declines everything
-    _HAS_PEER_WIRE = False
 
 _PEER_COLUMNS = (
     ("key_hash", np.uint64), ("hits", np.int64), ("limit", np.int64),
@@ -869,8 +785,6 @@ def parse_peer_batch(wire: bytes, max_items: int):
     behavior, and the offsets of name and unique_key in `wire`), or
     (code < 0, None) where the native parser declines (PEER_DECLINE).
     One call with the GIL released, no object per item."""
-    if not _HAS_PEER_WIRE:
-        return -7, None
     # an item is at least its tag and a length byte
     cap = min(max_items, len(wire) // 2) + 1
     cols = {name: np.empty(cap, dt) for name, dt in _PEER_COLUMNS}
@@ -891,20 +805,10 @@ STRING_DECLINE = {
     -4: PEER_DECLINE[-4],
     -5: PEER_DECLINE[-5],
     -6: PEER_DECLINE[-6],
-    -7: PEER_DECLINE[-7],
     -8: "empty_name_or_key",
     -9: "trailing_bytes",
     -10: "nul_byte",
 }
-
-try:  # absent in a stale prebuilt .so: the door keeps its Python loop
-    _lib.guber_parse_string_frame.restype = ctypes.c_int64
-    _lib.guber_parse_string_frame.argtypes = [
-        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_uint64,
-    ] + [_vp] * 12
-    _HAS_STRING_FRAME = True
-except AttributeError:
-    _HAS_STRING_FRAME = False
 
 
 def parse_string_frame(payload: bytes, n: int):
@@ -916,8 +820,6 @@ def parse_string_frame(payload: bytes, n: int):
     joined by NUL and valid UTF-8. (code < 0, None, None) where the
     native parser declines (STRING_DECLINE). One call with the GIL
     released, no object per item."""
-    if not _HAS_STRING_FRAME:
-        return -7, None, None
     size = len(payload)
     # the wire count is untrusted: an item is at least 30 bytes
     if not 0 <= n <= size // 30:
@@ -962,32 +864,8 @@ ANSWER_DECLINE = {
     -3: PEER_DECLINE[-3],
     -5: PEER_DECLINE[-5],
     -6: PEER_DECLINE[-6],
-    -7: PEER_DECLINE[-7],
     -11: "error_or_metadata",
 }
-
-try:  # absent in a stale prebuilt .so: the door declines to split
-    _lib.guber_ring_owners.restype = ctypes.c_int64
-    _lib.guber_ring_owners.argtypes = [
-        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, _vp,
-        ctypes.c_int64, _vp,
-    ]
-    _lib.guber_encode_peer_batch.restype = ctypes.c_int64
-    _lib.guber_encode_peer_batch.argtypes = [ctypes.c_char_p] + [_vp] * 10 + [
-        ctypes.c_int64, _vp, ctypes.c_int64,
-    ]
-    _lib.guber_parse_peer_answers.restype = ctypes.c_int64
-    _lib.guber_parse_peer_answers.argtypes = [
-        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
-    ] + [_vp] * 4
-    _lib.guber_encode_string_answers.restype = ctypes.c_int64
-    _lib.guber_encode_string_answers.argtypes = [_vp] * 6 + [
-        ctypes.c_char_p, _vp, ctypes.c_int64, ctypes.c_int64, _vp,
-        ctypes.c_int64,
-    ]
-    _HAS_SPLIT = True
-except AttributeError:
-    _HAS_SPLIT = False
 
 #: the columns of parse_string_frame that guber_encode_peer_batch reads
 _FORWARD_COLUMNS = (
@@ -1040,8 +918,6 @@ def parse_peer_answers(wire: bytes, max_items: int):
     GetPeerRateLimitsResp as four int64 columns, or (code < 0, None)
     where the native parser declines (ANSWER_DECLINE: an item with an
     error or metadata among them). One call, no object per item."""
-    if not _HAS_SPLIT:
-        return -7, None
     cap = min(max_items, len(wire) // 2) + 1
     cols = [np.empty(cap, np.int64) for _ in range(4)]
     n = _lib.guber_parse_peer_answers(
@@ -1086,27 +962,6 @@ def encode_string_answers(
 
 
 # -- the traffic observers' per-batch fold (guberhash.cc, last section) ------
-
-try:  # absent in a stale prebuilt .so: TrafficStats keeps its Python classes
-    _lib.guber_hotkeys_new.restype = _vp
-    _lib.guber_hotkeys_new.argtypes = [ctypes.c_int64]
-    _lib.guber_hotkeys_free.restype = None
-    _lib.guber_hotkeys_free.argtypes = [_vp]
-    _lib.guber_hotkeys_reset.restype = None
-    _lib.guber_hotkeys_reset.argtypes = [_vp]
-    _lib.guber_traffic_fold.restype = ctypes.c_int64
-    _lib.guber_traffic_fold.argtypes = [
-        _vp, _vp, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int64, _vp,
-        _vp, ctypes.c_int64,
-    ]
-    _lib.guber_hotkeys_size.restype = ctypes.c_int64
-    _lib.guber_hotkeys_size.argtypes = [_vp] * 3
-    _lib.guber_hotkeys_export.restype = ctypes.c_int64
-    _lib.guber_hotkeys_export.argtypes = [_vp] * 5
-    hotkeys_free = _lib.guber_hotkeys_free
-    _HAS_TRAFFIC_FOLD = True
-except AttributeError:
-    _HAS_TRAFFIC_FOLD = False
 
 
 def hotkeys_new(capacity: int) -> int:

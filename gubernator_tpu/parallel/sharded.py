@@ -56,6 +56,7 @@ from gubernator_tpu.core.engine import (
     pad_request_sorted,
     pad_to_bucket,
 )
+from gubernator_tpu.core.hashing import native_lib
 from gubernator_tpu.core.kernels import (
     BatchGroups,
     BatchRequest,
@@ -297,20 +298,15 @@ def _np_presort_sharded_grouped(
     return order, counts, group_id, leader_pos, group_counts
 
 
-try:  # native radix presort with shard partitioning (guberhash.cc)
-    from gubernator_tpu.native import hashlib_native as _hn
-
-    if not _hn._HAS_PRESORT_SHARDED:
-        raise AttributeError("guber_presort_sharded missing")
+# libguberhash.so, whole or absent (core/hashing.native_lib): the native
+# radix presort with shard partitioning and the fused one-call prep,
+# else the numpy twins above
+_hn = native_lib()
+if _hn is not None:
     _presort_sharded = _hn.presort_sharded
-    _presort_sharded_grouped = (
-        _hn.presort_sharded_grouped
-        if _hn._HAS_PRESORT_SHARDED_GROUPED
-        else _np_presort_sharded_grouped
-    )
-    _prep_native = _hn.prep_sharded if _hn._HAS_PREP else None
-except (ImportError, AttributeError, OSError):  # pragma: no cover
-    _hn = None
+    _presort_sharded_grouped = _hn.presort_sharded_grouped
+    _prep_native = _hn.prep_sharded
+else:
     _presort_sharded = _np_presort_sharded
     _presort_sharded_grouped = _np_presort_sharded_grouped
     _prep_native = None
@@ -573,7 +569,7 @@ def prep_run_sharded(
     fallback below is bit-identical."""
     from gubernator_tpu.core.engine import _gather_clip_sorted
 
-    if _hn is not None and getattr(_hn, "_HAS_PREP_RUN", False):
+    if _hn is not None:
         from gubernator_tpu.core.store import (
             COUNTER_MAX,
             MAX_DURATION_MS,
@@ -1253,11 +1249,9 @@ class PartitionedEngine:
     @property
     def stack_implementation(self) -> str:
         """Who lays a merged mesh batch out per shard: "native"
-        (guber_merge_runs_sharded in the library that loaded) or
+        (guber_merge_runs_sharded, where the library loaded) or
         "numpy" (`_stack_presorted`)."""
-        if _hn is not None and getattr(_hn, "_HAS_MERGE_SHARDED", False):
-            return "native"
-        return "numpy"
+        return "native" if _hn is not None else "numpy"
 
     def _stack_presorted(self, fields, skey, counts):
         """(req, take_idx, groups, B_sub) of an already-merged sorted
@@ -1532,7 +1526,6 @@ class PartitionedEngine:
 
         if self.flat:
             from gubernator_tpu.core.engine import (
-                _hn as _ce_hn,
                 build_presorted_request,
                 choose_bucket,
                 group_rungs,
@@ -1540,12 +1533,8 @@ class PartitionedEngine:
 
             n = int(sum(r["n"] for r in runs))
             B = choose_bucket(self.buckets, n)
-            if (
-                _ce_hn is not None
-                and getattr(_ce_hn, "_HAS_MERGE", False)
-                and n
-            ):
-                m = _ce_hn.merge_runs_native(
+            if _hn is not None and n:
+                m = _hn.merge_runs_native(
                     runs, B, g_rungs=group_rungs(B)
                 )
                 req = BatchRequest(
@@ -1667,10 +1656,7 @@ class PartitionedEngine:
         packed, order, take_idx, n, B, epoch = handle
         packed = np.asarray(jax.device_get(packed))
         if take_idx is None:
-            from gubernator_tpu.core.engine import (
-                _marshal,
-                unpermute_responses,
-            )
+            from gubernator_tpu.core.engine import unpermute_responses
             from gubernator_tpu.core.kernels import unpack_outputs
 
             self.stats.add_batch(
@@ -1679,8 +1665,8 @@ class PartitionedEngine:
                 int(packed[4 * B + 2]),
                 int(packed[4 * B + 3]),
             )
-            if _marshal is not None:
-                u = _marshal.unpermute_i32(
+            if _hn is not None:
+                u = _hn.unpermute_i32(
                     packed[: 4 * B].reshape(4, B), order, n
                 )
                 status, rlimit, remaining, reset = u[0], u[1], u[2], u[3]
@@ -1700,17 +1686,15 @@ class PartitionedEngine:
             int(packed[:, 4 * B_sub + 2].sum()),
             int(packed[:, 4 * B_sub + 3].sum()),
         )
-        if _prep_native is not None and n > 0:
+        if _hn is not None and n > 0:
             # native one-pass unflatten of all four response columns
-            from gubernator_tpu.native.hashlib_native import unflatten_resp
-
             bounds = np.searchsorted(
                 take_idx, np.arange(1, self.n + 1) * B_sub, side="left"
             )
             counts = np.diff(np.concatenate(([0], bounds))).astype(
                 np.int64
             )
-            u = unflatten_resp(packed, order, counts, n, B_sub)
+            u = _hn.unflatten_resp(packed, order, counts, n, B_sub)
             status, rlimit, remaining, reset = u[0], u[1], u[2], u[3]
         else:
 

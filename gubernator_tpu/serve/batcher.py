@@ -68,6 +68,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from gubernator_tpu.api.types import RateLimitReq, RateLimitResp
+from gubernator_tpu.core.hashing import native_lib
 from gubernator_tpu.serve import metrics, tracing
 from gubernator_tpu.serve.aio import collect_batch
 from gubernator_tpu.serve.faults import FAULTS, FaultError
@@ -177,12 +178,9 @@ class DeviceBatcher:
         if self.fetch_depth > 1:
             # size the native prep's buffer ring to the pipeline depth
             # BEFORE any prep call (see hashlib_native._PrepBuffers)
-            try:
-                from gubernator_tpu.native import hashlib_native
-
-                hashlib_native.set_prep_generations(self.fetch_depth + 1)
-            except Exception:  # pragma: no cover - native lib optional
-                pass
+            lib = native_lib()
+            if lib is not None:
+                lib.set_prep_generations(self.fetch_depth + 1)
         # ONE dedicated submit thread (not the shared to_thread pool):
         # the native prep keeps per-thread reusable buffers and scratch
         # (hashlib_native._PrepBuffersTL, C++ thread_locals), so letting

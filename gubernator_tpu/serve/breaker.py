@@ -100,7 +100,7 @@ class CircuitBreaker:
         return self._epoch
 
     def _stale(self, token) -> bool:
-        # token None = caller predates epochs (ad-hoc/test use): treat
+        # token None = caller older than epochs (ad-hoc/test use): treat
         # as current
         return token is not None and token != self._epoch
 

@@ -221,16 +221,6 @@ class ServerConfig:
     # this many microseconds per check — lower latency on dedicated
     # cores, at the price of burning them.
     shm_poll_us: int = 0
-    # String->array fold (r7 slow-path owner batching, bridge side): a
-    # string frame whose items are ALL plain (BATCHING/NO_BATCHING,
-    # valid non-empty name/key) and ALL owned by this node skips
-    # request/response objects and instance routing, riding the same
-    # array path as pre-hashed frames. This is what keeps the
-    # GUBER_EDGE_FAST=0 kill switch (and bridge-carrying slow paths in
-    # general) near fast-path latency; GLOBAL items, validation
-    # errors, and misrouted items still take the full instance path.
-    # GUBER_EDGE_STRING_FOLD=0 restores the pre-r7 all-objects path.
-    edge_string_fold: bool = True
     # Read-side payload cap on the trusted edge->bridge door, in MiB
     # (r12 hardening): the bridge refuses a frame header advertising
     # more BEFORE buffering a byte of it. The default (256) clears the
@@ -842,8 +832,6 @@ def config_from_env(env: Optional[dict] = None) -> ServerConfig:
         not in ("0", "false", "no", "off"),
         shm_ring_kib=_get_int(env, "GUBER_SHM_RING_KIB", 1024),
         shm_poll_us=_get_int(env, "GUBER_SHM_POLL_US", 0),
-        edge_string_fold=_get(env, "GUBER_EDGE_STRING_FOLD", "1").lower()
-        not in ("0", "false", "no", "off"),
         edge_max_frame_mib=_get_int(env, "GUBER_EDGE_MAX_FRAME_MIB", 256),
         dist_coordinator=_get(env, "GUBER_DIST_COORDINATOR"),
         dist_num_processes=_get_int(env, "GUBER_DIST_NUM_PROCESSES", 1),

@@ -109,12 +109,12 @@ Frame protocol (little-endian, lengths in bytes):
       down gracefully — frames already accepted on the connection have
       been answered (the bridge waits for them BEFORE sending the
       refusal), this one was not served, and reconnecting is pointless
-      (the listener is closed). An edge that predates the code treats
+      (the listener is closed). An edge older than the code treats
       it as a stale-ring refusal: it fails the refused frame for
       re-route and finds the node gone on reconnect — degraded, not
       broken. Sent on the windowed (GEB2/GEB7) and legacy fast (GEB6)
       framings, whose readers understand GEBR; a legacy STRING frame
-      (GEB1) predates GEBR entirely and is drain-refused with a
+      (GEB1) is older than GEBR and is drain-refused with a
       well-formed GEB3 response carrying per-item "node draining"
       errors instead.
 
@@ -336,9 +336,8 @@ def _parse_string_native(payload: bytes, n: int):
     NUL-joined buffer, the columns hashlib_native.parse_string_frame's,
     and that buffer as it came (the traffic observers fold it without
     a str: core/sketches.py TrafficStats.observe).
-    None where libguberhash.so is not built, or the parser declines
-    (counted by reason): EdgeBridge._fold_by_loop then runs its
-    per-item loop over the same bytes."""
+    None where libguberhash.so is absent, or the parser declines
+    (counted by reason): the object path then answers the frame."""
     lib = native_lib()
     if lib is None:
         return None
@@ -688,7 +687,6 @@ class FrameService:
         instance,
         fast_enabled: bool = True,
         window: int = 0,
-        string_fold: bool = True,
         peer_bridges: Optional[dict] = None,
         max_payload: int = MAX_FRAME_PAYLOAD,
         shm_enabled: bool = False,
@@ -697,7 +695,6 @@ class FrameService:
     ):
         self.instance = instance
         self.fast_enabled = fast_enabled
-        self.string_fold = string_fold
         # shared-memory lane policy (r18, serve/shm.py): negotiated
         # per-connection via GEBM, advertised (HELLO_SHM) on unix
         # sockets only — the lane maps a same-host file
@@ -1019,9 +1016,9 @@ class FrameService:
     def _screen_string_frame(self, payload: bytes, n: int):
         """Lean parse + eligibility screen for the string->array fold
         (r7 slow-path owner batching, bridge side): the parse is one
-        native call (_parse_string_native) and, where the library is
-        not built or declines the payload, the per-item loop below —
-        same columns, same hashes, same declines. When EVERY item in
+        native call (_parse_string_native); a frame it does not take —
+        the library is absent, or the parser declined the payload — is
+        the object path's. When EVERY item in
         a string frame is valid (non-empty UTF-8 name/key) and owned
         by this node under the current ring, the frame needs no
         request/response objects and no instance routing: it rides
@@ -1029,7 +1026,7 @@ class FrameService:
         BATCHING / NO_BATCHING / GLOBAL it holds — on the node that
         owns its key a GLOBAL item asks for one thing beside the
         decide, the owner's status broadcast, which
-        _decide_string_folded queues (gubernator.go:240-242).
+        _decide_folded queues (gubernator.go:240-242).
         Per-owner slow shards from the edge are all-owned by
         construction, so the GUBER_EDGE_FAST=0 kill switch and mixed
         fleets get fast-path treatment minus only the client-side
@@ -1042,137 +1039,49 @@ class FrameService:
         set. `fold` — every row owned, or a one-node ring, which asks
         no key — is (full_keys, fields, glob, route_s, packed): `glob`
         is [(index, name, unique_key)] of the GLOBAL items in frame
-        order, `route_s` the seconds of the ownership screen and (in
-        the loop; the native parse hashes off the wire) the key
-        hashing — the Instance's share of the work, stamped
-        `instance_route` by the caller — and `packed` the native
-        parse's NUL-joined key bytes (None from the loop), for the
-        traffic observers. `mixed` — some rows are another node's — is
+        order, `route_s` the seconds of the ownership screen (the
+        parse hashes the keys off the wire) — the Instance's share of
+        the work, stamped `instance_route` by the caller — and
+        `packed` the parse's NUL-joined key bytes, for the traffic
+        observers. `mixed` — some rows are another node's — is
         (full_keys, cols, packed, owner, route_s) for _plan_split,
         `owner` the column. `reason` says why the frame is neither (a
-        label of edge_split_declined_total; '' where this node shares
-        its ring with nobody): the object path answers it, which keeps
-        full semantics for per-item validation errors and for whatever
-        the split does not carry.
+        label of edge_split_declined_total — `no_native` where the
+        library is absent, `invalid_item` where the parser declined;
+        '' where this node shares its ring with nobody): the object
+        path answers it, which keeps full semantics for per-item
+        validation errors and for whatever the split does not carry.
         """
         picker = getattr(self.instance, "picker", None)
-        mask_fn = getattr(picker, "self_owned_mask", None)
-        if mask_fn is None or not getattr(picker, "size", lambda: 0)():
+        if (
+            getattr(picker, "owner_column", None) is None
+            or not getattr(picker, "size", lambda: 0)()
+        ):
             return None, None, ""
         own = picker.ring()[2]
         # this node shares its ring: some point on it is another node's
         shared = not own.all()
         parsed = _parse_string_native(payload, n)
-        if parsed is not None:
-            full, cols, packed = parsed
-            t0 = time.monotonic()
-            owner = None
-            if shared:
-                owner = picker.owner_column(full, packed)
-                if own[owner].all():
-                    owner = None
-            route_s = time.monotonic() - t0
-            if owner is not None:
-                return None, (full, cols, packed, owner, route_s), ""
-            fields = {k: cols[k] for k in DECIDE_FIELDS}
-            return (
-                full, fields, global_rows(payload, cols), route_s, packed
-            ), None, ""
-        # a frame the native parse did not take is never split: by the
-        # loop below it folds where every row is owned, else it is the
-        # object path's
-        fold = self._fold_by_loop(payload, n, mask_fn)
-        if fold is not None or not shared:
-            return fold, None, ""
-        lib = native_lib()
-        return None, None, (
-            "invalid_item"
-            if lib is not None and getattr(lib, "_HAS_STRING_FRAME", False)
-            else "no_native"
-        )
-
-    def _fold_by_loop(self, payload: bytes, n: int, mask_fn):
-        """_screen_string_frame's parse and ownership screen item by
-        item: the fold's 5-tuple, or None."""
-        import numpy as np
-
-        from gubernator_tpu.core.hashing import slot_hash_batch
-
-        # the library is not built, or it declined the payload: the
-        # per-item loop below is the same parse (and the oracle the
-        # native one is tested against)
-
-        # the wire count is untrusted: bound it by the payload's
-        # minimum bytes/item (2+2 length prefixes + 26 fixed) before
-        # sizing arrays from it, like _decide_fast's exact-length check
-        if n > len(payload) // 30:
-            return None
-        full: List[str] = []
-        glob: List[tuple] = []
-        hits = np.empty(n, np.int64)
-        limit = np.empty(n, np.int64)
-        duration = np.empty(n, np.int64)
-        algo = np.empty(n, np.int64)
-        off = 0
-        route_s = 0.0
-        # ownership is screened in chunks DURING the parse: a mixed-
-        # ownership frame (pre-r7 edge funnelling a cluster's items
-        # through one node) is near-certain to fail within its first
-        # chunk, so it pays ~256 items of lean parse before falling
-        # back to the object path instead of a full parse + re-parse
-        checked = 0
-        try:
-            for i in range(n):
-                if i - checked >= 256:
-                    t0 = time.monotonic()
-                    if not mask_fn(full[checked:]).all():
-                        return None
-                    route_s += time.monotonic() - t0
-                    checked = i
-                (nlen,) = struct.unpack_from("<H", payload, off)
-                off += 2
-                raw_name = payload[off : off + nlen]
-                off += nlen
-                (klen,) = struct.unpack_from("<H", payload, off)
-                off += 2
-                raw_key = payload[off : off + klen]
-                off += klen
-                h, li, d, a, b = _ITEM_FIX.unpack_from(payload, off)
-                off += _ITEM_FIX.size
-                if (
-                    len(raw_name) != nlen
-                    or len(raw_key) != klen
-                    or not raw_name
-                    or not raw_key
-                ):
-                    return None  # truncated/invalid: object path
-                name = raw_name.decode()
-                key = raw_key.decode()
-                full.append(name + "_" + key)
-                if b == 2:
-                    glob.append((i, name, key))
-                hits[i] = h
-                limit[i] = li
-                duration[i] = d
-                algo[i] = a
-        except (struct.error, UnicodeDecodeError):
-            return None  # malformed frame: the object path answers it
-        if off != len(payload):
-            return None
+        if parsed is None:
+            if not shared:
+                return None, None, ""
+            return None, None, (
+                "no_native" if native_lib() is None else "invalid_item"
+            )
+        full, cols, packed = parsed
         t0 = time.monotonic()
-        if full[checked:] and not mask_fn(full[checked:]).all():
-            return None
-        fields = dict(
-            key_hash=slot_hash_batch(full),
-            hits=hits,
-            limit=limit,
-            duration=duration,
-            # unknown algorithm bytes clamp to the default, matching
-            # decode_request_frame and the JSON gateway
-            algo=np.where(algo <= 3, algo, 0).astype(np.int32),
-        )
-        route_s += time.monotonic() - t0
-        return full, fields, glob, route_s, None
+        owner = None
+        if shared:
+            owner = picker.owner_column(full, packed)
+            if own[owner].all():
+                owner = None
+        route_s = time.monotonic() - t0
+        if owner is not None:
+            return None, (full, cols, packed, owner, route_s), ""
+        fields = {k: cols[k] for k in DECIDE_FIELDS}
+        return (
+            full, fields, global_rows(payload, cols), route_s, packed
+        ), None, ""
 
     def _note_frame(self, full, fields, glob, packed, mine=None) -> None:
         """What the owner branch of Instance.get_rate_limits does item
@@ -1216,7 +1125,7 @@ class FrameService:
             metrics.EDGE_FOLDED_GLOBAL_ITEMS.inc(len(glob))
             inst.global_mgr.queue_update_fields(full, glob, fields)
 
-    async def _decide_string_folded(
+    async def _decide_folded(
         self, full, fields, glob, route_s: float, n: int, packed=None
     ) -> bytes:
         """Array-decide one folded string frame and encode the GEB3/
@@ -1454,7 +1363,7 @@ class FrameService:
         fold = mixed = plan = None
         reason = ""
         if n:
-            if self.string_fold and self._arrays_ok():
+            if self._arrays_ok():
                 fold, mixed, reason = self._screen_string_frame(payload, n)
             elif self._shares_ring():
                 reason = "no_arrays"
@@ -1479,7 +1388,7 @@ class FrameService:
             metrics.EDGE_FOLDED_ITEMS.inc(n)
             # the lean parse alone: the ownership screen and the key
             # hashing are stamped with the rest of the frame's routing
-            # work (_decide_string_folded, _plan_split)
+            # work (_decide_folded, _plan_split)
             route_s = fold[3] if fold is not None else mixed[-1]
             STAGES.add("bridge_decode", t_screened - t_dec - route_s)
             hdr = _HDR.pack(magic, n)
@@ -1487,7 +1396,7 @@ class FrameService:
                 hdr += struct.pack("<I", frame_id)
         if fold is not None:
             full, fields, glob, route_s, packed = fold
-            return hdr + await self._decide_string_folded(
+            return hdr + await self._decide_folded(
                 full, fields, glob, route_s, n, packed
             )
         if plan is not None:
@@ -1835,7 +1744,7 @@ class FrameService:
                     bound_payload_len(plen, self.max_payload)
                 )
                 if self._draining:
-                    # the GEB1 string reader predates GEBR entirely (a
+                    # the GEB1 string reader is older than GEBR (a
                     # stale magic is a hard protocol failure there), so
                     # drain-refuse with a well-formed GEB3 response
                     # carrying per-item errors — degraded, in-protocol.
@@ -1954,7 +1863,7 @@ class FrameService:
             )
         if self._draining:
             if magic == MAGIC_REQ:
-                # GEB1 predates GEBR: refuse in-protocol (socket
+                # GEB1 is older than GEBR: refuse in-protocol (socket
                 # parity). The wire count is untrusted and this branch
                 # allocates n responses, so bound it by the payload's
                 # minimum bytes/item (30) BEFORE building anything —
@@ -2034,7 +1943,6 @@ class EdgeBridge(FrameService):
         peer_bridges: Optional[dict] = None,
         fast_enabled: bool = True,
         window: int = 0,
-        string_fold: bool = True,
         max_payload: int = EDGE_MAX_FRAME_PAYLOAD,
         shm_enabled: bool = False,
         shm_ring_kib: int = 0,
@@ -2044,7 +1952,6 @@ class EdgeBridge(FrameService):
             instance,
             fast_enabled=fast_enabled,
             window=window,
-            string_fold=string_fold,
             peer_bridges=peer_bridges,
             max_payload=max_payload,
             shm_enabled=shm_enabled,
@@ -2106,7 +2013,6 @@ class GebListener(FrameService):
         address: str,
         fast_enabled: bool = True,
         window: int = 0,
-        string_fold: bool = True,
         peer_bridges: Optional[dict] = None,
     ):
         # peer_bridges (r18, GUBER_GEB_PEER_DOORS): explicit
@@ -2117,7 +2023,6 @@ class GebListener(FrameService):
             instance,
             fast_enabled=fast_enabled,
             window=window,
-            string_fold=string_fold,
             peer_bridges=peer_bridges,
         )
         reject_ipv6_endpoint(address, "GUBER_GEB_PORT listener")

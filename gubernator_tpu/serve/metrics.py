@@ -102,8 +102,8 @@ MESH_NUMPY_STACKS = Gauge(
     "mesh_numpy_stacks_total",
     "Mesh backend: merged device batches laid out per shard in numpy "
     "on the submit thread (parallel/sharded.py "
-    "build_presorted_sharded): every one where libguberhash.so is not "
-    "built or predates the sharded merge, a batch past the sub-rung "
+    "build_presorted_sharded): every one where libguberhash.so is "
+    "absent, a batch past the sub-rung "
     "ladder's top, a lockstep follower's; none otherwise",
     registry=REGISTRY,
 )
@@ -161,9 +161,8 @@ EDGE_STRING_NATIVE_DECLINED = Counter(
     "edge_string_native_declined_total",
     "String frames the native parser declined, by reason "
     "(hashlib_native.STRING_DECLINE: too_many_items, truncated, "
-    "empty_name_or_key, bad_utf8, trailing_bytes, nul_byte, "
-    "stale_library); the Python loop then parses the same bytes and "
-    "what it declines too is served by the object path",
+    "empty_name_or_key, bad_utf8, trailing_bytes, nul_byte); the "
+    "object path then answers the frame",
     ["reason"],
     registry=REGISTRY,
 )
@@ -375,7 +374,7 @@ TRAFFIC_PYTHON_FOLDS = Gauge(
     "traffic_python_folds_total",
     "Batches the same observers folded in Python on the serving loop "
     "(core/sketches.py SpaceSaving + HyperLogLog): every one where "
-    "libguberhash.so is not built or predates the fold, none otherwise",
+    "libguberhash.so is absent, none otherwise",
     registry=REGISTRY,
 )
 FAULTS_INJECTED = Counter(

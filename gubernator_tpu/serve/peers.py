@@ -80,8 +80,8 @@ SPLIT_DECLINE_REASONS = (
     "foreign_no_batching",  # a NO_BATCHING one (its own unary RPC)
     "rescale_transition",  # an open double-serve window reroutes items
     "too_many_items",  # a frame past the per-RPC cap
-    "no_arrays",  # a backend that takes no arrays, or the fold off
-    "no_native",  # libguberhash.so not built, or without the symbols
+    "no_arrays",  # a backend that takes no arrays
+    "no_native",  # libguberhash.so is absent (core/hashing.native_lib)
     "error",  # the split raised before a row was sent anywhere
 )
 
@@ -151,8 +151,8 @@ def is_retryable(exc: BaseException, all_peek: bool = False) -> bool:
 class _WireReply:
     """One GetPeerRateLimits reply taken as BYTES (a flusher batch's
     RPC): four answer columns by one native parse, or, where
-    the parser declines (an item with an error or metadata, odd wire, a
-    library without the symbol), the protobuf runtime's items. Raises
+    the parser declines (an item with an error or metadata, odd wire)
+    or the library is absent, the protobuf runtime's items. Raises
     what the message path raises: a reply that is not the message, or
     one of another length than the batch."""
 
@@ -160,10 +160,9 @@ class _WireReply:
 
     def __init__(self, wire: bytes, n: int):
         lib = native_lib()
-        got, self.cols = (
-            lib.parse_peer_answers(wire, n) if lib is not None
-            else (-7, None)
-        )
+        got, self.cols = -1, None
+        if lib is not None:
+            got, self.cols = lib.parse_peer_answers(wire, n)
         self.items = None
         if got < 0:
             self.items = peers_pb2.GetPeerRateLimitsResp.FromString(
@@ -879,20 +878,3 @@ class ConsistentHashPicker:
         idx = np.searchsorted(points, pts, side="left")
         idx[idx == len(points)] = 0
         return idx.astype(np.int32)
-
-    def self_owned_mask(self, keys: Sequence[str], packed=None):
-        """bool[len(keys)]: the key's ring successor is this server
-        itself (is_owner). Vectorized ownership screen for the edge
-        bridge's string->array fold (r7): owner_column's positions
-        looked up in the ring's is_owner column, instead of a get()
-        with its dict lookups per key."""
-        import numpy as np
-
-        if not self._keys:
-            raise RuntimeError("unable to pick a peer; pool is empty")
-        own = self.ring()[2]
-        if own.all():
-            # every ring point is this node (a single-node ring):
-            # whatever a key hashes to, its successor is this node
-            return np.ones(len(keys), dtype=bool)
-        return own[self.owner_column(keys, packed)]

@@ -17,9 +17,9 @@ runs arrive in caller order — which is what makes the merged device
 fields byte-identical to the flush-time concat+argsort path
 (tests/test_prep_pipeline.py pins this).
 
-Pure numpy (plus the optional native lib) on purpose: importing this
-module never pulls jax. merge_runs dispatches to the fused native
-merge (guber_merge_runs, one GIL-free pass) when the library is built.
+Pure numpy; merge_runs dispatches to the fused native merge
+(guber_merge_runs, one GIL-free pass) where the library loaded
+(core/hashing.native_lib).
 
 Who calls it since PR 44: the engines' `merge_prepped` take ONE native
 call for the whole merge where the library has it — flat
@@ -40,19 +40,16 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from gubernator_tpu.core.hashing import native_lib
+
 #: field order of a prepped run's `fields` dict — matches
 #: backends._ArrayOps.ARRAY_FIELDS
 RUN_FIELDS = ("key_hash", "hits", "limit", "duration", "algo", "gnp")
 
-try:  # fused native merge (guberhash.cc guber_merge_runs): one GIL-free
-    # pass instead of ~30 small numpy ops — under a contended host the
-    # numpy form's wall time amplifies ~10x from GIL preemption alone
-    from gubernator_tpu.native import hashlib_native as _hn
-
-    if not getattr(_hn, "_HAS_MERGE", False):
-        raise AttributeError("guber_merge_runs missing")
-except (ImportError, AttributeError, OSError):  # pragma: no cover
-    _hn = None
+# fused native merge (guberhash.cc guber_merge_runs): one GIL-free pass
+# instead of ~30 small numpy ops — under a contended host the numpy
+# form's wall time amplifies ~10x from GIL preemption alone
+_hn = native_lib()
 
 
 def _merge2(

@@ -25,6 +25,7 @@ from google.protobuf.message import DecodeError
 from gubernator_tpu.api import convert
 from gubernator_tpu.api.grpc_glue import add_peers_servicer, add_v1_servicer
 from gubernator_tpu.api.proto.gen import gubernator_pb2, peers_pb2
+from gubernator_tpu.core.hashing import native_lib
 from gubernator_tpu.serve import metrics, tracing
 from gubernator_tpu.serve.backends import (
     ExactBackend,
@@ -497,21 +498,15 @@ class Server:
         """What this daemon serves from, for the boot log and
         /v1/debug/stages: the devices as JAX reports them (None on the
         exact backend, which touches no device) and which host-side
-        implementations — fused native prep, slot hasher — loaded."""
-        from gubernator_tpu.core.hashing import using_native_hash
-
-        try:
-            from gubernator_tpu.native import hashlib_native as _hn
-
-            has_prep = getattr(_hn, "_HAS_PREP", False)
-        except (ImportError, AttributeError, OSError):
-            # a missing or unloadable .so must not abort startup: the
-            # numpy twins serve, and this report is what says so
-            has_prep = False
+        implementations — fused native prep, slot hasher — serve:
+        both follow the ONE fact core/hashing.native_lib() holds (a
+        missing, unloadable or stale .so never aborts startup: it is
+        absent, and native_lib's one warning says why)."""
+        native = native_lib() is not None
         return {
             "device": self._describe_device(),
-            "host_prep": "native" if has_prep else "numpy",
-            "hasher": "native" if using_native_hash() else "python",
+            "host_prep": "native" if native else "numpy",
+            "hasher": "native" if native else "python",
         }
 
     def _mesh_engine(self):
@@ -589,8 +584,6 @@ class Server:
                 ),
             )
         if dev["host_prep"] == "native":
-            from gubernator_tpu.native import hashlib_native as _hn
-
             engine = getattr(self.backend, "engine", None)
             forms = (
                 engine.writeback_forms()
@@ -599,7 +592,7 @@ class Server:
             log.info(
                 "native prep: %d thread(s) (GUBER_PREP_THREADS), "
                 "writeback=%s (GUBER_WRITEBACK), form by rung: %s",
-                _hn.prep_threads(),
+                native_lib().prep_threads(),
                 os.environ.get("GUBER_WRITEBACK", "auto"),
                 " ".join(
                     "%d:%s" % (b, "/".join(f)) for b, f in forms.items()
@@ -607,15 +600,15 @@ class Server:
             )
         else:
             log.info(
-                "native prep library not built/loadable; numpy "
-                "fallbacks active"
+                "native prep: libguberhash.so is absent (the warning "
+                "above says why); numpy fallbacks active"
             )
         log.info(
             "slot hasher: %s",
             "native XXH64 (libguberhash.so)"
             if dev["hasher"] == "native"
-            else "pure-Python blake2b fallback (make -C "
-            "gubernator_tpu/native builds the native one)",
+            else "pure-Python blake2b fallback (libguberhash.so is "
+            "absent: make -C gubernator_tpu/native)",
         )
         log.info(
             "traffic observers: %s",
@@ -623,8 +616,8 @@ class Server:
             "guber_traffic_fold; traffic_native_folds_total)"
             if self.instance.traffic.implementation == "native"
             else "Python SpaceSaving + HyperLogLog on the serving loop "
-            "(libguberhash.so not built, or built before the fold: make "
-            "-C gubernator_tpu/native; traffic_python_folds_total)",
+            "(libguberhash.so is absent: make -C gubernator_tpu/native; "
+            "traffic_python_folds_total)",
         )
 
         engine = self._mesh_engine()
@@ -637,9 +630,8 @@ class Server:
                 if engine.stack_implementation == "native"
                 else "laid out per shard in numpy on the submit thread "
                 "(a multi-host mesh, whose merged batch crosses flat; "
-                "else libguberhash.so is not built, or was built before "
-                "the sharded merge: make -C gubernator_tpu/native; "
-                "mesh_numpy_stacks_total)",
+                "else libguberhash.so is absent: make -C "
+                "gubernator_tpu/native; mesh_numpy_stacks_total)",
             )
 
         shed = self.instance.shed
@@ -743,7 +735,6 @@ class Server:
                 f"0.0.0.0:{self.conf.geb_port}",
                 fast_enabled=self.conf.edge_fast,
                 window=self.conf.geb_window or self.conf.edge_window,
-                string_fold=self.conf.edge_string_fold,
                 peer_bridges=geb_peer_doors or None,
             )
             await self._geb.start()
@@ -775,7 +766,6 @@ class Server:
                 peer_bridges=peer_bridges,
                 fast_enabled=self.conf.edge_fast,
                 window=self.conf.edge_window,
-                string_fold=self.conf.edge_string_fold,
                 max_payload=self.conf.edge_max_frame_mib << 20,
                 shm_enabled=self.conf.shm,
                 shm_ring_kib=self.conf.shm_ring_kib,
@@ -1055,7 +1045,6 @@ class Server:
                 self.instance,
                 fast_enabled=self.conf.edge_fast,
                 window=self.conf.geb_window or self.conf.edge_window,
-                string_fold=self.conf.edge_string_fold,
             )
         return self._geb_core
 

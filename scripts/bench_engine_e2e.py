@@ -68,7 +68,7 @@ def main():
     # exactly the per-batch work decide_submit/decide_wait do around the
     # device call (native marshalling when built)
     from gubernator_tpu.core.engine import (
-        _marshal,
+        _hn,
         pad_request_sorted,
         unpermute_responses,
     )
@@ -83,8 +83,8 @@ def main():
             (B,), eng.config.slots, key_hash[i % N_BATCHES], hits, limit,
             duration, (zipf[i % N_BATCHES] % 2).astype(np.int32), gnp,
         )
-        if _marshal is not None:
-            _marshal.unpermute_i32(
+        if _hn is not None:
+            _hn.unpermute_i32(
                 fake_packed[: 4 * B].reshape(4, B), order, B
             )
         else:

@@ -185,56 +185,19 @@ def spawn_daemon_edge(
     pytest.fail("edge never started listening")
 
 
-_native_for_tests = None
+def native_lib_for_tests():
+    """The package's own native module — the one handle,
+    core/hashing.native_lib() — or skip with the import's reason.
+    tests/conftest.py builds libguberhash.so before collection, so the
+    skip is a box without a compiler."""
+    import pytest
 
+    from gubernator_tpu.core.hashing import native_lib
 
-def native_lib_for_tests(tmp_dir):
-    """gubernator_tpu.native.hashlib_native over a libguberhash.so
-    that has the PeersV1 wire fold, the GEB string-frame parse, the
-    traffic observers' fold and the GEB door's split by owner: the checkout's own where it is built and
-    current, else one compiled from guberhash.cc into `tmp_dir` (once
-    a process: the files that ask share it) and loaded from there
-    under a private module name — a test never drops a .so into the
-    checkout other tests run from (tests/test_chip_smoke.py does the
-    same with a copy)."""
-    global _native_for_tests
-    import importlib.util
-    import shutil
-    import subprocess
-
-    if _native_for_tests is not None:
-        return _native_for_tests
-    native = (
-        pathlib.Path(__file__).resolve().parent.parent
-        / "gubernator_tpu" / "native"
-    )
-    try:
-        from gubernator_tpu.native import hashlib_native
-
-        if all(
-            getattr(hashlib_native, has, False)
-            for has in (
-                "_HAS_PEER_WIRE", "_HAS_STRING_FRAME", "_HAS_TRAFFIC_FOLD",
-                "_HAS_SPLIT",
-            )
-        ):
-            _native_for_tests = hashlib_native
-            return hashlib_native
-    except ImportError:
-        pass
-    tmp_dir = pathlib.Path(tmp_dir)
-    for name in ("guberhash.cc", "Makefile", "hashlib_native.py"):
-        shutil.copy(native / name, tmp_dir)
-    subprocess.run(
-        ["make", "-C", str(tmp_dir)], check=True, capture_output=True
-    )
-    spec = importlib.util.spec_from_file_location(
-        "_hashlib_native_for_tests", tmp_dir / "hashlib_native.py"
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    _native_for_tests = mod
-    return mod
+    pytest.importorskip("gubernator_tpu.native.hashlib_native")
+    lib = native_lib()
+    assert lib is not None, "the library appeared after this process asked"
+    return lib
 
 
 class WireDoor:

@@ -26,6 +26,8 @@ import os
 import pathlib
 import subprocess
 
+import pytest
+
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
     + " --xla_force_host_platform_device_count=8"
@@ -37,7 +39,7 @@ def _build_native_library() -> None:
     """`make -C gubernator_tpu/native`, once, in the process that
     starts the session (an xdist worker finds it built). A box with no
     compiler keeps what it had: the tests that need the library skip
-    or build a private copy as before (tests/_util.py)."""
+    (tests/_util.py native_lib_for_tests)."""
     if os.environ.get("PYTEST_XDIST_WORKER"):
         return
     native = (
@@ -53,3 +55,13 @@ def _build_native_library() -> None:
 
 
 _build_native_library()
+
+
+@pytest.fixture(scope="session")
+def native():
+    """The native library this process serves with
+    (core/hashing.native_lib, the one handle), or skip where it is
+    absent: for the tests of what only the library does."""
+    from _util import native_lib_for_tests
+
+    return native_lib_for_tests()

@@ -589,12 +589,12 @@ def test_fast_kill_switch_unadvertises():
     assert flags & HELLO_WINDOWED  # windowed framing is fast-agnostic
 
 
-def _fold_fixture(is_owner: bool, string_fold: bool = True,
-                  fast_enabled: bool = True, shed=None):
+def _fold_fixture(is_owner: bool, fast_enabled: bool = True, shed=None,
+                  peers: int = 1):
     """Bridge over a real ConsistentHashPicker (one peer) and a real
     GlobalManager (never started: what the door queues stays in
     `_updates`) whose batcher and instance record which path served
-    each frame."""
+    each frame. `peers` > 1 puts that many other nodes on the ring."""
     import numpy as np
 
     from gubernator_tpu.serve.config import BehaviorConfig
@@ -639,9 +639,9 @@ def _fold_fixture(is_owner: bool, string_fold: bool = True,
     inst.shed = shed
     inst.global_mgr = GlobalManager(BehaviorConfig(), inst)
     inst.picker.add(FakePeer("127.0.0.1:81", is_owner=is_owner))
-    bridge = EdgeBridge(
-        inst, "", fast_enabled=fast_enabled, string_fold=string_fold
-    )
+    for i in range(1, peers):
+        inst.picker.add(FakePeer(f"127.0.0.{i + 1}:81"))
+    bridge = EdgeBridge(inst, "", fast_enabled=fast_enabled)
     return bridge, folded_sizes, object_path_keys
 
 
@@ -678,7 +678,7 @@ def _roundtrip_string_frame(bridge, items, sock_name):
     return asyncio.run(run())
 
 
-def test_string_fold_serves_plain_owned_frame_via_arrays():
+def test_string_frame_fold_serves_plain_owned_frame_via_arrays(native):
     """An all-plain all-owned GEB1 frame must skip the instance and
     ride the array path (r7 string->array fold), producing wire bytes
     identical in layout to the object path: 25-byte decisions with
@@ -707,7 +707,7 @@ def _folded_global_items() -> float:
 
 @pytest.mark.parametrize("shed_answers", [False, True],
                          ids=["device-decided", "shed-answered"])
-def test_string_fold_serves_owned_global_items(shed_answers):
+def test_string_frame_fold_serves_owned_global_items(native, shed_answers):
     """Owned plain + GLOBAL items in one frame fold as ONE array group
     with no Instance call, and every GLOBAL item queues its key's
     status broadcast before the decide — once per distinct key with
@@ -772,7 +772,7 @@ def test_string_fold_serves_owned_global_items(shed_answers):
     ids=["unowned-global", "unowned-plain", "empty-name", "bad-utf8",
          "truncated"],
 )
-def test_string_fold_declines_invalid_and_unowned_frames(
+def test_string_frame_fold_declines_invalid_and_unowned_frames(
     is_owner, items, answered
 ):
     """Any key this node does not own (a non-owner's GLOBAL item needs
@@ -823,12 +823,16 @@ def _seeded_mixed_frame(seed: int, n: int = 1000):
     return b"".join(items), n
 
 
-def test_seeded_mixed_frame_folds_byte_identical_to_object_path(monkeypatch):
+def test_seeded_mixed_frame_folds_byte_identical_to_object_path(
+    native, monkeypatch
+):
     """The fold's answers are the object path's, byte for byte: two
     fresh nodes on a standing clock serve the same two seeded 1000-item
     mixed frames (the first drives keys over their limit and fills the
-    shed cache), one through the fold and one with string_fold=False,
-    and leave the same broadcasts queued."""
+    shed cache), one through the fold and one through the object path
+    (`_decide_string` + `encode_response_frame`, where the door sends
+    whatever the fold declines), and leave the same broadcasts
+    queued."""
     from gubernator_tpu.core.store import StoreConfig
     from gubernator_tpu.serve.backends import TpuBackend
     from gubernator_tpu.serve.config import ServerConfig
@@ -847,7 +851,7 @@ def test_seeded_mixed_frame_folds_byte_identical_to_object_path(monkeypatch):
     addr = "127.0.0.1:9981"
     frames = [_seeded_mixed_frame(27), _seeded_mixed_frame(2027)]
 
-    async def serve(string_fold: bool):
+    async def serve(fold: bool):
         conf = ServerConfig(
             grpc_address=addr, advertise_address=addr, shed_cache=True
         )
@@ -870,12 +874,16 @@ def test_seeded_mixed_frame_folds_byte_identical_to_object_path(monkeypatch):
             return await served(reqs, stage_frame=stage_frame)
 
         inst.get_rate_limits = counting
-        bridge = EdgeBridge(inst, "", string_fold=string_fold)
+        bridge = EdgeBridge(inst, "")
+
+        async def by_objects(payload, n):
+            return encode_response_frame(
+                await bridge._decide_string(payload, n)
+            )
+
+        decide = bridge._decide_string_frame if fold else by_objects
         try:
-            out = [
-                await bridge._decide_string_frame(payload, n)
-                for payload, n in frames
-            ]
+            out = [await decide(payload, n) for payload, n in frames]
             return out, calls, dict(inst.global_mgr._updates), inst.shed.hits
         finally:
             await inst.stop()
@@ -915,35 +923,68 @@ def test_seeded_mixed_frame_folds_byte_identical_to_object_path(monkeypatch):
     }
 
 
-def test_string_fold_kill_switch():
-    """GUBER_EDGE_STRING_FOLD=0 (string_fold=False) must restore the
-    pre-r7 all-objects string path even for foldable frames."""
+def _object_items() -> float:
+    from gubernator_tpu.serve.metrics import REGISTRY
+
+    return REGISTRY.get_sample_value("edge_object_items_total") or 0.0
+
+
+@pytest.mark.parametrize(
+    "peers,reason", [(1, None), (3, "no_native")],
+    ids=["one-node-ring", "shared-ring"],
+)
+def test_string_frame_without_the_library_is_the_object_paths(
+    monkeypatch, peers, reason
+):
+    """Where native_lib() is None nothing parses a string frame into
+    arrays: the object path answers every one (no second parser in
+    Python), its items counted edge_object_items_total — and on a ring
+    this node shares, edge_split_declined_total{reason="no_native"}."""
+    from gubernator_tpu.serve import edge_bridge
+
+    monkeypatch.setattr(edge_bridge, "native_lib", lambda: None)
     bridge, folded_sizes, object_path_keys = _fold_fixture(
-        is_owner=True, string_fold=False
+        is_owner=True, peers=peers
     )
-    _roundtrip_string_frame(bridge, [_item(b"api", b"k1")], "fold-off")
+    items = [_item(b"api", b"k1"), _item(b"api", b"k2", behavior=2)]
+    payload = b"".join(items)
+    assert bridge._screen_string_frame(payload, 2) == (
+        None, None, reason or ""
+    )
+    objects = _object_items()
+    out = _roundtrip_string_frame(bridge, items, f"no-native-{peers}")
+    assert _object_items() - objects == 2
     assert folded_sizes == []
-    assert object_path_keys == ["k1"]
+    assert object_path_keys == ["k1", "k2"]
+    assert [o[:4] for o in out] == [(0, 5, 4, 77)] * 2
+    declined = bridge.instance.edge_split.declined
+    assert {r: c for r, c in declined.items() if c} == (
+        {reason: 1} if reason else {}
+    )
+    assert bridge.instance.global_mgr._updates == {}
 
 
-def test_picker_self_owned_mask_matches_get():
-    """self_owned_mask (the fold's vectorized ownership screen) must
-    agree with get() — the authoritative per-key placement — across a
-    multi-peer ring."""
+def test_picker_owner_column_matches_get():
+    """owner_column under the ring's is_owner column (the fold's
+    vectorized ownership screen) must agree with get() — the
+    authoritative per-key placement — across a multi-peer ring."""
     from gubernator_tpu.serve.peers import ConsistentHashPicker
+
+    def owned(picker, keys):
+        return picker.ring()[2][picker.owner_column(keys)]
 
     picker = ConsistentHashPicker()
     picker.add(FakePeer("10.0.0.1:81", is_owner=True))
     picker.add(FakePeer("10.0.0.2:81"))
     picker.add(FakePeer("10.0.0.3:81"))
     keys = [f"api_k{i}" for i in range(500)]
-    mask = picker.self_owned_mask(keys)
+    mask = owned(picker, keys)
     assert mask.any() and not mask.all()  # 500 keys spread over 3 peers
-    for k, owned in zip(keys, mask):
-        assert picker.get(k).is_owner == bool(owned)
-    # a ring whose every point is this node owns every key unhashed;
-    # one whose only point is another node owns none
+    for k, own in zip(keys, mask):
+        assert picker.get(k).is_owner == bool(own)
+    # a ring whose only point is this node owns every key; one whose
+    # only point is another node owns none
     for is_owner in (True, False):
         alone = ConsistentHashPicker()
         alone.add(FakePeer("10.0.0.1:81", is_owner=is_owner))
-        assert alone.self_owned_mask(keys).tolist() == [is_owner] * 500
+        assert owned(alone, keys).tolist() == [is_owner] * 500

@@ -303,13 +303,14 @@ def test_counters_on_one_crafted_frame(node):
 def test_merged_batches_count_who_stacked_them(node, monkeypatch):
     """PR 44: every merged device batch of the mesh is laid out per
     shard by the native merge in its one call (`mesh_native_stacks_total`
-    = batches, `mesh_numpy_stacks_total` stands still); with the symbol
-    hidden numpy lays them out and the counters trade places — the
-    answers are the same frame for frame, and equal the reference."""
-    from gubernator_tpu.native import hashlib_native
+    = batches, `mesh_numpy_stacks_total` stands still); with the
+    library hidden from the engine's module numpy lays them out and the
+    counters trade places — the answers are the same frame for frame,
+    and equal the reference."""
+    import gubernator_tpu.parallel.sharded as sharded_mod
 
-    if not hashlib_native._HAS_MERGE_SHARDED:
-        pytest.skip("libguberhash.so predates guber_merge_runs_sharded")
+    if sharded_mod._hn is None:
+        pytest.skip("libguberhash.so is absent")
     cluster, addr = node
     server = cluster.servers[0]
     engine = server.instance.backend.engine
@@ -345,7 +346,7 @@ def test_merged_batches_count_who_stacked_them(node, monkeypatch):
     with_library, (native, numpy_, stacks) = serve("stacked-native")
     assert stacks >= 12 and (native, numpy_) == (stacks, 0)
 
-    monkeypatch.setattr(hashlib_native, "_HAS_MERGE_SHARDED", False)
+    monkeypatch.setattr(sharded_mod, "_hn", None)
     assert engine.stack_implementation == "numpy"
     hidden, (native, numpy_, stacks) = serve("stacked-numpy")
     assert stacks >= 12 and (native, numpy_) == (0, stacks)

@@ -17,11 +17,9 @@ the object path it replaces.
   counters advance on a folded call; `peer_serve_folded_items_total`
   stays put on a declined one, which is still answered.
 
-libguberhash.so is git-ignored, so the driver's checkout has none and
-every OTHER test's peer calls take the object path; this file builds
-the library out of tree (`_util.native_lib_for_tests`) and lends it to
-the process's hashing singleton for its own duration. The clock stands
-still, as in tests/test_ring_owner6.py.
+libguberhash.so is git-ignored: tests/conftest.py builds it before
+collection, and its `native` fixture skips where it is absent. The
+clock stands still, as in tests/test_ring_owner6.py.
 """
 
 import asyncio
@@ -33,7 +31,7 @@ import grpc
 import numpy as np
 import pytest
 
-from _util import free_ports, native_lib_for_tests
+from _util import free_ports
 from gubernator_tpu.api.columns import PeerAnswers, PeerBatch
 from gubernator_tpu.api.grpc_glue import PEERS_SERVICE
 from gubernator_tpu.api.proto.gen import gubernator_pb2, peers_pb2
@@ -57,19 +55,6 @@ COUNTERS = ("peer_serve_batches_total", "peer_serve_items_total",
             "peer_serve_shed_hits_total", "peer_serve_folded_items_total")
 METHOD = f"/{PEERS_SERVICE}/GetPeerRateLimits"
 U64 = (1 << 64) - 1
-
-
-@pytest.fixture(scope="module")
-def native(tmp_path_factory):
-    """The native library, lent to core.hashing for this file: the
-    fold takes it from there, and every key this file's nodes hash is
-    hashed by it."""
-    lib = native_lib_for_tests(tmp_path_factory.mktemp("native"))
-    mp = pytest.MonkeyPatch()
-    mp.setattr(hashing, "_native", lib)
-    mp.setattr(hashing, "_native_checked", True)
-    yield lib
-    mp.undo()
 
 
 # -- hand encoding: what the runtime's own serialiser never sends -----------

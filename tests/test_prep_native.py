@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from gubernator_tpu.core.engine import dense_ladder_extension
+from gubernator_tpu.core.hashing import native_lib
 from gubernator_tpu.core.store import (
     COUNTER_MAX,
     MAX_DURATION_MS,
@@ -25,12 +26,10 @@ from gubernator_tpu.core.store import (
 )
 import gubernator_tpu.parallel.sharded as sh
 
-hn = pytest.importorskip(
-    "gubernator_tpu.native.hashlib_native", reason="native lib not built"
-)
-if not getattr(hn, "_HAS_PREP", False):
+hn = native_lib()
+if hn is None:
     pytest.skip(
-        "libguberhash.so predates guber_prep_sharded",
+        "libguberhash.so is absent (make -C gubernator_tpu/native)",
         allow_module_level=True,
     )
 
@@ -158,6 +157,7 @@ def test_prep_thread_pool_bit_identity(threads):
 import numpy as np, sys
 from gubernator_tpu.native import hashlib_native as hn
 from gubernator_tpu.core.engine import dense_ladder_extension
+from gubernator_tpu.core.hashing import native_lib
 from gubernator_tpu.core.store import COUNTER_MAX, MAX_DURATION_MS, TIME_FLOOR
 import gubernator_tpu.parallel.sharded as sh
 rng = np.random.default_rng(99)
@@ -271,6 +271,7 @@ import os, sys
 import numpy as np
 from gubernator_tpu.native import hashlib_native as hn
 from gubernator_tpu.core.engine import dense_ladder_extension
+from gubernator_tpu.core.hashing import native_lib
 from gubernator_tpu.core.store import COUNTER_MAX, MAX_DURATION_MS, TIME_FLOOR
 import gubernator_tpu.parallel.sharded as sh
 rng = np.random.default_rng(5)

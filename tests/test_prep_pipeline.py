@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from gubernator_tpu.api.types import Algorithm, RateLimitReq
-from gubernator_tpu.core.engine import _hn as _native  # None: not built
+from gubernator_tpu.core.hashing import native_lib
 from gubernator_tpu.core.engine import (
     build_presorted_request,
     pad_request_sorted,
@@ -246,8 +246,7 @@ def _numpy_twin(runs, n_shards, sub=SUB):
 def _assert_native_stack_is_the_twin(groups, n_shards, sub=SUB):
     """guber_merge_runs_sharded == the numpy twin, byte for byte in
     every output; returns the native call's result."""
-    from gubernator_tpu.native import hashlib_native as hn
-
+    hn = native_lib()
     runs = [prep_run_sharded(g, SLOTS, n_shards) for g in groups]
     m, req, take, grp, B_sub = _numpy_twin(runs, n_shards, sub)
     got = hn.merge_runs_sharded_native(runs, n_shards, SLOTS, sub)
@@ -269,9 +268,8 @@ def _assert_native_stack_is_the_twin(groups, n_shards, sub=SUB):
 
 
 needs_sharded_merge = pytest.mark.skipif(
-    not getattr(_native, "_HAS_MERGE_SHARDED", False),
-    reason="libguberhash.so not built, or built before "
-    "guber_merge_runs_sharded (make -C gubernator_tpu/native)",
+    native_lib() is None,
+    reason="libguberhash.so is absent (make -C gubernator_tpu/native)",
 )
 
 
@@ -422,18 +420,21 @@ def test_native_sharded_merge_edge_layouts(case, n_shards):
 @needs_sharded_merge
 @pytest.mark.parametrize("hidden", [False, True], ids=["native", "hidden"])
 def test_mesh_merge_prepped_follows_the_library(hidden, monkeypatch):
-    """PartitionedEngine.merge_prepped on a mesh: with the symbol the
+    """PartitionedEngine.merge_prepped on a mesh: with the library the
     native call lays the batch out, with it hidden the numpy twin does
     — the same dict either way — and the engine's two counters say
     which; `shard_counts` is told the same rows, slots and fullest
     shard by both."""
     import jax
 
-    from gubernator_tpu.native import hashlib_native as hn
+    import gubernator_tpu.parallel.sharded as sharded_mod
     from gubernator_tpu.parallel.sharded import MeshEngine
 
     if hidden:
-        monkeypatch.setattr(hn, "_HAS_MERGE_SHARDED", False)
+        # the handle the engine's module holds; the runs below are the
+        # arrival-time prep's, whose sort keys the merge reads: they
+        # come from the numpy twin then, bit for bit the native ones
+        monkeypatch.setattr(sharded_mod, "_hn", None)
     eng = MeshEngine(
         StoreConfig(rows=4, slots=SLOTS), devices=jax.devices()[:4],
         buckets=BUCKETS,
@@ -483,7 +484,6 @@ def test_batch_past_the_ladder_declines_to_the_twin_and_warns_once(
 
     import gubernator_tpu.parallel.sharded as sharded_mod
     from gubernator_tpu.core.engine import extend_ladder
-    from gubernator_tpu.native import hashlib_native as hn
     from gubernator_tpu.parallel.sharded import MeshEngine
 
     monkeypatch.setattr(sharded_mod, "_warned_ladder_overflow", False)
@@ -495,7 +495,7 @@ def test_batch_past_the_ladder_declines_to_the_twin_and_warns_once(
     top = max(eng.sub_buckets)
     groups = _fullest_shard_draws(rng, 4, top + 1)
     runs = [eng.prep_run(g) for g in groups]
-    assert hn.merge_runs_sharded_native(
+    assert native_lib().merge_runs_sharded_native(
         runs, 4, SLOTS, eng.sub_buckets
     ) is None
     with caplog.at_level(logging.WARNING, logger="gubernator.sharded"):

@@ -293,7 +293,8 @@ def test_owner_of_places_the_frames_keys_where_the_picker_does(ring):
         picker = cluster.instance_at(i).picker
         assert [picker.get(k).host for k in keys] == [
             reference_ring.owner_of(k, cluster.addresses) for k in keys]
-    owned = cluster.instance_at(0).picker.self_owned_mask(keys)
+    picker = cluster.instance_at(0).picker
+    owned = picker.ring()[2][picker.owner_column(keys)]
     assert [bool(x) for x in owned] == [
         reference_ring.owner_of(k, cluster.addresses) == cluster.addresses[0]
         for k in keys]

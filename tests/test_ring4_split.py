@@ -30,7 +30,6 @@ import sys
 
 import pytest
 
-from _util import native_lib_for_tests
 from gubernator_tpu.api.types import Behavior
 from gubernator_tpu.cluster import LocalCluster
 from gubernator_tpu.core import hashing, oracle
@@ -57,17 +56,6 @@ from test_ring4_served import (
 sys.path.insert(0, BENCH)
 import reference_ring  # noqa: E402
 import reference_ring4  # noqa: E402
-
-@pytest.fixture(scope="module")
-def native(tmp_path_factory):
-    lib = native_lib_for_tests(tmp_path_factory.mktemp("native"))
-    assert lib._HAS_SPLIT
-    mp = pytest.MonkeyPatch()
-    mp.setattr(hashing, "_native", lib)
-    mp.setattr(hashing, "_native_checked", True)
-    yield lib
-    mp.undo()
-
 
 @pytest.fixture(scope="module")
 def ring(native):
@@ -491,9 +479,10 @@ def test_each_decline_reason_is_counted_once_and_the_frame_answered(
 
         monkeypatch.setattr(inst, "rescale", _Open())
     elif reason == "no_arrays":
-        monkeypatch.setattr(door, "string_fold", False)
+        # what a host backend answers
+        monkeypatch.setattr(door, "_arrays_ok", lambda: False)
     elif reason == "no_native":
-        monkeypatch.setattr(native_lib_holder(), "_HAS_SPLIT", False)
+        hide_native_lib(monkeypatch)
     elif reason == "too_many_items":
         frame = frame + [item(950_000 + i, 1, "big-") for i in range(1001)]
         behaviors, n = None, len(frame)
@@ -541,8 +530,10 @@ def test_each_decline_reason_is_counted_once_and_the_frame_answered(
     assert len(bad) == (1 if reason == "invalid_item" else 0)
 
 
-def native_lib_holder():
-    return hashing.native_lib()
+def hide_native_lib(monkeypatch):
+    """The one handle says absent, to every node of this process."""
+    monkeypatch.setattr(hashing, "_native", None)
+    monkeypatch.setattr(hashing, "_native_checked", True)
 
 
 # -- failure -------------------------------------------------------------------
@@ -801,7 +792,7 @@ def test_the_boot_line_says_whether_the_split_is_live(ring, caplog, monkeypatch)
              for a in cluster.addresses]
     with caplog.at_level(logging.INFO, logger="gubernator_tpu.instance"):
         cluster.run(inst.set_peers(infos))
-        monkeypatch.setattr(native_lib_holder(), "_HAS_SPLIT", False)
+        hide_native_lib(monkeypatch)
         cluster.run(inst.set_peers(infos))
     lines = [r.getMessage() for r in caplog.records if "ring of 4" in r.getMessage()]
     assert len(lines) == 2

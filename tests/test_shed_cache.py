@@ -898,7 +898,7 @@ def test_bridge_tier_shed_windowed_frames():
     asyncio.run(run())
 
 
-def test_bridge_string_fold_shed():
+def test_bridge_string_frame_fold_shed():
     """The GEB1 string fold rides the same screen: the second frame for
     a frozen key sheds, and the response stays a well-formed GEB3."""
     from gubernator_tpu.serve.edge_bridge import MAGIC_RESP, EdgeBridge

@@ -52,22 +52,6 @@ def test_hll_real_key_hashes():
     assert abs(h.estimate() - distinct) <= 0.05 * distinct
 
 
-@pytest.fixture(scope="module")
-def native(tmp_path_factory):
-    """libguberhash.so with the observers' fold (built out of tree
-    where the checkout has none), lent to core.hashing for this file:
-    TrafficStats takes it from there."""
-    from _util import native_lib_for_tests
-    from gubernator_tpu.core import hashing
-
-    lib = native_lib_for_tests(tmp_path_factory.mktemp("native"))
-    mp = pytest.MonkeyPatch()
-    mp.setattr(hashing, "_native", lib)
-    mp.setattr(hashing, "_native_checked", True)
-    yield lib
-    mp.undo()
-
-
 # The three summary tests run against a TrafficStats on either
 # implementation (PR 40): "python" holds SpaceSaving itself, "native"
 # the summary behind libguberhash.so that must keep its guarantees.

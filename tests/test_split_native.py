@@ -24,8 +24,8 @@ one), each against the code it stands in for:
   messages only; a reply of the wrong length or of garbage fails the
   batch as the message path's does.
 
-libguberhash.so is git-ignored: built out of tree where needed
-(`_util.native_lib_for_tests`).
+libguberhash.so is git-ignored: tests/conftest.py builds it before
+collection (its `native` fixture skips where it is absent).
 """
 
 import asyncio
@@ -37,12 +37,10 @@ import zlib
 import numpy as np
 import pytest
 
-from _util import native_lib_for_tests
 from gubernator_tpu.api import convert
 from gubernator_tpu.api.columns import ForwardAnswers, ForwardGroup
 from gubernator_tpu.api.proto.gen import gubernator_pb2, peers_pb2
 from gubernator_tpu.api.types import RateLimitResp, Status
-from gubernator_tpu.core import hashing
 from gubernator_tpu.serve import peers as peers_mod
 from gubernator_tpu.serve.config import BehaviorConfig
 from gubernator_tpu.serve.edge_bridge import (
@@ -51,17 +49,6 @@ from gubernator_tpu.serve.edge_bridge import (
 )
 from gubernator_tpu.serve.peers import ConsistentHashPicker
 from test_string_frame_native import _random_frame
-
-
-@pytest.fixture(scope="module")
-def native(tmp_path_factory):
-    lib = native_lib_for_tests(tmp_path_factory.mktemp("native"))
-    assert lib._HAS_SPLIT
-    mp = pytest.MonkeyPatch()
-    mp.setattr(hashing, "_native", lib)
-    mp.setattr(hashing, "_native_checked", True)
-    yield lib
-    mp.undo()
 
 
 def _extreme_frame(seed: int, n: int):
@@ -229,10 +216,10 @@ def test_owner_column_equals_the_pickers_get(native, size):
     assert column.dtype == np.int32
     want = [picker.get(k) for k in keys]
     assert [peers[i] for i in column.tolist()] == want
-    # the same without the packed bytes (no native call), and the mask
+    # the same without the packed bytes (no native call), and the
+    # ring's is_owner column under it
     assert np.array_equal(picker.owner_column(keys), column)
-    assert picker.self_owned_mask(keys, packed).tolist() == [
-        p.is_owner for p in want]
+    assert own[column].tolist() == [p.is_owner for p in want]
     crc = np.array([zlib.crc32(k.encode()) for k in keys[:200]], np.uint64)
     idx = np.searchsorted(points, crc)
     idx[idx == size] = 0

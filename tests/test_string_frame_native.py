@@ -1,25 +1,25 @@
 """The GEB door's native string-frame parse (PR 37): one call of
 `libguberhash.so guber_parse_string_frame` — wire bytes -> columns, key
 hashes and the NUL-joined hash keys, GIL released — against the
-per-item Python loop of `EdgeBridge._fold_by_loop`, which stays as
-its fallback and is the oracle here.
+object path's decoder (`decode_request_frame`) and `slot_hash_batch`,
+the oracle that exists anyway. There is no second parser in Python: a
+frame the native call does not take is the object path's.
 
 - (a) wire: random frames (names and keys of 1-200 bytes, multi-byte
   UTF-8, every algorithm byte, behaviors 0-5, n = 0, 1, 1000) give the
-  loop's keys, columns and GLOBAL rows, and `slot_hash_batch`'s hashes;
-  one case per reason the parser declines, each declined by the loop
-  too — except a NUL byte in a name or key, which the loop serves;
+  decoder's keys, columns and GLOBAL rows, and `slot_hash_batch`'s
+  hashes; one case per reason the parser declines, the screen saying
+  `(None, None, reason)` for each — a NUL byte in a name or key among
+  them, which the object path answers as the fold answers a plain byte;
 - (b) fold: `_decide_string_frame` answers with the same bytes with the
-  library present and with `native_lib()` patched to None, on a
-  one-node ring (a real Instance on a standing clock) and on a
-  three-node ring where some frames are of mixed ownership; a frame the
-  parser declines is answered by the object path as before;
+  library present and with `native_lib()` patched to None (the object
+  path), on a one-node ring (a real Instance on a standing clock) and
+  on a three-node ring where some frames are of mixed ownership; a
+  frame the parser declines is answered by the object path as before;
 - (c) the two counters by growth.
 
-libguberhash.so is git-ignored, so the driver's checkout has none: like
-tests/test_peer_fold.py this file builds it out of tree where needed
-(`_util.native_lib_for_tests`) and lends it to the process's hashing
-singleton for its own duration.
+libguberhash.so is git-ignored: tests/conftest.py builds it before
+collection, and its `native` fixture skips where it is absent.
 """
 
 import asyncio
@@ -29,7 +29,6 @@ import struct
 import numpy as np
 import pytest
 
-from _util import native_lib_for_tests
 from gubernator_tpu.api.types import Behavior, RateLimitResp, Status
 from gubernator_tpu.core import hashing
 from gubernator_tpu.serve import edge_bridge
@@ -48,23 +47,12 @@ from test_edge_bridge import (
 FIELDS = ("key_hash", "hits", "limit", "duration", "algo")
 
 
-@pytest.fixture(scope="module")
-def native(tmp_path_factory):
-    """The native library, lent to core.hashing for this file: the door
-    takes it from there, and so does every key hashed meanwhile."""
-    lib = native_lib_for_tests(tmp_path_factory.mktemp("native"))
-    mp = pytest.MonkeyPatch()
-    mp.setattr(hashing, "_native", lib)
-    mp.setattr(hashing, "_native_checked", True)
-    yield lib
-    mp.undo()
-
-
 @pytest.fixture
 def python_parse(monkeypatch):
-    """Run the body with the door's native parse off — the tree as it
-    behaves where libguberhash.so is not built (key hashing aside: it
-    stays native, so both parses hash alike)."""
+    """Run the body with the door's native parse off — the door as it
+    behaves where libguberhash.so is absent: every string frame is the
+    object path's (key hashing aside: it stays native, so both nodes
+    of a comparison hash alike)."""
 
     def off():
         monkeypatch.setattr(edge_bridge, "native_lib", lambda: None)
@@ -78,8 +66,9 @@ def python_parse(monkeypatch):
 def _bridge(owners=(True,)):
     """EdgeBridge over a real ConsistentHashPicker with one peer per
     entry of `owners` (True = this node), a batcher that answers from
-    the columns it is handed and an Instance door that answers from the
-    request objects — deterministic, so two serves compare bytewise."""
+    the columns it is handed and an Instance door that answers the
+    same from the request objects — deterministic, so two serves
+    compare bytewise whichever path took the frame."""
     from gubernator_tpu.serve.config import BehaviorConfig
     from gubernator_tpu.serve.global_mgr import GlobalManager
 
@@ -109,12 +98,14 @@ def _bridge(owners=(True,)):
 
         async def get_rate_limits(self, reqs, stage_frame=False):
             paths.append(("objects", len(reqs)))
+            hashes = hashing.slot_hash_batch([r.hash_key() for r in reqs])
             return [
                 RateLimitResp(
-                    status=Status.UNDER_LIMIT, limit=r.limit,
-                    remaining=r.limit - r.hits, reset_time=r.duration,
+                    status=Status(int(h) % 2), limit=r.limit,
+                    remaining=r.limit - r.hits,
+                    reset_time=r.duration + int(r.algorithm),
                 )
-                for r in reqs
+                for r, h in zip(reqs, hashes)
             ]
 
     inst = FakeInstance()
@@ -166,49 +157,44 @@ def _random_frame(seed: int, n: int):
     return b"".join(items), rows
 
 
-def _same_fold(a, b):
-    assert a is not None and b is not None
-    assert a[0] == b[0]  # the hash keys
-    assert a[2] == b[2]  # the GLOBAL rows
-    assert set(a[1]) == set(b[1]) == set(FIELDS)
-    for k in FIELDS:
-        assert a[1][k].dtype == b[1][k].dtype, k
-        assert np.array_equal(a[1][k], b[1][k]), k
-
-
 @pytest.mark.parametrize(
     "seed,n", [(1, 0), (2, 1), (3, 1), (4, 2), (5, 37), (6, 1000), (7, 1000)]
 )
-def test_native_parse_equals_python_loop(native, python_parse, seed, n):
+def test_native_parse_equals_the_object_decoder(native, seed, n):
     payload, rows = _random_frame(seed, n)
     bridge, _ = _bridge()
     got, cols, keys = native.parse_string_frame(payload, n)
     assert got == n
-    by_native = bridge._screen_string_frame(payload, n)[0]
-    python_parse()
-    by_loop = bridge._screen_string_frame(payload, n)[0]
-    _same_fold(by_native, by_loop)
-    full, fields, glob, _, packed = by_native
+    fold, mixed, reason = bridge._screen_string_frame(payload, n)
+    assert mixed is None and reason == ""
+    full, fields, glob, _, packed = fold
     assert packed == keys
-    assert full == [
-        (name + b"_" + key).decode() for name, key, *_ in rows
-    ]
-    assert keys == b"\x00".join(name + b"_" + key for name, key, *_ in rows)
+    assert set(fields) == set(FIELDS)
+    # the oracle: what the object path makes of the same bytes
+    reqs = decode_request_frame(payload, n)
+    assert len(reqs) == n and None not in reqs
+    assert full == [r.name + "_" + r.unique_key for r in reqs]
     assert np.array_equal(fields["key_hash"], hashing.slot_hash_batch(full))
     assert fields["key_hash"].dtype == np.uint64
+    assert fields["hits"].tolist() == [r.hits for r in reqs]
+    assert fields["limit"].tolist() == [r.limit for r in reqs]
+    assert fields["duration"].tolist() == [r.duration for r in reqs]
+    # an algorithm byte over 3 reads as the default, on both sides
+    assert fields["algo"].tolist() == [int(r.algorithm) for r in reqs]
+    assert fields["algo"].dtype == np.int32
+    assert glob == [
+        (i, r.name, r.unique_key)
+        for i, r in enumerate(reqs) if r.behavior == Behavior.GLOBAL
+    ]
+    # and the frame as it was written
+    assert keys == b"\x00".join(name + b"_" + key for name, key, *_ in rows)
     for k in FIELDS:
         assert np.array_equal(fields[k], cols[k])
     assert fields["hits"].tolist() == [r[2] for r in rows]
-    assert fields["limit"].tolist() == [r[3] for r in rows]
-    assert fields["duration"].tolist() == [r[4] for r in rows]
     assert fields["algo"].tolist() == [
         r[5] if r[5] <= 3 else 0 for r in rows
     ]
     assert cols["behavior"].tolist() == [r[6] for r in rows]
-    assert glob == [
-        (i, r[0].decode(), r[1].decode())
-        for i, r in enumerate(rows) if r[6] == int(Behavior.GLOBAL)
-    ]
     for i, (name, key, *_) in enumerate(rows):
         no, nl = int(cols["name_off"][i]), int(cols["name_len"][i])
         ko, kl = int(cols["key_off"][i]), int(cols["key_len"][i])
@@ -218,7 +204,7 @@ def test_native_parse_equals_python_loop(native, python_parse, seed, n):
 
 def _decline_cases():
     """(id, payload, n, reasons): the parser must decline with one of
-    `reasons`, the loop must decline too."""
+    `reasons`."""
     a = _item(b"api", b"k-one", hits=3, limit=9, behavior=2)
     b = _item("né".encode(), "clé-€".encode(), algo=2)
     cases = [
@@ -257,50 +243,104 @@ def _decline_cases():
     "payload,n,reasons",
     [pytest.param(*c[1:], id=c[0]) for c in _decline_cases()],
 )
-def test_native_parse_declines_where_the_loop_does(
-    native, python_parse, payload, n, reasons
+def test_native_parse_declines_and_the_screen_says_why(
+    native, payload, n, reasons
 ):
-    bridge, _ = _bridge()
     got, cols, keys = native.parse_string_frame(payload, n)
     assert got < 0 and cols is None and keys is None
     assert native.STRING_DECLINE[got] in reasons
-    assert bridge._screen_string_frame(payload, n)[0] is None
-    python_parse()
-    assert bridge._screen_string_frame(payload, n)[0] is None
+    # the frame is the object path's: for no counted reason on a ring
+    # this node shares with nobody, as an invalid item on a shared one
+    alone, _ = _bridge()
+    assert alone._screen_string_frame(payload, n) == (None, None, "")
+    shared, _ = _bridge(owners=(True, False, False))
+    assert shared._screen_string_frame(payload, n) == (
+        None, None, "invalid_item"
+    )
+
+
+def _standing_node(monkeypatch, addr):
+    """serve(frames) -> (reply frames, queued broadcasts, shed hits):
+    each call boots a fresh one-node Instance over a small device store
+    on a standing clock and sends `frames` through its door."""
+    from gubernator_tpu.api.types import PeerInfo
+    from gubernator_tpu.core.store import StoreConfig
+    from gubernator_tpu.serve.backends import TpuBackend
+    from gubernator_tpu.serve.config import ServerConfig
+    from gubernator_tpu.serve.instance import Instance
+
+    import gubernator_tpu.api.types as types_mod
+    import gubernator_tpu.core.engine as engine_mod
+
+    def clock():
+        return 1_700_000_000_000
+
+    monkeypatch.setattr(types_mod, "millisecond_now", clock)
+    monkeypatch.setattr(engine_mod, "millisecond_now", clock)
+
+    async def serve(frames):
+        conf = ServerConfig(
+            grpc_address=addr, advertise_address=addr, shed_cache=True
+        )
+        conf.behaviors.global_sync_wait = 3600.0
+        inst = Instance(
+            conf,
+            TpuBackend(
+                StoreConfig(rows=16, slots=1 << 10), buckets=(64, 1024)
+            ),
+        )
+        inst.start()
+        await inst.set_peers([PeerInfo(address=addr, is_owner=True)])
+        inst.shed.now_fn = clock
+        bridge = EdgeBridge(inst, "")
+        try:
+            out = [
+                await bridge._decide_string_frame(payload, n)
+                for payload, n in frames
+            ]
+            return out, dict(inst.global_mgr._updates), inst.shed.hits
+        finally:
+            await inst.stop()
+
+    return lambda frames: asyncio.run(serve(frames))
 
 
 @pytest.mark.parametrize("where", ["name", "key", "key-end"])
-def test_nul_byte_declines_natively_and_the_loop_serves(
-    native, python_parse, where
+def test_nul_byte_declines_natively_and_the_object_path_answers(
+    native, monkeypatch, where
 ):
-    odd = {
-        "name": _item(b"a\x00pi", b"k2", behavior=2),
-        "key": _item(b"api", b"k\x002", behavior=2),
-        "key-end": _item(b"api", b"k2\x00", behavior=2),
-    }[where]
-    payload = _item(b"api", b"k1") + odd + _item(b"api", b"k3", algo=1)
-    got, _, _ = native.parse_string_frame(payload, 3)
+    """A NUL inside a name or key cannot ride the parser's NUL-joined
+    buffer: the object path answers that frame, byte for byte what the
+    fold answers for the same frame with a plain byte in the NUL's
+    place (fresh nodes, a standing clock)."""
+
+    def frame(byte: bytes):
+        odd = {
+            "name": _item(b"a" + byte + b"pi", b"k2", behavior=2),
+            "key": _item(b"api", b"k" + byte + b"2", behavior=2),
+            "key-end": _item(b"api", b"k2" + byte, behavior=2),
+        }[where]
+        return _item(b"api", b"k1") + odd + _item(b"api", b"k3", algo=1), 3
+
+    got, _, _ = native.parse_string_frame(*frame(b"\x00"))
     assert native.STRING_DECLINE[got] == "nul_byte"
-    bridge, _ = _bridge()
-    declined = _declined("nul_byte")
-    by_native = bridge._screen_string_frame(payload, 3)[0]
-    assert _declined("nul_byte") - declined == 1
-    python_parse()
-    by_loop = bridge._screen_string_frame(payload, 3)[0]
-    _same_fold(by_native, by_loop)
-    assert "\x00" in by_native[0][1] and by_native[2][0][0] == 1
-
-
-def test_stale_library_declines_every_frame(native, monkeypatch):
-    """A libguberhash.so built before the symbol keeps the loop."""
-    payload = _item(b"api", b"k1")
-    bridge, _ = _bridge()
-    want = bridge._screen_string_frame(payload, 1)[0]
-    monkeypatch.setattr(native, "_HAS_STRING_FRAME", False)
-    assert native.parse_string_frame(payload, 1) == (-7, None, None)
-    declined = _declined("stale_library")
-    _same_fold(bridge._screen_string_frame(payload, 1)[0], want)
-    assert _declined("stale_library") - declined == 1
+    serve = _standing_node(monkeypatch, "127.0.0.1:9984")
+    before = (
+        _declined("nul_byte"), _sample("edge_object_items_total"),
+        _sample("edge_folded_items_total"),
+    )
+    with_nul, updates, _ = serve([frame(b"\x00")])
+    assert _declined("nul_byte") - before[0] == 1
+    assert _sample("edge_object_items_total") - before[1] == 3
+    assert _sample("edge_folded_items_total") == before[2]
+    plain, plain_updates, _ = serve([frame(b"~")])
+    assert _sample("edge_folded_items_total") - before[2] == 3
+    assert _sample("edge_object_items_total") - before[1] == 3
+    assert with_nul == plain and len(with_nul[0]) == 8 + 3 * 29
+    # the GLOBAL item's broadcast is queued under the key as it came
+    assert len(updates) == len(plain_updates) == 1
+    assert "\x00" in next(iter(updates))
+    assert "~" in next(iter(plain_updates))
 
 
 # -- (b) the fold -------------------------------------------------------------
@@ -352,17 +392,19 @@ def test_three_node_ring_same_bytes_with_and_without_native(
     bridge, paths = _bridge(owners=(True, False, False))
     frames = _ring_frames(bridge.instance.picker)
     with_native = _serve(bridge, frames)
-    updates = dict(bridge.instance.global_mgr._updates)
     assert [p[0] for p in paths] == [
         "arrays", "objects", "arrays", "objects"
     ]
-    assert len(updates) > 20
+    assert len(bridge.instance.global_mgr._updates) > 20
+    declined = bridge.instance.edge_split.declined
     bridge2, paths2 = _bridge(owners=(True, False, False))
     python_parse()
     without = _serve(bridge2, frames)
     assert with_native == without
-    assert paths2 == paths
-    assert bridge2.instance.global_mgr._updates == updates
+    # without the library every frame is the object path's, and says so
+    assert paths2 == [("objects", n) for _, n in frames]
+    declined2 = bridge2.instance.edge_split.declined
+    assert declined2["no_native"] == 4 and declined["no_native"] == 0
 
 
 def test_one_node_ring_same_bytes_with_and_without_native(
@@ -371,57 +413,22 @@ def test_one_node_ring_same_bytes_with_and_without_native(
     """Two fresh nodes on a standing clock serve the same two seeded
     1000-item mixed frames (both algorithms, every tenth key id GLOBAL,
     keys driven over their limit, the shed cache filling): one parses
-    natively, one with the loop; reply bytes, queued broadcasts and
-    shed hits are equal."""
-    from gubernator_tpu.api.types import PeerInfo
-    from gubernator_tpu.core.store import StoreConfig
-    from gubernator_tpu.serve.backends import TpuBackend
-    from gubernator_tpu.serve.config import ServerConfig
-    from gubernator_tpu.serve.instance import Instance
-
-    import gubernator_tpu.api.types as types_mod
-    import gubernator_tpu.core.engine as engine_mod
-
-    def clock():
-        return 1_700_000_000_000
-
-    monkeypatch.setattr(types_mod, "millisecond_now", clock)
-    monkeypatch.setattr(engine_mod, "millisecond_now", clock)
-    addr = "127.0.0.1:9983"
+    natively and folds, one has no library and serves request objects;
+    reply bytes, queued broadcasts and shed hits are equal."""
+    serve = _standing_node(monkeypatch, "127.0.0.1:9983")
     frames = [_seeded_mixed_frame(37), _seeded_mixed_frame(2037)]
-
-    async def serve():
-        conf = ServerConfig(
-            grpc_address=addr, advertise_address=addr, shed_cache=True
-        )
-        conf.behaviors.global_sync_wait = 3600.0
-        inst = Instance(
-            conf,
-            TpuBackend(
-                StoreConfig(rows=16, slots=1 << 10), buckets=(64, 1024)
-            ),
-        )
-        inst.start()
-        await inst.set_peers([PeerInfo(address=addr, is_owner=True)])
-        inst.shed.now_fn = clock
-        bridge = EdgeBridge(inst, "")
-        try:
-            out = [
-                await bridge._decide_string_frame(payload, n)
-                for payload, n in frames
-            ]
-            return out, dict(inst.global_mgr._updates), inst.shed.hits
-        finally:
-            await inst.stop()
-
     parsed = _native_frames()
     folded = _sample("edge_folded_items_total")
-    with_native = asyncio.run(serve())
+    objects = _sample("edge_object_items_total")
+    with_native = serve(frames)
     assert _native_frames() - parsed == 2
+    assert _sample("edge_folded_items_total") - folded == 2000
+    assert _sample("edge_object_items_total") == objects
     python_parse()
-    without = asyncio.run(serve())
+    without = serve(frames)
     assert _native_frames() - parsed == 2
-    assert _sample("edge_folded_items_total") - folded == 4000
+    assert _sample("edge_folded_items_total") - folded == 2000
+    assert _sample("edge_object_items_total") - objects == 2000
     assert with_native == without
     out, updates, shed_hits = with_native
     assert len(updates) > 20 and shed_hits > 0
@@ -490,9 +497,9 @@ def test_counters_follow_the_frames(native):
     assert _sample("edge_folded_items_total") - before[2] == 10
     assert paths == [("arrays", 2)] * 5
     assert all(len(frame) == 8 + 2 * 29 for frame in out)
-    # a malformed frame: declined natively, by the loop, and the object
-    # path answers it as it always did — the decoder raises, the
-    # connection's handler closes it
+    # a malformed frame: declined natively, and the object path answers
+    # it as it always did — the decoder raises, the connection's
+    # handler closes it
     with pytest.raises(struct.error):
         _serve(bridge, [cut])
     assert _native_frames() - before[0] == 5

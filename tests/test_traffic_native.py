@@ -23,10 +23,8 @@ which stay as its fallback and are the oracle here.
 (b) — the three summary tests of tests/test_sketches.py on either
 implementation — lives there.
 
-libguberhash.so is git-ignored, so the driver's checkout has none: like
-tests/test_string_frame_native.py this file builds it out of tree where
-needed (`_util.native_lib_for_tests`) and lends it to the process's
-hashing singleton for its own duration.
+libguberhash.so is git-ignored: tests/conftest.py builds it before
+collection, and its `native` fixture skips where it is absent.
 """
 
 import asyncio
@@ -38,27 +36,14 @@ import urllib.request
 import numpy as np
 import pytest
 
-from _util import free_ports, native_lib_for_tests
-from gubernator_tpu.core import hashing
+from _util import free_ports
+from gubernator_tpu.core import hashing, sketches
 from gubernator_tpu.core.sketches import (
     NativeHotKeys,
     SpaceSaving,
     TrafficStats,
 )
 from test_edge_bridge import _seeded_mixed_frame
-
-
-@pytest.fixture(scope="module")
-def native(tmp_path_factory):
-    """The native library, lent to core.hashing for this file: a
-    TrafficStats takes it from there, and so does every key hashed
-    meanwhile."""
-    lib = native_lib_for_tests(tmp_path_factory.mktemp("native"))
-    mp = pytest.MonkeyPatch()
-    mp.setattr(hashing, "_native", lib)
-    mp.setattr(hashing, "_native_checked", True)
-    yield lib
-    mp.undo()
 
 
 def _tracked(hot):
@@ -172,10 +157,11 @@ def test_joined_keys_must_be_as_many_as_the_hashes(native):
 # -- (c) one picture whatever the door ----------------------------------------
 
 
-def _serve_frames(monkeypatch, frames, string_fold: bool):
+def _serve_frames(monkeypatch, frames, fold: bool):
     """A fresh one-node Instance on a standing clock serves `frames`
-    through EdgeBridge: folded, or (string_fold False) as request
-    objects through Instance.get_rate_limits. Returns its TrafficStats."""
+    through EdgeBridge: folded, or (`fold` False) by the door's object
+    path, as request objects through Instance.get_rate_limits. Returns
+    its TrafficStats."""
     from gubernator_tpu.api.types import PeerInfo
     from gubernator_tpu.core.store import StoreConfig
     from gubernator_tpu.serve.backends import TpuBackend
@@ -207,11 +193,14 @@ def _serve_frames(monkeypatch, frames, string_fold: bool):
         inst.start()
         await inst.set_peers([PeerInfo(address=addr, is_owner=True)])
         inst.shed.now_fn = clock
-        bridge = EdgeBridge(inst, "", string_fold=string_fold)
+        bridge = EdgeBridge(inst, "")
+        decide = (
+            bridge._decide_string_frame if fold else bridge._decide_string
+        )
         try:
             seen = []
             for payload, n in frames:
-                await bridge._decide_string_frame(payload, n)
+                await decide(payload, n)
                 seen.append(
                     (inst.traffic.native_folds, inst.traffic.python_folds)
                 )
@@ -246,7 +235,9 @@ def test_folded_frame_leaves_the_object_paths_stats(native, monkeypatch):
     assert _picture(objects) == want
     assert folded.hll._reg.tobytes() == objects.hll._reg.tobytes()
     # and both are the Python classes' picture of the same frames
-    monkeypatch.setattr(native, "_HAS_TRAFFIC_FOLD", False)
+    # (the observers alone without the library: the door parses and
+    # hashes natively still, so the hashes folded are the same)
+    monkeypatch.setattr(sketches, "_fold_lib", lambda: None)
     plain, seen = _serve_frames(monkeypatch, frames, True)
     assert plain.implementation == "python"
     assert seen == [(0, 1), (0, 2), (0, 3)]
@@ -254,7 +245,7 @@ def test_folded_frame_leaves_the_object_paths_stats(native, monkeypatch):
     assert plain.hll._reg.tobytes() == folded.hll._reg.tobytes()
 
 
-# -- (d) the counters and the boot log, symbol present and absent ----------
+# -- (d) the counters and the boot log, library present and absent ---------
 
 
 def _folds(http_port) -> dict:
@@ -277,7 +268,10 @@ def test_counters_and_boot_log_by_implementation(
     from gubernator_tpu.client import V1Client
     from gubernator_tpu.cluster import LocalCluster
 
-    monkeypatch.setattr(native, "_HAS_TRAFFIC_FOLD", present)
+    if not present:
+        # the one handle says absent: to the whole process
+        monkeypatch.setattr(hashing, "_native", None)
+        monkeypatch.setattr(hashing, "_native_checked", True)
     grpc_port, http = free_ports(2)
     addr = f"127.0.0.1:{grpc_port}"
     cluster = LocalCluster([addr], http_addresses=[f"127.0.0.1:{http}"])
