@@ -61,6 +61,8 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import contextlib
+import contextvars
 import os
 import time
 from typing import List, Optional, Sequence, Tuple
@@ -89,9 +91,13 @@ class _QMeta:
     those names in the caller's trace too, so the tiles have their
     call_e2e and a JSON-door, peer-loop or internal group records
     none. `t_done` is the instant the flusher resolved the group's
-    future: call_device ends there and call_wake begins."""
+    future: call_device ends there and call_wake begins. `peer` says
+    whose rows the group holds: a peer's forward (enqueued under
+    peer_rows(), Instance.get_peer_rate_limits) or, for every other
+    caller, this node's own doors' — a row's source rides the entry
+    that queued it, and a batch is counted by its groups' lengths."""
 
-    __slots__ = ("t", "frame", "call", "trace", "t_done")
+    __slots__ = ("t", "frame", "call", "trace", "t_done", "peer")
 
     def __init__(self, frame: bool):
         self.t = time.monotonic()
@@ -99,6 +105,25 @@ class _QMeta:
         self.call = not frame and claim_call()
         self.trace = tracing.active()
         self.t_done = 0.0
+        self.peer = _PEER_ROWS.get()
+
+
+#: set around the owner side's serving of one forwarded batch: what is
+#: enqueued under it is counted as rows a PEER sent
+#: (device_batch_rows_total{source="peer"}), everything else as rows of
+#: this node's own doors
+_PEER_ROWS: "contextvars.ContextVar[bool]" = contextvars.ContextVar(
+    "guber_batch_peer_rows", default=False
+)
+
+
+@contextlib.contextmanager
+def peer_rows():
+    token = _PEER_ROWS.set(True)
+    try:
+        yield
+    finally:
+        _PEER_ROWS.reset(token)
 
 
 #: what a device backend owes the batcher besides decide_submit_merged
@@ -125,6 +150,14 @@ def _prep_result(prep: "concurrent.futures.Future"):
         return prep.result()
     except concurrent.futures.CancelledError:
         raise RuntimeError("prep cancelled (batcher stopping)") from None
+
+
+def _group_rows(item) -> int:
+    """The answers a decide or chain queue entry is owed: its array
+    group's rows, or its requests."""
+    if item[0] == "decide_arrays":
+        return item[1]["key_hash"].shape[0]
+    return len(item[1])
 
 
 def _item_weight(item) -> int:
@@ -196,6 +229,12 @@ class DeviceBatcher:
         self._last_misses = 0
         self._last_dropped = 0
         self._last_evictions = 0
+        # every batch's rows by who sent them, and the batches that
+        # held rows of both (plain ints, exported lazily at scrape:
+        # device_batch_rows_total{source}, device_batches_mixed_total);
+        # door + peer = device_batch_size_sum by construction
+        self.rows_by_source = {"door": 0, "peer": 0}
+        self.mixed_batches = 0
         # set before the flusher is cancelled: a decide()/update_globals()
         # after stop() would otherwise enqueue into a queue no flusher
         # reads and await a future that never resolves (same guard as
@@ -323,7 +362,10 @@ class DeviceBatcher:
                     "device", start=t0, batch=len(resps),
                     rung=self._rung(len(resps)), inline=True,
                 )
-            self._observe_batch(len(resps), time.monotonic() - t0)
+            self._observe_batch(
+                len(resps), time.monotonic() - t0,
+                len(resps) if _PEER_ROWS.get() else 0,
+            )
             return resps
         # one queue item + ONE future per caller (an RPC's whole request
         # list): per-item futures cost ~0.1-0.3ms of event-loop work per
@@ -709,7 +751,10 @@ class DeviceBatcher:
                     chain_items, t_collect, len(all_chain),
                     extra=dict(chain=True, rows=rows),
                 )
-                self._observe_batch(len(resps), time.monotonic() - t0c)
+                self._observe_batch(
+                    len(resps), time.monotonic() - t0c,
+                    self._peer_rows(chain_items),
+                )
 
         if not decide_items:
             return
@@ -753,12 +798,7 @@ class DeviceBatcher:
         and submit the NEXT batch while the device computes this one."""
         # group lengths are exception-free to read and needed for the
         # response slicing regardless of submit outcome
-        lens = [
-            it[1]["key_hash"].shape[0]
-            if it[0] == "decide_arrays"
-            else len(it[1])
-            for it in decide_items
-        ]
+        lens = [_group_rows(it) for it in decide_items]
 
         def submit_call():
             # the span's own two stamps go back with the handle: the
@@ -879,7 +919,10 @@ class DeviceBatcher:
                 fetch_ms=round((time.monotonic() - t1) * 1e3, 3),
             ),
         )
-        self._observe_batch(k, submit_s + (time.monotonic() - t1))
+        self._observe_batch(
+            k, submit_s + (time.monotonic() - t1),
+            self._peer_rows(decide_items),
+        )
 
     def _fail(self, items, exc: BaseException) -> None:
         # both queue item shapes carry their future last
@@ -900,7 +943,9 @@ class DeviceBatcher:
             k += len(rs)
             if not fut.done():
                 fut.set_result(span)
-        self._observe_batch(len(resps), launch_s)
+        self._observe_batch(
+            len(resps), launch_s, self._peer_rows(decide_items)
+        )
 
     @staticmethod
     def _fetch(wait, handle):
@@ -949,11 +994,21 @@ class DeviceBatcher:
             STAGES.add("call_device", span * calls, calls)
         return now
 
-    def _observe_batch(self, n: int, launch_s: float) -> None:
-        """One device batch of n rows, launched at its padding rung:
-        useful rows over attempted slots is device_batch_size_sum /
-        device_batch_slots_total. Best-effort: metrics must never be
-        able to kill the flusher task."""
+    @staticmethod
+    def _peer_rows(items) -> int:
+        """Of one batch's rows, those its peers forwarded: the lengths
+        of the groups whose queue entry says so (_QMeta.peer)."""
+        return sum(_group_rows(it) for it in items if it[-2].peer)
+
+    def _observe_batch(self, n: int, launch_s: float, peer: int) -> None:
+        """One device batch of n rows, `peer` of them forwarded by
+        peers and the rest from this node's own doors, launched at its
+        padding rung: useful rows over attempted slots is
+        device_batch_size_sum / device_batch_slots_total. Best-effort:
+        metrics must never be able to kill the flusher task."""
+        self.rows_by_source["peer"] += peer
+        self.rows_by_source["door"] += n - peer
+        self.mixed_batches += 0 < peer < n
         try:
             metrics.DEVICE_BATCH_SIZE.observe(n)
             metrics.DEVICE_BATCH_SLOTS.inc(self._rung(n))

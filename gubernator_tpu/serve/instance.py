@@ -47,7 +47,11 @@ from gubernator_tpu.api.types import (
 from gubernator_tpu.core.hashing import slot_hash_batch
 from gubernator_tpu.core.sketches import TrafficStats
 from gubernator_tpu.serve import metrics, tracing
-from gubernator_tpu.serve.batcher import DeviceBatcher, is_device_backend
+from gubernator_tpu.serve.batcher import (
+    DeviceBatcher,
+    is_device_backend,
+    peer_rows,
+)
 from gubernator_tpu.serve.breaker import OPEN as BREAKER_OPEN
 from gubernator_tpu.serve.config import MAX_BATCH_SIZE, ServerConfig
 from gubernator_tpu.serve.faults import FAULTS
@@ -836,10 +840,13 @@ class Instance:
         self.peer_serve_batches += 1
         self.peer_serve_items += len(reqs)
         try:
-            if isinstance(reqs, PeerBatch):
-                self.peer_serve_folded_items += len(reqs)
-                return await self._peer_serve_folded(reqs, waited)
-            return await self._peer_serve(reqs, waited)
+            # what this call enqueues are a peer's rows, not this
+            # node's own doors' (device_batch_rows_total{source})
+            with peer_rows():
+                if isinstance(reqs, PeerBatch):
+                    self.peer_serve_folded_items += len(reqs)
+                    return await self._peer_serve_folded(reqs, waited)
+                return await self._peer_serve(reqs, waited)
         finally:
             STAGES.add("peer_serve", time.monotonic() - t0 - waited[0])
 

@@ -67,6 +67,28 @@ DEVICE_BATCH_SLOTS = Counter(
     "slots that carried a request",
     registry=REGISTRY,
 )
+DEVICE_BATCH_ROWS = Gauge(
+    "device_batch_rows_total",
+    "Rows of device batches by who sent them: door (a call or frame "
+    "that came through one of this node's own doors: the rows it owns "
+    "of them) or peer (a batch another node forwarded, "
+    "Instance.get_peer_rate_limits). A row's source rides the queue "
+    "entry that carried it (serve/batcher.py _QMeta.peer); plain ints "
+    "exported lazily at scrape. The two sum to device_batch_size_sum; "
+    "peer / both = the share of a ring member's device work that is "
+    "its peers'",
+    ["source"],
+    registry=REGISTRY,
+)
+DEVICE_BATCHES_MIXED = Gauge(
+    "device_batches_mixed_total",
+    "Device batches that carried rows of both sources, the node's own "
+    "doors' and a peer's forward, merged in one launch; / "
+    "device_batch_size_count = their share. 0 on a node that owns "
+    "every key, and on a ring member that is asked at no door of its "
+    "own",
+    registry=REGISTRY,
+)
 MESH_SHARD_ROWS = Counter(
     "mesh_shard_rows_total",
     "Mesh backend: rows that carried a request, summed over the shards "
@@ -238,6 +260,29 @@ THREAD_WALL_SECONDS = Gauge(
     "thread_wall_seconds_total",
     "The monotonic clock at the instant thread_cpu_seconds_total was "
     "read: difference both over two scrapes for a share of one core",
+    registry=REGISTRY,
+)
+LOOP_PAUSES_OVER_HALF_DEADLINE = Gauge(
+    "loop_pauses_over_half_deadline_total",
+    "Ticks of the 50 ms loop_lag timer that found the serving loop "
+    "held for half of a forwarded batch's deadline or longer "
+    "(BehaviorConfig.effective_peer_timeout / 2: 0.25 s by default) — "
+    "the timer itself that late, or a GC collection that long since "
+    "the tick before (serve/stages.py ProcessProbes; a daemon's, 0 in "
+    "an in-process cluster). A pause of twice that on an owner fails "
+    "every hit-carrying batch its peers have in flight, and those are "
+    "never sent again: any growth here is the warning before error "
+    "items",
+    registry=REGISTRY,
+)
+PROGRAMS_BUILT_AFTER_READY = Gauge(
+    "programs_built_after_ready_total",
+    "Programs XLA was handed since the daemon said Ready, compiled or "
+    "loaded from the persistent cache (a jax.monitoring listener on "
+    "the backend-compile event): each was traced and lowered on a "
+    "serving thread while callers and peers waited. The warm-up "
+    "exists so that this stays 0; the benchmark voids a window in "
+    "which it grew",
     registry=REGISTRY,
 )
 SHED_HITS = Gauge(

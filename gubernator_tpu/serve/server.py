@@ -493,6 +493,9 @@ class Server:
         self._pool = None
         # read by the scrape and snapshot handlers only
         self.thread_clocks = ThreadClocks()
+        #: the process's ProcessProbes once something started them
+        #: (run_daemon, at Ready): their counts are exported at scrape
+        self.probes: Optional[ProcessProbes] = None
 
     def device_report(self) -> dict:
         """What this daemon serves from, for the boot log and
@@ -1178,6 +1181,10 @@ class Server:
         metrics.PEER_SERVE_FOLDED_ITEMS.set(
             self.instance.peer_serve_folded_items
         )
+        batcher = self.instance.batcher
+        for source, rows in batcher.rows_by_source.items():
+            metrics.DEVICE_BATCH_ROWS.labels(source=source).set(rows)
+        metrics.DEVICE_BATCHES_MIXED.set(batcher.mixed_batches)
         fwd = self.instance.peer_forward
         metrics.PEER_FORWARD_BATCHES.set(fwd.batches)
         metrics.PEER_FORWARD_ITEMS.set(fwd.items)
@@ -1213,6 +1220,12 @@ class Server:
         metrics.THREAD_WALL_SECONDS.set(threads["wall_s"])
         for role, cpu_s in threads["cpu_s"].items():
             metrics.THREAD_CPU_SECONDS.labels(thread=role).set(cpu_s)
+        # the process probes' two counts (a daemon's: run_daemon starts
+        # them at Ready; an in-process cluster has none and reads 0)
+        probes = self.probes
+        if probes is not None:
+            metrics.LOOP_PAUSES_OVER_HALF_DEADLINE.set(probes.pauses_over)
+            metrics.PROGRAMS_BUILT_AFTER_READY.set(probes.programs_built)
         # queue-visibility gauges (r16): standing occupancy the stage
         # clock can't express, set lazily at scrape like shed_entries
         qs = self.instance.batcher.queue_stats()
@@ -1294,6 +1307,19 @@ class Server:
         # since the process began (a reset does not touch them: a
         # reader differences two snapshots' `cpu_s` and `wall_s`)
         body["threads"] = self.thread_clocks.snapshot()
+        # the batcher's rows by who sent them and the process probes'
+        # two counts, as /metrics has them (cumulative: a reset does
+        # not touch them)
+        batcher, probes = self.instance.batcher, self.probes
+        body["batch_rows"] = dict(
+            batcher.rows_by_source, mixed_batches=batcher.mixed_batches
+        )
+        if probes is not None:
+            body["process"] = {
+                "pause_threshold_s": probes.pause_s,
+                "loop_pauses_over_half_deadline": probes.pauses_over,
+                "programs_built_after_ready": probes.programs_built,
+            }
         # what served those stages: devices as JAX reports them, with
         # per-device bytes, and the host-prep / hasher implementations
         body.update(self.device_report())
@@ -1419,6 +1445,7 @@ class Server:
             )
         self._profiling = True
         started = False
+        stop = {}
         try:
             import jax
 
@@ -1442,15 +1469,43 @@ class Server:
             # for seconds, and calls must be answered meanwhile
             try:
                 if started:
-                    await asyncio.to_thread(jax.profiler.stop_trace)
+                    stop = await self._stop_capture(jax.profiler.stop_trace)
+                    log.info(
+                        "profile capture '%s' (%d ms, python tracer %s): "
+                        "stop_trace took %.3f s on a worker thread and "
+                        "held the serving loop for %.1f ms at most "
+                        "(a forwarded batch's deadline: %.0f ms)",
+                        name, ms, python, stop["stop_s"],
+                        stop["loop_held_s"] * 1e3,
+                        self.conf.behaviors.effective_peer_timeout() * 1e3,
+                    )
             except Exception:
                 log.exception("stop_trace failed")
             finally:
                 self._profiling = False
         return web.json_response(
             {"trace_dir": out_dir, "captured_ms": ms,
-             "python": int(python), "python_from": python_from}
+             "python": int(python), "python_from": python_from, **stop}
         )
+
+    @staticmethod
+    async def _stop_capture(stop_trace) -> dict:
+        """`stop_trace` on a worker thread, and what it cost the serving
+        loop meanwhile: `stop_s`, the call's seconds, and `loop_held_s`,
+        how late at worst a 10 ms timer on this loop fired while the
+        call ran — the stop holds the interpreter lock in stretches (a
+        Python-tracer capture's for seconds: PR 43), and on a ring
+        member a stretch of half a second fails its peers' forwards."""
+        loop = asyncio.get_running_loop()
+        t0 = loop.time()
+        call = loop.run_in_executor(None, stop_trace)
+        held = 0.0
+        while not call.done():
+            due = loop.time() + 0.010
+            await asyncio.wait([call], timeout=0.010)
+            held = max(held, loop.time() - due)
+        call.result()
+        return {"stop_s": loop.time() - t0, "loop_held_s": held}
 
     # -- discovery ----------------------------------------------------------
 
@@ -1516,16 +1571,21 @@ async def run_daemon(conf: ServerConfig) -> None:
     log.info(
         "tracing: stage clock on (%d batch tiles from collect to "
         "resolve, /v1/debug/stages batch_coverage); thread CPU clock %s"
-        "%s, read at scrape only (thread_cpu_seconds_total)",
+        "%s, read at scrape only (thread_cpu_seconds_total); from Ready "
+        "on, loop pauses of %.0f ms or more (half a forwarded batch's "
+        "deadline) and programs built are counted",
         len(BATCH_TILES), clocks.source,
         f", observed granularity {step_s * 1e3:.4g} ms"
         if step_s is not None else "",
+        conf.behaviors.effective_peer_timeout() * 500,
     )
     log.info("Ready")
     # the stage clock starts at Ready: warm-up ran every rung through
     # the engine's dispatch, and its jit_call spans are compiles
     STAGES.reset()
-    probes = ProcessProbes(STAGES)
+    probes = server.probes = ProcessProbes(
+        STAGES, conf.behaviors.effective_peer_timeout() / 2
+    )
     probes.start()
     stop = asyncio.Event()
     graceful: list = []

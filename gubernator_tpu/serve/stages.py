@@ -562,15 +562,40 @@ class ProcessProbes:
 
     The GC callback only appends to a list, which the timer drains
     into the clock at its next tick: a collection runs on whichever
-    thread allocated last, and the clock's tables belong to threads."""
+    thread allocated last, and the clock's tables belong to threads.
+
+    Two plain ints beside the stages, exported lazily at scrape
+    (serve/server.py): `pauses_over` counts the ticks that saw the
+    serving loop held for `pause_s` or longer — the tick itself that
+    late, or a collection that long since the tick before (a
+    collection holds the interpreter whichever thread it runs on, so
+    it is the same pause: one tick counts once). `pause_s` is half of
+    what a peer gives a forwarded batch
+    (BehaviorConfig.effective_peer_timeout): a loop held that long on
+    an owner has spent half of every waiting forwarder's deadline, and
+    twice that fails a hit-carrying batch that is never sent again
+    (loop_pauses_over_half_deadline_total). `programs_built` counts the
+    programs XLA was handed since start() — compiled, or loaded from
+    the persistent cache: either way traced and lowered first, on a
+    serving thread, while a peer waits; the warm-up exists so that
+    none is (programs_built_after_ready_total). Counted by a
+    jax.monitoring listener, in a process that has JAX."""
 
     #: 20 Hz, not 100: each tick wakes the serving loop, and on the
     #: gRPC path a loop wake-up is not free (PERF.md, PR 24). A stall
     #: longer than a tick is always seen, a shorter one in proportion
     TICK_S = 0.050
 
-    def __init__(self, stats: "StageStats"):
+    #: the XLA hand-over of one program (jax._src.dispatch
+    #: BACKEND_COMPILE_EVENT): the "Finished XLA compilation" line of
+    #: JAX_LOG_COMPILES, cache hit or not
+    COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self, stats: "StageStats", pause_s: float = 0.25):
         self._stats = stats
+        self.pause_s = pause_s
+        self.pauses_over = 0
+        self.programs_built = 0
         self._loop = self._timer = None
         self._due = 0.0
         self._gc_t0 = 0.0
@@ -581,18 +606,26 @@ class ProcessProbes:
         self._loop = asyncio.get_running_loop()
         self._due = self._loop.time() + self.TICK_S
         self._timer = self._loop.call_at(self._due, self._tick)
+        monitoring = sys.modules.get("jax.monitoring")
+        if monitoring is not None:
+            monitoring.register_event_duration_secs_listener(self._on_event)
 
     def stop(self) -> None:
         gc.callbacks.remove(self._on_gc)
         self._timer.cancel()
         self._drain_gc()
+        monitoring = sys.modules.get("jax.monitoring")
+        if monitoring is not None:
+            monitoring.unregister_event_duration_listener(self._on_event)
 
     def _tick(self) -> None:
         # a bare timer callback, not a task that sleeps: the serving
         # loop pays for this twenty times a second
         now = self._loop.time()
-        self._stats.add("loop_lag", max(0.0, now - self._due))
-        self._drain_gc()
+        lag = max(0.0, now - self._due)
+        self._stats.add("loop_lag", lag)
+        if max(lag, self._drain_gc()) >= self.pause_s:
+            self.pauses_over += 1
         self._due = now + self.TICK_S
         self._timer = self._loop.call_at(self._due, self._tick)
 
@@ -602,10 +635,17 @@ class ProcessProbes:
         else:
             self._gc_pauses.append(time.monotonic() - self._gc_t0)
 
-    def _drain_gc(self) -> None:
+    def _drain_gc(self) -> float:
+        """The collections since the last tick into the clock; the
+        longest of them in seconds."""
         pauses, self._gc_pauses = self._gc_pauses, []
         for seconds in pauses:
             self._stats.add("gc_pause", seconds)
+        return max(pauses, default=0.0)
+
+    def _on_event(self, event: str, seconds: float, **_) -> None:
+        if event == self.COMPILE_EVENT:
+            self.programs_built += 1
 
 
 #: the serving threads by role. `loop` is the thread that reads (the

@@ -35,7 +35,14 @@ over one window of --seconds:
 - on a ring's door node (the harness's node 0), the forwarder's stages
   and `peer_forward_*` counters (`forwarder`), PR 41: run a ring cell
   with `--captures 0`, a capture on the door node stalls its forwards
-  past their deadline.
+  past their deadline;
+- with `--nodes 0,1,2,3` (PR 46: a ring whose clients dial every node,
+  `ring4-lb.geb-frames-all-doors`), the same `door_counters` and
+  `forwarder` for each listed node under `by_node`, beside its
+  `device_batch_rows_total{source}`, `device_batches_mixed_total`,
+  `peer_serve_*`, `loop_pauses_over_half_deadline_total` and
+  `programs_built_after_ready_total` by growth; node 0's stand at the
+  top as ever.
 
 Prints one JSON object; the whole of it, and the Python-tracer-off
 capture's .xplane.pb, go to chiprun_out/trace_study/. The parent never
@@ -242,8 +249,14 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, default=50.0)
     ap.add_argument("--captures", type=int, choices=(0, 1), default=1,
                     help="0: an undisturbed window (tiles, slow calls, lag)")
+    ap.add_argument("--nodes", default="0",
+                    help="a ring's nodes whose door_counters, forwarder and "
+                         "rows by source are printed under by_node, e.g. "
+                         "0,1,2,3 where clients dial every node (ring4-lb); "
+                         "node 0's stand at the top as ever")
     args = ap.parse_args()
     args.daemon_argv = ""
+    nodes = sorted({int(i) for i in args.nodes.split(",")})
     os.environ["GUBER_TRACE_SLOW_MS"] = str(SLOW_MS)
 
     cell = bench.load("cells", args.workload)
@@ -282,6 +295,11 @@ def main() -> int:
         threads0 = get_json(d, "/v1/debug/stages?reset=1").get("threads")
         get_json(d, "/v1/debug/traces?reset=1")
         prom0 = d.prom()
+        proms0 = {0: prom0}
+        for i in nodes:
+            if i:  # node 0's clock was reset and its counters read above
+                get_json(d.nodes[i], "/v1/debug/stages?reset=1")
+                proms0[i] = d.nodes[i].prom()
         poller = Poller(d, t0)
         poller.start()
         caps = {}
@@ -296,6 +314,20 @@ def main() -> int:
         poller.stop.set()
         stages = get_json(d, "/v1/debug/stages")
         prom1 = d.prom()
+        by_node = {}
+        for i in nodes:
+            st_i = (stages if i == 0
+                    else get_json(d.nodes[i], "/v1/debug/stages"))["stages"]
+            p1 = prom1 if i == 0 else d.nodes[i].prom()
+            by_node[i] = {
+                "door_counters": door_counters(proms0[i], p1),
+                "forwarder": forwarder(st_i, proms0[i], p1),
+                # PR 46: a batch's rows by who sent them, the owner
+                # side's counts, loop pauses and programs built
+                "owner_and_batches": grown(proms0[i], p1, (
+                    "device_batch_rows_", "device_batches_mixed_",
+                    "peer_serve_", "loop_pauses_", "programs_built_")),
+            }
         traces = get_json(d, "/v1/debug/traces?limit=4096")
         results = fleet.results(traffic["drain_timeout_s"])
         summary = kind.summarize(results, spec)
@@ -357,6 +389,7 @@ def main() -> int:
         batches=stages.get("batches"),
         door_counters=door_counters(prom0, prom1),
         forwarder=forwarder(st, prom0, prom1),
+        by_node=by_node,
         batch_tiles_us=batch_tiles(st),
         threads=thread_shares(threads0, stages.get("threads"),
                               stages.get("batches")),

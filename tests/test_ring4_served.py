@@ -30,6 +30,7 @@ clock are the process's, and the four nodes share them here.
 """
 
 import asyncio
+import contextlib
 import json
 import logging
 import os
@@ -164,11 +165,12 @@ def ring_ports(per_node: int):
     raise RuntimeError("no even ring in 200 draws of free ports")
 
 
-@pytest.fixture(scope="module")
-def ring():
+@contextlib.contextmanager
+def served_ring(**cluster_options):
     """Four daemons' worth of serving stack in a ring, each backend
     sized by the daemon's own rule from the deployment's environment,
-    every GEB door open, every request traced, the clock pinned at T0."""
+    every GEB door open, the clock pinned at T0: (the cluster, its GEB
+    doors)."""
     import gubernator_tpu.api.types as types_mod
     import gubernator_tpu.core.engine as engine_mod
 
@@ -182,7 +184,7 @@ def ring():
         [f"127.0.0.1:{p}" for p in ports[:NODES]],
         backend_factory=lambda: make_backend(conf),
         geb_ports=ports[NODES:], device_batch_limit=conf.device_batch_limit,
-        trace_sample=1.0,
+        **cluster_options,
     )
     cluster.start(timeout=900.0)
     for server in cluster.servers:
@@ -199,6 +201,13 @@ def ring():
     finally:
         cluster.stop()
         mp.undo()
+
+
+@pytest.fixture(scope="module")
+def ring():
+    """served_ring with every request traced."""
+    with served_ring(trace_sample=1.0) as ring:
+        yield ring
 
 
 def stage_counts():

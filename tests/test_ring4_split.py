@@ -821,3 +821,6 @@ def test_a_ring_members_profile_capture_leaves_the_python_tracer_off(ring):
     assert by_default["python_from"] == "default: a member of a shared ring"
     asked = capture("&python=1")
     assert (asked["python"], asked["python_from"]) == (1, "query")
+    # PR 46: the reply says what stopping the capture cost the loop
+    for reply in (by_default, asked):
+        assert 0 <= reply["loop_held_s"] <= reply["stop_s"] and reply["stop_s"] > 0
