@@ -11,6 +11,7 @@ cancellation-race handling live here so a fix lands in one place.
 from __future__ import annotations
 
 import asyncio
+import collections
 
 
 async def pop_with_deadline(queue: "asyncio.Queue", timeout: float):
@@ -45,6 +46,129 @@ async def pop_with_deadline(queue: "asyncio.Queue", timeout: float):
         else:
             getter.cancel()
         raise
+
+
+#: no source at all (None is one: the batcher's own doors)
+_NO_SOURCE = object()
+
+
+class SourceLanes(asyncio.Queue):
+    """The device batcher's queue: one FIFO lane a SOURCE (who enqueued
+    the group: `source(item)`), collected in turn — the head group of
+    each non-empty lane, least recently served lane first — so that
+    what one source has queued never stands between another source's
+    group and its launch. Upstream has no such wait — a peer's
+    GetPeerRateLimits runs on its own goroutine beside the node's own
+    callers (reference gubernator.go:210-225) — and in ONE
+    arrival-order queue a ring member that is asked at its own door
+    answers a peer's forwarded batch only behind its own door's whole
+    backlog.
+
+    Order inside a lane is arrival order. Within one launch (`collect`:
+    collect_batch under this queue's `limit` and `weight`) a lane whose
+    head no longer fits the room left is passed over while another
+    lane's head fits, and is never starved by it: the lanes stand in
+    the order they were last served, the front lane's head always rides
+    the launch it opens, and serving a lane moves it to the back, so a
+    group at the head of its lane launches within (lanes - 1) launches.
+    When NO head fits, get hands out the front lane's: collect_batch
+    parks it in its `carry`, which ends the launch, and `collect` puts
+    it back where it was, first of the next launch.
+
+    With ONE source queued this is asyncio.Queue, entry for entry: the
+    front lane is the only lane."""
+
+    def __init__(self, limit: int, weight, source):
+        self._limit, self._weight, self._source = limit, weight, source
+        super().__init__()
+
+    def _init(self, maxsize):
+        # source -> its groups, oldest first; the lanes in the order
+        # they were last served (a lane that empties is dropped, one
+        # that appears joins at the back)
+        self._queue = collections.OrderedDict()
+        # weight handed out in this launch: collect_batch's `total`
+        self._taken = 0
+        # the source whose lane opened the last launch
+        self._opened = _NO_SOURCE
+
+    def qsize(self) -> int:
+        return sum(len(lane) for lane in self._queue.values())
+
+    def queued(self) -> list:
+        """Every queued item, lane by lane."""
+        return [item for lane in self._queue.values() for item in lane]
+
+    def heads(self) -> list:
+        """Each non-empty lane's oldest item."""
+        return [lane[0] for lane in self._queue.values()]
+
+    def _lane(self, item):
+        """(the item's source, its lane: made, at the back, if need be)"""
+        src = self._source(item)
+        lane = self._queue.get(src)
+        if lane is None:
+            lane = self._queue[src] = collections.deque()
+        return src, lane
+
+    def _pop(self, src):
+        """The head of `src`'s lane; a lane that empties is dropped."""
+        lane = self._queue[src]
+        item = lane.popleft()
+        if not lane:
+            del self._queue[src]
+        return item
+
+    def _put(self, item):
+        self._lane(item)[1].append(item)
+
+    def _get(self):
+        lanes = self._queue
+        if self._taken:
+            room = self._limit - self._taken
+            for src, lane in lanes.items():
+                if self._weight(lane[0]) <= room:
+                    break
+            else:
+                # no head fits: the front lane's, for the collector to
+                # park (collect puts it back; the lanes keep their order)
+                return self._pop(next(iter(lanes)))
+        else:
+            # a launch opens with the front lane's head, whatever its
+            # weight — not with the lane that opened the last one while
+            # another waits (a lane that stood alone was served last
+            # AND is the front)
+            src = next(iter(lanes))
+            if src == self._opened and len(lanes) > 1:
+                lanes.move_to_end(src)
+                src = next(iter(lanes))
+            self._opened = src
+        self._taken += self._weight(lanes[src][0])
+        item = self._pop(src)
+        if src in lanes:
+            lanes.move_to_end(src)
+        return item
+
+    async def collect(self, into: list, wait: float, hold_while=None) -> list:
+        """One launch collected INTO the caller's list: collect_batch's
+        contract (first item blocks, what is queued drains, the `wait`
+        and `hold_while` windows, a cancel leaves `into` visible), the
+        lanes taken in turn. The group that did not fit goes back to
+        the head of its lane, and the next launch's turn is that
+        lane's; no await lies between its parking and here, so a
+        cancel never finds it outside the queue."""
+        self._taken = 0
+        carry: list = []
+        await collect_batch(
+            self, self._limit, wait, into,
+            weight=self._weight, carry=carry, hold_while=hold_while,
+        )
+        if carry:
+            item = carry.pop()
+            src, lane = self._lane(item)
+            lane.appendleft(item)
+            self._queue.move_to_end(src, last=False)
+        return into
 
 
 #: poll period of collect_batch's hold_while phase — how long after the

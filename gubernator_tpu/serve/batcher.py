@@ -72,7 +72,7 @@ import numpy as np
 from gubernator_tpu.api.types import RateLimitReq, RateLimitResp
 from gubernator_tpu.core.hashing import native_lib
 from gubernator_tpu.serve import metrics, tracing
-from gubernator_tpu.serve.aio import collect_batch
+from gubernator_tpu.serve.aio import SourceLanes
 from gubernator_tpu.serve.faults import FAULTS, FaultError
 from gubernator_tpu.serve.stages import STAGES, claim_call
 
@@ -91,11 +91,13 @@ class _QMeta:
     those names in the caller's trace too, so the tiles have their
     call_e2e and a JSON-door, peer-loop or internal group records
     none. `t_done` is the instant the flusher resolved the group's
-    future: call_device ends there and call_wake begins. `peer` says
-    whose rows the group holds: a peer's forward (enqueued under
-    peer_rows(), Instance.get_peer_rate_limits) or, for every other
+    future: call_device ends there and call_wake begins. `peer` is the
+    group's SOURCE: the peer whose forward it is (enqueued under
+    peer_rows(sender), Instance.get_peer_rate_limits: the sender's
+    address as its PeersV1 call gave it) or None for every other
     caller, this node's own doors' — a row's source rides the entry
-    that queued it, and a batch is counted by its groups' lengths."""
+    that queued it: a batch is counted by its groups' lengths, and the
+    queue keeps one lane a source (aio.SourceLanes)."""
 
     __slots__ = ("t", "frame", "call", "trace", "t_done", "peer")
 
@@ -108,18 +110,22 @@ class _QMeta:
         self.peer = _PEER_ROWS.get()
 
 
-#: set around the owner side's serving of one forwarded batch: what is
-#: enqueued under it is counted as rows a PEER sent
-#: (device_batch_rows_total{source="peer"}), everything else as rows of
-#: this node's own doors
-_PEER_ROWS: "contextvars.ContextVar[bool]" = contextvars.ContextVar(
-    "guber_batch_peer_rows", default=False
+#: set around the owner side's serving of one forwarded batch, to who
+#: sent it: what is enqueued under it is that PEER's — its lane of the
+#: queue, and rows counted as device_batch_rows_total{source="peer"} —
+#: everything else (None) this node's own doors'
+_PEER_ROWS: "contextvars.ContextVar[Optional[str]]" = contextvars.ContextVar(
+    "guber_batch_peer_rows", default=None
 )
 
 
 @contextlib.contextmanager
-def peer_rows():
-    token = _PEER_ROWS.set(True)
+def peer_rows(sender: Optional[str] = None):
+    """`sender` names the forwarding peer (the PeersV1 door holds this
+    around a call, with the call's peer address); without one the
+    sender already named stands, and where none is — a caller that
+    cannot tell its peers apart — the one shared source "peer"."""
+    token = _PEER_ROWS.set(sender or _PEER_ROWS.get() or "peer")
     try:
         yield
     finally:
@@ -174,6 +180,10 @@ def _item_weight(item) -> int:
     return max(1, len(item[1]))
 
 
+def _item_source(item):
+    return item[-2].peer
+
+
 class DeviceBatcher:
     def __init__(
         self,
@@ -197,7 +207,10 @@ class DeviceBatcher:
         # hold predicate is False whenever a pipeline slot is free.
         self.deep_batch = bool(deep_batch)
         self.fetch_depth = max(1, int(fetch_depth))
-        self._queue: "asyncio.Queue" = asyncio.Queue()
+        # one FIFO lane a source (this node's own doors, each peer that
+        # forwards to it), collected in turn: with one source queued,
+        # one arrival-order queue
+        self._queue = SourceLanes(batch_limit, _item_weight, _item_source)
         self._task: Optional[asyncio.Task] = None
         # in-flight fetches of submitted batches (device backends
         # only); each task resolves its own batch's futures. The
@@ -235,6 +248,10 @@ class DeviceBatcher:
         # door + peer = device_batch_size_sum by construction
         self.rows_by_source = {"door": 0, "peer": 0}
         self.mixed_batches = 0
+        # groups launched while an OLDER group of another source stayed
+        # queued: the turn-taking between sources at work, 0 wherever
+        # one source feeds the batcher (device_groups_overtaking_total)
+        self.groups_overtaking = 0
         # set before the flusher is cancelled: a decide()/update_globals()
         # after stop() would otherwise enqueue into a queue no flusher
         # reads and await a future that never resolves (same guard as
@@ -287,9 +304,6 @@ class DeviceBatcher:
         )
         self._flushing = False
         self._live_batch: List = []
-        # one-slot park for a group that would have pushed the previous
-        # batch past batch_limit (aio.collect_batch carry contract)
-        self._carry: List = []
 
     def start(self) -> None:
         if self._task is None:
@@ -325,7 +339,6 @@ class DeviceBatcher:
         while (
             not self._queue.empty()
             or self._live_batch
-            or self._carry
             or self._flushing
             or self._pending
         ):
@@ -348,7 +361,6 @@ class DeviceBatcher:
             self._inline
             and not self._flushing
             and not self._live_batch
-            and not self._carry
             and self._queue.empty()
             and self._task is not None
         ):
@@ -364,7 +376,7 @@ class DeviceBatcher:
                 )
             self._observe_batch(
                 len(resps), time.monotonic() - t0,
-                len(resps) if _PEER_ROWS.get() else 0,
+                len(resps) if _PEER_ROWS.get() is not None else 0,
             )
             return resps
         # one queue item + ONE future per caller (an RPC's whole request
@@ -509,15 +521,11 @@ class DeviceBatcher:
     def queue_stats(self) -> dict:
         """Standing-work snapshot for the lazily-set scrape gauges
         (serve/metrics.py batcher_queue_*): depth counts caller groups
-        queued + collected-but-unflushed + parked carry; oldest age
-        reads the _QMeta enqueue stamps. Runs on the serving loop (the
-        /metrics handler), so the peek at the queue's internal deque
-        cannot race an enqueue."""
-        items = (
-            list(getattr(self._queue, "_queue", ()))
-            + self._live_batch
-            + self._carry
-        )
+        queued in every source's lane + collected-but-unflushed; oldest
+        age reads the _QMeta enqueue stamps. Runs on the serving loop
+        (the /metrics handler), so the peek at the lanes cannot race an
+        enqueue."""
+        items = self._queue.queued() + self._live_batch
         oldest = min((it[-2].t for it in items), default=None)
         prep_backlog = 0
         if self._prep_pool is not None:
@@ -598,13 +606,13 @@ class DeviceBatcher:
                 # the try (and is cancellation-race-safe, serve/aio.py):
                 # a cancel must reach the drain handler below with every
                 # collected item visible, or a caller would hang.
-                await collect_batch(
-                    self._queue, self.batch_limit, self.batch_wait, batch,
-                    weight=_item_weight, carry=self._carry,
+                await self._queue.collect(
+                    batch, self.batch_wait,
                     hold_while=(
                         self._inflight.locked if self.deep_batch else None
                     ),
                 )
+                self._note_overtaking(batch)
                 self._flushing = True
                 try:
                     await self._flush(batch)
@@ -618,14 +626,25 @@ class DeviceBatcher:
                 # have done futures, which _fail skips).
                 exc = RuntimeError("batcher stopped mid-batch")
                 self._fail(batch, exc)
-                self._fail(self._carry, exc)  # parked overflow group
-                self._carry.clear()
                 while True:
                     try:
                         self._fail([self._queue.get_nowait()], exc)
                     except asyncio.QueueEmpty:
                         break
                 raise
+
+    def _note_overtaking(self, batch) -> None:
+        """Count the groups of this launch that are YOUNGER than a
+        group of another source still queued: what arrival order would
+        have launched the other way round."""
+        if self._queue.empty():
+            return
+        waiting = [(h[-2].peer, h[-2].t) for h in self._queue.heads()]
+        for it in batch:
+            m = it[-2]
+            self.groups_overtaking += any(
+                t < m.t and src != m.peer for src, t in waiting
+            )
 
     async def _flush(self, batch) -> None:
         if FAULTS.enabled:
@@ -753,7 +772,7 @@ class DeviceBatcher:
                 )
                 self._observe_batch(
                     len(resps), time.monotonic() - t0c,
-                    self._peer_rows(chain_items),
+                    *self._by_source(chain_items),
                 )
 
         if not decide_items:
@@ -921,7 +940,7 @@ class DeviceBatcher:
         )
         self._observe_batch(
             k, submit_s + (time.monotonic() - t1),
-            self._peer_rows(decide_items),
+            *self._by_source(decide_items),
         )
 
     def _fail(self, items, exc: BaseException) -> None:
@@ -944,7 +963,7 @@ class DeviceBatcher:
             if not fut.done():
                 fut.set_result(span)
         self._observe_batch(
-            len(resps), launch_s, self._peer_rows(decide_items)
+            len(resps), launch_s, *self._by_source(decide_items)
         )
 
     @staticmethod
@@ -995,22 +1014,30 @@ class DeviceBatcher:
         return now
 
     @staticmethod
-    def _peer_rows(items) -> int:
-        """Of one batch's rows, those its peers forwarded: the lengths
-        of the groups whose queue entry says so (_QMeta.peer)."""
-        return sum(_group_rows(it) for it in items if it[-2].peer)
+    def _by_source(items) -> Tuple[int, int]:
+        """Of one batch's rows, those its peers forwarded — the lengths
+        of the groups whose queue entry names a peer (_QMeta.peer) —
+        and the distinct sources its groups came from."""
+        return (
+            sum(_group_rows(it) for it in items if it[-2].peer is not None),
+            len({it[-2].peer for it in items}),
+        )
 
-    def _observe_batch(self, n: int, launch_s: float, peer: int) -> None:
-        """One device batch of n rows, `peer` of them forwarded by
-        peers and the rest from this node's own doors, launched at its
-        padding rung: useful rows over attempted slots is
-        device_batch_size_sum / device_batch_slots_total. Best-effort:
-        metrics must never be able to kill the flusher task."""
+    def _observe_batch(
+        self, n: int, launch_s: float, peer: int, sources: int = 1
+    ) -> None:
+        """One device batch of n rows from `sources` distinct sources,
+        `peer` of the rows forwarded by peers and the rest from this
+        node's own doors, launched at its padding rung: useful rows
+        over attempted slots is device_batch_size_sum /
+        device_batch_slots_total. Best-effort: metrics must never be
+        able to kill the flusher task."""
         self.rows_by_source["peer"] += peer
         self.rows_by_source["door"] += n - peer
         self.mixed_batches += 0 < peer < n
         try:
             metrics.DEVICE_BATCH_SIZE.observe(n)
+            metrics.DEVICE_BATCH_SOURCES.observe(sources)
             metrics.DEVICE_BATCH_SLOTS.inc(self._rung(n))
             metrics.DEVICE_LAUNCH_MS.observe(launch_s * 1e3)
             self._observe_cache_stats()

@@ -15,6 +15,7 @@ from prometheus_client import (
     Counter,
     Gauge,
     Histogram,
+    Summary,
     generate_latest,
 )
 
@@ -87,6 +88,24 @@ DEVICE_BATCHES_MIXED = Gauge(
     "device_batch_size_count = their share. 0 on a node that owns "
     "every key, and on a ring member that is asked at no door of its "
     "own",
+    registry=REGISTRY,
+)
+DEVICE_GROUPS_OVERTAKING = Gauge(
+    "device_groups_overtaking_total",
+    "Caller groups launched while an OLDER group of another source "
+    "(this node's own doors, or one forwarding peer) stayed queued: "
+    "the batcher collects the head group of each source's lane in turn "
+    "(serve/aio.py SourceLanes), so an owner that is a busy door "
+    "answers its peers without their waiting out its own backlog. 0 "
+    "wherever one source feeds the batcher: the order is arrival order "
+    "there. A plain int exported lazily at scrape",
+    registry=REGISTRY,
+)
+DEVICE_BATCH_SOURCES = Summary(
+    "device_batch_sources",
+    "Distinct sources (this node's own doors; each forwarding peer) "
+    "whose groups one device batch merged, summed and counted: "
+    "_sum / _count is 1.0 wherever one source feeds the batcher",
     registry=REGISTRY,
 )
 MESH_SHARD_ROWS = Counter(

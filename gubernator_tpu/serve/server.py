@@ -27,6 +27,7 @@ from gubernator_tpu.api.grpc_glue import add_peers_servicer, add_v1_servicer
 from gubernator_tpu.api.proto.gen import gubernator_pb2, peers_pb2
 from gubernator_tpu.core.hashing import native_lib
 from gubernator_tpu.serve import metrics, tracing
+from gubernator_tpu.serve.batcher import peer_rows
 from gubernator_tpu.serve.backends import (
     ExactBackend,
     MeshBackend,
@@ -376,21 +377,25 @@ class PeersV1Servicer:
         # `wire` is the serialised GetPeerRateLimitsReq
         # (api/grpc_glue.py registers the method pass-through): the
         # fold serves it as arrays where it can, and what it declines
-        # is parsed here and served through request objects
-        out = await _serve_folded(self.instance, context, wire)
-        if out is not None:
-            return out
-        try:
-            request = peers_pb2.GetPeerRateLimitsReq.FromString(wire)
-        except DecodeError:
-            # what grpc answers when its own deserializer raises
-            await context.abort(
-                grpc.StatusCode.INTERNAL, "Exception deserializing request!"
+        # is parsed here and served through request objects. What the
+        # call enqueues on the batcher is THIS sender's: its lane of
+        # the queue, collected in turn with this node's own doors'
+        with peer_rows(context.peer()):
+            out = await _serve_folded(self.instance, context, wire)
+            if out is not None:
+                return out
+            try:
+                request = peers_pb2.GetPeerRateLimitsReq.FromString(wire)
+            except DecodeError:
+                # what grpc answers when its own deserializer raises
+                await context.abort(
+                    grpc.StatusCode.INTERNAL,
+                    "Exception deserializing request!",
+                )
+            return await _serve_call(
+                self.instance, "peers", context, request.requests,
+                self.instance.get_peer_rate_limits, _peers_reply,
             )
-        return await _serve_call(
-            self.instance, "peers", context, request.requests,
-            self.instance.get_peer_rate_limits, _peers_reply,
-        )
 
     async def UpdatePeerGlobals(self, request, context):
         updates = [
@@ -1185,6 +1190,7 @@ class Server:
         for source, rows in batcher.rows_by_source.items():
             metrics.DEVICE_BATCH_ROWS.labels(source=source).set(rows)
         metrics.DEVICE_BATCHES_MIXED.set(batcher.mixed_batches)
+        metrics.DEVICE_GROUPS_OVERTAKING.set(batcher.groups_overtaking)
         fwd = self.instance.peer_forward
         metrics.PEER_FORWARD_BATCHES.set(fwd.batches)
         metrics.PEER_FORWARD_ITEMS.set(fwd.items)
@@ -1312,7 +1318,8 @@ class Server:
         # not touch them)
         batcher, probes = self.instance.batcher, self.probes
         body["batch_rows"] = dict(
-            batcher.rows_by_source, mixed_batches=batcher.mixed_batches
+            batcher.rows_by_source, mixed_batches=batcher.mixed_batches,
+            groups_overtaking=batcher.groups_overtaking,
         )
         if probes is not None:
             body["process"] = {

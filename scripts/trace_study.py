@@ -42,7 +42,13 @@ over one window of --seconds:
   `device_batch_rows_total{source}`, `device_batches_mixed_total`,
   `peer_serve_*`, `loop_pauses_over_half_deadline_total` and
   `programs_built_after_ready_total` by growth; node 0's stand at the
-  top as ever.
+  top as ever. Since PR 47 each node's own stage means and counts,
+  device batches and frames a second and thread CPU shares too (the
+  node whose door takes the ring is not always node 0: its
+  `call_queue` beside its `batch_queue` says whether a peer's batch
+  waits out the node's own backlog), and in `door_counters` the
+  batcher's `device_groups_overtaking_total` and
+  `device_batch_sources_sum` / `_count`.
 
 Prints one JSON object; the whole of it, and the Python-tracer-off
 capture's .xplane.pb, go to chiprun_out/trace_study/. The parent never
@@ -179,11 +185,13 @@ def gap_threads(profile_dir):
 
 
 def grown(prom0, prom1, prefixes):
-    """Growth of every `*_total` series of /metrics that starts with one
-    of `prefixes` between two scrapes, series that stood still left out."""
+    """Growth of every `*_total` series of /metrics (and every summed
+    and counted one, `*_sum` / `*_count`) that starts with one of
+    `prefixes` between two scrapes, series that stood still left out."""
     return {
         k: v - prom0.get(k, 0.0) for k, v in sorted(prom1.items())
-        if k.startswith(prefixes) and "_total" in k
+        if k.startswith(prefixes)
+        and ("_total" in k or k.endswith(("_sum", "_count")))
         and v != prom0.get(k, 0.0)
     }
 
@@ -193,9 +201,14 @@ def door_counters(prom0, prom1):
     `edge_split_*_total` among them), the traffic observers'
     `traffic_*_total` and the mesh's `mesh_*_total` (PR 44: who laid
     the merged batches out per shard, `mesh_native_stacks_total` /
-    `mesh_numpy_stacks_total`, beside the shard rows and slots) over
-    the window."""
-    return grown(prom0, prom1, ("edge_", "traffic_", "mesh_"))
+    `mesh_numpy_stacks_total`, beside the shard rows and slots) and
+    the batcher's turn-taking between sources (PR 47:
+    `device_groups_overtaking_total`, `device_batch_sources_sum` /
+    `_count`: 0 and 1.0 a batch wherever one source feeds it) over the
+    window."""
+    return grown(prom0, prom1, (
+        "edge_", "traffic_", "mesh_", "device_groups_overtaking_",
+        "device_batch_sources_"))
 
 
 def forwarder(st, prom0, prom1):
@@ -295,10 +308,11 @@ def main() -> int:
         threads0 = get_json(d, "/v1/debug/stages?reset=1").get("threads")
         get_json(d, "/v1/debug/traces?reset=1")
         prom0 = d.prom()
-        proms0 = {0: prom0}
+        proms0, threads0_by = {0: prom0}, {0: threads0}
         for i in nodes:
             if i:  # node 0's clock was reset and its counters read above
-                get_json(d.nodes[i], "/v1/debug/stages?reset=1")
+                threads0_by[i] = get_json(
+                    d.nodes[i], "/v1/debug/stages?reset=1").get("threads")
                 proms0[i] = d.nodes[i].prom()
         poller = Poller(d, t0)
         poller.start()
@@ -316,16 +330,28 @@ def main() -> int:
         prom1 = d.prom()
         by_node = {}
         for i in nodes:
-            st_i = (stages if i == 0
-                    else get_json(d.nodes[i], "/v1/debug/stages"))["stages"]
+            snap_i = (stages if i == 0
+                      else get_json(d.nodes[i], "/v1/debug/stages"))
+            st_i = snap_i["stages"]
             p1 = prom1 if i == 0 else d.nodes[i].prom()
             by_node[i] = {
+                # PR 47: each node's own stage clock and thread CPU (the
+                # winner's call_queue is not node 0's): mean ms and
+                # samples a stage, device batches and frames a second
+                "stage_means_ms": {k: v["mean_ms"] for k, v in st_i.items()},
+                "stage_counts": {k: v["count"] for k, v in st_i.items()},
+                "batches_per_s": (snap_i.get("batches") or 0) / args.seconds,
+                "frames_per_s": (snap_i.get("frames") or 0) / args.seconds,
+                "threads": thread_shares(
+                    threads0_by[i], snap_i.get("threads"),
+                    snap_i.get("batches")),
                 "door_counters": door_counters(proms0[i], p1),
                 "forwarder": forwarder(st_i, proms0[i], p1),
                 # PR 46: a batch's rows by who sent them, the owner
                 # side's counts, loop pauses and programs built
                 "owner_and_batches": grown(proms0[i], p1, (
                     "device_batch_rows_", "device_batches_mixed_",
+                    "device_batch_size_",
                     "peer_serve_", "loop_pauses_", "programs_built_")),
             }
         traces = get_json(d, "/v1/debug/traces?limit=4096")

@@ -19,6 +19,9 @@ the clients AND the owner of a quarter of the keys.
   two sum to `device_batch_size_sum`, and a batch carried both;
 - every frame was split by owner as columns: no frame declined, no
   item on the object path;
+- an owner whose own door has 12 frames queued answers a peer's batch
+  in turn, before that backlog has drained (PR 47: the batcher keeps a
+  lane a source), and the answers are still one limiter's;
 - with every forward TO one owner hung past the deadline
   (`GUBER_FAULT_SPEC peer_rpc:hang:host=…`) while that owner is asked
   at its own door too: error items at the other three doors for its
@@ -112,30 +115,36 @@ def frames_by_door(seed: int, per_door: int, tag: str, size: int = FRAME):
     return out
 
 
-def at_all_doors(doors, frames_of_door, late=()):
+def at_all_doors(doors, frames_of_door, late=(), window=IN_FLIGHT, done_at=None):
     """Every door's frames sent at once (the doors in `late` 60 ms
     after the others), each door's over its own GEB connection as
-    STRING frames, IN_FLIGHT of them outstanding a door; {door index:
-    [[(status, limit, remaining, error)]]} in each door's own order."""
+    STRING frames, `window` of them outstanding a door; {door index:
+    [[(status, limit, remaining, error)]]} in each door's own order.
+    `done_at`, a dict, is handed the instant each frame's reply was
+    read: {(door, frame index): monotonic seconds}."""
 
     async def run():
         from gubernator_tpu.client_geb import AsyncGebClient
 
-        clients = {d: AsyncGebClient(doors[d], mode="string", window=IN_FLIGHT)
+        clients = {d: AsyncGebClient(doors[d], mode="string", window=window)
                    for d in frames_of_door}
         for c in clients.values():
             await c.connect()
 
-        async def one(d, frame):
+        async def one(d, n, frame):
             if d in late:
                 await asyncio.sleep(0.06)
             resps = await clients[d].get_rate_limits(
                 [to_req(it) for it in frame], timeout=120.0)
+            if done_at is not None:
+                done_at[d, n] = time.monotonic()
             return [(int(r.status), r.limit, r.remaining, r.error) for r in resps]
 
         try:
             flat = [(d, fr) for d, frames in frames_of_door.items() for fr in frames]
-            got = await asyncio.gather(*(one(d, fr) for d, fr in flat))
+            got = await asyncio.gather(*(
+                one(d, n, fr) for d, frames in frames_of_door.items()
+                for n, fr in enumerate(frames)))
         finally:
             for c in clients.values():
                 await c.close()
@@ -165,6 +174,7 @@ def node_counts(cluster):
             "door_rows": inst.batcher.rows_by_source["door"],
             "peer_rows": inst.batcher.rows_by_source["peer"],
             "mixed": inst.batcher.mixed_batches,
+            "overtaking": inst.batcher.groups_overtaking,
         })
     return out
 
@@ -175,6 +185,26 @@ def grew(after, before, key):
 
 def sample(name, labels=None):
     return REGISTRY.get_sample_value(name, labels or {}) or 0.0
+
+
+def one_limiter_answers(by_door, got, want):
+    """Per key, the multiset of answers and the hits admitted over
+    every door's replies equal the reference's summaries `want`, and
+    no item is an error: (the answers by key, a key's item)."""
+    answers, admitted, shape = {}, Counter(), {}
+    for d, frames in by_door.items():
+        for frame, replies in zip(frames, got[d]):
+            assert len(replies) == len(frame)
+            for it, a in zip(frame, replies):
+                assert a[3] == "", (d, it, a)  # no error item
+                answers.setdefault(it[0], Counter())[a[:3]] += 1
+                admitted[it[0]] += a[0] == 0
+                shape[it[0]] = it
+    assert set(answers) == set(want)
+    differ = [(k, answers[k], want[k][0]) for k in want
+              if answers[k] != want[k][0] or admitted[k] != want[k][1]]
+    assert not differ, differ[:3]
+    return answers, shape
 
 
 @pytest.mark.parametrize("seed", [46, 2**31 + 46])
@@ -206,19 +236,7 @@ def test_frames_at_four_doors_at_once_are_one_limiter_and_every_node_both(ring, 
     after, size1 = node_counts(cluster), sample("device_batch_size_sum")
 
     # (a) per key: the multiset of answers and the hits admitted
-    answers, admitted, shape = {}, Counter(), {}
-    for d, frames in by_door.items():
-        for frame, replies in zip(frames, got[d]):
-            assert len(replies) == FRAME
-            for it, a in zip(frame, replies):
-                assert a[3] == "", (d, it, a)  # no error item
-                answers.setdefault(it[0], Counter())[a[:3]] += 1
-                admitted[it[0]] += a[0] == 0
-                shape[it[0]] = it
-    assert set(answers) == set(want)
-    differ = [(k, answers[k], want[k][0]) for k in want
-              if answers[k] != want[k][0] or admitted[k] != want[k][1]]
-    assert not differ, differ[:3]
+    answers, shape = one_limiter_answers(by_door, got, want)
     over = sum(n for c in answers.values() for a, n in c.items() if a[0] == 1)
     leaky = sum(it[4] == 1 for it in shape.values())
     shared = sum(len({d for d, frames in by_door.items()
@@ -299,12 +317,57 @@ def test_a_batch_carries_both_sources_and_the_scrape_exports_the_counts(ring):
         assert REGISTRY.get_sample_value(
             "programs_built_after_ready_total") == probes.programs_built
         text = cluster.run(_stages_body(server))
+        assert REGISTRY.get_sample_value(
+            "device_groups_overtaking_total") == mine["overtaking"]
         assert text["batch_rows"] == {"door": mine["door_rows"],
                                       "peer": mine["peer_rows"],
-                                      "mixed_batches": mine["mixed"]}
+                                      "mixed_batches": mine["mixed"],
+                                      "groups_overtaking": mine["overtaking"]}
         assert text["process"]["pause_threshold_s"] == DEADLINE_S / 2
     # the warm-up built every program the traffic above needed
     assert probes.programs_built == 0
+
+
+@pytest.mark.parametrize("seed", [46, 2**31 + 46])
+def test_a_busy_door_answers_a_peers_batch_before_its_own_backlog_drains(ring, seed):
+    """One node's own door holds 12 frames in flight, every flush held
+    for 0.15 s (`device_submit:delay`, a slow device) so that their
+    owned rows queue on its batcher; 60 ms later the three other doors
+    send one frame each, a quarter of it that node's keys. Their
+    forwarded batches ride the busy node's next launches in turn — the
+    frames at the other doors are answered before the busy door's
+    last-queued frame is — and per key the answers are still ONE
+    limiter's, whatever order the owners saw."""
+    cluster, doors, _ = ring
+    busy, tag = seed % NODES, f"turn{seed}-"
+    backlog = frames_by_door(seed, 12, tag)[busy]
+    others = [d for d in range(NODES) if d != busy]
+    single = frames_by_door(seed + 1, 1, tag)
+    by_door = {busy: backlog, **{d: single[d] for d in others}}
+    peers = cluster.addresses
+    calls = {peers[d]: frames for d, frames in by_door.items()}
+    order = reference_ring4_doors.round_robin(calls)
+    assert reference_ring4_doors.same_as_one_limiter(calls, peers, order, T0, NAME)
+    want = reference_ring4_doors.key_summaries(calls, peers, order, T0, NAME)
+
+    before, done_at = node_counts(cluster), {}
+    FAULTS.configure("device_submit:delay=150ms")
+    try:
+        got = at_all_doors(doors, by_door, late=others, window=12, done_at=done_at)
+    finally:
+        FAULTS.clear()
+    after = node_counts(cluster)
+    one_limiter_answers(by_door, got, want)
+    # every other door's frame needed the busy owner's answer, and had
+    # it before the busy door's own backlog was through
+    assert grew(after, before, "served_batches")[busy] >= len(others)
+    last_own = max(t for (d, _), t in done_at.items() if d == busy)
+    assert all(done_at[d, 0] < last_own for d in others), done_at
+    # on the busy node the peers' groups were launched while older
+    # groups of its own door stayed queued; arrival order alone has
+    # nothing to overtake
+    assert grew(after, before, "overtaking")[busy] >= 1
+    assert all(a["failed"] == b["failed"] for a, b in zip(after, before))
 
 
 async def _stages_body(server):
@@ -437,10 +500,17 @@ def test_peer_rows_ride_the_queue_entry_not_the_rows():
     clear outside, and gone with the block."""
     from gubernator_tpu.serve import batcher
 
-    assert batcher._QMeta(False).peer is False
+    assert batcher._QMeta(False).peer is None
     with batcher.peer_rows():
-        assert batcher._QMeta(False).peer is True and batcher._QMeta(True).peer is True
-    assert batcher._QMeta(True).peer is False
+        # a caller that cannot name its peer: the one shared source
+        assert batcher._QMeta(False).peer == batcher._QMeta(True).peer == "peer"
+    with batcher.peer_rows("ipv4:10.0.0.7:4242"):
+        # the PeersV1 door names the sender, and the instance's own
+        # peer_rows() inside it keeps that name
+        with batcher.peer_rows():
+            assert batcher._QMeta(False).peer == "ipv4:10.0.0.7:4242"
+        assert batcher._QMeta(True).peer == "ipv4:10.0.0.7:4242"
+    assert batcher._QMeta(True).peer is None
 
 
 @pytest.mark.parametrize("door,source", [("v1", "door"), ("peers", "peer")])
