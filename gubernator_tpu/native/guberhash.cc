@@ -2226,3 +2226,247 @@ int64_t guber_hotkeys_export(const void* hot, int64_t* counts,
 }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------------
+// The shed cache's array consult and population (serve/shedcache.py
+// ShedCache.screen_fields / observe_fields): what the serving loop does
+// to a thousand-row frame on each side of its wait for the device, as ONE
+// call each with the GIL released. The numpy bodies there (_screen_numpy,
+// _observe_numpy) are some 45 array calls a frame, each long enough to
+// give the GIL up, and the loop then waits for the submit, fetch and prep
+// threads to hand it back; they stay as the form a host without this
+// library runs and as the oracle (tests/test_shed_cache.py holds the two
+// to each other row for row). The cache's dictionary, its LRU order and
+// every write to a slot stay in Python: nothing here writes to the cache.
+// ---------------------------------------------------------------------------
+
+namespace {
+
+// The cache's lookup structure as ShedCache._find reads it: the sorted
+// index of the fingerprints live at the last sort with their slots, the
+// sorted overlay of those bound since, and the slots' own fingerprint
+// column.
+struct ShedIndex {
+  const uint64_t* ix_fp;
+  const int64_t* ix_slot;
+  int64_t m;
+  const uint64_t* ov_fp;
+  const int64_t* ov_slot;
+  int64_t k;
+  const uint64_t* fp;
+
+  // How many fingerprints find() takes at once.
+  static constexpr int64_t BLOCK = 16;
+
+  // For each of `count` (at most BLOCK) fingerprints the slot that
+  // holds it, or -1: the index row at or after it (clamped to the
+  // last), overridden by the overlay's LAST binding of the fingerprint
+  // (stable sort, so the upper bound less one), proven by the slot's
+  // own fingerprint - a stale index row is refused here. The slot may
+  // be a dropped one (reset_time 0). The searches of a block descend
+  // together, a level at a time and without a branch on the keys, so
+  // their cache misses overlap: one search alone in a 30,000-row index
+  // is ~150 ns of mispredicted branches and waits, a thousand of them
+  // what the numpy searchsorted took.
+  void find(const uint64_t* kh, int64_t count, int64_t* slot) const {
+    int64_t at[BLOCK];
+    for (int64_t b = 0; b < count; ++b) slot[b] = -1;
+    if (m > 0) {
+      // lower bound: the first row whose fingerprint is >= kh
+      for (int64_t b = 0; b < count; ++b) at[b] = 0;
+      int64_t len = m;
+      for (; len > 1; len -= len / 2) {
+        const int64_t half = len / 2;
+        for (int64_t b = 0; b < count; ++b)
+          at[b] += ix_fp[at[b] + half - 1] < kh[b] ? half : 0;
+      }
+      for (int64_t b = 0; b < count; ++b) {
+        const int64_t pos = at[b] + (ix_fp[at[b]] < kh[b]);
+        slot[b] = ix_slot[pos < m ? pos : m - 1];
+      }
+    }
+    if (k > 0) {
+      // upper bound: the first row whose fingerprint is > kh
+      for (int64_t b = 0; b < count; ++b) at[b] = 0;
+      int64_t len = k;
+      for (; len > 1; len -= len / 2) {
+        const int64_t half = len / 2;
+        for (int64_t b = 0; b < count; ++b)
+          at[b] += ov_fp[at[b] + half - 1] <= kh[b] ? half : 0;
+      }
+      for (int64_t b = 0; b < count; ++b) {
+        const int64_t j = at[b] + (ov_fp[at[b]] <= kh[b]) - 1;
+        if (j >= 0 && ov_fp[j] == kh[b]) slot[b] = ov_slot[j];
+      }
+    }
+    for (int64_t b = 0; b < count; ++b)
+      if (slot[b] >= 0 && fp[slot[b]] != kh[b]) slot[b] = -1;
+  }
+};
+
+// One result column as the batcher hands it on: int32 from the device's
+// packed answers, int64 from a peer's reply.
+struct ResultColumn {
+  const void* at;
+  int64_t width;  // bytes an element: 4 or 8
+  int64_t operator[](int64_t i) const {
+    return width == 4 ? static_cast<const int32_t*>(at)[i]
+                      : static_cast<const int64_t*>(at)[i];
+  }
+};
+
+constexpr int32_t SHED_ALGO_TOKEN = 0;   // api/types.py Algorithm
+constexpr int64_t SHED_OVER_LIMIT = 1;   // api/types.py Status
+
+}  // namespace
+
+extern "C" {
+
+// screen_fields over one frame of n rows. A row is shed when its
+// fingerprint holds a live slot (find), it is a token-bucket request
+// that carries hits and is no replica read (`gnp`, which may be null),
+// its limit and duration are the slot's, and now < the slot's
+// reset_time. Writes mask[n] (1 = shed) and into `out`, nine int64
+// rows of n: the answers status / limit / remaining / reset_time with
+// the shed rows filled and the others zero, then for the rows NOT shed,
+// in frame order, their indices and their key_hash (the uint64's bits)
+// / hits / limit / duration; their algo and gnp go to r_algo and r_gnp.
+// Returns the rows shed, and through `eligible` the rows that were
+// token-bucket, hit-carrying and no replica read.
+int64_t guber_shed_screen(
+    const uint64_t* kh, const int64_t* hits, const int64_t* limit,
+    const int64_t* duration, const int32_t* algo, const uint8_t* gnp,
+    int64_t n, int64_t now,
+    const uint64_t* ix_fp, const int64_t* ix_slot, int64_t m,
+    const uint64_t* ov_fp, const int64_t* ov_slot, int64_t k,
+    const uint64_t* fp, const int64_t* lim, const int64_t* dur,
+    const int64_t* reset,
+    uint8_t* mask, int64_t* out, int32_t* r_algo, uint8_t* r_gnp,
+    int64_t* eligible) {
+  const ShedIndex index{ix_fp, ix_slot, m, ov_fp, ov_slot, k, fp};
+  int64_t* const status = out;
+  int64_t* const limit_o = out + n;
+  int64_t* const remaining = out + 2 * n;
+  int64_t* const reset_o = out + 3 * n;
+  int64_t* const keep = out + 4 * n;
+  int64_t* const r_kh = out + 5 * n;
+  int64_t* const r_hits = out + 6 * n;
+  int64_t* const r_limit = out + 7 * n;
+  int64_t* const r_duration = out + 8 * n;
+  int64_t shed = 0, asked = 0, r = 0;
+  int64_t slots[ShedIndex::BLOCK];
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t b = i % ShedIndex::BLOCK;
+    if (b == 0)
+      index.find(kh + i, std::min(n - i, ShedIndex::BLOCK), slots);
+    const int64_t slot = slots[b];
+    const bool can = algo[i] == SHED_ALGO_TOKEN && hits[i] > 0 &&
+                     !(gnp != nullptr && gnp[i]);
+    asked += can;
+    const bool hit = can && slot >= 0 && lim[slot] == limit[i] &&
+                     dur[slot] == duration[i] && now < reset[slot];
+    mask[i] = hit;
+    remaining[i] = 0;
+    if (hit) {
+      ++shed;
+      status[i] = SHED_OVER_LIMIT;
+      limit_o[i] = limit[i];
+      reset_o[i] = reset[slot];
+      continue;
+    }
+    status[i] = limit_o[i] = reset_o[i] = 0;
+    keep[r] = i;
+    std::memcpy(&r_kh[r], &kh[i], 8);
+    r_hits[r] = hits[i];
+    r_limit[r] = limit[i];
+    r_duration[r] = duration[i];
+    r_algo[r] = algo[i];
+    if (gnp != nullptr) r_gnp[r] = gnp[i];
+    ++r;
+  }
+  *eligible = asked;
+  return shed;
+}
+
+// observe_fields over the n rows the batcher resolved: which of them the
+// Python walk (_observe_one) has to visit. Every row whose fingerprint
+// holds a LIVE slot (find, and a reset_time that is not 0) - the
+// correctness rows: confirm, drop, leaky pop - and, after them, the
+// frozen verdicts (OVER_LIMIT with nothing remaining) of fingerprints
+// that hold none, `cap` of them at most; row order within each. Of
+// those, a row that is no token-bucket request (`algo`, which may be
+// null: all token) can only drop an entry, so it is walked only where
+// the call also holds a frozen token-bucket row of its fingerprint,
+// which the walk may have stored by then: a hot leaky key that is over
+// limit is a fifth of a frame's rows and none of the cache's business. The count is returned and the
+// indices written to walk[n]. With m and k both 0 (an empty cache)
+// nothing is cached. Where `keep` is not null the four result columns
+// are also stitched into the frame's answer columns of n_full rows:
+// full_c[keep[i]] = result_c[i]; an index outside them returns -1 with
+// nothing written.
+int64_t guber_shed_observe(
+    const uint64_t* kh, const int32_t* algo, int64_t n,
+    const void* r_status, int64_t w_status,
+    const void* r_limit, int64_t w_limit,
+    const void* r_remaining, int64_t w_remaining,
+    const void* r_reset, int64_t w_reset,
+    const uint64_t* ix_fp, const int64_t* ix_slot, int64_t m,
+    const uint64_t* ov_fp, const int64_t* ov_slot, int64_t k,
+    const uint64_t* fp, const int64_t* reset, int64_t cap,
+    int64_t* walk,
+    const int64_t* keep, int64_t n_full, int64_t* full_status,
+    int64_t* full_limit, int64_t* full_remaining, int64_t* full_reset) {
+  if (keep != nullptr)
+    for (int64_t i = 0; i < n; ++i)
+      if (keep[i] < 0 || keep[i] >= n_full) return -1;
+  const ShedIndex index{ix_fp, ix_slot, m, ov_fp, ov_slot, k, fp};
+  const ResultColumn status{r_status, w_status};
+  const ResultColumn limit{r_limit, w_limit};
+  const ResultColumn remaining{r_remaining, w_remaining};
+  const ResultColumn reset_r{r_reset, w_reset};
+  // the cached rows go to walk as they are met; the frozen rows of
+  // uncached fingerprints wait in `fresh`, the token-bucket ones'
+  // fingerprints in `stored`, for the rule above
+  int64_t count = 0;
+  std::vector<int64_t> fresh;
+  std::vector<uint64_t> stored;
+  int64_t slots[ShedIndex::BLOCK];
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t b = i % ShedIndex::BLOCK;
+    if (b == 0) {
+      const int64_t rows = std::min(n - i, ShedIndex::BLOCK);
+      if (m + k > 0)
+        index.find(kh + i, rows, slots);
+      else
+        std::fill(slots, slots + rows, -1);
+    }
+    if (slots[b] >= 0 && reset[slots[b]] != 0) {
+      walk[count++] = i;
+    } else if (status[i] == SHED_OVER_LIMIT && remaining[i] == 0) {
+      fresh.push_back(i);
+      if (algo == nullptr || algo[i] == SHED_ALGO_TOKEN)
+        stored.push_back(kh[i]);
+    }
+  }
+  std::sort(stored.begin(), stored.end());
+  int64_t ins = 0;
+  for (const int64_t i : fresh) {
+    if (ins == cap) break;
+    if (algo == nullptr || algo[i] == SHED_ALGO_TOKEN ||
+        std::binary_search(stored.begin(), stored.end(), kh[i])) {
+      walk[count++] = i;
+      ++ins;
+    }
+  }
+  if (keep != nullptr)
+    for (int64_t i = 0; i < n; ++i) {
+      const int64_t at = keep[i];
+      full_status[at] = status[i];
+      full_limit[at] = limit[i];
+      full_remaining[at] = remaining[i];
+      full_reset[at] = reset_r[i];
+    }
+  return count;
+}
+
+}  // extern "C"

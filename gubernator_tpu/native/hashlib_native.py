@@ -140,6 +140,19 @@ try:
     _lib.guber_hotkeys_size.argtypes = [_vp] * 3
     _lib.guber_hotkeys_export.restype = _i64
     _lib.guber_hotkeys_export.argtypes = [_vp] * 5
+    # the shed cache's array consult and population
+    _lib.guber_shed_screen.restype = _i64
+    _lib.guber_shed_screen.argtypes = (
+        [_vp] * 6 + [_i64, _i64]                  # the frame, n, now
+        + [_vp, _vp, _i64] * 2 + [_vp] * 4        # index, overlay, slots
+        + [_vp] * 4 + [_i64p]                     # outputs, eligible
+    )
+    _lib.guber_shed_observe.restype = _i64
+    _lib.guber_shed_observe.argtypes = (
+        [_vp, _vp, _i64] + [_vp, _i64] * 4        # key_hash, algo, n, results
+        + [_vp, _vp, _i64] * 2 + [_vp, _vp, _i64]  # index, overlay, slots
+        + [_vp, _vp, _i64] + [_vp] * 4            # walk, keep, answers
+    )
 except AttributeError as e:
     raise ImportError(
         f"native library not built from this tree's guberhash.cc: {e} "
@@ -1008,3 +1021,91 @@ def hotkeys_export(handle):
         keys.ctypes.data,
     )
     return total.value, counts, errs, offsets, keys.tobytes()
+
+
+# -- the shed cache's array consult and population (guberhash.cc, last
+# section) -------------------------------------------------------------------
+
+
+def shed_screen(cols: dict, now: int, index):
+    """ShedCache.screen_fields' gates over one frame in one call with
+    the GIL released (guber_shed_screen). `cols` holds the frame's
+    contiguous key_hash uint64 / hits, limit, duration int64 / algo
+    int32 and, where it has one, gnp bool columns; `index` is the
+    cache's (ix_fp, ix_slot, m, ov_fp, ov_slot, k, fp, lim, dur, reset)
+    as addresses and lengths. Returns (shed, eligible, mask bool[n],
+    the four int64[n] answer columns, the int64 indices of the rows not
+    shed, those rows' columns as a dict like `cols`) — every array but
+    the mask a view of one block the call filled."""
+    kh, gnp = cols["key_hash"], cols.get("gnp")
+    n = kh.shape[0]
+    if any(c.shape != (n,) for c in cols.values()):
+        raise ValueError("a frame's columns differ in length")
+    mask = np.empty(n, bool)
+    out = np.empty((9, n), np.int64)
+    r_algo = np.empty(n, np.int32)
+    r_gnp = None if gnp is None else np.empty(n, bool)
+    eligible = ctypes.c_int64(0)
+    shed = _lib.guber_shed_screen(
+        kh.ctypes.data, cols["hits"].ctypes.data, cols["limit"].ctypes.data,
+        cols["duration"].ctypes.data, cols["algo"].ctypes.data,
+        None if gnp is None else gnp.ctypes.data, n, now, *index,
+        mask.ctypes.data, out.ctypes.data, r_algo.ctypes.data,
+        None if gnp is None else r_gnp.ctypes.data, eligible,
+    )
+    r = n - shed
+    residue = dict(
+        key_hash=out[5, :r].view(np.uint64), hits=out[6, :r],
+        limit=out[7, :r], duration=out[8, :r], algo=r_algo[:r],
+    )
+    if gnp is not None:
+        residue["gnp"] = r_gnp[:r]
+    return shed, eligible.value, mask, tuple(out[:4]), out[4, :r], residue
+
+
+def shed_observe(kh, algo, results, index, cap: int, into=None):
+    """The rows ShedCache.observe_fields has to walk, as a list in walk
+    order, in one call with the GIL released (guber_shed_observe). `kh`
+    is the rows' contiguous uint64 fingerprints, `algo` their contiguous
+    int32 algorithms or None (all token bucket), `results` their four
+    result columns (int32 or int64 as they come; anything else is
+    copied to int64), `index` the cache's (ix_fp, ix_slot, m, ov_fp,
+    ov_slot, k, fp, reset) as addresses and lengths. `into`, where
+    given, is (four contiguous int64 answer columns of one length, the
+    int64 index of each row in them): the results are stitched there in
+    the same call; an index outside them raises IndexError with nothing
+    written."""
+    n = kh.shape[0]
+    if algo is not None and algo.shape != (n,):
+        raise ValueError("the algorithm column differs from the rows")
+    res = []  # held to the end of the call: a copy is nobody else's
+    for c in results:
+        c = np.ascontiguousarray(c)
+        if c.dtype not in (np.int32, np.int64):
+            c = c.astype(np.int64)
+        if c.shape != (n,):
+            raise ValueError("result columns differ from the rows")
+        res.append(c)
+    stitch = (None, 0) + (None,) * 4
+    if into is not None:
+        answers, keep = into
+        keep = np.ascontiguousarray(keep, np.int64)
+        full = answers[0].shape
+        if keep.shape != (n,) or len(full) != 1 or any(
+            c.dtype != np.int64 or not c.flags.c_contiguous or c.shape != full
+            for c in answers
+        ):
+            raise ValueError(
+                "the stitch takes four contiguous int64 answer columns "
+                "of one length and an index a row"
+            )
+        stitch = (keep.ctypes.data, full[0], *[c.ctypes.data for c in answers])
+    walk = np.empty(n, np.int64)
+    m = _lib.guber_shed_observe(
+        kh.ctypes.data, None if algo is None else algo.ctypes.data, n,
+        *[x for c in res for x in (c.ctypes.data, c.itemsize)],
+        *index, cap, walk.ctypes.data, *stitch,
+    )
+    if m < 0:
+        raise IndexError("a stitched row lies outside the answer columns")
+    return walk[:m].tolist()

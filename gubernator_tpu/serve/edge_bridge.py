@@ -1228,7 +1228,7 @@ class FrameService:
             todo = np.ones(n, bool)
             answers = tuple(np.zeros(n, np.int64) for _ in range(4))
         else:
-            mask, answers = screened
+            mask, answers = screened[:2]
             todo = ~mask
         plan = _Split(payload, cols, fields, full, peers, answers)
         # the owner tag a forwarded answer carries, shed or not
@@ -1270,9 +1270,13 @@ class FrameService:
                 t0 = time.monotonic()
                 shed = getattr(inst, "shed", None)
                 if shed is not None:
-                    shed.observe_fields(residue, res)
-                for col, got in zip(plan.answers, res):
-                    col[mine] = got
+                    # the cache's population and the stitch, one call
+                    shed.observe_fields(
+                        residue, res, into=(plan.answers, mine)
+                    )
+                else:
+                    for col, got in zip(plan.answers, res):
+                        col[mine] = got
                 _stamp_shed(time.monotonic() - t0)
             except Exception as e:
                 for i in mine.tolist():

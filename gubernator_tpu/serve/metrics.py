@@ -337,6 +337,21 @@ SHED_INDEX_REBUILDS = Gauge(
     "means every frame pays a sort",
     registry=REGISTRY,
 )
+SHED_NATIVE_CONSULTS = Gauge(
+    "shed_native_consults_total",
+    "Of shed_index_uses_total, the consults ONE native call served "
+    "with the GIL released (libguberhash.so guber_shed_screen / "
+    "guber_shed_observe); the boot log's `shed screen:` line says "
+    "which body runs",
+    registry=REGISTRY,
+)
+SHED_NUMPY_CONSULTS = Gauge(
+    "shed_numpy_consults_total",
+    "Of shed_index_uses_total, the consults the numpy twin served call "
+    "by call on the serving loop: all of them where libguberhash.so is "
+    "absent, none where it is there",
+    registry=REGISTRY,
+)
 PEER_SERVE_BATCHES = Gauge(
     "peer_serve_batches_total",
     "GetPeerRateLimits batches this node served as the owner "

@@ -668,6 +668,17 @@ class Server:
                 "(GUBER_SHED_CACHE / GUBER_SHED_CACHE_KEYS)",
                 shed.capacity, footprint_mib(shed.capacity),
             )
+            log.info(
+                "shed screen: %s",
+                "a frame's consult and its population are one native "
+                "call each, GIL released (libguberhash.so "
+                "guber_shed_screen / guber_shed_observe; "
+                "shed_native_consults_total)"
+                if shed.screen_implementation == "native"
+                else "numpy on the serving loop, call by call "
+                "(libguberhash.so is absent: make -C "
+                "gubernator_tpu/native; shed_numpy_consults_total)",
+            )
         else:
             log.info("over-limit shed cache: off (GUBER_SHED_CACHE=0)")
 
@@ -1180,6 +1191,8 @@ class Server:
             metrics.SHED_ENTRIES.set(len(shed))
             metrics.SHED_INDEX_USES.set(shed.index_uses)
             metrics.SHED_INDEX_REBUILDS.set(shed.index_rebuilds)
+            metrics.SHED_NATIVE_CONSULTS.set(shed.native_consults)
+            metrics.SHED_NUMPY_CONSULTS.set(shed.numpy_consults)
         metrics.PEER_SERVE_BATCHES.set(self.instance.peer_serve_batches)
         metrics.PEER_SERVE_ITEMS.set(self.instance.peer_serve_items)
         metrics.PEER_SERVE_SHED_HITS.set(self.instance.peer_serve_shed_hits)

@@ -69,7 +69,14 @@ purged — and each such change is a scalar write to a slot; only a
 change of the cached KEY SET reaches the index, one argsort per
 OVERLAY_MAX new fingerprints (class docstring; counted by
 index_uses / index_rebuilds). Nothing on the frame path iterates over
-the entries in Python.
+the entries in Python. The two array methods are ONE call each into
+libguberhash.so with the GIL released (guber_shed_screen,
+guber_shed_observe: the searches, the gates, the answers, the residue's
+rows, the stitch); where the library is absent (core/hashing
+native_lib, the one fact every native selection reads) their numpy
+twins run the same rules call by call, and the tests hold the two to
+each other. The dictionary, its LRU order and every write to a slot are
+Python's either way.
 
 Thread model: event-loop confined like the rest of the serving tier
 (the bridge and instance both consult from the loop); the only
@@ -93,6 +100,9 @@ from gubernator_tpu.api.types import (
     over_limit_resp,
 )
 from gubernator_tpu.core.algorithms import ALGO_TOKEN, SHEDDABLE_ALGOS
+from gubernator_tpu.core.hashing import native_lib
+
+_hn = native_lib()
 
 # r15 interplay audit: every consult and populate path below is gated
 # on Algorithm.TOKEN_BUCKET because the frozen-verdict fixed point this
@@ -133,6 +143,17 @@ OVERLAY_MAX = 256
 #: slot columns start this long and double up to the capacity
 _FIRST_SLOTS = 1024
 
+#: a frame's columns as the screen reads them, with the dtypes the
+#: doors' parsers give them (api/columns.py DECIDE_FIELDS, and the
+#: batcher's `gnp` where a caller has it)
+_COLUMNS = (
+    ("key_hash", np.uint64), ("hits", np.int64), ("limit", np.int64),
+    ("duration", np.int64), ("algo", np.int32), ("gnp", np.bool_),
+)
+
+#: _native_index of a cache that holds nothing
+_NO_INDEX = ((None, None, 0, None, None, 0, None), None, None, None)
+
 
 def footprint_mib(keys: int) -> float:
     return keys * ENTRY_BYTES / (1 << 20)
@@ -171,7 +192,7 @@ class ShedCache:
 
     Every live verdict owns one SLOT of four numpy columns
     (fingerprint, limit, duration, reset_time): the table the
-    vectorized screen gathers from. `_entries` maps fingerprint ->
+    array screen reads. `_entries` maps fingerprint ->
     (limit, duration, reset_time, slot) in LRU order for the point
     operations, which never read a numpy scalar. A change of VALUE
     (new reset_time, confirm, drop) is a write in place; a dropped
@@ -211,12 +232,17 @@ class ShedCache:
         self._new_fp = np.zeros(OVERLAY_MAX, np.uint64)
         self._new_slot = np.zeros(OVERLAY_MAX, np.int64)
         self._top = 0
+        self._at = None  # _native_index's addresses, and whose they are
         self.purge_all()
         # monotonic counters (ints: GIL-atomic, scrape reads them raw)
         self.hits = 0
         self.lookups = 0
         self.index_uses = 0  # screen_fields / observe_fields consults
         self.index_rebuilds = 0  # re-sorts of the index
+        # index_uses by the body that served the consult: the native
+        # call, or its numpy twin
+        self.native_consults = 0
+        self.numpy_consults = 0
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -266,7 +292,8 @@ class ShedCache:
         self._ix_slot = np.zeros(0, np.int64)
         # the overlay: how many of _new_fp / _new_slot were bound since
         # the sort (past OVERLAY_MAX: the next consult re-sorts), and
-        # those as sorted arrays once a consult needed them
+        # those as sorted arrays, with their addresses, once a consult
+        # needed them
         self._new = 0
         self._overlay = None
 
@@ -278,6 +305,14 @@ class ShedCache:
         self.lookups = 0
         self.index_uses = 0
         self.index_rebuilds = 0
+        self.native_consults = 0
+        self.numpy_consults = 0
+
+    @property
+    def screen_implementation(self) -> str:
+        """Which body screen_fields and observe_fields run: "native"
+        (one libguberhash.so call each) or "numpy"."""
+        return "numpy" if _hn is None else "native"
 
     def stats(self) -> dict:
         lk = self.lookups
@@ -289,6 +324,8 @@ class ShedCache:
             hit_rate=round(self.hits / lk, 4) if lk else 0.0,
             index_uses=self.index_uses,
             index_rebuilds=self.index_rebuilds,
+            native_consults=self.native_consults,
+            numpy_consults=self.numpy_consults,
             generation=self._gen,
         )
 
@@ -360,34 +397,82 @@ class ShedCache:
         self._new = 0
         self._overlay = None
 
-    def _find(self, kh):
-        """(slot int64[n], found bool[n]) for a frame's fingerprints:
-        one searchsorted against the sorted index, one against the
-        overlay where it has anything (its latest binding of a
-        fingerprint wins), and the slot's own fingerprint as the proof.
-        `found` rows may be dropped slots (reset_time 0). Callers have
-        checked that the cache is not empty."""
+    def _consult(self):
+        """Count one consult of the index and bring it up to date:
+        an overlay past OVERLAY_MAX (or a cache that has no index yet)
+        is folded in by one sort, and an overlay that gained a binding
+        since the last consult is sorted again. Returns the overlay —
+        (fingerprints sorted, their slots, the two arrays' addresses) —
+        or None where it holds nothing. Callers have checked that the
+        cache is not empty."""
         self.index_uses += 1
         k = self._new
         if k > OVERLAY_MAX or not self._ix_fp.shape[0]:
             self._resort()
             k = 0
+        if not k:
+            return None
+        if self._overlay is None:
+            fp = self._new_fp[:k]
+            # stable: of equal fingerprints the LAST is the binding in
+            # force, and the searches take the last
+            order = np.argsort(fp, kind="stable")
+            ov_fp, ov_slot = fp[order], self._new_slot[:k][order]
+            self._overlay = (
+                ov_fp, ov_slot, ov_fp.ctypes.data, ov_slot.ctypes.data
+            )
+        return self._overlay
+
+    def _find(self, kh):
+        """(slot int64[n], found bool[n]) for a frame's fingerprints,
+        in numpy: one searchsorted against the sorted index, one
+        against the overlay where it has anything (its latest binding
+        of a fingerprint wins), and the slot's own fingerprint as the
+        proof. `found` rows may be dropped slots (reset_time 0).
+        native/guberhash.cc ShedIndex::find is this rule for one row."""
+        overlay = self._consult()
+        self.numpy_consults += 1
         ix_fp = self._ix_fp
         pos = np.searchsorted(ix_fp, kh)
         np.minimum(pos, ix_fp.shape[0] - 1, out=pos)
         slot = self._ix_slot[pos]
-        if k:
-            if self._overlay is None:
-                fp = self._new_fp[:k]
-                order = np.argsort(fp, kind="stable")
-                self._overlay = fp[order], self._new_slot[:k][order]
-            ov_fp, ov_slot = self._overlay
+        if overlay is not None:
+            ov_fp, ov_slot = overlay[:2]
             # side="right" - 1: the LAST of equal fingerprints, the
             # binding in force (-1 wraps to a row the compare refuses)
             j = np.searchsorted(ov_fp, kh, side="right") - 1
             hit = np.flatnonzero(ov_fp[j] == kh)
             slot[hit] = ov_slot[j[hit]]
         return slot, self._fp[slot] == kh
+
+    def _native_index(self):
+        """One consult's view of the cache for the native calls, arrays
+        as addresses: (the lookup structure guberhash.cc ShedIndex
+        takes — index fingerprints, index slots, their length, overlay
+        fingerprints, overlay slots, their length, the slots'
+        fingerprint column —, then the slots' limit, duration and
+        reset_time columns). The cache keeps every array alive and,
+        confined to the loop's thread, unchanged for the length of the
+        call."""
+        overlay = self._consult()
+        self.native_consults += 1
+        at = self._at
+        if at is None or at[0] is not self._ix_fp or at[1] is not self._fp:
+            # the index pair is replaced as one (_resort, purge_all) and
+            # so are the four columns (_grow): the held arrays tell
+            # whether the addresses are still theirs
+            at = self._at = (self._ix_fp, self._fp) + tuple(
+                a.ctypes.data for a in (
+                    self._ix_fp, self._ix_slot, self._fp, self._lim,
+                    self._dur, self._reset,
+                )
+            )
+        _, _, ix_fp, ix_slot, fp, lim, dur, reset = at
+        ov = (None, None, 0) if overlay is None else (
+            overlay[2], overlay[3], overlay[0].shape[0]
+        )
+        find = (ix_fp, ix_slot, self._ix_fp.shape[0], *ov, fp)
+        return find, lim, dur, reset
 
     # -- consult -------------------------------------------------------------
 
@@ -433,15 +518,18 @@ class ShedCache:
         """Bridge-tier consult over one frame's dense arrays
         (key_hash/hits/limit/duration/algo[/gnp]). Returns None when
         nothing sheds, else (shed_mask bool[n], (status, limit,
-        remaining, reset) int64[n] with the shed rows filled; residue
-        rows are zero and overwritten by the device results).
+        remaining, reset) int64[n] with the shed rows filled — residue
+        rows are zero and overwritten by the device results —, the
+        indices int64[r] of the rows NOT shed, and those rows' columns
+        as a dict like `fields`: what goes on to the batcher).
 
-        Fully vectorized — _find's searchsorted, gathers from the slot
-        columns and elementwise gates — so a thousand-item frame
-        screens in ~0.1 ms of event-loop time whatever the cache
-        holds and however often its values change (the per-item
-        dict-probe loop this replaced measured ~1.4 ms/frame on a
-        throttled 2-core host, which ate the shed's own win).
+        One native call (guber_shed_screen, GIL released) where
+        libguberhash.so is there, else _screen_numpy: the same gates
+        row for row, a frame's cost whatever the cache holds and
+        however often its values change. The numpy body is ~0.2-0.3
+        ms of work a thousand-row frame but some thirty calls that
+        each give the GIL up, and was read as ~0.9 ms of `shed` span a
+        side under load (PERF.md section 6, PR 48).
         Two deliberate approximations vs lookup(): screen hits do not
         refresh LRU recency (entries refresh on insert; with the
         bound sized to the over-limit head that's ample), and expired
@@ -451,25 +539,44 @@ class ShedCache:
             return None
         if now is None:
             now = self.now_fn()
-        kh = np.asarray(fields["key_hash"], np.uint64)
+        cols = {
+            k: np.ascontiguousarray(fields[k], t)
+            for k, t in _COLUMNS
+            if fields.get(k) is not None
+        }
+        if _hn is None:
+            return self._screen_numpy(cols, now)
+        find, lim, dur, reset = self._native_index()
+        shed, eligible, *screened = _hn.shed_screen(
+            cols, now, (*find, lim, dur, reset)
+        )
+        self.lookups += eligible
+        self.hits += shed
+        return tuple(screened) if shed else None
+
+    def _screen_numpy(self, cols: Dict, now: int):
+        """screen_fields' body where libguberhash.so is absent, and
+        the tests' reference for the native call: _find's searchsorted,
+        gathers from the slot columns and elementwise gates."""
+        kh = cols["key_hash"]
         slot, found = self._find(kh)
         eligible = (
-            (np.asarray(fields["algo"]) == int(Algorithm.TOKEN_BUCKET))
-            & (np.asarray(fields["hits"]) > 0)
+            (cols["algo"] == int(Algorithm.TOKEN_BUCKET))
+            & (cols["hits"] > 0)
         )
-        gnp = fields.get("gnp")
+        gnp = cols.get("gnp")
         if gnp is not None:
             # replica reads answer from the live replica entry;
             # screening them here would skip the replica-miss
             # local-processing path — leave them to the device
-            eligible &= ~np.asarray(gnp, bool)
-        limit = np.asarray(fields["limit"], np.int64)
+            eligible &= ~gnp
+        limit = cols["limit"]
         reset = self._reset[slot]
         mask = (
             found
             & eligible
             & (self._lim[slot] == limit)
-            & (self._dur[slot] == np.asarray(fields["duration"], np.int64))
+            & (self._dur[slot] == cols["duration"])
             & (now < reset)
         )
         shed = int(mask.sum())
@@ -483,7 +590,11 @@ class ShedCache:
         limit_out = np.where(mask, limit, 0)
         remaining = np.zeros(kh.shape[0], np.int64)
         reset_out = np.where(mask, reset, 0)
-        return mask, (status, limit_out, remaining, reset_out)
+        keep = np.flatnonzero(~mask)
+        return (
+            mask, (status, limit_out, remaining, reset_out), keep,
+            {k: v[keep] for k, v in cols.items()},
+        )
 
     # -- populate / invalidate ----------------------------------------------
 
@@ -572,48 +683,66 @@ class ShedCache:
             )
 
     def observe_fields(
-        self, fields: Dict, results, now: Optional[int] = None
+        self, fields: Dict, results, now: Optional[int] = None, into=None
     ) -> None:
         """Array-path population (bridge tier): `results` is the
         (status, limit, remaining, reset) tuple the batcher resolved
-        for exactly these `fields` rows. The walk is bounded: every
+        for exactly these `fields` rows. `into`, where given, is
+        ((status, limit, remaining, reset) int64 columns of a whole
+        frame, the int64 indices of these rows in it) and the results
+        are written there too: screen_fields' answers and kept rows,
+        stitched on the way.
+
+        The walk is bounded: every
         row touching a CACHED fingerprint is visited (confirm / drop /
-        leaky pop — the correctness rows, pre-filtered with one
-        vectorized _find membership test), while frozen-verdict
+        leaky pop — the correctness rows, pre-filtered by one
+        membership test against the index), while frozen-verdict
         rows for UNCACHED fingerprints — pure population — are capped
         at OBSERVE_INSERT_CAP per call, so an over-limit-heavy frame
         whose key cardinality exceeds the cache bound cannot drag a
         ~1 ms/frame Python walk into steady state (the cost the
-        vectorized screen exists to avoid)."""
+        array screen exists to avoid). Of those, a row that is no
+        token-bucket request is left out unless the call also has a
+        frozen token-bucket row of its fingerprint: _observe_one can
+        only drop for it, and there is nothing to drop — a hot leaky
+        key over its limit was four fifths of the rows walked (~280 a
+        thousand-row frame, ~0.6 ms of this loop's Python; PERF.md
+        section 6, PR 48). Which rows are walked, and the stitch, is
+        one native call (guber_shed_observe, GIL released) or
+        _observe_numpy; the walk itself is Python's either way: the
+        rows in flight when a one-second window turned, some tens a
+        frame."""
+        kh = np.ascontiguousarray(fields["key_hash"], np.uint64)
+        algo = fields.get("algo")
+        if algo is not None:
+            algo = np.ascontiguousarray(algo, np.int32)
+        if _hn is None:
+            rows = self._observe_numpy(kh, algo, results, into)
+        else:
+            # an empty cache holds no fingerprint and counts no consult
+            find, _, _, reset = (
+                self._native_index() if self._entries else _NO_INDEX
+            )
+            rows = _hn.shed_observe(
+                kh, algo, results, (*find, reset), OBSERVE_INSERT_CAP, into
+            )
+        if not rows:
+            return
+        # a key's rows all land on one side of the cached split, and
+        # row order is kept within each side, so last-wins semantics
+        # per key survive the concat
+        if now is None:
+            now = self.now_fn()
         status, limit_r, remaining, reset = results
         sa = np.asarray(status)
         ra = np.asarray(remaining)
-        frozen = (sa == int(Status.OVER_LIMIT)) & (ra == 0)
-        kh = np.asarray(fields["key_hash"], np.uint64)
-        if self._entries:
-            slot, found = self._find(kh)
-            cached = found & (self._reset[slot] != 0)
-        else:
-            cached = np.zeros(kh.shape[0], bool)
-        must = np.flatnonzero(cached)
-        ins = np.flatnonzero(frozen & ~cached)
-        if ins.shape[0] > OBSERVE_INSERT_CAP:
-            ins = ins[:OBSERVE_INSERT_CAP]
-        if not must.shape[0] and not ins.shape[0]:
-            return
-        # a key's rows all land on one side of the cached split, and
-        # flatnonzero keeps row order within each side, so last-wins
-        # semantics per key survive the concat
-        if now is None:
-            now = self.now_fn()
         hits = fields["hits"]
         limit = fields["limit"]
         duration = fields["duration"]
-        algo = fields.get("algo")
         limit_a = np.asarray(limit_r)
         reset_a = np.asarray(reset)
         token = int(Algorithm.TOKEN_BUCKET)
-        for i in np.concatenate([must, ins]).tolist():
+        for i in rows:
             self._observe_one(
                 int(kh[i]), int(hits[i]), int(limit[i]),
                 int(duration[i]),
@@ -621,6 +750,36 @@ class ShedCache:
                 int(sa[i]), int(limit_a[i]), int(ra[i]),
                 int(reset_a[i]), now,
             )
+
+    def _observe_numpy(self, kh, algo, results, into) -> List[int]:
+        """The rows observe_fields walks, in walk order, and the
+        stitch, where libguberhash.so is absent; the tests' reference
+        for the native call."""
+        status, _, remaining, _ = results
+        frozen = (
+            (np.asarray(status) == int(Status.OVER_LIMIT))
+            & (np.asarray(remaining) == 0)
+        )
+        if self._entries:
+            slot, found = self._find(kh)
+            cached = found & (self._reset[slot] != 0)
+        else:
+            cached = np.zeros(kh.shape[0], bool)
+        must = np.flatnonzero(cached)
+        fresh = frozen & ~cached
+        if algo is not None:
+            # a row that is no token-bucket request can only DROP an
+            # entry, and its fingerprint holds none: it matters only
+            # where this call also has a frozen token-bucket row of
+            # the fingerprint, which the walk may have stored by then
+            token = algo == int(Algorithm.TOKEN_BUCKET)
+            fresh &= token | np.isin(kh, kh[fresh & token])
+        ins = np.flatnonzero(fresh)[:OBSERVE_INSERT_CAP]
+        if into is not None:
+            answers, keep = into
+            for col, got in zip(answers, results):
+                col[keep] = got
+        return np.concatenate([must, ins]).tolist()
 
 
 async def screened_decide(
@@ -642,8 +801,8 @@ async def screened_decide(
     t0 = time.monotonic()
     shed.refresh_generation()
     screened = shed.screen_fields(fields)
+    stamp(time.monotonic() - t0)
     if screened is None:
-        stamp(time.monotonic() - t0)
         res = await decide(fields, n)
         # population is shed work too: without the stamp, a
         # cold-cache frame's observe walk would sit between the
@@ -652,20 +811,11 @@ async def screened_decide(
         shed.observe_fields(fields, res)
         stamp(time.monotonic() - t1)
         return res
-    mask, (status, limit, remaining, reset) = screened
-    keep = ~mask
-    n_res = int(keep.sum())
-    if n_res == 0:
-        stamp(time.monotonic() - t0)
-        return status, limit, remaining, reset
-    residue = {k: v[keep] for k, v in fields.items()}
-    stamp(time.monotonic() - t0)
-    rs, rl, rr, rt = await decide(residue, n_res)
+    _, answers, keep, residue = screened
+    if not keep.shape[0]:
+        return answers
+    res = await decide(residue, keep.shape[0])
     t1 = time.monotonic()
-    shed.observe_fields(residue, (rs, rl, rr, rt))
-    status[keep] = rs
-    limit[keep] = rl
-    remaining[keep] = rr
-    reset[keep] = rt
+    shed.observe_fields(residue, res, into=(answers, keep))
     stamp(time.monotonic() - t1)
-    return status, limit, remaining, reset
+    return answers

@@ -28,7 +28,10 @@ over one window of --seconds:
   frames the native parser took or declined), PR 37; beside them the
   traffic observers' `traffic_*_folds_total` (which implementation
   folded the batches), PR 40; on a mesh `mesh_*_stacks_total` (who laid
-  the merged batches out per shard), PR 44; and on a ring's door node the split by
+  the merged batches out per shard), PR 44; the shed cache's
+  `shed_index_uses_total` beside `shed_native_consults_total` /
+  `shed_numpy_consults_total` (which body served the array consults:
+  the two sum to the first), PR 48; and on a ring's door node the split by
   owner's `edge_split_frames_total`, `edge_split_items_total{lane}`
   and `edge_split_declined_total{reason}` (how often it engaged, where
   its items went, what it declined and why), PR 43;
@@ -204,11 +207,15 @@ def door_counters(prom0, prom1):
     `mesh_numpy_stacks_total`, beside the shard rows and slots) and
     the batcher's turn-taking between sources (PR 47:
     `device_groups_overtaking_total`, `device_batch_sources_sum` /
-    `_count`: 0 and 1.0 a batch wherever one source feeds it) over the
-    window."""
+    `_count`: 0 and 1.0 a batch wherever one source feeds it) and the
+    shed cache's array consults by the body that served them (PR 48:
+    `shed_index_uses_total` = `shed_native_consults_total` +
+    `shed_numpy_consults_total`, `shed_index_rebuilds_total`) over the
+    window. A counter that did not move is left out."""
     return grown(prom0, prom1, (
         "edge_", "traffic_", "mesh_", "device_groups_overtaking_",
-        "device_batch_sources_"))
+        "device_batch_sources_", "shed_index_", "shed_native_",
+        "shed_numpy_"))
 
 
 def forwarder(st, prom0, prom1):

@@ -60,6 +60,19 @@ class FakeClock:
 # -- ShedCache unit gates ---------------------------------------------------
 
 
+def _run_body(monkeypatch, body: str) -> None:
+    """Make ShedCache's array methods run their native call or, with
+    the library hidden from the module, their numpy twin."""
+    from gubernator_tpu.serve import shedcache
+
+    if body == "numpy":
+        monkeypatch.setattr(shedcache, "_hn", None)
+    elif shedcache._hn is None:
+        pytest.skip("libguberhash.so is absent: the numpy body is the "
+                    "one there is (make -C gubernator_tpu/native)")
+
+
+
 def test_lookup_gates_and_expiry():
     clock = FakeClock()
     c = ShedCache(8, now_fn=clock)
@@ -257,20 +270,28 @@ class _DictModel:
     def observe_rows(self, rows, now):
         cached = [r[0] in self.entries for r in rows]
         must = [r for r, c in zip(rows, cached) if c]
-        ins = [
+        fresh = [
             r for r, c in zip(rows, cached)
             if not c and r[4] == int(Status.OVER_LIMIT) and r[6] == 0
+        ]
+        # a row of another algorithm can only drop, and only what a
+        # token row of this call may have stored before the walk
+        # reaches it
+        storable = {r[0] for r in fresh if r[3] == 0}
+        ins = [
+            r for r in fresh if r[3] == 0 or r[0] in storable
         ][: self.insert_cap]
         for r in must + ins:
             self.observe_one(*r, now)
 
 
+@pytest.mark.parametrize("body", ["native", "numpy"])
 @pytest.mark.parametrize(
     "seed,capacity,overlay,insert_cap",
     [(1, 40, 6, 5), (2, 40, 256, 512), (3, 4096, 16, 512)],
 )
 def test_slot_table_equals_dict_model(
-    monkeypatch, seed, capacity, overlay, insert_cap
+    monkeypatch, seed, capacity, overlay, insert_cap, body
 ):
     """A few thousand seeded seed / observe_fields / observe_resps /
     lookup / purge / refresh_generation operations under a moving
@@ -282,6 +303,7 @@ def test_slot_table_equals_dict_model(
     recycling and LRU eviction happen every few operations."""
     from gubernator_tpu.serve import shedcache
 
+    _run_body(monkeypatch, body)
     monkeypatch.setattr(shedcache, "OVERLAY_MAX", overlay)
     monkeypatch.setattr(shedcache, "OBSERVE_INSERT_CAP", insert_cap)
     rng = np.random.default_rng(seed)
@@ -357,8 +379,13 @@ def test_slot_table_equals_dict_model(
         if got is None:
             assert not any(w is not None for w in want)
             return
-        mask, (status, limit_out, remaining, reset) = got
+        mask, (status, limit_out, remaining, reset), keep, residue = got
         assert mask.tolist() == [w is not None for w in want]
+        assert keep.tolist() == np.flatnonzero(~mask).tolist()
+        assert sorted(residue) == sorted(fields)
+        for k, col in fields.items():
+            assert residue[k].dtype == col.dtype
+            assert residue[k].tolist() == col[~mask].tolist()
         assert reset.tolist() == [w or 0 for w in want]
         assert status.tolist() == [
             int(Status.OVER_LIMIT) if w is not None else 0 for w in want
@@ -422,6 +449,8 @@ def test_slot_table_equals_dict_model(
         check_screen()
         assert [h for h in model.entries] == list(c._entries)
     assert c.index_rebuilds < c.index_uses
+    served = dict(native=c.native_consults, numpy=c.numpy_consults)
+    assert served.pop(body) == c.index_uses and served.popitem()[1] == 0
 
 
 def test_value_changes_never_resort_the_index():
@@ -508,6 +537,325 @@ def test_value_changes_never_resort_the_index():
     assert c.screen_fields(probe)[0].all()
     assert c.screen_fields(probe)[0].all()
     assert c.index_rebuilds == rebuilds + 1 and c._new == 0
+
+
+# -- the native call against its numpy twin ----------------------------------
+
+#: what each case of the identity fuzz puts in front of the two bodies
+TWIN_CASES = [
+    "empty_cache", "first_consult", "index_only", "index_and_overlay",
+    "rebound_in_overlay", "dropped_slots", "expired_entries", "gnp_rows",
+    "leaky_on_cached", "param_mismatch", "duplicate_keys", "one_row",
+    "thousand_rows", "past_the_insert_cap",
+]
+
+
+def _twin_state(case, rng, clock):
+    """(build, pool): `build(cache)` brings a fresh ShedCache to the
+    case's state by the same calls whoever it is handed, `pool` the
+    fingerprints it cached (a frame draws its hot rows from them)."""
+    from gubernator_tpu.serve.shedcache import OVERLAY_MAX
+
+    over = int(Status.OVER_LIMIT)
+    pool = np.unique(rng.integers(0, 1 << 64, size=600, dtype=np.uint64))
+    pool[0], pool[-1] = 0, (1 << 64) - 1
+    resets = clock.t + rng.integers(200, 90_000, size=pool.shape[0])
+    late = rng.integers(0, 1 << 64, size=40, dtype=np.uint64)
+
+    def store(c, h, reset):
+        c._observe_one(int(h), 1, 100, 60_000, 0, over, 100, 0,
+                       int(reset), clock.t)
+
+    def fold(c):
+        probe = dict(
+            key_hash=pool[:1], hits=np.ones(1, np.int64),
+            limit=np.ones(1, np.int64), duration=np.ones(1, np.int64),
+            algo=np.zeros(1, np.int32),
+        )
+        c.screen_fields(probe)  # a consult: builds or folds the index
+        assert c._new == 0 and c._ix_fp.shape[0]
+
+    def build(c):
+        if case == "empty_cache":
+            return
+        for h, reset in zip(pool.tolist(), resets.tolist()):
+            store(c, h, reset)
+        if case == "first_consult":
+            assert not c._ix_fp.shape[0] and c._new > OVERLAY_MAX
+            return
+        fold(c)
+        if case == "index_only":
+            return
+        for h in late.tolist():
+            store(c, h, clock.t + 5000)  # these wait in the overlay
+        if case == "rebound_in_overlay":
+            # late[0] loses its slot to late[1]'s second binding and is
+            # bound again to another: two overlay rows of one
+            # fingerprint, the later one in force
+            a, b = int(late[0]), int(late[1])
+            c.purge([a, b])
+            store(c, b, clock.t + 7000)
+            store(c, a, clock.t + 9000)
+            assert (c._new_fp[:c._new] == np.uint64(a)).sum() == 2
+        if case == "dropped_slots":
+            c.purge(pool[::3])  # indexed rows whose slots read reset 0
+            c.purge(late[::4])
+
+    return build, np.concatenate([pool, late])
+
+
+def _twin_frame(case, rng, pool, clock):
+    """One frame for the case: cached and uncached fingerprints, a few
+    rows each of every gate's other side, and the case's own stress."""
+    n = {"one_row": 1, "thousand_rows": 1000}.get(
+        case, int(rng.integers(2, 400))
+    )
+    if case == "past_the_insert_cap":
+        n = 1000
+    cold = rng.integers(0, 1 << 64, size=n, dtype=np.uint64)
+    hot_share = 0.1 if case == "past_the_insert_cap" else 0.55
+    kh = np.where(rng.random(n) < hot_share, rng.choice(pool, n), cold)
+    if case == "duplicate_keys":
+        kh = rng.choice(kh[: max(1, n // 8)], n)
+    limit = np.full(n, 100, np.int64)
+    duration = np.full(n, 60_000, np.int64)
+    odds = 0.4 if case == "param_mismatch" else 0.05
+    limit[rng.random(n) < odds] += 1
+    duration[rng.random(n) < odds] -= 1
+    leaky = 0.4 if case == "leaky_on_cached" else 0.08
+    algo = np.where(
+        rng.random(n) < leaky, rng.integers(1, 4, size=n), 0
+    ).astype(np.int32)
+    fields = dict(
+        key_hash=kh, hits=(rng.random(n) < 0.92).astype(np.int64),
+        limit=limit, duration=duration, algo=algo,
+    )
+    if case == "gnp_rows" or rng.random() < 0.2:
+        fields["gnp"] = rng.random(n) < 0.3
+    return fields
+
+
+def _twin_results(case, rng, fields, cache, clock, peer_reply):
+    """What a device (int32 status / limit / remaining beside an int64
+    reset_time) or a peer's reply (four int64) could say of `fields`:
+    frozen and under-limit answers, echoes of the cached window,
+    answers under other stored params, expired resets."""
+    n = fields["key_hash"].shape[0]
+    frozen = rng.random(n) < (0.9 if case == "past_the_insert_cap" else 0.5)
+    status = np.where(frozen, int(Status.OVER_LIMIT), 0)
+    remaining = np.where(frozen, 0, rng.integers(0, 50, size=n))
+    limit = np.where(rng.random(n) < 0.9, fields["limit"], 100)
+    reset = clock.t + rng.choice([-5, 1, 300, 800, 70_000], n)
+    for i, h in enumerate(fields["key_hash"].tolist()):
+        e = cache.get(h)
+        if e is not None and rng.random() < 0.4:
+            reset[i] = e[2]  # echo the cached window
+    small = np.int64 if peer_reply else np.int32
+    return (
+        status.astype(small), limit.astype(small), remaining.astype(small),
+        reset.astype(np.int64),
+    )
+
+
+def _recorded(c, rows):
+    """c._observe_one that also appends each call's arguments to rows."""
+    def observe_one(*args):
+        rows.append(args)
+        ShedCache._observe_one(c, *args)
+
+    return observe_one
+
+
+def _cache_state(c):
+    top = c._top
+    return dict(
+        entries=list(c._entries.items()), free=list(c._free), top=top,
+        new=c._new, new_fp=c._new_fp[:c._new].tolist(),
+        new_slot=c._new_slot[:c._new].tolist(),
+        fp=c._fp[:top].tolist(), lim=c._lim[:top].tolist(),
+        dur=c._dur[:top].tolist(), reset=c._reset[:top].tolist(),
+        ix_fp=c._ix_fp.tolist(), ix_slot=c._ix_slot.tolist(),
+        counters=(c.hits, c.lookups, c.index_uses, c.index_rebuilds),
+    )
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4])
+@pytest.mark.parametrize("case", TWIN_CASES)
+def test_native_calls_are_their_numpy_twins(monkeypatch, case, seed):
+    """guber_shed_screen / guber_shed_observe against _screen_numpy /
+    _observe_numpy from ONE cache state each step: the same mask, the
+    same four answer columns, the same kept rows and residue columns
+    (names, dtypes, values), the same rows walked in the same order
+    with the same values, the same stitched answers, the same counters
+    and, after the walk, the same entries in the same LRU order in the
+    same slots."""
+    from gubernator_tpu.serve import shedcache
+
+    if shedcache._hn is None:
+        pytest.skip("libguberhash.so is absent: the numpy body has no "
+                    "twin to be held to (make -C gubernator_tpu/native)")
+    native_lib = shedcache._hn
+    rng = np.random.default_rng([seed, TWIN_CASES.index(case)])
+    clock = FakeClock()
+    build, pool = _twin_state(case, rng, clock)
+    caches = {}
+    walked = {}
+    for body in ("numpy", "native"):
+        c = caches[body] = ShedCache(1 << 12, now_fn=clock)
+        build(c)
+        c.reset_counters()
+        walked[body] = []
+        c._observe_one = _recorded(c, walked[body])
+    assert _cache_state(caches["numpy"]) == _cache_state(caches["native"])
+
+    def on(body, call):
+        monkeypatch.setattr(
+            shedcache, "_hn", native_lib if body == "native" else None
+        )
+        return call(caches[body])
+
+    for step in range(6):
+        if case == "expired_entries" or step % 3 == 2:
+            clock.t += int(rng.choice([1, 250, 5000, 61_000]))
+        fields = _twin_frame(case, rng, pool, clock)
+        n = fields["key_hash"].shape[0]
+        got = {
+            body: on(body, lambda c: c.screen_fields(fields))
+            for body in ("numpy", "native")
+        }
+        assert (got["numpy"] is None) == (got["native"] is None)
+        if got["numpy"] is None:
+            rows, into = {b: fields for b in got}, {b: None for b in got}
+        else:
+            rows, into = {}, {}
+            for body, (mask, answers, keep, residue) in got.items():
+                assert mask.dtype == bool and mask.shape == (n,)
+                assert keep.dtype == np.int64
+                assert all(
+                    a.dtype == np.int64 and a.shape == (n,) for a in answers
+                )
+                rows[body], into[body] = residue, (answers, keep)
+            a, b = got["numpy"], got["native"]
+            assert a[0].tolist() == b[0].tolist()
+            assert a[0].any()
+            for col_a, col_b in zip(a[1], b[1]):
+                assert col_a.tolist() == col_b.tolist()
+            assert a[2].tolist() == b[2].tolist()
+            assert list(a[3]) == list(b[3])
+            for k in a[3]:
+                assert a[3][k].dtype == b[3][k].dtype == fields[k].dtype
+                assert a[3][k].tolist() == b[3][k].tolist()
+        assert _cache_state(caches["numpy"]) == _cache_state(caches["native"])
+
+        if rows["numpy"]["key_hash"].shape[0]:
+            results = _twin_results(
+                case, rng, rows["numpy"], caches["numpy"], clock,
+                peer_reply=bool(step % 2),
+            )
+            for body in ("numpy", "native"):
+                on(body, lambda c: c.observe_fields(
+                    rows[body], results, into=into[body]
+                ))
+            assert walked["numpy"] == walked["native"]
+            if case == "past_the_insert_cap":
+                assert len(walked["numpy"]) >= shedcache.OBSERVE_INSERT_CAP
+            for body in walked:
+                walked[body].clear()
+            if into["numpy"] is not None:
+                for col_a, col_b in zip(into["numpy"][0], into["native"][0]):
+                    assert col_a.tolist() == col_b.tolist()
+        assert _cache_state(caches["numpy"]) == _cache_state(caches["native"])
+    c = caches["native"]
+    assert c.native_consults == c.index_uses and c.numpy_consults == 0
+    c = caches["numpy"]
+    assert c.numpy_consults == c.index_uses and c.native_consults == 0
+    if case != "empty_cache":
+        assert c.index_uses and c.hits
+
+
+@pytest.mark.parametrize("body", ["native", "numpy"])
+@pytest.mark.parametrize("seed", [5, 6, 7])
+def test_rows_left_out_of_the_walk_would_have_changed_nothing(
+    monkeypatch, seed, body
+):
+    """observe_fields leaves a frozen row of another algorithm out of
+    the walk where its fingerprint holds no slot and no token-bucket
+    row of the call could give it one. Walking EVERY frozen row of an
+    uncached fingerprint, as the cache did before, ends in the same
+    entries, in the same order, in the same slots — under frames where
+    a key's rows switch algorithm mid-frame, on both sides of a token
+    row that stores it."""
+    from gubernator_tpu.serve import shedcache
+
+    _run_body(monkeypatch, body)
+    monkeypatch.setattr(shedcache, "OBSERVE_INSERT_CAP", 10_000)
+    rng = np.random.default_rng(seed)
+    clock = FakeClock()
+    over = int(Status.OVER_LIMIT)
+    pool = rng.integers(0, 1 << 64, size=120, dtype=np.uint64)
+    new, old = ShedCache(256, now_fn=clock), ShedCache(256, now_fn=clock)
+    skipped = 0
+    for step in range(40):
+        clock.t += int(rng.choice([0, 1, 400, 1100]))
+        n = int(rng.integers(1, 300))
+        kh = rng.choice(pool, n)
+        algo = np.where(
+            rng.random(n) < 0.45, rng.integers(1, 4, size=n), 0
+        ).astype(np.int32)
+        fields = dict(
+            key_hash=kh, hits=np.ones(n, np.int64),
+            limit=np.full(n, 100, np.int64),
+            duration=np.full(n, 1000, np.int64), algo=algo,
+        )
+        frozen = rng.random(n) < 0.7
+        results = (
+            np.where(frozen, over, 0).astype(np.int32),
+            np.full(n, 100, np.int32),
+            np.where(frozen, 0, 7).astype(np.int32),
+            clock.t + rng.choice([-3, 500, 1000], n),
+        )
+        # the old rule, by hand: the cached rows, then every frozen row
+        # of an uncached fingerprint, each in row order
+        cached = np.array([int(h) in old for h in kh.tolist()])
+        rows = np.concatenate([
+            np.flatnonzero(cached), np.flatnonzero(frozen & ~cached)
+        ])
+        for i in rows.tolist():
+            old._observe_one(
+                int(kh[i]), 1, 100, 1000, int(algo[i]),
+                *(int(c[i]) for c in results), clock.t,
+            )
+        walked = []
+        new._observe_one = _recorded(new, walked)
+        new.observe_fields(fields, results)
+        skipped += rows.shape[0] - len(walked)
+        # (the cache walked by hand never consulted its index)
+        held = ("entries", "free", "top", "fp", "lim", "dur", "reset")
+        a, b = _cache_state(new), _cache_state(old)
+        assert {k: a[k] for k in held} == {k: b[k] for k in held}
+    assert skipped > 100  # the rule left rows out, and it did not matter
+
+
+def test_a_stitch_outside_the_answers_is_refused():
+    """Both bodies refuse to write a result past the answer columns,
+    and write nothing."""
+    from gubernator_tpu.serve import shedcache
+
+    fields = dict(key_hash=np.array([1, 2], np.uint64))
+    results = tuple(np.full(2, 7, np.int64) for _ in range(4))
+    bodies = [None] if shedcache._hn is None else [shedcache._hn, None]
+    for lib in bodies:
+        answers = tuple(np.zeros(2, np.int64) for _ in range(4))
+        c = ShedCache(8)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(shedcache, "_hn", lib)
+            with pytest.raises(IndexError):
+                c.observe_fields(
+                    fields, results,
+                    into=(answers, np.array([0, 2], np.int64)),
+                )
+        if lib is not None:
+            assert not any(col.any() for col in answers)
 
 
 # -- instance harness -------------------------------------------------------
