@@ -235,8 +235,8 @@ def boot_nodes(ring: Ring, which, deadline: float, named: str, cache_dir: str,
             raise BenchFailure(
                 f"the daemon{where} serves from '{device['platform']}', not a TPU"
             )
-        # one daemon may see more chips than it uses (cell 6); a ring's
-        # node sees its own and no other's
+        # one daemon may see more chips than it uses (a one-chip cell run
+        # on a four-chip machine); a ring's node sees its own and no other's
         if device["count"] < chips or (n > 1 and device["count"] != chips):
             raise BenchFailure(
                 f"{device['count']} devices{where}, the cell needs {chips}"
@@ -263,10 +263,13 @@ def set_aside(ring: Ring, which) -> float:
     return time.monotonic() + REBOOT_TIMEOUT
 
 
-def boot(args, config: dict, t_exec: float):
+def boot(args, config: dict, t_exec: float, tag: str = None):
     """The configuration's daemons up, each on the device it asks for:
     (the ring, node 0's device report, the platform named in the
-    environment or '', the seconds each boot took).
+    environment or '', the seconds each boot took). Every ring is drawn
+    for the run's key `tag`, so node 0 owns least of the zipf head
+    whichever of its boots is measured (a script that gives none gets
+    the circle's order as drawn).
 
     The daemons that are measured have found their programs in the
     compile cache. A boot that had to compile some (its own log says so:
@@ -285,12 +288,12 @@ def boot(args, config: dict, t_exec: float):
     argvs = daemon_argvs(args.daemon_argv, len(daemon_mod.node_specs(config)))
     how = (named, cache_dir)
     deadline, boots = t_exec + BOOT_TIMEOUT, []
-    ring = Ring(args.workload, config, OUT_DIR, argvs)
+    ring = Ring(args.workload, config, OUT_DIR, argvs, tag)
     try:
         if boot_nodes(ring, [0], deadline, *how, boots, True):
             deadline = set_aside(ring, [0])
             ring.stop()  # lets the ports held for the other nodes go
-            ring = Ring(args.workload, config, OUT_DIR, argvs)
+            ring = Ring(args.workload, config, OUT_DIR, argvs, tag)
             boot_nodes(ring, [0], deadline, *how, boots, False)
         others = list(range(1, len(ring.nodes)))
         if others:
@@ -403,8 +406,12 @@ def run(args, t_exec: float) -> int:
     shutil.rmtree(profile_dir, ignore_errors=True)
 
     tag = f"s{args.seed}"
-    ring, device, named, boots = boot(args, config, t_exec)
+    ring, device, named, boots = boot(args, config, t_exec, tag)
     n_nodes = len(ring.nodes)
+    if n_nodes > 1:
+        peers = [a["grpc"] for a in ring.addrs]
+        emit(phase="ring", peers=peers,
+             head_ids_owned=daemon_mod.head_owned(tag, peers))
     phases["boot"] = sum(boots)
     rehearsal = device["platform"] != "tpu"
     fleet = doors = None

@@ -17,7 +17,10 @@ Every port is drawn free, in every run, from below the range the
 kernel hands out, and held until its daemon starts. A key belongs to the node whose gRPC ADDRESS hashes next after
 it on the crc32 circle, so a ring's gRPC ports are the ones, of many
 drawn, that cut the circle into the most even arcs: each node owns one
-N-th of the keys whatever ports the machine had free.
+N-th of the keys whatever ports the machine had free. Which of them is
+node 0 follows the run's key names: the node that owns least of the
+zipf head (`head_owned`), so the door that clients dial is the same
+kind of node in every run.
 
 Node 0's addresses and reports stand at the top of the ring, so what
 knew one daemon (generators, the checks' doors, scripts) reaches node 0
@@ -42,6 +45,9 @@ import time
 import urllib.request
 import zlib
 
+from harness import keyspace
+from reference_ring import owner_of
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 DEFAULT_ARGV = ["-m", "gubernator_tpu.cli.daemon"]
 
@@ -51,6 +57,7 @@ class BenchFailure(Exception):
 
 
 POOL = 64  # free ports drawn for each node of a ring, to choose its gRPC port from
+HEAD_IDS = range(1, 17)  # the zipf head: id 1 alone is 18% of a stream at a = 1.2
 
 
 def ring_point(addr: str) -> int:
@@ -76,6 +83,17 @@ def even_points(points: list, n: int) -> list:
         if len({j for _, j in picks}) == n and (best is None or max(picks) < best[0]):
             best = (max(picks), [j for _, j in picks])
     return best[1]
+
+
+def head_owned(tag: str, grpc: list) -> list:
+    """For each gRPC address of a ring, the key ids of the zipf head it
+    owns under the run's key tag, by the plain reference's ring: a key
+    stands at the crc32 of its hash key, `<name>_<tag>:<id>` as the
+    generators send it."""
+    owned = {a: [] for a in grpc}
+    for k in HEAD_IDS:
+        owned[owner_of(f"{keyspace.NAME}_{tag}:{k}", grpc)].append(k)
+    return [owned[a] for a in grpc]
 
 
 def free_sockets(k: int) -> list:
@@ -109,14 +127,23 @@ def free_sockets(k: int) -> list:
     raise BenchFailure(f"no {k} free ports for the daemons")
 
 
-def draw_addresses(n: int):
+def draw_addresses(n: int, tag: str = None):
     """([{"grpc", "http", "geb"}, ...] for n nodes, the sockets that hold
     each node's three ports): every port free and distinct, all bound
     at once; the caller lets a node's go when it starts the node. A
-    ring's gRPC ports are chosen from POOL a node for even arcs."""
+    ring's gRPC ports are chosen from POOL a node for even arcs, in the
+    circle's order. Given the run's key tag, the order starts at the
+    node that owns none of the zipf head's ids, or where each of the n
+    owns some, at the one that does not own id 1 and owns the fewest of
+    the others: a run whose door node owns id 1 sits at another level
+    (PERF.md section 2), and ports x seed would decide which run does."""
     socks = free_sockets(3 if n == 1 else POOL * n)
     addr = [f"127.0.0.1:{s.getsockname()[1]}" for s in socks]
     grpc = even_points([ring_point(a) for a in addr], n) if n > 1 else [0]
+    if n > 1 and tag is not None:
+        owned = head_owned(tag, [addr[g] for g in grpc])
+        first = min(range(n), key=lambda i: (1 in owned[i], len(owned[i]), i))
+        grpc = grpc[first:] + grpc[:first]
     rest = iter(i for i in range(len(socks)) if i not in grpc)
     mine = [{"grpc": g, "http": next(rest), "geb": next(rest)} for g in grpc]
     kept = {i for m in mine for i in m.values()}
@@ -291,15 +318,17 @@ class Daemon:
 
 
 class Ring:
-    """A configuration's daemons on addresses drawn before any boots,
+    """A configuration's daemons on addresses drawn before any boots
+    (for the run's key tag, where one is given: `draw_addresses`),
     each node's ports held until it does. `start(i)` boots node i, or
     boots it afresh on the same addresses (its peers keep the list they
     were given)."""
 
-    def __init__(self, name: str, config: dict, log_dir: str, argvs=None):
+    def __init__(self, name: str, config: dict, log_dir: str, argvs=None,
+                 tag: str = None):
         self.name, self.log_dir = name, log_dir
         self.specs = node_specs(config)
-        self.addrs, self._held = draw_addresses(len(self.specs))
+        self.addrs, self._held = draw_addresses(len(self.specs), tag)
         self.argvs = argvs or {}
         self.nodes = [None] * len(self.specs)
         self.devices = [None] * len(self.specs)  # each node's own report

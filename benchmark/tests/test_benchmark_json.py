@@ -53,6 +53,27 @@ def test_per_layer_metrics_have_their_readers(bench):
         assert NAME.match(m["name"]) and len(m["unit"]) <= 16
 
 
+def test_a_reading_is_one_file_with_its_cells_as_a_list(bench):
+    """No two metric files say the same but for `cells` and `what`: a
+    reading that several cells report is ONE file that lists them, and
+    a suffixed name is left only where the spec differs (`moves`, a
+    `node`, a reader of its own). So twins do not come back, and the
+    128 entries `per_layer` may hold go to readings, not to cells."""
+    by_spec = {}
+    for name in sorted(os.listdir(os.path.join(BENCH, "layer_metrics"))):
+        spec = load(BENCH, "layer_metrics", name)
+        key = json.dumps({k: v for k, v in spec.items() if k not in ("cells", "what")},
+                         sort_keys=True)
+        by_spec.setdefault(key, []).append(name)
+    assert not [names for names in by_spec.values() if len(names) > 1]
+    names = [m["name"] for m in bench["per_layer"]]
+    assert len(set(names)) == len(names) <= 128
+    order = [w["name"] for w in bench["workloads"]]
+    for m in bench["per_layer"]:
+        # a list in BENCHMARK.json's cell order, no cell twice
+        assert m["workloads"] == sorted(set(m["workloads"]), key=order.index), m["name"]
+
+
 def test_every_cell_reports_setup_and_one_more(bench):
     for w in bench["workloads"]:
         mine = [m["name"] for m in bench["end_to_end"]

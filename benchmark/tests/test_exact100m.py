@@ -3,11 +3,12 @@
 
 - the configuration file states the shape the program derives from the
   environment it names, and zipf10m's guarantees word for word;
-- every `.x100m` / `.node` metric names its one cell, a reader that
-  exists and the `BENCHMARK.json` entry of its name;
+- each cell's metrics are the `BENCHMARK.json` entries that list it =
+  the files that list it, each with a reader that exists; what both
+  cells share with `zipf10m.geb-frames` is ONE file that lists the three;
 - the `gauge` reader by hand, and what it reads from a program that
   exports no such gauge (the parent) or no peak (the CPU): nothing;
-- `decide_roofline.x100m` by hand: the bytes are keyed on rows touched,
+- `decide_roofline` by hand: the bytes are keyed on rows touched,
   so a step of the same items needs the same bytes on 8 GiB as on 512 MiB;
 - both cells rehearsed traced on the CPU: `upstream-node.geb-frames` as
   it is (its store is 16 MiB), `exact100m.geb-frames` with the key
@@ -23,24 +24,19 @@ import sys
 
 import pytest
 
+import cell_metrics
 import kernel_bytes
 from readers import gauge
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 X100M, NODE = "exact100m.geb-frames", "upstream-node.geb-frames"
-#: what reads the device trace and needs a device plane
-FROM_THE_TRACE = {"decide_step_us", "decide_roofline", "device_idle_share"}
+CONTROL = "zipf10m.geb-frames"  # the same door and traffic on 512 MiB
 
 
 def load(*rel):
     with open(os.path.join(BENCH, *rel)) as f:
         return json.load(f)
-
-
-def metric_files(suffix):
-    return sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, "layer_metrics"))
-                  if f.endswith(suffix + ".json"))
 
 
 def test_the_configuration_states_what_the_program_derives():
@@ -70,31 +66,24 @@ def test_the_configuration_states_what_the_program_derives():
         "cells", NODE + ".json")["traffic"] == "geb-frames"
 
 
-@pytest.mark.parametrize("suffix,cell,count", [
-    (".x100m", X100M, 10), (".node", NODE, 7)])
-def test_every_new_metric_names_its_cell_and_a_reader(suffix, cell, count):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        declared = {m["name"]: m for m in json.load(f)["per_layer"]}
-    names = metric_files(suffix)
-    assert len(names) == count
-    for name in names:
-        spec = load("layer_metrics", name + ".json")
-        assert spec["cells"] == [cell] and spec["moves"] == "decisions_per_s"
-        assert os.path.isfile(os.path.join(BENCH, "readers", spec["reader"] + ".py"))
-        entry = declared[name]
-        assert entry["workloads"] == [cell]
-        for key in ("layer", "unit", "source", "moves"):
-            assert entry[key] == spec[key], (name, key)
-        # the same reading as the accepted metric of that name, another cell
-        twin = name[: -len(suffix)]
-        accepted = [n for n in (twin, twin + ".sat") if n in declared
-                    and os.path.isfile(os.path.join(BENCH, "layer_metrics", n + ".json"))]
-        if name.startswith("hbm_peak_over_state"):
-            assert not accepted and spec["reader"] == "gauge"
-            continue
-        old = load("layer_metrics", accepted[0] + ".json")
-        for key in set(old) - {"cells", "what"}:
-            assert spec[key] == old[key], (name, key)
+@pytest.mark.parametrize("cell", [X100M, NODE])
+def test_every_metric_of_the_cell_is_an_entry_a_file_and_a_reader(cell):
+    mine = cell_metrics.held_together(cell)
+    assert all(s["moves"] == "decisions_per_s" for s in mine.values())
+    # door, batcher, engine, kernel and device are read by the files
+    # that read them in the control cell: one spec, the cells a list
+    shared = {n for n, s in mine.items() if CONTROL in s["cells"]}
+    assert {"door_codec_us_per_frame", "shed_us_per_frame", "batch_fill_pct",
+            "submit_host_us_per_batch", "jit_call_us_per_batch",
+            "decide_step_us", "device_idle_share"} <= shared
+    own = set(mine) - shared
+    if cell == X100M:
+        # the one reading no other cell has: a second table alive
+        assert own == {"hbm_peak_over_state.x100m"}
+        assert mine["hbm_peak_over_state.x100m"]["reader"] == "gauge"
+        assert {"decide_roofline", "dispatch_us_per_batch"} <= shared
+    else:
+        assert not own
 
 
 def test_gauge_reader_by_hand():
@@ -141,8 +130,8 @@ def _edit(root, rel, **changes):
     path.write_text(json.dumps(obj))
 
 
-@pytest.mark.parametrize("cell,suffix", [(NODE, ".node"), (X100M, ".x100m")])
-def test_a_traced_rehearsal_reads_the_program_side_metrics(tmp_path, cell, suffix):
+@pytest.mark.parametrize("cell", [NODE, X100M])
+def test_a_traced_rehearsal_reads_the_program_side_metrics(tmp_path, cell):
     """`upstream-node.geb-frames` runs as it is but for the generators'
     size (2 workers x 4 frames of 200 items: a CPU answers them inside
     the frame timeout); `exact100m.geb-frames` also gets a key budget
@@ -175,9 +164,8 @@ def test_a_traced_rehearsal_reads_the_program_side_metrics(tmp_path, cell, suffi
     assert last["rehearsal"] == "cpu" and last["device"]["count"] == 1
     assert last["attempted"] > 0 and last["metrics"] == {}  # no timing
     trace = next(x for x in lines if x.get("phase") == "trace")
-    want = {n for n in metric_files(suffix)
-            if n[: -len(suffix)] not in FROM_THE_TRACE | {"hbm_peak_over_state"}}
-    assert set(trace["layer_metrics_read"]) == want
+    want = cell_metrics.rehearsed(cell)
+    assert set(trace["layer_metrics_read"]) == want and want
     post = next(x for x in lines if x.get("phase") == "post_window_check")
     assert post["tallies"]["outside_bounds"] == 0
     assert not any(post["counters_whole_run"].values())

@@ -3,7 +3,10 @@
 generator's GLOBAL pick by key id, its GLOBAL canaries, the two new
 readers on hand-worked numbers, the one-node GLOBAL reference against
 the program's oracle, and a traced rehearsal of the cell at a CPU's size
-that finds every `.mesh4` metric with a program-side source read.
+that finds every metric of the cell with a program-side source read
+(the cell's metrics = the `BENCHMARK.json` entries that list it = the
+files that list it: a `.mesh4` suffix is left only where the spec is
+the mesh's own).
 """
 
 import json
@@ -16,6 +19,7 @@ import sys
 import numpy as np
 import pytest
 
+import cell_metrics
 import kernel_bytes
 import reference_global
 from generators import closed_loop_frames_global as gen
@@ -163,16 +167,20 @@ def test_prom_sum_shares_and_what_a_parent_reads():
     assert prom_sum.read(skew, {"prom0": {}, "prom1": {}}) is None
 
 
-def test_every_mesh4_metric_names_the_cell_and_a_reader():
-    names = [f[:-5] for f in os.listdir(os.path.join(BENCH, "layer_metrics"))
-             if f.endswith(".mesh4.json")]
-    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
-        listed = [m["name"] for m in json.load(f)["per_layer"]]
-    assert sorted(names) == sorted(n for n in listed if n.endswith(".mesh4"))
-    for name in names:
-        spec = load("layer_metrics", name + ".json")
-        assert spec["cells"] == [CELL] and spec["moves"] == "decisions_per_s"
-        assert os.path.isfile(os.path.join(BENCH, "readers", spec["reader"] + ".py"))
+def test_every_metric_of_the_cell_is_an_entry_a_file_and_a_reader():
+    mine = cell_metrics.held_together(CELL)
+    assert all(s["moves"] == "decisions_per_s" for s in mine.values())
+    # the sharded roofline has a reader of its own (the flat one would
+    # flatter fourfold); step and idle share are the flat cells' files
+    assert mine["decide_roofline.mesh4"]["reader"] == "roofline_sharded"
+    assert "decide_roofline" not in mine
+    for name in ("decide_step_us", "device_idle_share", "shed_us_per_frame",
+                 "submit_host_us_per_batch", "jit_call_us_per_batch",
+                 "dispatch_us_per_batch", "door_codec_us_per_frame"):
+        assert "zipf10m.geb-frames" in mine[name]["cells"], name
+        assert CELL.split(".")[0] in mine[name]["what"], name  # its own sentence
+    # a suffix only where the file is this cell's alone
+    assert all(s["cells"] == [CELL] for n, s in mine.items() if n.endswith(".mesh4"))
 
 
 # -- the reference ------------------------------------------------------------
@@ -234,8 +242,8 @@ def test_one_node_global_reference_equals_the_oracle(seed):
 def test_a_traced_rehearsal_reads_the_program_side_metrics(tmp_path):
     """The cell at a CPU's size (tiny store, two workers, 200-item
     frames) on four simulated devices: exit 3 (`rehearsal`), correct,
-    and every `.mesh4` metric that reads a span or a counter is read;
-    the three that read the device trace need a device plane."""
+    and every metric of the cell that reads a span or a counter is
+    read; the three that read the device trace need a device plane."""
     root = tmp_path / "checkout"
     shutil.copytree(BENCH, root / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -269,8 +277,7 @@ def test_a_traced_rehearsal_reads_the_program_side_metrics(tmp_path):
     assert window["generator"]["frames_without_global_pct"] < 5.0
     assert 4.0 < window["generator"]["global_items_pct"] < 12.0
     trace = next(x for x in lines if x.get("phase") == "trace")
-    from_the_trace = {"decide_step_us.mesh4", "device_idle_share.mesh4",
-                      "decide_roofline.mesh4"}
-    want = {f[:-5] for f in os.listdir(os.path.join(BENCH, "layer_metrics"))
-            if f.endswith(".mesh4.json")} - from_the_trace
-    assert set(trace["layer_metrics_read"]) == want
+    want = cell_metrics.rehearsed(CELL)
+    assert set(trace["layer_metrics_read"]) == want and want
+    assert set(cell_metrics.files(CELL)) - want == {
+        "decide_step_us", "device_idle_share", "decide_roofline.mesh4"}

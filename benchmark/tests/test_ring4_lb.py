@@ -10,10 +10,11 @@ directory):
   workers (8), the seed and the words; the generator maps worker w to
   node w mod n, changes nothing but the address, and refuses a
   configuration of one node;
-- every `.ring4lb` metric names the one cell, a reader that exists, a
-  node and its `BENCHMARK.json` entry, and reads as its `.ring4` twin
-  where it has one; the four new readings by hand on made-up
-  snapshots, and nothing from a program without the counters;
+- the cell's metrics are the `BENCHMARK.json` entries that list it =
+  the files that list it, each with a reader that exists and a node; a
+  `.ring4lb` file reads as its `.ring4` twin but for the node, `.ring`
+  is ONE file for both ring cells; the four new readings by hand on
+  made-up snapshots, and nothing from a program without the counters;
 - `reference_ring4_doors` over 200 seeded interleavings of four doors
   equals ONE `reference.Limiter`, and for single hits every
   interleaving gives a key one summary;
@@ -31,6 +32,7 @@ import sys
 
 import pytest
 
+import cell_metrics
 import check
 import reference_ring4_doors
 from generators import closed_loop_frames, closed_loop_frames_all_doors
@@ -41,19 +43,16 @@ ROOT = os.path.dirname(BENCH)
 CELL = "ring4-lb.geb-frames-all-doors"
 SUFFIX = ".ring4lb"
 FROM_THE_TRACE = {"decide_step_us", "device_idle_share"}
-#: what reads PR 46's counters, the pooled loop lag and the generator
-NEW = {"peer_rows_pct", "mixed_batches_pct", "loop_lag_p99_ms", "door_skew_pct"}
+#: what reads PR 46's counters, the pooled loop lag, the generator and
+#: (PR 47) the owners' queue: no `.ring4` twin
+NEW = {"peer_rows_pct", "mixed_batches_pct", "loop_lag_p99_ms", "door_skew_pct",
+       "call_queue_ms"}
 NOT_RING4S = ("name", "source", "why", "reduced", "reduced_detail", "assumed")
 
 
 def load(*rel):
     with open(os.path.join(BENCH, *rel)) as f:
         return json.load(f)
-
-
-def lb_metrics():
-    return sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, "layer_metrics"))
-                  if f.endswith(SUFFIX + ".json"))
 
 
 # -- the files ----------------------------------------------------------------
@@ -167,33 +166,25 @@ def test_the_summary_adds_each_doors_pace_and_the_workers_cpu():
     assert g["door_skew_pct"] == pytest.approx(20.0 / 65.0 * 100.0)
 
 
-def test_every_ring4lb_metric_names_the_cell_a_reader_and_a_node():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    declared = {m["name"]: m for m in bench["per_layer"]}
-    names = lb_metrics()
-    assert len(names) == 18 and {n[:-len(SUFFIX)] for n in names} >= NEW
-    assert [m["name"] for m in bench["per_layer"]][-18:] == [
-        m["name"] for m in bench["per_layer"] if m["name"].endswith(SUFFIX)]
-    for name in names:
-        spec = load("layer_metrics", name + ".json")
-        assert spec["cells"] == [CELL] and spec["moves"] == "decisions_per_s"
-        assert os.path.isfile(os.path.join(BENCH, "readers", spec["reader"] + ".py"))
-        base = name[:-len(SUFFIX)]
+def test_every_metric_of_the_cell_is_an_entry_a_file_a_reader_and_a_node():
+    mine = cell_metrics.held_together(CELL)
+    declared = cell_metrics.declared(CELL)
+    ring4 = cell_metrics.declared("ring4.geb-frames")
+    assert {n.split(".")[0] for n in mine} >= NEW
+    for name, spec in mine.items():
+        assert spec["moves"] == "decisions_per_s"
+        assert name.endswith((SUFFIX, ".ring")), name
+        assert (spec["cells"] == [CELL]) == name.endswith(SUFFIX), name
+        base = name.rsplit(".", 1)[0]
         if spec["reader"] != "generator":
             # every node is a door and an owner: pooled, but the capture
             assert spec["node"] == (0 if base in FROM_THE_TRACE else "all")
-        entry = declared[name]
-        assert entry["workloads"] == [CELL]
-        for key in ("layer", "unit", "source", "moves"):
-            assert entry[key] == spec[key], (name, key)
-        if base in NEW:
+        if base in NEW or name.endswith(".ring"):
             continue
-        twin = load("layer_metrics", base + ".ring4.json")  # reads as its twin
-        assert declared[base + ".ring4"]["better"] == entry["better"]
+        twin = cell_metrics.spec(base + ".ring4")  # reads as its twin
+        assert ring4[base + ".ring4"]["better"] == declared[name]["better"]
         for key in set(twin) - {"cells", "what", "node"}:
             assert spec[key] == twin[key], (name, key)
-    assert len({m["name"] for m in bench["per_layer"]}) == len(bench["per_layer"]) <= 128
 
 
 def _node(prom, lag_buckets=None, scale=2):
@@ -348,8 +339,10 @@ def test_a_traced_rehearsal_reads_every_program_side_metric(tmp_path):
     ready = next(x for x in lines if x.get("phase") == "generators_ready")
     assert len(ready["workers"]) == 8
     trace = next(x for x in lines if x.get("phase") == "trace")
-    want = {n for n in lb_metrics() if n[:-len(SUFFIX)] not in FROM_THE_TRACE}
-    assert set(trace["layer_metrics_read"]) == want and len(want) == 16
+    want = cell_metrics.rehearsed(CELL)
+    assert set(trace["layer_metrics_read"]) == want and want
+    assert set(cell_metrics.files(CELL)) - want == {
+        n + SUFFIX for n in FROM_THE_TRACE}
     window = next(x for x in lines if x.get("phase") == "window")
     assert window["node_exits"] == [0, 0, 0, 0]
     g = window["generator"]

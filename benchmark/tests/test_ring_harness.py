@@ -50,11 +50,17 @@ def ring_config(n=3):
                        "chips": 1} for i in range(n)]}
 
 
-def drawn(n):
-    addrs, held = daemon.draw_addresses(n)
+def drawn(n, tag=None):
+    addrs, held = daemon.draw_addresses(n, tag)
     for s in sum(held, []):
         s.close()
     return addrs
+
+
+def arcs(addrs):
+    """Each node's share of the circle, the nodes taken in the order given."""
+    points = [daemon.ring_point(a["grpc"]) for a in addrs]
+    return [(points[i] - points[i - 1]) % 2**32 / 2**32 for i in range(len(addrs))]
 
 
 def test_the_rings_environment_is_composed_as_stated():
@@ -112,6 +118,48 @@ def test_a_rings_ports_are_drawn_held_and_cut_the_circle_evenly(n):
         ring.stop()
 
 
+def test_the_door_node_owns_least_of_the_zipf_head_and_never_key_id_1():
+    """Node 0 is the node the clients of `ring4.geb-frames` dial, and a
+    run in which it owns key id 1 sat 9% above one in which it does not
+    (PERF.md section 2). Given the run's key tag the ring starts at the
+    node that owns none of ids 1-16 whenever one of the four does, else
+    at the one without id 1 that owns the fewest of 2-16; the owner is
+    the PLAIN REFERENCE's (`reference_ring.owner_of` over the hash key
+    the generators send), the arcs are as even as without a tag and in
+    the circle's order, and two rings drawn at once share no port."""
+    import reference_ring
+
+    from harness import keyspace
+
+    n = 4
+    for seed in range(200):
+        tag = f"s{2**31 + 7919 * seed}"
+        addrs, held = daemon.draw_addresses(n, tag)
+        again = drawn(n, tag)  # while the first ring's ports are held
+        for s in sum(held, []):
+            s.close()
+        both = [a[door] for a in addrs + again for door in a]
+        assert len(set(both)) == 6 * n  # two rings at once share none
+        peers = [a["grpc"] for a in addrs]
+        owner = {k: peers.index(reference_ring.owner_of(
+            f"{keyspace.NAME}_{tag}:{k}", peers)) for k in daemon.HEAD_IDS}
+        owned = [[k for k in daemon.HEAD_IDS if owner[k] == i] for i in range(n)]
+        assert owned == daemon.head_owned(tag, peers)
+        assert 1 not in owned[0], (tag, owned)
+        if any(not o for o in owned):
+            assert not owned[0], (tag, owned)
+        else:  # every node owns some: the fewest among those without id 1
+            assert len(owned[0]) == min(len(o) for o in owned if 1 not in o)
+        assert all(abs(arc - 1 / n) < 0.01 for arc in arcs(addrs))  # and in order
+
+
+def test_a_ring_drawn_without_a_tag_is_in_the_circles_order_as_ever():
+    assert all(abs(arc - 0.25) < 0.01 for arc in arcs(drawn(4)))
+    # one daemon has no ring to order, tag or none
+    (one,) = drawn(1, "s7")
+    assert set(one) == {"grpc", "http", "geb"}
+
+
 def test_one_node_is_given_what_the_harness_always_gave_it():
     config = {"chips": 1, "env": dict(TINY_ENV)}
     (spec,) = daemon.node_specs(config)
@@ -131,12 +179,16 @@ def test_one_node_is_given_what_the_harness_always_gave_it():
     assert one[0]["env"]["A"] == "b"
 
 
-def test_the_six_configurations_are_one_daemon_each():
+def test_a_configuration_without_nodes_is_one_daemon():
     for name in os.listdir(os.path.join(BENCH, "configs")):
         with open(os.path.join(BENCH, "configs", name)) as f:
             config = json.load(f)
-        assert daemon.node_specs(config) == [
-            {"env": config["env"], "chips": config["chips"]}], name
+        specs = daemon.node_specs(config)
+        if "nodes" in config:  # a ring: its nodes' chips are the cell's
+            assert len(specs) == len(config["nodes"]) > 1, name
+            assert sum(s["chips"] for s in specs) == config["chips"], name
+        else:
+            assert specs == [{"env": config["env"], "chips": config["chips"]}], name
 
 
 @pytest.mark.parametrize("config", [

@@ -4,10 +4,12 @@
 - the configuration file states zipf10m's environment, store and
   guarantees word for word and the ring's shape; the traffic file
   geb-frames' limit classes and algorithm mix letter for letter;
-- every `.peer` metric names the one cell, a reader that exists and the
-  `BENCHMARK.json` entry of its name, and reads as its accepted twin
-  does; the two that read PR 32's span and counters read nothing from
-  a program that has neither (the parent);
+- the cell's metrics are the `BENCHMARK.json` entries that list it =
+  the files that list it; what it shares with `zipf10m.geb-frames`
+  (batcher, engine, kernel, device) is ONE file that lists both, a
+  `.peer` file is the peer door's own; the two that read PR 32's span
+  and counters read nothing from a program that has neither (the
+  parent), and the two guards read 100 where the native paths serve;
 - the cell rehearsed traced on the CPU with the key budget cut to a
   CPU's size: exit 3, `correct: true`, every program-side metric read,
   the generator's own check of the peer door printed;
@@ -25,18 +27,18 @@ import sys
 
 import pytest
 
+import cell_metrics
 from readers import prom_sum, stages
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 CELL = "ring-owner6.peer-batches"
-FROM_THE_TRACE = {"decide_step_us", "decide_roofline", "device_idle_share"}
-#: the accepted metric each `.peer` metric reads like; the two new
-#: readings (PR 32's span and counters) have none
+#: the gRPC cell's metric each call-tile `.peer` metric reads like, at
+#: 1000 items a call instead of 2 (another end-to-end metric: no merge)
 TWIN = {
-    "door_codec_us_per_batch": "grpc_codec_us_per_call",
-    "device_idle_share": "device_idle_share.sat",
-    "peer_serve_us_per_batch": None, "peer_shed_hit_pct": None,
+    "door_codec_us_per_batch.peer": "grpc_codec_us_per_call",
+    "call_server_ms.peer": "call_server_ms", "call_queue_ms.peer": "call_queue_ms",
+    "call_device_ms.peer": "call_device_ms", "call_wake_us.peer": "call_wake_us",
 }
 
 
@@ -45,21 +47,16 @@ def load(*rel):
         return json.load(f)
 
 
-def peer_metrics():
-    return sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, "layer_metrics"))
-                  if f.endswith(".peer.json"))
-
-
 def test_the_configuration_is_zipf10m_behind_another_door():
     from gubernator_tpu.core.store import derive_store_config
 
     config, zipf10m = load("configs", "ring-owner6.json"), load("configs", "zipf10m.json")
     for key in ("env", "store", "key_population", "preload_keys", "guarantees"):
         assert config[key] == zipf10m[key], key
-    # one chip serves, as zipf10m's; the machine asked for has four, for
-    # the host's sake alone (the file says why)
-    assert (config["chips_used"], config["chips"]) == (zipf10m["chips"], 4)
-    assert "steadiness alone" in config["chips_why"]
+    # one chip serves, as zipf10m's, and since PR 49 the cell asks for
+    # the machine it uses (four before, for the host's sake alone)
+    assert config["chips_used"] == config["chips"] == zipf10m["chips"] == 1
+    assert "PR 49" in config["chips_why"]
     derived = derive_store_config(
         target_keys=int(config["env"]["GUBER_STORE_TARGET_KEYS"]))
     assert (config["store"]["ways"], config["store"]["rows"]) == (
@@ -80,29 +77,24 @@ def test_the_configuration_is_zipf10m_behind_another_door():
     assert (cell["config"], cell["traffic"]) == ("ring-owner6", "peer-batches")
 
 
-def test_every_peer_metric_names_the_cell_and_a_reader():
+def test_every_metric_of_the_cell_is_an_entry_a_file_and_a_reader():
+    mine = cell_metrics.held_together(CELL)
+    assert all(s["moves"] == "decisions_per_s" for s in mine.values())
+    for name, twin in TWIN.items():  # the call's tiles read as cell 1's do
+        old = cell_metrics.spec(twin)
+        for key in set(old) - {"cells", "what", "layer", "moves"}:
+            assert mine[name][key] == old[key], (name, key)
+    # below the door the cell is zipf10m.geb-frames: the same files
+    for name in ("batch_fill_pct", "submit_host_us_per_batch", "jit_call_us_per_batch",
+                 "decide_step_us", "decide_roofline", "device_idle_share"):
+        assert "zipf10m.geb-frames" in mine[name]["cells"], name
+    assert all(s["cells"] == [CELL] for n, s in mine.items() if n.endswith(".peer"))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    declared = {m["name"]: m for m in bench["per_layer"]}
-    names = peer_metrics()
-    assert len(names) == 13
-    for name in names:
-        spec = load("layer_metrics", name + ".json")
-        assert spec["cells"] == [CELL] and spec["moves"] == "decisions_per_s"
-        assert os.path.isfile(os.path.join(BENCH, "readers", spec["reader"] + ".py"))
-        entry = declared[name]
-        assert entry["workloads"] == [CELL]
-        for key in ("layer", "unit", "source", "moves"):
-            assert entry[key] == spec[key], (name, key)
-        base = name[: -len(".peer")]
-        twin = TWIN.get(base, base)
-        if twin is None:
-            continue
-        old = load("layer_metrics", twin + ".json")
-        for key in set(old) - {"cells", "what", "layer", "moves"}:
-            assert spec[key] == old[key], (name, key)
     e2e = next(m for m in bench["end_to_end"] if m["name"] == "decisions_per_s")
-    assert e2e["workloads"][-1] == CELL and e2e["bound"] == 0.1  # 0.05 until PR 38
+    assert CELL in e2e["workloads"] and e2e["bound"] == 0.1  # 0.05 until PR 38
+    work = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert work["chips"] == 1
 
 
 def test_the_new_readings_by_hand_and_on_a_program_without_them():
@@ -123,6 +115,22 @@ def test_the_new_readings_by_hand_and_on_a_program_without_them():
     parent = {"stages0": bare, "stages1": bare, "prom0": {}, "prom1": {"shed_hits_total": 5.0}}
     assert stages.read(span, parent) is None
     assert prom_sum.read(share, parent) is None
+    # PR 49's two guards: 100 where the native body and the fold serve,
+    # less where the twin does, nothing from a program without the counters
+    native = load("layer_metrics", "shed_native_consults_pct.json")
+    folded = load("layer_metrics", "peer_folded_items_pct.json")
+    assert CELL in native["cells"] and folded["cells"] == [CELL]
+    ctx["prom0"].update(shed_index_uses_total=10.0, shed_native_consults_total=10.0,
+                        peer_serve_folded_items_total=1000.0)
+    ctx["prom1"].update(shed_index_uses_total=410.0, shed_native_consults_total=410.0,
+                        peer_serve_folded_items_total=5000.0)
+    assert prom_sum.read(native, ctx) == prom_sum.read(folded, ctx) == 100.0
+    ctx["prom1"].update(shed_native_consults_total=110.0,  # the numpy twin took over
+                        peer_serve_folded_items_total=4000.0)  # a batch in four declined
+    assert prom_sum.read(native, ctx) == pytest.approx(25.0)
+    assert prom_sum.read(folded, ctx) == pytest.approx(75.0)
+    assert prom_sum.read(native, parent) is None
+    assert prom_sum.read(folded, parent) is None
 
 
 def _copy(tmp_path):
@@ -139,7 +147,7 @@ def _copy(tmp_path):
         (f"cells/{CELL}.json", dict(trace_ms=500)),
         ("configs/ring-owner6.json", dict(
             env={"GUBER_STORE_TARGET_KEYS": "20000"}, key_population=5000,
-            preload_keys=5000, chips=1)),  # the CPU here is one device
+            preload_keys=5000)),
     ):
         path = root / "benchmark" / rel
         obj = json.loads(path.read_text())
@@ -175,8 +183,11 @@ def test_a_traced_rehearsal_reads_the_program_side_metrics(tmp_path):
     assert door["over_limit_answers"] > 0
     assert "peer_door_check" not in ready["workers"][1]
     trace = next(x for x in lines if x.get("phase") == "trace")
-    want = {n for n in peer_metrics() if n[: -len(".peer")] not in FROM_THE_TRACE}
-    assert set(trace["layer_metrics_read"]) == want and len(want) == 10
+    want = cell_metrics.rehearsed(CELL)
+    assert set(trace["layer_metrics_read"]) == want and want
+    assert {"shed_native_consults_pct", "peer_folded_items_pct"} <= want
+    assert set(cell_metrics.files(CELL)) - want == {
+        "decide_step_us", "decide_roofline", "device_idle_share"}
     gen = next(x for x in lines if x.get("phase") == "window")["generator"]
     assert len(gen["worker_cpu_share"]) == 2 and gen["batches_per_s"] > 0
     assert "peer_door_check" not in gen  # said once, in the ready line
